@@ -97,6 +97,10 @@ class WorkerState:
     # reconstruction deadlocks the moment every worker slot holds a
     # consumer blocked on an object only a queued task can re-derive.
     blocked: bool = False
+    # a chip grant is in flight on this worker: it must not be handed
+    # to anyone else until the env is acknowledged
+    reserved: bool = False
+    chips_acked: bool = False  # the chip env it is pinned to has landed
 
     @property
     def idle(self):
@@ -104,6 +108,7 @@ class WorkerState:
             not self.in_flight
             and self.actor_id is None
             and self.leased_to is None
+            and not self.reserved
         )
 
 
@@ -793,8 +798,7 @@ class NodeDaemon:
                 # env; retire one so the queued task can't starve
                 self._reclaim_idle_pinned(tpu_n, spec.env_hash)
                 return None
-            if tpu_n and not self._assign_chips(w, tpu_n):
-                self._reclaim_idle_pinned(tpu_n, spec.env_hash)
+            if tpu_n and not self._chips_ready(w, tpu_n, spec.env_hash):
                 return None
             if spec.env_hash is not None:
                 w.env_hash = spec.env_hash
@@ -1383,34 +1387,43 @@ class NodeDaemon:
         t = float(demand.get("TPU", 0.0))
         return int(t) if t >= 1 and t.is_integer() else 0
 
-    def _assign_chips(self, w: WorkerState, n: int) -> bool:
-        """Pin `n` chips to worker `w` (no-op match if already pinned to
-        exactly n) and push the isolation env over its conn.  Safe for
-        the daemon-dispatch path: the env rides the same ordered stream
-        as the execute_task push that follows.  The direct-push lease
-        path must use `_assign_chips_acked` instead — there the task
-        arrives on a different conn (caller -> worker) and nothing else
-        orders the two streams."""
+    def _chips_ready(self, w: WorkerState, n: int,
+                     env_hash: Optional[str] = None) -> bool:
+        """Synchronous scheduling path: True once `w` is pinned to `n`
+        chips AND has acknowledged the env.  Otherwise the grant is
+        started in the background with the worker reserved, and the
+        queue is scheduled again when the answer is in — a task is
+        never pushed behind an env the worker might refuse."""
         if self._chip_pool is None:
             return True
-        chips = self._chip_pool.assign(w.worker_id, n)
-        if chips is None:
+        held = self._chip_pool.pinned(w.worker_id)
+        if held is not None and len(held) != n:
+            self._reclaim_idle_pinned(n, env_hash)
             return False
-        env = accelerators.chip_isolation_env(
-            list(chips), self._chip_pool.num_chips
-        )
-        try:
-            w.conn.send("set_accel_env", env)
-        except Exception as e:
-            logger.debug("set_accel_env send to %s failed: %s",
-                         w.worker_id[:8], e)
-            return False
-        return True
+        if held is not None and w.chips_acked:
+            return True
+
+        async def _grant():
+            try:
+                ok = await self._assign_chips_acked(w, n)
+            finally:
+                w.reserved = False
+            if not ok:
+                self._reclaim_idle_pinned(n, env_hash)
+            self._schedule()
+
+        w.reserved = True  # before the loop can pick it again
+        asyncio.ensure_future(_grant())
+        return False
 
     async def _assign_chips_acked(self, w: WorkerState, n: int) -> bool:
-        """Like `_assign_chips` but waits for the worker to acknowledge
-        the env before returning, so a lease reply cannot race the
-        caller's first direct task push past the isolation setup."""
+        """Pin `n` chips to worker `w` (no-op match if already pinned to
+        exactly n), push the isolation env over its conn and wait for
+        the answer, so neither a lease reply nor a task push can race
+        past the isolation setup.  A worker that has ALREADY imported
+        JAX cannot change devices any more: it says so, and is retired
+        here — its death frees the chips and the pool respawns a clean
+        worker for the next attempt."""
         if self._chip_pool is None:
             return True
         chips = self._chip_pool.assign(w.worker_id, n)
@@ -1419,12 +1432,31 @@ class NodeDaemon:
         env = accelerators.chip_isolation_env(
             list(chips), self._chip_pool.num_chips
         )
+        # reserved across the await: nobody else may pick this worker
+        # while its grant is in flight
+        w.reserved = True
         try:
-            await w.conn.call("set_accel_env", env, timeout=10)
+            reply = await w.conn.call("set_accel_env", env, timeout=10)
         except Exception as e:
             logger.debug("set_accel_env call to %s failed: %s",
                          w.worker_id[:8], e)
             return False
+        finally:
+            w.reserved = False
+        if not (reply or {}).get("ok"):
+            logger.warning(
+                "worker %s refused a %d-chip grant (%s): retiring it",
+                w.worker_id[:8], n, (reply or {}).get("error"),
+            )
+            try:
+                os.kill(w.pid, signal.SIGKILL)
+            except (OSError, ProcessLookupError) as e:
+                logger.debug("killing refusing worker %d: %s", w.pid, e)
+            # out of the pool NOW (chips freed, replacement spawned):
+            # nothing may pick it again while the kill is reaped
+            self._on_worker_dead(w, "refused a chip grant")
+            return False
+        w.chips_acked = True
         return True
 
     def _pick_idle_worker(
@@ -2332,9 +2364,9 @@ class NodeDaemon:
 
         actor_container = container_section(aspec.runtime_env)
         target = None
-        # generous: a fresh worker's first boot imports jax + the TPU
-        # plugin (~10s/worker on hardware, multiplied under CPU
-        # contention); 60s raced that boot and spuriously failed actor
+        # generous: a fresh worker's first boot imports jax and brings
+        # up the TPU runtime (~15-30s/worker on a v5e host, multiplied
+        # under CPU contention); 60s raced that boot and spuriously failed actor
         # creation on loaded hosts
         deadline = time.monotonic() + 240
         while target is None:
@@ -2342,8 +2374,8 @@ class NodeDaemon:
                 tpu_n, require_no_lease=True, env_hash=actor_env_hash,
                 require_exact_env=actor_container is not None,
             )
-            if target is not None and tpu_n and not self._assign_chips(
-                target, tpu_n
+            if target is not None and tpu_n and not (
+                await self._assign_chips_acked(target, tpu_n)
             ):
                 target = None
                 self._reclaim_idle_pinned(tpu_n, actor_env_hash)

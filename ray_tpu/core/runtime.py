@@ -3094,27 +3094,28 @@ class Runtime:
 
     async def _h_set_accel_env(self, payload, conn):
         """Daemon push at lease-grant time: accelerator isolation env
-        (TPU_VISIBLE_CHIPS et al — `core/accelerators.py`).  Must land
-        before user code first initializes the ML framework; the daemon
-        sends it on the same ordered stream as the task push.  An empty
-        string unsets the variable (all-chip grants clear restrictions).
+        (TPU_VISIBLE_CHIPS et al plus `JAX_PLATFORMS=tpu` —
+        `core/accelerators.py`).  It must land before this process
+        imports JAX: afterwards the devices are fixed and a changed env
+        would be silently ignored, so the grant is REFUSED and the
+        daemon retires this worker for a fresh one.  An empty string
+        unsets the variable (all-chip grants clear restrictions).
         """
         import sys as _sys
 
-        changed = False
-        for k, v in (payload or {}).items():
+        changes = {
+            k: v for k, v in (payload or {}).items()
+            if os.environ.get(k, "") != v
+        }
+        if changes and "jax" in _sys.modules:
+            return {"ok": False,
+                    "error": "jax already imported in this worker; "
+                             f"cannot apply {sorted(changes)}"}
+        for k, v in changes.items():
             if v == "":
-                if k in os.environ:
-                    del os.environ[k]
-                    changed = True
-            elif os.environ.get(k) != v:
+                del os.environ[k]
+            else:
                 os.environ[k] = v
-                changed = True
-        if changed and "jax" in _sys.modules:
-            logger.warning(
-                "accelerator env changed after jax was imported; the new "
-                "chip visibility takes effect only in a fresh worker"
-            )
         return {"ok": True}
 
     # ---- executor side ----------------------------------------------

@@ -1,7 +1,7 @@
 """User-visible exception hierarchy.
 
 Mirrors the surface of the reference's `python/ray/exceptions.py` so users
-switching over find the same failure taxonomy: task errors wrap the user
+switching over find the same failure hierarchy: task errors wrap the user
 traceback, worker/actor/node crashes and lost objects are distinct types,
 and `get` re-raises the underlying cause.
 """

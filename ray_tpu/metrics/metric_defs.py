@@ -126,7 +126,7 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[tuple]]] = {
     "rt_serve_decode_kernel_total": (
         "gauge", "decode ticks dispatched through the fused paged-"
         "attention kernel (monotonic, bridged; gather-fallback ticks "
-        "are the engine's decode_fallback_dispatch_total)",
+        "are the engine's decode_gather_dispatch_total)",
         ("app", "deployment", "replica"), None),
     # ---- serve request ledger (serve/request_ledger.py; windowed
     # per-request phase latencies replacing EMA-only reporting) -------

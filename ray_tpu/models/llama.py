@@ -665,7 +665,7 @@ def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
 
 def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
                       k_pool, v_pool, tables, pos, *, kv_scales=None,
-                      interpret: Optional[bool] = None):
+                      interpret: bool = False):
     """One decode step with PER-ROW positions straight off the paged
     KV pool — `decode_step_vec` with the dense gather/scatter replaced
     by the Pallas kernels in `ops/paged_attention.py`.

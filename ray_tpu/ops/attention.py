@@ -28,20 +28,19 @@ lost to it):
   split kernel pair pays 7 matmuls + 2 exps + an XLA delta pass for
   the same math (measured +6% end-to-end GPT-2 step on v5e).
 
-On CPU (tests) the kernels run in interpreter mode when small, else
-fall back to the XLA path (`plain_attention`).
+The kernels are what runs: a shape they cannot tile raises, naming the
+shape, and nothing here looks at the backend or gives way to the XLA
+path.  `interpret=True` is the caller's explicit choice (the CPU
+tests); `tests/test_aot_tpu_compile.py` lowers the train shapes for a
+described v5e chip.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from ray_tpu.ops.pallas_compat import compiler_params as _compiler_params
-from ray_tpu.parallel.ring_attention import plain_attention
 
 _NEG_INF = -1e30
 
@@ -131,7 +130,7 @@ def _build_fwd(causal, scale, block_q, block_k, n_k, interpret, dtype):
                 pltpu.VMEM((block_q,), jnp.float32),
                 pltpu.VMEM((block_q, D), jnp.float32),
             ],
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -190,7 +189,7 @@ def _build_bwd_dq(causal, scale, block_q, block_k, n_k, interpret, dtype):
             out_specs=pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -241,7 +240,7 @@ def _build_bwd_fused(causal, scale, T, interpret, dtype):
             in_specs=[spec, spec, spec, spec, vec, spec],
             out_specs=[spec, spec, spec],
             out_shape=[jax.ShapeDtypeStruct((BH, T_, D), q.dtype)] * 3,
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
             ),
             interpret=interpret,
@@ -316,7 +315,7 @@ def _build_bwd_dkv(causal, scale, block_q, block_k, n_q, interpret, dtype):
                 pltpu.VMEM((block_k, D), jnp.float32),
                 pltpu.VMEM((block_k, D), jnp.float32),
             ],
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -325,13 +324,19 @@ def _build_bwd_dkv(causal, scale, block_q, block_k, n_q, interpret, dtype):
     return call
 
 
-def _supported(T: int, D: int, block_q: int, block_k: int) -> bool:
-    return (
-        T % block_q == 0
-        and T % block_k == 0
-        and D % 8 == 0
-        and T >= block_q
-    )
+def _blocks(q, block_q: int, block_k: int):
+    """(block_q, block_k) clamped to T, or a ValueError naming the
+    shape the kernels cannot tile."""
+    B, T, H, D = q.shape
+    block_q, block_k = min(block_q, T), min(block_k, T)
+    if T % block_q or T % block_k or D % 8:
+        raise ValueError(
+            f"flash_attention cannot tile q/k/v {tuple(q.shape)} "
+            f"[B, T, H, D] with blocks ({block_q}, {block_k}): T must "
+            "be a multiple of both blocks and D of 8 — pad the "
+            "sequence or use attention=\"dense\""
+        )
+    return block_q, block_k
 
 
 def _fold(x):
@@ -349,58 +354,34 @@ def _unfold(x, B, H):
 )
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 1024, block_k: int = 1024,
-                    force_pallas: Optional[bool] = None):
+                    interpret: bool = False):
     """q/k/v [B, T, H, D] -> [B, T, H, D]."""
-    out, _ = _fwd(q, k, v, causal, block_q, block_k, force_pallas)
+    out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret)
     return out
 
 
-def _use_pallas(q, block_q, block_k, force_pallas):
+def _fwd(q, k, v, causal, block_q, block_k, interpret):
     B, T, H, D = q.shape
-    on_tpu = jax.default_backend() == "tpu"
-    use = force_pallas if force_pallas is not None else on_tpu
-    return (use and _supported(T, D, min(block_q, T), min(block_k, T)),
-            on_tpu)
-
-
-def _fwd(q, k, v, causal, block_q, block_k, force_pallas):
-    B, T, H, D = q.shape
-    use_pallas, on_tpu = _use_pallas(q, block_q, block_k, force_pallas)
-    if not use_pallas:
-        return plain_attention(q, k, v, causal=causal), (q, k, v, None, None)
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
+    block_q, block_k = _blocks(q, block_q, block_k)
     scale = 1.0 / (D ** 0.5)
     n_k = T // block_k
     fwd = _build_fwd(causal, scale, block_q, block_k, n_k,
-                     not on_tpu, q.dtype)
+                     interpret, q.dtype)
     out, lse = fwd(_fold(q), _fold(k), _fold(v))
-    return _unfold(out, B, H), (q, k, v, _unfold_lse(lse, B, H), out)
+    # lse and out stay folded [B*H, T, ...] for the backward kernels
+    return _unfold(out, B, H), (q, k, v, lse, out)
 
 
-def _unfold_lse(lse, B, H):
-    # [B*H, T] -> kept folded; tagged via tuple to avoid reshuffling
-    return lse
-
-
-def _bwd(causal, block_q, block_k, force_pallas, res, g):
+def _bwd(causal, block_q, block_k, interpret, res, g):
     q, k, v, lse, out_folded = res
-    if lse is None:
-        # fallback path: differentiate the XLA attention
-        _, vjp = jax.vjp(
-            lambda q, k, v: plain_attention(q, k, v, causal=causal), q, k, v
-        )
-        return vjp(g)
     B, T, H, D = q.shape
-    on_tpu = jax.default_backend() == "tpu"
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
+    block_q, block_k = _blocks(q, block_q, block_k)
     scale = 1.0 / (D ** 0.5)
     n_q = T // block_q
     n_k = T // block_k
     qf, kf, vf, dof = _fold(q), _fold(k), _fold(v), _fold(g)
     if block_q == T and block_k == T:
-        fused = _build_bwd_fused(causal, scale, T, not on_tpu, q.dtype)
+        fused = _build_bwd_fused(causal, scale, T, interpret, q.dtype)
         dq, dk, dv = fused(qf, kf, vf, dof, lse, out_folded)
         return _unfold(dq, B, H), _unfold(dk, B, H), _unfold(dv, B, H)
     delta = jnp.sum(
@@ -408,9 +389,9 @@ def _bwd(causal, block_q, block_k, force_pallas, res, g):
         axis=-1, keepdims=True,
     )  # [BH, T, 1], matching lse's singleton lane dim
     dq_call = _build_bwd_dq(causal, scale, block_q, block_k, n_k,
-                            not on_tpu, q.dtype)
+                            interpret, q.dtype)
     dkv_call = _build_bwd_dkv(causal, scale, block_q, block_k, n_q,
-                              not on_tpu, q.dtype)
+                              interpret, q.dtype)
     dq = dq_call(qf, kf, vf, dof, lse, delta)
     dk, dv = dkv_call(qf, kf, vf, dof, lse, delta)
     return _unfold(dq, B, H), _unfold(dk, B, H), _unfold(dv, B, H)

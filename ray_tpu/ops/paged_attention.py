@@ -38,27 +38,22 @@ KV]` stored blockwise beside the pool; dequantization is fused inside
 the attention kernel (int8 payload is all that crosses HBM) and the
 append kernel writes the quantized row + its scale.
 
-On CPU the kernels run in interpret mode (`interpret=None` resolves
-via `jax.default_backend()`); `ray_tpu.testing.pallas_kernel_support
-("paged")` probes the environment and tier-1 kernel tests skip-guard
-on it.
+The kernels are COMPILED for the TPU unless the caller passes
+`interpret=True` (the CPU tests do); nothing here looks at the backend.
+`tests/test_aot_tpu_compile.py` lowers every variant for a described
+v5e chip at serving widths, so a block shape Mosaic refuses fails
+tier-1 instead of the first chip run.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.pallas_compat import compiler_params as _compiler_params
-
 _NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ----------------------------------------------------------------------
@@ -111,8 +106,10 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
     def row_map(b, *_refs):
         return (b, 0, 0)
 
-    def srow_map(b, *_refs):
-        return (b, 0)
+    # per-row scales ride as [B, 1, KV]: Mosaic wants a block's last
+    # two dims tile-aligned or equal to the array's, and (1, KV) of a
+    # [B, 1, KV] array is the latter where (KV,) of [B, KV] is neither
+    srow_map = row_map
 
     if quantized:
         def kernel(layer_ref, tables_ref, pos_ref, kp_ref, vp_ref,
@@ -146,8 +143,8 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
                 pl.BlockSpec((None, None, BS, KV), scale_map),
                 pl.BlockSpec((None, KV, HD), row_map),
                 pl.BlockSpec((None, KV, HD), row_map),
-                pl.BlockSpec((None, KV), srow_map),
-                pl.BlockSpec((None, KV), srow_map),
+                pl.BlockSpec((None, 1, KV), srow_map),
+                pl.BlockSpec((None, 1, KV), srow_map),
             ],
             out_specs=[
                 pl.BlockSpec((None, None, BS, KV, HD), pool_map),
@@ -204,7 +201,7 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # two idle rows can share the scratch tail block: the grid
             # must stay sequential so their copy-through writes don't race
             dimension_semantics=("arbitrary",),
@@ -215,7 +212,7 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
 
 def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
                     k_scale=None, v_scale=None, k_new_scale=None,
-                    v_new_scale=None, interpret: Optional[bool] = None):
+                    v_new_scale=None, interpret: bool = False):
     """Write each row's new KV into its tail pool block, in place.
 
     k_pool/v_pool [L, NB, BS, KV, hd]; k_new/v_new [B, KV, hd] (pool
@@ -227,8 +224,6 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
     L, NB, BS, KV, HD = k_pool.shape
     B, W = tables.shape
     quantized = k_scale is not None
-    if interpret is None:
-        interpret = _interpret()
     fn = _build_append(L, NB, BS, KV, HD, B, W,
                        jnp.dtype(k_pool.dtype).name,
                        jnp.dtype(k_new.dtype).name, quantized,
@@ -236,7 +231,9 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if quantized:
         return tuple(fn(layer, tables, pos, k_pool, v_pool, k_scale,
-                        v_scale, k_new, v_new, k_new_scale, v_new_scale))
+                        v_scale, k_new, v_new,
+                        k_new_scale.reshape(B, 1, KV),
+                        v_new_scale.reshape(B, 1, KV)))
     return tuple(fn(layer, tables, pos, k_pool, v_pool, k_new, v_new))
 
 
@@ -355,7 +352,7 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, HD), q_dt),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # rows are independent; the block walk carries the online
             # softmax scratch and must stay sequential
             dimension_semantics=("parallel", "arbitrary"),
@@ -366,7 +363,7 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
 
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
                            k_scale=None, v_scale=None,
-                           interpret: Optional[bool] = None):
+                           interpret: bool = False):
     """One step of decode attention straight off the paged pool.
 
     q [B, H, hd] (post-RoPE, current positions); k_pool/v_pool
@@ -380,8 +377,6 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
     B, W = tables.shape
     H = q.shape[1]
     quantized = k_scale is not None
-    if interpret is None:
-        interpret = _interpret()
     fn = _build_attention(L, NB, BS, KV, HD, B, W, H,
                           jnp.dtype(k_pool.dtype).name,
                           jnp.dtype(q.dtype).name, quantized,

@@ -33,19 +33,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.pallas_compat import compiler_params as _compiler_params
-
 _NEG = -1e30
 
 
 def _pad_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
-
-
-def _interpret() -> bool:
-    # CPU (tests) runs the kernels in interpreter mode, same switch as
-    # ops/attention.py
-    return jax.default_backend() != "tpu"
 
 
 def _fwd_kernel(x_ref, w_ref, tg_ref, lse_ref, tgt_ref,
@@ -158,9 +150,9 @@ def _prep(x, w, targets, block_n, block_v):
     return xc, wc, tg.reshape(-1, 1), N, V, Np, Vp
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def pallas_cross_entropy(x, w, targets, block_n: int = 512,
-                         block_v: int = 512):
+                         block_v: int = 512, interpret: bool = False):
     """Mean softmax cross-entropy of rows of `x` against classes of
     `w`, never materializing [N, V] logits in HBM.
 
@@ -168,13 +160,14 @@ def pallas_cross_entropy(x, w, targets, block_n: int = 512,
     int32.  Returns scalar f32 mean loss.  Gradients flow to x and w.
     Default blocks fit double-buffered VMEM for f32 inputs at E<=1024;
     block_v=1024 is ~96 KB over the 16 MB scoped-vmem limit with f32
-    blocks (and measured no faster with bf16 ones).
+    blocks (and measured no faster with bf16 ones).  Compiled for the
+    TPU unless the caller passes `interpret=True` (the CPU tests).
     """
-    loss, _ = _fwd(x, w, targets, block_n, block_v)
+    loss, _ = _fwd(x, w, targets, block_n, block_v, interpret)
     return loss
 
 
-def _lse_tgt(x, w, targets, block_n, block_v):
+def _lse_tgt(x, w, targets, block_n, block_v, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -202,23 +195,23 @@ def _lse_tgt(x, w, targets, block_n, block_v):
             pltpu.VMEM((block_n, 1), jnp.float32),
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=interpret,
     )(xc, wc, tg2)
     return lse, tgt, (xc, wc, tg2, N, V, Np, Vp)
 
 
-def _fwd(x, w, targets, block_n, block_v):
+def _fwd(x, w, targets, block_n, block_v, interpret):
     lse, tgt, (xc, wc, tg2, N, V, Np, Vp) = _lse_tgt(
-        x, w, targets, block_n, block_v
+        x, w, targets, block_n, block_v, interpret
     )
     loss = jnp.mean(lse[:N, 0] - tgt[:N, 0])
     return loss, (x, w, targets, lse)
 
 
-def _bwd(block_n, block_v, res, g):
+def _bwd(block_n, block_v, interpret, res, g):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -239,10 +232,10 @@ def _bwd(block_n, block_v, res, g):
         out_specs=pl.BlockSpec((block_n, E), lambda n, v: (n, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, E), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_n, E), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=interpret,
     )(xc, wc, tg2, lse)
 
     dw = pl.pallas_call(
@@ -258,10 +251,10 @@ def _bwd(block_n, block_v, res, g):
         out_specs=pl.BlockSpec((block_v, E), lambda v, n: (v, 0)),
         out_shape=jax.ShapeDtypeStruct((Vp, E), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_v, E), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=interpret,
     )(wc, xc, tg2, lse)
 
     dx = (dx[:N] * scale).astype(x.dtype)

@@ -36,7 +36,17 @@ def select_attention(kind: str, q, k, v, mesh=None, causal: bool = True):
     if kind == "flash":
         from ray_tpu.ops import flash_attention
 
-        return flash_attention(q, k, v, causal)
+        if mesh is None:
+            return flash_attention(q, k, v, causal)
+        # the kernel is a custom call the SPMD partitioner cannot
+        # split, so place it per shard: batch over the data axes, heads
+        # over tp, every device running the kernel on its own block
+        spec = P(("dp", "fsdp"), None, "tp", None)
+        return shard_map(
+            lambda q, k, v: flash_attention(q, k, v, causal),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_rep=False,
+        )(q, k, v)
     if kind == "ring" and mesh is not None:
         return ring_attention(q, k, v, mesh, causal=causal)
     if kind == "ulysses" and mesh is not None:

@@ -25,13 +25,16 @@ from typing import Any, Dict, Optional
 
 
 def _ensure_cpu_gang_env(gang_devices: int) -> None:
-    """The pjit gang needs >= gang_devices visible XLA devices; on CPU
-    that is ``--xla_force_host_platform_device_count``, which only
-    takes effect BEFORE jax initializes.  A no-op when jax is already
-    up (make_data_mesh then raises a helpful error if short)."""
+    """This bench ASKS for the CPU: its learner gang is a pjit over
+    virtual host devices in the driver process, and a driver that came
+    up on a chip would hold it against every worker.  The pjit gang
+    needs >= gang_devices visible XLA devices; on CPU that is
+    ``--xla_force_host_platform_device_count``, which only takes
+    effect BEFORE jax initializes.  A no-op when jax is already up
+    (make_data_mesh then raises a helpful error if short)."""
     if "jax" in sys.modules:
         return
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

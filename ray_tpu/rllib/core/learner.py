@@ -161,9 +161,8 @@ class _RemoteLearner:
     def __init__(self, module: RLModule, loss_fn: Callable, lr: float,
                  grad_clip: Optional[float], seed: int, world_size: int,
                  rank: int, group_name: str):
-        import os
-
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # the platform is the worker's lease's business
+        # (`core/env_utils.py`): CPU unless this actor was given a chip
         from ray_tpu.parallel import collectives
 
         self._learner = Learner(module, loss_fn, lr, grad_clip, seed=seed)

@@ -742,7 +742,8 @@ def measure_engine_trace(*, requests: int = 24, n_new: int = 8,
 
 
 def measure_decode_kernel(*, batches=(16, 32, 64), n_new: int = 8,
-                          seed: int = 0) -> Dict[str, Dict[str, float]]:
+                          seed: int = 0, kernel_interpret: bool = False,
+                          ) -> Dict[str, Dict[str, float]]:
     """Bare-decode rows for the fused paged-attention kernel
     (`ops/paged_attention.py`) vs the gather+`decode_step_vec`
     reference route, plus the int8 pool-occupancy row.
@@ -755,10 +756,10 @@ def measure_decode_kernel(*, batches=(16, 32, 64), n_new: int = 8,
       pool at the SAME block budget — the int8 row must sit at half,
       with the f32 scale sidecar priced separately.
 
-    Off-TPU the kernel runs in Pallas interpret mode, so CPU tok/s
-    compares an interpreter against compiled XLA — the rows are
-    structural evidence (kernel dispatched, gather plane dead), not a
-    speed claim.  On TPU the same rows are the perf claim.
+    The kernel rows are compiled for the chip unless the caller asks
+    for `kernel_interpret` (`--kernel-interpret`, the CPU structural
+    check): interpreter tok/s against compiled XLA is evidence that
+    the kernel dispatched and the gather plane is dead, never a speed.
     """
     import jax
 
@@ -779,7 +780,8 @@ def measure_decode_kernel(*, batches=(16, 32, 64), n_new: int = 8,
         for mode in ("pallas", "gather"):
             eng = LlamaEngine(cfg, params, slots=b, chunk=4,
                               block_size=bs, max_len=plen + n_new + 2,
-                              prefix_cache=False, decode_kernel=mode)
+                              prefix_cache=False, decode_kernel=mode,
+                              kernel_interpret=kernel_interpret)
             name = f"decode_b{b}_{mode}"
             try:
                 _engine_run(eng, prompts[: max(1, b // 4)], n_new)
@@ -789,7 +791,7 @@ def measure_decode_kernel(*, batches=(16, 32, 64), n_new: int = 8,
                 out[name]["kernel_ticks"] = (
                     s["decode_kernel_dispatch_total"])
                 out[name]["fallback_ticks"] = (
-                    s["decode_fallback_dispatch_total"])
+                    s["decode_gather_dispatch_total"])
             finally:
                 eng.shutdown()
             print(f"decode[{name}]: " + ", ".join(
@@ -1616,6 +1618,9 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Dict[str, float]]:
                         "batch sizes + int8 vs bf16 pool occupancy)")
     p.add_argument("--decode-batches", default="16,32,64",
                    help="decode_kernel: comma-separated batch sizes")
+    p.add_argument("--kernel-interpret", action="store_true",
+                   help="decode_kernel: run the Pallas rows in the "
+                        "interpreter (CPU structural check only)")
     p.add_argument("--dag-calls-n", type=int, default=2000,
                    help="dag_calls: round trips per plane")
     p.add_argument("--dag-tensor-mb", type=float, default=4.0,
@@ -1741,7 +1746,8 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Dict[str, float]]:
         batches = tuple(
             int(x) for x in str(args.decode_batches).split(",") if x
         )
-        results = measure_decode_kernel(batches=batches)
+        results = measure_decode_kernel(
+            batches=batches, kernel_interpret=args.kernel_interpret)
         if args.json:
             with open(args.json, "w") as f:
                 json.dump(results, f, indent=2)

@@ -80,7 +80,7 @@ class Deployment:
                 setattr(cfg, k, v)
             else:
                 raise TypeError(f"unknown deployment option {k!r}")
-        return Deployment(
+        return type(self)(
             self.func_or_class, name, cfg,
             dict(resources) if resources else dict(self.resources),
         )

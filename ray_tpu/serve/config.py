@@ -96,8 +96,8 @@ class LLMEngineConfig:
     The decode/quantization plane:
     - `decode_kernel`: "auto" (fused Pallas paged-attention kernel on
       TPU, compiled gather+`decode_step_vec` elsewhere), "pallas"
-      (force the kernel; interpret mode off-TPU), or "gather" (force
-      the reference route).
+      (the kernel, compiled: an engine that cannot compile it fails to
+      start), or "gather" (the reference route).
     - `kv_dtype`: "model" stores KV in the compute dtype; "int8"
       stores per-row-scaled int8 (half the pool HBM, f32 scale
       sidecar, dequant fused in the kernel / applied on gather).

@@ -42,19 +42,65 @@ KIND_OVERFLOW_MARKER = 0x7FFFFFFF
 _build_lock = threading.Lock()
 
 
-def _ensure_built() -> str:
-    with _build_lock:
-        if (not os.path.exists(_LIB)) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-            tmp = _LIB + f".tmp.{os.getpid()}"
-            # one-time native build at first touch, cached on mtime;
-            # any caller (sync or async) accepts the startup hit
-            subprocess.run(  # rtlint: disable=RT009
-                ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC, "-lpthread", "-lrt"],
-                check=True,
-                capture_output=True,
+def _fresh(lib: str) -> bool:
+    return os.path.exists(lib) and os.path.getmtime(lib) >= os.path.getmtime(_SRC)
+
+
+def _build(lib: str) -> str:
+    """Compile `_SRC` into `lib` unless a fresh copy is already there.
+    The library is not committed (`*.so` is ignored), so EVERY process
+    of a clean checkout's first session lands here at once: an flock
+    beside the target lets one of them compile while the rest wait and
+    then load its result."""
+    import fcntl
+
+    os.makedirs(os.path.dirname(lib), exist_ok=True)
+    # (one-time native build at first touch: see the g++ call below)
+    with open(lib + ".lock", "w") as lock:  # rtlint: disable=RT009
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _fresh(lib):
+            return lib
+        tmp = f"{lib}.tmp.{os.getpid()}"
+        # one-time native build at first touch, cached on mtime;
+        # any caller (sync or async) accepts the startup hit
+        proc = subprocess.run(  # rtlint: disable=RT009
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC, "-lpthread", "-lrt"],
+            capture_output=True, text=True,
+        )
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"building {os.path.basename(lib)} failed "
+                f"(g++ exit {proc.returncode}): {proc.stderr[-2000:]}"
             )
-            os.replace(tmp, _LIB)
-    return _LIB
+        os.replace(tmp, lib)
+    return lib
+
+
+def _ensure_built() -> str:
+    """Path of a library built from this checkout's source: beside the
+    source when the checkout is writable, else under RT_TMPDIR keyed by
+    the source's hash (a read-only install must still come up)."""
+    with _build_lock:
+        if _fresh(_LIB):
+            return _LIB
+        try:
+            return _build(_LIB)
+        except OSError as e:  # read-only checkout (or g++ missing)
+            if isinstance(e, FileNotFoundError) and e.filename == "g++":
+                raise RuntimeError(
+                    "the shm object store needs g++ to build "
+                    f"{_SRC} on first use"
+                ) from e
+            import hashlib
+
+            with open(_SRC, "rb") as f:  # rtlint: disable=RT009
+                digest = hashlib.sha1(f.read()).hexdigest()[:12]
+            alt = os.path.join(
+                os.environ.get("RT_TMPDIR", "/tmp/ray_tpu"), "native",
+                f"libshmstore-{digest}.so",
+            )
+            logger.info("cannot build into %s (%s); using %s", _HERE, e, alt)
+            return alt if os.path.exists(alt) else _build(alt)
 
 
 _lib = None

@@ -1,0 +1,233 @@
+"""The chip's compiler, without the chip: every Pallas kernel on the
+train and serve paths is lowered by Mosaic for a DESCRIBED v5e device
+at the widths `chip_smoke.py` runs (GPT-2 124M, llama1b4) — the
+`on-chip-measurement` guide's third rehearsal, kept as tier-1 tests.
+
+Interpret mode cannot see what this does: a block shape the TPU tiling
+refuses, too much VMEM, an operand that cannot alias.  (The int8 append
+kernel passed every interpret test and was refused here until its
+per-row scale operands became `[B, 1, KV]` blocks.)  Nothing runs, so
+there are no results or times to check — a compile that passes is not
+a chip run.
+
+The file sorts first on purpose: the suite has hit its time limit
+before, and a test the clock never reaches guards nothing.  Skipped
+only where the topology cannot be described (no libtpu).
+"""
+
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.ops import flash_attention  # noqa: E402
+from ray_tpu.ops import paged_attention as pa  # noqa: E402
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One device of a described `v5e:2x2` slice, as a sharding to hang
+    on argument shapes.  The compile cache is off around the module: a
+    TPU executable written here could not be read back without a chip,
+    and the next run would warn instead of staying silent."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # libtpu lets ONE process load it (a lock file in /tmp) because a
+    # chip has one owner.  Describing a topology owns no chip, and
+    # several pytest processes may do it at once (xdist workers, a
+    # second session): say so for the load, then take the word back so
+    # no worker spawned later inherits it
+    had = os.environ.get("ALLOW_MULTIPLE_LIBTPU_LOAD")
+    os.environ["ALLOW_MULTIPLE_LIBTPU_LOAD"] = "1"
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # rtlint: disable=RT005 — the skip reason
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    finally:
+        if had is None:
+            del os.environ["ALLOW_MULTIPLE_LIBTPU_LOAD"]
+        else:
+            os.environ["ALLOW_MULTIPLE_LIBTPU_LOAD"] = had
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(chip, fn, *args, **jit_kw):
+    """Lower `fn` for the described chip from argument SHAPES (there is
+    no device to hold an array) and compile it; returns the HLO text."""
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        args,
+    )
+    return jax.jit(fn, **jit_kw).lower(*shapes).compile().as_text()
+
+
+def _s(*shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def llama1b4() -> llama.LlamaConfig:
+    from ray_tpu.examples.serve_llm import _model_config
+
+    return _model_config("llama1b4")
+
+
+def _bf16_params(cfg):
+    """Parameter shapes as the serve path holds them (bf16)."""
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    return jax.tree.map(lambda p: _s(*p.shape), shapes)
+
+
+# ----------------------------------------------------------------------
+# train path: flash attention forward + backward
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("B,T,H,D,block", [
+    # GPT-2 124M at the smoke's batch: single tile -> FUSED backward
+    (16, 1024, 12, 64, 1024),
+    # llama1b4 LoRA bench shape: single tile, D=128
+    (8, 1024, 16, 128, 1024),
+    # long sequences: multi-block forward + SPLIT dq / dkv backward
+    (1, 4096, 12, 64, 1024),
+    (1, 2048, 16, 128, 512),
+], ids=["gpt2-fused", "llama1b4-fused", "t4096-split", "t2048-d128-split"])
+def test_flash_attention_fwd_bwd(chip, B, T, H, D, block):
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, block, block)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = [_s(B, T, H, D)] * 3
+    hlo = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    # forward + fused backward, or forward + dq + dkv
+    assert hlo.count("tpu_custom_call") >= (2 if block == T else 3)
+
+
+# ----------------------------------------------------------------------
+# serve path: paged decode attention + in-place KV append
+# ----------------------------------------------------------------------
+# llama1b4's pool as ContinuousLlamaService sizes it (22 layers, block
+# 16, 32 slots), and Llama-3-8B's GQA head layout on the same pool
+_POOLS = {
+    "llama1b4": dict(L=22, NB=512, BS=16, KV=16, HD=128, B=32, W=11, H=16),
+    "llama3-8b-gqa": dict(L=22, NB=512, BS=16, KV=8, HD=128, B=32, W=11,
+                          H=32),
+}
+
+
+def _pool_args(L, NB, BS, KV, HD, B, W, H, int8):
+    pool = _s(L, NB, BS, KV, HD, dtype=jnp.int8 if int8 else BF16)
+    scale = _s(L, NB, BS, KV, dtype=jnp.float32) if int8 else None
+    tables, pos = _s(B, W, dtype=jnp.int32), _s(B, dtype=jnp.int32)
+    return pool, scale, tables, pos
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("heads", sorted(_POOLS))
+def test_paged_decode_attention(chip, heads, int8):
+    d = _POOLS[heads]
+    pool, scale, tables, pos = _pool_args(int8=int8, **d)
+    q = _s(d["B"], d["H"], d["HD"])
+
+    if int8:
+        def fn(q, kp, vp, ks, vs, tables, pos):
+            return pa.paged_decode_attention(
+                q, kp, vp, tables, pos, 3, k_scale=ks, v_scale=vs)
+
+        hlo = _compile(chip, fn, q, pool, pool, scale, scale, tables, pos)
+    else:
+        def fn(q, kp, vp, tables, pos):
+            return pa.paged_decode_attention(q, kp, vp, tables, pos, 3)
+
+        hlo = _compile(chip, fn, q, pool, pool, tables, pos)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_kv_append(chip, int8):
+    """The int8 row is the one Mosaic refused before this file existed
+    (`[B, KV]` per-row scale blocks: last two dims neither tile-aligned
+    nor the whole array)."""
+    d = _POOLS["llama1b4"]
+    pool, scale, tables, pos = _pool_args(int8=int8, **d)
+    row = _s(d["B"], d["KV"], d["HD"], dtype=pool.dtype)
+
+    if int8:
+        srow = _s(d["B"], d["KV"], dtype=jnp.float32)
+
+        def fn(kp, vp, ks, vs, kn, vn, kns, vns, tables, pos):
+            return pa.paged_kv_append(
+                kp, vp, kn, vn, tables, pos, 3, k_scale=ks, v_scale=vs,
+                k_new_scale=kns, v_new_scale=vns)
+
+        hlo = _compile(chip, fn, pool, pool, scale, scale, row, row,
+                       srow, srow, tables, pos,
+                       donate_argnums=(0, 1, 2, 3))
+    else:
+        def fn(kp, vp, kn, vn, tables, pos):
+            return pa.paged_kv_append(kp, vp, kn, vn, tables, pos, 3)
+
+        hlo = _compile(chip, fn, pool, pool, row, row, tables, pos,
+                       donate_argnums=(0, 1))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_llama1b4_decode_step_paged(chip, int8):
+    """The engine's whole decode step at serving size: both kernels
+    inside the layer scan, pools donated."""
+    cfg = llama1b4()
+    d = _POOLS["llama1b4"]
+    pool, scale, tables, pos = _pool_args(int8=int8, **d)
+    tok = _s(d["B"], dtype=jnp.int32)
+
+    if int8:
+        def fn(params, tok, kp, vp, ks, vs, tables, pos):
+            return llama.decode_step_paged(
+                cfg, params, tok, kp, vp, tables, pos, kv_scales=(ks, vs))
+
+        hlo = _compile(chip, fn, _bf16_params(cfg), tok, pool, pool,
+                       scale, scale, tables, pos,
+                       donate_argnums=(2, 3, 4, 5))
+    else:
+        def fn(params, tok, kp, vp, tables, pos):
+            return llama.decode_step_paged(
+                cfg, params, tok, kp, vp, tables, pos)
+
+        hlo = _compile(chip, fn, _bf16_params(cfg), tok, pool, pool,
+                       tables, pos, donate_argnums=(2, 3))
+    assert hlo.count("tpu_custom_call") >= 2  # append + attention
+
+
+# ----------------------------------------------------------------------
+# the @serve.batch path (LlamaService -> llama.generate): no kernels,
+# but the two programs it is made of must fit and compile at size
+# ----------------------------------------------------------------------
+def test_llama1b4_prefill_and_decode_step(chip):
+    cfg = llama1b4()
+    B, T, M = 8, 128, 160
+    params = _bf16_params(cfg)
+    _compile(chip, lambda p, t: llama.prefill(cfg, p, t, M), params,
+             _s(B, T, dtype=jnp.int32))
+    cache = _s(cfg.n_layers, B, M, cfg.n_kv_heads, cfg.head_dim)
+    _compile(
+        chip,
+        lambda p, tok, kc, vc, pos: llama.decode_step(
+            cfg, p, tok, (kc, vc), pos),
+        params, _s(B, dtype=jnp.int32), cache, cache, _s(dtype=jnp.int32),
+        donate_argnums=(2, 3),
+    )

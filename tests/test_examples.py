@@ -33,7 +33,7 @@ def test_serve_llm_example(cluster):
     from ray_tpu import serve
     from ray_tpu.examples.serve_llm import run
 
-    handle = run(model_size="tiny", max_new_tokens=5)
+    handle = run(model_size="tiny", max_new_tokens=5, jax_platform="cpu")
     try:
         prompts = [[1, 2, 3, 4], [9, 8, 7, 6]]
         out = handle.generate.remote(prompts).result(timeout_s=120)

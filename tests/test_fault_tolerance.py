@@ -238,7 +238,7 @@ def test_deadline_expired_predicate():
 
 
 # ----------------------------------------------------------------------
-# exception taxonomy
+# exception hierarchy
 # ----------------------------------------------------------------------
 def test_deadline_error_is_a_get_timeout_error():
     """Existing `except GetTimeoutError` call sites keep working."""

@@ -9,15 +9,6 @@ import pytest
 from ray_tpu.ops import flash_attention
 from ray_tpu.parallel.moe import MoEConfig, init_moe, moe_forward
 from ray_tpu.parallel.ring_attention import plain_attention
-from ray_tpu.testing import pallas_kernel_support
-
-_pallas_ok, _pallas_why = pallas_kernel_support("attention")
-# the MoE tests below need no Pallas — guard only the kernel tests
-requires_pallas = pytest.mark.skipif(
-    not _pallas_ok,
-    reason=f"Pallas flash-attention kernels unavailable in this "
-           f"JAX/Pallas environment: {_pallas_why}",
-)
 
 
 def _qkv(B=2, T=64, H=4, D=16, seed=0, dtype=jnp.float32):
@@ -26,18 +17,16 @@ def _qkv(B=2, T=64, H=4, D=16, seed=0, dtype=jnp.float32):
     return tuple(jax.random.normal(k, shape, dtype) * 0.3 for k in ks)
 
 
-@requires_pallas
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_matches_plain(causal):
     q, k, v = _qkv()
     ref = plain_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal, 32, 32, True)  # force pallas
+    out = flash_attention(q, k, v, causal, 32, 32, True)  # interpret mode
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4
     )
 
 
-@requires_pallas
 def test_flash_attention_grad_matches_plain():
     q, k, v = _qkv(T=32)
 
@@ -55,7 +44,6 @@ def test_flash_attention_grad_matches_plain():
         )
 
 
-@requires_pallas
 @pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16)])
 def test_flash_attention_grad_rect_blocks(bq, bk):
     """Rectangular blocks exercise the causal block-skip predicates and
@@ -76,7 +64,6 @@ def test_flash_attention_grad_rect_blocks(bq, bk):
         )
 
 
-@requires_pallas
 def test_flash_attention_bf16():
     q, k, v = _qkv(T=64, dtype=jnp.bfloat16)
     out = flash_attention(q, k, v, True, 32, 32, True)
@@ -88,11 +75,47 @@ def test_flash_attention_bf16():
     )
 
 
-def test_flash_attention_fallback_on_odd_shapes():
-    q, k, v = _qkv(T=60, D=12)  # not divisible: falls back to XLA path
-    out = flash_attention(q, k, v)
-    ref = plain_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
+def test_flash_attention_refuses_odd_shapes():
+    """A shape the kernels cannot tile is an error naming the shape —
+    never a quiet switch to the XLA path."""
+    q, k, v = _qkv(T=60, D=12)  # D not a multiple of 8
+    with pytest.raises(ValueError, match=r"\(2, 60, 4, 12\)"):
+        flash_attention(q, k, v, True, 32, 32, True)
+    q, k, v = _qkv(T=96, D=16)  # T not a multiple of the blocks
+    with pytest.raises(ValueError, match=r"\(2, 96, 4, 16\)"):
+        jax.grad(lambda q: flash_attention(q, k, v, True, 64, 64, True).sum())(q)
+
+
+def test_flash_attention_under_a_mesh_runs_per_shard(monkeypatch):
+    """`select_attention("flash", mesh=...)`: the kernel is a custom
+    call the SPMD partitioner cannot split, so it is placed per shard
+    (batch over the data axes, heads over tp) with shard_map — same
+    values and gradients as dense attention on the whole arrays.  The
+    test steers the kernel into interpret mode; the program's own call
+    is the compiled one (`tests/test_aot_tpu_compile.py`)."""
+    import ray_tpu.ops as ops
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.parallel.ring_attention import select_attention
+
+    real = ops.flash_attention
+    monkeypatch.setattr(
+        ops, "flash_attention",
+        lambda q, k, v, causal: real(q, k, v, causal, 32, 32, True))
+    mesh = MeshSpec(fsdp=2, tp=2).build(jax.devices()[:4])
+    q, k, v = _qkv(B=4, T=32, H=4, D=16)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) ** 2)
+
+    flash = loss(lambda q, k, v: select_attention("flash", q, k, v, mesh))
+    dense = loss(lambda q, k, v: plain_attention(q, k, v, causal=True))
+    with mesh:
+        got, g_got = jax.jit(jax.value_and_grad(flash, (0, 1, 2)))(q, k, v)
+    want, g_want = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
 
 
 def test_fused_cross_entropy_matches_direct():
@@ -172,7 +195,6 @@ def test_moe_expert_parallel_matches_local():
     )
 
 
-@requires_pallas
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_grad_fused_single_tile(causal):
     """blocks == T dispatches the FUSED single-tile backward (one

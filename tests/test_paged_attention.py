@@ -5,9 +5,9 @@ attending straight into the `BlockPool` tensor must reproduce the
 gather+`decode_step_vec` reference route — dense-reference numerics at
 fp32/bf16 across ragged block tables and partial last blocks, greedy
 engine outputs BIT-IDENTICAL kernel on vs off, and the int8 KV/weight
-planes gated on argmax-match plus bounded logit error.  Everything
-rides the `pallas_kernel_support("paged")` probe so an environment
-without a workable Pallas surface skips instead of failing tier-1
+planes gated on argmax-match plus bounded logit error.  Interpret mode
+is this file's explicit choice (`interpret=True` / `kernel_interpret=
+True`): the program's default is the compiled kernel
 (RT008: all RNGs seeded).
 """
 
@@ -21,12 +21,6 @@ from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.ops import paged_attention as pa  # noqa: E402
 from ray_tpu.serve.config import LLMEngineConfig  # noqa: E402
 from ray_tpu.serve.llm_engine import LlamaEngine  # noqa: E402
-from ray_tpu.testing import pallas_kernel_support  # noqa: E402
-
-_ok, _why = pallas_kernel_support("paged")
-pytestmark = pytest.mark.skipif(
-    not _ok, reason=f"paged Pallas kernels unsupported here: {_why}"
-)
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +89,8 @@ def test_kernel_matches_dense_reference_ragged(dtype, tol):
     kp = jnp.asarray(_scatter_pool(np.asarray(kd), tables, NB, BS))
     vp = jnp.asarray(_scatter_pool(np.asarray(vd), tables, NB, BS))
     out = pa.paged_decode_attention(
-        qd, kp, vp, jnp.asarray(tables), jnp.asarray(pos), 0
+        qd, kp, vp, jnp.asarray(tables), jnp.asarray(pos), 0,
+        interpret=True,
     )
     assert out.dtype == dtype and out.shape == (B, H, hd)
     ref = _dense_reference(np.asarray(qd, np.float32),
@@ -120,7 +115,7 @@ def test_append_writes_one_row_and_preserves_rest():
     v_new = rng.standard_normal((B, KV, hd)).astype(np.float32)
     kp, vp = pa.paged_kv_append(
         jnp.asarray(kp0), jnp.asarray(vp0), jnp.asarray(k_new),
-        jnp.asarray(v_new), tables, pos, 0
+        jnp.asarray(v_new), tables, pos, 0, interpret=True
     )
     ek, ev = kp0.copy(), vp0.copy()
     ek[0, 1, 0], ev[0, 1, 0] = k_new[0], v_new[0]  # pos 0 -> blk 1 slot 0
@@ -173,7 +168,7 @@ def test_decode_step_paged_matches_decode_step_vec(model):
                 vc[:, b, w * BS:(w + 1) * BS])
     l_paged, _, _ = llama.decode_step_paged(
         cfg, params, tok, jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), pos
+        jnp.asarray(tables), pos, interpret=True
     )
     np.testing.assert_allclose(np.asarray(l_paged), np.asarray(l_ref),
                                rtol=2e-2, atol=2e-2)
@@ -183,7 +178,7 @@ def test_decode_step_paged_matches_decode_step_vec(model):
 
 def _run_engine(cfg, params, prompts, n_new, **kw):
     eng = LlamaEngine(cfg, params, slots=4, chunk=4, block_size=8,
-                      max_len=64, **kw)
+                      max_len=64, kernel_interpret=True, **kw)
     try:
         outs = [f.result(timeout=120) for f in
                 [eng.submit(p, n) for p, n in zip(prompts, n_new)]]
@@ -229,13 +224,30 @@ def test_engine_greedy_bit_identical_kernel_on_off(model, workload,
     assert on == off
     assert s_on["decode_kernel"] == "pallas"
     assert s_on["decode_kernel_dispatch_total"] > 0
-    assert s_on["decode_fallback_dispatch_total"] == 0
+    assert s_on["decode_gather_dispatch_total"] == 0
     assert s_off["decode_kernel"] == "gather"
     assert s_off["decode_kernel_dispatch_total"] == 0
-    assert s_off["decode_fallback_dispatch_total"] > 0
+    assert s_off["decode_gather_dispatch_total"] > 0
     # and both routes match the dedicated-generate oracle
     for p, n, got in zip(prompts, n_new, on):
         assert got == _expected(cfg, params, p, n)
+
+
+def test_engine_kernel_route_compiles_or_fails_to_start(model):
+    """`decode_kernel="pallas"` means the COMPILED kernel: the engine
+    runs a warm-up chunk through it in `__init__`, so where it cannot
+    compile (here: a CPU backend, no interpret asked) the engine fails
+    to start — it never serves through the gather route instead, and
+    no engine thread is left behind."""
+    import threading
+
+    cfg, params = model
+    before = {t.name for t in threading.enumerate()}
+    with pytest.raises(Exception, match="(?i)interpret|cpu|pallas|mosaic"):
+        LlamaEngine(cfg, params, slots=2, chunk=2, block_size=8,
+                    max_len=32, decode_kernel="pallas")
+    assert "llm-engine" not in (
+        {t.name for t in threading.enumerate()} - before)
 
 
 def test_engine_eviction_churned_pool_kernel_on(model):
@@ -248,7 +260,7 @@ def test_engine_eviction_churned_pool_kernel_on(model):
                for _ in range(8)]
     eng = LlamaEngine(cfg, params, slots=2, chunk=2, block_size=8,
                       max_len=32, kv_blocks=10, prefix_cache=False,
-                      decode_kernel="pallas")
+                      decode_kernel="pallas", kernel_interpret=True)
     try:
         futs = [eng.submit(p, 6) for p in prompts]
         outs = [f.result(timeout=120) for f in futs]
@@ -273,7 +285,7 @@ def test_engine_int8_kv_pallas_equals_gather(model, workload):
     assert q_on == q_off
     assert s_on["kv_dtype"] == "int8"
     assert s_on["decode_kernel_dispatch_total"] > 0
-    assert s_off["decode_fallback_dispatch_total"] > 0
+    assert s_off["decode_gather_dispatch_total"] > 0
     # documented tolerance: >= 70% of requests reproduce the fp greedy
     # tokens end-to-end (int8 KV error can flip a near-tie argmax)
     matches = sum(

@@ -91,17 +91,10 @@ def test_decode_kernel_rows():
       equal batch);
     - the int8 pool sits at exactly half the bf16 payload bytes at
       the same block budget, scale sidecar priced separately."""
-    import pytest as _pytest
-
-    from ray_tpu.testing import pallas_kernel_support
-
-    ok, why = pallas_kernel_support("paged")
-    if not ok:
-        _pytest.skip(f"paged Pallas kernels unsupported here: {why}")
     from ray_tpu.scripts.perf import main
 
     results = main(["--config", "decode_kernel",
-                    "--decode-batches", "4"])
+                    "--decode-batches", "4", "--kernel-interpret"])
     pal, gat = results["decode_b4_pallas"], results["decode_b4_gather"]
     assert pal["decode_kernel"] == "pallas"
     assert pal["kernel_ticks"] > 0 and pal["fallback_ticks"] == 0

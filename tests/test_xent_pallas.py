@@ -11,14 +11,6 @@ from ray_tpu.ops.xent_pallas import (
     pallas_cross_entropy,
     reference_cross_entropy,
 )
-from ray_tpu.testing import pallas_kernel_support
-
-_pallas_ok, _pallas_why = pallas_kernel_support("xent")
-pytestmark = pytest.mark.skipif(
-    not _pallas_ok,
-    reason=f"Pallas xent kernel unavailable in this JAX/Pallas "
-           f"environment: {_pallas_why}",
-)
 
 
 @pytest.mark.parametrize("n,e,v,bn,bv", [
@@ -37,7 +29,7 @@ def test_loss_and_grads_match_reference(n, e, v, bn, bv):
         reference_cross_entropy, argnums=(0, 1)
     )(x, w, tg)
     loss, (dx, dw) = jax.value_and_grad(
-        lambda x_, w_: pallas_cross_entropy(x_, w_, tg, bn, bv),
+        lambda x_, w_: pallas_cross_entropy(x_, w_, tg, bn, bv, True),
         argnums=(0, 1),
     )(x, w)
 
@@ -56,11 +48,11 @@ def test_bf16_inputs():
     w = jax.random.normal(kw, (v, e), jnp.float32) * 0.1
     tg = jax.random.randint(kt, (n,), 0, v, jnp.int32)
     ref = reference_cross_entropy(x, w, tg)
-    got = pallas_cross_entropy(x, w, tg, 128, 128)
+    got = pallas_cross_entropy(x, w, tg, 128, 128, True)
     np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-2)
     # grads exist and are finite in the storage dtypes
     dx, dw = jax.grad(
-        lambda x_, w_: pallas_cross_entropy(x_, w_, tg, 128, 128),
+        lambda x_, w_: pallas_cross_entropy(x_, w_, tg, 128, 128, True),
         argnums=(0, 1),
     )(x, w)
     assert dx.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
