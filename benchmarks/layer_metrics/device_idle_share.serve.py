@@ -1,0 +1,12 @@
+"""1 - the union of the device's op intervals over the traced window,
+mean over the replicas' chips."""
+LAYER, UNIT, SOURCE, MOVES = "device", "%", "device_trace", "serve_tokens_per_s"
+
+
+def read(ctx):
+    from benchmarks.layer_metrics._common import mean, traces
+
+    if ctx.get("plane") != "serve":
+        return None
+    m = mean(t["idle_share"] for t in traces(ctx))
+    return None if m is None else 100.0 * m

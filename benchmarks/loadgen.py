@@ -1,0 +1,322 @@
+"""The one general traffic generator, and the client that offers it.
+
+A traffic mix is a data file (`benchmarks/traffic/<mix>.json`); this
+module turns it and `--seed` into requests and sends them over HTTP
+from one thread.  The work is fixed by the mix, not by the seed: the
+sizes and the gaps between arrivals, and their order, are drawn from
+the mix's own `mix_seed`; `--seed` fills in the token ids (and, in the
+planes, the weights).  With `"seed_reorders": true` in the mix the seed
+also shuffles sizes and gaps: the same work in another order.  That is
+off in the cells' mixes because the order decides which requests meet
+at the end of the window: reordering moved `request_p95_ms` by +-2.2%
+and the tokens completed in the window by +-2.4% from seed to seed,
+while two runs of one seed agree within 0.7% (chip, PR 24).
+
+Kinds:
+- `open_loop`: arrivals on a schedule at `rate_per_s` whether or not
+  earlier requests have finished; gaps are gamma-distributed with
+  coefficient of variation `arrival_cv` (1 = Poisson).  A request is
+  timed from the instant it was DUE, and how late it was sent is kept.
+- `closed_loop`: `clients` callers, each sending its next request when
+  the previous one returns.
+- `train_stream`: no requests; the train plane reads the batch shape
+  and token distribution from the same file.
+
+Lengths: `{"fixed": n}`, `{"choices": [...], "weights": [...]}`, or
+`{"dist": "lognormal", "median": m, "sigma": s}`; with a `grid`
+(`min`, `max`, `step`) a drawn length is rounded UP to the grid and
+clipped to it, so the engine sees a closed set of shapes.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import dataclasses
+import json
+import math
+import time
+from typing import List, Optional
+
+import numpy as np
+
+
+@dataclasses.dataclass
+class Request:
+    idx: int
+    due_s: float            # open loop: offset from the window's start
+    prompt: List[int]
+    n_out: int
+    client: int = -1        # closed loop: which caller sends it
+
+
+@dataclasses.dataclass
+class Record:
+    idx: int
+    due_s: float
+    sent_s: float = math.nan
+    done_s: float = math.nan
+    ok: bool = False
+    status: int = 0
+    prompt_len: int = 0
+    want: int = 0
+    got: int = 0
+    engine_s: float = math.nan   # measured by the deployment around submit
+    replica: str = ""
+    error: str = ""
+    cut: bool = False            # closed loop: still in flight at the end
+
+
+# ----------------------------------------------------------------------
+# sizes and schedules (pure: no clock, no I/O)
+# ----------------------------------------------------------------------
+def grid_values(grid: dict) -> List[int]:
+    return list(range(int(grid["min"]), int(grid["max"]) + 1,
+                      int(grid["step"])))
+
+
+def draw_lengths(spec: dict, n: int, rng: np.random.Generator) -> np.ndarray:
+    if "fixed" in spec:
+        out = np.full(n, int(spec["fixed"]), np.int64)
+    elif "choices" in spec:
+        w = np.asarray(spec.get("weights", [1] * len(spec["choices"])), float)
+        out = rng.choice(np.asarray(spec["choices"], np.int64), size=n,
+                         p=w / w.sum())
+    elif spec.get("dist") == "lognormal":
+        out = np.rint(np.exp(rng.normal(math.log(spec["median"]),
+                                        spec["sigma"], size=n))).astype(np.int64)
+    else:
+        raise ValueError(f"unknown length spec {spec!r}")
+    grid = spec.get("grid")
+    if grid:
+        step, lo, hi = int(grid["step"]), int(grid["min"]), int(grid["max"])
+        out = lo + -(-(np.maximum(out, lo) - lo) // step) * step  # round UP
+        out = np.minimum(out, hi)
+    return np.maximum(out, 1)
+
+
+def possible_lengths(spec: dict) -> List[int]:
+    """Every length a spec can produce — what set-up has to warm.  A
+    lognormal without a grid has no closed set and is refused."""
+    if "fixed" in spec:
+        return [int(spec["fixed"])]
+    if "choices" in spec:
+        return sorted(int(c) for c in spec["choices"])
+    if spec.get("grid"):
+        return grid_values(spec["grid"])
+    raise ValueError("a drawn length needs a `grid`: continuous lengths "
+                     "would compile inside the measured window")
+
+
+def _tokens(rng, n: int, vocab: int) -> List[int]:
+    return rng.integers(1, vocab, size=n).tolist()
+
+
+def _prompts(mix: dict, plens, rng, vocab: int) -> List[List[int]]:
+    """Unshared random prompts, or — with `shared_prefix` — `groups`
+    prefixes of `len` tokens that requests pick at random."""
+    sp = mix.get("shared_prefix") or {}
+    groups = [_tokens(rng, int(sp["len"]), vocab)
+              for _ in range(int(sp.get("groups", 0)))]
+    out = []
+    for n in plens:
+        n = int(n)
+        if groups:
+            g = groups[int(rng.integers(len(groups)))]
+            keep = min(len(g), max(0, n - int(sp.get("min_suffix", 16))))
+            out.append(g[:keep] + _tokens(rng, n - keep, vocab))
+        else:
+            out.append(_tokens(rng, n, vocab))
+    return out
+
+
+def open_loop_schedule(mix: dict, seconds: float, seed: int,
+                       vocab: int) -> List[Request]:
+    rate, cv = float(mix["rate_per_s"]), float(mix.get("arrival_cv", 1.0))
+    fixed = np.random.default_rng(int(mix.get("mix_seed", 0)))
+    shape = 1.0 / (cv * cv)
+    gaps = []
+    total = 0.0
+    while True:  # the mix's own draw: same gaps and sizes for every seed
+        g = float(fixed.gamma(shape, 1.0 / (rate * shape)))
+        if total + g > seconds:
+            break
+        total += g
+        gaps.append(g)
+    n = len(gaps)
+    plens = draw_lengths(mix["prompt_len"], n, fixed)
+    olens = draw_lengths(mix["output_len"], n, fixed)
+    rng = np.random.default_rng([int(seed), 0x5EED])
+    gaps = np.asarray(gaps)
+    if mix.get("seed_reorders"):
+        gaps = rng.permutation(gaps)
+        order = rng.permutation(n)
+        plens, olens = plens[order], olens[order]
+    due = np.cumsum(gaps)
+    prompts = _prompts(mix, plens, rng, vocab)
+    return [Request(i, float(due[i]), prompts[i], int(olens[i]))
+            for i in range(n)]
+
+
+def closed_loop_schedule(mix: dict, seed: int, vocab: int) -> List[List[Request]]:
+    """Per client, the requests it will send one after the other (more
+    than any window can use).  `first_output_step`: client i's FIRST
+    answer is `step * (1 + i mod (n_out // step))` tokens long, so the
+    callers do not finish in lockstep and completions are spread over
+    the window instead of arriving in waves of a whole batch."""
+    clients = int(mix["clients"])
+    per = int(mix.get("requests_per_client", 48))
+    fixed = np.random.default_rng(int(mix.get("mix_seed", 0)))
+    plens = draw_lengths(mix["prompt_len"], clients * per, fixed)
+    olens = draw_lengths(mix["output_len"], clients * per, fixed)
+    rng = np.random.default_rng([int(seed), 0x5EED])
+    if mix.get("seed_reorders"):
+        order = rng.permutation(clients * per)
+        plens, olens = plens[order], olens[order]
+    step = int(mix.get("first_output_step", 0))
+    prompts = _prompts(mix, plens, rng, vocab)
+    out, k = [], 0
+    for c in range(clients):
+        mine = []
+        for j in range(per):
+            n_out = int(olens[k])
+            if step and j == 0:
+                n_out = step * (1 + c % max(1, n_out // step))
+            mine.append(Request(k, 0.0, prompts[k], n_out, client=c))
+            k += 1
+        out.append(mine)
+    return out
+
+
+# ----------------------------------------------------------------------
+# the client (one thread, asyncio + aiohttp)
+# ----------------------------------------------------------------------
+async def _post(session, url: str, req: Request, rec: Record,
+                t0: float) -> None:
+    rec.prompt_len, rec.want = len(req.prompt), req.n_out
+    rec.sent_s = time.perf_counter() - t0
+    try:
+        async with session.post(url, data=json.dumps(
+                {"tokens": [req.prompt], "max_new_tokens": req.n_out})) as r:
+            rec.status = r.status
+            body = await r.read()
+        rec.done_s = time.perf_counter() - t0
+        if rec.status == 200:
+            out = json.loads(body)
+            rec.got = len(out["tokens"][0])
+            rec.engine_s = float(out.get("engine_s", math.nan))
+            rec.replica = str(out.get("replica", ""))
+            rec.ok = rec.got == req.n_out
+            if not rec.ok:
+                rec.error = f"asked {req.n_out} tokens, got {rec.got}"
+        else:
+            rec.error = body[:200].decode(errors="replace")
+    except asyncio.CancelledError:
+        rec.error = "not answered by the end of the drain"
+        rec.cut = True
+        raise
+    except Exception as e:  # a failed request is a result, not a crash
+        rec.done_s = time.perf_counter() - t0
+        rec.error = f"{type(e).__name__}: {e}"
+
+
+async def _open_loop(url: str, reqs: List[Request], seconds: float,
+                     drain_s: float) -> List[Record]:
+    import aiohttp
+
+    recs = [Record(r.idx, r.due_s) for r in reqs]
+    timeout = aiohttp.ClientTimeout(total=None)
+    conn = aiohttp.TCPConnector(limit=0)
+    async with aiohttp.ClientSession(timeout=timeout, connector=conn) as s:
+        t0 = time.perf_counter()
+        tasks = []
+        for req, rec in zip(reqs, recs):
+            wait = req.due_s - (time.perf_counter() - t0)
+            if wait > 0:
+                await asyncio.sleep(wait)
+            tasks.append(asyncio.ensure_future(_post(s, url, req, rec, t0)))
+        left = seconds + drain_s - (time.perf_counter() - t0)
+        if tasks:
+            _, pending = await asyncio.wait(tasks, timeout=max(left, 0.0))
+            for t in pending:
+                t.cancel()
+            await asyncio.gather(*pending, return_exceptions=True)
+    return recs
+
+
+async def _closed_loop(url: str, plans: List[List[Request]], seconds: float,
+                       drain_s: float) -> List[Record]:
+    import aiohttp
+
+    recs: List[Record] = []
+    timeout = aiohttp.ClientTimeout(total=None)
+    conn = aiohttp.TCPConnector(limit=0)
+    async with aiohttp.ClientSession(timeout=timeout, connector=conn) as s:
+        t0 = time.perf_counter()
+
+        async def client(plan):
+            for req in plan:
+                now = time.perf_counter() - t0
+                if now >= seconds:
+                    return
+                rec = Record(req.idx, now)
+                recs.append(rec)
+                await _post(s, url, req, rec, t0)
+
+        # a closed loop has no schedule to honour after the window: what
+        # is still in flight `drain_s` after its end is cut, not failed
+        tasks = [asyncio.ensure_future(client(p)) for p in plans]
+        _, pending = await asyncio.wait(tasks, timeout=seconds + drain_s)
+        for t in pending:
+            t.cancel()
+        await asyncio.gather(*pending, return_exceptions=True)
+    return recs
+
+
+def run_open_loop(url, reqs, seconds, drain_s) -> List[Record]:
+    return asyncio.run(_open_loop(url, reqs, seconds, drain_s))
+
+
+def run_closed_loop(url, plans, seconds, drain_s) -> List[Record]:
+    return asyncio.run(_closed_loop(url, plans, seconds, drain_s))
+
+
+# ----------------------------------------------------------------------
+# reduction of the client's records (the yardstick: kept here)
+# ----------------------------------------------------------------------
+def percentile(values, q: float) -> Optional[float]:
+    """Nearest-rank percentile; None on an empty sample."""
+    v = sorted(values)
+    if not v:
+        return None
+    return v[min(len(v) - 1, max(0, math.ceil(q / 100.0 * len(v)) - 1))]
+
+
+def summarize(recs: List[Record], seconds: float, miss_ms: float,
+              closed: bool = False) -> dict:
+    """`tokens_per_s`: output tokens of requests that completed inside
+    the window, over the window.  `latency_ms`: one value per request
+    SENT, from its due instant; a failed, short or unanswered request
+    counts `miss_ms` (worse than any answer)."""
+    cut = [r for r in recs if r.cut and closed]
+    recs = [r for r in recs if not (r.cut and closed)]
+    done_in = [r for r in recs if r.ok and r.done_s <= seconds]
+    lat = [(r.done_s - r.due_s) * 1e3 if r.ok else miss_ms for r in recs]
+    late = [(r.sent_s - r.due_s) * 1e3 for r in recs
+            if not math.isnan(r.sent_s)]
+    return {
+        "cut_at_end": len(cut),
+        "attempted": len(recs),
+        "failed": sum(not r.ok for r in recs),
+        "completed_in_window": len(done_in),
+        "tokens_per_s": sum(r.got for r in done_in) / seconds,
+        "latency_ms": lat,
+        "late_ms": late,
+        "plane_overhead_ms": [
+            (r.done_s - r.sent_s - r.engine_s) * 1e3 for r in recs
+            if r.ok and not math.isnan(r.engine_s)],
+        "per_replica": {
+            rid: sum(1 for r in recs if r.replica == rid)
+            for rid in sorted({r.replica for r in recs if r.replica})},
+        "unanswered_at_window_end": sum(
+            1 for r in recs if math.isnan(r.done_s) or r.done_s > seconds),
+    }

@@ -1,0 +1,256 @@
+"""The manifest against its contract, the files it names, and the
+traffic generator's promises."""
+
+import asyncio
+import json
+import math
+import os
+import re
+import shutil
+import threading
+
+import numpy as np
+import pytest
+
+from benchmarks import loadgen, manifest
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+M = manifest.manifest()
+CELLS = [c["name"] for c in M["workloads"]]
+
+
+def test_top_level_keys_and_sizes():
+    assert set(M) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    assert isinstance(M["run_seconds"], int) and 1 <= M["run_seconds"] <= 51
+    assert 1 <= len(M["configs"]) <= 24 and 1 <= len(M["workloads"]) <= 24
+    assert 1 <= len(M["end_to_end"]) <= 16 and 1 <= len(M["per_layer"]) <= 128
+    assert os.path.getsize(os.path.join(manifest.REPO, "BENCHMARK.json")) < 65536
+    for word in M["command"]:
+        assert 1 <= len(word) <= 200 and not word.startswith("/")
+    assert any(w.startswith(tuple(M["paths"])) for w in M["command"])
+
+
+@pytest.mark.parametrize("kind", ["configs", "workloads", "end_to_end",
+                                  "per_layer"])
+def test_names_units_and_keys(kind):
+    allowed = {
+        "configs": {"name", "source", "file", "reduced", "why"},
+        "workloads": {"name", "config", "traffic", "chips", "why"},
+        "end_to_end": {"name", "unit", "better", "bound", "source",
+                       "workloads"},
+        "per_layer": {"name", "unit", "better", "source", "layer", "moves",
+                      "workloads"},
+    }[kind]
+    names = [e["name"] for e in M[kind]]
+    assert len(names) == len(set(names))
+    for e in M[kind]:
+        assert set(e) <= allowed and NAME.match(e["name"]), e
+        for key in ("why", "layer", "source"):
+            if key in e:
+                assert 1 <= len(e[key]) <= 200 and "\n" not in e[key] \
+                    and "\t" not in e[key]
+        if "unit" in e:
+            assert UNIT.match(e["unit"]) and e["better"] in ("lower", "higher")
+            assert e["source"] in SOURCES
+        for w in e.get("workloads", []):
+            assert w in CELLS
+
+
+def test_end_to_end_bounds_and_setup():
+    e2e = {e["name"]: e for e in M["end_to_end"]}
+    assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
+    for e in M["end_to_end"]:
+        assert 0.01 <= e["bound"] <= 0.1
+        assert e["source"] in ("host_clock", "device_trace")
+
+
+def test_every_cell_reports_enough_and_uses_a_known_config():
+    configs = {c["name"]: c for c in M["configs"]}
+    assert len({(c["config"], c["traffic"]) for c in M["workloads"]}) == len(CELLS)
+    for c in M["workloads"]:
+        assert c["config"] in configs and c["chips"] in (1, 4)
+        assert NAME.match(c["config"]) and NAME.match(c["traffic"])
+        e2e = [e["name"] for e in manifest.metrics_for(c["name"], "end_to_end")]
+        assert "setup_s" in e2e and len(e2e) >= 2
+        assert manifest.metrics_for(c["name"], "per_layer")
+    assert {c["config"] for c in M["workloads"]} == set(configs)
+    four = sum(c["chips"] == 4 for c in M["workloads"])
+    assert four <= max(1, len(CELLS) // 4)
+
+
+def test_each_layer_metric_moves_a_metric_its_cells_report():
+    e2e = {e["name"] for e in M["end_to_end"]}
+    for p in M["per_layer"]:
+        assert p["moves"] in e2e
+        for cell in p.get("workloads", CELLS):
+            mine = {e["name"] for e in
+                    manifest.metrics_for(cell, "end_to_end")}
+            if "workloads" in p:
+                assert p["moves"] in mine, (p["name"], cell)
+
+
+def test_every_named_file_exists_and_declares_what_the_manifest_says():
+    for c in M["configs"]:
+        assert c["file"].startswith(tuple(p + "/" for p in M["paths"]))
+        cfg = manifest.load_json(os.path.join(manifest.REPO, c["file"]))
+        assert cfg["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
+        assert cfg["source"] == c["source"]
+        assert os.path.exists(os.path.join(manifest.REPO,
+                                           cfg["reference"]["file"]))
+    for c in M["workloads"]:
+        assert manifest.traffic(c["traffic"])["kind"] in (
+            "open_loop", "closed_loop", "train_stream")
+    for p in M["per_layer"]:
+        mod = manifest.layer_metric(p["name"])
+        assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
+            p["layer"], p["unit"], p["source"], p["moves"]), p["name"]
+        assert callable(mod.read)
+
+
+def test_no_width_is_reduced():
+    width = re.compile(r"(hidden_size|intermediate|latent|state_size|proj|_dim$|_rank$"
+                       r"|head_dim|expand|experts_per_tok|n_embd)")
+    for c in M["configs"]:
+        assert not [k for k in c["reduced"] if width.search(k)]
+
+
+def test_a_dropped_in_file_is_found_with_no_code_change(tmp_path, monkeypatch):
+    bench = tmp_path / "benchmarks"
+    for sub in ("traffic", "configs", "layer_metrics"):
+        shutil.copytree(os.path.join(manifest.BENCH, sub), bench / sub)
+    shutil.copy(os.path.join(manifest.BENCH, "peaks.json"), bench)
+    (bench / "traffic" / "bursty_new.json").write_text(json.dumps({
+        "kind": "open_loop", "rate_per_s": 3.0, "arrival_cv": 3.0,
+        "mix_seed": 1, "prompt_len": {"fixed": 64},
+        "output_len": {"fixed": 32}}))
+    (bench / "configs" / "new-model.json").write_text(json.dumps(
+        {"name": "new-model", "plane": "serve"}))
+    (bench / "layer_metrics" / "new_metric.py").write_text(
+        'LAYER, UNIT, SOURCE, MOVES = "engine", "ms", "host_clock", '
+        '"setup_s"\n\ndef read(ctx):\n    return ctx["x"] * 2.0\n')
+    monkeypatch.setattr(manifest, "BENCH", str(bench))
+    mix = manifest.traffic("bursty_new")
+    reqs = loadgen.open_loop_schedule(mix, 20.0, 5, 1000)
+    assert 30 <= len(reqs) <= 90 and {len(r.prompt) for r in reqs} == {64}
+    assert manifest.config("new-model")["plane"] == "serve"
+    assert manifest.layer_metric("new_metric").read({"x": 2.0}) == 4.0
+
+
+def test_peaks_reject_an_unknown_device_kind():
+    assert manifest.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    with pytest.raises(KeyError, match="not in benchmarks/peaks.json"):
+        manifest.peaks("TPU v9 imaginary")
+
+
+# ------------------------------------------------------------- traffic
+MIX = manifest.traffic("chat_open")
+
+
+def test_schedule_is_deterministic_in_the_seed():
+    a = loadgen.open_loop_schedule(MIX, 30.0, 2**31 + 7, 32768)
+    b = loadgen.open_loop_schedule(MIX, 30.0, 2**31 + 7, 32768)
+    c = loadgen.open_loop_schedule(MIX, 30.0, 8, 32768)
+    assert [(r.due_s, r.prompt, r.n_out) for r in a] == \
+        [(r.due_s, r.prompt, r.n_out) for r in b]
+    assert [r.prompt for r in a] != [r.prompt for r in c]
+
+
+def test_every_seed_offers_the_same_work():
+    """In the cells' mixes the seed changes token ids only; with
+    `seed_reorders` it also shuffles: same sizes and gaps, other order."""
+    a = loadgen.open_loop_schedule(MIX, 30.0, 1, 32768)
+    b = loadgen.open_loop_schedule(MIX, 30.0, 2, 32768)
+    assert [(r.due_s, len(r.prompt), r.n_out) for r in a] == \
+        [(r.due_s, len(r.prompt), r.n_out) for r in b]
+    assert [r.prompt for r in a] != [r.prompt for r in b]
+    shuffled = {**MIX, "seed_reorders": True}
+    a = loadgen.open_loop_schedule(shuffled, 30.0, 1, 32768)
+    b = loadgen.open_loop_schedule(shuffled, 30.0, 2, 32768)
+    size = lambda rs: sorted((len(r.prompt), r.n_out) for r in rs)  # noqa: E731
+    assert size(a) == size(b) and len(a) == len(b)
+    gaps = lambda rs: sorted(np.round(np.diff([0.0] + [r.due_s for r in rs]), 9))  # noqa: E731
+    assert gaps(a) == gaps(b)
+    assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
+    assert all(0 <= r.due_s <= 30.0 for r in a)
+
+
+def test_only_grid_lengths_are_drawn():
+    grid = set(loadgen.grid_values(MIX["prompt_len"]["grid"]))
+    reqs = loadgen.open_loop_schedule({**MIX, "rate_per_s": 50.0}, 30.0, 3,
+                                      32768)
+    assert {len(r.prompt) for r in reqs} <= grid
+    assert len({len(r.prompt) for r in reqs}) > 8
+    assert {r.n_out for r in reqs} <= set(MIX["output_len"]["choices"])
+    assert loadgen.possible_lengths(MIX["prompt_len"]) == sorted(grid)
+    with pytest.raises(ValueError):
+        loadgen.possible_lengths({"dist": "lognormal", "median": 9, "sigma": 1})
+
+
+def test_closed_loop_first_answers_are_staggered():
+    mix = manifest.traffic("batch_closed")
+    plans = loadgen.closed_loop_schedule(mix, 4, 32768)
+    assert len(plans) == mix["clients"]
+    firsts = [p[0].n_out for p in plans]
+    assert len(set(firsts)) == mix["output_len"]["fixed"] // mix["first_output_step"]
+    assert all(r.n_out == mix["output_len"]["fixed"] for p in plans for r in p[1:])
+    assert all(len(r.prompt) == 128 for p in plans for r in p)
+
+
+def test_latency_counts_from_the_due_instant_and_misses_count():
+    recs = [loadgen.Record(0, due_s=1.0, sent_s=1.5, done_s=2.0, ok=True,
+                           got=10, want=10, engine_s=0.4),
+            loadgen.Record(1, due_s=2.0, sent_s=2.0, done_s=11.0, ok=True,
+                           got=20, want=20, engine_s=8.9),
+            loadgen.Record(2, due_s=3.0, sent_s=3.0, ok=False)]
+    s = loadgen.summarize(recs, seconds=10.0, miss_ms=99_000.0)
+    assert s["latency_ms"] == [1000.0, 9000.0, 99_000.0]   # from DUE, not sent
+    assert s["late_ms"] == [500.0, 0.0, 0.0]
+    assert s["attempted"] == 3 and s["failed"] == 1
+    assert s["tokens_per_s"] == 1.0      # only the answer inside the window
+    assert s["unanswered_at_window_end"] == 2
+    assert loadgen.percentile(s["latency_ms"], 95) == 99_000.0
+    assert loadgen.percentile([], 95) is None
+
+
+def test_open_loop_client_sends_on_schedule_against_a_slow_server():
+    from aiohttp import web
+
+    async def handle(request):
+        body = await request.json()
+        await asyncio.sleep(0.2)
+        return web.json_response({
+            "tokens": [[1] * body["max_new_tokens"]], "engine_s": 0.2,
+            "replica": "r0"})
+
+    started, box = threading.Event(), {}
+
+    def serve():
+        loop = asyncio.new_event_loop()
+        asyncio.set_event_loop(loop)
+        app = web.Application()
+        app.router.add_post("/x", handle)
+        runner = web.AppRunner(app)
+        loop.run_until_complete(runner.setup())
+        site = web.TCPSite(runner, "127.0.0.1", 0)
+        loop.run_until_complete(site.start())
+        box["port"] = site._server.sockets[0].getsockname()[1]
+        box["loop"] = loop
+        started.set()
+        loop.run_forever()
+
+    threading.Thread(target=serve, daemon=True).start()
+    assert started.wait(10)
+    reqs = [loadgen.Request(i, 0.05 * i, [1, 2, 3], 4) for i in range(10)]
+    recs = loadgen.run_open_loop(f"http://127.0.0.1:{box['port']}/x", reqs,
+                                 1.0, 5.0)
+    box["loop"].call_soon_threadsafe(box["loop"].stop)
+    assert all(r.ok for r in recs)
+    # an open loop does not wait for answers: all ten were sent within
+    # their schedule although each answer takes 0.2 s
+    assert max(r.sent_s for r in recs) < 0.45 + 0.2
+    lat = [(r.done_s - r.due_s) for r in recs]
+    assert all(0.2 <= x < 0.6 for x in lat)
+    assert math.isclose(recs[0].engine_s, 0.2)
