@@ -172,10 +172,14 @@ _SAVED_NAMES = (
 )
 
 
+# `jax.named_scope`s (`embed`, `attn`, `mlp`, `norm`, `lm_head`) put the
+# block part an op came from into its metadata, which is what a device
+# trace prints; they change nothing that is computed.
 def _layer_norm(x, g, b, eps=1e-5):
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + eps) * g + b
+    with jax.named_scope("norm"):
+        mu = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.var(x, axis=-1, keepdims=True)
+        return (x - mu) * lax.rsqrt(var + eps) * g + b
 
 
 def backbone(cfg: GPT2Config, params: Dict, tokens: jax.Array,
@@ -183,7 +187,9 @@ def backbone(cfg: GPT2Config, params: Dict, tokens: jax.Array,
     """tokens [B, T] int32 -> final hidden states [B, T, embd] (compute
     dtype), i.e. everything up to (not including) the lm-head matmul."""
     B, T = tokens.shape
-    x = params["wte"].astype(cfg.dtype)[tokens] + params["wpe"].astype(cfg.dtype)[:T]
+    with jax.named_scope("embed"):
+        x = (params["wte"].astype(cfg.dtype)[tokens]
+             + params["wpe"].astype(cfg.dtype)[:T])
 
     blocks = params["blocks"]
 
@@ -192,43 +198,42 @@ def backbone(cfg: GPT2Config, params: Dict, tokens: jax.Array,
         from jax.ad_checkpoint import checkpoint_name
 
         def one(cfg_x):
-            h = _layer_norm(
-                cfg_x,
-                layer_params["ln1_g"].astype(cfg.dtype),
-                layer_params["ln1_b"].astype(cfg.dtype),
-            )
-            h = checkpoint_name(h, "ln1_out")
-            B_, T_, E = cfg_x.shape
-            qkv = h @ layer_params["attn_qkv_w"].astype(cfg.dtype) + layer_params[
-                "attn_qkv_b"
-            ].astype(cfg.dtype)
-            qkv = checkpoint_name(qkv, "qkv")
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B_, T_, cfg.n_head, cfg.head_dim)
-            k = k.reshape(B_, T_, cfg.n_head, cfg.head_dim)
-            v = v.reshape(B_, T_, cfg.n_head, cfg.head_dim)
-            o = select_attention(cfg.attention, q, k, v, mesh, causal=True)
-            o = checkpoint_name(o.reshape(B_, T_, E), "attn_out_in")
-            x1 = cfg_x + (
-                o @ layer_params["attn_out_w"].astype(cfg.dtype)
-                + layer_params["attn_out_b"].astype(cfg.dtype)
-            )
-            x1 = checkpoint_name(x1, "resid_attn")
-            h2 = _layer_norm(
-                x1,
-                layer_params["ln2_g"].astype(cfg.dtype),
-                layer_params["ln2_b"].astype(cfg.dtype),
-            )
-            h2 = checkpoint_name(h2, "ln2_out")
-            h2 = h2 @ layer_params["mlp_fc_w"].astype(cfg.dtype) + layer_params[
-                "mlp_fc_b"
-            ].astype(cfg.dtype)
-            h2 = checkpoint_name(h2, "pre_gelu")
-            h2 = jax.nn.gelu(h2)
-            h2 = checkpoint_name(h2, "gelu_out")
-            h2 = h2 @ layer_params["mlp_out_w"].astype(cfg.dtype) + layer_params[
-                "mlp_out_b"
-            ].astype(cfg.dtype)
+            with jax.named_scope("attn"):
+                h = _layer_norm(
+                    cfg_x,
+                    layer_params["ln1_g"].astype(cfg.dtype),
+                    layer_params["ln1_b"].astype(cfg.dtype),
+                )
+                h = checkpoint_name(h, "ln1_out")
+                B_, T_, E = cfg_x.shape
+                qkv = (h @ layer_params["attn_qkv_w"].astype(cfg.dtype)
+                       + layer_params["attn_qkv_b"].astype(cfg.dtype))
+                qkv = checkpoint_name(qkv, "qkv")
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(B_, T_, cfg.n_head, cfg.head_dim)
+                k = k.reshape(B_, T_, cfg.n_head, cfg.head_dim)
+                v = v.reshape(B_, T_, cfg.n_head, cfg.head_dim)
+                o = select_attention(cfg.attention, q, k, v, mesh, causal=True)
+                o = checkpoint_name(o.reshape(B_, T_, E), "attn_out_in")
+                x1 = cfg_x + (
+                    o @ layer_params["attn_out_w"].astype(cfg.dtype)
+                    + layer_params["attn_out_b"].astype(cfg.dtype)
+                )
+                x1 = checkpoint_name(x1, "resid_attn")
+            with jax.named_scope("mlp"):
+                h2 = _layer_norm(
+                    x1,
+                    layer_params["ln2_g"].astype(cfg.dtype),
+                    layer_params["ln2_b"].astype(cfg.dtype),
+                )
+                h2 = checkpoint_name(h2, "ln2_out")
+                h2 = (h2 @ layer_params["mlp_fc_w"].astype(cfg.dtype)
+                      + layer_params["mlp_fc_b"].astype(cfg.dtype))
+                h2 = checkpoint_name(h2, "pre_gelu")
+                h2 = jax.nn.gelu(h2)
+                h2 = checkpoint_name(h2, "gelu_out")
+                h2 = (h2 @ layer_params["mlp_out_w"].astype(cfg.dtype)
+                      + layer_params["mlp_out_b"].astype(cfg.dtype))
             return x1 + h2
 
         return one
@@ -296,7 +301,8 @@ def lm_head(cfg: GPT2Config, params: Dict, x: jax.Array,
             out_dtype=jnp.float32) -> jax.Array:
     """Weight-tied projection to vocab logits — the ONE definition both
     the training loss and inference share."""
-    return (x @ params["wte"].astype(cfg.dtype).T).astype(out_dtype)
+    with jax.named_scope("lm_head"):
+        return (x @ params["wte"].astype(cfg.dtype).T).astype(out_dtype)
 
 
 def forward(cfg: GPT2Config, params: Dict, tokens: jax.Array,

@@ -187,11 +187,12 @@ def _apply(x, w, dtype, lora_layer=None, name: str = "", scale=None):
 def _lm_head(x, params, dtype):
     """Final projection to vocab logits in f32, int8-aware (sibling
     `lm_head_scale` leaf => per-vocab-column dequant after the matmul)."""
-    logits = x @ params["lm_head"].astype(dtype)
-    scale = params.get("lm_head_scale")
-    if scale is not None:
-        logits = logits * scale.astype(dtype)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = x @ params["lm_head"].astype(dtype)
+        scale = params.get("lm_head_scale")
+        if scale is not None:
+            logits = logits * scale.astype(dtype)
+        return logits.astype(jnp.float32)
 
 
 # weights the serve path quantizes; norms and the embedding lookup stay
@@ -231,9 +232,33 @@ def quantize_weights_int8(params: Dict) -> Dict:
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
+# `jax.named_scope`s (`embed`, `attn`, `mlp`, `norm`, `lm_head`) put the
+# block part an op came from into its metadata, which is what a device
+# trace prints; they change nothing that is computed.
+def _embed(params, tokens, dtype):
+    with jax.named_scope("embed"):
+        return params["tok_emb"].astype(dtype)[tokens]
+
+
 def _rms_norm(x, g, eps):
-    ms = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * lax.rsqrt(ms + eps).astype(x.dtype)) * g
+    with jax.named_scope("norm"):
+        ms = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                      keepdims=True)
+        return (x * lax.rsqrt(ms + eps).astype(x.dtype)) * g
+
+
+def _mlp(cfg, x, layer, lora_layer=None):
+    """A block's gated MLP with its pre-norm and residual: the one
+    part every forward and decode body shares verbatim."""
+    with jax.named_scope("mlp"):
+        h = _rms_norm(x, layer["mlp_norm"].astype(cfg.dtype), cfg.norm_eps)
+        gate = _apply(h, layer["w_gate"], cfg.dtype, lora_layer, "w_gate",
+                      layer.get("w_gate_scale"))
+        up = _apply(h, layer["w_up"], cfg.dtype, lora_layer, "w_up",
+                    layer.get("w_up_scale"))
+        down = _apply(jax.nn.silu(gate) * up, layer["w_down"], cfg.dtype,
+                      lora_layer, "w_down", layer.get("w_down_scale"))
+        return x + down
 
 
 def _rope(x, theta: float, t0=0):
@@ -261,7 +286,7 @@ def forward(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
     inference path for serve replicas).
     """
     B, T = tokens.shape
-    x = params["tok_emb"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg.dtype)
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     group = H // KV
 
@@ -279,35 +304,27 @@ def forward(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
             layer_lora = None
 
         def one(xin):
-            h = _rms_norm(xin, layer["attn_norm"].astype(cfg.dtype), cfg.norm_eps)
-            q = _apply(h, layer["wq"], cfg.dtype, layer_lora, "wq",
-                       layer.get("wq_scale"))
-            k = _apply(h, layer["wk"], cfg.dtype, layer_lora, "wk",
-                       layer.get("wk_scale"))
-            v = _apply(h, layer["wv"], cfg.dtype, layer_lora, "wv",
-                       layer.get("wv_scale"))
-            q = _rope(q.reshape(B, T, H, hd), cfg.rope_theta)
-            k_kv = _rope(k.reshape(B, T, KV, hd), cfg.rope_theta)
-            v_kv = v.reshape(B, T, KV, hd)
-            k, v = k_kv, v_kv
-            if group > 1:  # GQA: each kv head serves `group` query heads
-                k = jnp.repeat(k, group, axis=2)
-                v = jnp.repeat(v, group, axis=2)
-            o = select_attention(cfg.attention, q, k, v, mesh, causal=True)
-            o = o.reshape(B, T, H * hd)
-            x1 = xin + _apply(o, layer["wo"], cfg.dtype, layer_lora, "wo",
-                              layer.get("wo_scale"))
-
-            h2 = _rms_norm(x1, layer["mlp_norm"].astype(cfg.dtype), cfg.norm_eps)
-            gate = _apply(h2, layer["w_gate"], cfg.dtype, layer_lora,
-                          "w_gate", layer.get("w_gate_scale"))
-            up = _apply(h2, layer["w_up"], cfg.dtype, layer_lora, "w_up",
-                        layer.get("w_up_scale"))
-            down = _apply(
-                jax.nn.silu(gate) * up, layer["w_down"], cfg.dtype,
-                layer_lora, "w_down", layer.get("w_down_scale"),
-            )
-            return x1 + down, k_kv, v_kv
+            with jax.named_scope("attn"):
+                h = _rms_norm(xin, layer["attn_norm"].astype(cfg.dtype),
+                              cfg.norm_eps)
+                q = _apply(h, layer["wq"], cfg.dtype, layer_lora, "wq",
+                           layer.get("wq_scale"))
+                k = _apply(h, layer["wk"], cfg.dtype, layer_lora, "wk",
+                           layer.get("wk_scale"))
+                v = _apply(h, layer["wv"], cfg.dtype, layer_lora, "wv",
+                           layer.get("wv_scale"))
+                q = _rope(q.reshape(B, T, H, hd), cfg.rope_theta)
+                k_kv = _rope(k.reshape(B, T, KV, hd), cfg.rope_theta)
+                v_kv = v.reshape(B, T, KV, hd)
+                k, v = k_kv, v_kv
+                if group > 1:  # GQA: each kv head serves `group` query heads
+                    k = jnp.repeat(k, group, axis=2)
+                    v = jnp.repeat(v, group, axis=2)
+                o = select_attention(cfg.attention, q, k, v, mesh, causal=True)
+                o = o.reshape(B, T, H * hd)
+                x1 = xin + _apply(o, layer["wo"], cfg.dtype, layer_lora, "wo",
+                                  layer.get("wo_scale"))
+            return _mlp(cfg, x1, layer, layer_lora), k_kv, v_kv
 
         fn = jax.checkpoint(one) if cfg.remat else one
         out, k_kv, v_kv = fn(x)
@@ -422,7 +439,7 @@ def forward_with_prefix(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
     group = H // KV
     scale = hd ** -0.5
 
-    x = params["tok_emb"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg.dtype)
     # column validity over the concatenated [Pmax + S] axis: live
     # prefix columns, then causal self-attention within the suffix
     cols = jnp.arange(Pmax + S)
@@ -435,34 +452,29 @@ def forward_with_prefix(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
 
     def body(x, inputs):
         layer, pk_l, pv_l = inputs  # pk_l/pv_l [B, Pmax, KV, hd]
-        h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype), cfg.norm_eps)
-        q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
-        k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
-        v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
-        q = _rope(q.reshape(B, S, H, hd), cfg.rope_theta, t0=prefix_len)
-        k_suf = _rope(k.reshape(B, S, KV, hd), cfg.rope_theta, t0=prefix_len)
-        v_suf = v.reshape(B, S, KV, hd)
-        kk = jnp.concatenate([pk_l.astype(cfg.dtype), k_suf], axis=1)
-        vv = jnp.concatenate([pv_l.astype(cfg.dtype), v_suf], axis=1)
-        if group > 1:  # GQA: each kv head serves `group` query heads
-            kk = jnp.repeat(kk, group, axis=2)
-            vv = jnp.repeat(vv, group, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
-        s = jnp.where(mask, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, vv)
-        o = o.reshape(B, S, H * hd)
-        x1 = x + _apply(o, layer["wo"], cfg.dtype,
-                        scale=layer.get("wo_scale"))
-
-        h2 = _rms_norm(x1, layer["mlp_norm"].astype(cfg.dtype), cfg.norm_eps)
-        gate = _apply(h2, layer["w_gate"], cfg.dtype,
-                      scale=layer.get("w_gate_scale"))
-        up = _apply(h2, layer["w_up"], cfg.dtype,
-                    scale=layer.get("w_up_scale"))
-        down = _apply(jax.nn.silu(gate) * up, layer["w_down"], cfg.dtype,
-                      scale=layer.get("w_down_scale"))
-        return x1 + down, (k_suf, v_suf)
+        with jax.named_scope("attn"):
+            h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype),
+                          cfg.norm_eps)
+            q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
+            k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
+            v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
+            q = _rope(q.reshape(B, S, H, hd), cfg.rope_theta, t0=prefix_len)
+            k_suf = _rope(k.reshape(B, S, KV, hd), cfg.rope_theta,
+                          t0=prefix_len)
+            v_suf = v.reshape(B, S, KV, hd)
+            kk = jnp.concatenate([pk_l.astype(cfg.dtype), k_suf], axis=1)
+            vv = jnp.concatenate([pv_l.astype(cfg.dtype), v_suf], axis=1)
+            if group > 1:  # GQA: each kv head serves `group` query heads
+                kk = jnp.repeat(kk, group, axis=2)
+                vv = jnp.repeat(vv, group, axis=2)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
+            s = jnp.where(mask, s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+            o = o.reshape(B, S, H * hd)
+            x1 = x + _apply(o, layer["wo"], cfg.dtype,
+                            scale=layer.get("wo_scale"))
+        return _mlp(cfg, x1, layer), (k_suf, v_suf)
 
     x = x.astype(cfg.dtype)
     x, kv = lax.scan(body, x, (dict(params["blocks"]), pk, pv))
@@ -511,55 +523,49 @@ def decode_step(cfg: LlamaConfig, params: Dict, token: jax.Array,
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     group = H // KV
 
-    x = params["tok_emb"].astype(cfg.dtype)[token][:, None, :]  # [B,1,d]
+    x = _embed(params, token, cfg.dtype)[:, None, :]  # [B,1,d]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     # causal-by-position mask over the cache slots
     valid = (jnp.arange(M) <= pos)[None, None, :, None]  # [1,1,M,1]
 
     def body(x, inputs):
         layer, kc, vc = inputs  # kc/vc [B, M, KV, hd]
-        h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype), cfg.norm_eps)
-        q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
-        k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
-        v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
-        q = _rope(q.reshape(B, 1, H, hd), cfg.rope_theta, t0=pos)
-        k_new = _rope(k.reshape(B, 1, KV, hd), cfg.rope_theta, t0=pos)
-        v_new = v.reshape(B, 1, KV, hd)
-        kc = lax.dynamic_update_slice(kc, k_new.astype(kc.dtype),
-                                      (0, pos, 0, 0))
-        vc = lax.dynamic_update_slice(vc, v_new.astype(vc.dtype),
-                                      (0, pos, 0, 0))
-        kk, vv = kc, vc
-        if group > 1:
-            kk = jnp.repeat(kk, group, axis=2)
-            vv = jnp.repeat(vv, group, axis=2)
-        # scores over all cache slots, masked beyond pos.  bf16
-        # operands with f32 ACCUMULATION (flash-style numerics, the
-        # standard decode form; measured equal to explicit .astype(f32)
-        # operands on v5e — XLA fuses those casts — but this shape
-        # guarantees no cache-sized f32 copy on any backend)
-        s = jnp.einsum(
-            "bohd,bmhd->bhom", q, kk,
-            preferred_element_type=jnp.float32,
-        ) * scale  # [B,H,1,M] f32
-        s = jnp.where(valid.transpose(0, 3, 1, 2), s, -1e30)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum(
-            "bhom,bmhd->bohd", w.astype(cfg.dtype), vv,
-            preferred_element_type=jnp.float32,
-        )
-        o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
-        x1 = x + _apply(o, layer["wo"], cfg.dtype,
-                        scale=layer.get("wo_scale"))
-
-        h2 = _rms_norm(x1, layer["mlp_norm"].astype(cfg.dtype), cfg.norm_eps)
-        gate = _apply(h2, layer["w_gate"], cfg.dtype,
-                      scale=layer.get("w_gate_scale"))
-        up = _apply(h2, layer["w_up"], cfg.dtype,
-                    scale=layer.get("w_up_scale"))
-        down = _apply(jax.nn.silu(gate) * up, layer["w_down"], cfg.dtype,
-                      scale=layer.get("w_down_scale"))
-        return x1 + down, (kc, vc)
+        with jax.named_scope("attn"):
+            h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype),
+                          cfg.norm_eps)
+            q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
+            k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
+            v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
+            q = _rope(q.reshape(B, 1, H, hd), cfg.rope_theta, t0=pos)
+            k_new = _rope(k.reshape(B, 1, KV, hd), cfg.rope_theta, t0=pos)
+            v_new = v.reshape(B, 1, KV, hd)
+            kc = lax.dynamic_update_slice(kc, k_new.astype(kc.dtype),
+                                          (0, pos, 0, 0))
+            vc = lax.dynamic_update_slice(vc, v_new.astype(vc.dtype),
+                                          (0, pos, 0, 0))
+            kk, vv = kc, vc
+            if group > 1:
+                kk = jnp.repeat(kk, group, axis=2)
+                vv = jnp.repeat(vv, group, axis=2)
+            # scores over all cache slots, masked beyond pos.  bf16
+            # operands with f32 ACCUMULATION (flash-style numerics, the
+            # standard decode form; measured equal to explicit .astype(f32)
+            # operands on v5e — XLA fuses those casts — but this shape
+            # guarantees no cache-sized f32 copy on any backend)
+            s = jnp.einsum(
+                "bohd,bmhd->bhom", q, kk,
+                preferred_element_type=jnp.float32,
+            ) * scale  # [B,H,1,M] f32
+            s = jnp.where(valid.transpose(0, 3, 1, 2), s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum(
+                "bhom,bmhd->bohd", w.astype(cfg.dtype), vv,
+                preferred_element_type=jnp.float32,
+            )
+            o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
+            x1 = x + _apply(o, layer["wo"], cfg.dtype,
+                            scale=layer.get("wo_scale"))
+        return _mlp(cfg, x1, layer), (kc, vc)
 
     x = x.astype(cfg.dtype)
     x, (k_cache, v_cache) = lax.scan(
@@ -604,7 +610,7 @@ def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     group = H // KV
 
-    x = params["tok_emb"].astype(cfg.dtype)[token][:, None, :]  # [B,1,d]
+    x = _embed(params, token, cfg.dtype)[:, None, :]  # [B,1,d]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     # per-row causal mask over cache slots: [B, M]
     valid = jnp.arange(M)[None, :] <= pos[:, None]
@@ -618,41 +624,35 @@ def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
 
     def body(x, inputs):
         layer, kc, vc = inputs  # kc/vc [B, M, KV, hd]
-        h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype), cfg.norm_eps)
-        q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
-        k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
-        v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
-        q = _rope_at(q.reshape(B, 1, H, hd), cfg.rope_theta, pos)
-        k_new = _rope_at(k.reshape(B, 1, KV, hd), cfg.rope_theta, pos)
-        v_new = v.reshape(B, 1, KV, hd)
-        kc = jnp.where(write, k_new.astype(kc.dtype), kc)
-        vc = jnp.where(write, v_new.astype(vc.dtype), vc)
-        kk, vv = kc, vc
-        if group > 1:
-            kk = jnp.repeat(kk, group, axis=2)
-            vv = jnp.repeat(vv, group, axis=2)
-        s = jnp.einsum(
-            "bohd,bmhd->bhom", q, kk,
-            preferred_element_type=jnp.float32,
-        ) * scale  # [B,H,1,M] f32
-        s = jnp.where(valid[:, None, None, :], s, -1e30)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum(
-            "bhom,bmhd->bohd", w.astype(cfg.dtype), vv,
-            preferred_element_type=jnp.float32,
-        )
-        o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
-        x1 = x + _apply(o, layer["wo"], cfg.dtype,
-                        scale=layer.get("wo_scale"))
-
-        h2 = _rms_norm(x1, layer["mlp_norm"].astype(cfg.dtype), cfg.norm_eps)
-        gate = _apply(h2, layer["w_gate"], cfg.dtype,
-                      scale=layer.get("w_gate_scale"))
-        up = _apply(h2, layer["w_up"], cfg.dtype,
-                    scale=layer.get("w_up_scale"))
-        down = _apply(jax.nn.silu(gate) * up, layer["w_down"], cfg.dtype,
-                      scale=layer.get("w_down_scale"))
-        return x1 + down, (kc, vc)
+        with jax.named_scope("attn"):
+            h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype),
+                          cfg.norm_eps)
+            q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
+            k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
+            v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
+            q = _rope_at(q.reshape(B, 1, H, hd), cfg.rope_theta, pos)
+            k_new = _rope_at(k.reshape(B, 1, KV, hd), cfg.rope_theta, pos)
+            v_new = v.reshape(B, 1, KV, hd)
+            kc = jnp.where(write, k_new.astype(kc.dtype), kc)
+            vc = jnp.where(write, v_new.astype(vc.dtype), vc)
+            kk, vv = kc, vc
+            if group > 1:
+                kk = jnp.repeat(kk, group, axis=2)
+                vv = jnp.repeat(vv, group, axis=2)
+            s = jnp.einsum(
+                "bohd,bmhd->bhom", q, kk,
+                preferred_element_type=jnp.float32,
+            ) * scale  # [B,H,1,M] f32
+            s = jnp.where(valid[:, None, None, :], s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum(
+                "bhom,bmhd->bohd", w.astype(cfg.dtype), vv,
+                preferred_element_type=jnp.float32,
+            )
+            o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
+            x1 = x + _apply(o, layer["wo"], cfg.dtype,
+                            scale=layer.get("wo_scale"))
+        return _mlp(cfg, x1, layer), (kc, vc)
 
     x = x.astype(cfg.dtype)
     x, (k_cache, v_cache) = lax.scan(
@@ -693,7 +693,7 @@ def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     quant = kv_scales is not None
 
-    x = params["tok_emb"].astype(cfg.dtype)[token][:, None, :]  # [B,1,d]
+    x = _embed(params, token, cfg.dtype)[:, None, :]  # [B,1,d]
 
     def body(carry, inputs):
         if quant:
@@ -701,48 +701,43 @@ def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
         else:
             x, kp, vp = carry
         li, layer = inputs
-        h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype), cfg.norm_eps)
-        q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
-        k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
-        v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
-        q = _rope_at(q.reshape(B, 1, H, hd), cfg.rope_theta, pos)
-        k_new = _rope_at(k.reshape(B, 1, KV, hd), cfg.rope_theta, pos)
-        v_new = v.reshape(B, 1, KV, hd)
+        with jax.named_scope("attn"):
+            h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype),
+                          cfg.norm_eps)
+            q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
+            k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
+            v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
+            q = _rope_at(q.reshape(B, 1, H, hd), cfg.rope_theta, pos)
+            k_new = _rope_at(k.reshape(B, 1, KV, hd), cfg.rope_theta, pos)
+            v_new = v.reshape(B, 1, KV, hd)
+            if quant:
+                kq, ks_new = _pa.quantize_int8(k_new[:, 0])
+                vq, vs_new = _pa.quantize_int8(v_new[:, 0])
+                kp, vp, ks, vs = _pa.paged_kv_append(
+                    kp, vp, kq, vq, tables, pos, li,
+                    k_scale=ks, v_scale=vs, k_new_scale=ks_new,
+                    v_new_scale=vs_new, interpret=interpret,
+                )
+                o = _pa.paged_decode_attention(
+                    q[:, 0], kp, vp, tables, pos, li,
+                    k_scale=ks, v_scale=vs, interpret=interpret,
+                )
+            else:
+                kp, vp = _pa.paged_kv_append(
+                    kp, vp, k_new[:, 0].astype(kp.dtype),
+                    v_new[:, 0].astype(vp.dtype), tables, pos, li,
+                    interpret=interpret,
+                )
+                o = _pa.paged_decode_attention(
+                    q[:, 0], kp, vp, tables, pos, li, interpret=interpret,
+                )
+            o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
+            x1 = x + _apply(o, layer["wo"], cfg.dtype,
+                            scale=layer.get("wo_scale"))
+        out = _mlp(cfg, x1, layer)
         if quant:
-            kq, ks_new = _pa.quantize_int8(k_new[:, 0])
-            vq, vs_new = _pa.quantize_int8(v_new[:, 0])
-            kp, vp, ks, vs = _pa.paged_kv_append(
-                kp, vp, kq, vq, tables, pos, li,
-                k_scale=ks, v_scale=vs, k_new_scale=ks_new,
-                v_new_scale=vs_new, interpret=interpret,
-            )
-            o = _pa.paged_decode_attention(
-                q[:, 0], kp, vp, tables, pos, li,
-                k_scale=ks, v_scale=vs, interpret=interpret,
-            )
-        else:
-            kp, vp = _pa.paged_kv_append(
-                kp, vp, k_new[:, 0].astype(kp.dtype),
-                v_new[:, 0].astype(vp.dtype), tables, pos, li,
-                interpret=interpret,
-            )
-            o = _pa.paged_decode_attention(
-                q[:, 0], kp, vp, tables, pos, li, interpret=interpret,
-            )
-        o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
-        x1 = x + _apply(o, layer["wo"], cfg.dtype,
-                        scale=layer.get("wo_scale"))
-
-        h2 = _rms_norm(x1, layer["mlp_norm"].astype(cfg.dtype), cfg.norm_eps)
-        gate = _apply(h2, layer["w_gate"], cfg.dtype,
-                      scale=layer.get("w_gate_scale"))
-        up = _apply(h2, layer["w_up"], cfg.dtype,
-                    scale=layer.get("w_up_scale"))
-        down = _apply(jax.nn.silu(gate) * up, layer["w_down"], cfg.dtype,
-                      scale=layer.get("w_down_scale"))
-        if quant:
-            return (x1 + down, kp, vp, ks, vs), None
-        return (x1 + down, kp, vp), None
+            return (out, kp, vp, ks, vs), None
+        return (out, kp, vp), None
 
     if quant:
         carry0 = (x.astype(cfg.dtype), k_pool, v_pool) + tuple(kv_scales)

@@ -111,6 +111,7 @@ def _build_fwd(causal, scale, block_q, block_k, n_k, interpret, dtype):
         grid = (BH, n_q, n_k)
         return pl.pallas_call(
             kernel,
+            name="flash_fwd",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -177,6 +178,7 @@ def _build_bwd_dq(causal, scale, block_q, block_k, n_k, interpret, dtype):
         n_q = T // block_q
         return pl.pallas_call(
             kernel,
+            name="flash_bwd_dq",
             grid=(BH, n_q, n_k),
             in_specs=[
                 pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -236,6 +238,7 @@ def _build_bwd_fused(causal, scale, T, interpret, dtype):
         vec = pl.BlockSpec((None, T_, 1), lambda b: (b, 0, 0))
         return pl.pallas_call(
             kernel,
+            name="flash_bwd_fused",
             grid=(BH,),
             in_specs=[spec, spec, spec, spec, vec, spec],
             out_specs=[spec, spec, spec],
@@ -294,6 +297,7 @@ def _build_bwd_dkv(causal, scale, block_q, block_k, n_q, interpret, dtype):
         n_k = T // block_k
         return pl.pallas_call(
             kernel,
+            name="flash_bwd_dkv",
             grid=(BH, n_k, n_q),
             in_specs=[
                 pl.BlockSpec((None, block_q, D), lambda b, j, i: (b, i, 0)),

@@ -198,6 +198,7 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
 
     return pl.pallas_call(
         kernel,
+        name="paged_kv_append",
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -340,6 +341,7 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
 
     return pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, W),

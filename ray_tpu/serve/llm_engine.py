@@ -72,9 +72,9 @@ from ray_tpu.serve.kv_cache import SCRATCH_BLOCK, BlockPool, RadixCache
 logger = logging.getLogger(__name__)
 
 
-# per-tick phase timing to stdout (the tool that found the
-# per-admission host read and the unoverlapped chunk sync)
-_TRACE = os.environ.get("RT_LLM_ENGINE_TRACE", "") not in ("", "0")
+# finished requests kept in stats()["request_ring"]: bounded so that a
+# pickled stats() stays small on the 2 s health-check path
+REQUEST_RING = 512
 
 
 def _next_pow2(n: int) -> int:
@@ -270,6 +270,21 @@ class LlamaEngine:
         )))
         self._tick_ema_s = 0.0
         self._last_gather_blocks = 0  # W of the latest chunk dispatch
+        # request lifecycle ring: one record per FINISHED request (ok,
+        # shed, refused or failed), the last REQUEST_RING of them, in
+        # stats()["request_ring"].  Each request is stamped once, on
+        # the wall clock, where the work happens (submit, admit,
+        # prefill dispatched, first token harvested, done); an
+        # EngineTicket, when there is one, is handed the same stamps.
+        # submit() refuses on the caller's thread, so the ring and its
+        # count have a small lock of their own.
+        self._request_ring: deque = deque(maxlen=REQUEST_RING)
+        self._finished_total = 0
+        self._ring_lock = threading.Lock()
+        # engine-loop spans: no-ops (a third of a microsecond each)
+        # unless a jax.profiler session is active, in which case they
+        # land on the host plane of the same trace as the device's ops
+        self._span = jax.profiler.TraceAnnotation
         # last computed stats() dict, served when the engine lock is
         # busy (admission compiles hold it for seconds) — whole-dict
         # swaps only, so readers never see a partial snapshot.  Seeded
@@ -343,24 +358,27 @@ class LlamaEngine:
             ))
             return f
         n_new = max(1, min(int(max_new_tokens), limit - len(prompt_ids)))
+        # the lifecycle's first stamp (wall clock, like the ledger's)
+        t_submit = _time.time()
         # engine slice of the request's latency ledger: None (zero
         # allocations) unless an ambient ledger or sampled trace exists
-        tk = _rl.engine_ticket()
+        tk = _rl.engine_ticket(t_submit)
         # no pool-size check needed: __init__ guarantees the pool holds
         # a full max_len sequence, and T + n_new - 1 <= max_len - 1
         now = _time.monotonic()
         deadline = None if timeout_s is None else now + max(0.0, timeout_s)
         fut: Future = Future()
+
         with self._wake:
             if not self._running:
-                if tk is not None:
-                    tk.refused("shutdown")
+                self._refused("shutdown", t_submit, t_submit, tk,
+                              len(prompt_ids))
                 fut.set_exception(RuntimeError("engine is shut down"))
                 return fut
             if self._draining:
                 self._rejected_total += 1
-                if tk is not None:
-                    tk.refused("draining")
+                self._refused("draining", t_submit, t_submit, tk,
+                              len(prompt_ids))
                 fut.set_exception(BackPressureError(
                     "engine is draining (replica scaling down)",
                     retry_after_s=self.retry_after_hint_s(),
@@ -379,8 +397,8 @@ class LlamaEngine:
                 # slots are zero and the queue is bounded at exactly
                 # max_queued.
                 self._rejected_total += 1
-                if tk is not None:
-                    tk.refused("queue_full")
+                self._refused("queue_full", t_submit, t_submit, tk,
+                              len(prompt_ids))
                 fut.set_exception(BackPressureError(
                     f"engine queue full (max_queued={self.max_queued})",
                     retry_after_s=self.retry_after_hint_s(),
@@ -388,15 +406,15 @@ class LlamaEngine:
                 return fut
             if deadline is not None and now >= deadline:
                 self._shed_expired += 1
-                if tk is not None:
-                    tk.refused("expired_at_submit")
+                self._refused("expired_at_submit", t_submit, t_submit, tk,
+                              len(prompt_ids))
                 fut.set_exception(DeadlineExceededError(
                     "request budget already spent at submission",
                     timeout_s=timeout_s,
                 ))
                 return fut
             self._queue.append(
-                (list(prompt_ids), n_new, fut, now, deadline, tk)
+                (list(prompt_ids), n_new, fut, t_submit, deadline, tk)
             )
             self._wake.notify()
         return fut
@@ -438,6 +456,9 @@ class LlamaEngine:
     def _stats_locked(self) -> Dict[str, object]:
         served = self._hit_tokens + self._prefill_tokens
         cached = self._radix.cached_blocks if self._radix else 0
+        with self._ring_lock:
+            ring = list(self._request_ring)
+            finished = self._finished_total
         return {
                 "active": len(self._active),
                 "queued": len(self._queue),
@@ -492,6 +513,11 @@ class LlamaEngine:
                 # the dashboard / postmortems (list of small dicts;
                 # numeric-bridge consumers skip non-float values)
                 "tick_ring": list(self._tick_ring),
+                # request lifecycle ring: one record per finished
+                # request (see _record); `finished_total` is the last
+                # record's `seq`, so a reader knows what the ring lost
+                "request_ring": ring,
+                "finished_total": finished,
                 # overload plane (admission control + shedding):
                 # consumed by the SLO autoscaler and /api/serve
                 "max_queued": (-1 if self.max_queued is None
@@ -571,7 +597,6 @@ class LlamaEngine:
                     return (k_pool, v_pool, k_scale, v_scale, tok, pos,
                             jnp.concatenate([tok_in[None], toks], axis=0))
 
-                fn = jax.jit(_fn, donate_argnums=(1, 2, 3, 4))
             else:
                 def _fn(params, k_pool, v_pool, tables, tok, pos):
                     def body(carry, _):
@@ -595,7 +620,6 @@ class LlamaEngine:
                         [tok_in[None], toks], axis=0
                     )
 
-                fn = jax.jit(_fn, donate_argnums=(1, 2))
         elif self._kv_int8:
             from ray_tpu.ops import paged_attention as _pa
 
@@ -662,7 +686,6 @@ class LlamaEngine:
                 return (k_pool, v_pool, k_scale, v_scale, tok, pos,
                         jnp.concatenate([tok_in[None], toks], axis=0))
 
-            fn = jax.jit(_fn, donate_argnums=(1, 2, 3, 4))
         else:
             def _fn(params, k_pool, v_pool, tables, tok, pos):
                 # tables [slots, W] -> dense [L, slots, W*bs, KV, hd]
@@ -704,8 +727,11 @@ class LlamaEngine:
                     [tok_in[None], toks], axis=0
                 )
 
-            fn = jax.jit(_fn, donate_argnums=(1, 2))
-
+        # the name the device trace prints the program under
+        # (`jit_decode_chunk_w<W>`): readers match it by prefix
+        _fn.__name__ = f"decode_chunk_w{W}"
+        fn = jax.jit(_fn, donate_argnums=(
+            (1, 2, 3, 4) if self._kv_int8 else (1, 2)))
         while len(self._chunk_cache) >= self._chunk_cache_cap:
             old_w, _old = self._chunk_cache.popitem(last=False)
             self._chunk_cache_evictions += 1
@@ -733,6 +759,7 @@ class LlamaEngine:
                 )
                 return logits[0], ks, vs  # ks/vs [L, 1, bucket, KV, hd]
 
+            _pf.__name__ = f"prefill_b{bucket}"
             fn = self._prefill_cache[bucket] = jax.jit(_pf)
         return fn
 
@@ -778,6 +805,7 @@ class LlamaEngine:
                     )
                     return logits[0], ks, vs
 
+            _pf.__name__ = f"suffix_prefill_s{s_bucket}_p{p_blocks}"
             fn = self._suffix_cache[key] = jax.jit(_pf)
         return fn
 
@@ -830,9 +858,6 @@ class LlamaEngine:
                     tok = tok.at[slot].set(tok0)
                     return k_pool, v_pool, k_scale, v_scale, pos, tok
 
-                fn = self._write_cache[key] = jax.jit(
-                    _fn, donate_argnums=(0, 1, 2, 3)
-                )
             else:
                 def _fn(k_pool, v_pool, k1, v1, blk_ids, slot, pos0,
                         tok0, pos, tok):
@@ -849,14 +874,51 @@ class LlamaEngine:
                     tok = tok.at[slot].set(tok0)
                     return k_pool, v_pool, pos, tok
 
-                fn = self._write_cache[key] = jax.jit(
-                    _fn, donate_argnums=(0, 1)
-                )
+            _fn.__name__ = f"kv_write_t{t_in}_n{nb}"
+            fn = self._write_cache[key] = jax.jit(_fn, donate_argnums=(
+                (0, 1, 2, 3) if self._kv_int8 else (0, 1)))
         return fn
+
+    # -- request lifecycle ring ------------------------------------------
+    def _record(self, status: str, t_submit: float, t_done: float,
+                tokens_in: int, req: Optional[Dict] = None) -> None:
+        """One record per finished request, from the stamps taken where
+        the work happened (`req`: its `_active` entry, None when it
+        never was admitted).  A phase the request never reached is
+        None; a request refused or shed before admission spent
+        `queue_s` waiting for that verdict.  `first_token_s + decode_s`
+        is `t_done - t_submit` exactly."""
+        rec = {
+            "seq": 0, "t_done": t_done, "status": status,
+            "queue_s": t_done - t_submit, "prefill_dispatch_s": None,
+            "first_token_s": None, "decode_s": None, "harvests": 0,
+            "tokens_in": tokens_in, "tokens_hit": 0, "tokens_out": 0,
+        }
+        if req is not None:
+            rec["queue_s"] = req["t_admit"] - t_submit
+            rec["prefill_dispatch_s"] = req["t_prefill"] - req["t_admit"]
+            if req["t_first"] is not None:
+                rec["first_token_s"] = req["t_first"] - t_submit
+                rec["decode_s"] = t_done - req["t_first"]
+            rec["harvests"] = req["harvests"]
+            rec["tokens_hit"] = req["tokens_hit"]
+            rec["tokens_out"] = min(len(req["out"]), req["want"])
+        with self._ring_lock:
+            self._finished_total += 1
+            rec["seq"] = self._finished_total
+            self._request_ring.append(rec)
+
+    def _refused(self, reason: str, t_submit: float, now: float, tk,
+                 tokens_in: int) -> None:
+        """A request that ends before admission (refused by submit() or
+        shed from the queue): its record, and its ticket's terminal."""
+        self._record(reason, t_submit, now, tokens_in)
+        if tk is not None:
+            tk.refused(reason, now)
 
     # -- admission -----------------------------------------------------
     def _maybe_shed(self, fut: Future, deadline: Optional[float],
-                    tk=None) -> bool:
+                    t_submit: float, tokens_in: int, tk=None) -> bool:
         """Deadline-aware load shedding, applied when a request is
         popped for admission — the last instant before it costs a
         prefill dispatch.  Sheds when the deadline has already passed,
@@ -887,8 +949,7 @@ class LlamaEngine:
             reason = "shed_predicted"
         else:
             return False
-        if tk is not None:
-            tk.refused(reason)
+        self._refused(reason, t_submit, _time.time(), tk, tokens_in)
         fut.set_exception(DeadlineExceededError(
             f"shed before prefill: {why}",
             timeout_s=max(0.0, deadline - now),
@@ -922,85 +983,80 @@ class LlamaEngine:
             if self._radix is not None:
                 self._radix.release(path)
             return False
-        if tk is not None:
-            # queue wait ends here: the request holds a slot and its
-            # blocks; everything after is prefill dispatch
-            tk.admitted(_time.time())
-
+        # queue wait ends here: the request holds a slot and its
+        # blocks; everything after is prefill dispatch
+        t_admit = _time.time()
         slot = self._free.pop()
-        if P > 0:
-            # PREFIX HIT: prefill only the suffix, attending over the
-            # gathered prefix blocks (pow-2 buckets on both axes)
-            S = T - P
-            s_bucket = min(_next_pow2(S), self.max_len - 1)
-            p_bucket = _next_pow2(len(shared))
-            blk_ids = jnp.asarray(
-                shared + [SCRATCH_BLOCK] * (p_bucket - len(shared)),
-                jnp.int32,
-            )
-            suffix = jnp.asarray(
-                [prompt[P:] + [0] * (s_bucket - S)], jnp.int32
-            )
-            sfn = self._suffix_prefill_for(s_bucket, p_bucket)
-            if self._kv_int8:
-                logits, k1, v1 = sfn(
-                    self.params, self._k_pool, self._v_pool,
-                    self._k_scale, self._v_scale, suffix, blk_ids,
-                    jnp.asarray(P, jnp.int32),
+        S = T - P  # tokens to prefill (the whole prompt on a miss)
+        # pow-2 length buckets: RIGHT-pad (the scheme depends on it —
+        # causal prefill keeps positions 0..T-1 correct, the pad
+        # tail's garbage KV is masked by the starting pos and
+        # overwritten as decoding advances)
+        bucket = min(_next_pow2(S), self.max_len - 1)
+        with self._span("engine.prefill", bucket=bucket, slot=slot,
+                        hit_blocks=len(shared)):
+            if P > 0:
+                # PREFIX HIT: prefill only the suffix, attending over
+                # the gathered prefix blocks (pow-2 buckets on both axes)
+                p_bucket = _next_pow2(len(shared))
+                blk_ids = jnp.asarray(
+                    shared + [SCRATCH_BLOCK] * (p_bucket - len(shared)),
+                    jnp.int32,
                 )
+                suffix = jnp.asarray(
+                    [prompt[P:] + [0] * (bucket - S)], jnp.int32
+                )
+                sfn = self._suffix_prefill_for(bucket, p_bucket)
+                if self._kv_int8:
+                    logits, k1, v1 = sfn(
+                        self.params, self._k_pool, self._v_pool,
+                        self._k_scale, self._v_scale, suffix, blk_ids,
+                        jnp.asarray(P, jnp.int32),
+                    )
+                else:
+                    logits, k1, v1 = sfn(
+                        self.params, self._k_pool, self._v_pool, suffix,
+                        blk_ids, jnp.asarray(P, jnp.int32),
+                    )
+                self._hit_tokens += P
+                self._prefix_hits += 1
             else:
-                logits, k1, v1 = sfn(
-                    self.params, self._k_pool, self._v_pool, suffix,
-                    blk_ids, jnp.asarray(P, jnp.int32),
+                padded = prompt + [0] * (bucket - T)
+                logits, k1, v1 = self._prefill_for(bucket)(
+                    self.params, jnp.asarray([padded], jnp.int32)
                 )
-            tok0 = jnp.argmax(logits[S - 1], axis=-1).astype(jnp.int32)
-            # suffix KV starts exactly at block boundary P//bs; write
-            # only the blocks holding real suffix tokens — bucket-pad
-            # garbage past them is dropped, garbage within the last
-            # real block is masked by pos until decode overwrites it
-            nb_real = _cdiv(S, bs)
-            write_ids = own[:nb_real]
-            self._hit_tokens += P
-            self._prefill_tokens += S
-            self._prefix_hits += 1
-            wfn = self._write_blocks_for(s_bucket, nb_real)
-        else:
-            # pow-2 length buckets: RIGHT-pad (the scheme depends on it
-            # — causal prefill keeps positions 0..T-1 correct, the pad
-            # tail's garbage KV is masked by the starting pos and
-            # overwritten as decoding advances)
-            bucket = min(_next_pow2(T), self.max_len - 1)
-            padded = prompt + [0] * (bucket - T)
-            logits, k1, v1 = self._prefill_for(bucket)(
-                self.params, jnp.asarray([padded], jnp.int32)
-            )
             # first generated token comes from the LAST REAL prompt
             # position; it STAYS on device — the next chunk emits it in
             # its pre-chunk token row, so admission costs only async
             # dispatches
-            tok0 = jnp.argmax(logits[T - 1], axis=-1).astype(jnp.int32)
-            nb_real = _cdiv(T, bs)
+            tok0 = jnp.argmax(logits[S - 1], axis=-1).astype(jnp.int32)
+            # the prefilled KV starts at a block boundary (0, or P);
+            # write only the blocks holding real tokens — bucket-pad
+            # garbage past them is dropped, garbage within the last
+            # real block is masked by pos until decode overwrites it
+            nb_real = _cdiv(S, bs)
             write_ids = own[:nb_real]
-            self._prefill_tokens += T
+            self._prefill_tokens += S
+            self._prefill_calls += 1
             wfn = self._write_blocks_for(bucket, nb_real)
-        self._prefill_calls += 1
-
-        if self._kv_int8:
-            (self._k_pool, self._v_pool, self._k_scale, self._v_scale,
-             self._pos, self._tok) = wfn(
-                self._k_pool, self._v_pool, self._k_scale,
-                self._v_scale, k1, v1,
-                jnp.asarray(write_ids, jnp.int32),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(T, jnp.int32),
-                tok0, self._pos, self._tok,
-            )
-        else:
-            self._k_pool, self._v_pool, self._pos, self._tok = wfn(
-                self._k_pool, self._v_pool, k1, v1,
-                jnp.asarray(write_ids, jnp.int32),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(T, jnp.int32),
-                tok0, self._pos, self._tok,
-            )
+            if self._kv_int8:
+                (self._k_pool, self._v_pool, self._k_scale,
+                 self._v_scale, self._pos, self._tok) = wfn(
+                    self._k_pool, self._v_pool, self._k_scale,
+                    self._v_scale, k1, v1,
+                    jnp.asarray(write_ids, jnp.int32),
+                    jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(T, jnp.int32),
+                    tok0, self._pos, self._tok,
+                )
+            else:
+                self._k_pool, self._v_pool, self._pos, self._tok = wfn(
+                    self._k_pool, self._v_pool, k1, v1,
+                    jnp.asarray(write_ids, jnp.int32),
+                    jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(T, jnp.int32),
+                    tok0, self._pos, self._tok,
+                )
 
         # donate this prompt's full blocks to the radix cache (pinned
         # until completion); blocks the trie adopts stop being
@@ -1014,15 +1070,19 @@ class LlamaEngine:
                 own_set = [b for b in own_set if b not in adopted_set]
 
         self._slot_blocks[slot] = shared + own
+        # host-side dispatch timestamp: the prefill computes async on
+        # device, so this is when the host let go of it
+        t_prefill = _time.time()
         if tk is not None:
-            # host-side dispatch timestamp: the prefill computes async
-            # on device, but the ledger phases are wall-clock anyway
-            tk.prefilled(_time.time())
+            tk.admitted(t_admit)
+            tk.prefilled(t_prefill)
         self._active[slot] = {
             "fut": fut, "out": [], "want": n_new,
             "since": self._chunk_seq + 1,  # first chunk with its steps
             "pos_host": T, "own_blocks": own_set, "tree_path": path,
-            "t_submit": t_submit, "first_tok": False, "tk": tk,
+            "tk": tk, "tokens_in": T, "tokens_hit": P, "harvests": 0,
+            "t_submit": t_submit, "t_admit": t_admit,
+            "t_prefill": t_prefill, "t_first": None,
         }
         return True
 
@@ -1054,8 +1114,8 @@ class LlamaEngine:
         dispatched are skipped — their tokens start in a later chunk.
         A request's FIRST chunk contributes from row 0 (its prefill
         token rode along); later chunks from row 1."""
-        now = _time.monotonic()
-        wall = _time.time()
+        now = _time.monotonic()  # ages the shed predictor's samples
+        wall = _time.time()      # the lifecycle stamp of this harvest
         done = []
         for slot, req in self._active.items():
             if req["since"] > seq:
@@ -1066,9 +1126,10 @@ class LlamaEngine:
                 req["out"].extend(
                     int(t) for t in toks_host[start:start + need, slot]
                 )
-            if req["out"] and not req["first_tok"]:
-                req["first_tok"] = True
-                ttft = now - req["t_submit"]
+                req["harvests"] += 1
+            if req["out"] and req["t_first"] is None:
+                req["t_first"] = wall
+                ttft = wall - req["t_submit"]
                 self._ttft_ema_s = (
                     ttft if self._ttft_ema_s == 0.0
                     else 0.8 * self._ttft_ema_s + 0.2 * ttft
@@ -1081,10 +1142,131 @@ class LlamaEngine:
         for slot in done:
             req = self._active.pop(slot)
             self._release(slot, req)
+            out = req["out"][:req["want"]]
+            self._record("ok", req["t_submit"], wall, req["tokens_in"],
+                         req)
             if req["tk"] is not None:
-                req["tk"].done(len(req["out"][:req["want"]]), wall)
+                req["tk"].done(len(out), wall)
             if not req["fut"].done():
-                req["fut"].set_result(req["out"][:req["want"]])
+                req["fut"].set_result(out)
+
+    def _tick(self, admissions: List[tuple], t_wall: float) -> None:
+        """One engine tick: admit (shed, prefill) what was popped,
+        dispatch the next chunk, harvest the previous one.  Requeued
+        entries are taken OUT of `admissions` in place, so the caller's
+        failure path fails only what this tick consumed."""
+        jnp = self._jnp
+        t0 = _time.perf_counter()
+        with self._span("engine.admit"):
+            requeued = 0
+            for i, (prompt, n_new, fut, ts, dl, tk) in \
+                    enumerate(admissions):
+                # shed BEFORE the prefill dispatch: an expired (or,
+                # under load, predictably-expiring) request consumes
+                # neither a slot nor a KV block nor a compile
+                if self._maybe_shed(fut, dl, ts, len(prompt), tk):
+                    self._pending_admissions -= 1
+                    continue
+                with self._lock:
+                    if not self._admit(prompt, n_new, fut, ts, tk):
+                        # pool exhausted by LIVE sequences: wait for
+                        # completions, preserving arrival order
+                        requeued = len(admissions) - i
+                        break
+                    self._pending_admissions -= 1
+            if requeued:
+                with self._wake:
+                    self._queue.extendleft(
+                        reversed(admissions[-requeued:])
+                    )
+                    self._pending_admissions = 0
+                del admissions[-requeued:]
+            else:
+                self._pending_admissions = 0
+        t1 = _time.perf_counter()
+        with self._lock:
+            # 0 = nothing live (a live batch needs at least one block)
+            W = self._gather_width() if self._active else 0
+        toks = None
+        if W:
+            with self._span("engine.dispatch", W=W):
+                with self._lock:
+                    tables = np.zeros((self.slots, W), np.int32)
+                    for slot in self._active:
+                        blocks = self._slot_blocks[slot][:W]
+                        tables[slot, :len(blocks)] = blocks
+                self._last_gather_blocks = W
+                cfn = self._chunk_step_for(W)
+                if self._kv_int8:
+                    (self._k_pool, self._v_pool, self._k_scale,
+                     self._v_scale, self._tok, self._pos,
+                     toks) = cfn(
+                        self.params, self._k_pool, self._v_pool,
+                        self._k_scale, self._v_scale,
+                        jnp.asarray(tables), self._tok, self._pos,
+                    )
+                else:
+                    (self._k_pool, self._v_pool, self._tok,
+                     self._pos, toks) = cfn(
+                        self.params, self._k_pool, self._v_pool,
+                        jnp.asarray(tables), self._tok, self._pos,
+                    )
+                if self._decode_kernel == "pallas":
+                    self._decode_kernel_dispatches += 1
+                else:
+                    self._decode_gather_dispatches += 1
+                self._chunk_seq += 1
+                with self._lock:
+                    for req in self._active.values():
+                        req["pos_host"] = min(
+                            req["pos_host"] + self.chunk,
+                            self.max_len - 1,
+                        )
+        # OVERLAP: harvest the PREVIOUS chunk's tokens while the
+        # current chunk computes — the device->host read is round-trip
+        # latency (measured at ~half the synced chunk wall time on an
+        # earlier remote device), and the dispatch above is async, so
+        # the read rides under the compute.  Cost: finish detection
+        # lags one chunk.
+        t2 = _time.perf_counter()
+        if self._pending_toks is not None:
+            p_toks, p_seq = self._pending_toks
+            with self._span("engine.harvest"):
+                toks_host = np.asarray(p_toks)
+                with self._lock:
+                    self._harvest(toks_host, p_seq)
+        self._pending_toks = (
+            (toks, self._chunk_seq) if toks is not None else None
+        )
+        t3 = _time.perf_counter()
+        self._tick_ema_s = (
+            (t3 - t0) if self._tick_ema_s == 0.0
+            else 0.8 * self._tick_ema_s + 0.2 * (t3 - t0)
+        )
+        with self._lock:  # keep the lock-free stats() snapshot
+            # one introspection record per tick (bounded ring;
+            # shipped through stats() -> health piggyback ->
+            # /api/serve for batch-composition postmortems)
+            self._tick_ring.append({
+                "seq": self._chunk_seq,
+                "t_wall": t_wall,  # wall clock at the tick's start
+                "admitted": len(admissions),
+                "active": len(self._active),
+                "queued": len(self._queue),
+                "free_slots": len(self._free),
+                "live_tokens": sum(
+                    r["pos_host"] for r in self._active.values()
+                ),
+                "gather_blocks": W,
+                "kernel": self._decode_kernel,
+                "admit_s": t1 - t0,
+                "dispatch_s": t2 - t1,
+                "harvest_s": t3 - t2,
+                "shed_expired": self._shed_expired,
+                "shed_predicted": self._shed_predicted,
+                "rejected_total": self._rejected_total,
+            })
+            self._stats_snapshot = self._stats_locked()  # fresh
 
     def _loop(self):
         jnp = self._jnp
@@ -1092,7 +1274,8 @@ class LlamaEngine:
             with self._wake:
                 while (self._running and not self._active
                        and not (self._queue and self._free)):
-                    self._wake.wait()
+                    with self._span("engine.wait"):
+                        self._wake.wait()
                 if not self._running:
                     # the engine thread sweeps its own state on exit:
                     # shutdown()'s sweep runs after a BOUNDED join, so
@@ -1120,127 +1303,24 @@ class LlamaEngine:
                     admissions.append(self._queue.popleft())
                 self._pending_admissions = len(admissions)
             try:
-                t0 = _time.perf_counter()
-                requeue = []
-                for i, (prompt, n_new, fut, ts, dl, tk) in \
-                        enumerate(admissions):
-                    # shed BEFORE the prefill dispatch: an expired (or,
-                    # under load, predictably-expiring) request consumes
-                    # neither a slot nor a KV block nor a compile
-                    if self._maybe_shed(fut, dl, tk):
-                        self._pending_admissions -= 1
-                        continue
-                    with self._lock:
-                        if not self._admit(prompt, n_new, fut, ts, tk):
-                            # pool exhausted by LIVE sequences: wait for
-                            # completions, preserving arrival order
-                            requeue = admissions[i:]
-                            break
-                        self._pending_admissions -= 1
-                if requeue:
-                    with self._wake:
-                        self._queue.extendleft(reversed(requeue))
-                        self._pending_admissions = 0
-                    admissions = admissions[:len(admissions) - len(requeue)]
-                else:
-                    self._pending_admissions = 0
-                t1 = _time.perf_counter()
-                with self._lock:
-                    have_active = bool(self._active)
-                    W = self._gather_width() if have_active else 0
-                    if have_active:
-                        tables = np.zeros((self.slots, W), np.int32)
-                        for slot in self._active:
-                            blocks = self._slot_blocks[slot][:W]
-                            tables[slot, :len(blocks)] = blocks
-                toks = None
-                if have_active:
-                    self._last_gather_blocks = W
-                    cfn = self._chunk_step_for(W)
-                    if self._kv_int8:
-                        (self._k_pool, self._v_pool, self._k_scale,
-                         self._v_scale, self._tok, self._pos,
-                         toks) = cfn(
-                            self.params, self._k_pool, self._v_pool,
-                            self._k_scale, self._v_scale,
-                            jnp.asarray(tables), self._tok, self._pos,
-                        )
-                    else:
-                        (self._k_pool, self._v_pool, self._tok,
-                         self._pos, toks) = cfn(
-                            self.params, self._k_pool, self._v_pool,
-                            jnp.asarray(tables), self._tok, self._pos,
-                        )
-                    if self._decode_kernel == "pallas":
-                        self._decode_kernel_dispatches += 1
-                    else:
-                        self._decode_gather_dispatches += 1
-                    self._chunk_seq += 1
-                    with self._lock:
-                        for req in self._active.values():
-                            req["pos_host"] = min(
-                                req["pos_host"] + self.chunk,
-                                self.max_len - 1,
-                            )
-                # OVERLAP: harvest the PREVIOUS chunk's tokens while
-                # the current chunk computes — the device->host read is
-                # round-trip latency (measured at ~half the synced chunk
-                # wall time on an earlier remote device), and the dispatch
-                # above is async, so the read rides under the compute.
-                # Cost: finish detection lags one chunk.
-                t2 = _time.perf_counter()
-                if self._pending_toks is not None:
-                    p_toks, p_seq = self._pending_toks
-                    toks_host = np.asarray(p_toks)
-                    with self._lock:
-                        self._harvest(toks_host, p_seq)
-                self._pending_toks = (
-                    (toks, self._chunk_seq) if toks is not None else None
-                )
-                t3 = _time.perf_counter()
-                self._tick_ema_s = (
-                    (t3 - t0) if self._tick_ema_s == 0.0
-                    else 0.8 * self._tick_ema_s + 0.2 * (t3 - t0)
-                )
-                with self._lock:  # keep the lock-free stats() snapshot
-                    # one introspection record per tick (bounded ring;
-                    # shipped through stats() -> health piggyback ->
-                    # /api/serve for batch-composition postmortems)
-                    self._tick_ring.append({
-                        "seq": self._chunk_seq,
-                        "admitted": len(admissions),
-                        "active": len(self._active),
-                        "queued": len(self._queue),
-                        "free_slots": len(self._free),
-                        "live_tokens": sum(
-                            r["pos_host"] for r in self._active.values()
-                        ),
-                        "gather_blocks": W,
-                        "kernel": self._decode_kernel,
-                        "admit_s": t1 - t0,
-                        "dispatch_s": t2 - t1,
-                        "harvest_s": t3 - t2,
-                        "shed_expired": self._shed_expired,
-                        "shed_predicted": self._shed_predicted,
-                        "rejected_total": self._rejected_total,
-                    })
-                    self._stats_snapshot = self._stats_locked()  # fresh
-                if _TRACE:
-                    with self._lock:
-                        na, nf = len(self._active), len(self._free)
-                        bf = self._pool.free_blocks
-                    print(f"tick adm={len(admissions)} "
-                          f"admit={1e3*(t1-t0):.0f} "
-                          f"dispatch={1e3*(t2-t1):.0f} "
-                          f"read+harvest={1e3*(t3-t2):.0f}ms "
-                          f"W={W} blkfree={bf} "
-                          f"active={na} free={nf}", flush=True)
+                # `wall_ns` anchors the trace's clock (which starts at 0
+                # with the profiler session) to the wall stamps of
+                # tick_ring and request_ring
+                wall_ns = _time.time_ns()
+                with self._span("engine.tick", seq=self._chunk_seq,
+                                active=len(self._active),
+                                admitted=len(admissions),
+                                wall_ns=wall_ns):
+                    self._tick(admissions, wall_ns * 1e-9)
             except Exception as e:  # engine must not die silently
                 logger.exception("llm engine tick failed; failing %d "
                                  "active request(s)", len(self._active))
                 self._pending_toks = None
+                wall = _time.time()
                 with self._lock:
                     for slot, req in list(self._active.items()):
+                        self._record("error", req["t_submit"], wall,
+                                     req["tokens_in"], req)
                         if not req["fut"].done():
                             req["fut"].set_exception(e)
                     # admissions popped from the queue but not (yet)

@@ -342,19 +342,22 @@ def start_request(kind: str, app: str, deployment: str,
 
 
 class EngineTicket:
-    """The engine-side sliver of the ledger: one per admitted request,
-    timestamps assigned on the engine loop thread (plain attribute
-    stores, no allocation), assembled into ledger notes + spans only at
-    the request's terminal tick."""
+    """The engine-side sliver of the ledger: one per admitted request.
+    The engine stamps every request once per boundary for its own
+    `request_ring` and hands the ticket those SAME wall-clock stamps
+    (plain attribute stores, no clock read of the ticket's own), which
+    are assembled into ledger notes + spans only at the request's
+    terminal tick."""
 
     __slots__ = ("ledger", "trace_ctx", "t_submit", "t_admit",
                  "t_prefill_done", "t_first", "t_done", "n_tokens")
 
     def __init__(self, ledger: Optional[RequestLedger],
-                 trace_ctx: Optional[Dict[str, str]]):
+                 trace_ctx: Optional[Dict[str, str]],
+                 t_submit: Optional[float] = None):
         self.ledger = ledger
         self.trace_ctx = trace_ctx
-        self.t_submit = time.time()
+        self.t_submit = time.time() if t_submit is None else t_submit
         self.t_admit = 0.0
         self.t_prefill_done = 0.0
         self.t_first = 0.0
@@ -437,11 +440,13 @@ class EngineTicket:
         }
 
 
-def engine_ticket() -> Optional[EngineTicket]:
-    """Ticket for one engine submit: rides the ambient ledger and/or a
-    sampled ambient trace; None (no allocation) when neither exists."""
+def engine_ticket(t_submit: Optional[float] = None
+                  ) -> Optional[EngineTicket]:
+    """Ticket for one engine submit (`t_submit`: the engine's own
+    submit stamp): rides the ambient ledger and/or a sampled ambient
+    trace; None (no allocation) when neither exists."""
     led = _ledger_var.get()
     ctx = _tracing.current_context() if _tracing.is_enabled() else None
     if led is None and (ctx is None or not ctx.get("trace_id")):
         return None
-    return EngineTicket(led, ctx)
+    return EngineTicket(led, ctx, t_submit)

@@ -1,0 +1,332 @@
+"""The engine's own account of a request and of a tick (no cluster, CPU):
+one lifecycle record per finished request in `stats()["request_ring"]`,
+the engine-loop spans a `jax.profiler` session records on the host
+plane, and the names the engine's programs and kernels lower under."""
+
+import glob
+import pickle
+import re
+import time
+
+import numpy as np
+import pytest
+
+from ray_tpu.metrics import metric_defs as mdefs
+from ray_tpu.serve import request_ledger as rl
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.serve import llm_engine  # noqa: E402
+from ray_tpu.serve.llm_engine import LlamaEngine  # noqa: E402
+
+PHASES = ("queue_s", "prefill_dispatch_s", "first_token_s", "decode_s")
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = llama.LlamaConfig.tiny(vocab_size=128)
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture
+def engine(model):
+    eng = LlamaEngine(*model, slots=2, max_len=48, chunk=2, block_size=8)
+    yield eng
+    eng.shutdown()
+
+
+def _prompt(i, n=12):
+    rng = np.random.RandomState(i)
+    return [int(x) for x in rng.randint(1, 128, size=n)]
+
+
+def _serve(eng, n, new=5, first=0):
+    futs = [eng.submit(_prompt(first + i), new) for i in range(n)]
+    return [f.result(timeout=60) for f in futs]
+
+
+# ----------------------------------------------------------------------
+# A. the lifecycle ring
+# ----------------------------------------------------------------------
+def test_one_record_per_finished_request_and_the_phases_add_up(engine):
+    stamps = []
+    record = engine._record
+
+    def spy(status, t_submit, t_done, *a, **k):
+        stamps.append((t_submit, t_done))
+        return record(status, t_submit, t_done, *a, **k)
+
+    engine._record = spy
+    outs = _serve(engine, 6)
+    s = engine.stats()
+    ring = s["request_ring"]
+    assert [r["seq"] for r in ring] == list(range(1, 7))
+    assert s["finished_total"] == 6 == len(stamps)
+    for r, (t_submit, t_done), out in zip(ring, stamps, outs):
+        assert r["status"] == "ok" and r["t_done"] == t_done
+        assert all(r[k] >= 0.0 for k in PHASES)
+        # queue + (admission -> first token) + decode is the whole of it
+        assert (r["queue_s"] + (r["first_token_s"] - r["queue_s"])
+                + r["decode_s"]) == pytest.approx(t_done - t_submit,
+                                                  abs=1e-9)
+        assert r["queue_s"] + r["prefill_dispatch_s"] <= r["first_token_s"]
+        assert (r["tokens_in"], r["tokens_hit"]) == (12, 0)
+        assert r["tokens_out"] == len(out) == 5
+        # 5 tokens at chunk 2: the first chunk carries the prefill's
+        # token and two more, the second the rest
+        assert r["harvests"] == 2
+
+
+def test_first_token_sample_is_the_records_first_token(engine):
+    _serve(engine, 1)
+    (_, ttft), = engine._ttft_samples
+    assert ttft == engine.stats()["request_ring"][-1]["first_token_s"]
+
+
+def test_a_prefix_hit_is_counted_in_the_record(engine):
+    shared = _prompt(0, 24)
+    for tail in (1, 2):
+        engine.submit(shared + [tail], 3).result(timeout=60)
+    first, second = engine.stats()["request_ring"]
+    assert (first["tokens_in"], first["tokens_hit"]) == (25, 0)
+    assert second["tokens_hit"] == 24  # three blocks of eight
+
+
+def test_shed_and_refused_requests_are_recorded_with_their_status(engine):
+    from ray_tpu.exceptions import BackPressureError, DeadlineExceededError
+
+    # refused by submit(): the budget is already spent
+    with pytest.raises(DeadlineExceededError):
+        engine.submit(_prompt(0), 4, timeout_s=0.0).result(timeout=60)
+    # shed at admission: the predictor says the budget cannot be met
+    engine._ttft_samples.append((time.monotonic(), 9.0))
+    with pytest.raises(DeadlineExceededError):
+        engine.submit(_prompt(1), 4, timeout_s=1.0).result(timeout=60)
+    engine._ttft_samples.clear()
+    engine.begin_drain()
+    with pytest.raises(BackPressureError):
+        engine.submit(_prompt(2), 4).result(timeout=60)
+    ring = engine.stats()["request_ring"]
+    assert [r["status"] for r in ring] == [
+        "expired_at_submit", "shed_predicted", "draining"]
+    for r in ring:
+        assert r["queue_s"] >= 0.0 and r["tokens_in"] == 12
+        assert r["first_token_s"] is None and r["decode_s"] is None
+        assert (r["harvests"], r["tokens_out"]) == (0, 0)
+    assert engine.stats()["shed_total"] == 2
+
+
+def test_the_ring_is_bounded_and_a_pickled_stats_stays_small(engine):
+    assert llm_engine.REQUEST_RING == 512
+    _serve(engine, 2)
+    ok = dict(engine.stats()["request_ring"][-1])
+    # the widest records there are: every phase a float
+    with engine._ring_lock:
+        for _ in range(600):
+            engine._request_ring.append(dict(ok, t_done=time.time()))
+    engine.begin_drain()
+    for i in range(40):
+        engine.submit(_prompt(i), 4)  # refused: one record each
+    s = engine.stats()
+    assert len(s["request_ring"]) == 512
+    assert s["request_ring"][-1]["seq"] == s["finished_total"] == 42
+    assert len(pickle.dumps(s)) < 64 * 1024
+
+
+@pytest.fixture
+def ledger_on():
+    rl._reset_for_tests()
+    mdefs.set_enabled(True)
+    yield
+    mdefs.set_enabled(False)
+    rl._reset_for_tests()
+
+
+def test_a_ticket_and_the_ring_carry_the_same_stamps(engine, ledger_on,
+                                                     monkeypatch):
+    led = rl.start_request("replica", "app", "dep", "r0")
+    tickets = []
+    real = rl.engine_ticket
+
+    def keep(*a, **k):
+        tickets.append(real(*a, **k))
+        return tickets[-1]
+
+    monkeypatch.setattr(rl, "engine_ticket", keep)
+    with rl.use_ledger(led):
+        fut = engine.submit(_prompt(3), 5)
+    fut.result(timeout=60)
+    (tk,), r = tickets, engine.stats()["request_ring"][-1]
+    assert tk.t_done == r["t_done"]
+    assert tk.t_admit - tk.t_submit == r["queue_s"]
+    assert tk.t_prefill_done - tk.t_admit == r["prefill_dispatch_s"]
+    assert tk.t_first - tk.t_submit == r["first_token_s"]
+    assert tk.t_done - tk.t_first == r["decode_s"]
+    assert led.notes["ttft_s"] == r["first_token_s"]
+    assert led.notes["n_tokens"] == r["tokens_out"] == 5
+
+
+def test_no_ticket_is_built_when_the_ledger_is_off(engine, monkeypatch):
+    assert not rl.enabled()
+    built = []
+    real = rl.EngineTicket.__init__
+    monkeypatch.setattr(
+        rl.EngineTicket, "__init__",
+        lambda self, *a, **k: (built.append(1), real(self, *a, **k))[1])
+    _serve(engine, 3)
+    assert not built and engine.stats()["finished_total"] == 3
+
+
+def test_tick_records_carry_their_wall_time(engine):
+    t0 = time.time()
+    _serve(engine, 3)
+    ring = engine.stats()["tick_ring"]
+    walls = [t["t_wall"] for t in ring]
+    assert walls == sorted(walls) and t0 <= walls[0] <= time.time()
+
+
+# ----------------------------------------------------------------------
+# B. the engine-loop spans, under a profiler session
+# ----------------------------------------------------------------------
+def _host_spans(trace_dir):
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))[-1]
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"]
+    assert len(host) == 1
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for line in host[0].lines for e in line.events
+            if e.name.startswith("engine.")]
+
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+def test_a_profiler_session_records_the_loops_spans_nested(engine, tmp_path):
+    _serve(engine, 2)  # compile outside the session
+    t0 = time.time_ns()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve(engine, 4, first=10)  # unshared prompts: no prefix hit
+    finally:
+        jax.profiler.stop_trace()
+    t1 = time.time_ns()
+    spans = _host_spans(tmp_path)
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    assert {"engine.tick", "engine.admit", "engine.prefill",
+            "engine.dispatch", "engine.harvest"} <= set(by)
+    ticks = by["engine.tick"]
+    for _, _, _, st in ticks:
+        assert {"seq", "active", "admitted", "wall_ns"} <= set(st)
+        assert t0 <= st["wall_ns"] <= t1  # the anchor to the wall clock
+    assert sum(st["admitted"] for *_, st in ticks) == 4
+    assert len(by["engine.prefill"]) == 4
+    for _, _, _, st in by["engine.prefill"]:
+        assert st["bucket"] == 16 and st["hit_blocks"] == 0
+        assert st["slot"] in (0, 1)
+    assert all(st["W"] >= 1 for *_, st in by["engine.dispatch"])
+
+    def inside(inner, outers):
+        return any(o[1] <= inner[1] and inner[2] <= o[2] for o in outers)
+
+    # a tick in flight when the session starts or stops is not recorded,
+    # though the phases it ran inside the session are: look between the
+    # first recorded tick's start and the last one's end
+    lo, hi = min(t[1] for t in ticks), max(t[2] for t in ticks)
+    for name in ("engine.admit", "engine.dispatch", "engine.harvest"):
+        whole = [s for s in by[name] if lo <= s[1] and s[2] <= hi]
+        assert whole and all(inside(s, ticks) for s in whole), name
+    assert all(inside(s, by["engine.admit"]) for s in by["engine.prefill"])
+    # blocked on the wake-up: outside every tick
+    assert not any(inside(s, ticks) for s in by.get("engine.wait", []))
+
+
+# ----------------------------------------------------------------------
+# C. names: programs and kernels
+# ----------------------------------------------------------------------
+def test_the_jitted_families_lower_under_their_names(engine):
+    cfg, e = engine.cfg, engine
+    L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    i32 = jnp.int32
+    tables = jnp.zeros((e.slots, 2), i32)
+    chunk = e._chunk_step_for(2).lower(
+        e.params, e._k_pool, e._v_pool, tables, e._tok, e._pos)
+    assert "@jit_decode_chunk_w2" in chunk.as_text()
+    prefill = e._prefill_for(16).lower(e.params, jnp.zeros((1, 16), i32))
+    assert "@jit_prefill_b16" in prefill.as_text()
+    kv1 = jnp.zeros((L, 1, 16, KV, hd), cfg.dtype)
+    write = e._write_blocks_for(16, 2).lower(
+        e._k_pool, e._v_pool, kv1, kv1, jnp.zeros((2,), i32),
+        jnp.asarray(0, i32), jnp.asarray(12, i32), jnp.asarray(1, i32),
+        e._pos, e._tok)
+    assert "@jit_kv_write_t16_n2" in write.as_text()
+    suffix = e._suffix_prefill_for(8, 2).lower(
+        e.params, e._k_pool, e._v_pool, jnp.zeros((1, 8), i32),
+        jnp.zeros((2,), i32), jnp.asarray(16, i32))
+    assert "@jit_suffix_prefill_s8_p2" in suffix.as_text()
+
+
+def _tpu_lowering(fn, *shapes):
+    """Lowered for the TPU from shapes (Mosaic is part of jaxlib; no
+    chip and no libtpu needed), with the ops' name stacks in it."""
+    return jax.jit(fn).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def test_the_paged_kernels_lower_under_their_names():
+    from ray_tpu.ops import paged_attention as pa
+
+    L, NB, BS, KV, HD, B, W, H = 2, 16, 16, 8, 128, 8, 2, 32
+
+    def S(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def step(q, kp, vp, kn, vn, tables, pos):
+        kp, vp = pa.paged_kv_append(kp, vp, kn, vn, tables, pos, 0)
+        return pa.paged_decode_attention(q, kp, vp, tables, pos, 0)
+
+    text = _tpu_lowering(
+        step, S(B, H, HD), S(L, NB, BS, KV, HD), S(L, NB, BS, KV, HD),
+        S(B, KV, HD), S(B, KV, HD), S(B, W, dtype=jnp.int32),
+        S(B, dtype=jnp.int32))
+    # the kernel's `name` is the scope its pallas_call lowers under
+    assert "/paged_kv_append/pallas_call" in text
+    assert "/paged_decode_attention/pallas_call" in text
+
+
+@pytest.mark.parametrize("T,block,names", [
+    (512, 512, ("flash_fwd", "flash_bwd_fused")),
+    (512, 256, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+])
+def test_the_flash_kernels_lower_under_their_names(T, block, names):
+    from ray_tpu.ops import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((1, T, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block,
+                               block_k=block).sum()
+
+    text = _tpu_lowering(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    for name in names:
+        # under grad the scope is wrapped: `jvp(flash_fwd)/pallas_call`
+        assert re.search(rf"[/(]{name}\)*/pallas_call", text), name
+
+
+def test_the_models_block_parts_are_named_scopes(model):
+    from ray_tpu.models import gpt2
+
+    cfg, params = model
+    text = jax.jit(lambda p, t: llama.forward(cfg, p, t)).lower(
+        params, jnp.zeros((1, 8), jnp.int32)).as_text(debug_info=True)
+    for part in ("embed", "attn", "attn/norm", "mlp", "mlp/norm", "lm_head"):
+        assert f"/{part}/" in text, part
+    gcfg = gpt2.GPT2Config.tiny()
+    gparams = gpt2.init_params(gcfg, jax.random.PRNGKey(0))
+    text = jax.jit(lambda p, t: gpt2.forward(gcfg, p, t)).lower(
+        gparams, jnp.zeros((1, 8), jnp.int32)).as_text(debug_info=True)
+    for part in ("embed", "attn", "attn/norm", "mlp", "mlp/norm", "lm_head"):
+        assert f"/{part}/" in text, part
