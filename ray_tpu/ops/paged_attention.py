@@ -19,11 +19,42 @@ engine's per-layer scan never slices (= copies) the pool:
 - `paged_kv_append`: writes one new KV row per sequence into its tail
   block, in place (`input_output_aliases`) — the grid touches ONE
   block per row, replacing the chunk stepper's whole-view scatter.
-- `paged_decode_attention`: grid `(B, W)`; block tables and per-row
-  positions ride in SMEM (`PrefetchScalarGridSpec`), each grid step
-  DMAs pool block `tables[b, w]` and folds it into an online softmax
-  (running max / sum / f32 accumulator in VMEM scratch) — the
-  split-KV combine, one sequential axis per row.
+- `paged_decode_attention`: ONE grid step whose own loops walk rows
+  and, per row, COMPUTE BLOCKS of P pages (`_pages_per_block`: 128
+  tokens = 8 pages at the serving shapes, the whole table where it is
+  narrower).  The walk ends at the row's last live page (`pos[b]`): a
+  row of 200 tokens takes two blocks whether its table is 16, 64 or 81
+  wide, and a page a row does not have is neither copied nor stepped
+  over.  The pools stay in HBM; a block's live pages are copied through
+  the row's table into one of two VMEM tiles (`make_async_copy`, page
+  by page: pages are scattered, so no `BlockSpec` describes the tile)
+  while the block before it is folded, across row boundaries too.  A
+  page is taken as it lies in the pool, `[BS * KV, hd]` rows ordered
+  (token, kv head) — the wrapper's reshape is a bitcast — and the
+  block's tile meets ALL query heads at once: one score product
+  `[H, hd] x tile^T` with other kv heads' columns masked, one exp on
+  full lanes, one value product in which those columns weigh zero, one
+  update of the running max / sum / f32 accumulator.  That spends `KV`
+  times the MXU columns a per-head product would, and removes what a
+  page-at-a-time, head-at-a-time walk spends its time on: per 16
+  tokens eight sublane slices `k[:, h, :]`, eight sliver dots and eight
+  softmax chains on 6%-full registers (2.6 us against 0.08 us of DMA;
+  PERF.md section 6, PR 26).  Tokens past `pos[b]` (in a row's last
+  block) have their scores masked to -1e30 and V's rows to zero, so
+  nothing past the position — table padding, the scratch block, a
+  tile's stale bytes — reaches a result, not even as `0 * NaN`.
+  One result, `[B, H, hd]`: the benchmark finds the kernel in a trace
+  by that shape.  The single grid step is a deliberate trade for the
+  one-core v5e: rows are walked serially, where a ("parallel",
+  "arbitrary") grid over rows would let a two-core chip (v4, v5p)
+  split them.  There, restore it as a grid over halves of the rows
+  (`grid=(2,)`, "parallel", each step walking `B // 2` rows with its
+  own prefetch chain); a step per row would give up the prefetch
+  across rows and pay Pallas's per-step cost 64 times a call.  With
+  MHA (`KV == H`, llama1b4) `KV - 1` of every `KV` score columns are
+  masked; the MXU is idle in decode, so the call still follows the
+  bytes (62% of the HBM peak at llama1b4's shapes, 49% at Mistral's:
+  PERF.md section 5's microbenchmark).
 
 Numerics mirror `llama.decode_step_vec`'s attention exactly in form
 (q.k^T with f32 accumulation, -1e30 mask, softmax weights cast to the
@@ -35,8 +66,21 @@ float rounding and greedy argmax is preserved (pinned by
 Int8 KV rides the same kernels: pools carry int8 payload plus a
 per-row, per-kv-head f32 scale sidecar `[L, num_blocks, block_size,
 KV]` stored blockwise beside the pool; dequantization is fused inside
-the attention kernel (int8 payload is all that crosses HBM) and the
-append kernel writes the quantized row + its scale.
+the attention kernel (K and V cross HBM as int8 and are never written
+back dequantized: a column's K scale multiplies its score, its V scale
+its weight) and the append kernel writes the quantized row + its
+scale.  The SCALES take a longer way than the payload: Mosaic cannot
+cut a `[BS, KV]` page out of a sidecar whose minor dim is narrower
+than a tile, so the wrapper gathers every row's scales through its
+table, `W` pages wide whatever the row holds, into two f32 arrays
+`[B, blocks, 1, P * BS * KV]` (2 MB each at 64 slots and `W` 64; an
+XLA gather that writes them and a kernel that reads the live blocks
+back, per layer call), which is 1/32 of the payload's bytes on a full
+table and more than the payload on a nearly empty one.  On the v5e it
+makes the int8 call SLOWER than the bf16 one and dependent on `W`
+again (170 us at `W` 16, 250 at `W` 64, against 105 for bf16 on the
+same rows; PERF.md section 5): int8 KV buys pool capacity here, not
+time, until the scales lie in the pool in a form the kernel can copy.
 
 The kernels are COMPILED for the TPU unless the caller passes
 `interpret=True` (the CPU tests do); nothing here looks at the backend.
@@ -241,6 +285,23 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
 # ----------------------------------------------------------------------
 # decode attention kernel: split-KV walk over the block table
 # ----------------------------------------------------------------------
+# A compute block is this many tokens of a row, fewer where its score
+# tile [H, tokens * KV] f32 would outgrow the 32 vector registers' worth
+# the softmax works in (which also keeps the four K / V tiles, two of
+# each so that the next block's copies run under this block's
+# arithmetic, within a few MB of VMEM)
+_BLOCK_TOKENS = 128
+_SCORE_TILE_BYTES = 128 * 1024
+
+
+def _pages_per_block(BS, KV, H, W):
+    """Pages folded per step of the walk, from the shapes: at the
+    serving shapes (BS 16, KV 8 or 16, H 16 or 32) 8 pages = 128
+    tokens; the whole table where it is narrower; never less than one."""
+    by_score = _SCORE_TILE_BYTES // (4 * H * KV * BS)
+    return max(1, min(W, _BLOCK_TOKENS // BS, by_score))
+
+
 @functools.lru_cache(maxsize=32)
 def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
                      quantized, interpret):
@@ -250,114 +311,146 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
     group = H // KV
     scale = HD ** -0.5
     q_dt = jnp.dtype(q_dtype)
+    pool_dt = jnp.dtype(pool_dtype)
+    P = _pages_per_block(BS, KV, H, W)
+    T = P * BS   # tokens in a compute block
+    R = BS * KV  # rows of one page: (token, kv head) pairs, token-major
+    C = P * R    # ... and of a compute block: the score tile's columns
+    cap = W * BS - 1  # last position the table can address
 
-    def pool_map(b, w, layer_ref, tables_ref, pos_ref):
-        return (layer_ref[0], tables_ref[b, w], 0, 0, 0)
-
-    def scale_map(b, w, layer_ref, tables_ref, pos_ref):
-        return (layer_ref[0], tables_ref[b, w], 0, 0)
-
-    def q_map(b, w, *_refs):
-        return (b, 0, 0)
-
-    def kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref,
+    def kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
                *rest):
         if quantized:
-            ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+            (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
+             sems) = rest
         else:
-            o_ref, m_ref, l_ref, acc_ref = rest
-        b = pl.program_id(0)
-        w = pl.program_id(1)
-        n_w = pl.num_programs(1)
-        p_b = pos_ref[b]
+            o_ref, k_buf, v_buf, sems = rest
+        layer = layer_ref[0]
 
-        @pl.when(w == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def last_pos(b):
+            # an overshooting finished row attends all W pages, as the
+            # gather route's clamp does
+            return jnp.minimum(pos_ref[b], cap)
 
-        @pl.when(w * BS <= p_b)
-        def _compute():
-            cols = w * BS + jax.lax.broadcasted_iota(
-                jnp.int32, (group, BS), 1
+        def block_copies(b, blk, slot, each):
+            """`each` (start or wait) on every copy of block `blk` of
+            row b: the pages the row HAS there, and no other."""
+            n_live = jnp.minimum(last_pos(b) // BS + 1 - blk * P, P)
+
+            def page_copies(j, _):
+                page = tables_ref[b, blk * P + j]
+                rows = pl.ds(pl.multiple_of(j * R, R), R)
+                each(pltpu.make_async_copy(
+                    k_hbm.at[layer, page], k_buf.at[slot, rows],
+                    sems.at[0, slot]))
+                each(pltpu.make_async_copy(
+                    v_hbm.at[layer, page], v_buf.at[slot, rows],
+                    sems.at[1, slot]))
+
+            jax.lax.fori_loop(0, n_live, page_copies, None)
+            if quantized:
+                each(pltpu.make_async_copy(
+                    ks_hbm.at[b, blk], ks_buf.at[slot], sems.at[0, slot]))
+                each(pltpu.make_async_copy(
+                    vs_hbm.at[b, blk], vs_buf.at[slot], sems.at[1, slot]))
+
+        # column c of a score tile is (token c // KV, kv head c % KV),
+        # as a page's rows lie in the pool; query head r attends
+        # through kv head r // group only
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, C), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, C), 0)
+        own_head = jax.lax.rem(col, KV) == jax.lax.div(row, group)
+        col_tok = jax.lax.div(col, KV)
+        row_tok = jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0), KV)
+
+        def fold(b, i, n_blk, q, carry):
+            """Compute block i of row b into the online softmax; its
+            copies are in flight, the next block's are started here."""
+            m, l, acc, slot = carry
+            last = i + 1 >= n_blk
+            nxt_b = jnp.where(last, b + 1, b)
+
+            @pl.when(nxt_b < B)
+            def _prefetch():
+                block_copies(nxt_b, jnp.where(last, 0, i + 1), 1 - slot,
+                             lambda c: c.start())
+
+            block_copies(b, i, slot, lambda c: c.wait())
+            k, v = k_buf[slot], v_buf[slot]
+            if quantized:
+                # int8 is exact in the compute dtype; a column's scale
+                # multiplies its score and, for V, its weight
+                k, v = k.astype(q_dt), v.astype(q_dt)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            if quantized:
+                s = s * ks_buf[slot]
+            live = last_pos(b) - i * T  # the block's last live token
+            valid = own_head & (col_tok <= live)
+            s = jnp.where(valid, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            # a walked block's first token is live, so m_new is a real
+            # score and a masked column's weight exp(-1e30 - m_new) is 0
+            p = jnp.exp(s - m_new)
+            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            # what lies past the position (in a row's last block: a
+            # page never copied holds any bits, a NaN's too) must not
+            # meet even a zero weight
+            if quantized:
+                p = jnp.where(valid, p * vs_buf[slot], 0.0)
+            v = jnp.where(row_tok <= live, v, jnp.zeros_like(v))
+            # softmax weights cast to the compute dtype for the value
+            # matmul, f32 accumulation — decode_step_vec form; another
+            # head's columns weigh zero, so one product serves all
+            acc = acc * corr + jax.lax.dot_general(
+                p.astype(q_dt), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            valid = cols <= p_b
-            # unrolled kv-head loop: 2-D MXU dots only (batched
-            # dot_general does not lower on TPU Pallas); KV is small
-            for h in range(KV):
-                g0 = h * group
-                if quantized:
-                    kh = (k_ref[:, h, :].astype(jnp.float32)
-                          * ks_ref[:, h][:, None]).astype(q_dt)
-                    vh = (v_ref[:, h, :].astype(jnp.float32)
-                          * vs_ref[:, h][:, None]).astype(q_dt)
-                else:
-                    kh = k_ref[:, h, :]
-                    vh = v_ref[:, h, :]
-                s = jax.lax.dot_general(
-                    q_ref[g0:g0 + group, :], kh,
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale
-                s = jnp.where(valid, s, _NEG_INF)
-                m = m_ref[g0:g0 + group]
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-                corr = jnp.exp(m - m_new)
-                p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-                m_ref[g0:g0 + group] = m_new
-                l_ref[g0:g0 + group] = (
-                    l_ref[g0:g0 + group] * corr + jnp.sum(p, axis=-1)
-                )
-                # softmax weights cast to the compute dtype for the
-                # value matmul, f32 accumulation — decode_step_vec form
-                acc_ref[g0:g0 + group, :] = (
-                    acc_ref[g0:g0 + group, :] * corr[:, None]
-                    + jax.lax.dot_general(
-                        p.astype(q_dt), vh,
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                )
+            return m_new, l, acc, 1 - slot
 
-        @pl.when(w == n_w - 1)
-        def _finalize():
-            l = l_ref[...]
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[...] = (acc_ref[...] / safe_l[:, None]).astype(
-                o_ref.dtype
-            )
+        block_copies(0, 0, 0, lambda c: c.start())
 
-    in_specs = [
-        pl.BlockSpec((None, H, HD), q_map),
-        pl.BlockSpec((None, None, BS, KV, HD), pool_map),
-        pl.BlockSpec((None, None, BS, KV, HD), pool_map),
-    ]
+        def row_body(b, slot):
+            n_blk = last_pos(b) // T + 1
+            q = q_ref[b]
+            _, l, acc, slot = jax.lax.fori_loop(
+                0, n_blk, lambda i, c: fold(b, i, n_blk, q, c),
+                (jnp.full((H, 1), _NEG_INF, jnp.float32),
+                 jnp.zeros((H, 1), jnp.float32),
+                 jnp.zeros((H, HD), jnp.float32), slot))
+            o_ref[b] = (acc / l).astype(o_ref.dtype)
+            return slot
+
+        jax.lax.fori_loop(0, B, row_body, 0)
+
+    whole = pl.BlockSpec((B, H, HD), lambda *_: (0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    scratch = [pltpu.VMEM((2, C, HD), pool_dt)] * 2
     if quantized:
-        in_specs += [
-            pl.BlockSpec((None, None, BS, KV), scale_map),
-            pl.BlockSpec((None, None, BS, KV), scale_map),
-        ]
+        scratch += [pltpu.VMEM((2, 1, C), jnp.float32)] * 2
+    scratch.append(pltpu.SemaphoreType.DMA((2, 2)))  # [K | V, slot]
 
     return pl.pallas_call(
         kernel,
         name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, W),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((None, H, HD), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((H,), jnp.float32),
-                pltpu.VMEM((H,), jnp.float32),
-                pltpu.VMEM((H, HD), jnp.float32),
-            ],
+            # one step: the walk over rows and their blocks is the
+            # kernel's own loop, so it takes no step for a page a row
+            # does not have and prefetches across rows (a trade for the
+            # one-core v5e: see the module docstring)
+            grid=(1,),
+            in_specs=[whole] + [in_hbm] * (4 if quantized else 2),
+            out_specs=whole,
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, HD), q_dt),
         compiler_params=pltpu.CompilerParams(
-            # rows are independent; the block walk carries the online
-            # softmax scratch and must stay sequential
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )
@@ -384,6 +477,21 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
                           jnp.dtype(q.dtype).name, quantized,
                           bool(interpret))
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    if quantized:
-        return fn(layer, tables, pos, q, k_pool, v_pool, k_scale, v_scale)
-    return fn(layer, tables, pos, q, k_pool, v_pool)
+    # a page as its [BS * KV, hd] rows: the same bytes, no copy
+    k_pool = k_pool.reshape(L, NB, BS * KV, HD)
+    v_pool = v_pool.reshape(L, NB, BS * KV, HD)
+    if not quantized:
+        return fn(layer, tables, pos, q, k_pool, v_pool)
+    P = _pages_per_block(BS, KV, H, W)
+
+    def block_rows(scales):
+        # Mosaic cannot slice a page's [BS, KV] scales out of the
+        # sidecar (its minor dim is narrower than a tile), so each
+        # row's are gathered through its table here and laid out as the
+        # score tile's columns: [B, blocks, 1, P * BS * KV]
+        rows = jnp.pad(scales[layer[0]][tables],
+                       ((0, 0), (0, -W % P), (0, 0), (0, 0)))
+        return rows.reshape(B, -1, 1, P * BS * KV)
+
+    return fn(layer, tables, pos, q, k_pool, v_pool, block_rows(k_scale),
+              block_rows(v_scale))

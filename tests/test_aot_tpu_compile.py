@@ -121,11 +121,18 @@ def test_flash_attention_fwd_bwd(chip, B, T, H, D, block):
 # serve path: paged decode attention + in-place KV append
 # ----------------------------------------------------------------------
 # llama1b4's pool as ContinuousLlamaService sizes it (22 layers, block
-# 16, 32 slots), and Llama-3-8B's GQA head layout on the same pool
+# 16, 32 slots), Llama-3-8B's GQA head layout on the same pool, and the
+# benchmark's `mistral-7b-v0.3-l16` engine at the table widths its
+# cells reach (16 closed, 64 chat) and the widest it can (81 =
+# `max_len` 1296 / 16: a multiple of no compute block)
+_MISTRAL = dict(L=16, NB=4096, BS=16, KV=8, HD=128, B=64, H=32)
 _POOLS = {
     "llama1b4": dict(L=22, NB=512, BS=16, KV=16, HD=128, B=32, W=11, H=16),
     "llama3-8b-gqa": dict(L=22, NB=512, BS=16, KV=8, HD=128, B=32, W=11,
                           H=32),
+    "mistral-7b-l16-w16": dict(W=16, **_MISTRAL),
+    "mistral-7b-l16-w64": dict(W=64, **_MISTRAL),
+    "mistral-7b-l16-w81": dict(W=81, **_MISTRAL),
 }
 
 

@@ -56,6 +56,18 @@ def test_compile_cache_placed_from_outside(make_env, outside):
         assert ".jax_cache/" in f.read().split()
 
 
+@pytest.mark.parametrize("make_env", [env_utils.infra_env,
+                                      env_utils.worker_env])
+@pytest.mark.parametrize("outside", [None, "1.0"])
+def test_compile_cache_keeps_quick_programs(make_env, outside):
+    """A program that compiled in under JAX's one second is kept too
+    (most of a replica's set-up is such programs); the caller's own
+    threshold passes through."""
+    name = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+    base = {} if outside is None else {name: outside}
+    assert make_env(base)[name] == (outside or "0")
+
+
 def test_no_code_sets_the_cache_path():
     """Environment only: nothing in the package or the smoke names a
     cache directory through `jax.config`."""

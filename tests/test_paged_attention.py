@@ -58,46 +58,129 @@ def _dense_reference(q, k, v, pos):
     return out
 
 
-def _scatter_pool(rows, tables, NB, BS):
-    """Dense per-seq rows [B, T, KV, hd] -> pool [1, NB, BS, KV, hd]
-    laid out by each row's block table (layer axis size 1)."""
-    B, T, KV, hd = rows.shape
-    pool = np.zeros((1, NB, BS, KV, hd), rows.dtype)
+# block size, table width, kv heads, query heads.  The kernel folds P
+# pages a compute block, P = min(W, 128 // BS) at these sizes: "w3" is
+# one block (the shape this test began with), "w1" / "w2" tables
+# narrower than any block, "w11" 8 + 3 pages and "w81" 32 + 32 + 17 —
+# widths that are a multiple of no P — each as MHA (H == KV) and GQA
+_RAGGED = {
+    "w3-gqa2": (4, 3, 2, 4),
+    "w1-gqa2": (8, 1, 2, 4),
+    "w2-mha": (8, 2, 2, 2),
+    "w11-gqa4": (16, 11, 2, 8),
+    "w11-mha": (16, 11, 4, 4),
+    "w81-gqa4": (4, 81, 2, 8),
+    "w81-mha": (4, 81, 2, 2),
+}
+
+
+def _ragged_case(BS, W, KV, hd, seed, pad_page=0):
+    """A random pool and five rows over it: a row at position 0, one
+    whose last live page is partial, one that fills all W pages, an
+    IDLE row (position 0, its table all scratch padding) and one that
+    ends on a page's first token.  Table entries past a row's live
+    pages hold `pad_page`, as the engine pads with the scratch block."""
+    rng = np.random.default_rng(seed)
+    cap = W * BS
+    pos = np.asarray([0, min(cap - 1, BS * (W - 1) + BS // 2), cap - 1,
+                      0, BS * (W // 2)], np.int32)
+    B = len(pos)
+    NB = 2 + B * W
+    tables = np.full((B, W), pad_page, np.int32)
+    pages = rng.permutation(np.arange(2, NB))
     for b in range(B):
-        for p in range(T):
-            pool[0, tables[b, p // BS], p % BS] = rows[b, p]
-    return pool
+        if b == 3:
+            continue
+        n = pos[b] // BS + 1
+        tables[b, :n], pages = pages[:n], pages[n:]
+    k = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
+    v = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
+    return k, v, tables, pos
 
 
+def _rows(pool, tables):
+    """Each row's dense view [B, W * BS, KV, hd] through its table."""
+    B, W = tables.shape
+    return np.asarray(pool)[0][tables].reshape(
+        (B, -1) + tuple(pool.shape[3:]))
+
+
+def _pools(k, v, dtype, int8):
+    """(pools, scale kwargs, dense f32 pools the kernel should see)."""
+    kd, vd = jnp.asarray(k).astype(dtype), jnp.asarray(v).astype(dtype)
+    if not int8:
+        return (kd, vd), {}, (np.asarray(kd, np.float32),
+                              np.asarray(vd, np.float32))
+    (kq, ks), (vq, vs) = pa.quantize_int8(kd), pa.quantize_int8(vd)
+    return (kq, vq), dict(k_scale=ks, v_scale=vs), (
+        np.asarray(pa.dequantize_int8(kq, ks, jnp.float32)),
+        np.asarray(pa.dequantize_int8(vq, vs, jnp.float32)))
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("shape", sorted(_RAGGED))
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 2e-2)])
-def test_kernel_matches_dense_reference_ragged(dtype, tol):
-    """Ragged positions (different live lengths, partial last blocks,
-    shuffled non-contiguous block tables) against a dense softmax."""
-    B, KV, H, hd, BS, NB = 4, 2, 4, 16, 4, 16
-    W = 3  # per-seq table width: up to 12 tokens
-    rng = np.random.default_rng(7)
-    pos = np.asarray([0, 3, 7, 10], np.int32)  # block counts 1, 1, 2, 3
-    tables = rng.permutation(np.arange(1, 1 + B * W)).reshape(B, W)
-    tables = tables.astype(np.int32)
-    k = rng.standard_normal((B, W * BS, KV, hd)).astype(np.float32)
-    v = rng.standard_normal((B, W * BS, KV, hd)).astype(np.float32)
-    q = rng.standard_normal((B, H, hd)).astype(np.float32)
-    kd = jnp.asarray(k).astype(dtype)
-    vd = jnp.asarray(v).astype(dtype)
+def test_kernel_matches_dense_reference_ragged(dtype, tol, shape, kv):
+    """Ragged positions (different live lengths, partial last pages,
+    shuffled non-contiguous block tables, an idle row) against a dense
+    softmax, at table widths around and between compute blocks."""
+    BS, W, KV, H = _RAGGED[shape]
+    hd = 16
+    k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed=7)
+    (kp, vp), scales, (kf, vf) = _pools(k, v, dtype, kv == "int8")
+    q = np.random.default_rng(8).standard_normal(
+        (len(pos), H, hd)).astype(np.float32)
     qd = jnp.asarray(q).astype(dtype)
-    kp = jnp.asarray(_scatter_pool(np.asarray(kd), tables, NB, BS))
-    vp = jnp.asarray(_scatter_pool(np.asarray(vd), tables, NB, BS))
     out = pa.paged_decode_attention(
         qd, kp, vp, jnp.asarray(tables), jnp.asarray(pos), 0,
-        interpret=True,
+        interpret=True, **scales,
     )
-    assert out.dtype == dtype and out.shape == (B, H, hd)
-    ref = _dense_reference(np.asarray(qd, np.float32),
-                           np.asarray(kd, np.float32),
-                           np.asarray(vd, np.float32), pos)
+    assert out.dtype == dtype and out.shape == q.shape
+    ref = _dense_reference(np.asarray(qd, np.float32), _rows(kf, tables),
+                           _rows(vf, tables), pos)
     np.testing.assert_allclose(np.asarray(out, np.float32), ref,
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("shape", ["w3-gqa2", "w11-gqa4", "w81-mha"])
+def test_kernel_never_reads_dead_pages_into_a_result(shape, kv):
+    """Every page past a row's position — the scratch block its table
+    is padded with, and every pool block no row owns — is poisoned
+    (NaN in K and V; under int8 in the scales, which is where a NaN
+    can live).  A row's result is finite and BIT-equal to the result
+    over a clean pool: dead pages carry no weight because they are
+    never part of the sum, not because their weight rounds to zero."""
+    BS, W, KV, H = _RAGGED[shape]
+    hd = 16
+    k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed=13)
+    live = np.zeros(k.shape[1], bool)
+    for b in range(len(pos)):
+        live[tables[b, :pos[b] // BS + 1]] = b != 3
+    q = jnp.asarray(np.random.default_rng(14).standard_normal(
+        (len(pos), H, hd)), jnp.bfloat16)
+
+    def run(poisoned):
+        (kp, vp), scales, _ = _pools(k, v, jnp.bfloat16, kv == "int8")
+        if poisoned:
+            dead = jnp.asarray(~live)[None, :, None, None]
+            if scales:
+                scales = {n: jnp.where(dead, jnp.nan, s)
+                          for n, s in scales.items()}
+                kp = jnp.where(dead[..., None], 127, kp)
+                vp = jnp.where(dead[..., None], 127, vp)
+            else:
+                kp = jnp.where(dead[..., None], jnp.nan, kp)
+                vp = jnp.where(dead[..., None], jnp.nan, vp)
+        return np.asarray(pa.paged_decode_attention(
+            q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), 0,
+            interpret=True, **scales), np.float32)
+
+    clean, poisoned = run(False), run(True)
+    rows = [0, 1, 2, 4]  # row 3 is idle: it attends the scratch block
+    assert np.isfinite(poisoned[rows]).all()
+    np.testing.assert_array_equal(poisoned[rows], clean[rows])
 
 
 def test_append_writes_one_row_and_preserves_rest():
