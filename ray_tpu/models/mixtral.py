@@ -7,6 +7,11 @@ dense SwiGLU MLP replaced by a top-k routed mixture of experts
 (`parallel/moe.py`: capacity-slot dispatch, Switch-style load-balance
 aux loss, `lax.all_to_all` over the `ep` mesh axis under shard_map).
 
+This is the expert model that TRAINS (capacity slots: a token over an
+expert's capacity is dropped).  The expert layer that SERVES through
+the engine is the dropless one (`parallel/moe.dropless_moe`, under
+`models/deepseek_v3.py`): this file has no prefill or decode.
+
 Same design stance as gpt2/llama: explicit param pytrees + pure
 functions, blocks stacked under `lax.scan` (one compiled block body),
 logical-axis tree so TP/FSDP/EP are rule-table swaps, bf16 compute.

@@ -130,7 +130,7 @@ def dequantize_int8(q: jax.Array, scale: jax.Array, dtype,
 # ----------------------------------------------------------------------
 @functools.lru_cache(maxsize=32)
 def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
-                  quantized, interpret):
+                  quantized, interpret, pools=2):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -141,7 +141,8 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         # (pos past its own allocation) indexes table PADDING (the
         # scratch block) instead of reading out of bounds
         w = jnp.minimum(pos_ref[b] // BS, W - 1)
-        return (layer_ref[0], tables_ref[b, w], 0, 0, 0)
+        # a page is [BS, KV, HD], or [BS, HD] in the one-pool latent form
+        return (layer_ref[0], tables_ref[b, w]) + (0,) * (1 + pools)
 
     def scale_map(b, layer_ref, tables_ref, pos_ref):
         w = jnp.minimum(pos_ref[b] // BS, W - 1)
@@ -207,42 +208,48 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         # prefetch args (megablox gmm convention)
         aliases = {3: 0, 4: 1, 5: 2, 6: 3}
     else:
-        def kernel(layer_ref, tables_ref, pos_ref, kp_ref, vp_ref,
-                   kn_ref, vn_ref, kp_out, vp_out):
+        # `pools`: 2 = the K and V pools `[L, NB, BS, KV, HD]`; 1 = one
+        # latent pool `[L, NB, BS, HD]` (MLA: nothing per head, so a
+        # page is a plain `[BS, HD]` tile and a new row `[1, HD]`)
+        page = (BS, KV, HD) if pools == 2 else (BS, HD)
+        row = (KV, HD) if pools == 2 else (1, HD)
+        def kernel(layer_ref, tables_ref, pos_ref, *refs):
+            ins, news, outs = (refs[:pools], refs[pools:2 * pools],
+                               refs[2 * pools:])
             b = pl.program_id(0)
             p_b = pos_ref[b]
             off = p_b % BS
-            kp_out[...] = kp_ref[...]
-            vp_out[...] = vp_ref[...]
+            if pools == 1:
+                # a `[BS, HD]` page packs two bf16 rows a sublane, and
+                # Mosaic stores a single row only at an offset it can
+                # prove aligned: select the row into the whole page
+                hit = (jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0)
+                       == off) & (p_b < view)
+                outs[0][...] = jnp.where(hit, news[0][...], ins[0][...])
+                return
+            for src, out in zip(ins, outs):
+                out[...] = src[...]
 
             @pl.when(p_b < view)
             def _write():
-                kp_out[pl.ds(off, 1)] = kn_ref[...].reshape(1, KV, HD)
-                vp_out[pl.ds(off, 1)] = vn_ref[...].reshape(1, KV, HD)
+                for new, out in zip(news, outs):
+                    out[pl.ds(off, 1)] = new[...].reshape(1, KV, HD)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
-            in_specs=[
-                pl.BlockSpec((None, None, BS, KV, HD), pool_map),
-                pl.BlockSpec((None, None, BS, KV, HD), pool_map),
-                pl.BlockSpec((None, KV, HD), row_map),
-                pl.BlockSpec((None, KV, HD), row_map),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, None, BS, KV, HD), pool_map),
-                pl.BlockSpec((None, None, BS, KV, HD), pool_map),
-            ],
+            in_specs=(
+                [pl.BlockSpec((None, None) + page, pool_map)] * pools
+                + [pl.BlockSpec((None,) + row, row_map)] * pools),
+            out_specs=[pl.BlockSpec((None, None) + page, pool_map)] * pools,
         )
         out_shape = [
-            jax.ShapeDtypeStruct((L, NB, BS, KV, HD), pool_dtype),
-            jax.ShapeDtypeStruct((L, NB, BS, KV, HD), pool_dtype),
-        ]
-        aliases = {3: 0, 4: 1}
+            jax.ShapeDtypeStruct((L, NB) + page, pool_dtype)] * pools
+        aliases = {3 + i: i for i in range(pools)}
 
     return pl.pallas_call(
         kernel,
-        name="paged_kv_append",
+        name="paged_kv_append" if pools == 2 else "mla_paged_kv_append",
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -282,6 +289,35 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
     return tuple(fn(layer, tables, pos, k_pool, v_pool, k_new, v_new))
 
 
+# A latent pool's rows are padded to whole lanes.  Mosaic cuts a page
+# out of the pool only along whole (8, 128) tiles, and the TPU lays a
+# 576-wide bf16 array out 640 wide in HBM whatever its logical shape, so
+# the padding costs the device no byte a 576-wide pool would not cost;
+# the roofline counts the 576 that carry values.
+MLA_LANES = 128
+
+
+def mla_pool_width(d: int) -> int:
+    return -(-d // MLA_LANES) * MLA_LANES
+
+
+def mla_paged_kv_append(pool, new, tables, pos, layer, *,
+                        interpret: bool = False):
+    """The latent form of `paged_kv_append`: ONE pool `[L, NB, BS, D]`
+    and one new row `new` [B, d] a sequence (d = compressed KV + rotary
+    key, 576 for DeepSeek-V3's attention; D = d rounded up to whole
+    lanes, see `MLA_LANES`; the columns past d are written as zeros).
+    Returns the pool."""
+    L, NB, BS, D = pool.shape
+    B, W = tables.shape
+    new = jnp.pad(new, ((0, 0), (0, D - new.shape[-1])))
+    fn = _build_append(L, NB, BS, 1, D, B, W, jnp.dtype(pool.dtype).name,
+                       jnp.dtype(new.dtype).name, False, bool(interpret),
+                       pools=1)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    return fn(layer, tables, pos, pool, new.reshape(B, 1, D))[0]
+
+
 # ----------------------------------------------------------------------
 # decode attention kernel: split-KV walk over the block table
 # ----------------------------------------------------------------------
@@ -291,40 +327,57 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
 # each so that the next block's copies run under this block's
 # arithmetic, within a few MB of VMEM)
 _BLOCK_TOKENS = 128
+# the latent form's block: one kv "head", so the score tile stays small
+# ([H, 512] f32 = 64 KB) and a longer block spreads the per-block costs
+# (DMA waits, the accumulator's rescale) over more tokens: 0.61 / 0.47 /
+# 0.40 ms a call at 128 / 256 / 512 with 64 rows of 1,500 live tokens
+# (PERF.md section 6, PR 27); its two [512, 640] tiles are 1.3 MB of VMEM
+_MLA_BLOCK_TOKENS = 512
 _SCORE_TILE_BYTES = 128 * 1024
 
 
-def _pages_per_block(BS, KV, H, W):
+def _pages_per_block(BS, KV, H, W, block_tokens=_BLOCK_TOKENS):
     """Pages folded per step of the walk, from the shapes: at the
     serving shapes (BS 16, KV 8 or 16, H 16 or 32) 8 pages = 128
     tokens; the whole table where it is narrower; never less than one."""
     by_score = _SCORE_TILE_BYTES // (4 * H * KV * BS)
-    return max(1, min(W, _BLOCK_TOKENS // BS, by_score))
+    return max(1, min(W, block_tokens // BS, by_score))
 
 
 @functools.lru_cache(maxsize=32)
 def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
-                     quantized, interpret):
+                     quantized, interpret, latent=0, scale=None):
+    """`latent` > 0 is the MLA form: ONE pool whose row is the latent
+    (`KV` 1, `HD` the score width, 576), all `H` query heads score
+    against it, and the VALUE is the first `latent` columns (512) of
+    the very tile the scores were taken on: the pool is read once, no
+    V pool exists, and with one kv head the head mask falls away.
+    `scale` is then the caller's (1 / sqrt(192): the width of the
+    un-absorbed query, not of the latent)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    assert not (latent and (quantized or KV != 1))
     group = H // KV
-    scale = HD ** -0.5
+    scale = HD ** -0.5 if scale is None else scale
+    VD = latent or HD  # width of a value row, and of the result
     q_dt = jnp.dtype(q_dtype)
     pool_dt = jnp.dtype(pool_dtype)
-    P = _pages_per_block(BS, KV, H, W)
+    P = _pages_per_block(BS, KV, H, W,
+                         _MLA_BLOCK_TOKENS if latent else _BLOCK_TOKENS)
     T = P * BS   # tokens in a compute block
     R = BS * KV  # rows of one page: (token, kv head) pairs, token-major
     C = P * R    # ... and of a compute block: the score tile's columns
     cap = W * BS - 1  # last position the table can address
 
-    def kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
-               *rest):
+    def kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, *rest):
         if quantized:
-            (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
+            (v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
              sems) = rest
+        elif latent:
+            o_ref, k_buf, sems = rest
         else:
-            o_ref, k_buf, v_buf, sems = rest
+            v_hbm, o_ref, k_buf, v_buf, sems = rest
         layer = layer_ref[0]
 
         def last_pos(b):
@@ -343,9 +396,10 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
                 each(pltpu.make_async_copy(
                     k_hbm.at[layer, page], k_buf.at[slot, rows],
                     sems.at[0, slot]))
-                each(pltpu.make_async_copy(
-                    v_hbm.at[layer, page], v_buf.at[slot, rows],
-                    sems.at[1, slot]))
+                if not latent:
+                    each(pltpu.make_async_copy(
+                        v_hbm.at[layer, page], v_buf.at[slot, rows],
+                        sems.at[1, slot]))
 
             jax.lax.fori_loop(0, n_live, page_copies, None)
             if quantized:
@@ -377,7 +431,9 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
                              lambda c: c.start())
 
             block_copies(b, i, slot, lambda c: c.wait())
-            k, v = k_buf[slot], v_buf[slot]
+            k = k_buf[slot]
+            # latent: the value is the compressed part of the same rows
+            v = k[:, :latent] if latent else v_buf[slot]
             if quantized:
                 # int8 is exact in the compute dtype; a column's scale
                 # multiplies its score and, for V, its weight
@@ -389,7 +445,9 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
             if quantized:
                 s = s * ks_buf[slot]
             live = last_pos(b) - i * T  # the block's last live token
-            valid = own_head & (col_tok <= live)
+            valid = col_tok <= live
+            if KV > 1:
+                valid = own_head & valid
             s = jnp.where(valid, s, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             corr = jnp.exp(m - m_new)
@@ -421,7 +479,7 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
                 0, n_blk, lambda i, c: fold(b, i, n_blk, q, c),
                 (jnp.full((H, 1), _NEG_INF, jnp.float32),
                  jnp.zeros((H, 1), jnp.float32),
-                 jnp.zeros((H, HD), jnp.float32), slot))
+                 jnp.zeros((H, VD), jnp.float32), slot))
             o_ref[b] = (acc / l).astype(o_ref.dtype)
             return slot
 
@@ -429,14 +487,15 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
 
     whole = pl.BlockSpec((B, H, HD), lambda *_: (0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    scratch = [pltpu.VMEM((2, C, HD), pool_dt)] * 2
+    scratch = [pltpu.VMEM((2, C, HD), pool_dt)] * (1 if latent else 2)
     if quantized:
         scratch += [pltpu.VMEM((2, 1, C), jnp.float32)] * 2
     scratch.append(pltpu.SemaphoreType.DMA((2, 2)))  # [K | V, slot]
 
     return pl.pallas_call(
         kernel,
-        name="paged_decode_attention",
+        name=("mla_paged_decode_attention" if latent
+              else "paged_decode_attention"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             # one step: the walk over rows and their blocks is the
@@ -444,11 +503,12 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
             # does not have and prefetches across rows (a trade for the
             # one-core v5e: see the module docstring)
             grid=(1,),
-            in_specs=[whole] + [in_hbm] * (4 if quantized else 2),
-            out_specs=whole,
+            in_specs=[whole] + [in_hbm] * (
+                4 if quantized else 1 if latent else 2),
+            out_specs=pl.BlockSpec((B, H, VD), lambda *_: (0, 0, 0)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, HD), q_dt),
+        out_shape=jax.ShapeDtypeStruct((B, H, VD), q_dt),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
@@ -495,3 +555,30 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
 
     return fn(layer, tables, pos, q, k_pool, v_pool, block_rows(k_scale),
               block_rows(v_scale))
+
+
+def mla_paged_decode_attention(q, pool, tables, pos, layer, *,
+                               value_dim: int, scale: float,
+                               interpret: bool = False):
+    """One step of ABSORBED latent attention straight off the paged
+    latent pool (DeepSeek-V2/V3's MLA in its decode form).
+
+    q [B, H, D]: per head `q_nope W_uk` (width `value_dim`) beside the
+    rotated `q_rope`; pool [L, NB, BS, D] holds, a token and layer, the
+    normalised compressed KV (first `value_dim` columns) beside the
+    rotated shared key; tables / pos / layer as `paged_decode_attention`
+    (the current row already appended).  Scores `q . row * scale` over
+    the live rows, softmax in float32, and the result is the weighted
+    sum of the rows' first `value_dim` columns: o [B, H, value_dim],
+    which the caller takes through `W_uv`."""
+    L, NB, BS, D = pool.shape
+    B, W = tables.shape
+    H = q.shape[1]
+    # zero query columns meet the pool's zero padding columns
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, D - q.shape[-1])))
+    fn = _build_attention(L, NB, BS, 1, D, B, W, H,
+                          jnp.dtype(pool.dtype).name,
+                          jnp.dtype(q.dtype).name, False, bool(interpret),
+                          latent=int(value_dim), scale=float(scale))
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    return fn(layer, tables, pos, q, pool)

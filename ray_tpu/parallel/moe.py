@@ -7,6 +7,14 @@ capacity factor; tokens are dispatched to their experts' devices with
 resident experts' FFN as one batched matmul (MXU-friendly fixed
 capacity slots — dropped tokens pass through the residual), results
 return via the inverse all-to-all and combine weighted by router probs.
+
+Which expert layer does what: `moe_forward` (capacity slots, softmax
+router, ungated FFN, a token over capacity is dropped) is the one that
+TRAINS (`models/mixtral.py`).  `dropless_moe` at the end of this file
+is the one that SERVES (`models/deepseek_v3.py` through the engine):
+sigmoid scores, every token reaches all of its experts whatever the
+load, SwiGLU experts as grouped products over tokens sorted by expert.
+Do not take one for the other.
 """
 
 from __future__ import annotations
@@ -179,3 +187,106 @@ def _moe_forward_ep(cfg: MoEConfig, params: Dict, x: jax.Array, mesh: Mesh):
     )
     out, aux = fn(params["router"], params["w_in"], params["w_out"], x)
     return out, {"load_balance_loss": jnp.mean(aux)}
+
+
+# ----------------------------------------------------------------------
+# the expert layer that serves: dropless, one chip
+# ----------------------------------------------------------------------
+def sigmoid_topk_route(h, w_router, bias, top_k: int, scale: float):
+    """DeepSeek-V3's `noaux_tc` router with one group: scores
+    `sigmoid(h W_g)` in float32 (matmul precision `highest`: a TPU's
+    default would round the operands to bfloat16), the top `top_k` of
+    `scores + bias` chosen, the weights taken from the scores WITHOUT
+    the bias, normalised and scaled.  h [N, D] -> (weights [N, k] f32,
+    experts [N, k] int32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision="highest"))
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    return w, idx.astype(jnp.int32)
+
+
+def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
+                   interpret: bool = False, stack_index=None):
+    """Rows of `xs` [M, K], sorted by group, each times its group's
+    matrix of `w` [G, K, N]; `group_sizes` [G] sums to M.  A group
+    with no row is never read.  `kernel`: megablox's grouped product
+    (Pallas, TPU); otherwise `lax.ragged_dot`, which every backend
+    lowers.
+
+    `w` may be a whole STACK of layers `[L, G, K, N]` with
+    `stack_index` (traced) naming the layer.  That is how a layer scan
+    hands the kernel its weights: a Pallas call cannot take a
+    `dynamic_slice` of a stack as a fused operand, so slicing the
+    layer out first is a copy of its experts every step (three 403 MB
+    copies a layer at kanana's widths: 20 of a 33 ms decode step;
+    PERF.md section 6, PR 27).  Instead the kernel sees all `L * G`
+    groups, of which only the layer's own have rows; its grid is as
+    long as the tiles that have work, so the other layers cost nothing."""
+    if w.ndim == 4 and not kernel:
+        w = lax.dynamic_index_in_dim(w, stack_index, 0, keepdims=False)
+    if not kernel:
+        return lax.ragged_dot(xs, w, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    if w.ndim == 4:
+        L, G = w.shape[:2]
+        w = w.reshape((L * G,) + w.shape[2:])
+        group_sizes = lax.dynamic_update_slice(
+            jnp.zeros((L * G,), group_sizes.dtype), group_sizes,
+            (stack_index * G,))
+    M, K = xs.shape
+    N = w.shape[-1]
+    # whole K and N in one tile where they fit: 0.57 ms a product at
+    # (128, 2048, 768) against 0.65-0.67 at tk 1024 / 512 (PERF.md, PR 27)
+    tm = 128
+    pad = -M % tm
+    if pad:  # the kernel walks whole row tiles; the tail belongs to no group
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    out = gmm(xs, w, group_sizes, preferred_element_type=xs.dtype,
+              tiling=(tm, min(K, 2048), min(N, 2048)),
+              interpret=interpret)
+    return out[:M] if pad else out
+
+
+def dropless_moe(h, layer: Dict, *, top_k: int, scale: float, dtype,
+                 kernel: bool = False, interpret: bool = False,
+                 stack_index=None):
+    """Routed experts for inference, nothing dropped: h [N, D] ->
+    (y [N, D], stats).  `layer`: `router` [D, E] float32, `router_bias`
+    [E], `e_gate` / `e_up` [E, D, I], `e_down` [E, I, D] — or the three
+    expert leaves as whole stacks `[L, E, ...]` with `stack_index`
+    naming the layer (see `grouped_matmul`).
+
+    The N * k (token, expert) pairs are sorted by expert, so each
+    expert's rows are contiguous and the three SwiGLU products are
+    grouped products over them; the results go back to token order by
+    the inverse permutation (a gather, not a scatter-add) and are
+    summed with the router's weights in float32.  There is no capacity:
+    if one expert gets every token its group is the whole array.  A
+    padding row routes like any other row and changes no other row's
+    result.  `stats`: `experts_touched` (groups with at least one row)
+    and `load_max` (rows of the largest group), int32 scalars."""
+    N, D = h.shape
+    E = layer["router"].shape[-1]
+    with jax.named_scope("moe_router"):
+        w, idx = sigmoid_topk_route(h, layer["router"], layer["router_bias"],
+                                    top_k, scale)
+        flat = idx.reshape(-1)                      # [N * k], pair -> expert
+        order = jnp.argsort(flat, stable=True)      # sorted row -> pair
+        inverse = jnp.argsort(order)                # pair -> sorted row
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    with jax.named_scope("moe_routed"):
+        xs = h.astype(dtype)[order // top_k]        # [N * k, D]
+        mm = lambda a, b: grouped_matmul(  # noqa: E731
+            a, b.astype(dtype), sizes, kernel=kernel, interpret=interpret,
+            stack_index=stack_index)
+        act = jax.nn.silu(mm(xs, layer["e_gate"])) * mm(xs, layer["e_up"])
+        ys = mm(act, layer["e_down"])               # [N * k, D]
+        y = ys[inverse].reshape(N, top_k, D).astype(jnp.float32)
+        y = jnp.sum(y * w[..., None], axis=1).astype(dtype)
+    stats = {"experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
+             "load_max": jnp.max(sizes)}
+    return y, stats

@@ -25,9 +25,29 @@ single scheduler thread — no locks, no device calls.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+import dataclasses
+import math
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 SCRATCH_BLOCK = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLeaf:
+    """One device array of a model's paged cache, as the model's cache
+    spec states it (`serve/engine_model.py`): the pool leaf has shape
+    `[layers, num_blocks, block_size, *tail]`.  `used`: how many of the
+    last dim's values a token really caches where the leaf is padded to
+    the device's tiling (None = all of them); `sidecar`: scales beside
+    a quantized payload."""
+
+    name: str
+    tail: Tuple[int, ...]
+    dtype: Any
+    sidecar: bool = False
+    used: Optional[int] = None
 
 
 KV_DTYPES = ("model", "int8")
@@ -49,7 +69,12 @@ class BlockPool:
     every consumer sizes and interprets the device tensors the same
     way."""
 
-    def __init__(self, num_blocks: int, kv_dtype: str = "model"):
+    def __init__(self, num_blocks: int, kv_dtype: str = "model",
+                 spec: Sequence[CacheLeaf] = ()):
+        """`spec`: the model's cache leaves.  The pool allocates what
+        the spec says (`leaf_shapes`): two per-head pools for a Llama, a
+        single latent pool for an MLA model; block ids, the scratch
+        block and the radix cache do not care which."""
         if num_blocks < 2:
             raise ValueError("block pool needs >= 2 blocks (1 is scratch)")
         if kv_dtype not in KV_DTYPES:
@@ -58,9 +83,25 @@ class BlockPool:
             )
         self.num_blocks = num_blocks
         self.kv_dtype = kv_dtype
+        self.spec: Tuple[CacheLeaf, ...] = tuple(spec)
         # pop() from the tail hands out low ids first (stable layouts
         # across runs -> deterministic tests)
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+
+    def leaf_shapes(self, layers: int, block_size: int) -> List[tuple]:
+        """(shape, dtype) of every device array the spec asks for."""
+        return [((layers, self.num_blocks, block_size) + tuple(leaf.tail),
+                 leaf.dtype) for leaf in self.spec]
+
+    def bytes_per_token(self, layers: int) -> int:
+        """Bytes one cached token costs over all layers, counting the
+        values it caches and not a leaf's tiling pad."""
+        total = 0
+        for leaf in self.spec:
+            width = leaf.tail[-1] if leaf.used is None else leaf.used
+            total += (math.prod(leaf.tail[:-1]) * width
+                      * np.dtype(leaf.dtype).itemsize)
+        return layers * total
 
     @property
     def free_blocks(self) -> int:

@@ -88,6 +88,22 @@ def _cdiv(a: int, b: int) -> int:
 class LlamaEngine:
     """Resident continuous-batching decode engine over a paged KV pool.
 
+    THE MODEL SEAM.  This class is the scheduler: admission, shedding,
+    slots, block tables, the radix prefix cache, the tick, its rings and
+    spans, and the jit / name / donate / LRU bookkeeping of four
+    program families.  What those programs compute, and what a cached
+    token is, belongs to the model behind `serve/engine_model.py`: the
+    engine asks it for its cache spec (`cache_leaves`: the pool leaves
+    and their per-block shapes, which `BlockPool` allocates) and for the
+    bodies of prefill, suffix prefill, KV write and the paged decode
+    chunk, all with flat signatures `(params, *cache, ...)`.  Two
+    implementers, picked by the config's type (`engine_model_for`):
+    `LlamaEngineModel` — per-head K and V pools, the bodies this class
+    always ran, moved unchanged — and `LatentMoeEngineModel` — one
+    latent pool, absorbed decode attention, dropless experts
+    (`models/deepseek_v3.py`).  The class keeps its name; nothing a
+    caller passes changed.
+
     submit() is thread-safe and returns a `concurrent.futures.Future`
     resolving to the generated token ids (greedy — identical to what a
     dedicated `llama.generate` would produce for the same prompt).
@@ -110,9 +126,9 @@ class LlamaEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
+        from ray_tpu.serve.engine_model import engine_model_for
 
-        self._jax, self._jnp, self._llama = jax, jnp, llama
+        self._jax, self._jnp = jax, jnp
         self.cfg = cfg
         self.params = params
         self.slots = slots
@@ -129,11 +145,6 @@ class LlamaEngine:
                 f"kv_blocks={budget} cannot hold one max_len sequence "
                 f"({self._max_seq_blocks} blocks of {self.block_size})"
             )
-        # +1: reserved scratch block.  kv_dtype is validated (and
-        # carried) by the pool: "int8" halves pool HBM and adds the f32
-        # scale sidecar the paged kernels dequant from.
-        self._pool = BlockPool(budget + 1, kv_dtype=kv_dtype)
-        self._kv_int8 = self._pool.kv_dtype == "int8"
         if decode_kernel not in ("auto", "pallas", "gather"):
             raise ValueError(
                 f"decode_kernel={decode_kernel!r} not in "
@@ -157,6 +168,18 @@ class LlamaEngine:
         # True only when the CALLER asks (the CPU kernel tests): the
         # Pallas interpreter is a correctness vehicle, never a default
         self._kernel_interpret = bool(kernel_interpret)
+        # the model's side of the seam: its cache spec and the bodies
+        # of the four program families (`serve/engine_model.py`)
+        self._model = engine_model_for(
+            cfg, slots=slots, max_len=self.max_len, chunk=chunk,
+            block_size=self.block_size, decode_kernel=mode,
+            kv_int8=kv_dtype == "int8",
+            kernel_interpret=self._kernel_interpret)
+        # +1: reserved scratch block.  kv_dtype is validated (and
+        # carried) by the pool: "int8" halves pool HBM and adds the f32
+        # scale sidecar the paged kernels dequant from.
+        self._pool = BlockPool(budget + 1, kv_dtype=kv_dtype,
+                               spec=self._model.cache_leaves)
         if prefix_cache and getattr(cfg, "attention", "dense") != "dense":
             # the suffix prefill (`llama.forward_with_prefix`) mirrors
             # the DENSE attention numerics; under flash/ring/ulysses
@@ -174,21 +197,18 @@ class LlamaEngine:
             else None
         )
 
-        L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        pool_dtype = jnp.int8 if self._kv_int8 else cfg.dtype
-        self._k_pool = jnp.zeros(
-            (L, self._pool.num_blocks, self.block_size, KV, hd), pool_dtype
-        )
-        self._v_pool = jnp.zeros_like(self._k_pool)
-        # int8 scale sidecar: one f32 scale per (layer, row, kv-head),
-        # written by the same paths that write KV rows
-        self._k_scale = self._v_scale = None
-        if self._kv_int8:
-            self._k_scale = jnp.zeros(
-                (L, self._pool.num_blocks, self.block_size, KV),
-                jnp.float32,
-            )
-            self._v_scale = jnp.zeros_like(self._k_scale)
+        # the pool allocates what the model's cache spec says: two
+        # per-head pools (plus the int8 scale sidecars) for a Llama, one
+        # latent pool for an MLA model
+        self._cache = self._alloc_cache()
+        # what the cache costs, fixed at allocation (stats() reports it
+        # every tick): payload bytes, scale-sidecar bytes, and the bytes
+        # one cached token costs by the spec (a tiling pad not counted)
+        self._cache_bytes = tuple(
+            sum(a.nbytes for a, leaf in zip(self._cache, self._pool.spec)
+                if leaf.sidecar == side) for side in (False, True))
+        self._cache_bytes_per_token = self._pool.bytes_per_token(
+            self._model.n_layers)
         self._pos = jnp.zeros((slots,), jnp.int32)
         self._tok = jnp.zeros((slots,), jnp.int32)
 
@@ -300,6 +320,21 @@ class LlamaEngine:
         )
         self._thread.start()
 
+    def _alloc_cache(self) -> tuple:
+        """The device arrays of the model's cache spec, zeroed."""
+        return tuple(
+            self._jnp.zeros(shape, dtype) for shape, dtype in
+            self._pool.leaf_shapes(self._model.n_layers, self.block_size))
+
+    # the first two leaves of a per-head cache, under their old names
+    @property
+    def _k_pool(self):
+        return self._cache[0]
+
+    @property
+    def _v_pool(self):
+        return self._cache[1]
+
     def _warm_kernel_route(self) -> None:
         """Compile and run one all-idle chunk through the kernel route
         before the engine takes requests: every row's table is the
@@ -310,18 +345,9 @@ class LlamaEngine:
         tables = self._jnp.full((self.slots, 1), SCRATCH_BLOCK,
                                 self._jnp.int32)
         cfn = self._chunk_step_for(1)
-        if self._kv_int8:
-            (self._k_pool, self._v_pool, self._k_scale,
-             self._v_scale) = cfn(
-                self.params, self._k_pool, self._v_pool, self._k_scale,
-                self._v_scale, tables, self._tok, self._pos,
-            )[:4]
-        else:
-            self._k_pool, self._v_pool = cfn(
-                self.params, self._k_pool, self._v_pool, tables,
-                self._tok, self._pos,
-            )[:2]
-        self._jax.block_until_ready(self._k_pool)
+        self._cache = tuple(cfn(self.params, *self._cache, tables,
+                                self._tok, self._pos)[:len(self._cache)])
+        self._jax.block_until_ready(self._cache)
 
     # -- public surface ------------------------------------------------
     def retry_after_hint_s(self) -> float:
@@ -489,12 +515,9 @@ class LlamaEngine:
                 "kernel_interpret": self._kernel_interpret,
                 "device": dict(self._device),
                 "kv_dtype": self._pool.kv_dtype,
-                "kv_pool_bytes": (self._k_pool.nbytes
-                                  + self._v_pool.nbytes),
-                "kv_scale_bytes": (
-                    (self._k_scale.nbytes + self._v_scale.nbytes)
-                    if self._kv_int8 else 0
-                ),
+                "kv_pool_bytes": self._cache_bytes[0],
+                "kv_scale_bytes": self._cache_bytes[1],
+                "cache_bytes_per_token": self._cache_bytes_per_token,
                 "decode_kernel_dispatch_total":
                     self._decode_kernel_dispatches,
                 "decode_gather_dispatch_total":
@@ -548,17 +571,17 @@ class LlamaEngine:
     # -- compiled-program families ------------------------------------
     def _chunk_step_for(self, W: int):
         """Chunk stepper for gather width W, under the `decode_kernel`
-        knob:
+        knob (the bodies live behind the seam, `serve/engine_model.py`):
 
-        - "pallas": the fused paged route — `llama.decode_step_paged`
+        - "pallas": the fused paged route — the model's decode step
           reads/writes the pool IN PLACE through the block tables (the
           Pallas kernels in `ops/paged_attention.py`); no gather, no
           scatter, no dense copy.  Per-step HBM traffic is the live KV
           once, not three times.
         - "gather": the reference route — gather every slot's blocks
-          into a dense W-block view, run `llama.decode_step_vec`,
-          scatter the blocks back.  Per-step cost is O(W * block_size)
-          per slot — live tokens, not pool budget.
+          into a dense W-block view, run the model's dense-cache decode
+          step, scatter the blocks back.  Per-step cost is
+          O(W * block_size) per slot — live tokens, not pool budget.
 
         Entries are LRU-bounded at `chunk_cache_cap` programs; an
         evicted width recompiles on next use (degradation, not
@@ -567,171 +590,12 @@ class LlamaEngine:
         if fn is not None:
             self._chunk_cache.move_to_end(W)
             return fn
-        jax, jnp, llama = self._jax, self._jnp, self._llama
-        cfg, bs, chunk = self.cfg, self.block_size, self.chunk
-        L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        S = self.slots
-
-        if self._decode_kernel == "pallas":
-            interp = self._kernel_interpret
-            if self._kv_int8:
-                def _fn(params, k_pool, v_pool, k_scale, v_scale,
-                        tables, tok, pos):
-                    def body(carry, _):
-                        tok, kp, vp, ks, vs, pos = carry
-                        logits, kp, vp, ks, vs = llama.decode_step_paged(
-                            cfg, params, tok, kp, vp, tables, pos,
-                            kv_scales=(ks, vs), interpret=interp,
-                        )
-                        nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        pos2 = jnp.minimum(pos + 1, self.max_len - 1)
-                        return (nt, kp, vp, ks, vs, pos2), nt
-
-                    tok_in = tok
-                    (tok, k_pool, v_pool, k_scale, v_scale, pos), toks = \
-                        jax.lax.scan(
-                            body,
-                            (tok, k_pool, v_pool, k_scale, v_scale, pos),
-                            None, length=chunk,
-                        )
-                    return (k_pool, v_pool, k_scale, v_scale, tok, pos,
-                            jnp.concatenate([tok_in[None], toks], axis=0))
-
-            else:
-                def _fn(params, k_pool, v_pool, tables, tok, pos):
-                    def body(carry, _):
-                        tok, kp, vp, pos = carry
-                        logits, kp, vp = llama.decode_step_paged(
-                            cfg, params, tok, kp, vp, tables, pos,
-                            interpret=interp,
-                        )
-                        nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        # clamp: idle/finished slots must never walk
-                        # their position past the sequence cap
-                        pos2 = jnp.minimum(pos + 1, self.max_len - 1)
-                        return (nt, kp, vp, pos2), nt
-
-                    tok_in = tok  # pre-chunk tokens (see gather route)
-                    (tok, k_pool, v_pool, pos), toks = jax.lax.scan(
-                        body, (tok, k_pool, v_pool, pos), None,
-                        length=chunk,
-                    )
-                    return k_pool, v_pool, tok, pos, jnp.concatenate(
-                        [tok_in[None], toks], axis=0
-                    )
-
-        elif self._kv_int8:
-            from ray_tpu.ops import paged_attention as _pa
-
-            def _fn(params, k_pool, v_pool, k_scale, v_scale, tables,
-                    tok, pos):
-                # gather payload + scales, dequant to the compute dtype
-                kq = jnp.take(k_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-                vq = jnp.take(v_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-                ks = jnp.take(k_scale, tables, axis=1).reshape(
-                    L, S, W * bs, KV
-                )
-                vs = jnp.take(v_scale, tables, axis=1).reshape(
-                    L, S, W * bs, KV
-                )
-                k = _pa.dequantize_int8(kq, ks, cfg.dtype)
-                v = _pa.dequantize_int8(vq, vs, cfg.dtype)
-                pos0 = pos
-
-                def body(carry, _):
-                    tok, kv, pos = carry[0], (carry[1], carry[2]), carry[3]
-                    logits, (k2, v2) = llama.decode_step_vec(
-                        cfg, params, tok, kv, pos
-                    )
-                    nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    pos2 = jnp.minimum(pos + 1, self.max_len - 1)
-                    return (nt, k2, v2, pos2), nt
-
-                tok_in = tok
-                (tok, k, v, pos), toks = jax.lax.scan(
-                    body, (tok, k, v, pos), None, length=chunk
-                )
-                # requantize ONLY the rows this chunk wrote; untouched
-                # rows keep their stored payload+scale bit-exactly, so
-                # repeated gather/scatter cycles cannot drift the cache
-                # (a full-view requant would re-round every row through
-                # the compute dtype each chunk)
-                idx = jnp.arange(W * bs)[None, :]
-                touched = ((idx >= pos0[:, None])
-                           & (idx < pos0[:, None] + chunk))  # [S, M]
-                kq2, ks2 = _pa.quantize_int8(k)
-                vq2, vs2 = _pa.quantize_int8(v)
-                t_p = touched[None, :, :, None, None]
-                t_s = touched[None, :, :, None]
-                kq2 = jnp.where(t_p, kq2, kq)
-                vq2 = jnp.where(t_p, vq2, vq)
-                ks2 = jnp.where(t_s, ks2, ks)
-                vs2 = jnp.where(t_s, vs2, vs)
-                k_pool = k_pool.at[:, tables].set(
-                    kq2.reshape(L, S, W, bs, KV, hd)
-                )
-                v_pool = v_pool.at[:, tables].set(
-                    vq2.reshape(L, S, W, bs, KV, hd)
-                )
-                k_scale = k_scale.at[:, tables].set(
-                    ks2.reshape(L, S, W, bs, KV)
-                )
-                v_scale = v_scale.at[:, tables].set(
-                    vs2.reshape(L, S, W, bs, KV)
-                )
-                return (k_pool, v_pool, k_scale, v_scale, tok, pos,
-                        jnp.concatenate([tok_in[None], toks], axis=0))
-
-        else:
-            def _fn(params, k_pool, v_pool, tables, tok, pos):
-                # tables [slots, W] -> dense [L, slots, W*bs, KV, hd]
-                k = jnp.take(k_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-                v = jnp.take(v_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-
-                def body(carry, _):
-                    tok, kv, pos = carry[0], (carry[1], carry[2]), carry[3]
-                    logits, (k2, v2) = llama.decode_step_vec(
-                        cfg, params, tok, kv, pos
-                    )
-                    nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    # clamp: idle/finished slots must never walk their
-                    # position past the sequence cap
-                    pos2 = jnp.minimum(pos + 1, self.max_len - 1)
-                    return (nt, k2, v2, pos2), nt
-
-                tok_in = tok  # pre-chunk tokens: a freshly admitted
-                # slot's FIRST token (from prefill) — emitting it here
-                # means admission never needs its own device->host read
-                # (one full round trip PER REQUEST)
-                (tok, k, v, pos), toks = jax.lax.scan(
-                    body, (tok, k, v, pos), None, length=chunk
-                )
-                # scatter the (updated) blocks back into the pool.
-                # Shared prefix blocks scatter identical, unmodified
-                # values from every sharer; padding rows target the
-                # scratch block — both make duplicate indices benign.
-                kb = k.reshape(L, S, W, bs, KV, hd)
-                vb = v.reshape(L, S, W, bs, KV, hd)
-                k_pool = k_pool.at[:, tables].set(kb)
-                v_pool = v_pool.at[:, tables].set(vb)
-                # [1 + chunk, slots]: row 0 = pre-chunk tokens
-                return k_pool, v_pool, tok, pos, jnp.concatenate(
-                    [tok_in[None], toks], axis=0
-                )
-
+        _fn = self._model.decode_chunk(W)
         # the name the device trace prints the program under
         # (`jit_decode_chunk_w<W>`): readers match it by prefix
         _fn.__name__ = f"decode_chunk_w{W}"
-        fn = jax.jit(_fn, donate_argnums=(
-            (1, 2, 3, 4) if self._kv_int8 else (1, 2)))
+        fn = self._jax.jit(
+            _fn, donate_argnums=tuple(range(1, 1 + len(self._cache))))
         while len(self._chunk_cache) >= self._chunk_cache_cap:
             old_w, _old = self._chunk_cache.popitem(last=False)
             self._chunk_cache_evictions += 1
@@ -745,22 +609,9 @@ class LlamaEngine:
     def _prefill_for(self, bucket: int):
         fn = self._prefill_cache.get(bucket)
         if fn is None:
-            jax, jnp, llama = self._jax, self._jnp, self._llama
-
-            def _pf(params, prompt):  # prompt [1, bucket]
-                # full-sequence logits (not llama.prefill's last-pos
-                # form): the prompt is right-padded to the bucket, so
-                # the real continuation logit lives at position T-1.
-                # Garbage KV rows written for pad positions stay masked
-                # (pos starts at T) and are overwritten as decoding
-                # advances through them.
-                logits, (ks, vs) = llama.forward(
-                    self.cfg, params, prompt, return_kv=True
-                )
-                return logits[0], ks, vs  # ks/vs [L, 1, bucket, KV, hd]
-
+            _pf = self._model.prefill(bucket)
             _pf.__name__ = f"prefill_b{bucket}"
-            fn = self._prefill_cache[bucket] = jax.jit(_pf)
+            fn = self._prefill_cache[bucket] = self._jax.jit(_pf)
         return fn
 
     def _suffix_prefill_for(self, s_bucket: int, p_blocks: int):
@@ -770,43 +621,9 @@ class LlamaEngine:
         key = (s_bucket, p_blocks)
         fn = self._suffix_cache.get(key)
         if fn is None:
-            jax, jnp, llama = self._jax, self._jnp, self._llama
-            cfg, bs = self.cfg, self.block_size
-            L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-
-            if self._kv_int8:
-                from ray_tpu.ops import paged_attention as _pa
-
-                def _pf(params, k_pool, v_pool, k_scale, v_scale,
-                        suffix, blk_ids, prefix_len):
-                    pk = _pa.dequantize_int8(
-                        jnp.take(k_pool, blk_ids, axis=1),
-                        jnp.take(k_scale, blk_ids, axis=1), cfg.dtype,
-                    ).reshape(L, 1, p_blocks * bs, KV, hd)
-                    pv = _pa.dequantize_int8(
-                        jnp.take(v_pool, blk_ids, axis=1),
-                        jnp.take(v_scale, blk_ids, axis=1), cfg.dtype,
-                    ).reshape(L, 1, p_blocks * bs, KV, hd)
-                    logits, (ks, vs) = llama.forward_with_prefix(
-                        cfg, params, suffix, (pk, pv), prefix_len
-                    )
-                    return logits[0], ks, vs
-            else:
-                def _pf(params, k_pool, v_pool, suffix, blk_ids,
-                        prefix_len):
-                    pk = jnp.take(k_pool, blk_ids, axis=1).reshape(
-                        L, 1, p_blocks * bs, KV, hd
-                    )
-                    pv = jnp.take(v_pool, blk_ids, axis=1).reshape(
-                        L, 1, p_blocks * bs, KV, hd
-                    )
-                    logits, (ks, vs) = llama.forward_with_prefix(
-                        cfg, params, suffix, (pk, pv), prefix_len
-                    )
-                    return logits[0], ks, vs
-
+            _pf = self._model.suffix_prefill(s_bucket, p_blocks)
             _pf.__name__ = f"suffix_prefill_s{s_bucket}_p{p_blocks}"
-            fn = self._suffix_cache[key] = jax.jit(_pf)
+            fn = self._suffix_cache[key] = self._jax.jit(_pf)
         return fn
 
     def _write_blocks_for(self, t_in: int, nb: int):
@@ -818,65 +635,10 @@ class LlamaEngine:
         key = (t_in, nb)
         fn = self._write_cache.get(key)
         if fn is None:
-            jax, jnp = self._jax, self._jnp
-            bs = self.block_size
-            L, KV, hd = (self.cfg.n_layers, self.cfg.n_kv_heads,
-                         self.cfg.head_dim)
-            target = nb * bs
-
-            def _clip(k1, v1):
-                # k1/v1 [L, 1, t_in, KV, hd] -> exactly nb blocks
-                if t_in < target:
-                    pad = [(0, 0), (0, 0), (0, target - t_in), (0, 0),
-                           (0, 0)]
-                    return jnp.pad(k1, pad), jnp.pad(v1, pad)
-                if t_in > target:
-                    return k1[:, :, :target], v1[:, :, :target]
-                return k1, v1
-
-            if self._kv_int8:
-                from ray_tpu.ops import paged_attention as _pa
-
-                def _fn(k_pool, v_pool, k_scale, v_scale, k1, v1,
-                        blk_ids, slot, pos0, tok0, pos, tok):
-                    k1, v1 = _clip(k1, v1)
-                    kq, ksc = _pa.quantize_int8(k1)  # [L,1,target,KV]
-                    vq, vsc = _pa.quantize_int8(v1)
-                    k_pool = k_pool.at[:, blk_ids].set(
-                        kq.reshape(L, nb, bs, KV, hd)
-                    )
-                    v_pool = v_pool.at[:, blk_ids].set(
-                        vq.reshape(L, nb, bs, KV, hd)
-                    )
-                    k_scale = k_scale.at[:, blk_ids].set(
-                        ksc.reshape(L, nb, bs, KV)
-                    )
-                    v_scale = v_scale.at[:, blk_ids].set(
-                        vsc.reshape(L, nb, bs, KV)
-                    )
-                    pos = pos.at[slot].set(pos0)
-                    tok = tok.at[slot].set(tok0)
-                    return k_pool, v_pool, k_scale, v_scale, pos, tok
-
-            else:
-                def _fn(k_pool, v_pool, k1, v1, blk_ids, slot, pos0,
-                        tok0, pos, tok):
-                    k1, v1 = _clip(k1, v1)
-                    kb = k1.astype(k_pool.dtype).reshape(
-                        L, nb, bs, KV, hd
-                    )
-                    vb = v1.astype(v_pool.dtype).reshape(
-                        L, nb, bs, KV, hd
-                    )
-                    k_pool = k_pool.at[:, blk_ids].set(kb)
-                    v_pool = v_pool.at[:, blk_ids].set(vb)
-                    pos = pos.at[slot].set(pos0)
-                    tok = tok.at[slot].set(tok0)
-                    return k_pool, v_pool, pos, tok
-
+            _fn = self._model.kv_write(t_in, nb)
             _fn.__name__ = f"kv_write_t{t_in}_n{nb}"
-            fn = self._write_cache[key] = jax.jit(_fn, donate_argnums=(
-                (0, 1, 2, 3) if self._kv_int8 else (0, 1)))
+            fn = self._write_cache[key] = self._jax.jit(
+                _fn, donate_argnums=tuple(range(len(self._cache))))
         return fn
 
     # -- request lifecycle ring ------------------------------------------
@@ -1007,22 +769,15 @@ class LlamaEngine:
                     [prompt[P:] + [0] * (bucket - S)], jnp.int32
                 )
                 sfn = self._suffix_prefill_for(bucket, p_bucket)
-                if self._kv_int8:
-                    logits, k1, v1 = sfn(
-                        self.params, self._k_pool, self._v_pool,
-                        self._k_scale, self._v_scale, suffix, blk_ids,
-                        jnp.asarray(P, jnp.int32),
-                    )
-                else:
-                    logits, k1, v1 = sfn(
-                        self.params, self._k_pool, self._v_pool, suffix,
-                        blk_ids, jnp.asarray(P, jnp.int32),
-                    )
+                logits, *kv = sfn(
+                    self.params, *self._cache, suffix, blk_ids,
+                    jnp.asarray(P, jnp.int32),
+                )
                 self._hit_tokens += P
                 self._prefix_hits += 1
             else:
                 padded = prompt + [0] * (bucket - T)
-                logits, k1, v1 = self._prefill_for(bucket)(
+                logits, *kv = self._prefill_for(bucket)(
                     self.params, jnp.asarray([padded], jnp.int32)
                 )
             # first generated token comes from the LAST REAL prompt
@@ -1039,24 +794,15 @@ class LlamaEngine:
             self._prefill_tokens += S
             self._prefill_calls += 1
             wfn = self._write_blocks_for(bucket, nb_real)
-            if self._kv_int8:
-                (self._k_pool, self._v_pool, self._k_scale,
-                 self._v_scale, self._pos, self._tok) = wfn(
-                    self._k_pool, self._v_pool, self._k_scale,
-                    self._v_scale, k1, v1,
-                    jnp.asarray(write_ids, jnp.int32),
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(T, jnp.int32),
-                    tok0, self._pos, self._tok,
-                )
-            else:
-                self._k_pool, self._v_pool, self._pos, self._tok = wfn(
-                    self._k_pool, self._v_pool, k1, v1,
-                    jnp.asarray(write_ids, jnp.int32),
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(T, jnp.int32),
-                    tok0, self._pos, self._tok,
-                )
+            out = wfn(
+                *self._cache, *kv,
+                jnp.asarray(write_ids, jnp.int32),
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(T, jnp.int32),
+                tok0, self._pos, self._tok,
+            )
+            self._cache = tuple(out[:-2])
+            self._pos, self._tok = out[-2:]
 
         # donate this prompt's full blocks to the radix cache (pinned
         # until completion); blocks the trie adopts stop being
@@ -1197,20 +943,10 @@ class LlamaEngine:
                         tables[slot, :len(blocks)] = blocks
                 self._last_gather_blocks = W
                 cfn = self._chunk_step_for(W)
-                if self._kv_int8:
-                    (self._k_pool, self._v_pool, self._k_scale,
-                     self._v_scale, self._tok, self._pos,
-                     toks) = cfn(
-                        self.params, self._k_pool, self._v_pool,
-                        self._k_scale, self._v_scale,
-                        jnp.asarray(tables), self._tok, self._pos,
-                    )
-                else:
-                    (self._k_pool, self._v_pool, self._tok,
-                     self._pos, toks) = cfn(
-                        self.params, self._k_pool, self._v_pool,
-                        jnp.asarray(tables), self._tok, self._pos,
-                    )
+                out = cfn(self.params, *self._cache, jnp.asarray(tables),
+                          self._tok, self._pos)
+                self._cache = tuple(out[:-3])
+                self._tok, self._pos, toks = out[-3:]
                 if self._decode_kernel == "pallas":
                     self._decode_kernel_dispatches += 1
                 else:
@@ -1229,10 +965,15 @@ class LlamaEngine:
         # the read rides under the compute.  Cost: finish detection
         # lags one chunk.
         t2 = _time.perf_counter()
+        model_fields: Dict[str, object] = {}
         if self._pending_toks is not None:
             p_toks, p_seq = self._pending_toks
             with self._span("engine.harvest"):
                 toks_host = np.asarray(p_toks)
+                if self._model.aux_rows:
+                    rows = 1 + self.chunk
+                    model_fields = self._model.tick_fields(toks_host[rows:])
+                    toks_host = toks_host[:rows]
                 with self._lock:
                     self._harvest(toks_host, p_seq)
         self._pending_toks = (
@@ -1265,6 +1006,9 @@ class LlamaEngine:
                 "shed_expired": self._shed_expired,
                 "shed_predicted": self._shed_predicted,
                 "rejected_total": self._rejected_total,
+                # the model's own counters of the chunk harvested in
+                # this tick (`engine_model.tick_fields`; none for Llama)
+                **model_fields,
             })
             self._stats_snapshot = self._stats_locked()  # fresh
 
@@ -1337,28 +1081,16 @@ class LlamaEngine:
                     # block returns to the pool and the radix cache
                     # empties (its pinned paths died with the requests)
                     self._pool = BlockPool(self._pool.num_blocks,
-                                           kv_dtype=self._pool.kv_dtype)
+                                           kv_dtype=self._pool.kv_dtype,
+                                           spec=self._pool.spec)
                     if self._radix is not None:
                         self._radix = RadixCache(
                             self.block_size, self._pool
                         )
                 # the failed tick may have DONATED pool buffers without
-                # ever rebinding them — rebuild the device state (int8
-                # scale sidecars included: they are donated too) or
-                # every later dispatch dies on invalid donated buffers
-                self._k_pool = jnp.zeros(
-                    (self.cfg.n_layers, self._pool.num_blocks,
-                     self.block_size, self.cfg.n_kv_heads,
-                     self.cfg.head_dim),
-                    jnp.int8 if self._kv_int8 else self.cfg.dtype,
-                )
-                self._v_pool = jnp.zeros_like(self._k_pool)
-                if self._kv_int8:
-                    self._k_scale = jnp.zeros(
-                        (self.cfg.n_layers, self._pool.num_blocks,
-                         self.block_size, self.cfg.n_kv_heads),
-                        jnp.float32,
-                    )
-                    self._v_scale = jnp.zeros_like(self._k_scale)
+                # ever rebinding them — rebuild the device state (every
+                # cache leaf: sidecars are donated too) or every later
+                # dispatch dies on invalid donated buffers
+                self._cache = self._alloc_cache()
                 self._pos = jnp.zeros((self.slots,), jnp.int32)
                 self._tok = jnp.zeros((self.slots,), jnp.int32)

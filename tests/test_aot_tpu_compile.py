@@ -238,3 +238,62 @@ def test_llama1b4_prefill_and_decode_step(chip):
         params, _s(B, dtype=jnp.int32), cache, cache, _s(dtype=jnp.int32),
         donate_argnums=(2, 3),
     )
+
+
+# ----------------------------------------------------------------------
+# serve path, latent attention + experts: `kanana-2-30b-a3b-l7`'s engine
+# ----------------------------------------------------------------------
+# one latent pool [7, 9217, 16, 640] (576 values lane-padded), 64 slots,
+# 32 heads; table widths the cell reaches (32 ... 145 = `max_len` 2320 /
+# 16, a multiple of no compute block)
+_KANANA = dict(L=7, NB=9217, BS=16, D=640, B=64, H=32)
+
+
+@pytest.mark.parametrize("W", [32, 145])
+def test_mla_paged_kernels(chip, W):
+    """A 576-wide page is refused ("slice shape ... must be aligned to
+    tiling (128)"), which is why the pool is 640 wide; a one-row store
+    at a dynamic offset into a `[16, 640]` bf16 page is refused too,
+    which is why the append selects the row into the whole page."""
+    d = _KANANA
+    pool = _s(d["L"], d["NB"], d["BS"], d["D"])
+    tables, pos = _s(d["B"], W, dtype=jnp.int32), _s(d["B"], dtype=jnp.int32)
+
+    def attend(q, pool, tables, pos):
+        return pa.mla_paged_decode_attention(
+            q, pool, tables, pos, 3, value_dim=512, scale=192 ** -0.5)
+
+    hlo = _compile(chip, attend, _s(d["B"], d["H"], 576), pool, tables, pos)
+    assert "tpu_custom_call" in hlo and "bf16[64,32,512]" in hlo
+
+    def append(pool, new, tables, pos):
+        return pa.mla_paged_kv_append(pool, new, tables, pos, 3)
+
+    hlo = _compile(chip, append, pool, _s(d["B"], 576), tables, pos,
+                   donate_argnums=(0,))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("rows", [64, 2048], ids=["decode", "prefill-2048"])
+def test_dropless_expert_layer_with_grouped_kernel(chip, rows):
+    """The serving expert layer at kanana's widths with megablox's
+    grouped product: 384 (token, expert) rows of a decode step, 12,288
+    of a 2,048-token prefill, over 128 groups."""
+    from ray_tpu.parallel import moe
+
+    D, I, E, L = 2048, 768, 128, 6
+    layer = {"router": _s(D, E, dtype=jnp.float32),
+             "router_bias": _s(E, dtype=jnp.float32),
+             # whole stacks, as the layer scan hands them over: the kernel
+             # picks its layer by index, no 403 MB slice is copied
+             "e_gate": _s(L, E, D, I), "e_up": _s(L, E, D, I),
+             "e_down": _s(L, E, I, D)}
+
+    def fn(h, layer, index):
+        return moe.dropless_moe(h, layer, top_k=6, scale=2.448,
+                                dtype=BF16, kernel=True, stack_index=index)
+
+    hlo = _compile(chip, fn, _s(rows, D), layer, _s(dtype=jnp.int32))
+    assert hlo.count("tpu_custom_call") >= 3
+    # no copy of a layer's experts beside the kernels
+    assert "bf16[128,2048,768]" not in hlo and "bf16[128,768,2048]" not in hlo
