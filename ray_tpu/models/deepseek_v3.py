@@ -225,9 +225,11 @@ def _attend_expanded(cfg, q, k, v, mask):
     return o.astype(cfg.dtype).reshape(o.shape[:2] + (-1,))
 
 
-def _ffn(cfg, layer, x, *, kernel=False, interpret=False, stack_index=None):
+def _ffn(cfg, layer, x, *, kernel=False, interpret=False, stack_index=None,
+         row_mask=None):
     """The second half of a layer: dense SwiGLU, or routed experts plus
-    the shared expert.  x [..., D] -> (x + y, stats or None)."""
+    the shared expert.  x [..., D] -> (x + y, stats or None).
+    `row_mask` (one bool a row of the flattened x): see `dropless_moe`."""
     h = _rms_norm(x, layer["mlp_norm"].astype(cfg.dtype), cfg.norm_eps)
     if "router" not in layer:
         with jax.named_scope("dense_mlp"):
@@ -237,7 +239,7 @@ def _ffn(cfg, layer, x, *, kernel=False, interpret=False, stack_index=None):
     y, stats = dropless_moe(flat, layer, top_k=cfg.top_k,
                             scale=cfg.routed_scale, dtype=cfg.dtype,
                             kernel=kernel, interpret=interpret,
-                            stack_index=stack_index)
+                            stack_index=stack_index, row_mask=row_mask)
     with jax.named_scope("moe_shared"):
         y = y.reshape(h.shape) + _swiglu(h, layer["s_gate"], layer["s_up"],
                                          layer["s_down"], cfg.dtype)
@@ -346,8 +348,8 @@ def _absorbed_dense(cfg, q, rows, pos):
 
 
 def decode_step(cfg: DeepseekV3Config, params: Dict, token: jax.Array,
-                cache, pos, *, tables=None, kernel: bool = False,
-                interpret: bool = False):
+                cache, pos, *, tables=None, live=None,
+                kernel: bool = False, interpret: bool = False):
     """One decode step at per-row positions.  token [B], pos [B].
 
     `tables` given: `cache` is the paged latent pool `[L, NB, BS, Dp]`
@@ -357,12 +359,23 @@ def decode_step(cfg: DeepseekV3Config, params: Dict, token: jax.Array,
     way the attention is the absorbed form.  Returns (logits [B,
     vocab] float32, cache, stats) with `stats` = `experts_touched`
     (distinct (layer, expert) pairs this step) and `load_max` (most
-    rows any one expert got)."""
+    rows any one expert got).
+
+    `live` [B] bool (the engine's `pos < stop`): a row that is not live
+    writes nothing into the cache, attends nothing on the paged route
+    (`dead_row_positions`) and is routed to no expert, so `stats`
+    count live rows only."""
     H, n, r = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
     x = _embed(params, token, cfg.dtype).astype(cfg.dtype)  # [B, D]
     if tables is None:
         M = cache.shape[2]
-        write = (jnp.arange(M)[None, :] == pos[:, None])[:, :, None]
+        write = jnp.arange(M)[None, :] == pos[:, None]
+        if live is not None:
+            write = write & live[:, None]
+        write = write[:, :, None]
+    else:  # where a row appends, how far it attends
+        w_pos, a_pos = _pa.dead_row_positions(pos, live, tables,
+                                              cache.shape[2])
 
     def body(experts, l0, carry, inputs):
         x, cache = carry
@@ -377,10 +390,10 @@ def decode_step(cfg: DeepseekV3Config, params: Dict, token: jax.Array,
             q = jnp.concatenate([q_lat.astype(cfg.dtype), q_rope], axis=-1)
             if tables is not None:
                 cache = _pa.mla_paged_kv_append(
-                    cache, new.astype(cache.dtype), tables, pos, li,
+                    cache, new.astype(cache.dtype), tables, w_pos, li,
                     interpret=interpret)
                 o_lat = _pa.mla_paged_decode_attention(
-                    q, cache, tables, pos, li, value_dim=r,
+                    q, cache, tables, a_pos, li, value_dim=r,
                     scale=cfg.qk_head_dim ** -0.5, interpret=interpret)
             else:
                 new = jnp.pad(new, ((0, 0), (0, cache.shape[-1]
@@ -395,7 +408,7 @@ def decode_step(cfg: DeepseekV3Config, params: Dict, token: jax.Array,
             x = x + _apply(o.astype(cfg.dtype).reshape(o.shape[0], -1),
                            layer["wo"], cfg.dtype)
         x, stats = _ffn(cfg, {**layer, **experts}, x, kernel=kernel,
-                        interpret=interpret, stack_index=i)
+                        interpret=interpret, stack_index=i, row_mask=live)
         return (x, cache), stats
 
     touched = jnp.zeros((), jnp.int32)

@@ -592,7 +592,7 @@ def _rope_at(x, theta: float, pos_b):
 
 
 def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
-                    cache, pos):
+                    cache, pos, live=None):
     """One decode step with PER-ROW positions (continuous batching:
     every slot advances at its own length; reference capability: the
     vLLM-on-Ray serving pattern's step-level scheduling).
@@ -603,7 +603,10 @@ def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
     so a slot's tokens are identical to what a dedicated `generate`
     would produce.  Deliberately duplicates `decode_step`'s body (see
     its SYNC CONTRACT note): the masked-select write here must not tax
-    the scalar path, and the parity test pins the two together."""
+    the scalar path, and the parity test pins the two together.
+
+    `live` [B] bool (the engine's `pos < stop`): a row that is not
+    live writes nothing into the cache; what it computes is nobody's."""
     k_cache, v_cache = cache  # [L, B, M, KV, hd]
     B = token.shape[0]
     M = k_cache.shape[2]
@@ -620,7 +623,10 @@ def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
     # the layer scan (measured ~30x the whole step's bandwidth cost);
     # the select is one dense read+write of the cache the step already
     # reads anyway.
-    write = (jnp.arange(M)[None, :] == pos[:, None])[:, :, None, None]
+    write = jnp.arange(M)[None, :] == pos[:, None]
+    if live is not None:
+        write = write & live[:, None]
+    write = write[:, :, None, None]
 
     def body(x, inputs):
         layer, kc, vc = inputs  # kc/vc [B, M, KV, hd]
@@ -665,7 +671,7 @@ def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
 
 def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
                       k_pool, v_pool, tables, pos, *, kv_scales=None,
-                      interpret: bool = False):
+                      live=None, interpret: bool = False):
     """One decode step with PER-ROW positions straight off the paged
     KV pool — `decode_step_vec` with the dense gather/scatter replaced
     by the Pallas kernels in `ops/paged_attention.py`.
@@ -679,6 +685,8 @@ def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
     walks each row's blocks with an online softmax.  Returns
     (logits [B, vocab] f32, k_pool, v_pool) — plus the updated
     (k_scale, v_scale) sidecar when `kv_scales` is given (int8 pools).
+    `live` [B] bool (the engine's `pos < stop`): a row that is not live
+    appends nothing and attends nothing (`dead_row_positions`).
 
     Numerics mirror `decode_step_vec` (write-then-attend, f32 score
     accumulation, -1e30 mask, f32 softmax, weights cast to cfg.dtype
@@ -692,6 +700,8 @@ def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
     L = k_pool.shape[0]
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     quant = kv_scales is not None
+    # where a row appends, how far it attends
+    w_pos, a_pos = _pa.dead_row_positions(pos, live, tables, k_pool.shape[2])
 
     x = _embed(params, token, cfg.dtype)[:, None, :]  # [B,1,d]
 
@@ -714,22 +724,22 @@ def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
                 kq, ks_new = _pa.quantize_int8(k_new[:, 0])
                 vq, vs_new = _pa.quantize_int8(v_new[:, 0])
                 kp, vp, ks, vs = _pa.paged_kv_append(
-                    kp, vp, kq, vq, tables, pos, li,
+                    kp, vp, kq, vq, tables, w_pos, li,
                     k_scale=ks, v_scale=vs, k_new_scale=ks_new,
                     v_new_scale=vs_new, interpret=interpret,
                 )
                 o = _pa.paged_decode_attention(
-                    q[:, 0], kp, vp, tables, pos, li,
+                    q[:, 0], kp, vp, tables, a_pos, li,
                     k_scale=ks, v_scale=vs, interpret=interpret,
                 )
             else:
                 kp, vp = _pa.paged_kv_append(
                     kp, vp, k_new[:, 0].astype(kp.dtype),
-                    v_new[:, 0].astype(vp.dtype), tables, pos, li,
+                    v_new[:, 0].astype(vp.dtype), tables, w_pos, li,
                     interpret=interpret,
                 )
                 o = _pa.paged_decode_attention(
-                    q[:, 0], kp, vp, tables, pos, li, interpret=interpret,
+                    q[:, 0], kp, vp, tables, a_pos, li, interpret=interpret,
                 )
             o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
             x1 = x + _apply(o, layer["wo"], cfg.dtype,

@@ -25,7 +25,12 @@ engine's per-layer scan never slices (= copies) the pool:
   narrower).  The walk ends at the row's last live page (`pos[b]`): a
   row of 200 tokens takes two blocks whether its table is 16, 64 or 81
   wide, and a page a row does not have is neither copied nor stepped
-  over.  The pools stay in HBM; a block's live pages are copied through
+  over.  A DEAD row, one that owes no token in this step (the engine's
+  `pos >= stop`), is handed position -1 and has no live page: no copy,
+  no block, a row of zeros out, and the next row's first copies start
+  in its place; the append kernel is handed a position past the
+  table's reach and writes nothing for it (`dead_row_positions`).  The
+  pools stay in HBM; a block's live pages are copied through
   the row's table into one of two VMEM tiles (`make_async_copy`, page
   by page: pages are scattered, so no `BlockSpec` describes the tile)
   while the block before it is folded, across row boundaries too.  A
@@ -137,9 +142,10 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
     view = W * BS  # positions the W-wide table can address
 
     def pool_map(b, layer_ref, tables_ref, pos_ref):
-        # tail block of row b; clamped so an overshooting finished row
-        # (pos past its own allocation) indexes table PADDING (the
-        # scratch block) instead of reading out of bounds
+        # tail block of row b; clamped so that a position past the
+        # table's reach (a dead row's: `dead_row_positions`) maps the
+        # table's LAST block, copied through unchanged (scratch padding
+        # for a slot without a table), instead of reading out of bounds
         w = jnp.minimum(pos_ref[b] // BS, W - 1)
         # a page is [BS, KV, HD], or [BS, HD] in the one-pool latent form
         return (layer_ref[0], tables_ref[b, w]) + (0,) * (1 + pools)
@@ -254,8 +260,8 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         out_shape=out_shape,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
-            # two idle rows can share the scratch tail block: the grid
-            # must stay sequential so their copy-through writes don't race
+            # dead rows share the scratch tail block: the grid must stay
+            # sequential so their copy-through writes don't race
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -287,6 +293,23 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
                         k_new_scale.reshape(B, 1, KV),
                         v_new_scale.reshape(B, 1, KV)))
     return tuple(fn(layer, tables, pos, k_pool, v_pool, k_new, v_new))
+
+
+def dead_row_positions(pos, live, tables, block_size: int):
+    """The positions the two kernels take for a step in which only the
+    rows `live` [B] (bool; None: every row) owe a token: `(append_pos,
+    attend_pos)`.  A live row keeps `pos[b]` in both.  A DEAD row (the engine's `pos >=
+    stop`: a slot never used, a budget that ended inside the chunk, a
+    finished row not harvested yet) appends at the first position past
+    its table's reach, which the append kernels reject (`p_b < view`),
+    so it WRITES NOTHING: a finished row still holds its real table,
+    whose blocks the radix cache may share.  It attends at -1, which
+    the attention kernels take as a row with nothing to read: no page
+    copied, no block folded, a row of zeros out."""
+    if live is None:
+        return pos, pos
+    view = tables.shape[1] * block_size
+    return jnp.where(live, pos, view), jnp.where(live, pos, -1)
 
 
 # A latent pool's rows are padded to whole lanes.  Mosaic cuts a page
@@ -381,14 +404,14 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
         layer = layer_ref[0]
 
         def last_pos(b):
-            # an overshooting finished row attends all W pages, as the
-            # gather route's clamp does
-            return jnp.minimum(pos_ref[b], cap)
+            # the table's reach bounds a position from above; -1 (any
+            # negative position) is a row with nothing to read
+            return jnp.clip(pos_ref[b], -1, cap)
 
         def block_copies(b, blk, slot, each):
             """`each` (start or wait) on every copy of block `blk` of
             row b: the pages the row HAS there, and no other."""
-            n_live = jnp.minimum(last_pos(b) // BS + 1 - blk * P, P)
+            n_live = jnp.minimum((last_pos(b) + BS) // BS - blk * P, P)
 
             def page_copies(j, _):
                 page = tables_ref[b, blk * P + j]
@@ -403,10 +426,14 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
 
             jax.lax.fori_loop(0, n_live, page_copies, None)
             if quantized:
-                each(pltpu.make_async_copy(
-                    ks_hbm.at[b, blk], ks_buf.at[slot], sems.at[0, slot]))
-                each(pltpu.make_async_copy(
-                    vs_hbm.at[b, blk], vs_buf.at[slot], sems.at[1, slot]))
+                @pl.when(n_live > 0)
+                def _scales():
+                    each(pltpu.make_async_copy(
+                        ks_hbm.at[b, blk], ks_buf.at[slot],
+                        sems.at[0, slot]))
+                    each(pltpu.make_async_copy(
+                        vs_hbm.at[b, blk], vs_buf.at[slot],
+                        sems.at[1, slot]))
 
         # column c of a score tile is (token c // KV, kv head c % KV),
         # as a page's rows lie in the pool; query head r attends
@@ -473,14 +500,23 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
         block_copies(0, 0, 0, lambda c: c.start())
 
         def row_body(b, slot):
-            n_blk = last_pos(b) // T + 1
+            n_blk = (last_pos(b) + T) // T
             q = q_ref[b]
+
+            @pl.when((n_blk == 0) & (b + 1 < B))
+            def _skip():  # no block folds, so none starts the next
+                # row's first copies: start them here, into the tile
+                # this row did not use
+                block_copies(b + 1, 0, slot, lambda c: c.start())
+
             _, l, acc, slot = jax.lax.fori_loop(
                 0, n_blk, lambda i, c: fold(b, i, n_blk, q, c),
                 (jnp.full((H, 1), _NEG_INF, jnp.float32),
                  jnp.zeros((H, 1), jnp.float32),
                  jnp.zeros((H, VD), jnp.float32), slot))
-            o_ref[b] = (acc / l).astype(o_ref.dtype)
+            # a row that read nothing (l == 0) is a row of zeros
+            o_ref[b] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(
+                o_ref.dtype)
             return slot
 
         jax.lax.fori_loop(0, B, row_body, 0)
@@ -525,7 +561,9 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
     [L, NB, BS, KV, hd]; tables [B, W] int32 block tables (pad with the
     scratch block); pos [B] int32 per-row positions — attention covers
     columns 0..pos[b] inclusive, so the current row must already be
-    written (`paged_kv_append` first).  `layer` scalar int32 selects
+    written (`paged_kv_append` first); a negative position is a row
+    with nothing to read, whose result is zeros (a dead row:
+    `dead_row_positions`).  `layer` scalar int32 selects
     the pool layer.  GQA: query head h attends through kv head
     h // (H // KV).  Returns o [B, H, hd] in q's dtype."""
     L, NB, BS, KV, HD = k_pool.shape

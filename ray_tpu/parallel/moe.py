@@ -253,7 +253,7 @@ def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
 
 def dropless_moe(h, layer: Dict, *, top_k: int, scale: float, dtype,
                  kernel: bool = False, interpret: bool = False,
-                 stack_index=None):
+                 stack_index=None, row_mask=None):
     """Routed experts for inference, nothing dropped: h [N, D] ->
     (y [N, D], stats).  `layer`: `router` [D, E] float32, `router_bias`
     [E], `e_gate` / `e_up` [E, D, I], `e_down` [E, I, D] — or the three
@@ -268,16 +268,24 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float, dtype,
     if one expert gets every token its group is the whole array.  A
     padding row routes like any other row and changes no other row's
     result.  `stats`: `experts_touched` (groups with at least one row)
-    and `load_max` (rows of the largest group), int32 scalars."""
+    and `load_max` (rows of the largest group), int32 scalars.
+
+    `row_mask` [N] bool (the serve engine's live rows of a decode step;
+    prefill passes none): a row it leaves out is routed to NO expert.
+    Its pairs sort behind the last group and count in no group size, so
+    the grouped products never read them, `stats` count the other rows
+    only, and its row of `y` is zeros."""
     N, D = h.shape
     E = layer["router"].shape[-1]
     with jax.named_scope("moe_router"):
         w, idx = sigmoid_topk_route(h, layer["router"], layer["router_bias"],
                                     top_k, scale)
+        if row_mask is not None:  # expert E: behind every group, in none
+            idx = jnp.where(row_mask[:, None], idx, E)
         flat = idx.reshape(-1)                      # [N * k], pair -> expert
         order = jnp.argsort(flat, stable=True)      # sorted row -> pair
         inverse = jnp.argsort(order)                # pair -> sorted row
-        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
     with jax.named_scope("moe_routed"):
         xs = h.astype(dtype)[order // top_k]        # [N * k, D]
         mm = lambda a, b: grouped_matmul(  # noqa: E731
@@ -287,6 +295,10 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float, dtype,
         ys = mm(act, layer["e_down"])               # [N * k, D]
         y = ys[inverse].reshape(N, top_k, D).astype(jnp.float32)
         y = jnp.sum(y * w[..., None], axis=1).astype(dtype)
+        if row_mask is not None:
+            # rows of `ys` past the last group are whatever the product
+            # left there, a NaN's bits too
+            y = jnp.where(row_mask[:, None], y, jnp.zeros_like(y))
     stats = {"experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
              "load_max": jnp.max(sizes)}
     return y, stats

@@ -12,19 +12,32 @@ tells the engine
   to, all with FLAT signatures so that the engine can jit, name, donate
   and cache them without knowing what the leaves mean:
 
-  - `decode_chunk(W)`: `(params, *cache, tables, tok, pos) ->
+  - `decode_chunk(W)`: `(params, *cache, tables, tok, pos, stop) ->
     (*cache, tok, pos, toks)`; `toks` is `[1 + chunk (+ aux_rows),
     slots]` int32, row 0 the pre-chunk tokens, and after the chunk's
     rows `aux_rows` rows of the model's own per-tick counters, which
     ride the one device->host read the tokens already cost and come
-    back through `tick_fields`;
+    back through `tick_fields`.  `stop` [slots] int32 lives on the
+    device beside `pos` and `tok`: in each step of the chunk a row is
+    LIVE iff `pos < stop`, and only a live row's `pos` advances.  A
+    DEAD row (a slot never used: `stop` 0; a budget that ended inside
+    the chunk; a finished row whose harvest lags) costs the step
+    nothing it can avoid: it attends nothing, it WRITES NOTHING into
+    the cache (it may still hold a real table whose blocks the prefix
+    cache shares: `ops/paged_attention.dead_row_positions`, the dense
+    routes' masked select), the latent model routes it to no expert,
+    and the token it yields is nobody's;
   - `prefill(bucket)`: `(params, prompt [1, bucket]) -> (logits
     [bucket, vocab], *kv)`, `kv` the rows to cache, `[L, 1, bucket, ...]`;
   - `suffix_prefill(s_bucket, p_blocks)`: `(params, *cache, suffix,
     blk_ids, prefix_len) -> (logits, *kv)`: prefill behind a cached
     prefix, read from the pool through `blk_ids`;
   - `kv_write(t_in, nb)`: `(*cache, *kv, blk_ids, slot, pos0, tok0,
-    pos, tok) -> (*cache, pos, tok)`.
+    pos, tok, stop0, stop) -> (*cache, pos, tok, stop)`: the prefilled
+    rows into their blocks, and the admitted slot's `pos`, `tok` and
+    `stop` (`T + n_new - 1`: the engine is greedy with a fixed budget,
+    so the host never has to tell the device that a row ended).
+    Without `stop0, stop` it returns `(*cache, pos, tok)`.
 
 Two implementers: `LlamaEngineModel` (per-head K and V pools, optional
 int8 pools with a scale sidecar: the program bodies `LlamaEngine` has
@@ -39,6 +52,16 @@ from __future__ import annotations
 from typing import Dict, List
 
 from ray_tpu.serve.kv_cache import CacheLeaf
+
+
+def _set_slot(slot, pos0, tok0, pos, tok, stop0, stop):
+    """The tail of both `kv_write` bodies: an admitted slot's rows of
+    the device state.  A caller that only wants blocks written passes
+    no stop and gets `(pos, tok)` back."""
+    out = (pos.at[slot].set(pos0), tok.at[slot].set(tok0))
+    if stop is not None:
+        out += (stop.at[slot].set(stop0),)
+    return out
 
 
 class LlamaEngineModel:
@@ -90,15 +113,16 @@ class LlamaEngineModel:
             interp = self._kernel_interpret
             if self._kv_int8:
                 def _fn(params, k_pool, v_pool, k_scale, v_scale,
-                        tables, tok, pos):
+                        tables, tok, pos, stop):
                     def body(carry, _):
                         tok, kp, vp, ks, vs, pos = carry
+                        live = pos < stop
                         logits, kp, vp, ks, vs = llama.decode_step_paged(
                             cfg, params, tok, kp, vp, tables, pos,
-                            kv_scales=(ks, vs), interpret=interp,
+                            kv_scales=(ks, vs), live=live, interpret=interp,
                         )
                         nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        pos2 = jnp.minimum(pos + 1, self.max_len - 1)
+                        pos2 = jnp.where(live, pos + 1, pos)
                         return (nt, kp, vp, ks, vs, pos2), nt
 
                     tok_in = tok
@@ -112,17 +136,18 @@ class LlamaEngineModel:
                             jnp.concatenate([tok_in[None], toks], axis=0))
 
             else:
-                def _fn(params, k_pool, v_pool, tables, tok, pos):
+                def _fn(params, k_pool, v_pool, tables, tok, pos, stop):
                     def body(carry, _):
                         tok, kp, vp, pos = carry
+                        # a row owes a token while it is short of its
+                        # stop; a dead row stays where it is
+                        live = pos < stop
                         logits, kp, vp = llama.decode_step_paged(
                             cfg, params, tok, kp, vp, tables, pos,
-                            interpret=interp,
+                            live=live, interpret=interp,
                         )
                         nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        # clamp: idle/finished slots must never walk
-                        # their position past the sequence cap
-                        pos2 = jnp.minimum(pos + 1, self.max_len - 1)
+                        pos2 = jnp.where(live, pos + 1, pos)
                         return (nt, kp, vp, pos2), nt
 
                     tok_in = tok  # pre-chunk tokens (see gather route)
@@ -138,7 +163,7 @@ class LlamaEngineModel:
             from ray_tpu.ops import paged_attention as _pa
 
             def _fn(params, k_pool, v_pool, k_scale, v_scale, tables,
-                    tok, pos):
+                    tok, pos, stop):
                 # gather payload + scales, dequant to the compute dtype
                 kq = jnp.take(k_pool, tables, axis=1).reshape(
                     L, S, W * bs, KV, hd
@@ -158,25 +183,27 @@ class LlamaEngineModel:
 
                 def body(carry, _):
                     tok, kv, pos = carry[0], (carry[1], carry[2]), carry[3]
+                    live = pos < stop
                     logits, (k2, v2) = llama.decode_step_vec(
-                        cfg, params, tok, kv, pos
+                        cfg, params, tok, kv, pos, live
                     )
                     nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    pos2 = jnp.minimum(pos + 1, self.max_len - 1)
+                    pos2 = jnp.where(live, pos + 1, pos)
                     return (nt, k2, v2, pos2), nt
 
                 tok_in = tok
                 (tok, k, v, pos), toks = jax.lax.scan(
                     body, (tok, k, v, pos), None, length=chunk
                 )
-                # requantize ONLY the rows this chunk wrote; untouched
+                # requantize ONLY the rows this chunk wrote (a row's
+                # live steps: pos0 up to where it stands now); untouched
                 # rows keep their stored payload+scale bit-exactly, so
                 # repeated gather/scatter cycles cannot drift the cache
                 # (a full-view requant would re-round every row through
                 # the compute dtype each chunk)
                 idx = jnp.arange(W * bs)[None, :]
                 touched = ((idx >= pos0[:, None])
-                           & (idx < pos0[:, None] + chunk))  # [S, M]
+                           & (idx < pos[:, None]))  # [S, M]
                 kq2, ks2 = _pa.quantize_int8(k)
                 vq2, vs2 = _pa.quantize_int8(v)
                 t_p = touched[None, :, :, None, None]
@@ -201,7 +228,7 @@ class LlamaEngineModel:
                         jnp.concatenate([tok_in[None], toks], axis=0))
 
         else:
-            def _fn(params, k_pool, v_pool, tables, tok, pos):
+            def _fn(params, k_pool, v_pool, tables, tok, pos, stop):
                 # tables [slots, W] -> dense [L, slots, W*bs, KV, hd]
                 k = jnp.take(k_pool, tables, axis=1).reshape(
                     L, S, W * bs, KV, hd
@@ -212,13 +239,12 @@ class LlamaEngineModel:
 
                 def body(carry, _):
                     tok, kv, pos = carry[0], (carry[1], carry[2]), carry[3]
+                    live = pos < stop
                     logits, (k2, v2) = llama.decode_step_vec(
-                        cfg, params, tok, kv, pos
+                        cfg, params, tok, kv, pos, live
                     )
                     nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    # clamp: idle/finished slots must never walk their
-                    # position past the sequence cap
-                    pos2 = jnp.minimum(pos + 1, self.max_len - 1)
+                    pos2 = jnp.where(live, pos + 1, pos)
                     return (nt, k2, v2, pos2), nt
 
                 tok_in = tok  # pre-chunk tokens: a freshly admitted
@@ -230,8 +256,9 @@ class LlamaEngineModel:
                 )
                 # scatter the (updated) blocks back into the pool.
                 # Shared prefix blocks scatter identical, unmodified
-                # values from every sharer; padding rows target the
-                # scratch block — both make duplicate indices benign.
+                # values from every sharer (a dead row wrote nothing
+                # into its view); padding rows target the scratch
+                # block — both make duplicate indices benign.
                 kb = k.reshape(L, S, W, bs, KV, hd)
                 vb = v.reshape(L, S, W, bs, KV, hd)
                 k_pool = k_pool.at[:, tables].set(kb)
@@ -317,7 +344,8 @@ class LlamaEngineModel:
             from ray_tpu.ops import paged_attention as _pa
 
             def _fn(k_pool, v_pool, k_scale, v_scale, k1, v1,
-                    blk_ids, slot, pos0, tok0, pos, tok):
+                    blk_ids, slot, pos0, tok0, pos, tok,
+                    stop0=None, stop=None):
                 k1, v1 = _clip(k1, v1)
                 kq, ksc = _pa.quantize_int8(k1)  # [L,1,target,KV]
                 vq, vsc = _pa.quantize_int8(v1)
@@ -333,13 +361,12 @@ class LlamaEngineModel:
                 v_scale = v_scale.at[:, blk_ids].set(
                     vsc.reshape(L, nb, bs, KV)
                 )
-                pos = pos.at[slot].set(pos0)
-                tok = tok.at[slot].set(tok0)
-                return k_pool, v_pool, k_scale, v_scale, pos, tok
+                return (k_pool, v_pool, k_scale, v_scale) + _set_slot(
+                    slot, pos0, tok0, pos, tok, stop0, stop)
 
         else:
             def _fn(k_pool, v_pool, k1, v1, blk_ids, slot, pos0,
-                    tok0, pos, tok):
+                    tok0, pos, tok, stop0=None, stop=None):
                 k1, v1 = _clip(k1, v1)
                 kb = k1.astype(k_pool.dtype).reshape(
                     L, nb, bs, KV, hd
@@ -349,9 +376,8 @@ class LlamaEngineModel:
                 )
                 k_pool = k_pool.at[:, blk_ids].set(kb)
                 v_pool = v_pool.at[:, blk_ids].set(vb)
-                pos = pos.at[slot].set(pos0)
-                tok = tok.at[slot].set(tok0)
-                return k_pool, v_pool, pos, tok
+                return (k_pool, v_pool) + _set_slot(
+                    slot, pos0, tok0, pos, tok, stop0, stop)
         return _fn
 
 
@@ -418,7 +444,7 @@ class LatentMoeEngineModel:
         L, Dp = cfg.n_layers, self.width
         paged, kw = self._kernel, self._kw()
 
-        def _fn(params, pool, tables, tok, pos):
+        def _fn(params, pool, tables, tok, pos, stop):
             # kernel route: the pool in place through the tables; else
             # the dense view [L, slots, W * bs, Dp], scattered back
             cache = pool if paged else jnp.take(
@@ -426,13 +452,12 @@ class LatentMoeEngineModel:
 
             def body(carry, _):
                 tok, cache, pos = carry
+                live = pos < stop
                 logits, cache, st = m.decode_step(
                     cfg, params, tok, cache, pos,
-                    tables=tables if paged else None, **kw)
+                    tables=tables if paged else None, live=live, **kw)
                 nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                # clamp: idle/finished slots must never walk their
-                # position past the sequence cap
-                pos2 = jnp.minimum(pos + 1, self.max_len - 1)
+                pos2 = jnp.where(live, pos + 1, pos)
                 return (nt, cache, pos2), (
                     nt, st["experts_touched"], st["load_max"])
 
@@ -476,14 +501,16 @@ class LatentMoeEngineModel:
         L, bs = self.cfg.n_layers, self.block_size
         target = nb * bs
 
-        def _fn(pool, lat, blk_ids, slot, pos0, tok0, pos, tok):
+        def _fn(pool, lat, blk_ids, slot, pos0, tok0, pos, tok,
+                stop0=None, stop=None):
             # lat [L, 1, t_in, 576] -> exactly nb blocks of Dp columns
             lat = lat[:, 0, :target]
             lat = jnp.pad(lat, ((0, 0), (0, target - lat.shape[1]),
                                 (0, self.width - lat.shape[2])))
             pool = pool.at[:, blk_ids].set(
                 lat.astype(pool.dtype).reshape(L, nb, bs, self.width))
-            return pool, pos.at[slot].set(pos0), tok.at[slot].set(tok0)
+            return (pool,) + _set_slot(slot, pos0, tok0, pos, tok, stop0,
+                                       stop)
 
         return _fn
 

@@ -47,6 +47,14 @@ TPU-native design points:
   the chunk emits its pre-chunk token row so admission never needs a
   device->host read, and the token read of chunk N overlaps chunk
   N+1's compute.
+- A ROW THAT OWES NO TOKEN COSTS NOTHING IT CAN AVOID: the device holds
+  `stop[slots]` beside `pos` and `tok`, set at admission to `T + n_new
+  - 1`; in every decode step a row is live iff `pos < stop`.  A dead
+  row (a slot never used, a budget that ended inside the chunk, a
+  finished row whose harvest lags) attends nothing, writes nothing
+  into the cache and stays where it is (`serve/engine_model.py`); the
+  host never tells the device that a row ended.  `tick_ring` counts
+  both kinds of row-step (`row_steps_live`, `row_steps`).
 
 Greedy outputs are bit-identical to a dedicated `llama.generate` for
 the same prompt, with the prefix cache on or off
@@ -211,6 +219,9 @@ class LlamaEngine:
             self._model.n_layers)
         self._pos = jnp.zeros((slots,), jnp.int32)
         self._tok = jnp.zeros((slots,), jnp.int32)
+        # a row is live in a decode step iff pos < stop; 0: a slot
+        # nothing was admitted to owes nobody a token
+        self._stop = jnp.zeros((slots,), jnp.int32)
 
         # compiled-program families (each keyed by a static shape).
         # The chunk family is LRU-BOUNDED: each entry retains a
@@ -345,8 +356,9 @@ class LlamaEngine:
         tables = self._jnp.full((self.slots, 1), SCRATCH_BLOCK,
                                 self._jnp.int32)
         cfn = self._chunk_step_for(1)
-        self._cache = tuple(cfn(self.params, *self._cache, tables,
-                                self._tok, self._pos)[:len(self._cache)])
+        self._cache = tuple(cfn(
+            self.params, *self._cache, tables, self._tok, self._pos,
+            self._stop)[:len(self._cache)])
         self._jax.block_until_ready(self._cache)
 
     # -- public surface ------------------------------------------------
@@ -732,8 +744,11 @@ class LlamaEngine:
         jnp = self._jnp
         bs = self.block_size
         T = len(prompt)
-        # highest KV index a WANTED token's step touches is T+n_new-2
-        total_blocks = _cdiv(T + n_new - 1, bs)
+        # highest KV index a WANTED token's step touches is T+n_new-2:
+        # the row is live while its position is short of `stop`, on the
+        # device (`decode_chunk`) as in the host's mirror `pos_host`
+        stop = T + n_new - 1
+        total_blocks = _cdiv(stop, bs)
 
         shared: List[int] = []
         path: List = []
@@ -800,9 +815,10 @@ class LlamaEngine:
                 jnp.asarray(slot, jnp.int32),
                 jnp.asarray(T, jnp.int32),
                 tok0, self._pos, self._tok,
+                jnp.asarray(stop, jnp.int32), self._stop,
             )
-            self._cache = tuple(out[:-2])
-            self._pos, self._tok = out[-2:]
+            self._cache = tuple(out[:-3])
+            self._pos, self._tok, self._stop = out[-3:]
 
         # donate this prompt's full blocks to the radix cache (pinned
         # until completion); blocks the trie adopts stop being
@@ -825,7 +841,8 @@ class LlamaEngine:
         self._active[slot] = {
             "fut": fut, "out": [], "want": n_new,
             "since": self._chunk_seq + 1,  # first chunk with its steps
-            "pos_host": T, "own_blocks": own_set, "tree_path": path,
+            "pos_host": T, "stop": stop,
+            "own_blocks": own_set, "tree_path": path,
             "tk": tk, "tokens_in": T, "tokens_hit": P, "harvests": 0,
             "t_submit": t_submit, "t_admit": t_admit,
             "t_prefill": t_prefill, "t_first": None,
@@ -842,15 +859,12 @@ class LlamaEngine:
     # -- engine loop ---------------------------------------------------
     def _gather_width(self) -> int:
         """Blocks per slot the next chunk must see: covers every active
-        slot's highest touched index, capped per slot at its own
-        allocation (overshoot past a finished budget reads scratch
-        garbage that only ever lands in truncated surplus tokens)."""
+        slot's highest touched index, which stops short of the row's
+        `stop` (its allocation ends there too)."""
         need = 1
-        for slot, req in self._active.items():
-            hi = min(req["pos_host"] + self.chunk - 1, self.max_len - 1)
-            w = min(hi // self.block_size + 1,
-                    len(self._slot_blocks[slot]))
-            need = max(need, w)
+        for req in self._active.values():
+            hi = min(req["pos_host"] + self.chunk, req["stop"]) - 1
+            need = max(need, hi // self.block_size + 1)
         return min(_next_pow2(need), self._max_seq_blocks)
 
     def _harvest(self, toks_host: np.ndarray, seq: int):
@@ -934,6 +948,9 @@ class LlamaEngine:
             # 0 = nothing live (a live batch needs at least one block)
             W = self._gather_width() if self._active else 0
         toks = None
+        # of the chunk's slots x chunk row-steps, those a request was
+        # waiting for (its steps before its stop); the rest are dead
+        row_steps = row_steps_live = 0
         if W:
             with self._span("engine.dispatch", W=W):
                 with self._lock:
@@ -944,7 +961,7 @@ class LlamaEngine:
                 self._last_gather_blocks = W
                 cfn = self._chunk_step_for(W)
                 out = cfn(self.params, *self._cache, jnp.asarray(tables),
-                          self._tok, self._pos)
+                          self._tok, self._pos, self._stop)
                 self._cache = tuple(out[:-3])
                 self._tok, self._pos, toks = out[-3:]
                 if self._decode_kernel == "pallas":
@@ -952,12 +969,14 @@ class LlamaEngine:
                 else:
                     self._decode_gather_dispatches += 1
                 self._chunk_seq += 1
+                row_steps = self.slots * self.chunk
                 with self._lock:
+                    # the host's mirror of the device's `pos`: a row
+                    # advances while it is short of its stop
                     for req in self._active.values():
-                        req["pos_host"] = min(
-                            req["pos_host"] + self.chunk,
-                            self.max_len - 1,
-                        )
+                        end = min(req["pos_host"] + self.chunk, req["stop"])
+                        row_steps_live += end - req["pos_host"]
+                        req["pos_host"] = end
         # OVERLAP: harvest the PREVIOUS chunk's tokens while the
         # current chunk computes — the device->host read is round-trip
         # latency (measured at ~half the synced chunk wall time on an
@@ -999,6 +1018,8 @@ class LlamaEngine:
                     r["pos_host"] for r in self._active.values()
                 ),
                 "gather_blocks": W,
+                "row_steps_live": row_steps_live,
+                "row_steps": row_steps,
                 "kernel": self._decode_kernel,
                 "admit_s": t1 - t0,
                 "dispatch_s": t2 - t1,
@@ -1094,3 +1115,4 @@ class LlamaEngine:
                 self._cache = self._alloc_cache()
                 self._pos = jnp.zeros((self.slots,), jnp.int32)
                 self._tok = jnp.zeros((self.slots,), jnp.int32)
+                self._stop = jnp.zeros((self.slots,), jnp.int32)

@@ -116,6 +116,42 @@ def test_dropless_layer_under_one_sided_routing():
     np.testing.assert_allclose(y, want, atol=1e-4, rtol=1e-4)
 
 
+
+@pytest.mark.parametrize("dead", [(), (0, 3, 4, 39), tuple(range(40))],
+                         ids=["none", "some", "all"])
+def test_masked_rows_are_routed_nowhere_and_counted_nowhere(dead):
+    """`dropless_moe(row_mask=...)`, the serve engine's dead decode
+    rows: the other rows' results are bit-equal to the unmasked
+    layer's, the masked rows' are zeros, and `experts_touched` /
+    `load_max` are those of the live rows alone.  The masked rows carry
+    one and the same input, as idle slots do (token 0), which unmasked
+    piles them onto one set of experts."""
+    D, I, E, K, N = 32, 16, 16, 6, 40
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    layer = {
+        "router": jax.random.normal(ks[0], (D, E)),
+        "router_bias": jnp.zeros((E,)),
+        "e_gate": jax.random.normal(ks[1], (E, D, I)) * 0.3,
+        "e_up": jax.random.normal(ks[2], (E, D, I)) * 0.3,
+        "e_down": jax.random.normal(ks[3], (E, I, D)) * 0.3,
+    }
+    h = jax.random.normal(ks[4], (N, D))
+    mask = np.ones(N, bool)
+    mask[list(dead)] = False
+    h = jnp.where(jnp.asarray(mask)[:, None], h, h[0])
+    kw = dict(top_k=K, scale=2.448, dtype=jnp.float32)
+    y_all, _ = moe.dropless_moe(h, layer, **kw)
+    y, stats = moe.dropless_moe(h, layer, row_mask=jnp.asarray(mask), **kw)
+    np.testing.assert_array_equal(np.asarray(y)[mask], np.asarray(y_all)[mask])
+    assert not np.asarray(y)[~mask].any()
+    if mask.any():
+        _, alone = moe.dropless_moe(h[np.flatnonzero(mask)], layer, **kw)
+        want = (int(alone["experts_touched"]), int(alone["load_max"]))
+    else:
+        want = (0, 0)
+    assert (int(stats["experts_touched"]), int(stats["load_max"])) == want
+
+
 # ----------------------------------------------------------------------
 # behind the seam
 # ----------------------------------------------------------------------
@@ -185,8 +221,13 @@ def test_engine_serves_the_model_greedy_and_cache_on_equals_off(model, kw):
         total = cfg.n_moe_layers * cfg.n_routed_experts
         for t in ticks:
             assert t["experts_total"] == total
-            assert 0 < t["experts_touched"] <= total
-            assert 1 <= t["expert_load_max"] <= eng.slots * cfg.top_k
+            assert 0 <= t["experts_touched"] <= total
+            assert 0 <= t["expert_load_max"] <= eng.slots * cfg.top_k
+            # dead rows route nowhere: a chunk with no live row (the
+            # one in flight when the last request's harvest lags)
+            # touches no expert
+            assert (t["experts_touched"] > 0) == (t["expert_load_max"] > 0)
+        assert any(t["experts_touched"] > 0 for t in ticks)
     finally:
         eng.shutdown()
 

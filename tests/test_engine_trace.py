@@ -187,6 +187,33 @@ def test_tick_records_carry_their_wall_time(engine):
     assert walls == sorted(walls) and t0 <= walls[0] <= time.time()
 
 
+
+@pytest.mark.parametrize("script", [
+    [(12, 5)], [(12, 4)], [(12, 4), (7, 6)], [(3, 1)],
+], ids=["whole-chunks", "mid-chunk", "two-rows", "prefill-token-only"])
+def test_tick_records_count_the_row_steps_that_were_owed(engine, script):
+    """`row_steps` is what a chunk computes (slots x chunk, 0 in a tick
+    that dispatched none), `row_steps_live` what requests were waiting
+    for: a request of n tokens owes n - 1 decode steps (its first token
+    is the prefill's), wherever its budget ends in a chunk, and a row
+    that is finished but not harvested yet owes none."""
+    futs = [engine.submit(_prompt(i, n), new)
+            for i, (n, new) in enumerate(script)]
+    for f in futs:
+        f.result(timeout=60)
+    ring = engine.stats()["tick_ring"]
+    per_chunk = engine.slots * engine.chunk
+    for t in ring:
+        assert t["row_steps"] == (per_chunk if t["gather_blocks"] else 0)
+        assert 0 <= t["row_steps_live"] <= t["row_steps"]
+        # a row's cached tokens stop at its stop
+        assert t["live_tokens"] <= sum(n + new - 1 for n, new in script)
+    assert sum(t["row_steps_live"] for t in ring) == sum(
+        new - 1 for _, new in script)
+    # the chunk in flight while the last harvest lagged was all dead
+    assert any(t["row_steps"] and not t["row_steps_live"] for t in ring)
+
+
 # ----------------------------------------------------------------------
 # B. the engine-loop spans, under a profiler session
 # ----------------------------------------------------------------------
@@ -253,7 +280,7 @@ def test_the_jitted_families_lower_under_their_names(engine):
     i32 = jnp.int32
     tables = jnp.zeros((e.slots, 2), i32)
     chunk = e._chunk_step_for(2).lower(
-        e.params, e._k_pool, e._v_pool, tables, e._tok, e._pos)
+        e.params, e._k_pool, e._v_pool, tables, e._tok, e._pos, e._stop)
     assert "@jit_decode_chunk_w2" in chunk.as_text()
     prefill = e._prefill_for(16).lower(e.params, jnp.zeros((1, 16), i32))
     assert "@jit_prefill_b16" in prefill.as_text()

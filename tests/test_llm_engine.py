@@ -268,3 +268,116 @@ def test_prefix_cache_disabled_for_non_dense_attention(model):
         assert eng._radix is not None  # dense keeps the cache
     finally:
         eng.shutdown()
+
+
+# ----------------------------------------------------------------------
+# dead rows: a slot whose position has reached its stop
+# ----------------------------------------------------------------------
+def _latent_model():
+    from ray_tpu.models import deepseek_v3 as m
+
+    cfg = m.DeepseekV3Config.tiny()
+    params = m.init_params(cfg, jax.random.PRNGKey(0), std=0.2)
+    return cfg, params
+
+
+def _latent_greedy(cfg, params, prompt, n_new):
+    from ray_tpu.models import deepseek_v3 as m
+
+    seq = list(prompt)
+    for _ in range(n_new):
+        lg = m.forward(cfg, params, jnp.asarray([seq]))
+        seq.append(int(jnp.argmax(lg[0, -1])))
+    return seq[len(prompt):]
+
+
+def _alone_int8(cfg, params, prompt, n_new):
+    """int8 KV rounds the cache, so `generate` is no oracle for it: the
+    request alone in an engine of one slot that steps one token a chunk
+    (no neighbour, no step past a budget inside a chunk)."""
+    eng = LlamaEngine(cfg, params, slots=1, chunk=1, block_size=8,
+                      max_len=64, kv_dtype="int8", prefix_cache=False)
+    try:
+        return eng.submit(prompt, n_new).result(timeout=120)
+    finally:
+        eng.shutdown()
+
+
+_BODIES = {
+    "llama-bf16": (lambda m: m, _expected, {}),
+    "llama-int8kv": (lambda m: m, _alone_int8, {"kv_dtype": "int8"}),
+    "latent": (lambda m: _latent_model(), _latent_greedy, {}),
+}
+_ORACLE = {}
+
+
+@pytest.mark.parametrize("route", [
+    dict(decode_kernel="pallas", kernel_interpret=True),
+    dict(decode_kernel="gather"),
+], ids=["paged-interpret", "gather"])
+@pytest.mark.parametrize("body", sorted(_BODIES))
+def test_a_dead_row_stays_put_writes_nothing_and_costs_no_token(
+        model, body, route):
+    """Two requests beside two slots never used; A's budget ends inside
+    its second chunk and B decodes four chunks longer.  Every body of
+    `decode_chunk`, on both routes: (a) the tokens are the oracle's,
+    (b) on the DEVICE a finished row's position stays at its stop while
+    the chunks go on, and a slot never used stays at 0, (c) a request
+    that shares A's first blocks through the prefix cache, admitted
+    after A ran its dead steps with its real table, gets the oracle's
+    tokens: a dead row wrote nothing into a shared block."""
+    pick, oracle, kw = _BODIES[body]
+    cfg, params = pick(model)
+    rng = np.random.RandomState(5)
+    a = [int(x) for x in rng.randint(1, cfg.vocab_size, size=20)]
+    b = [int(x) for x in rng.randint(1, cfg.vocab_size, size=5)]
+    c = a[:16] + [int(x) for x in rng.randint(1, cfg.vocab_size, size=4)]
+    reqs = [(a, 6), (b, 23), (c, 5)]
+    if body not in _ORACLE:
+        _ORACLE[body] = [oracle(cfg, params, p, n) for p, n in reqs]
+    want = _ORACLE[body]
+    eng = LlamaEngine(cfg, params, slots=4, chunk=4, block_size=8,
+                      max_len=64, prefix_cache=True, **kw, **route)
+    try:
+        assert not np.asarray(eng._stop).any()  # nothing admitted: all dead
+        futs = [eng.submit(p, n) for p, n in reqs[:2]]
+        assert [f.result(timeout=300) for f in futs] == want[:2]
+        # A took 5 steps (2 chunks), B 22 (6 chunks): A's slot sat dead,
+        # released and unreused, through B's last four
+        ring = {r["tokens_in"]: r for r in eng.stats()["request_ring"]}
+        assert ring[20]["harvests"] == 2 and ring[5]["harvests"] == 6
+        assert sorted(np.asarray(eng._pos)) == [0, 0, 20 + 6 - 1, 5 + 23 - 1]
+        assert sorted(np.asarray(eng._stop)) == sorted(np.asarray(eng._pos))
+        # A's first two blocks, which the prefix cache now owns, hold
+        # what A's prefill wrote, bit for bit, in every cache leaf
+        shared, path = eng._radix.match(a)
+        eng._radix.release(path)
+        assert len(shared) == 2
+        _, *kv = eng._prefill_for(32)(
+            params, jnp.asarray([a + [0] * 12], jnp.int32))
+        i32 = jnp.int32
+        # (the engine's own program, jitted as the engine's is: int8's
+        # division rounds otherwise when run op by op)
+        written = jax.jit(eng._model.kv_write(32, 3))(
+            *[jnp.zeros_like(x) for x in eng._cache], *kv,
+            jnp.asarray([1, 2, 3], i32), jnp.asarray(0, i32),
+            jnp.asarray(20, i32), jnp.asarray(0, i32), eng._pos, eng._tok)
+
+        def prefix_blocks_intact():
+            for leaf, ref in zip(eng._cache, written):
+                np.testing.assert_array_equal(
+                    np.asarray(leaf[:, np.asarray(shared)]),
+                    np.asarray(ref[:, 1:3]))
+
+        prefix_blocks_intact()
+        assert eng.submit(*reqs[2]).result(timeout=300) == want[2]
+        s = eng.stats()
+        assert s["prefix_hit_tokens"] == 16
+        prefix_blocks_intact()  # C's dead steps had them in its table too
+        pos = sorted(np.asarray(eng._pos))
+        assert pos[:2] == [0, 0] and 20 + 5 - 1 in pos
+        live = sum(t["row_steps_live"] for t in s["tick_ring"])
+        assert live == sum(n - 1 for _, n in reqs)
+        assert live < sum(t["row_steps"] for t in s["tick_ring"])
+    finally:
+        eng.shutdown()

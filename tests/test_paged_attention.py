@@ -207,6 +207,103 @@ def test_append_writes_one_row_and_preserves_rest():
     np.testing.assert_array_equal(np.asarray(vp), ev)
 
 
+
+def _dead_case(kind, BS, W, KV, H, hd, seed):
+    """(call, tables, pos): `call(tables, pos)` runs the decode
+    attention of `kind` (per-head bf16, per-head int8, latent) over one
+    random pool."""
+    k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed)
+    q = jnp.asarray(np.random.default_rng(seed + 1).standard_normal(
+        (len(pos), H, hd)), jnp.bfloat16)
+    if kind == "latent":
+        pool = jnp.asarray(k[:, :, :, 0], jnp.bfloat16)  # [1, NB, BS, hd]
+        pool = jnp.pad(pool, ((0, 0),) * 3 + ((0, 128 - hd),))
+
+        def call(tables, pos):
+            return pa.mla_paged_decode_attention(
+                q, pool, jnp.asarray(tables), jnp.asarray(pos), 0,
+                value_dim=hd - 4, scale=0.25, interpret=True)
+    else:
+        (kp, vp), scales, _ = _pools(k, v, jnp.bfloat16, kind == "int8")
+
+        def call(tables, pos):
+            return pa.paged_decode_attention(
+                q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), 0,
+                interpret=True, **scales)
+    return call, tables, pos
+
+
+@pytest.mark.parametrize("dead", [(0, 2, 3), (1, 4)], ids=["first", "last"])
+@pytest.mark.parametrize("dead_pos", [-1, 0], ids=["no-block", "one-block"])
+@pytest.mark.parametrize("kind", ["model", "int8", "latent"])
+def test_dead_rows_leave_live_rows_bit_identical(kind, dead_pos, dead):
+    """Rows that owe no token, before, between and behind live rows
+    (the walk prefetches across rows): handed -1 over a scratch table
+    they read nothing and give a row of zeros (the engine's form, see
+    `dead_row_positions`); handed 0 they read one block.  Either way
+    every other row's result is bit-equal to what it is beside rows
+    that walk their tables."""
+    BS, W, KV, H = _RAGGED["w11-gqa4"]
+    if kind == "latent":
+        KV = 1
+    call, tables, pos = _dead_case(kind, BS, W, KV, H, 16, seed=21)
+    want = np.asarray(call(tables, pos), np.float32)
+    t2, p2 = tables.copy(), pos.copy()
+    for b in dead:
+        t2[b], p2[b] = 0, dead_pos  # the scratch block, W times
+    got = np.asarray(call(t2, p2), np.float32)
+    live = [b for b in range(len(pos)) if b not in dead]
+    np.testing.assert_array_equal(got[live], want[live])
+    assert np.isfinite(got).all()
+    if dead_pos < 0:
+        assert not got[list(dead)].any()
+
+
+@pytest.mark.parametrize("kind", ["model", "int8", "latent"])
+def test_append_writes_nothing_for_a_dead_row(kind):
+    """`dead_row_positions` hands a dead row the first position past its
+    table's reach: the append kernels reject it, so the pool (and the
+    int8 sidecar) is bit-equal except for the live rows' one row each,
+    though the dead rows hold REAL tables, as a finished row does whose
+    harvest lags."""
+    B, KV, hd, BS, NB, W = 4, 2, 8, 4, 10, 2
+    rng = np.random.default_rng(31)
+    tables = jnp.asarray([[1, 2], [3, 4], [5, 6], [7, 8]], jnp.int32)
+    pos = jnp.asarray([0, 5, 2, 7], jnp.int32)
+    live = jnp.asarray([True, False, True, False])
+    w_pos, a_pos = pa.dead_row_positions(pos, live, tables, BS)
+    assert list(np.asarray(w_pos)) == [0, W * BS, 2, W * BS]
+    assert list(np.asarray(a_pos)) == [0, -1, 2, -1]
+    if kind == "latent":
+        pool0 = jnp.asarray(rng.standard_normal((1, NB, BS, 128)),
+                            jnp.bfloat16)
+        new = jnp.asarray(rng.standard_normal((B, 100)), jnp.bfloat16)
+        got = [pa.mla_paged_kv_append(pool0, new, tables, w_pos, 0,
+                                      interpret=True)]
+        want = [pa.mla_paged_kv_append(pool0, new[::2], tables[::2],
+                                       pos[::2], 0, interpret=True)]
+    else:
+        k0 = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
+        v0 = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
+        (kp, vp), scales, _ = _pools(k0, v0, jnp.float32, kind == "int8")
+        kn = jnp.asarray(rng.standard_normal((B, KV, hd)), jnp.float32)
+        vn = jnp.asarray(rng.standard_normal((B, KV, hd)), jnp.float32)
+        new_scales = [{}, {}]
+        if scales:
+            (kn, ksn), (vn, vsn) = pa.quantize_int8(kn), pa.quantize_int8(vn)
+            new_scales = [dict(k_new_scale=ksn[sl], v_new_scale=vsn[sl],
+                               **scales) for sl in (slice(None),
+                                                    slice(None, None, 2))]
+        got = pa.paged_kv_append(kp, vp, kn, vn, tables, w_pos, 0,
+                                 interpret=True, **new_scales[0])
+        # the oracle: the live rows alone
+        want = pa.paged_kv_append(kp, vp, kn[::2], vn[::2], tables[::2],
+                                  pos[::2], 0, interpret=True,
+                                  **new_scales[1])
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_quantize_int8_idempotent_and_bounded():
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((4, 16)).astype(np.float32))
