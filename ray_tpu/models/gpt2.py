@@ -317,15 +317,12 @@ def loss_fn(cfg: GPT2Config, params: Dict, tokens: jax.Array,
 
     Uses the lse-reduction form: XLA fuses the logsumexp into the
     lm-head matmul's epilogue, so the [B, T, vocab] *log-prob* tensor
-    never materializes (the logits do, transiently).  Measured best at
-    EVERY scale tried on v5e (PERF.md r5): the lm-head is MXU-bound at
-    these widths, XLA stores bf16 logits once and skips the backward
-    recompute, and its fused schedule keeps scaling linearly even when
-    logits+dlogits exceed HBM — so both no-materialize formulations
-    (`ops.xent.fused_cross_entropy` scan-chunked, and the Pallas
-    blockwise `ops.xent_pallas.pallas_cross_entropy`) lose: they must
-    recompute the lm-head matmul in the backward, which costs more
-    than the HBM they save.
+    never materializes (the logits do, transiently).  The lm-head is
+    MXU-bound at these widths and XLA stores bf16 logits once; forms
+    that never materialize the logits (a scan over chunks, a blockwise
+    Pallas kernel) recompute the lm-head matmul in the backward, which
+    cost more than the HBM they saved when both were tried
+    (`docs/perf_history.md`), so neither is kept.
     """
     inputs = tokens[:, :-1]
     targets = tokens[:, 1:]

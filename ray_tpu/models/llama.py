@@ -500,50 +500,49 @@ def prefill(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
     return logits[:, -1, :], (k_cache, v_cache)
 
 
-def decode_step(cfg: LlamaConfig, params: Dict, token: jax.Array,
-                cache, pos):
-    """One token of autoregressive decoding against the KV cache.
-
-    SYNC CONTRACT with `decode_step_vec`: the vector-position variant
-    duplicates this body on purpose — delegating would put its
-    masked-select cache write (a full cache read+write per step) on
-    this scalar hot path, which `generate`'s fused scan rides.  Any
-    numerics change here must land in both;
-    `tests/test_llm_engine.py::test_decode_step_vec_matches_scalar_pos`
-    fails on divergence.
-
-    token [B] int32, pos scalar (current sequence length) ->
-    (logits [B, vocab], updated cache).  Static shapes throughout (the
-    cache is max_len-sized and masked by position), so the step compiles
-    once and every subsequent token reuses it.
-    """
-    k_cache, v_cache = cache  # [L, B, M, KV, hd]
-    B = token.shape[0]
-    M = k_cache.shape[2]
+def _decode_layer(cfg: LlamaConfig, layer: Dict, x, rope, attend):
+    """THE decoder layer of a decode step, x [B, 1, d]: norm, q/k/v,
+    rotation, attention, `wo`, MLP.  The decode steps differ in one
+    thing only, which they hand in: `rope(t)` rotates `[B, 1, heads,
+    hd]` at this step's position(s), and `attend(q, k_new, v_new)`
+    writes this step's row into the cache and attends, returning `(o,
+    cache)` with `o` holding `[B, H, hd]` values in any float type.
+    Int8 weights ride the `<name>_scale` leaves here as in `forward`."""
+    B = x.shape[0]
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    group = H // KV
+    with jax.named_scope("attn"):
+        h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype), cfg.norm_eps)
+        q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
+        k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
+        v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
+        q = rope(q.reshape(B, 1, H, hd))
+        k_new = rope(k.reshape(B, 1, KV, hd))
+        v_new = v.reshape(B, 1, KV, hd)
+        o, cache = attend(q, k_new, v_new)
+        o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
+        x1 = x + _apply(o, layer["wo"], cfg.dtype,
+                        scale=layer.get("wo_scale"))
+    return _mlp(cfg, x1, layer), cache
 
+
+def _decode_dense(cfg: LlamaConfig, params: Dict, token, cache, valid,
+                  rope, write):
+    """A decode step over a dense cache (k, v), each [L, B, M, KV, hd]:
+    `write(cache_l, new)` puts this step's row into one layer's K or V,
+    and attention runs over all M slots under `valid` (broadcastable to
+    [B, H, 1, M]).  Static shapes throughout (the cache is M-sized and
+    masked by position), so a step compiles once."""
+    group = cfg.n_heads // cfg.n_kv_heads
     x = _embed(params, token, cfg.dtype)[:, None, :]  # [B,1,d]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    # causal-by-position mask over the cache slots
-    valid = (jnp.arange(M) <= pos)[None, None, :, None]  # [1,1,M,1]
+    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
 
     def body(x, inputs):
         layer, kc, vc = inputs  # kc/vc [B, M, KV, hd]
-        with jax.named_scope("attn"):
-            h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype),
-                          cfg.norm_eps)
-            q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
-            k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
-            v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
-            q = _rope(q.reshape(B, 1, H, hd), cfg.rope_theta, t0=pos)
-            k_new = _rope(k.reshape(B, 1, KV, hd), cfg.rope_theta, t0=pos)
-            v_new = v.reshape(B, 1, KV, hd)
-            kc = lax.dynamic_update_slice(kc, k_new.astype(kc.dtype),
-                                          (0, pos, 0, 0))
-            vc = lax.dynamic_update_slice(vc, v_new.astype(vc.dtype),
-                                          (0, pos, 0, 0))
-            kk, vv = kc, vc
+
+        def attend(q, k_new, v_new):
+            k2 = write(kc, k_new.astype(kc.dtype))
+            v2 = write(vc, v_new.astype(vc.dtype))
+            kk, vv = k2, v2
             if group > 1:
                 kk = jnp.repeat(kk, group, axis=2)
                 vv = jnp.repeat(vv, group, axis=2)
@@ -556,24 +555,39 @@ def decode_step(cfg: LlamaConfig, params: Dict, token: jax.Array,
                 "bohd,bmhd->bhom", q, kk,
                 preferred_element_type=jnp.float32,
             ) * scale  # [B,H,1,M] f32
-            s = jnp.where(valid.transpose(0, 3, 1, 2), s, -1e30)
+            s = jnp.where(valid, s, -1e30)
             w = jax.nn.softmax(s, axis=-1)
             o = jnp.einsum(
                 "bhom,bmhd->bohd", w.astype(cfg.dtype), vv,
                 preferred_element_type=jnp.float32,
             )
-            o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
-            x1 = x + _apply(o, layer["wo"], cfg.dtype,
-                            scale=layer.get("wo_scale"))
-        return _mlp(cfg, x1, layer), (kc, vc)
+            return o, (k2, v2)
+
+        return _decode_layer(cfg, layer, x, rope, attend)
 
     x = x.astype(cfg.dtype)
-    x, (k_cache, v_cache) = lax.scan(
-        body, x, (dict(params["blocks"]), k_cache, v_cache)
-    )
+    x, cache = lax.scan(body, x, (dict(params["blocks"]), *cache))
     x = _rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
-    logits = _lm_head(x[:, 0, :], params, cfg.dtype)
-    return logits, (k_cache, v_cache)
+    return _lm_head(x[:, 0, :], params, cfg.dtype), cache
+
+
+def decode_step(cfg: LlamaConfig, params: Dict, token: jax.Array,
+                cache, pos):
+    """One token of autoregressive decoding at ONE position for the
+    whole batch: what `generate`'s fused scan rides, and the tests'
+    oracle for `decode_step_rows`.  It exists beside that step for its
+    cache write: a `dynamic_update_slice` of one row, where per-row
+    positions need a masked select over the whole cache.
+
+    token [B] int32, pos scalar (current sequence length), cache (k, v)
+    each [L, B, M, KV, hd] -> (logits [B, vocab], updated cache)."""
+    M = cache[0].shape[2]
+    # causal-by-position mask over the cache slots
+    valid = (jnp.arange(M) <= pos)[None, None, None, :]
+    return _decode_dense(
+        cfg, params, token, cache, valid,
+        lambda t: _rope(t, cfg.rope_theta, t0=pos),
+        lambda c, new: lax.dynamic_update_slice(c, new, (0, pos, 0, 0)))
 
 
 def _rope_at(x, theta: float, pos_b):
@@ -591,174 +605,93 @@ def _rope_at(x, theta: float, pos_b):
                            axis=-1)
 
 
-def decode_step_vec(cfg: LlamaConfig, params: Dict, token: jax.Array,
-                    cache, pos, live=None):
+def decode_step_rows(cfg: LlamaConfig, params: Dict, token: jax.Array,
+                     cache, pos, *, tables=None, live=None,
+                     interpret: bool = False):
     """One decode step with PER-ROW positions (continuous batching:
     every slot advances at its own length; reference capability: the
-    vLLM-on-Ray serving pattern's step-level scheduling).
+    vLLM-on-Ray serving pattern's step-level scheduling).  token [B]
+    int32, pos [B] int32 (current length per row) -> (logits [B, vocab]
+    f32, cache).  Rows are independent, so a slot's tokens are what a
+    dedicated `generate` would produce.
 
-    token [B] int32, pos [B] int32 (current length per row) ->
-    (logits [B, vocab] f32, updated cache).  Same math as
-    `decode_step` restricted to equal positions; rows are independent,
-    so a slot's tokens are identical to what a dedicated `generate`
-    would produce.  Deliberately duplicates `decode_step`'s body (see
-    its SYNC CONTRACT note): the masked-select write here must not tax
-    the scalar path, and the parity test pins the two together.
+    `tables` None: `cache` is the dense view (k, v), each [L, B, M, KV,
+    hd] (the engine's gather route: CPU, and the tests' reference),
+    written by a masked select.  `tables` [B, W] int32 (scratch-block
+    padded): `cache` is the paged pool's leaves passed WHOLE, `(k_pool,
+    v_pool)` each [L, num_blocks, block_size, KV, hd], or with int8
+    pools `(k_pool, v_pool, k_scale, v_scale)`, and comes back as a
+    tuple of the same arity; per layer `paged_kv_append` writes the new
+    row in place, then `paged_decode_attention` walks each row's blocks
+    with an online softmax (`ops/paged_attention.py`; the layer index
+    rides the kernels as a scalar-prefetch argument, so the scan never
+    slices the pool).  The two routes share their numerics in form
+    (write-then-attend, f32 score accumulation, -1e30 mask, f32
+    softmax, weights cast to cfg.dtype for the value matmul); the paged
+    reduction is blockwise-online, so logits agree to float rounding
+    and greedy argmax is preserved (`tests/test_paged_attention.py`).
 
     `live` [B] bool (the engine's `pos < stop`): a row that is not
-    live writes nothing into the cache; what it computes is nobody's."""
-    k_cache, v_cache = cache  # [L, B, M, KV, hd]
-    B = token.shape[0]
-    M = k_cache.shape[2]
-    hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    group = H // KV
+    live writes nothing into the cache and, on the paged route, attends
+    nothing (`dead_row_positions`); what it computes is nobody's."""
+    def rope(t):
+        return _rope_at(t, cfg.rope_theta, pos)
 
-    x = _embed(params, token, cfg.dtype)[:, None, :]  # [B,1,d]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    # per-row causal mask over cache slots: [B, M]
-    valid = jnp.arange(M)[None, :] <= pos[:, None]
-    # per-row write mask for the cache update.  A masked SELECT, not a
-    # batched scatter: `.at[arange(B), pos].set(...)` lowers to a
-    # general scatter that TPU executes catastrophically slowly inside
-    # the layer scan (measured ~30x the whole step's bandwidth cost);
-    # the select is one dense read+write of the cache the step already
-    # reads anyway.
-    write = jnp.arange(M)[None, :] == pos[:, None]
-    if live is not None:
-        write = write & live[:, None]
-    write = write[:, :, None, None]
+    if tables is None:
+        M = cache[0].shape[2]
+        # per-row causal mask over cache slots
+        valid = (jnp.arange(M)[None, :] <= pos[:, None])[:, None, None, :]
+        # per-row write mask for the cache update.  A masked SELECT, not
+        # a batched scatter: `.at[arange(B), pos].set(...)` lowers to a
+        # general scatter that TPU executes catastrophically slowly
+        # inside the layer scan (measured ~30x the whole step's
+        # bandwidth cost); the select is one dense read+write of the
+        # cache the step already reads anyway.
+        row = jnp.arange(M)[None, :] == pos[:, None]
+        if live is not None:
+            row = row & live[:, None]
+        row = row[:, :, None, None]
+        return _decode_dense(cfg, params, token, cache, valid, rope,
+                             lambda c, new: jnp.where(row, new, c))
 
-    def body(x, inputs):
-        layer, kc, vc = inputs  # kc/vc [B, M, KV, hd]
-        with jax.named_scope("attn"):
-            h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype),
-                          cfg.norm_eps)
-            q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
-            k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
-            v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
-            q = _rope_at(q.reshape(B, 1, H, hd), cfg.rope_theta, pos)
-            k_new = _rope_at(k.reshape(B, 1, KV, hd), cfg.rope_theta, pos)
-            v_new = v.reshape(B, 1, KV, hd)
-            kc = jnp.where(write, k_new.astype(kc.dtype), kc)
-            vc = jnp.where(write, v_new.astype(vc.dtype), vc)
-            kk, vv = kc, vc
-            if group > 1:
-                kk = jnp.repeat(kk, group, axis=2)
-                vv = jnp.repeat(vv, group, axis=2)
-            s = jnp.einsum(
-                "bohd,bmhd->bhom", q, kk,
-                preferred_element_type=jnp.float32,
-            ) * scale  # [B,H,1,M] f32
-            s = jnp.where(valid[:, None, None, :], s, -1e30)
-            w = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum(
-                "bhom,bmhd->bohd", w.astype(cfg.dtype), vv,
-                preferred_element_type=jnp.float32,
-            )
-            o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
-            x1 = x + _apply(o, layer["wo"], cfg.dtype,
-                            scale=layer.get("wo_scale"))
-        return _mlp(cfg, x1, layer), (kc, vc)
-
-    x = x.astype(cfg.dtype)
-    x, (k_cache, v_cache) = lax.scan(
-        body, x, (dict(params["blocks"]), k_cache, v_cache)
-    )
-    x = _rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
-    logits = _lm_head(x[:, 0, :], params, cfg.dtype)
-    return logits, (k_cache, v_cache)
-
-
-def decode_step_paged(cfg: LlamaConfig, params: Dict, token: jax.Array,
-                      k_pool, v_pool, tables, pos, *, kv_scales=None,
-                      live=None, interpret: bool = False):
-    """One decode step with PER-ROW positions straight off the paged
-    KV pool — `decode_step_vec` with the dense gather/scatter replaced
-    by the Pallas kernels in `ops/paged_attention.py`.
-
-    token [B] int32; k_pool/v_pool [L, num_blocks, block_size, KV, hd]
-    (the `BlockPool` tensors, passed WHOLE — the layer index rides the
-    kernels as a scalar-prefetch arg, so the scan never slices the
-    pool); tables [B, W] int32 block tables (scratch-block padded);
-    pos [B] int32 per-row positions.  Per layer: `paged_kv_append`
-    writes the new KV row in place, then `paged_decode_attention`
-    walks each row's blocks with an online softmax.  Returns
-    (logits [B, vocab] f32, k_pool, v_pool) — plus the updated
-    (k_scale, v_scale) sidecar when `kv_scales` is given (int8 pools).
-    `live` [B] bool (the engine's `pos < stop`): a row that is not live
-    appends nothing and attends nothing (`dead_row_positions`).
-
-    Numerics mirror `decode_step_vec` (write-then-attend, f32 score
-    accumulation, -1e30 mask, f32 softmax, weights cast to cfg.dtype
-    for the value matmul); the reduction is blockwise-online, so
-    logits agree to float rounding and greedy argmax is preserved
-    (`tests/test_paged_attention.py` pins both).  Int8 weights ride
-    the same `<name>_scale` leaves as the other decode paths."""
     from ray_tpu.ops import paged_attention as _pa
 
-    B = token.shape[0]
-    L = k_pool.shape[0]
-    hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    quant = kv_scales is not None
     # where a row appends, how far it attends
-    w_pos, a_pos = _pa.dead_row_positions(pos, live, tables, k_pool.shape[2])
-
+    w_pos, a_pos = _pa.dead_row_positions(pos, live, tables,
+                                          cache[0].shape[2])
     x = _embed(params, token, cfg.dtype)[:, None, :]  # [B,1,d]
 
     def body(carry, inputs):
-        if quant:
-            x, kp, vp, ks, vs = carry
-        else:
-            x, kp, vp = carry
+        x, pool = carry[0], carry[1:]
         li, layer = inputs
-        with jax.named_scope("attn"):
-            h = _rms_norm(x, layer["attn_norm"].astype(cfg.dtype),
-                          cfg.norm_eps)
-            q = _apply(h, layer["wq"], cfg.dtype, scale=layer.get("wq_scale"))
-            k = _apply(h, layer["wk"], cfg.dtype, scale=layer.get("wk_scale"))
-            v = _apply(h, layer["wv"], cfg.dtype, scale=layer.get("wv_scale"))
-            q = _rope_at(q.reshape(B, 1, H, hd), cfg.rope_theta, pos)
-            k_new = _rope_at(k.reshape(B, 1, KV, hd), cfg.rope_theta, pos)
-            v_new = v.reshape(B, 1, KV, hd)
-            if quant:
+
+        def attend(q, k_new, v_new):
+            if len(pool) == 4:  # int8 payload beside its scales
                 kq, ks_new = _pa.quantize_int8(k_new[:, 0])
                 vq, vs_new = _pa.quantize_int8(v_new[:, 0])
                 kp, vp, ks, vs = _pa.paged_kv_append(
-                    kp, vp, kq, vq, tables, w_pos, li,
-                    k_scale=ks, v_scale=vs, k_new_scale=ks_new,
-                    v_new_scale=vs_new, interpret=interpret,
-                )
-                o = _pa.paged_decode_attention(
-                    q[:, 0], kp, vp, tables, a_pos, li,
-                    k_scale=ks, v_scale=vs, interpret=interpret,
-                )
-            else:
-                kp, vp = _pa.paged_kv_append(
-                    kp, vp, k_new[:, 0].astype(kp.dtype),
-                    v_new[:, 0].astype(vp.dtype), tables, w_pos, li,
-                    interpret=interpret,
-                )
-                o = _pa.paged_decode_attention(
-                    q[:, 0], kp, vp, tables, a_pos, li, interpret=interpret,
-                )
-            o = o.astype(cfg.dtype).reshape(B, 1, H * hd)
-            x1 = x + _apply(o, layer["wo"], cfg.dtype,
-                            scale=layer.get("wo_scale"))
-        out = _mlp(cfg, x1, layer)
-        if quant:
-            return (out, kp, vp, ks, vs), None
-        return (out, kp, vp), None
+                    *pool[:2], kq, vq, tables, w_pos, li,
+                    k_scale=pool[2], v_scale=pool[3], k_new_scale=ks_new,
+                    v_new_scale=vs_new, interpret=interpret)
+                return _pa.paged_decode_attention(
+                    q[:, 0], kp, vp, tables, a_pos, li, k_scale=ks,
+                    v_scale=vs, interpret=interpret), (kp, vp, ks, vs)
+            kp, vp = _pa.paged_kv_append(
+                *pool, k_new[:, 0].astype(pool[0].dtype),
+                v_new[:, 0].astype(pool[1].dtype), tables, w_pos, li,
+                interpret=interpret)
+            return _pa.paged_decode_attention(
+                q[:, 0], kp, vp, tables, a_pos, li,
+                interpret=interpret), (kp, vp)
 
-    if quant:
-        carry0 = (x.astype(cfg.dtype), k_pool, v_pool) + tuple(kv_scales)
-    else:
-        carry0 = (x.astype(cfg.dtype), k_pool, v_pool)
-    xs = (jnp.arange(L, dtype=jnp.int32), dict(params["blocks"]))
-    carry, _ = lax.scan(body, carry0, xs)
-    x = _rms_norm(carry[0], params["final_norm"].astype(cfg.dtype),
-                  cfg.norm_eps)
-    logits = _lm_head(x[:, 0, :], params, cfg.dtype)
-    return (logits,) + tuple(carry[1:])
+        x, pool = _decode_layer(cfg, layer, x, rope, attend)
+        return (x, *pool), None
+
+    xs = (jnp.arange(cache[0].shape[0], dtype=jnp.int32),
+          dict(params["blocks"]))
+    (x, *pool), _ = lax.scan(body, (x.astype(cfg.dtype), *cache), xs)
+    x = _rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
+    return _lm_head(x[:, 0, :], params, cfg.dtype), tuple(pool)
 
 
 _DECODE_JIT_CACHE: Dict = {}

@@ -1,11 +1,5 @@
 """TPU kernels and fused ops (Pallas where it wins, XLA elsewhere)."""
 
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.xent import fused_cross_entropy
-from ray_tpu.ops.xent_pallas import pallas_cross_entropy
 
-__all__ = [
-    "flash_attention",
-    "fused_cross_entropy",
-    "pallas_cross_entropy",
-]
+__all__ = ["flash_attention"]

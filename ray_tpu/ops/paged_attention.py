@@ -61,7 +61,7 @@ engine's per-layer scan never slices (= copies) the pool:
   bytes (62% of the HBM peak at llama1b4's shapes, 49% at Mistral's:
   PERF.md section 5's microbenchmark).
 
-Numerics mirror `llama.decode_step_vec`'s attention exactly in form
+Numerics mirror `llama.decode_step_rows`' dense attention exactly in form
 (q.k^T with f32 accumulation, -1e30 mask, softmax weights cast to the
 compute dtype for the value matmul, f32 value accumulation); the
 reduction is blockwise-online rather than dense, so logits agree to
@@ -489,7 +489,7 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
                 p = jnp.where(valid, p * vs_buf[slot], 0.0)
             v = jnp.where(row_tok <= live, v, jnp.zeros_like(v))
             # softmax weights cast to the compute dtype for the value
-            # matmul, f32 accumulation — decode_step_vec form; another
+            # matmul, f32 accumulation — decode_step_rows' dense form; another
             # head's columns weigh zero, so one product serves all
             acc = acc * corr + jax.lax.dot_general(
                 p.astype(q_dt), v, (((1,), (0,)), ((), ())),

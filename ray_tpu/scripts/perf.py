@@ -745,7 +745,7 @@ def measure_decode_kernel(*, batches=(16, 32, 64), n_new: int = 8,
                           seed: int = 0, kernel_interpret: bool = False,
                           ) -> Dict[str, Dict[str, float]]:
     """Bare-decode rows for the fused paged-attention kernel
-    (`ops/paged_attention.py`) vs the gather+`decode_step_vec`
+    (`ops/paged_attention.py`) vs the gather (dense `decode_step_rows`)
     reference route, plus the int8 pool-occupancy row.
 
     - `decode_b{B}_{pallas,gather}`: the same short-prompt workload at

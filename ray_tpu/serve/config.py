@@ -95,7 +95,7 @@ class LLMEngineConfig:
 
     The decode/quantization plane:
     - `decode_kernel`: "auto" (fused Pallas paged-attention kernel on
-      TPU, compiled gather+`decode_step_vec` elsewhere), "pallas"
+      TPU, compiled gather + dense `decode_step_rows` elsewhere), "pallas"
       (the kernel, compiled: an engine that cannot compile it fails to
       start), or "gather" (the reference route).
     - `kv_dtype`: "model" stores KV in the compute dtype; "int8"

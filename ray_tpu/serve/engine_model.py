@@ -39,239 +39,224 @@ tells the engine
     so the host never has to tell the device that a row ended).
     Without `stop0, stop` it returns `(*cache, pos, tok)`.
 
-Two implementers: `LlamaEngineModel` (per-head K and V pools, optional
-int8 pools with a scale sidecar: the program bodies `LlamaEngine` has
-always run, moved here unchanged) and `LatentMoeEngineModel`
-(`models/deepseek_v3.py`: ONE latent pool, absorbed decode attention,
-dropless experts).  `engine_model_for` picks by the config's type: a
-user passes a model's config and the model picks its route.
+`PagedKV` states the cache FORMAT once: its leaves, blocks -> rows of
+the compute dtype, rows -> blocks (int8 pools with their scale sidecar
+are a value of it, not a family of bodies).  `chunk_program` is the
+decode chunk: liveness, the greedy pick, the positions, row 0 and the
+aux rows, around ONE decode step the model hands it.
+`kv_write_program` is `kv_write`'s flat signature and the admitted
+slot's state.
+
+Two implementers: `LlamaEngineModel` (per-head K and V pools) and
+`LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
+absorbed decode attention, dropless experts).  `engine_model_for` picks
+by the config's type, builds the format from the user's `kv_dtype` and
+hands the implementer the resolved route: a user passes a model's
+config and the model picks its route.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import deepseek_v3, llama
+from ray_tpu.ops import paged_attention as _pa
 from ray_tpu.serve.kv_cache import CacheLeaf
 
-
-def _set_slot(slot, pos0, tok0, pos, tok, stop0, stop):
-    """The tail of both `kv_write` bodies: an admitted slot's rows of
-    the device state.  A caller that only wants blocks written passes
-    no stop and gets `(pos, tok)` back."""
-    out = (pos.at[slot].set(pos0), tok.at[slot].set(tok0))
-    if stop is not None:
-        out += (stop.at[slot].set(stop0),)
-    return out
+KV_DTYPES = ("model", "int8")
 
 
-class LlamaEngineModel:
-    """Per-head K / V pools `[L, num_blocks, block_size, KV, hd]`; with
-    `kv_dtype="int8"` an int8 payload plus a float32 scale sidecar
-    `[L, num_blocks, block_size, KV]` per pool."""
+class PagedKV:
+    """How cached rows lie in a pool's blocks: the one place that knows.
+
+    A cache is a tuple of leaves `[layers, num_blocks, block_size,
+    *tail]`, one per entry of `rows` (name -> the tail one token
+    caches).  `kv_dtype` "model" stores the rows in the compute dtype
+    `dtype`.  "int8" stores a symmetric int8 payload per leaf (half the
+    bytes of bf16) and, after the payloads, a float32 scale sidecar per
+    leaf with one scale per (layer, row, kv head): the tail without its
+    last axis (`ops/paged_attention.quantize_int8`; the paged kernels
+    take the leaves as they lie and fuse the dequant).  `used`: see
+    `CacheLeaf`."""
+
+    def __init__(self, rows: Dict[str, Tuple[int, ...]], dtype,
+                 block_size: int, kv_dtype: str = "model",
+                 used: Optional[int] = None):
+        if kv_dtype not in KV_DTYPES:
+            raise ValueError(f"kv_dtype={kv_dtype!r} not in {KV_DTYPES}")
+        self.kv_dtype, self.dtype = kv_dtype, dtype
+        self.block_size, self.n_rows = block_size, len(rows)
+        self._int8 = kv_dtype == "int8"
+        self.leaves: List[CacheLeaf] = [
+            CacheLeaf(name, tail, jnp.int8 if self._int8 else dtype,
+                      used=used) for name, tail in rows.items()]
+        if self._int8:
+            self.leaves += [
+                CacheLeaf(f"{name}_scale", tail[:-1], jnp.float32,
+                          sidecar=True) for name, tail in rows.items()]
+
+    def rows(self, cache: Sequence, blk) -> tuple:
+        """Blocks -> rows: the blocks `blk` names (`[n]`, one sequence's,
+        or `[B, n]`, a batch's tables) as dense rows of the compute
+        dtype, one `[L, B, n * block_size, *tail]` per row leaf."""
+        lead = blk.shape[:-1] or (1,)
+
+        def take(pool):
+            x = jnp.take(pool, blk, axis=1)
+            return x.reshape((x.shape[0],) + lead + (-1,) + pool.shape[3:])
+
+        taken = [take(pool) for pool in cache]
+        if not self._int8:
+            return tuple(taken)
+        return tuple(_pa.dequantize_int8(q, s, self.dtype) for q, s in
+                     zip(taken[:self.n_rows], taken[self.n_rows:]))
+
+    def write(self, cache: Sequence, blk, rows: Sequence, span=None) -> tuple:
+        """Rows -> blocks: `rows` (one array per row leaf, `[L, ..., n *
+        block_size, *tail]`, any float type) into the blocks `blk`
+        names, `[n]` or `[B, n]`; returns the cache.  `span` = `(lo,
+        hi)`, `[B]` positions: the rows are a view that `rows()` made
+        and of which only `lo[b] <= row < hi[b]` were written since."""
+        def blocks(x, pool):
+            return x.reshape((x.shape[0],) + blk.shape + pool.shape[2:])
+
+        if not self._int8:
+            # a view's untouched rows are the pool's own bytes: blocks
+            # that sequences share get the same values from every sharer
+            # and padding rows land in the scratch block, so duplicate
+            # indices are benign
+            new = [blocks(r.astype(pool.dtype), pool)
+                   for pool, r in zip(cache, rows)]
+        else:
+            quant = [_pa.quantize_int8(r) for r in rows]
+            new = [blocks(x, pool) for pool, x in zip(
+                cache, [q for q, _ in quant] + [s for _, s in quant])]
+            if span is not None:
+                # requantize ONLY the rows written since the view was
+                # made; untouched rows keep their stored payload+scale
+                # bit-exactly, so repeated gather/scatter cycles cannot
+                # drift the cache (a full-view requant would re-round
+                # every row through the compute dtype each chunk)
+                idx = jnp.arange(blk.shape[-1] * self.block_size)[None, :]
+                touched = ((idx >= span[0][:, None])
+                           & (idx < span[1][:, None])).reshape(
+                               (1,) + blk.shape + (self.block_size,))
+                new = [jnp.where(
+                    jnp.expand_dims(touched, tuple(range(4, x.ndim))),
+                    x, jnp.take(pool, blk, axis=1))
+                    for pool, x in zip(cache, new)]
+        return tuple(pool.at[:, blk].set(x) for pool, x in zip(cache, new))
+
+
+def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
+                  aux=None):
+    """THE decode chunk, `(params, *cache, tables, tok, pos, stop) ->
+    (*cache, tok, pos, toks)`: `chunk` greedy steps in one `lax.scan`.
+
+    `step(params, tok, cache, tables, pos, live) -> (logits, cache,
+    stats)` is the model's decode step at per-row positions; `cache` is
+    a tuple of arrays in and out, `stats` a tuple of per-step scalars.
+    `gather` None: the step runs on the pool in place through the
+    tables (the paged kernels).  `gather` the cache's format: the step
+    runs on the dense view `[L, slots, W * block_size, ...]` of every
+    slot's blocks, and the view goes back into the pool after the scan
+    (`pos` before and after it say which rows the chunk wrote).
+    `aux(stats)`: the model's `aux_rows` counters, `[aux_rows]`, from
+    the steps' stacked stats."""
+    def _fn(params, *flat):
+        *pool, tables, tok, pos, stop = flat
+        cache = tuple(pool) if gather is None else gather.rows(pool, tables)
+
+        def body(carry, _):
+            tok, cache, pos = carry
+            # a row owes a token while it is short of its stop; a dead
+            # row stays where it is
+            live = pos < stop
+            logits, cache, stats = step(params, tok, cache, tables, pos, live)
+            nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (nt, cache, jnp.where(live, pos + 1, pos)), (nt, stats)
+
+        # pre-chunk tokens: a freshly admitted slot's FIRST token (from
+        # prefill).  Emitting it here as row 0 means admission never
+        # needs its own device->host read (one round trip PER REQUEST)
+        tok_in, pos_in = tok, pos
+        (tok, cache, pos), (toks, stats) = jax.lax.scan(
+            body, (tok, cache, pos), None, length=chunk)
+        if gather is not None:
+            cache = gather.write(pool, tables, cache, span=(pos_in, pos))
+        counters = None if aux is None else aux(stats)
+        rows = [tok_in[None], toks]
+        if counters is not None:
+            rows.append(jnp.broadcast_to(
+                counters[:, None], counters.shape + tok.shape
+            ).astype(jnp.int32))
+        return (*cache, tok, pos, jnp.concatenate(rows, axis=0))
+
+    return _fn
+
+
+def kv_write_program(kv: PagedKV, fit):
+    """`kv_write`, `(*cache, *kv, blk_ids, slot, pos0, tok0, pos, tok
+    [, stop0, stop]) -> (*cache, pos, tok [, stop])`: the prefilled
+    rows, which `fit(*kv)` cuts or pads to exactly `blk_ids`' blocks,
+    into the pool, and the admitted slot's rows of the device state.  A
+    caller that only wants blocks written passes no stop."""
+    n = len(kv.leaves)
+
+    def _fn(*flat):
+        cache, rows = flat[:n], flat[n:n + kv.n_rows]
+        blk_ids, slot, pos0, tok0, pos, tok, *stop = flat[n + kv.n_rows:]
+        cache = kv.write(cache, blk_ids, fit(*rows))
+        state = (pos.at[slot].set(pos0), tok.at[slot].set(tok0))
+        if stop:
+            state += (stop[1].at[slot].set(stop[0]),)
+        return (*cache, *state)
+
+    return _fn
+
+
+class _EngineModel:
+    """What the engine reads off either implementer besides its four
+    bodies: `cache_leaves`, `n_layers`, `kv.kv_dtype`, `aux_rows` and
+    `tick_fields` (the model's own per-tick counters; none here)."""
 
     aux_rows = 0
 
-    def __init__(self, cfg, *, slots: int, max_len: int, chunk: int,
-                 block_size: int, decode_kernel: str, kv_int8: bool,
-                 kernel_interpret: bool):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.models import llama
-
-        self._jax, self._jnp, self._llama = jax, jnp, llama
-        self.cfg = cfg
-        self.slots, self.max_len, self.chunk = slots, max_len, chunk
-        self.block_size = block_size
-        self._decode_kernel = decode_kernel
-        self._kv_int8 = kv_int8
-        self._kernel_interpret = kernel_interpret
-        KV, hd = cfg.n_kv_heads, cfg.head_dim
-        payload = jnp.int8 if kv_int8 else cfg.dtype
+    def __init__(self, cfg, kv: PagedKV, *, chunk: int, paged: bool,
+                 interpret: bool):
+        self.cfg, self.kv, self.chunk = cfg, kv, chunk
+        self._paged, self._interpret = paged, interpret
         self.n_layers = cfg.n_layers
-        self.cache_leaves: List[CacheLeaf] = [
-            CacheLeaf("k", (KV, hd), payload), CacheLeaf("v", (KV, hd), payload)]
-        if kv_int8:
-            # one f32 scale per (layer, row, kv-head), written by the
-            # same paths that write KV rows
-            self.cache_leaves += [
-                CacheLeaf("k_scale", (KV,), jnp.float32, sidecar=True),
-                CacheLeaf("v_scale", (KV,), jnp.float32, sidecar=True)]
-        self.n_kv = 2  # prefill hands back K and V
+        self.cache_leaves = kv.leaves
 
     def tick_fields(self, aux) -> Dict[str, object]:
         return {}
 
-    # -- compiled-program bodies (moved from LlamaEngine, unchanged) ----
+
+class LlamaEngineModel(_EngineModel):
+    """Per-head K / V pools `[L, num_blocks, block_size, KV, hd]` in the
+    format `kv` (`PagedKV`).  `paged`: the decode step reads and writes
+    the pool in place through the Pallas kernels (`interpret`: in the
+    Pallas interpreter); else through the gathered dense view."""
+
+    # -- compiled-program bodies ---------------------------------------
     def decode_chunk(self, W: int):
-        jax, jnp, llama = self._jax, self._jnp, self._llama
-        cfg, bs, chunk = self.cfg, self.block_size, self.chunk
-        L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        S = self.slots
+        cfg, paged, interpret = self.cfg, self._paged, self._interpret
 
-        if self._decode_kernel == "pallas":
-            interp = self._kernel_interpret
-            if self._kv_int8:
-                def _fn(params, k_pool, v_pool, k_scale, v_scale,
-                        tables, tok, pos, stop):
-                    def body(carry, _):
-                        tok, kp, vp, ks, vs, pos = carry
-                        live = pos < stop
-                        logits, kp, vp, ks, vs = llama.decode_step_paged(
-                            cfg, params, tok, kp, vp, tables, pos,
-                            kv_scales=(ks, vs), live=live, interpret=interp,
-                        )
-                        nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        pos2 = jnp.where(live, pos + 1, pos)
-                        return (nt, kp, vp, ks, vs, pos2), nt
+        def step(params, tok, cache, tables, pos, live):
+            logits, cache = llama.decode_step_rows(
+                cfg, params, tok, cache, pos,
+                tables=tables if paged else None, live=live,
+                interpret=interpret)
+            return logits, cache, ()
 
-                    tok_in = tok
-                    (tok, k_pool, v_pool, k_scale, v_scale, pos), toks = \
-                        jax.lax.scan(
-                            body,
-                            (tok, k_pool, v_pool, k_scale, v_scale, pos),
-                            None, length=chunk,
-                        )
-                    return (k_pool, v_pool, k_scale, v_scale, tok, pos,
-                            jnp.concatenate([tok_in[None], toks], axis=0))
-
-            else:
-                def _fn(params, k_pool, v_pool, tables, tok, pos, stop):
-                    def body(carry, _):
-                        tok, kp, vp, pos = carry
-                        # a row owes a token while it is short of its
-                        # stop; a dead row stays where it is
-                        live = pos < stop
-                        logits, kp, vp = llama.decode_step_paged(
-                            cfg, params, tok, kp, vp, tables, pos,
-                            live=live, interpret=interp,
-                        )
-                        nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        pos2 = jnp.where(live, pos + 1, pos)
-                        return (nt, kp, vp, pos2), nt
-
-                    tok_in = tok  # pre-chunk tokens (see gather route)
-                    (tok, k_pool, v_pool, pos), toks = jax.lax.scan(
-                        body, (tok, k_pool, v_pool, pos), None,
-                        length=chunk,
-                    )
-                    return k_pool, v_pool, tok, pos, jnp.concatenate(
-                        [tok_in[None], toks], axis=0
-                    )
-
-        elif self._kv_int8:
-            from ray_tpu.ops import paged_attention as _pa
-
-            def _fn(params, k_pool, v_pool, k_scale, v_scale, tables,
-                    tok, pos, stop):
-                # gather payload + scales, dequant to the compute dtype
-                kq = jnp.take(k_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-                vq = jnp.take(v_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-                ks = jnp.take(k_scale, tables, axis=1).reshape(
-                    L, S, W * bs, KV
-                )
-                vs = jnp.take(v_scale, tables, axis=1).reshape(
-                    L, S, W * bs, KV
-                )
-                k = _pa.dequantize_int8(kq, ks, cfg.dtype)
-                v = _pa.dequantize_int8(vq, vs, cfg.dtype)
-                pos0 = pos
-
-                def body(carry, _):
-                    tok, kv, pos = carry[0], (carry[1], carry[2]), carry[3]
-                    live = pos < stop
-                    logits, (k2, v2) = llama.decode_step_vec(
-                        cfg, params, tok, kv, pos, live
-                    )
-                    nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    pos2 = jnp.where(live, pos + 1, pos)
-                    return (nt, k2, v2, pos2), nt
-
-                tok_in = tok
-                (tok, k, v, pos), toks = jax.lax.scan(
-                    body, (tok, k, v, pos), None, length=chunk
-                )
-                # requantize ONLY the rows this chunk wrote (a row's
-                # live steps: pos0 up to where it stands now); untouched
-                # rows keep their stored payload+scale bit-exactly, so
-                # repeated gather/scatter cycles cannot drift the cache
-                # (a full-view requant would re-round every row through
-                # the compute dtype each chunk)
-                idx = jnp.arange(W * bs)[None, :]
-                touched = ((idx >= pos0[:, None])
-                           & (idx < pos[:, None]))  # [S, M]
-                kq2, ks2 = _pa.quantize_int8(k)
-                vq2, vs2 = _pa.quantize_int8(v)
-                t_p = touched[None, :, :, None, None]
-                t_s = touched[None, :, :, None]
-                kq2 = jnp.where(t_p, kq2, kq)
-                vq2 = jnp.where(t_p, vq2, vq)
-                ks2 = jnp.where(t_s, ks2, ks)
-                vs2 = jnp.where(t_s, vs2, vs)
-                k_pool = k_pool.at[:, tables].set(
-                    kq2.reshape(L, S, W, bs, KV, hd)
-                )
-                v_pool = v_pool.at[:, tables].set(
-                    vq2.reshape(L, S, W, bs, KV, hd)
-                )
-                k_scale = k_scale.at[:, tables].set(
-                    ks2.reshape(L, S, W, bs, KV)
-                )
-                v_scale = v_scale.at[:, tables].set(
-                    vs2.reshape(L, S, W, bs, KV)
-                )
-                return (k_pool, v_pool, k_scale, v_scale, tok, pos,
-                        jnp.concatenate([tok_in[None], toks], axis=0))
-
-        else:
-            def _fn(params, k_pool, v_pool, tables, tok, pos, stop):
-                # tables [slots, W] -> dense [L, slots, W*bs, KV, hd]
-                k = jnp.take(k_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-                v = jnp.take(v_pool, tables, axis=1).reshape(
-                    L, S, W * bs, KV, hd
-                )
-
-                def body(carry, _):
-                    tok, kv, pos = carry[0], (carry[1], carry[2]), carry[3]
-                    live = pos < stop
-                    logits, (k2, v2) = llama.decode_step_vec(
-                        cfg, params, tok, kv, pos, live
-                    )
-                    nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    pos2 = jnp.where(live, pos + 1, pos)
-                    return (nt, k2, v2, pos2), nt
-
-                tok_in = tok  # pre-chunk tokens: a freshly admitted
-                # slot's FIRST token (from prefill) — emitting it here
-                # means admission never needs its own device->host read
-                # (one full round trip PER REQUEST)
-                (tok, k, v, pos), toks = jax.lax.scan(
-                    body, (tok, k, v, pos), None, length=chunk
-                )
-                # scatter the (updated) blocks back into the pool.
-                # Shared prefix blocks scatter identical, unmodified
-                # values from every sharer (a dead row wrote nothing
-                # into its view); padding rows target the scratch
-                # block — both make duplicate indices benign.
-                kb = k.reshape(L, S, W, bs, KV, hd)
-                vb = v.reshape(L, S, W, bs, KV, hd)
-                k_pool = k_pool.at[:, tables].set(kb)
-                v_pool = v_pool.at[:, tables].set(vb)
-                # [1 + chunk, slots]: row 0 = pre-chunk tokens
-                return k_pool, v_pool, tok, pos, jnp.concatenate(
-                    [tok_in[None], toks], axis=0
-                )
-        return _fn
+        return chunk_program(step, self.chunk,
+                             gather=None if paged else self.kv)
 
     def prefill(self, bucket: int):
-        llama = self._llama
-
         def _pf(params, prompt):  # prompt [1, bucket]
             # full-sequence logits (not llama.prefill's last-pos
             # form): the prompt is right-padded to the bucket, so
@@ -287,101 +272,30 @@ class LlamaEngineModel:
         return _pf
 
     def suffix_prefill(self, s_bucket: int, p_blocks: int):
-        jax, jnp, llama = self._jax, self._jnp, self._llama
-        cfg, bs = self.cfg, self.block_size
-        L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        def _pf(params, *flat):
+            *cache, suffix, blk_ids, prefix_len = flat
+            logits, (ks, vs) = llama.forward_with_prefix(
+                self.cfg, params, suffix, self.kv.rows(cache, blk_ids),
+                prefix_len)
+            return logits[0], ks, vs
 
-        if self._kv_int8:
-            from ray_tpu.ops import paged_attention as _pa
-
-            def _pf(params, k_pool, v_pool, k_scale, v_scale,
-                    suffix, blk_ids, prefix_len):
-                pk = _pa.dequantize_int8(
-                    jnp.take(k_pool, blk_ids, axis=1),
-                    jnp.take(k_scale, blk_ids, axis=1), cfg.dtype,
-                ).reshape(L, 1, p_blocks * bs, KV, hd)
-                pv = _pa.dequantize_int8(
-                    jnp.take(v_pool, blk_ids, axis=1),
-                    jnp.take(v_scale, blk_ids, axis=1), cfg.dtype,
-                ).reshape(L, 1, p_blocks * bs, KV, hd)
-                logits, (ks, vs) = llama.forward_with_prefix(
-                    cfg, params, suffix, (pk, pv), prefix_len
-                )
-                return logits[0], ks, vs
-        else:
-            def _pf(params, k_pool, v_pool, suffix, blk_ids,
-                    prefix_len):
-                pk = jnp.take(k_pool, blk_ids, axis=1).reshape(
-                    L, 1, p_blocks * bs, KV, hd
-                )
-                pv = jnp.take(v_pool, blk_ids, axis=1).reshape(
-                    L, 1, p_blocks * bs, KV, hd
-                )
-                logits, (ks, vs) = llama.forward_with_prefix(
-                    cfg, params, suffix, (pk, pv), prefix_len
-                )
-                return logits[0], ks, vs
         return _pf
 
     def kv_write(self, t_in: int, nb: int):
-        jax, jnp = self._jax, self._jnp
-        bs = self.block_size
-        L, KV, hd = (self.cfg.n_layers, self.cfg.n_kv_heads,
-                     self.cfg.head_dim)
-        target = nb * bs
+        target = nb * self.kv.block_size
 
-        def _clip(k1, v1):
+        def fit(k1, v1):
             # k1/v1 [L, 1, t_in, KV, hd] -> exactly nb blocks
             if t_in < target:
                 pad = [(0, 0), (0, 0), (0, target - t_in), (0, 0),
                        (0, 0)]
                 return jnp.pad(k1, pad), jnp.pad(v1, pad)
-            if t_in > target:
-                return k1[:, :, :target], v1[:, :, :target]
-            return k1, v1
+            return k1[:, :, :target], v1[:, :, :target]
 
-        if self._kv_int8:
-            from ray_tpu.ops import paged_attention as _pa
-
-            def _fn(k_pool, v_pool, k_scale, v_scale, k1, v1,
-                    blk_ids, slot, pos0, tok0, pos, tok,
-                    stop0=None, stop=None):
-                k1, v1 = _clip(k1, v1)
-                kq, ksc = _pa.quantize_int8(k1)  # [L,1,target,KV]
-                vq, vsc = _pa.quantize_int8(v1)
-                k_pool = k_pool.at[:, blk_ids].set(
-                    kq.reshape(L, nb, bs, KV, hd)
-                )
-                v_pool = v_pool.at[:, blk_ids].set(
-                    vq.reshape(L, nb, bs, KV, hd)
-                )
-                k_scale = k_scale.at[:, blk_ids].set(
-                    ksc.reshape(L, nb, bs, KV)
-                )
-                v_scale = v_scale.at[:, blk_ids].set(
-                    vsc.reshape(L, nb, bs, KV)
-                )
-                return (k_pool, v_pool, k_scale, v_scale) + _set_slot(
-                    slot, pos0, tok0, pos, tok, stop0, stop)
-
-        else:
-            def _fn(k_pool, v_pool, k1, v1, blk_ids, slot, pos0,
-                    tok0, pos, tok, stop0=None, stop=None):
-                k1, v1 = _clip(k1, v1)
-                kb = k1.astype(k_pool.dtype).reshape(
-                    L, nb, bs, KV, hd
-                )
-                vb = v1.astype(v_pool.dtype).reshape(
-                    L, nb, bs, KV, hd
-                )
-                k_pool = k_pool.at[:, blk_ids].set(kb)
-                v_pool = v_pool.at[:, blk_ids].set(vb)
-                return (k_pool, v_pool) + _set_slot(
-                    slot, pos0, tok0, pos, tok, stop0, stop)
-        return _fn
+        return kv_write_program(self.kv, fit)
 
 
-class LatentMoeEngineModel:
+class LatentMoeEngineModel(_EngineModel):
     """`models/deepseek_v3.py` behind the seam: ONE latent pool
     `[L, num_blocks, block_size, Dp]` — a token and layer cache the
     normalised compressed KV beside the rotated shared key, 576 values
@@ -390,37 +304,15 @@ class LatentMoeEngineModel:
     (and the suffix prefill behind a cached prefix) expands the latents
     through `W_kvb`; decode is the absorbed form on the pool as it
     lies.  The decode program hands back two counters of the expert
-    layers with its tokens (`aux_rows`)."""
+    layers with its tokens (`aux_rows`).  `paged`: latent kernels +
+    megablox grouped products (TPU); else the dense view +
+    `lax.ragged_dot` (anywhere)."""
 
     aux_rows = 2  # [experts_touched summed over the chunk | load_max]
 
-    def __init__(self, cfg, *, slots: int, max_len: int, chunk: int,
-                 block_size: int, decode_kernel: str, kv_int8: bool,
-                 kernel_interpret: bool):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.models import deepseek_v3
-        from ray_tpu.ops.paged_attention import mla_pool_width
-
-        if kv_int8:
-            raise ValueError(
-                "kv_dtype='int8' quantizes per-head K and V rows; the "
-                "latent pool has no heads to scale by")
-        self._jax, self._jnp, self._m = jax, jnp, deepseek_v3
-        self.cfg = cfg
-        self.slots, self.max_len, self.chunk = slots, max_len, chunk
-        self.block_size = block_size
-        # "pallas": latent kernels + megablox grouped products (TPU);
-        # "gather": dense view + `lax.ragged_dot` (anywhere)
-        self._kernel = decode_kernel == "pallas"
-        self._interpret = kernel_interpret
-        self.n_layers = cfg.n_layers
-        self.width = mla_pool_width(cfg.latent_dim)
-        self.cache_leaves: List[CacheLeaf] = [
-            CacheLeaf("latent", (self.width,), cfg.dtype,
-                      used=cfg.latent_dim)]
-        self.n_kv = 1
+    def __init__(self, cfg, kv: PagedKV, **route):
+        super().__init__(cfg, kv, **route)
+        self.width = kv.leaves[0].tail[0]
         self._pairs = cfg.n_moe_layers * cfg.n_routed_experts
 
     def tick_fields(self, aux) -> Dict[str, object]:
@@ -435,90 +327,63 @@ class LatentMoeEngineModel:
     def _kw(self):
         # interpret mode walks the grouped product tile by tile in
         # Python: the CPU kernel tests take `lax.ragged_dot` instead
-        return dict(kernel=self._kernel and not self._interpret,
+        return dict(kernel=self._paged and not self._interpret,
                     interpret=self._interpret)
 
     def decode_chunk(self, W: int):
-        jax, jnp, m = self._jax, self._jnp, self._m
-        cfg, bs, chunk, S = self.cfg, self.block_size, self.chunk, self.slots
-        L, Dp = cfg.n_layers, self.width
-        paged, kw = self._kernel, self._kw()
+        cfg, paged, kw = self.cfg, self._paged, self._kw()
 
-        def _fn(params, pool, tables, tok, pos, stop):
-            # kernel route: the pool in place through the tables; else
-            # the dense view [L, slots, W * bs, Dp], scattered back
-            cache = pool if paged else jnp.take(
-                pool, tables, axis=1).reshape(L, S, W * bs, Dp)
+        def step(params, tok, cache, tables, pos, live):
+            logits, pool, st = deepseek_v3.decode_step(
+                cfg, params, tok, cache[0], pos,
+                tables=tables if paged else None, live=live, **kw)
+            return logits, (pool,), (st["experts_touched"], st["load_max"])
 
-            def body(carry, _):
-                tok, cache, pos = carry
-                live = pos < stop
-                logits, cache, st = m.decode_step(
-                    cfg, params, tok, cache, pos,
-                    tables=tables if paged else None, live=live, **kw)
-                nt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                pos2 = jnp.where(live, pos + 1, pos)
-                return (nt, cache, pos2), (
-                    nt, st["experts_touched"], st["load_max"])
-
-            tok_in = tok  # pre-chunk tokens: row 0 (see LlamaEngineModel)
-            (tok, cache, pos), (toks, touched, load) = jax.lax.scan(
-                body, (tok, cache, pos), None, length=chunk)
-            if not paged:
-                cache = pool.at[:, tables].set(
-                    cache.reshape(L, S, W, bs, Dp))
-            aux = jnp.stack([jnp.sum(touched), jnp.max(load)])
-            return cache, tok, pos, jnp.concatenate(
-                [tok_in[None], toks,
-                 jnp.broadcast_to(aux[:, None], (2, S)).astype(jnp.int32)],
-                axis=0)
-
-        return _fn
+        return chunk_program(
+            step, self.chunk, gather=None if paged else self.kv,
+            aux=lambda st: jnp.stack([jnp.sum(st[0]), jnp.max(st[1])]))
 
     def prefill(self, bucket: int):
         def _pf(params, prompt):  # prompt [1, bucket], right-padded
-            logits, lat = self._m.forward(self.cfg, params, prompt,
-                                          return_kv=True, **self._kw())
+            logits, lat = deepseek_v3.forward(
+                self.cfg, params, prompt, return_kv=True, **self._kw())
             return logits[0], lat  # lat [L, 1, bucket, 576]
 
         return _pf
 
     def suffix_prefill(self, s_bucket: int, p_blocks: int):
-        jnp = self._jnp
-        L, bs = self.cfg.n_layers, self.block_size
-
         def _pf(params, pool, suffix, blk_ids, prefix_len):
-            prefix = jnp.take(pool, blk_ids, axis=1).reshape(
-                L, 1, p_blocks * bs, self.width)
-            logits, lat = self._m.forward_with_prefix(
-                self.cfg, params, suffix, prefix, prefix_len, **self._kw())
+            logits, lat = deepseek_v3.forward_with_prefix(
+                self.cfg, params, suffix, self.kv.rows((pool,), blk_ids)[0],
+                prefix_len, **self._kw())
             return logits[0], lat
 
         return _pf
 
     def kv_write(self, t_in: int, nb: int):
-        jnp = self._jnp
-        L, bs = self.cfg.n_layers, self.block_size
-        target = nb * bs
+        target = nb * self.kv.block_size
 
-        def _fn(pool, lat, blk_ids, slot, pos0, tok0, pos, tok,
-                stop0=None, stop=None):
+        def fit(lat):
             # lat [L, 1, t_in, 576] -> exactly nb blocks of Dp columns
             lat = lat[:, 0, :target]
-            lat = jnp.pad(lat, ((0, 0), (0, target - lat.shape[1]),
-                                (0, self.width - lat.shape[2])))
-            pool = pool.at[:, blk_ids].set(
-                lat.astype(pool.dtype).reshape(L, nb, bs, self.width))
-            return (pool,) + _set_slot(slot, pos0, tok0, pos, tok, stop0,
-                                       stop)
+            return (jnp.pad(lat, ((0, 0), (0, target - lat.shape[1]),
+                                  (0, self.width - lat.shape[2]))),)
 
-        return _fn
+        return kv_write_program(self.kv, fit)
 
 
-def engine_model_for(cfg, **kw):
-    """The implementer for a model's config: the model picks its route."""
-    from ray_tpu.models.deepseek_v3 import DeepseekV3Config
-
-    if isinstance(cfg, DeepseekV3Config):
-        return LatentMoeEngineModel(cfg, **kw)
-    return LlamaEngineModel(cfg, **kw)
+def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
+    """The implementer for a model's config, its cache in the format
+    the user's `kv_dtype` names: the model picks its route."""
+    if isinstance(cfg, deepseek_v3.DeepseekV3Config):
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' quantizes per-head K and V rows; the "
+                "latent pool has no heads to scale by")
+        width = _pa.mla_pool_width(cfg.latent_dim)
+        return LatentMoeEngineModel(cfg, PagedKV(
+            {"latent": (width,)}, cfg.dtype, block_size, kv_dtype,
+            used=cfg.latent_dim), **route)
+    tail = (cfg.n_kv_heads, cfg.head_dim)
+    return LlamaEngineModel(cfg, PagedKV(
+        {"k": tail, "v": tail}, cfg.dtype, block_size, kv_dtype), **route)

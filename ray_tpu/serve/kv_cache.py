@@ -50,39 +50,23 @@ class CacheLeaf:
     used: Optional[int] = None
 
 
-KV_DTYPES = ("model", "int8")
-
-
 class BlockPool:
     """Free-list allocator over device KV-pool block ids.
 
     `num_blocks` counts ALL blocks including the reserved scratch block
-    0, which is never handed out.
+    0, which is never handed out.  Pure bookkeeping: block ids are
+    blind to what a block holds, and the device arrays are sized by the
+    model's cache spec."""
 
-    `kv_dtype` declares the DEVICE pool's element type: "model" stores
-    K/V in the model's compute dtype; "int8" stores a symmetric
-    per-row-per-kv-head int8 payload (half of bf16 per element) with an
-    f32 scale sidecar `[L, num_blocks, block_size, KV]` living beside
-    the pool — the engine allocates both and the kernels in
-    `ops/paged_attention.py` fuse the dequant.  Pure bookkeeping here
-    (block ids are dtype-blind); the pool carries the declaration so
-    every consumer sizes and interprets the device tensors the same
-    way."""
-
-    def __init__(self, num_blocks: int, kv_dtype: str = "model",
-                 spec: Sequence[CacheLeaf] = ()):
+    def __init__(self, num_blocks: int, spec: Sequence[CacheLeaf] = ()):
         """`spec`: the model's cache leaves.  The pool allocates what
-        the spec says (`leaf_shapes`): two per-head pools for a Llama, a
-        single latent pool for an MLA model; block ids, the scratch
-        block and the radix cache do not care which."""
+        the spec says (`leaf_shapes`): two per-head pools for a Llama
+        (and their scale sidecars, where the format is int8), a single
+        latent pool for an MLA model; block ids, the scratch block and
+        the radix cache do not care which."""
         if num_blocks < 2:
             raise ValueError("block pool needs >= 2 blocks (1 is scratch)")
-        if kv_dtype not in KV_DTYPES:
-            raise ValueError(
-                f"kv_dtype={kv_dtype!r} not in {KV_DTYPES}"
-            )
         self.num_blocks = num_blocks
-        self.kv_dtype = kv_dtype
         self.spec: Tuple[CacheLeaf, ...] = tuple(spec)
         # pop() from the tail hands out low ids first (stable layouts
         # across runs -> deterministic tests)

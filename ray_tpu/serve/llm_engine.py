@@ -14,15 +14,16 @@ TPU-native design points:
   chunk dispatch gathers every slot's live blocks into a dense
   `[L, slots, W*block_size, ...]` view, runs `chunk` decode steps on
   it (one `lax.scan` per dispatch, per-row positions via
-  `llama.decode_step_vec`), and scatters the blocks back.  The gather
+  `llama.decode_step_rows`), and scatters the blocks back.  The gather
   width W is the pow-2 bucket of the LONGEST live sequence's block
   count — per-step attention cost tracks LIVE tokens, not the pool
   budget, killing the measured "ring size is a per-step tax" cost
   (PERF.md round 5: a 1024-ring ran ~20x slower than a 192-ring).
 - FUSED DECODE KERNEL (`decode_kernel="pallas"`, what "auto" means
   on TPU): the gather/scatter copies die entirely —
-  `llama.decode_step_paged` reads and writes the pool IN PLACE through
-  the block tables via the Pallas kernels in `ops/paged_attention.py`
+  the same `llama.decode_step_rows`, handed the block tables, reads and
+  writes the pool IN PLACE through the Pallas kernels in
+  `ops/paged_attention.py`
   (tables in SMEM, split-KV walk with an online softmax,
   `input_output_aliases` for the append).  The route is resolved ONCE
   and never downgraded: the kernels compile and run a warm-up chunk in
@@ -104,11 +105,12 @@ class LlamaEngine:
     engine asks it for its cache spec (`cache_leaves`: the pool leaves
     and their per-block shapes, which `BlockPool` allocates) and for the
     bodies of prefill, suffix prefill, KV write and the paged decode
-    chunk, all with flat signatures `(params, *cache, ...)`.  Two
-    implementers, picked by the config's type (`engine_model_for`):
-    `LlamaEngineModel` — per-head K and V pools, the bodies this class
-    always ran, moved unchanged — and `LatentMoeEngineModel` — one
-    latent pool, absorbed decode attention, dropless experts
+    chunk, all with flat signatures `(params, *cache, ...)`.  The
+    cache's FORMAT (`kv_dtype`) is the model's too: the engine hands
+    the string over and reads it back for `stats()`.  Two implementers,
+    picked by the config's type (`engine_model_for`): `LlamaEngineModel`
+    — per-head K and V pools — and `LatentMoeEngineModel` — one latent
+    pool, absorbed decode attention, dropless experts
     (`models/deepseek_v3.py`).  The class keeps its name; nothing a
     caller passes changed.
 
@@ -176,18 +178,15 @@ class LlamaEngine:
         # True only when the CALLER asks (the CPU kernel tests): the
         # Pallas interpreter is a correctness vehicle, never a default
         self._kernel_interpret = bool(kernel_interpret)
-        # the model's side of the seam: its cache spec and the bodies
-        # of the four program families (`serve/engine_model.py`)
+        # the model's side of the seam: its cache spec, in the format
+        # `kv_dtype` names (a value it does not know is refused there),
+        # and the bodies of the four program families
+        # (`serve/engine_model.py`)
         self._model = engine_model_for(
-            cfg, slots=slots, max_len=self.max_len, chunk=chunk,
-            block_size=self.block_size, decode_kernel=mode,
-            kv_int8=kv_dtype == "int8",
-            kernel_interpret=self._kernel_interpret)
-        # +1: reserved scratch block.  kv_dtype is validated (and
-        # carried) by the pool: "int8" halves pool HBM and adds the f32
-        # scale sidecar the paged kernels dequant from.
-        self._pool = BlockPool(budget + 1, kv_dtype=kv_dtype,
-                               spec=self._model.cache_leaves)
+            cfg, kv_dtype=kv_dtype, block_size=self.block_size, chunk=chunk,
+            paged=mode == "pallas", interpret=self._kernel_interpret)
+        # +1: reserved scratch block
+        self._pool = BlockPool(budget + 1, spec=self._model.cache_leaves)
         if prefix_cache and getattr(cfg, "attention", "dense") != "dense":
             # the suffix prefill (`llama.forward_with_prefix`) mirrors
             # the DENSE attention numerics; under flash/ring/ulysses
@@ -336,15 +335,6 @@ class LlamaEngine:
         return tuple(
             self._jnp.zeros(shape, dtype) for shape, dtype in
             self._pool.leaf_shapes(self._model.n_layers, self.block_size))
-
-    # the first two leaves of a per-head cache, under their old names
-    @property
-    def _k_pool(self):
-        return self._cache[0]
-
-    @property
-    def _v_pool(self):
-        return self._cache[1]
 
     def _warm_kernel_route(self) -> None:
         """Compile and run one all-idle chunk through the kernel route
@@ -526,7 +516,7 @@ class LlamaEngine:
                 "decode_kernel": self._decode_kernel,
                 "kernel_interpret": self._kernel_interpret,
                 "device": dict(self._device),
-                "kv_dtype": self._pool.kv_dtype,
+                "kv_dtype": self._model.kv.kv_dtype,
                 "kv_pool_bytes": self._cache_bytes[0],
                 "kv_scale_bytes": self._cache_bytes[1],
                 "cache_bytes_per_token": self._cache_bytes_per_token,
@@ -1102,7 +1092,6 @@ class LlamaEngine:
                     # block returns to the pool and the radix cache
                     # empties (its pinned paths died with the requests)
                     self._pool = BlockPool(self._pool.num_blocks,
-                                           kv_dtype=self._pool.kv_dtype,
                                            spec=self._pool.spec)
                     if self._radix is not None:
                         self._radix = RadixCache(

@@ -194,7 +194,7 @@ def test_paged_kv_append(chip, int8):
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_llama1b4_decode_step_paged(chip, int8):
+def test_llama1b4_decode_step_rows_paged(chip, int8):
     """The engine's whole decode step at serving size: both kernels
     inside the layer scan, pools donated."""
     cfg = llama1b4()
@@ -204,16 +204,16 @@ def test_llama1b4_decode_step_paged(chip, int8):
 
     if int8:
         def fn(params, tok, kp, vp, ks, vs, tables, pos):
-            return llama.decode_step_paged(
-                cfg, params, tok, kp, vp, tables, pos, kv_scales=(ks, vs))
+            return llama.decode_step_rows(
+                cfg, params, tok, (kp, vp, ks, vs), pos, tables=tables)
 
         hlo = _compile(chip, fn, _bf16_params(cfg), tok, pool, pool,
                        scale, scale, tables, pos,
                        donate_argnums=(2, 3, 4, 5))
     else:
         def fn(params, tok, kp, vp, tables, pos):
-            return llama.decode_step_paged(
-                cfg, params, tok, kp, vp, tables, pos)
+            return llama.decode_step_rows(
+                cfg, params, tok, (kp, vp), pos, tables=tables)
 
         hlo = _compile(chip, fn, _bf16_params(cfg), tok, pool, pool,
                        tables, pos, donate_argnums=(2, 3))

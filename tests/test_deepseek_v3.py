@@ -157,15 +157,15 @@ def test_masked_rows_are_routed_nowhere_and_counted_nowhere(dead):
 # ----------------------------------------------------------------------
 def test_the_seam_picks_the_model_by_its_config(model):
     cfg, _ = model
-    kw = dict(slots=2, max_len=32, chunk=2, block_size=8,
-              decode_kernel="gather", kv_int8=False, kernel_interpret=False)
+    kw = dict(chunk=2, block_size=8, kv_dtype="model", paged=False,
+              interpret=False)
     assert isinstance(engine_model.engine_model_for(cfg, **kw),
                       engine_model.LatentMoeEngineModel)
     assert isinstance(
         engine_model.engine_model_for(llama.LlamaConfig.tiny(), **kw),
         engine_model.LlamaEngineModel)
     with pytest.raises(ValueError, match="latent pool has no heads"):
-        engine_model.engine_model_for(cfg, **{**kw, "kv_int8": True})
+        engine_model.engine_model_for(cfg, **{**kw, "kv_dtype": "int8"})
 
 
 def test_cache_spec_at_the_cells_sizes():
@@ -173,8 +173,8 @@ def test_cache_spec_at_the_cells_sizes():
     and layer (lane-padded to 640 on the device), 7 x 1,152 B a token
     against Mistral-7B's 16 x 4,096."""
     cfg = m.DeepseekV3Config(n_layers=7)
-    kw = dict(slots=64, max_len=2320, chunk=8, block_size=16,
-              decode_kernel="pallas", kv_int8=False, kernel_interpret=False)
+    kw = dict(chunk=8, block_size=16, kv_dtype="model", paged=True,
+              interpret=False)
     model = engine_model.engine_model_for(cfg, **kw)
     pool = BlockPool(9216 + 1, spec=model.cache_leaves)
     assert pool.leaf_shapes(7, 16) == [((7, 9217, 16, 640), jnp.bfloat16)]

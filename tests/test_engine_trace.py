@@ -280,18 +280,18 @@ def test_the_jitted_families_lower_under_their_names(engine):
     i32 = jnp.int32
     tables = jnp.zeros((e.slots, 2), i32)
     chunk = e._chunk_step_for(2).lower(
-        e.params, e._k_pool, e._v_pool, tables, e._tok, e._pos, e._stop)
+        e.params, *e._cache, tables, e._tok, e._pos, e._stop)
     assert "@jit_decode_chunk_w2" in chunk.as_text()
     prefill = e._prefill_for(16).lower(e.params, jnp.zeros((1, 16), i32))
     assert "@jit_prefill_b16" in prefill.as_text()
     kv1 = jnp.zeros((L, 1, 16, KV, hd), cfg.dtype)
     write = e._write_blocks_for(16, 2).lower(
-        e._k_pool, e._v_pool, kv1, kv1, jnp.zeros((2,), i32),
+        *e._cache, kv1, kv1, jnp.zeros((2,), i32),
         jnp.asarray(0, i32), jnp.asarray(12, i32), jnp.asarray(1, i32),
         e._pos, e._tok)
     assert "@jit_kv_write_t16_n2" in write.as_text()
     suffix = e._suffix_prefill_for(8, 2).lower(
-        e.params, e._k_pool, e._v_pool, jnp.zeros((1, 8), i32),
+        e.params, *e._cache, jnp.zeros((1, 8), i32),
         jnp.zeros((2,), i32), jnp.asarray(16, i32))
     assert "@jit_suffix_prefill_s8_p2" in suffix.as_text()
 

@@ -34,24 +34,6 @@ def _expected(cfg, params, prompt, n_new):
     return [int(t) for t in np.asarray(out)[0]]
 
 
-def test_decode_step_vec_matches_scalar_pos(model):
-    """Equal positions: the vector-pos step must reproduce the scalar
-    one exactly (same math, different mask/update plumbing)."""
-    cfg, params = model
-    B, T, M = 3, 8, 32
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0,
-                                cfg.vocab_size, jnp.int32)
-    logits, cache = llama.prefill(cfg, params, prompt, M)
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    l_s, c_s = llama.decode_step(cfg, params, tok, cache,
-                                 jnp.asarray(T, jnp.int32))
-    l_v, c_v = llama.decode_step_vec(cfg, params, tok, cache,
-                                     jnp.full((B,), T, jnp.int32))
-    np.testing.assert_allclose(l_s, l_v, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(c_s[0]), np.asarray(c_v[0]),
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_engine_matches_dedicated_generate(model):
     cfg, params = model
     eng = LlamaEngine(cfg, params, slots=4, max_len=64, chunk=4)

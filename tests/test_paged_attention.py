@@ -2,7 +2,7 @@
 
 The contract (`ops/paged_attention.py`): the Pallas split-KV kernel
 attending straight into the `BlockPool` tensor must reproduce the
-gather+`decode_step_vec` reference route — dense-reference numerics at
+gather (dense-view `decode_step_rows`) reference route — dense-reference numerics at
 fp32/bf16 across ragged block tables and partial last blocks, greedy
 engine outputs BIT-IDENTICAL kernel on vs off, and the int8 KV/weight
 planes gated on argmax-match plus bounded logit error.  Interpret mode
@@ -20,6 +20,7 @@ import jax.numpy as jnp  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.ops import paged_attention as pa  # noqa: E402
 from ray_tpu.serve.config import LLMEngineConfig  # noqa: E402
+from ray_tpu.serve.engine_model import PagedKV  # noqa: E402
 from ray_tpu.serve.llm_engine import LlamaEngine  # noqa: E402
 
 
@@ -321,39 +322,130 @@ def test_quantize_int8_idempotent_and_bounded():
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s), rtol=1e-6)
 
 
-def test_decode_step_paged_matches_decode_step_vec(model):
-    """Full-model parity: the paged step (append kernel + attention
-    kernel + pools as scan carry) against the dense-cache reference
-    step, from a real prefilled cache scattered into pool blocks."""
+def _per_head_kv(cfg, block_size, kv_dtype, dtype=None):
+    tail = (cfg.n_kv_heads, cfg.head_dim)
+    return PagedKV({"k": tail, "v": tail}, dtype or cfg.dtype, block_size,
+                   kv_dtype)
+
+
+def _empty_pool(kv, layers, num_blocks):
+    return tuple(jnp.zeros((layers, num_blocks, kv.block_size) + leaf.tail,
+                           leaf.dtype) for leaf in kv.leaves)
+
+
+@pytest.mark.parametrize("dead", [(), (1,)], ids=["all-live", "a-dead-row"])
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+@pytest.mark.parametrize("route", ["dense-view", "paged-interpret"])
+def test_decode_step_rows_matches_the_scalar_oracle(model, route, kv_dtype,
+                                                    dead):
+    """THE per-row step on both of its routes, in both cache formats,
+    against the scalar-position `decode_step` at equal positions, from
+    a real prefilled cache written into pool blocks through the format.
+    The oracle reads the cache as the format hands it back (int8 rounds
+    it), so the dense view must agree with it exactly; the paged
+    reduction is blockwise-online, and under int8 it also rounds the
+    new row before it attends.  A dead row writes nothing."""
     cfg, params = model
     B, T, M, BS = 3, 6, 16, 4
-    W = M // BS
-    NB = 1 + B * W
+    W, L = M // BS, cfg.n_layers
     prompt = jax.random.randint(jax.random.PRNGKey(5), (B, T), 0,
                                 cfg.vocab_size, jnp.int32)
-    logits, (kc, vc) = llama.prefill(cfg, params, prompt, M)
+    logits, cache = llama.prefill(cfg, params, prompt, M)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    pos = jnp.full((B,), T, jnp.int32)
-    l_ref, _ = llama.decode_step_vec(cfg, params, tok, (kc, vc), pos)
+    kv = _per_head_kv(cfg, BS, kv_dtype)
+    tables = jnp.arange(1, 1 + B * W, dtype=jnp.int32).reshape(B, W)
+    pool = kv.write(_empty_pool(kv, L, 1 + B * W), tables, cache)
+    stored = kv.rows(pool, tables)
+    l_ref, c_ref = llama.decode_step(cfg, params, tok, stored,
+                                     jnp.asarray(T, jnp.int32))
 
-    tables = np.arange(1, NB, dtype=np.int32).reshape(B, W)
-    L = cfg.n_layers
-    kp = np.zeros((L, NB) + (BS,) + kc.shape[3:], np.asarray(kc).dtype)
-    vp = np.zeros_like(kp)
-    for b in range(B):
-        for w in range(W):
-            kp[:, tables[b, w]] = np.asarray(
-                kc[:, b, w * BS:(w + 1) * BS])
-            vp[:, tables[b, w]] = np.asarray(
-                vc[:, b, w * BS:(w + 1) * BS])
-    l_paged, _, _ = llama.decode_step_paged(
-        cfg, params, tok, jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), pos, interpret=True
-    )
-    np.testing.assert_allclose(np.asarray(l_paged), np.asarray(l_ref),
-                               rtol=2e-2, atol=2e-2)
-    assert np.array_equal(np.argmax(np.asarray(l_paged), -1),
-                          np.argmax(np.asarray(l_ref), -1))
+    pos = jnp.full((B,), T, jnp.int32)
+    live = jnp.asarray([b not in dead for b in range(B)])
+    if route == "dense-view":
+        l_got, view = llama.decode_step_rows(cfg, params, tok, stored, pos,
+                                             live=live)
+        pool2 = kv.write(pool, tables, view, span=(pos, pos + live))
+        tol = 1e-5
+    else:
+        l_got, pool2 = llama.decode_step_rows(
+            cfg, params, tok, pool, pos, tables=tables, live=live,
+            interpret=True)
+        assert len(pool2) == len(pool)  # back in the arity it came in
+        tol = 2e-2 if kv_dtype == "model" else 5e-2
+    rows = [b for b in range(B) if b not in dead]
+    l_got, l_ref = np.asarray(l_got)[rows], np.asarray(l_ref)[rows]
+    np.testing.assert_allclose(l_got, l_ref, rtol=tol, atol=tol)
+    # the choice is the oracle's, or a tie inside the tolerance
+    chosen = np.take_along_axis(l_ref, l_got.argmax(-1)[:, None], 1)[:, 0]
+    assert np.all(l_ref.max(-1) - chosen <= (0 if tol == 1e-5 else tol))
+    # the cache: a live row holds the oracle's new row, to the format's
+    # rounding; nothing else moved, least of all a dead row's blocks
+    after = kv.rows(pool2, tables)
+    for got, want, before in zip(after, c_ref, stored):
+        got, want = np.asarray(got), np.asarray(want)
+        step = np.abs(want).max() / 127 if kv_dtype == "int8" else 0
+        np.testing.assert_allclose(got[:, rows, T], want[:, rows, T],
+                                   rtol=tol, atol=0.5 * step + tol)
+        keep = np.ones(got.shape[:3], bool)
+        keep[:, rows, T] = False
+        np.testing.assert_array_equal(got[keep], np.asarray(before)[keep])
+    for b in dead:
+        for leaf, leaf2 in zip(pool, pool2):
+            np.testing.assert_array_equal(
+                np.asarray(leaf2[:, np.asarray(tables[b])]),
+                np.asarray(leaf[:, np.asarray(tables[b])]))
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_paged_kv_write_then_view_round_trip(model, kv_dtype):
+    """The format object alone, with a bf16 compute dtype so that a
+    round trip through the view would re-round an int8 row.  Rows
+    written into blocks come back exactly ("model") or within half a
+    scale (int8); blocks not named keep every bit; and after a view is
+    written back with a span, ONLY the span's rows were re-encoded."""
+    cfg, _ = model
+    L, B, n, BS = 2, 3, 2, 4
+    KV, hd = cfg.n_kv_heads, cfg.head_dim
+    kv = _per_head_kv(cfg, BS, kv_dtype, dtype=jnp.bfloat16)
+    rng = np.random.default_rng(11)
+    pool = tuple(
+        jnp.asarray(rng.integers(-100, 100, (L, 9, BS) + leaf.tail), leaf.dtype)
+        if not leaf.sidecar else
+        jnp.asarray(rng.uniform(0.01, 0.02, (L, 9, BS) + leaf.tail), leaf.dtype)
+        for leaf in kv.leaves)
+    blk = jnp.asarray([[1, 2], [5, 3], [7, 8]], jnp.int32)
+    rows = [jnp.asarray(rng.standard_normal((L, B, n * BS, KV, hd)),
+                        jnp.bfloat16) for _ in range(2)]
+    pool2 = jax.jit(kv.write)(pool, blk, rows)
+    back = kv.rows(pool2, blk)
+    for got, want in zip(back, rows):
+        assert got.dtype == jnp.bfloat16 and got.shape == want.shape
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        if kv_dtype == "model":
+            np.testing.assert_array_equal(got, want)
+        else:  # half a scale, and bf16's own rounding of the product
+            scale = np.abs(want).max(-1, keepdims=True) / 127
+            assert np.all(np.abs(got - want) <= 0.5 * scale + 2 ** -8
+                          * np.abs(want))
+    unnamed = np.asarray([0, 4, 6])
+    for leaf, leaf2 in zip(pool, pool2):
+        np.testing.assert_array_equal(np.asarray(leaf2[:, unnamed]),
+                                      np.asarray(leaf[:, unnamed]))
+    # a chunk's write-back: rows lo <= r < hi of the view were written.
+    # The view is of the pool's first contents, whose int8 rows (no
+    # payload at 127) a requantization would NOT give back
+    lo, hi = jnp.asarray([1, 0, 5], jnp.int32), jnp.asarray([3, 0, 8], jnp.int32)
+    inside = ((np.arange(n * BS)[None] >= np.asarray(lo)[:, None])
+              & (np.arange(n * BS)[None] < np.asarray(hi)[:, None]))
+    view = [jnp.where(inside[None, :, :, None, None], r, x)
+            for r, x in zip(rows, kv.rows(pool, blk))]
+    pool3 = jax.jit(lambda p, v: kv.write(p, blk, v, span=(lo, hi)))(
+        pool, view)
+    for leaf, leaf3, leaf2 in zip(pool, pool3, pool2):
+        was, now, new = (np.asarray(jnp.take(x, blk, axis=1)).reshape(
+            (L, B, n * BS) + x.shape[3:]) for x in (leaf, leaf3, leaf2))
+        np.testing.assert_array_equal(now[:, ~inside], was[:, ~inside])
+        np.testing.assert_array_equal(now[:, inside], new[:, inside])
 
 
 def _run_engine(cfg, params, prompts, n_new, **kw):
@@ -494,7 +586,9 @@ def test_engine_int8_pool_half_bytes(model, workload):
 
 def test_int8_weights_bounded_error_and_engine_parity(model):
     """`quantize_weights_int8`: per-output-channel scales keep the
-    forward logits within ~5% of fp and preserve the argmax row-wise;
+    forward logits within ~5% of fp, and the int8 choice sits within
+    that bound of the float choice under the float logits (a margin,
+    as the benchmark's `correct` compares: a near tie may flip);
     the engine serving the quantized params reproduces the dedicated
     `generate` over the same quantized params exactly."""
     cfg, params = model
@@ -512,7 +606,8 @@ def test_int8_weights_bounded_error_and_engine_parity(model):
         f"int8 weight logit error {np.abs(lq - lf).max():.4f} "
         f"vs scale {scale:.4f}"
     )
-    assert np.array_equal(np.argmax(lq, -1), np.argmax(lf, -1))
+    chosen = np.take_along_axis(lf, np.argmax(lq, -1)[..., None], -1)[..., 0]
+    assert (lf.max(-1) - chosen).max() <= 0.05 * scale
 
     rng = np.random.RandomState(17)
     prompts = [[int(x) for x in rng.randint(0, cfg.vocab_size, size=8)]
