@@ -43,13 +43,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.llama import _apply, _embed, _lm_head, _rms_norm
+from ray_tpu.models.llama import (Packed, _apply, _embed, _lm_head,
+                                  _rms_norm)
 from ray_tpu.ops import paged_attention as _pa
 from ray_tpu.parallel.moe import dropless_moe
 
@@ -272,26 +273,33 @@ def _stacks(params):
 # ----------------------------------------------------------------------
 def forward(cfg: DeepseekV3Config, params: Dict, tokens: jax.Array,
             return_kv: bool = False, *, kernel: bool = False,
-            interpret: bool = False):
+            interpret: bool = False, packed: Optional[Packed] = None):
     """tokens [B, T] -> logits [B, T, vocab] float32; with `return_kv`
     also the latents to cache, [L, B, T, 576].  Right-padding changes
     no real token's result: attention is causal and the expert layer
-    drops nothing."""
+    drops nothing.  `packed`: see `forward_with_prefix`."""
     return forward_with_prefix(cfg, params, tokens, None, 0,
                                return_kv=return_kv, kernel=kernel,
-                               interpret=interpret)
+                               interpret=interpret, packed=packed)
 
 
 def forward_with_prefix(cfg: DeepseekV3Config, params: Dict,
                         tokens: jax.Array, prefix, prefix_len, *,
                         return_kv: bool = True, kernel: bool = False,
-                        interpret: bool = False):
+                        interpret: bool = False,
+                        packed: Optional[Packed] = None):
     """Suffix forward over cached latents: `tokens` [B, S] live at
     positions `prefix_len`..; `prefix` [L, B, Pmax, >= 576] are the
     gathered rows of the shared prefix (columns at or past
     `prefix_len` masked), expanded through `W_kvb` like the suffix's
     own.  `prefix` None is the full prefill.  Returns (logits [B, S,
-    vocab], suffix latents [L, B, S, 576])."""
+    vocab], suffix latents [L, B, S, 576]).
+
+    `packed` (full prefill, B == 1; `llama.Packed`): the row holds
+    several prompts end to end.  Its `pos` and `seg` take the place of
+    the positions and the mask above, a padding token (`seg` < 0) is
+    routed to no expert, and the logits are `[B, K, vocab]`, the rows
+    `packed.last` only."""
     B, S = tokens.shape
     Pmax = 0 if prefix is None else prefix.shape[2]
     pos = prefix_len + jnp.arange(S)
@@ -299,6 +307,9 @@ def forward_with_prefix(cfg: DeepseekV3Config, params: Dict,
     mask = ((cols[None, :] < jnp.minimum(prefix_len, Pmax))
             | ((cols[None, :] >= Pmax)
                & (cols[None, :] - Pmax <= jnp.arange(S)[:, None])))
+    real = None  # every row routes
+    if packed is not None and packed.seg is not None:
+        pos, mask, real = packed.pos, packed.mask(), packed.seg >= 0
     x = _embed(params, tokens, cfg.dtype).astype(cfg.dtype)
 
     def body(experts, x, inputs):
@@ -312,7 +323,7 @@ def forward_with_prefix(cfg: DeepseekV3Config, params: Dict,
                 cfg, jnp.concatenate([q_nope, q_rope], axis=-1), k, v, mask)
             x = x + _apply(o, layer["wo"], cfg.dtype)
         x, _ = _ffn(cfg, {**layer, **experts}, x, kernel=kernel,
-                    interpret=interpret, stack_index=i)
+                    interpret=interpret, stack_index=i, row_mask=real)
         return x, latent
 
     kvs = []
@@ -322,6 +333,8 @@ def forward_with_prefix(cfg: DeepseekV3Config, params: Dict,
             functools.partial(body, experts), x,
             (jnp.arange(n, dtype=jnp.int32), scanned, pre))
         kvs.append(kv)
+    if packed is not None:
+        x = x[:, packed.last]  # the head reads K rows, not S
     x = _rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
     logits = _lm_head(x, params, cfg.dtype)
     if not return_kv:

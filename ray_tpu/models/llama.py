@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -261,13 +261,41 @@ def _mlp(cfg, x, layer, lora_layer=None):
         return x + down
 
 
-def _rope(x, theta: float, t0=0):
+class Packed(NamedTuple):
+    """Several prompts end to end in ONE row of T tokens (the serve
+    engine's packed prefill, `serve/engine_model.py`): what a prefill
+    forward needs to treat the row as the prompts it holds.  `last` [K]
+    int32: the rows the head runs on (each prompt's last token), so the
+    logits come back `[B, K, vocab]`.  `seg` [T] int32: which prompt a
+    token belongs to, -1 for padding; a token attends inside its own
+    prompt only.  `pos` [T] int32: its position inside that prompt,
+    which is where it is rotated.  `seg` and `pos` None: the row is one
+    prompt from position 0, right-padded (the plain causal form, which
+    every `cfg.attention` has)."""
+    last: jax.Array
+    seg: Optional[jax.Array] = None
+    pos: Optional[jax.Array] = None
+
+    def mask(self):
+        """[T, T] bool, `same prompt AND causal`; None without `seg`.
+        One prompt's block of it is the causal mask."""
+        if self.seg is None:
+            return None
+        t = jnp.arange(self.seg.shape[0])
+        return ((self.seg[:, None] == self.seg[None, :])
+                & (t[:, None] >= t[None, :]))
+
+
+def _rope(x, theta: float, t0=0, pos=None):
     """Rotary embedding over the last dim; x [B, T, H, hd].  t0 may be
-    a traced offset (KV-cached decode positions)."""
+    a traced offset (KV-cached decode positions); `pos` [T], when
+    given, names each token's position itself (`Packed`)."""
     B, T, H, hd = x.shape
     half = hd // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
-    pos = jnp.asarray(t0, jnp.float32) + jnp.arange(T, dtype=jnp.float32)
+    if pos is None:
+        pos = jnp.asarray(t0, jnp.float32) + jnp.arange(T, dtype=jnp.float32)
+    pos = pos.astype(jnp.float32)
     ang = pos[:, None] * freqs[None, :]  # [T, half]
     cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
@@ -277,15 +305,20 @@ def _rope(x, theta: float, t0=0):
 
 def forward(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
             mesh=None, lora: Optional[Dict] = None,
-            return_kv: bool = False):
+            return_kv: bool = False, packed: Optional[Packed] = None):
     """tokens [B, T] int32 -> logits [B, T, vocab] (f32).
 
     With return_kv=True also returns the per-layer post-RoPE K/V
     ([L, B, T, KV, hd] each) — the prefill path of KV-cached decoding
     (reference capability: vLLM-style serving on Ray; here the native
     inference path for serve replicas).
+
+    `packed` (B == 1): the row holds several prompts end to end, see
+    `Packed`; the logits are `[B, K, vocab]`, the rows `packed.last` only.
     """
     B, T = tokens.shape
+    pos, mask = (None, None) if packed is None else (packed.pos,
+                                                     packed.mask())
     x = _embed(params, tokens, cfg.dtype)
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     group = H // KV
@@ -313,14 +346,19 @@ def forward(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
                            layer.get("wk_scale"))
                 v = _apply(h, layer["wv"], cfg.dtype, layer_lora, "wv",
                            layer.get("wv_scale"))
-                q = _rope(q.reshape(B, T, H, hd), cfg.rope_theta)
-                k_kv = _rope(k.reshape(B, T, KV, hd), cfg.rope_theta)
+                q = _rope(q.reshape(B, T, H, hd), cfg.rope_theta, pos=pos)
+                k_kv = _rope(k.reshape(B, T, KV, hd), cfg.rope_theta,
+                             pos=pos)
                 v_kv = v.reshape(B, T, KV, hd)
                 k, v = k_kv, v_kv
                 if group > 1:  # GQA: each kv head serves `group` query heads
                     k = jnp.repeat(k, group, axis=2)
                     v = jnp.repeat(v, group, axis=2)
-                o = select_attention(cfg.attention, q, k, v, mesh, causal=True)
+                if mask is None:
+                    o = select_attention(cfg.attention, q, k, v, mesh,
+                                         causal=True)
+                else:  # the dense form is the one that takes a mask
+                    o = plain_attention(q, k, v, mask=mask)
                 o = o.reshape(B, T, H * hd)
                 x1 = xin + _apply(o, layer["wo"], cfg.dtype, layer_lora, "wo",
                                   layer.get("wo_scale"))
@@ -335,6 +373,10 @@ def forward(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
         scan_tree.update(lora_blocks)
     x = x.astype(cfg.dtype)
     x, kv = lax.scan(body, x, scan_tree)
+    if packed is not None:
+        # the head reads K rows, not T; the batch axis stays, so the
+        # product has the shape family it has without `packed`
+        x = x[:, packed.last]
     x = _rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
     logits = _lm_head(x, params, cfg.dtype)
     if return_kv:

@@ -187,15 +187,17 @@ def ulysses_attention(
     return fn(q, k, v)
 
 
-def plain_attention(q, k, v, *, causal=True, scale=None):
+def plain_attention(q, k, v, *, causal=True, scale=None, mask=None):
     """Reference (unsharded) attention used in tests and as the
-    single-device path."""
+    single-device path.  `mask` [T, S] bool, when given, says which
+    keys a query sees, in place of the causal triangle."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
+    if mask is None and causal:
         T, S = s.shape[-2], s.shape[-1]
         mask = jnp.arange(T)[:, None] >= jnp.arange(S)[None, :]
+    if mask is not None:
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)

@@ -902,6 +902,7 @@ def measure_overload(*, overflow: int = 12, seed: int = 0
             "queue_peak": queue_peak,
             "blocks_free_delta": float(s["blocks_free"] - free0),
             "prefill_calls": s["prefill_calls"] - base["prefill_calls"],
+            "prefill_rows": s["prefill_rows"] - base["prefill_rows"],
             "wall_s": round(wall, 3),
             "admitted_tok_s": round(admitted_tokens / wall, 1),
         }

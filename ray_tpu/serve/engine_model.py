@@ -8,7 +8,7 @@ tells the engine
 - its CACHE SPEC: the pool leaves one cached token needs, each as the
   shape after `[layers, num_blocks, block_size]` and a dtype
   (`cache_leaves`), and the bytes one token costs (`cache_bytes_per_token`);
-- four program bodies, each keyed by the static shape the engine buckets
+- five program bodies, each keyed by the static shape the engine buckets
   to, all with FLAT signatures so that the engine can jit, name, donate
   and cache them without knowing what the leaves mean:
 
@@ -27,8 +27,27 @@ tells the engine
     cache shares: `ops/paged_attention.dead_row_positions`, the dense
     routes' masked select), the latent model routes it to no expert,
     and the token it yields is nobody's;
+  - `prefill_packed(N)`: `(params, *cache, tokens, seg, posn,
+    blk_ids, last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos,
+    tok, stop)`: ADMISSION, a tick's cache-miss prompts in one program
+    and one read of the weights.  Up to `K` prompts lie end to end in
+    one row of `N` tokens, each starting on a block boundary: `tokens`
+    [N]; `seg` [N] which prompt a token belongs to, -1 for padding;
+    `posn` [N] its position inside its own prompt; `blk_ids` [N /
+    block_size] the pool block each block of the row is cached in
+    (padding: the scratch block).  A token attends inside its own
+    prompt only (`same segment AND causal`: one prompt alone is the
+    causal mask, so there is no second form), the head runs on the `K`
+    rows `last` names (each prompt's last token), the greedy pick is
+    taken INSIDE the program, and per prompt `slots`, `pos0` (its
+    length) and `stop0` set the admitted rows of the device state; an
+    unused entry's slot is out of range and dropped.  Nothing comes
+    back to the host.  `K` is 1 where `cfg.attention` is not dense (the
+    mask needs the dense form): `seg` and `posn` are then ignored;
   - `prefill(bucket)`: `(params, prompt [1, bucket]) -> (logits
-    [bucket, vocab], *kv)`, `kv` the rows to cache, `[L, 1, bucket, ...]`;
+    [bucket, vocab], *kv)`, `kv` the rows to cache, `[L, 1, bucket, ...]`:
+    the same model forward with no segments, kept for callers that
+    want the logits;
   - `suffix_prefill(s_bucket, p_blocks)`: `(params, *cache, suffix,
     blk_ids, prefix_len) -> (logits, *kv)`: prefill behind a cached
     prefix, read from the pool through `blk_ids`;
@@ -45,7 +64,7 @@ are a value of it, not a family of bodies).  `chunk_program` is the
 decode chunk: liveness, the greedy pick, the positions, row 0 and the
 aux rows, around ONE decode step the model hands it.
 `kv_write_program` is `kv_write`'s flat signature and the admitted
-slot's state.
+slot's state; `packed_prefill_program` is `prefill_packed`'s.
 
 Two implementers: `LlamaEngineModel` (per-head K and V pools) and
 `LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
@@ -218,9 +237,36 @@ def kv_write_program(kv: PagedKV, fit):
     return _fn
 
 
+def packed_prefill_program(kv: PagedKV, forward, fit, segmented: bool):
+    """`prefill_packed`, `(params, *cache, tokens, seg, posn, blk_ids,
+    last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos, tok,
+    stop)`.  `forward(params, tokens [1, N], packed) -> (logits [1, K,
+    vocab], *rows)` is the model's prefill forward under a
+    `llama.Packed`; `fit(*rows)` the rows `[L, 1, N, ...]` as the pool
+    caches them, which then reshape straight into `blk_ids`' blocks."""
+    n = len(kv.leaves)
+
+    def _fn(params, *flat):
+        cache = flat[:n]
+        (tokens, seg, posn, blk_ids, last, slots, pos0, stop0,
+         pos, tok, stop) = flat[n:]
+        packed = (llama.Packed(last, seg, posn) if segmented
+                  else llama.Packed(last))
+        logits, *rows = forward(params, tokens[None], packed)
+        tok0 = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+        cache = kv.write(cache, blk_ids, fit(*rows))
+        # an unused entry's slot is past the last one: dropped
+        return (*cache, pos.at[slots].set(pos0, mode="drop"),
+                tok.at[slots].set(tok0, mode="drop"),
+                stop.at[slots].set(stop0, mode="drop"))
+
+    return _fn
+
+
 class _EngineModel:
-    """What the engine reads off either implementer besides its four
-    bodies: `cache_leaves`, `n_layers`, `kv.kv_dtype`, `aux_rows` and
+    """What the engine reads off either implementer besides its five
+    bodies: `cache_leaves`, `n_layers`, `kv.kv_dtype`, `segmented`
+    (a packed prefill may hold several prompts), `aux_rows` and
     `tick_fields` (the model's own per-tick counters; none here)."""
 
     aux_rows = 0
@@ -231,6 +277,8 @@ class _EngineModel:
         self._paged, self._interpret = paged, interpret
         self.n_layers = cfg.n_layers
         self.cache_leaves = kv.leaves
+        # the segment mask needs the dense attention form
+        self.segmented = getattr(cfg, "attention", "dense") == "dense"
 
     def tick_fields(self, aux) -> Dict[str, object]:
         return {}
@@ -256,14 +304,22 @@ class LlamaEngineModel(_EngineModel):
         return chunk_program(step, self.chunk,
                              gather=None if paged else self.kv)
 
+    def prefill_packed(self, N: int):
+        def forward(params, tokens, packed):
+            # garbage KV rows written for pad positions stay masked
+            # (a row's pos starts at its prompt's length) and are
+            # overwritten as decoding advances through them
+            logits, (ks, vs) = llama.forward(
+                self.cfg, params, tokens, return_kv=True, packed=packed)
+            return logits, ks, vs  # ks/vs [L, 1, N, KV, hd]
+
+        return packed_prefill_program(
+            self.kv, forward, lambda k, v: (k, v), self.segmented)
+
     def prefill(self, bucket: int):
-        def _pf(params, prompt):  # prompt [1, bucket]
-            # full-sequence logits (not llama.prefill's last-pos
-            # form): the prompt is right-padded to the bucket, so
-            # the real continuation logit lives at position T-1.
-            # Garbage KV rows written for pad positions stay masked
-            # (pos starts at T) and are overwritten as decoding
-            # advances through them.
+        def _pf(params, prompt):  # prompt [1, bucket], right-padded
+            # full-sequence logits: the real continuation logit lives
+            # at position T-1
             logits, (ks, vs) = llama.forward(
                 self.cfg, params, prompt, return_kv=True
             )
@@ -342,6 +398,18 @@ class LatentMoeEngineModel(_EngineModel):
         return chunk_program(
             step, self.chunk, gather=None if paged else self.kv,
             aux=lambda st: jnp.stack([jnp.sum(st[0]), jnp.max(st[1])]))
+
+    def prefill_packed(self, N: int):
+        def forward(params, tokens, packed):
+            return deepseek_v3.forward(
+                self.cfg, params, tokens, return_kv=True, packed=packed,
+                **self._kw())  # (logits, lat [L, 1, N, 576])
+
+        def fit(lat):  # -> the pool's Dp columns
+            return (jnp.pad(lat, ((0, 0),) * 3
+                            + ((0, self.width - lat.shape[-1]),)),)
+
+        return packed_prefill_program(self.kv, forward, fit, self.segmented)
 
     def prefill(self, bucket: int):
         def _pf(params, prompt):  # prompt [1, bucket], right-padded

@@ -48,6 +48,13 @@ TPU-native design points:
   the chunk emits its pre-chunk token row so admission never needs a
   device->host read, and the token read of chunk N overlaps chunk
   N+1's compute.
+- ONE PACKED PREFILL A TICK: a tick's cache-miss admissions lie end
+  to end in one row of `N` tokens and are prefilled by ONE program
+  (`prefill_packed_n<N>`: a segment mask, the head on each prompt's
+  last row only, the greedy pick and the block writes inside it), so
+  they share one read of the weights and nothing comes back to the
+  host.  `N` comes from a small closed set (`_pack_sizes`), all of it
+  compiled and run once at start on the kernel route.
 - A ROW THAT OWES NO TOKEN COSTS NOTHING IT CAN AVOID: the device holds
   `stop[slots]` beside `pos` and `tok`, set at admission to `T + n_new
   - 1`; in every decode step a row is live iff `pos < stop`.  A dead
@@ -70,7 +77,7 @@ import threading
 import time as _time
 from collections import OrderedDict, deque
 from concurrent.futures import Future
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -86,6 +93,16 @@ logger = logging.getLogger(__name__)
 REQUEST_RING = 512
 
 
+# requests a tick admits at most: the cap keeps one tick's admission
+# work from starving the active slots of decode chunks.  Also the rows
+# one packed prefill program sets (its `K`)
+ADMIT_BUDGET = 16
+
+# the smallest packed prefill: at and under this many tokens a prefill
+# costs the read of the weights, so a smaller program would be no faster
+PACK_MIN_TOKENS = 128
+
+
 def _next_pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
 
@@ -94,18 +111,43 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def _pack_sizes(top: int, block_size: int) -> List[int]:
+    """The closed set of `N` a packed prefill is compiled for: powers
+    of two from `PACK_MIN_TOKENS`, in whole blocks, below `top`; then
+    `top` itself, a maximal sequence's blocks, which holds any prompt
+    `submit` accepts.  Few on purpose: each is traced, lowered and
+    loaded at every start (0.2 s a program for a dense 7B, 0.9 s for
+    the latent expert model, cache warm), and past ~512 tokens a
+    program is compute-bound, so a finer ladder saves only padding."""
+    sizes, p = [], PACK_MIN_TOKENS
+    while p < top:
+        sizes.append(_cdiv(p, block_size) * block_size)
+        p *= 2
+    return sorted(n for n in set(sizes) if n < top) + [top]
+
+
+class _Plan(NamedTuple):
+    """An admission between its two phases: the request's `_active`
+    entry, and what its prefill dispatch needs."""
+    req: Dict
+    slot: int
+    prompt: List[int]
+    shared: List[int]  # the cached prefix's blocks (a hit), or none
+    own: List[int]     # the blocks allocated to it, in position order
+
+
 class LlamaEngine:
     """Resident continuous-batching decode engine over a paged KV pool.
 
     THE MODEL SEAM.  This class is the scheduler: admission, shedding,
     slots, block tables, the radix prefix cache, the tick, its rings and
-    spans, and the jit / name / donate / LRU bookkeeping of four
+    spans, and the jit / name / donate / LRU bookkeeping of five
     program families.  What those programs compute, and what a cached
     token is, belongs to the model behind `serve/engine_model.py`: the
     engine asks it for its cache spec (`cache_leaves`: the pool leaves
     and their per-block shapes, which `BlockPool` allocates) and for the
-    bodies of prefill, suffix prefill, KV write and the paged decode
-    chunk, all with flat signatures `(params, *cache, ...)`.  The
+    bodies of packed prefill, prefill, suffix prefill, KV write and the
+    paged decode chunk, all with flat signatures `(params, *cache, ...)`.  The
     cache's FORMAT (`kv_dtype`) is the model's too: the engine hands
     the string over and reads it back for `stats()`.  Two implementers,
     picked by the config's type (`engine_model_for`): `LlamaEngineModel`
@@ -232,6 +274,7 @@ class LlamaEngine:
         self._chunk_cache_evictions = 0
         self._decode_kernel_dispatches = 0   # fused-kernel chunk ticks
         self._decode_gather_dispatches = 0  # gather-route chunk ticks
+        self._packed_cache: Dict[int, object] = {}         # pack size N
         self._prefill_cache: Dict[int, object] = {}        # prompt bucket
         self._suffix_cache: Dict[tuple, object] = {}       # (S_bucket, P_blocks)
         self._write_cache: Dict[tuple, object] = {}        # (T_in, nb)
@@ -266,7 +309,14 @@ class LlamaEngine:
         self._hit_tokens = 0          # prefix tokens served from cache
         self._prefill_tokens = 0      # tokens actually prefilled
         self._prefix_hits = 0         # requests with a non-empty match
-        self._prefill_calls = 0       # prefill dispatches (full+suffix)
+        self._prefill_calls = 0       # prefill programs (packed+suffix)
+        self._prefill_rows = 0        # requests those programs prefilled
+        self._prefill_padded_tokens = 0  # their sizes (N, bucket) summed
+        # the packed prefill's closed set of sizes and the prompts one
+        # program holds: the segment mask needs the dense attention form
+        self._pack_sizes = _pack_sizes(
+            self._max_seq_blocks * self.block_size, self.block_size)
+        self._pack_rows = ADMIT_BUDGET if self._model.segmented else 1
         # overload plane: bound the admission queue and shed queued
         # requests whose caller has (or must have) given up BEFORE
         # they burn prefill compute.  All counters are plain ints
@@ -342,13 +392,17 @@ class LlamaEngine:
         scratch block, so only scratch is written.  On the chip this
         proves the Mosaic lowering and the in-place pool update; where
         the kernels cannot compile the engine fails HERE, at start,
-        instead of serving through another route."""
+        instead of serving through another route.  Then every packed
+        prefill of the closed set, empty (padding only, into scratch):
+        no admission compiles after this, whatever the traffic."""
         tables = self._jnp.full((self.slots, 1), SCRATCH_BLOCK,
                                 self._jnp.int32)
         cfn = self._chunk_step_for(1)
         self._cache = tuple(cfn(
             self.params, *self._cache, tables, self._tok, self._pos,
             self._stop)[:len(self._cache)])
+        for n in self._pack_sizes:
+            self._run_packed(n, [])
         self._jax.block_until_ready(self._cache)
 
     # -- public surface ------------------------------------------------
@@ -359,7 +413,7 @@ class LlamaEngine:
         A heuristic, not a promise — floored/capped so cold engines
         (no EMA yet) and pathological backlogs still hint sanely."""
         backlog = len(self._queue) + self._pending_admissions
-        per_tick = float(max(1, min(16, self.slots)))
+        per_tick = float(max(1, min(ADMIT_BUDGET, self.slots)))
         est = self._tick_ema_s * max(1.0, backlog / per_tick)
         if est <= 0.0:
             est = 1.0  # no tick has completed yet: default hint
@@ -507,7 +561,11 @@ class LlamaEngine:
                 "prefix_hit_rate": (
                     self._hit_tokens / served if served else 0.0
                 ),
+                # programs dispatched, the requests they prefilled, and
+                # the tokens they were compiled for (real: prefill_tokens)
                 "prefill_calls": self._prefill_calls,
+                "prefill_rows": self._prefill_rows,
+                "prefill_padded_tokens": self._prefill_padded_tokens,
                 "gather_blocks": self._last_gather_blocks,
                 # decode-kernel / quantization plane: which route the
                 # chunk dispatches take and what the pool costs in HBM
@@ -608,7 +666,20 @@ class LlamaEngine:
         self._chunk_cache[W] = fn
         return fn
 
+    def _prefill_packed_for(self, N: int):
+        """Admission's program: up to `_pack_rows` prompts in one row
+        of `N` tokens (`engine_model`'s `prefill_packed`)."""
+        fn = self._packed_cache.get(N)
+        if fn is None:
+            _pf = self._model.prefill_packed(N)
+            _pf.__name__ = f"prefill_packed_n{N}"
+            fn = self._packed_cache[N] = self._jax.jit(
+                _pf, donate_argnums=tuple(range(1, 1 + len(self._cache))))
+        return fn
+
     def _prefill_for(self, bucket: int):
+        """One right-padded prompt -> (logits, *kv), nothing written:
+        not on admission's path (a caller that wants the logits)."""
         fn = self._prefill_cache.get(bucket)
         if fn is None:
             _pf = self._model.prefill(bucket)
@@ -657,6 +728,7 @@ class LlamaEngine:
             "queue_s": t_done - t_submit, "prefill_dispatch_s": None,
             "first_token_s": None, "decode_s": None, "harvests": 0,
             "tokens_in": tokens_in, "tokens_hit": 0, "tokens_out": 0,
+            "prefill_rows": None,
         }
         if req is not None:
             rec["queue_s"] = req["t_admit"] - t_submit
@@ -666,6 +738,7 @@ class LlamaEngine:
                 rec["decode_s"] = t_done - req["t_first"]
             rec["harvests"] = req["harvests"]
             rec["tokens_hit"] = req["tokens_hit"]
+            rec["prefill_rows"] = req["prefill_rows"]
             rec["tokens_out"] = min(len(req["out"]), req["want"])
         with self._ring_lock:
             self._finished_total += 1
@@ -727,11 +800,12 @@ class LlamaEngine:
             own = self._pool.alloc(n)
         return own
 
-    def _admit(self, prompt: List[int], n_new: int, fut: Future,
-               t_submit: float, tk=None) -> bool:
-        """Returns False (without consuming anything) when the pool
-        cannot cover the request right now — the caller requeues it."""
-        jnp = self._jnp
+    def _plan(self, prompt: List[int], n_new: int, fut: Future,
+              t_submit: float, tk=None) -> Optional[_Plan]:
+        """Admission's first phase, on the host only: the radix match,
+        the request's blocks, its slot and its `_active` entry.
+        Returns None, without consuming anything, when the pool cannot
+        cover the request right now: the caller requeues it."""
         bs = self.block_size
         T = len(prompt)
         # highest KV index a WANTED token's step touches is T+n_new-2:
@@ -744,74 +818,19 @@ class LlamaEngine:
         path: List = []
         if self._radix is not None:
             shared, path = self._radix.match(prompt)
-        P = len(shared) * bs
         own = self._alloc_or_evict(total_blocks - len(shared))
         if own is None:
             if self._radix is not None:
                 self._radix.release(path)
-            return False
-        # queue wait ends here: the request holds a slot and its
-        # blocks; everything after is prefill dispatch
+            return None
+        # the request holds a slot and its blocks; `_admitting` stamps
+        # it again where the host starts on its prefill program
         t_admit = _time.time()
         slot = self._free.pop()
-        S = T - P  # tokens to prefill (the whole prompt on a miss)
-        # pow-2 length buckets: RIGHT-pad (the scheme depends on it —
-        # causal prefill keeps positions 0..T-1 correct, the pad
-        # tail's garbage KV is masked by the starting pos and
-        # overwritten as decoding advances)
-        bucket = min(_next_pow2(S), self.max_len - 1)
-        with self._span("engine.prefill", bucket=bucket, slot=slot,
-                        hit_blocks=len(shared)):
-            if P > 0:
-                # PREFIX HIT: prefill only the suffix, attending over
-                # the gathered prefix blocks (pow-2 buckets on both axes)
-                p_bucket = _next_pow2(len(shared))
-                blk_ids = jnp.asarray(
-                    shared + [SCRATCH_BLOCK] * (p_bucket - len(shared)),
-                    jnp.int32,
-                )
-                suffix = jnp.asarray(
-                    [prompt[P:] + [0] * (bucket - S)], jnp.int32
-                )
-                sfn = self._suffix_prefill_for(bucket, p_bucket)
-                logits, *kv = sfn(
-                    self.params, *self._cache, suffix, blk_ids,
-                    jnp.asarray(P, jnp.int32),
-                )
-                self._hit_tokens += P
-                self._prefix_hits += 1
-            else:
-                padded = prompt + [0] * (bucket - T)
-                logits, *kv = self._prefill_for(bucket)(
-                    self.params, jnp.asarray([padded], jnp.int32)
-                )
-            # first generated token comes from the LAST REAL prompt
-            # position; it STAYS on device — the next chunk emits it in
-            # its pre-chunk token row, so admission costs only async
-            # dispatches
-            tok0 = jnp.argmax(logits[S - 1], axis=-1).astype(jnp.int32)
-            # the prefilled KV starts at a block boundary (0, or P);
-            # write only the blocks holding real tokens — bucket-pad
-            # garbage past them is dropped, garbage within the last
-            # real block is masked by pos until decode overwrites it
-            nb_real = _cdiv(S, bs)
-            write_ids = own[:nb_real]
-            self._prefill_tokens += S
-            self._prefill_calls += 1
-            wfn = self._write_blocks_for(bucket, nb_real)
-            out = wfn(
-                *self._cache, *kv,
-                jnp.asarray(write_ids, jnp.int32),
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(T, jnp.int32),
-                tok0, self._pos, self._tok,
-                jnp.asarray(stop, jnp.int32), self._stop,
-            )
-            self._cache = tuple(out[:-3])
-            self._pos, self._tok, self._stop = out[-3:]
-
         # donate this prompt's full blocks to the radix cache (pinned
-        # until completion); blocks the trie adopts stop being
+        # until completion) NOW, so that a later admission of this tick
+        # shares them: its suffix prefill is dispatched behind the
+        # program that writes them.  Blocks the trie adopts stop being
         # request-owned so completion doesn't double-free them
         own_set = list(own)
         if self._radix is not None:
@@ -820,24 +839,165 @@ class LlamaEngine:
             if adopted:
                 adopted_set = set(adopted)
                 own_set = [b for b in own_set if b not in adopted_set]
-
         self._slot_blocks[slot] = shared + own
-        # host-side dispatch timestamp: the prefill computes async on
-        # device, so this is when the host let go of it
-        t_prefill = _time.time()
-        if tk is not None:
-            tk.admitted(t_admit)
-            tk.prefilled(t_prefill)
-        self._active[slot] = {
+        self._active[slot] = req = {
             "fut": fut, "out": [], "want": n_new,
             "since": self._chunk_seq + 1,  # first chunk with its steps
             "pos_host": T, "stop": stop,
             "own_blocks": own_set, "tree_path": path,
-            "tk": tk, "tokens_in": T, "tokens_hit": P, "harvests": 0,
-            "t_submit": t_submit, "t_admit": t_admit,
-            "t_prefill": t_prefill, "t_first": None,
+            "tk": tk, "tokens_in": T, "tokens_hit": len(shared) * bs,
+            "harvests": 0, "t_submit": t_submit, "t_admit": t_admit,
+            "t_prefill": t_admit, "t_first": None, "prefill_rows": 0,
         }
-        return True
+        return _Plan(req, slot, prompt, shared, own)
+
+    def _prefill(self, plans: List[_Plan]) -> None:
+        """Admission's second phase: the planned requests' prefills,
+        dispatched (async; nothing is read back).  Those with no cached
+        prefix are packed, in arrival order, into as few programs as
+        hold them (a program holds `_pack_rows` prompts and the largest
+        pack size of tokens, each prompt in whole blocks); then each
+        prefix hit prefills its suffix, one program a request, behind
+        whatever wrote the blocks it shares."""
+        bs = self.block_size
+        cap = self._pack_sizes[-1]
+        pack: List[_Plan] = []
+        used = 0
+        for plan in [p for p in plans if not p.shared] + [None]:
+            need = 0 if plan is None else _cdiv(len(plan.prompt), bs) * bs
+            if pack and (plan is None or used + need > cap
+                         or len(pack) == self._pack_rows):
+                self._run_packed(next(n for n in self._pack_sizes
+                                      if n >= used), pack)
+                pack, used = [], 0
+            if plan is not None:
+                pack.append(plan)
+                used += need
+        for plan in plans:
+            if plan.shared:
+                self._run_suffix(plan)
+
+    def _admitting(self, plans: List[_Plan]) -> None:
+        """Queue wait ends HERE, where the host starts on the program
+        that prefills these requests: behind the tick's earlier
+        programs, as a request's one program always was.  Everything
+        up to `_prefilled` is prefill dispatch."""
+        t_admit = _time.time()
+        for plan in plans:
+            plan.req["t_admit"] = plan.req["t_prefill"] = t_admit
+
+    def _prefilled(self, plans: List[_Plan]) -> None:
+        """Stamps the requests whose prefill was just dispatched, in
+        one program: it computes async on the device, so this is when
+        the host let go of it."""
+        t_prefill = _time.time()
+        for req in (plan.req for plan in plans):
+            req["t_prefill"] = t_prefill
+            req["prefill_rows"] = len(plans)
+            if req["tk"] is not None:
+                req["tk"].admitted(req["t_admit"])
+                req["tk"].prefilled(t_prefill)
+
+    def _pack_arrays(self, N: int, pack: List[_Plan]) -> tuple:
+        """A packed prefill's host-made arguments, `(tokens, seg, posn,
+        blk_ids, last, slots, pos0, stop0)`: the prompts of `pack` end
+        to end in a row of `N` tokens, each from a block boundary."""
+        bs, K = self.block_size, self._pack_rows
+        i32 = np.int32
+        tokens, posn = np.zeros(N, i32), np.zeros(N, i32)
+        seg = np.full(N, -1, i32)
+        blk_ids = np.full(N // bs, SCRATCH_BLOCK, i32)
+        last, pos0, stop0 = (np.zeros(K, i32) for _ in range(3))
+        slots = np.full(K, self.slots, i32)  # out of range: dropped
+        at = 0
+        for i, plan in enumerate(pack):
+            T = len(plan.prompt)
+            nb = _cdiv(T, bs)
+            tokens[at:at + T] = plan.prompt
+            seg[at:at + T] = i
+            posn[at:at + T] = np.arange(T)
+            # only the blocks holding real tokens; garbage within the
+            # last of them is masked by pos until decode overwrites it
+            blk_ids[at // bs:at // bs + nb] = plan.own[:nb]
+            last[i], slots[i] = at + T - 1, plan.slot
+            pos0[i], stop0[i] = T, plan.req["stop"]
+            at += nb * bs
+        return tokens, seg, posn, blk_ids, last, slots, pos0, stop0
+
+    def _run_packed(self, N: int, pack: List[_Plan]) -> None:
+        """ONE program for the prompts of `pack`, end to end in a row
+        of `N` tokens, each from a block boundary: their KV into their
+        own blocks, their first tokens picked on the device, their
+        slots' `pos` / `tok` / `stop` set.  An empty pack is the
+        warm-up: padding only, into the scratch block."""
+        self._admitting(pack)
+        arrays = self._pack_arrays(N, pack)
+        real = sum(len(plan.prompt) for plan in pack)
+        with self._span("engine.prefill", N=N, rows=len(pack), tokens=real):
+            out = self._prefill_packed_for(N)(
+                self.params, *self._cache, *arrays,
+                self._pos, self._tok, self._stop)
+            self._cache = tuple(out[:-3])
+            self._pos, self._tok, self._stop = out[-3:]
+        self._prefilled(pack)
+        if pack:
+            self._prefill_calls += 1
+            self._prefill_rows += len(pack)
+            self._prefill_tokens += real
+            self._prefill_padded_tokens += N
+
+    def _run_suffix(self, plan: _Plan) -> None:
+        """PREFIX HIT: prefill only the suffix, attending over the
+        gathered prefix blocks (pow-2 buckets on both axes), then write
+        its KV and the slot's state."""
+        self._admitting([plan])
+        jnp = self._jnp
+        bs = self.block_size
+        req, slot, prompt, shared, own = plan
+        T, P = len(prompt), len(shared) * bs
+        S = T - P
+        # RIGHT-pad (the scheme depends on it: causal prefill keeps the
+        # real positions correct, the pad tail's garbage KV is masked
+        # by the starting pos and overwritten as decoding advances)
+        bucket = min(_next_pow2(S), self.max_len - 1)
+        with self._span("engine.prefill", bucket=bucket, slot=slot,
+                        hit_blocks=len(shared)):
+            p_bucket = _next_pow2(len(shared))
+            blk_ids = jnp.asarray(
+                shared + [SCRATCH_BLOCK] * (p_bucket - len(shared)),
+                jnp.int32,
+            )
+            suffix = jnp.asarray(
+                [prompt[P:] + [0] * (bucket - S)], jnp.int32
+            )
+            logits, *kv = self._suffix_prefill_for(bucket, p_bucket)(
+                self.params, *self._cache, suffix, blk_ids,
+                jnp.asarray(P, jnp.int32),
+            )
+            # first generated token comes from the LAST REAL prompt
+            # position; it STAYS on device — the next chunk emits it in
+            # its pre-chunk token row
+            tok0 = jnp.argmax(logits[S - 1], axis=-1).astype(jnp.int32)
+            # the suffix starts at a block boundary; write only the
+            # blocks holding real tokens
+            nb_real = _cdiv(S, bs)
+            out = self._write_blocks_for(bucket, nb_real)(
+                *self._cache, *kv,
+                jnp.asarray(own[:nb_real], jnp.int32),
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(T, jnp.int32),
+                tok0, self._pos, self._tok,
+                jnp.asarray(req["stop"], jnp.int32), self._stop,
+            )
+            self._cache = tuple(out[:-3])
+            self._pos, self._tok, self._stop = out[-3:]
+        self._prefilled([plan])
+        self._hit_tokens += P
+        self._prefix_hits += 1
+        self._prefill_calls += 1
+        self._prefill_rows += 1
+        self._prefill_tokens += S
+        self._prefill_padded_tokens += bucket
 
     def _release(self, slot: int, req: Dict):
         self._slot_blocks[slot] = []
@@ -908,6 +1068,9 @@ class LlamaEngine:
         jnp = self._jnp
         t0 = _time.perf_counter()
         with self._span("engine.admit"):
+            # PLAN every popped admission in arrival order, on the host
+            # only; then dispatch what was planned
+            plans: List[_Plan] = []
             requeued = 0
             for i, (prompt, n_new, fut, ts, dl, tk) in \
                     enumerate(admissions):
@@ -918,12 +1081,14 @@ class LlamaEngine:
                     self._pending_admissions -= 1
                     continue
                 with self._lock:
-                    if not self._admit(prompt, n_new, fut, ts, tk):
-                        # pool exhausted by LIVE sequences: wait for
-                        # completions, preserving arrival order
-                        requeued = len(admissions) - i
-                        break
-                    self._pending_admissions -= 1
+                    plan = self._plan(prompt, n_new, fut, ts, tk)
+                if plan is None:
+                    # pool exhausted by LIVE sequences: wait for
+                    # completions, preserving arrival order
+                    requeued = len(admissions) - i
+                    break
+                self._pending_admissions -= 1
+                plans.append(plan)
             if requeued:
                 with self._wake:
                     self._queue.extendleft(
@@ -933,6 +1098,7 @@ class LlamaEngine:
                 del admissions[-requeued:]
             else:
                 self._pending_admissions = 0
+            self._prefill(plans)
         t1 = _time.perf_counter()
         with self._lock:
             # 0 = nothing live (a live batch needs at least one block)
@@ -1053,7 +1219,7 @@ class LlamaEngine:
                 # keeps one straggler admission from starving active
                 # slots of decode ticks, but filling MATTERS — an
                 # engine below full occupancy wastes its whole premise
-                budget = min(16, len(self._free))
+                budget = min(ADMIT_BUDGET, len(self._free))
                 while self._queue and len(admissions) < budget:
                     admissions.append(self._queue.popleft())
                 self._pending_admissions = len(admissions)

@@ -297,3 +297,56 @@ def test_dropless_expert_layer_with_grouped_kernel(chip, rows):
     assert hlo.count("tpu_custom_call") >= 3
     # no copy of a layer's experts beside the kernels
     assert "bf16[128,2048,768]" not in hlo and "bf16[128,768,2048]" not in hlo
+
+
+# ----------------------------------------------------------------------
+# admission: the packed prefill at both serve configurations' shapes
+# ----------------------------------------------------------------------
+def _mistral_l16() -> llama.LlamaConfig:
+    return llama.LlamaConfig(
+        vocab_size=32768, max_seq_len=32768, dim=4096, n_layers=16,
+        n_heads=32, n_kv_heads=8, intermediate=14336, rope_theta=1e6,
+        norm_eps=1e-5, dtype=BF16)
+
+
+def _kanana_l7():
+    from ray_tpu.models import deepseek_v3
+
+    return deepseek_v3.DeepseekV3Config(n_layers=7, dtype=BF16)
+
+
+@pytest.mark.parametrize("make,blocks,N", [
+    (_mistral_l16, 4097, 1296), (_kanana_l7, 9217, 2320),
+], ids=["mistral-7b-l16-n1296", "kanana-2-l7-n2320"])
+def test_prefill_packed_at_the_cells_shapes(chip, make, blocks, N):
+    """`prefill_packed_n<N>` as the engine jits it (the cache donated,
+    16 rows a program, 64 slots, 16-token blocks) at the LARGEST size
+    of each cell's closed set, a maximal sequence's blocks: 16 layers
+    of Mistral-7B over two per-head pools; kanana's latent pool with
+    the grouped expert products.  It holds no paged decode kernel: the
+    benchmark tells decode programs from prefill by that."""
+    from ray_tpu.models import deepseek_v3
+    from ray_tpu.serve.engine_model import engine_model_for
+
+    cfg = make()
+    latent = isinstance(cfg, deepseek_v3.DeepseekV3Config)
+    init = deepseek_v3.init_params if latent else llama.init_params
+    params = jax.tree.map(
+        lambda p: _s(*p.shape, dtype=p.dtype if latent else BF16),
+        jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0))))
+    model = engine_model_for(cfg, kv_dtype="model", block_size=16, chunk=8,
+                             paged=True, interpret=False)
+    cache = [_s(cfg.n_layers, blocks, 16, *leaf.tail, dtype=leaf.dtype)
+             for leaf in model.cache_leaves]
+    i32 = jnp.int32
+    fn = model.prefill_packed(N)
+    fn.__name__ = f"prefill_packed_n{N}"
+    hlo = _compile(
+        chip, fn, params, *cache, *[_s(N, dtype=i32)] * 3,
+        _s(N // 16, dtype=i32), *[_s(16, dtype=i32)] * 4,
+        *[_s(64, dtype=i32)] * 3,
+        donate_argnums=tuple(range(1, 1 + len(cache))))
+    assert f"jit_prefill_packed_n{N}" in hlo
+    assert "input_output_alias" in hlo  # the pool is written in place
+    # kanana's grouped products are kernels; Mistral's prefill has none
+    assert ("tpu_custom_call" in hlo) == latent

@@ -250,10 +250,14 @@ def test_a_profiler_session_records_the_loops_spans_nested(engine, tmp_path):
         assert {"seq", "active", "admitted", "wall_ns"} <= set(st)
         assert t0 <= st["wall_ns"] <= t1  # the anchor to the wall clock
     assert sum(st["admitted"] for *_, st in ticks) == 4
-    assert len(by["engine.prefill"]) == 4
-    for _, _, _, st in by["engine.prefill"]:
-        assert st["bucket"] == 16 and st["hit_blocks"] == 0
-        assert st["slot"] in (0, 1)
+    # one span a packed program: the four requests, 12 real tokens
+    # each, in programs of 48 (`max_len` in whole blocks) that hold as
+    # many rows as a tick admitted (at most the two slots)
+    packs = [st for *_, st in by["engine.prefill"]]
+    assert 2 <= len(packs) <= 4
+    assert all(st["N"] == 48 and st["rows"] in (1, 2)
+               and st["tokens"] == 12 * st["rows"] for st in packs)
+    assert sum(st["rows"] for st in packs) == 4
     assert all(st["W"] >= 1 for *_, st in by["engine.dispatch"])
 
     def inside(inner, outers):
@@ -282,6 +286,10 @@ def test_the_jitted_families_lower_under_their_names(engine):
     chunk = e._chunk_step_for(2).lower(
         e.params, *e._cache, tables, e._tok, e._pos, e._stop)
     assert "@jit_decode_chunk_w2" in chunk.as_text()
+    packed = e._prefill_packed_for(48).lower(
+        e.params, *e._cache, *e._pack_arrays(48, []),
+        e._pos, e._tok, e._stop)
+    assert "@jit_prefill_packed_n48" in packed.as_text()
     prefill = e._prefill_for(16).lower(e.params, jnp.zeros((1, 16), i32))
     assert "@jit_prefill_b16" in prefill.as_text()
     kv1 = jnp.zeros((L, 1, 16, KV, hd), cfg.dtype)

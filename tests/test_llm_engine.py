@@ -335,15 +335,20 @@ def test_a_dead_row_stays_put_writes_nothing_and_costs_no_token(
         shared, path = eng._radix.match(a)
         eng._radix.release(path)
         assert len(shared) == 2
-        _, *kv = eng._prefill_for(32)(
-            params, jnp.asarray([a + [0] * 12], jnp.int32))
-        i32 = jnp.int32
-        # (the engine's own program, jitted as the engine's is: int8's
-        # division rounds otherwise when run op by op)
-        written = jax.jit(eng._model.kv_write(32, 3))(
-            *[jnp.zeros_like(x) for x in eng._cache], *kv,
-            jnp.asarray([1, 2, 3], i32), jnp.asarray(0, i32),
-            jnp.asarray(20, i32), jnp.asarray(0, i32), eng._pos, eng._tok)
+        # (the engine's own program, as admission runs it: A from the
+        # row's first token, its KV into blocks 1..3 of a zeroed cache;
+        # whoever else shared A's row, masked, added exact zeros)
+        N, K, i32 = eng._pack_sizes[-1], eng._pack_rows, np.int32
+        tokens, seg, posn = np.zeros(N, i32), np.full(N, -1, i32), np.zeros(
+            N, i32)
+        tokens[:20], seg[:20], posn[:20] = a, 0, np.arange(20)
+        blk = np.zeros(N // 8, i32)
+        blk[:3] = [1, 2, 3]
+        none = np.full(K, eng.slots, i32)  # no slot's state is set
+        written = eng._prefill_packed_for(N)(
+            params, *[jnp.zeros_like(x) for x in eng._cache], tokens, seg,
+            posn, blk, np.full(K, 19, i32), none, none, none,
+            eng._pos, eng._tok, eng._stop)[:len(eng._cache)]
 
         def prefix_blocks_intact():
             for leaf, ref in zip(eng._cache, written):
