@@ -102,6 +102,13 @@ class DeadlineExceededError(GetTimeoutError):
     `except GetTimeoutError` call sites keep working."""
 
 
+class PrefixCacheUnsupportedError(RayTpuError, ValueError):
+    """`prefix_cache=True` was asked of a serve engine whose model keeps
+    its context as a per-slot STATE (`serve/engine_model.SlotState`): a
+    radix trie shares a prefix by pointing block tables at the same
+    blocks, and a state has no blocks to point at."""
+
+
 class BackPressureError(RayTpuError):
     """The target's admission queue is full: the request was rejected
     IMMEDIATELY instead of queueing unboundedly (reference analog:

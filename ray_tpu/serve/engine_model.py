@@ -1,13 +1,16 @@
 """The seam between the serve engine and a model.
 
-`LlamaEngine` (`serve/llm_engine.py`) is a scheduler over a paged cache:
+`LlamaEngine` (`serve/llm_engine.py`) is a scheduler over a cache:
 admission, shedding, block tables, the radix prefix cache, the tick and
 its rings.  What it computes WITH is behind this seam.  An engine model
 tells the engine
 
-- its CACHE SPEC: the pool leaves one cached token needs, each as the
-  shape after `[layers, num_blocks, block_size]` and a dtype
-  (`cache_leaves`), and the bytes one token costs (`cache_bytes_per_token`);
+- its CACHE SPEC (`cache_leaves`), of one of two kinds.  PAGED: the pool
+  leaves one cached token needs, each as the shape after `[layers,
+  num_blocks, block_size]` and a dtype, and the bytes one token costs
+  (`cache_bytes_per_token`).  PER SLOT (`per_slot`): the leaves one
+  SEQUENCE holds, each as the shape after `[layers, slots]`, and the
+  bytes one slot costs (`cache_bytes_per_slot`; a token costs 0);
 - five program bodies, each keyed by the static shape the engine buckets
   to, all with FLAT signatures so that the engine can jit, name, donate
   and cache them without knowing what the leaves mean:
@@ -58,20 +61,31 @@ tells the engine
     so the host never has to tell the device that a row ended).
     Without `stop0, stop` it returns `(*cache, pos, tok)`.
 
-`PagedKV` states the cache FORMAT once: its leaves, blocks -> rows of
-the compute dtype, rows -> blocks (int8 pools with their scale sidecar
-are a value of it, not a family of bodies).  `chunk_program` is the
-decode chunk: liveness, the greedy pick, the positions, row 0 and the
-aux rows, around ONE decode step the model hands it.
-`kv_write_program` is `kv_write`'s flat signature and the admitted
-slot's state; `packed_prefill_program` is `prefill_packed`'s.
+`PagedKV` states the paged cache's FORMAT once: its leaves, blocks ->
+rows of the compute dtype, rows -> blocks (int8 pools with their scale
+sidecar are a value of it, not a family of bodies).  `SlotState` states
+the second cache KIND beside it: leaves `[layers, slots, *tail]`, one
+state a sequence whatever its length, with no tables, no `blk_ids` and
+no gather width.  The flat signatures keep their order with `tables` /
+`blk_ids` dropped: `decode_chunk` is `(params, *cache, tok, pos, stop)`
+and `prefill_packed(N)` `(params, *cache, tokens, seg, posn, last,
+slots, pos0, stop0, pos, tok, stop)`, whose forward leaves each
+prompt's end state in its slot itself; `suffix_prefill` and `kv_write`
+do not exist for it (no cached prefix to prefill behind).
+`chunk_program` is the decode chunk of BOTH kinds: liveness, the greedy
+pick, the positions, row 0 and the aux rows, around ONE decode step the
+model hands it.  `kv_write_program` is `kv_write`'s flat signature and
+the admitted slot's state; `packed_prefill_program` and
+`slot_prefill_program` are `prefill_packed`'s, for either kind.
 
-Two implementers: `LlamaEngineModel` (per-head K and V pools) and
+Three implementers: `LlamaEngineModel` (per-head K and V pools),
 `LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
-absorbed decode attention, dropless experts).  `engine_model_for` picks
-by the config's type, builds the format from the user's `kv_dtype` and
-hands the implementer the resolved route: a user passes a model's
-config and the model picks its route.
+absorbed decode attention, dropless experts) and `RetentionEngineModel`
+(`models/brumby.py`: every layer a power-retention layer, a per-slot
+state).  `engine_model_for` picks by the config's type, builds the
+format from the user's `kv_dtype` and hands the implementer the
+resolved route: a user passes a model's config and the model picks its
+route.
 """
 
 from __future__ import annotations
@@ -81,8 +95,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import deepseek_v3, llama
+from ray_tpu.exceptions import PrefixCacheUnsupportedError
+from ray_tpu.models import brumby, deepseek_v3, llama
 from ray_tpu.ops import paged_attention as _pa
+from ray_tpu.ops import retention as _ret
 from ray_tpu.serve.kv_cache import CacheLeaf
 
 KV_DTYPES = ("model", "int8")
@@ -170,10 +186,33 @@ class PagedKV:
         return tuple(pool.at[:, blk].set(x) for pool, x in zip(cache, new))
 
 
+class SlotState:
+    """The second cache KIND: a sequence's context is a STATE of fixed
+    size in its slot, not rows that grow.  A cache is a tuple of leaves
+    `[layers, slots, *tail]`, one per entry of `leaves` (name -> (the
+    tail one sequence holds, dtype)): slot b's state is row b.  There
+    are no blocks, no tables and no gather width; admission is bounded
+    by slots alone (`cache_bytes_per_token` 0, `cache_bytes_per_slot`
+    the leaves' bytes); a prefill's end state enters a slot inside the
+    packed prefill program, which is handed the slots; the decode chunk
+    takes no tables (`chunk_program(paged=False)`).  A trie of blocks
+    cannot share a state, so the radix prefix cache is refused
+    (`PrefixCacheUnsupportedError`)."""
+
+    kv_dtype = "model"
+
+    def __init__(self, leaves: Dict[str, Tuple[Tuple[int, ...], object]]):
+        self.leaves: List[CacheLeaf] = [
+            CacheLeaf(name, tail, dtype, per_slot=True)
+            for name, (tail, dtype) in leaves.items()]
+
+
 def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
-                  aux=None):
+                  aux=None, paged: bool = True):
     """THE decode chunk, `(params, *cache, tables, tok, pos, stop) ->
     (*cache, tok, pos, toks)`: `chunk` greedy steps in one `lax.scan`.
+    `paged` False (a per-slot cache, `SlotState`): the same without
+    `tables`, in the signature and handed to `step` as None.
 
     `step(params, tok, cache, tables, pos, live) -> (logits, cache,
     stats)` is the model's decode step at per-row positions; `cache` is
@@ -186,7 +225,10 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
     `aux(stats)`: the model's `aux_rows` counters, `[aux_rows]`, from
     the steps' stacked stats."""
     def _fn(params, *flat):
-        *pool, tables, tok, pos, stop = flat
+        if paged:
+            *pool, tables, tok, pos, stop = flat
+        else:
+            (*pool, tok, pos, stop), tables = flat, None
         cache = tuple(pool) if gather is None else gather.rows(pool, tables)
 
         def body(carry, _):
@@ -237,6 +279,14 @@ def kv_write_program(kv: PagedKV, fit):
     return _fn
 
 
+def _admitted(pos, tok, stop, slots, pos0, tok0, stop0) -> tuple:
+    """A packed prefill's admitted rows of the device state; an unused
+    entry's slot is past the last one: dropped."""
+    return (pos.at[slots].set(pos0, mode="drop"),
+            tok.at[slots].set(tok0, mode="drop"),
+            stop.at[slots].set(stop0, mode="drop"))
+
+
 def packed_prefill_program(kv: PagedKV, forward, fit, segmented: bool):
     """`prefill_packed`, `(params, *cache, tokens, seg, posn, blk_ids,
     last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos, tok,
@@ -255,10 +305,27 @@ def packed_prefill_program(kv: PagedKV, forward, fit, segmented: bool):
         logits, *rows = forward(params, tokens[None], packed)
         tok0 = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
         cache = kv.write(cache, blk_ids, fit(*rows))
-        # an unused entry's slot is past the last one: dropped
-        return (*cache, pos.at[slots].set(pos0, mode="drop"),
-                tok.at[slots].set(tok0, mode="drop"),
-                stop.at[slots].set(stop0, mode="drop"))
+        return (*cache, *_admitted(pos, tok, stop, slots, pos0, tok0, stop0))
+
+    return _fn
+
+
+def slot_prefill_program(n_leaves: int, forward):
+    """`prefill_packed` over a per-slot cache (`SlotState`): the paged
+    signature without `blk_ids`, `(params, *cache, tokens, seg, posn,
+    last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos, tok,
+    stop)`.  `forward(params, cache, tokens [1, N], packed, slots) ->
+    (logits [1, K, vocab], cache)` is the model's prefill forward under
+    a `llama.Packed`, which leaves each prompt's end state in its slot
+    of the cache itself."""
+    def _fn(params, *flat):
+        cache = flat[:n_leaves]
+        (tokens, seg, posn, last, slots, pos0, stop0,
+         pos, tok, stop) = flat[n_leaves:]
+        logits, cache = forward(params, cache, tokens[None],
+                                llama.Packed(last, seg, posn), slots)
+        tok0 = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+        return (*cache, *_admitted(pos, tok, stop, slots, pos0, tok0, stop0))
 
     return _fn
 
@@ -270,8 +337,9 @@ class _EngineModel:
     `tick_fields` (the model's own per-tick counters; none here)."""
 
     aux_rows = 0
+    per_slot = False  # the cache kind: `PagedKV` (False) or `SlotState`
 
-    def __init__(self, cfg, kv: PagedKV, *, chunk: int, paged: bool,
+    def __init__(self, cfg, kv, *, chunk: int, paged: bool,
                  interpret: bool):
         self.cfg, self.kv, self.chunk = cfg, kv, chunk
         self._paged, self._interpret = paged, interpret
@@ -440,9 +508,74 @@ class LatentMoeEngineModel(_EngineModel):
         return kv_write_program(self.kv, fit)
 
 
+class RetentionEngineModel(_EngineModel):
+    """`models/brumby.py` behind the seam: every layer a power
+    retention layer, the cache a `SlotState` of two float32 leaves
+    (`ops/retention.state_shapes`): `state` `[L, slots, KV, d/2 + 1, d,
+    d]` and `keysum` `[L, slots, KV, d/2 + 1, d]`.  Admission is a
+    chunked scan over the packed row that leaves each prompt's state in
+    its slot (`pack_align`: every prompt starts on a multiple of the
+    scan's chunk, which is the engine's `block_size`); decode is one
+    state step a live row.  `suffix_prefill` and `kv_write` do not
+    exist: there is no cached prefix to prefill behind.  `paged`: the
+    Pallas kernels (TPU); else the same algorithms in plain XLA."""
+
+    per_slot = True
+
+    def __init__(self, cfg, kv: SlotState, *, pack_align: int, **route):
+        super().__init__(cfg, kv, **route)
+        self.pack_align = pack_align
+
+    def _kw(self):
+        return dict(kernel=self._paged and not self._interpret,
+                    interpret=self._interpret)
+
+    def decode_chunk(self, W: int):
+        cfg, kw = self.cfg, self._kw()
+
+        def step(params, tok, cache, tables, pos, live):
+            logits, cache = brumby.decode_step(cfg, params, tok, cache, pos,
+                                               live=live, **kw)
+            return logits, cache, ()
+
+        return chunk_program(step, self.chunk, paged=False)
+
+    def prefill_packed(self, N: int):
+        def forward(params, cache, tokens, packed, slots):
+            return brumby.forward(self.cfg, params, tokens, cache,
+                                  packed=packed, slots=slots,
+                                  chunk=self.pack_align, **self._kw())
+
+        return slot_prefill_program(len(self.kv.leaves), forward)
+
+    def prefill(self, bucket: int):
+        def _pf(params, prompt):  # prompt [1, bucket], right-padded
+            logits, _ = brumby.forward(self.cfg, params, prompt,
+                                       chunk=self.pack_align, **self._kw())
+            return (logits[0],)
+
+        return _pf
+
+    def _no_prefix(self, *_):
+        raise PrefixCacheUnsupportedError(
+            "a per-slot state has no cached prefix to prefill behind")
+
+    suffix_prefill = kv_write = _no_prefix
+
+
 def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
     """The implementer for a model's config, its cache in the format
     the user's `kv_dtype` names: the model picks its route."""
+    if isinstance(cfg, brumby.BrumbyConfig):
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' quantizes per-token K and V rows; a "
+                "retention state is one float32 accumulator a sequence")
+        state, keysum = _ret.state_shapes(0, 0, cfg.n_kv_heads, cfg.head_dim)
+        return RetentionEngineModel(cfg, SlotState(
+            {"state": (state[2:], jnp.float32),
+             "keysum": (keysum[2:], jnp.float32)}),
+            pack_align=block_size, **route)
     if isinstance(cfg, deepseek_v3.DeepseekV3Config):
         if kv_dtype == "int8":
             raise ValueError(

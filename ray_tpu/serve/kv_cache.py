@@ -36,9 +36,12 @@ SCRATCH_BLOCK = 0
 
 @dataclasses.dataclass(frozen=True)
 class CacheLeaf:
-    """One device array of a model's paged cache, as the model's cache
-    spec states it (`serve/engine_model.py`): the pool leaf has shape
-    `[layers, num_blocks, block_size, *tail]`.  `used`: how many of the
+    """One device array of a model's cache, as the model's cache spec
+    states it (`serve/engine_model.py`).  A PAGED leaf has shape
+    `[layers, num_blocks, block_size, *tail]`: `tail` is what one token
+    caches.  A `per_slot` leaf has shape `[layers, slots, *tail]`:
+    `tail` is what one SEQUENCE holds, whatever its length (a recurrent
+    state), and no block table points at it.  `used`: how many of the
     last dim's values a token really caches where the leaf is padded to
     the device's tiling (None = all of them); `sidecar`: scales beside
     a quantized payload."""
@@ -48,6 +51,7 @@ class CacheLeaf:
     dtype: Any
     sidecar: bool = False
     used: Optional[int] = None
+    per_slot: bool = False
 
 
 class BlockPool:
@@ -58,15 +62,19 @@ class BlockPool:
     blind to what a block holds, and the device arrays are sized by the
     model's cache spec."""
 
-    def __init__(self, num_blocks: int, spec: Sequence[CacheLeaf] = ()):
+    def __init__(self, num_blocks: int, spec: Sequence[CacheLeaf] = (),
+                 slots: int = 0):
         """`spec`: the model's cache leaves.  The pool allocates what
         the spec says (`leaf_shapes`): two per-head pools for a Llama
         (and their scale sidecars, where the format is int8), a single
-        latent pool for an MLA model; block ids, the scratch block and
-        the radix cache do not care which."""
+        latent pool for an MLA model, one state a slot (`slots` of
+        them) for a recurrent model; block ids, the scratch block and
+        the radix cache do not care which, and a spec of per-slot
+        leaves alone never asks for a block."""
         if num_blocks < 2:
             raise ValueError("block pool needs >= 2 blocks (1 is scratch)")
         self.num_blocks = num_blocks
+        self.slots = int(slots)
         self.spec: Tuple[CacheLeaf, ...] = tuple(spec)
         # pop() from the tail hands out low ids first (stable layouts
         # across runs -> deterministic tests)
@@ -74,18 +82,30 @@ class BlockPool:
 
     def leaf_shapes(self, layers: int, block_size: int) -> List[tuple]:
         """(shape, dtype) of every device array the spec asks for."""
-        return [((layers, self.num_blocks, block_size) + tuple(leaf.tail),
-                 leaf.dtype) for leaf in self.spec]
+        return [(((layers, self.slots) if leaf.per_slot
+                  else (layers, self.num_blocks, block_size))
+                 + tuple(leaf.tail), leaf.dtype) for leaf in self.spec]
 
-    def bytes_per_token(self, layers: int) -> int:
-        """Bytes one cached token costs over all layers, counting the
-        values it caches and not a leaf's tiling pad."""
+    def _bytes(self, layers: int, per_slot: bool) -> int:
         total = 0
         for leaf in self.spec:
+            if leaf.per_slot != per_slot:
+                continue
             width = leaf.tail[-1] if leaf.used is None else leaf.used
             total += (math.prod(leaf.tail[:-1]) * width
                       * np.dtype(leaf.dtype).itemsize)
         return layers * total
+
+    def bytes_per_token(self, layers: int) -> int:
+        """Bytes one cached token costs over all layers, counting the
+        values it caches and not a leaf's tiling pad (0 for a cache of
+        per-slot leaves: a longer context costs it nothing)."""
+        return self._bytes(layers, per_slot=False)
+
+    def bytes_per_slot(self, layers: int) -> int:
+        """Bytes one sequence's per-slot leaves hold over all layers,
+        whatever its length (0 for a paged cache)."""
+        return self._bytes(layers, per_slot=True)
 
     @property
     def free_blocks(self) -> int:

@@ -165,12 +165,20 @@ class LlamaEngine:
     i.e. ring-equivalent capacity — but unlike the ring, an
     over-provisioned pool costs HBM only, not per-step time).
     `prefix_cache=False` disables radix reuse (every request prefills
-    its whole prompt)."""
+    its whole prompt); None, the default, is on wherever the model's
+    cache can share a prefix.
+
+    A model whose context is a per-slot STATE (`engine_model.SlotState`;
+    a linear-attention model) takes the same scheduler: it holds no
+    blocks, so admission is bounded by slots alone, the decode chunk
+    takes no tables (one program, `decode_chunk_state`), `block_size`
+    is only what a prompt is aligned to in a packed prefill, and
+    `prefix_cache=True` raises `PrefixCacheUnsupportedError`."""
 
     def __init__(self, cfg, params, *, slots: int = 32,
                  max_len: Optional[int] = None, chunk: int = 8,
                  block_size: int = 16, kv_blocks: Optional[int] = None,
-                 prefix_cache: bool = True,
+                 prefix_cache: Optional[bool] = None,
                  max_queued: Optional[int] = None,
                  decode_kernel: str = "auto", kv_dtype: str = "model",
                  chunk_cache_cap: int = 8,
@@ -227,8 +235,25 @@ class LlamaEngine:
         self._model = engine_model_for(
             cfg, kv_dtype=kv_dtype, block_size=self.block_size, chunk=chunk,
             paged=mode == "pallas", interpret=self._kernel_interpret)
+        # the cache KIND: blocks through tables, or one state a slot.
+        # A per-slot cache never asks the pool for a block: admission
+        # is bounded by slots alone
+        self._per_slot = self._model.per_slot
+        if self._per_slot:
+            if prefix_cache:
+                from ray_tpu.exceptions import PrefixCacheUnsupportedError
+
+                raise PrefixCacheUnsupportedError(
+                    f"prefix_cache=True: {type(cfg).__name__} keeps a "
+                    "sequence's context as a per-slot state, which a "
+                    "radix trie cannot share block by block; pass "
+                    "prefix_cache=False (or leave it unset)")
+            prefix_cache, budget = False, 1
+        elif prefix_cache is None:
+            prefix_cache = True
         # +1: reserved scratch block
-        self._pool = BlockPool(budget + 1, spec=self._model.cache_leaves)
+        self._pool = BlockPool(budget + 1, spec=self._model.cache_leaves,
+                               slots=slots)
         if prefix_cache and getattr(cfg, "attention", "dense") != "dense":
             # the suffix prefill (`llama.forward_with_prefix`) mirrors
             # the DENSE attention numerics; under flash/ring/ulysses
@@ -257,6 +282,8 @@ class LlamaEngine:
             sum(a.nbytes for a, leaf in zip(self._cache, self._pool.spec)
                 if leaf.sidecar == side) for side in (False, True))
         self._cache_bytes_per_token = self._pool.bytes_per_token(
+            self._model.n_layers)
+        self._cache_bytes_per_slot = self._pool.bytes_per_slot(
             self._model.n_layers)
         self._pos = jnp.zeros((slots,), jnp.int32)
         self._tok = jnp.zeros((slots,), jnp.int32)
@@ -395,11 +422,11 @@ class LlamaEngine:
         instead of serving through another route.  Then every packed
         prefill of the closed set, empty (padding only, into scratch):
         no admission compiles after this, whatever the traffic."""
-        tables = self._jnp.full((self.slots, 1), SCRATCH_BLOCK,
-                                self._jnp.int32)
-        cfn = self._chunk_step_for(1)
+        tables = () if self._per_slot else (self._jnp.full(
+            (self.slots, 1), SCRATCH_BLOCK, self._jnp.int32),)
+        cfn = self._chunk_step_for(0 if self._per_slot else 1)
         self._cache = tuple(cfn(
-            self.params, *self._cache, tables, self._tok, self._pos,
+            self.params, *self._cache, *tables, self._tok, self._pos,
             self._stop)[:len(self._cache)])
         for n in self._pack_sizes:
             self._run_packed(n, [])
@@ -578,6 +605,8 @@ class LlamaEngine:
                 "kv_pool_bytes": self._cache_bytes[0],
                 "kv_scale_bytes": self._cache_bytes[1],
                 "cache_bytes_per_token": self._cache_bytes_per_token,
+                # a per-slot state's bytes a sequence (0: a paged cache)
+                "cache_bytes_per_slot": self._cache_bytes_per_slot,
                 "decode_kernel_dispatch_total":
                     self._decode_kernel_dispatches,
                 "decode_gather_dispatch_total":
@@ -652,8 +681,9 @@ class LlamaEngine:
             return fn
         _fn = self._model.decode_chunk(W)
         # the name the device trace prints the program under
-        # (`jit_decode_chunk_w<W>`): readers match it by prefix
-        _fn.__name__ = f"decode_chunk_w{W}"
+        # (`jit_decode_chunk_w<W>`; a per-slot cache has no width:
+        # `jit_decode_chunk_state`): readers match it by prefix
+        _fn.__name__ = f"decode_chunk_w{W}" if W else "decode_chunk_state"
         fn = self._jax.jit(
             _fn, donate_argnums=tuple(range(1, 1 + len(self._cache))))
         while len(self._chunk_cache) >= self._chunk_cache_cap:
@@ -818,7 +848,9 @@ class LlamaEngine:
         path: List = []
         if self._radix is not None:
             shared, path = self._radix.match(prompt)
-        own = self._alloc_or_evict(total_blocks - len(shared))
+        # a per-slot state holds no blocks: the free slot is all it needs
+        own = [] if self._per_slot else self._alloc_or_evict(
+            total_blocks - len(shared))
         if own is None:
             if self._radix is not None:
                 self._radix.release(path)
@@ -901,7 +933,8 @@ class LlamaEngine:
     def _pack_arrays(self, N: int, pack: List[_Plan]) -> tuple:
         """A packed prefill's host-made arguments, `(tokens, seg, posn,
         blk_ids, last, slots, pos0, stop0)`: the prompts of `pack` end
-        to end in a row of `N` tokens, each from a block boundary."""
+        to end in a row of `N` tokens, each from a block boundary.  A
+        per-slot cache has no `blk_ids`."""
         bs, K = self.block_size, self._pack_rows
         i32 = np.int32
         tokens, posn = np.zeros(N, i32), np.zeros(N, i32)
@@ -918,10 +951,13 @@ class LlamaEngine:
             posn[at:at + T] = np.arange(T)
             # only the blocks holding real tokens; garbage within the
             # last of them is masked by pos until decode overwrites it
-            blk_ids[at // bs:at // bs + nb] = plan.own[:nb]
+            if not self._per_slot:
+                blk_ids[at // bs:at // bs + nb] = plan.own[:nb]
             last[i], slots[i] = at + T - 1, plan.slot
             pos0[i], stop0[i] = T, plan.req["stop"]
             at += nb * bs
+        if self._per_slot:
+            return tokens, seg, posn, last, slots, pos0, stop0
         return tokens, seg, posn, blk_ids, last, slots, pos0, stop0
 
     def _run_packed(self, N: int, pack: List[_Plan]) -> None:
@@ -1101,22 +1137,28 @@ class LlamaEngine:
             self._prefill(plans)
         t1 = _time.perf_counter()
         with self._lock:
-            # 0 = nothing live (a live batch needs at least one block)
-            W = self._gather_width() if self._active else 0
+            # 0 = nothing live (a live batch needs at least one block);
+            # a per-slot cache has no width, so any live row is 1
+            live_now = bool(self._active)
+            W = (int(live_now) if self._per_slot
+                 else self._gather_width() if live_now else 0)
         toks = None
         # of the chunk's slots x chunk row-steps, those a request was
         # waiting for (its steps before its stop); the rest are dead
-        row_steps = row_steps_live = 0
+        row_steps = row_steps_live = rows_live = 0
         if W:
             with self._span("engine.dispatch", W=W):
-                with self._lock:
-                    tables = np.zeros((self.slots, W), np.int32)
-                    for slot in self._active:
-                        blocks = self._slot_blocks[slot][:W]
-                        tables[slot, :len(blocks)] = blocks
-                self._last_gather_blocks = W
-                cfn = self._chunk_step_for(W)
-                out = cfn(self.params, *self._cache, jnp.asarray(tables),
+                tables = ()
+                if not self._per_slot:
+                    with self._lock:
+                        table = np.zeros((self.slots, W), np.int32)
+                        for slot in self._active:
+                            blocks = self._slot_blocks[slot][:W]
+                            table[slot, :len(blocks)] = blocks
+                    tables = (jnp.asarray(table),)
+                self._last_gather_blocks = 0 if self._per_slot else W
+                cfn = self._chunk_step_for(0 if self._per_slot else W)
+                out = cfn(self.params, *self._cache, *tables,
                           self._tok, self._pos, self._stop)
                 self._cache = tuple(out[:-3])
                 self._tok, self._pos, toks = out[-3:]
@@ -1132,6 +1174,7 @@ class LlamaEngine:
                     for req in self._active.values():
                         end = min(req["pos_host"] + self.chunk, req["stop"])
                         row_steps_live += end - req["pos_host"]
+                        rows_live += end > req["pos_host"]
                         req["pos_host"] = end
         # OVERLAP: harvest the PREVIOUS chunk's tokens while the
         # current chunk computes — the device->host read is round-trip
@@ -1173,7 +1216,10 @@ class LlamaEngine:
                 "live_tokens": sum(
                     r["pos_host"] for r in self._active.values()
                 ),
-                "gather_blocks": W,
+                "gather_blocks": 0 if self._per_slot else W,
+                # rows that owed a token at the chunk's first step: for
+                # a per-slot cache, the states its first step moves
+                "state_rows_live": rows_live if self._per_slot else 0,
                 "row_steps_live": row_steps_live,
                 "row_steps": row_steps,
                 "kernel": self._decode_kernel,
@@ -1258,7 +1304,8 @@ class LlamaEngine:
                     # block returns to the pool and the radix cache
                     # empties (its pinned paths died with the requests)
                     self._pool = BlockPool(self._pool.num_blocks,
-                                           spec=self._pool.spec)
+                                           spec=self._pool.spec,
+                                           slots=self.slots)
                     if self._radix is not None:
                         self._radix = RadixCache(
                             self.block_size, self._pool

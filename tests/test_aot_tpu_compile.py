@@ -350,3 +350,102 @@ def test_prefill_packed_at_the_cells_shapes(chip, make, blocks, N):
     assert "input_output_alias" in hlo  # the pool is written in place
     # kanana's grouped products are kernels; Mistral's prefill has none
     assert ("tpu_custom_call" in hlo) == latent
+
+
+# ----------------------------------------------------------------------
+# power retention: the two kernels and the state model's two programs
+# ----------------------------------------------------------------------
+_BRUMBY = dict(L=5, B=32, H=40, KV=8, d=128)
+
+
+def _brumby_l5():
+    from ray_tpu.models import brumby
+
+    return brumby.BrumbyConfig(n_layers=5, dtype=BF16)
+
+
+def _state_leaves(d=_BRUMBY):
+    from ray_tpu.ops import retention
+
+    return [_s(*shape, dtype=jnp.float32) for shape in
+            retention.state_shapes(d["L"], d["B"], d["KV"], d["d"])]
+
+
+def test_retention_decode_kernel(chip):
+    """One `[65, 128, 128]` float32 state block a grid step (4.26 MB,
+    two copies each way under a 64 MB VMEM limit), the state and the
+    key sum aliased in place; the benchmark finds the kernel by its
+    first result, the numerators `f32[32,8,128,128]`."""
+    from ray_tpu.ops import retention
+
+    d = _BRUMBY
+
+    def step(q, k, v, g, state, keysum, live, layer):
+        return retention.retention_decode(q, k, v, g, state, keysum, live,
+                                          layer, eps=1e-6, kernel=True)
+
+    hlo = _compile(
+        chip, step, _s(d["B"], d["H"], d["d"]), _s(d["B"], d["KV"], d["d"]),
+        _s(d["B"], d["KV"], d["d"]), _s(d["B"], d["KV"], dtype=jnp.float32),
+        *_state_leaves(), _s(d["B"], dtype=jnp.bool_), _s(dtype=jnp.int32),
+        donate_argnums=(4, 5))
+    assert "tpu_custom_call" in hlo and "(f32[32,8,128,128]" in hlo
+    assert "input_output_alias" in hlo
+
+
+@pytest.mark.parametrize("N", [256, 2560])
+def test_retention_prefill_kernel(chip, N):
+    """The chunked scan at the smallest and the largest packed row of
+    the cell's closed set, 256-token chunks: dynamic lane rotations, a
+    tile of the carried state by its leading index, one-row stores of
+    the key sum at a dynamic offset."""
+    from ray_tpu.ops import retention
+
+    d = _BRUMBY
+    i32 = jnp.int32
+
+    def scan(q, k, v, g, seg, posn, slots, state, keysum, layer):
+        return retention.retention_prefill(
+            q, k, v, g, seg, posn, slots, state, keysum, layer, chunk=256,
+            eps=1e-6, kernel=True)
+
+    hlo = _compile(
+        chip, scan, _s(N, d["H"], d["d"]), _s(N, d["KV"], d["d"]),
+        _s(N, d["KV"], d["d"]), _s(N, d["KV"], dtype=jnp.float32),
+        _s(N, dtype=i32), _s(N, dtype=i32), _s(16, dtype=i32),
+        *_state_leaves(), _s(dtype=i32), donate_argnums=(7, 8))
+    assert "tpu_custom_call" in hlo and f"(bf16[8,5,{N},128]" in hlo
+    assert "input_output_alias" in hlo
+
+
+def test_state_model_programs_at_the_cells_shapes(chip):
+    """`decode_chunk_state` and `prefill_packed_n2048` as the engine
+    jits them for the retention model at the published widths (5
+    layers, 32 slots, chunk 8, 256-token alignment): no tables, no
+    `blk_ids`, the two state leaves donated and written in place."""
+    from ray_tpu.models import brumby
+    from ray_tpu.serve.engine_model import engine_model_for
+
+    cfg = _brumby_l5()
+    params = jax.tree.map(
+        lambda p: _s(*p.shape, dtype=p.dtype),
+        jax.eval_shape(lambda: brumby.init_params(cfg, jax.random.PRNGKey(0))))
+    model = engine_model_for(cfg, kv_dtype="model", block_size=256, chunk=8,
+                             paged=True, interpret=False)
+    assert model.per_slot and [l.per_slot for l in model.cache_leaves] == [
+        True, True]
+    cache = _state_leaves()
+    i32 = jnp.int32
+    rows = [_s(32, dtype=i32)] * 3
+    donate = dict(donate_argnums=(1, 2))
+    fn = model.decode_chunk(0)
+    fn.__name__ = "decode_chunk_state"
+    hlo = _compile(chip, fn, params, *cache, *rows, **donate)
+    assert "jit_decode_chunk_state" in hlo and "(f32[32,8,128,128]" in hlo
+    assert "input_output_alias" in hlo
+    fn = model.prefill_packed(2048)
+    fn.__name__ = "prefill_packed_n2048"
+    hlo = _compile(chip, fn, params, *cache, *[_s(2048, dtype=i32)] * 3,
+                   *[_s(16, dtype=i32)] * 4, *rows, **donate)
+    assert "jit_prefill_packed_n2048" in hlo and "(bf16[8,5,2048,128]" in hlo
+    assert "input_output_alias" in hlo and "(f32[32,8,128,128]" not in hlo
