@@ -8,36 +8,78 @@ softmax (running max / sum / accumulator in f32), so the [Tq, Tk] score
 matrix never materializes in HBM — the memory shape that unlocks long
 context on one chip.
 
-What makes it *beat* dense XLA attention at seq ~1k (the round-1 kernel
-lost to it):
-- matmuls run on the MXU in bf16 with f32 accumulation
-  (`preferred_element_type`) — the old kernel upcast q/k/v to f32
-  first, quartering MXU throughput;
-- causal block skipping: fully-masked [block_q, block_k] tiles skip
-  their matmuls entirely (~half the quadratic FLOPs at equal block
-  counts), where the dense path computes-then-masks;
-- a real Pallas backward (dq kernel + dk/dv kernel, FlashAttention-2
-  style with the per-row logsumexp saved from forward) instead of
-  recomputing dense attention with XLA ops — same block skipping, no
-  [T, T] HBM tensor in the backward either;
+The GRID is coarse and the walk is INSIDE the grid step.  A grid step
+owns a `[block_q, block_k]` tile, by default 1024 x 1024: at T 1024 (the
+train shapes) that is ONE tile a (batch, head), grid `(B*H, 1, 1)`.
+Finer grid blocks lost when measured (512 x 512: 78.9k against 89.7k
+tokens/s at GPT-2 124M; a grid step costs ~0.35 us and fetches K and V
+again), and a single tile can skip nothing at the grid level: until
+PR 32 both kernels computed all T x T scores of a head and masked half
+of them away.  Now the tile is cut into sub-tiles (`_sub_tile`: 256
+wide where that cuts the block at least in two) and walked with static
+bounds (`_row_walk`, `_col_walk`; `causal_walk` counts them): sub-tiles
+wholly above the diagonal are never computed, the iota / compare /
+select of the mask falls only on the sub-tiles the diagonal crosses,
+and everything below it is plain.  At T 1024 that is 10 of 16 sub-tiles
+visited, 4 masked.  The sub-tiles a strip holds are taken TOGETHER: a
+row-group's max, exp, sum and its two products cost a fixed amount a
+strip whatever its width, and on the v5e a walk sub-tile by sub-tile
+(rolled loops, an online-softmax update each) ran SLOWER than no
+skipping at all (PERF.md section 6, PR 32).
+
+- forward (`_build_fwd`): q sub-block by q sub-block, each against its
+  live columns `[0, vis * sub_k)` as one strip: one score product, the
+  mask on the strip's last sub-tile(s), one max / exp / sum a row, one
+  value product.  Where one grid step sees the whole kv sequence the
+  softmax is complete in the strip and no running state is kept; across
+  kv grid steps the running max / sum / f32 accumulator live in VMEM
+  scratch `[block_q, 1]` / `[block_q, D]` (keepdims columns: the old
+  forward kept 1-D vectors and relaid them out with `[:, None]` every
+  step, and the same whole-tile math on columns ran 1.8 x faster).  A grid tile wholly below the diagonal takes no
+  mask, one above it is skipped, a diagonal tile of square blocks is
+  walked like the single tile; only rectangular blocks, whose offset
+  from the diagonal is not static, mask a whole tile.
+- fused backward (`_build_bwd_fused`, block_q == block_k == T: the
+  train shapes): ONE kernel a (batch, head) computes s, p = exp(s -
+  lse), dp, ds once each per live element and gives dQ, dK, dV — the
+  split pair pays 7 matmuls + 2 exps + an XLA delta pass for the same
+  math.  It walks kv sub-block by kv sub-block: the q rows the diagonal
+  crosses under the mask, every row below them as one strip; dK_j and
+  dV_j are summed in f32 values over the two, dQ gathers in an f32
+  `[T, D]` VMEM scratch for the whole head (256 KB at hd 64), and
+  `delta = rowsum(do * out)` is computed in the kernel, once a head,
+  into a `[T, 1]` scratch.
+- split backward (`_build_bwd_dq`, `_build_bwd_dkv`: T > block): the
+  FlashAttention-2 pair with the per-row logsumexp saved from forward;
+  grid tiles above the diagonal are skipped and only tiles the diagonal
+  crosses are masked.  No sub-tile walk yet: no benchmark cell runs it.
+- matmuls run on the MXU in the input dtype with f32 accumulation
+  (`preferred_element_type`); max, exp, sum, lse and every accumulator
+  are f32; `p` and `ds` are cast to the input dtype for their products.
+  A power-of-two softmax scale (hd 16, 64, 256) is folded into a bf16
+  operand, exactly; any other stays on the f32 scores.
 - `dimension_semantics`: batch*heads and q blocks are parallel grid
-  axes, the kv walk is the sole sequential axis;
-- single-tile FUSED backward when block_q == block_k == T (the bench
-  shapes): dq/dk/dv come out of one kernel per (batch, head) that
-  computes s, p, dp, ds once and delta=rowsum(do*out) in-kernel — the
-  split kernel pair pays 7 matmuls + 2 exps + an XLA delta pass for
-  the same math (measured +6% end-to-end GPT-2 step on v5e).
+  axes, the kv walk is the sole sequential axis.
+
+With hd 64 every product half-fills the MXU (the contraction of q.k^T,
+the output columns of p.v), so at hd 64 and hd 128 a head costs the
+same MXU time; the roofline in `benchmarks/roofline.py` counts useful
+FLOPs, so a 256-wide walk at the MXU's own limit would read ~40% there
+at hd 64 (half the pairs needed of 62.5% computed, on half the array).
 
 The kernels are what runs: a shape they cannot tile raises, naming the
 shape, and nothing here looks at the backend or gives way to the XLA
 path.  `interpret=True` is the caller's explicit choice (the CPU
 tests); `tests/test_aot_tpu_compile.py` lowers the train shapes for a
-described v5e chip.
+described v5e chip.  The benchmark finds the calls in a trace by their
+RESULT shapes (`(bf16[B*H,T,hd], f32[B*H,T,1])` and three
+`bf16[B*H,T,hd]`): operands and scratch are free, results are not.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -51,59 +93,168 @@ def _dot_f32(a, b, trans_b=False):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _causal_mask(s, qi, kb, block_q, block_k):
-    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(rows >= cols, s, _NEG_INF)
+# ----------------------------------------------------------------------
+# the walk: which sub-tiles of a causal tile are live, and which of
+# those the diagonal crosses
+# ----------------------------------------------------------------------
+def _sub_tile(block: int) -> int:
+    """The sub-tile width a block of `block` rows (or columns) is
+    walked in: 256 where that cuts it at least in two, else 128, else
+    the block whole.  256 is what the v5e measured fastest for the
+    forward and the fused backward at hd 64 and hd 128, bf16 (PERF.md
+    section 6, PR 32): 128 skips more (36 of 64 sub-tiles at T 1024
+    against 10 of 16) and loses it again in per-strip costs, 512 skips
+    only one tile of four."""
+    for sub in (256, 128):
+        if block % sub == 0 and block > sub:
+            return sub
+    return block
 
 
-def _build_fwd(causal, scale, block_q, block_k, n_k, interpret, dtype):
+def _clip(x, lo, hi):
+    return max(lo, min(x, hi))
+
+
+def _row_walk(r0, sub_q, sub_k, n):
+    """For the q sub-block on rows `[r0, r0 + sub_q)` and `n` kv
+    sub-blocks on columns `j * sub_k`: `(full, vis)`.  Sub-blocks
+    `[0, full)` lie at or below the diagonal in every row (no mask),
+    `[full, vis)` are crossed by it (masked), `[vis, n)` lie wholly
+    above it (skipped).  Python ints: the walk is static."""
+    full = _clip(r0 + 1, 0, n * sub_k) // sub_k
+    vis = -(-_clip(r0 + sub_q, 0, n * sub_k) // sub_k)
+    return full, vis
+
+
+def _col_walk(c0, sub_k, sub_q, n):
+    """The backward's view of the same walk, for the kv sub-block on
+    columns `[c0, c0 + sub_k)` and `n` q sub-blocks on rows
+    `i * sub_q`: `(vis, full)`.  Sub-blocks `[0, vis)` see none of
+    these columns (skipped), `[vis, full)` are crossed by the diagonal
+    (masked), `[full, n)` see all of them (no mask)."""
+    vis = _clip(c0, 0, n * sub_q) // sub_q
+    full = -(-_clip(c0 + sub_k - 1, 0, n * sub_q) // sub_q)
+    return vis, full
+
+
+def causal_walk(block_q, block_k, sub_q, sub_k, q0=0, k0=0):
+    """Sub-tiles the kernels visit, and mask, in the `[block_q,
+    block_k]` tile whose first row is `q0` and first column `k0`, of
+    `total`.  Counted from `_row_walk`, where the forward takes its
+    strips; the fused backward takes the same walk kv sub-block by kv
+    sub-block from `_col_walk` (`tests/test_ops.py` holds the two views
+    to the same counts)."""
+    n_q, n_k = block_q // sub_q, block_k // sub_k
+    rows = [_row_walk(q0 - k0 + i * sub_q, sub_q, sub_k, n_k)
+            for i in range(n_q)]
+    return {"visited": sum(vis for _, vis in rows),
+            "masked": sum(vis - full for full, vis in rows),
+            "total": n_q * n_k}
+
+
+def _causal_mask(s, r0, c0):
+    """`s` is the tile whose first row is `r0` and first column `c0`."""
+    below = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    return jnp.where(below >= c0 - r0, s, _NEG_INF)
+
+
+def _folds(scale):
+    """A power-of-two scale (hd 16, 64, 256) multiplies a bf16 operand
+    exactly, so it is applied to `[rows, hd]` once instead of to every
+    `[rows, cols]` of scores; any other scale stays on the f32 scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _build_fwd(causal, scale, block_q, block_k, n_k, interpret, dtype, sub):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref):
-        qi = pl.program_id(1)
-        kb = pl.program_id(2)
+    sub_q, sub_k = sub
+    n_sq, n_sk = block_q // sub_q, block_k // sub_k
+    fold = _folds(scale)
 
-        @pl.when(kb == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state):
+        # `state`: running max, sum and accumulator of the block's rows
+        # across the kv grid steps; none where one step sees every kv
+        m_ref, l_ref, acc_ref = state or (None, None, None)
+        q0 = pl.program_id(1) * block_q
+        k0 = pl.program_id(2) * block_k
 
-        def compute():
-            qb = q_ref[...]  # [block_q, D] compute dtype
-            s = _dot_f32(qb, k_ref[...], trans_b=True) * scale
-            if causal:
-                s = _causal_mask(s, qi, kb, block_q, block_k)
-            m = m_ref[...]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            corr = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            m_ref[...] = m_new
-            l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-            acc_ref[...] = acc_ref[...] * corr[:, None] + _dot_f32(
-                p.astype(dtype), v_ref[...]
-            )
+        if state:
+            @pl.when(pl.program_id(2) == 0)
+            def _init():
+                m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+                l_ref[...] = jnp.zeros_like(l_ref)
+                acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        if causal:
-            # skip tiles strictly above the diagonal (fully masked)
-            @pl.when(kb * block_k <= qi * block_q + block_q - 1)
-            def _():
-                compute()
+        def walk(rel):
+            """The tile whose first row lies `rel` below its first
+            column.  A Python int, so every bound is static: a q
+            sub-block meets its live columns `[0, vis * sub_k)` as ONE
+            strip (one product, one max, one exp, one sum a row), the
+            mask falls on the sub-tiles `[full, vis)` the diagonal
+            crosses, and the strips are straight-line code the
+            scheduler overlaps.  None: not known until run time
+            (rectangular blocks), the whole width then goes under the
+            mask."""
+            for i in range(n_sq):
+                r = i * sub_q
+                rows = slice(r, r + sub_q)
+                if rel is None:
+                    full, vis, origin = 0, n_sk, (q0 + r, k0)
+                else:
+                    full, vis = _row_walk(rel + r, sub_q, sub_k, n_sk)
+                    origin = (rel + r, full * sub_k)
+                lo, hi = full * sub_k, vis * sub_k
+                qb = q_ref[rows, :]  # [sub_q, D] compute dtype
+                s = _dot_f32(qb * scale if fold else qb, k_ref[:hi, :],
+                             trans_b=True)
+                if not fold:
+                    s = s * scale
+                if hi > lo:
+                    crossed = _causal_mask(s[:, lo:], *origin)
+                    s = crossed if lo == 0 else jnp.concatenate(
+                        [s[:, :lo], crossed], axis=1)
+                m = jnp.max(s, axis=-1, keepdims=True)
+                if state:
+                    m_prev = m_ref[rows, :]
+                    m = jnp.maximum(m_prev, m)
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                acc = _dot_f32(p.astype(dtype), v_ref[:hi, :])
+                if state:
+                    corr = jnp.exp(m_prev - m)
+                    m_ref[rows, :] = m
+                    l_ref[rows, :] = l_ref[rows, :] * corr + l
+                    acc_ref[rows, :] = acc_ref[rows, :] * corr + acc
+                else:  # a row's max weighs exp(0): l >= 1
+                    o_ref[rows, :] = (acc / l).astype(o_ref.dtype)
+                    lse_ref[rows, :] = m + jnp.log(l)
+
+        if not causal:
+            walk(block_k)
+        elif n_k == 1:
+            # the whole sequence in one tile (the train shapes), or
+            # every q block against all of kv
+            walk(0 if block_q == block_k else None)
         else:
-            compute()
+            _when_live(
+                q0, block_q, k0, block_k, lambda: walk(block_k),
+                lambda: walk(0 if block_q == block_k else None))
 
-        @pl.when(kb == n_k - 1)
-        def _finalize():
-            l = l_ref[...]
-            # fully-masked rows (can't happen causally, but keep the
-            # kernel total): lse=-inf, out=0
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[...] = (acc_ref[...] / safe_l[:, None]).astype(o_ref.dtype)
-            # lse rides a trailing singleton lane dim: TPU block specs
-            # need the last two dims (8, 128)-divisible or array-equal
-            lse_ref[...] = (m_ref[...] + jnp.log(safe_l))[:, None]
+        if state:
+            @pl.when(pl.program_id(2) == n_k - 1)
+            def _finalize():
+                l = l_ref[...]
+                # fully-masked rows (can't happen causally, but keep the
+                # kernel total): lse=-inf, out=0
+                safe_l = jnp.where(l == 0.0, 1.0, l)
+                o_ref[...] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+                # lse rides a trailing singleton lane dim: TPU block
+                # specs need the last two dims (8, 128)-divisible or
+                # array-equal
+                lse_ref[...] = m_ref[...] + jnp.log(safe_l)
 
     def call(q, k, v):
         BH, T, D = q.shape
@@ -127,10 +278,10 @@ def _build_fwd(causal, scale, block_q, block_k, n_k, interpret, dtype):
                 jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, D), jnp.float32),
-            ],
+            ] if n_k > 1 else [],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
@@ -140,36 +291,67 @@ def _build_fwd(causal, scale, block_q, block_k, n_k, interpret, dtype):
     return call
 
 
+def _bwd_tile(qb, kb, vb, dob, lse, delta, scale, mask):
+    """One `[rows, cols]` tile of the backward: `p = exp(s - lse)` and
+    `ds = p * (dp - delta)`, each computed once.  `scale` is None where
+    the caller folded it into `kb`; `mask` is None or the tile's
+    `(first row, first column)`."""
+    s = _dot_f32(qb, kb, trans_b=True)
+    if scale is not None:
+        s = s * scale
+    if mask is not None:
+        s = _causal_mask(s, *mask)
+    p = jnp.exp(s - lse)  # [rows, cols] - [rows, 1] broadcast
+    ds = p * (_dot_f32(dob, vb, trans_b=True) - delta)
+    if scale is not None:
+        ds = ds * scale
+    return p, ds
+
+
+def _when_live(q0, block_q, k0, block_k, below, crossing):
+    """A causal grid tile: `below()` where it lies wholly at or below
+    the diagonal (no mask needed), `crossing()` where the diagonal
+    crosses it, nothing where it lies above."""
+    from jax.experimental import pallas as pl
+
+    is_below = k0 + block_k - 1 <= q0
+    pl.when(is_below)(below)
+    pl.when(jnp.logical_and(jnp.logical_not(is_below),
+                            k0 <= q0 + block_q - 1))(crossing)
+
+
+def _compute_live(causal, q0, block_q, k0, block_k, compute):
+    """The split backward's tile: `compute(masked)`, masked only where
+    the diagonal crosses it."""
+    if causal:
+        _when_live(q0, block_q, k0, block_k,
+                   lambda: compute(False), lambda: compute(True))
+    else:
+        compute(False)
+
+
 def _build_bwd_dq(causal, scale, block_q, block_k, n_k, interpret, dtype):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref, acc_ref):
-        qi = pl.program_id(1)
-        kb = pl.program_id(2)
+        q0 = pl.program_id(1) * block_q
+        k0 = pl.program_id(2) * block_k
 
-        @pl.when(kb == 0)
+        @pl.when(pl.program_id(2) == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def compute():
-            qb = q_ref[...]
-            s = _dot_f32(qb, k_ref[...], trans_b=True) * scale
-            if causal:
-                s = _causal_mask(s, qi, kb, block_q, block_k)
-            p = jnp.exp(s - lse_ref[...])  # [bq,bk] - [bq,1] broadcast
-            dp = _dot_f32(do_ref[...], v_ref[...], trans_b=True)
-            ds = p * (dp - dlt_ref[...]) * scale
+        def compute(masked):
+            _, ds = _bwd_tile(
+                q_ref[...], k_ref[...], v_ref[...], do_ref[...],
+                lse_ref[...], dlt_ref[...], scale,
+                (q0, k0) if masked else None)
             acc_ref[...] += _dot_f32(ds.astype(dtype), k_ref[...])
 
-        if causal:
-            @pl.when(kb * block_k <= qi * block_q + block_q - 1)
-            def _():
-                compute()
-        else:
-            compute()
+        _compute_live(causal, q0, block_q, k0, block_k, compute)
 
-        @pl.when(kb == n_k - 1)
+        @pl.when(pl.program_id(2) == n_k - 1)
         def _fin():
             dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
@@ -200,37 +382,53 @@ def _build_bwd_dq(causal, scale, block_q, block_k, n_k, interpret, dtype):
     return call
 
 
-def _build_bwd_fused(causal, scale, T, interpret, dtype):
-    """Single-tile backward for the whole-sequence block case
-    (block_q == block_k == T): with a (BH,) grid there is no
-    cross-block accumulation, so dq/dk/dv come out of ONE kernel that
-    computes s, p=exp(s-lse), dp, ds exactly once — the split
-    dq/dkdv pair recomputes all four per kernel (7 matmuls + 2 exps vs
-    5 matmuls + 1 exp here) and re-reads q/k/v/do twice from HBM."""
+def _build_bwd_fused(causal, scale, T, interpret, dtype, sub):
+    """Backward for the whole-sequence tile (block_q == block_k == T):
+    with a (BH,) grid there is no cross-step accumulation, so dq/dk/dv
+    come out of ONE kernel (the module docstring says how it walks)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    n_sub = T // sub
+    fold = _folds(scale)
+
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, out_ref,
-               dq_ref, dk_ref, dv_ref):
-        qb = q_ref[...]
-        kb = k_ref[...]
-        dob = do_ref[...]
-        # delta = rowsum(do * out) computed here instead of a separate
-        # XLA pass that would re-read both [BH, T, D] tensors from HBM
-        delta = jnp.sum(
-            dob.astype(jnp.float32) * out_ref[...].astype(jnp.float32),
+               dq_ref, dk_ref, dv_ref, dq_acc, dlt_ref):
+        dlt_ref[...] = jnp.sum(
+            do_ref[...].astype(jnp.float32) * out_ref[...].astype(jnp.float32),
             axis=-1, keepdims=True,
         )
-        s = _dot_f32(qb, kb, trans_b=True) * scale
-        if causal:
-            s = _causal_mask(s, 0, 0, T, T)
-        p = jnp.exp(s - lse_ref[...])
-        pc = p.astype(dtype)
-        dv_ref[...] = _dot_f32(pc.T, dob).astype(dv_ref.dtype)
-        dp = _dot_f32(dob, v_ref[...], trans_b=True)
-        ds = (p * (dp - delta) * scale).astype(dtype)
-        dq_ref[...] = _dot_f32(ds, kb).astype(dq_ref.dtype)
-        dk_ref[...] = _dot_f32(ds.T, qb).astype(dk_ref.dtype)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        for j in range(n_sub):
+            c = j * sub
+            cols = slice(c, c + sub)
+            kb, vb = k_ref[cols, :], v_ref[cols, :]
+            # folded: s, dq and (at the end) dk take the scale through
+            # an operand, exactly; `ds` below is then unscaled
+            ks = kb * scale if fold else kb
+            vis, full = _col_walk(c, sub, sub, n_sub) if causal else (0, 0)
+            dk = dv = jnp.zeros((sub, q_ref.shape[-1]), jnp.float32)
+            # the q sub-block the diagonal crosses, under the mask, then
+            # every one below it as ONE strip
+            for lo, hi, masked in ((vis * sub, full * sub, True),
+                                   (full * sub, T, False)):
+                if hi == lo:
+                    continue
+                rows = slice(lo, hi)
+                qb, dob = q_ref[rows, :], do_ref[rows, :]
+                p, ds = _bwd_tile(
+                    qb, ks, vb, dob, lse_ref[rows, :], dlt_ref[rows, :],
+                    None if fold else scale, (lo, c) if masked else None)
+                ds = ds.astype(dtype)
+                dq_acc[rows, :] += _dot_f32(ds, ks)
+                dk = dk + _dot_f32(ds.T, qb)
+                dv = dv + _dot_f32(p.astype(dtype).T, dob)
+            if fold:
+                dk = dk * scale
+            dk_ref[cols, :] = dk.astype(dk_ref.dtype)
+            dv_ref[cols, :] = dv.astype(dv_ref.dtype)
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
     def call(q, k, v, do, lse, out):
         BH, T_, D = q.shape
@@ -243,6 +441,10 @@ def _build_bwd_fused(causal, scale, T, interpret, dtype):
             in_specs=[spec, spec, spec, spec, vec, spec],
             out_specs=[spec, spec, spec],
             out_shape=[jax.ShapeDtypeStruct((BH, T_, D), q.dtype)] * 3,
+            scratch_shapes=[
+                pltpu.VMEM((T_, D), jnp.float32),
+                pltpu.VMEM((T_, 1), jnp.float32),
+            ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
             ),
@@ -258,36 +460,27 @@ def _build_bwd_dkv(causal, scale, block_q, block_k, n_q, interpret, dtype):
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                dk_ref, dv_ref, dk_acc, dv_acc):
-        kb = pl.program_id(1)
-        qi = pl.program_id(2)
+        k0 = pl.program_id(1) * block_k
+        q0 = pl.program_id(2) * block_q
 
-        @pl.when(qi == 0)
+        @pl.when(pl.program_id(2) == 0)
         def _init():
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
-        def compute():
-            qb = q_ref[...]
-            s = _dot_f32(qb, k_ref[...], trans_b=True) * scale
-            if causal:
-                s = _causal_mask(s, qi, kb, block_q, block_k)
-            p = jnp.exp(s - lse_ref[...])  # [bq,bk] - [bq,1] broadcast
-            pT = p.astype(dtype).T  # [bk, bq]
-            dv_acc[...] += _dot_f32(pT, do_ref[...])
-            dp = _dot_f32(do_ref[...], v_ref[...], trans_b=True)
-            ds = p * (dp - dlt_ref[...]) * scale
+        def compute(masked):
+            qb, dob = q_ref[...], do_ref[...]
+            p, ds = _bwd_tile(
+                qb, k_ref[...], v_ref[...], dob, lse_ref[...], dlt_ref[...],
+                scale, (q0, k0) if masked else None)
+            dv_acc[...] += _dot_f32(p.astype(dtype).T, dob)
             dk_acc[...] += _dot_f32(ds.astype(dtype).T, qb)
 
-        if causal:
-            # q blocks entirely above the diagonal see this kv block
-            # fully masked: skip
-            @pl.when(qi * block_q + block_q - 1 >= kb * block_k)
-            def _():
-                compute()
-        else:
-            compute()
+        # q blocks entirely above the diagonal see this kv block fully
+        # masked: skipped
+        _compute_live(causal, q0, block_q, k0, block_k, compute)
 
-        @pl.when(qi == n_q - 1)
+        @pl.when(pl.program_id(2) == n_q - 1)
         def _fin():
             dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -369,8 +562,8 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
     block_q, block_k = _blocks(q, block_q, block_k)
     scale = 1.0 / (D ** 0.5)
     n_k = T // block_k
-    fwd = _build_fwd(causal, scale, block_q, block_k, n_k,
-                     interpret, q.dtype)
+    fwd = _build_fwd(causal, scale, block_q, block_k, n_k, interpret,
+                     q.dtype, (_sub_tile(block_q), _sub_tile(block_k)))
     out, lse = fwd(_fold(q), _fold(k), _fold(v))
     # lse and out stay folded [B*H, T, ...] for the backward kernels
     return _unfold(out, B, H), (q, k, v, lse, out)
@@ -385,7 +578,8 @@ def _bwd(causal, block_q, block_k, interpret, res, g):
     n_k = T // block_k
     qf, kf, vf, dof = _fold(q), _fold(k), _fold(v), _fold(g)
     if block_q == T and block_k == T:
-        fused = _build_bwd_fused(causal, scale, T, interpret, q.dtype)
+        fused = _build_bwd_fused(causal, scale, T, interpret, q.dtype,
+                                 _sub_tile(T))
         dq, dk, dv = fused(qf, kf, vf, dof, lse, out_folded)
         return _unfold(dq, B, H), _unfold(dk, B, H), _unfold(dv, B, H)
     delta = jnp.sum(

@@ -97,24 +97,55 @@ def _bf16_params(cfg):
 # ----------------------------------------------------------------------
 # train path: flash attention forward + backward
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("B,T,H,D,block", [
-    # GPT-2 124M at the smoke's batch: single tile -> FUSED backward
-    (16, 1024, 12, 64, 1024),
-    # llama1b4 LoRA bench shape: single tile, D=128
-    (8, 1024, 16, 128, 1024),
-    # long sequences: multi-block forward + SPLIT dq / dkv backward
-    (1, 4096, 12, 64, 1024),
-    (1, 2048, 16, 128, 512),
-], ids=["gpt2-fused", "llama1b4-fused", "t4096-split", "t2048-d128-split"])
-def test_flash_attention_fwd_bwd(chip, B, T, H, D, block):
+def _flash_grad_hlo(chip, B, T, H, D, block):
     def loss(q, k, v):
         out = flash_attention(q, k, v, True, block, block)
         return jnp.sum(out.astype(jnp.float32))
 
     qkv = [_s(B, T, H, D)] * 3
-    hlo = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    return _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+
+
+@pytest.mark.parametrize("B,T,H,D,block", [
+    # GPT-2 124M at the smoke's batch: single tile -> FUSED backward
+    (16, 1024, 12, 64, 1024),
+    # the benchmark's train cell (gpt2-medium): the same, 16 heads
+    (16, 1024, 16, 64, 1024),
+    # llama1b4 LoRA bench shape: single tile, D=128
+    (8, 1024, 16, 128, 1024),
+    # long sequences: multi-block forward + SPLIT dq / dkv backward
+    (1, 4096, 12, 64, 1024),
+    (1, 2048, 16, 128, 512),
+], ids=["gpt2-fused", "gpt2m-cell-fused", "llama1b4-fused", "t4096-split",
+        "t2048-d128-split"])
+def test_flash_attention_fwd_bwd(chip, B, T, H, D, block):
+    hlo = _flash_grad_hlo(chip, B, T, H, D, block)
     # forward + fused backward, or forward + dq + dkv
     assert hlo.count("tpu_custom_call") >= (2 if block == T else 3)
+
+
+def test_flash_calls_are_what_the_benchmark_looks_for(chip):
+    """`gpt2m_train_stream` finds the two kernels in a device trace by
+    the custom calls' RESULT shapes (`benchmarks/planes/train.py::
+    kernel_predicates`, read here and not edited): a kernel change that
+    returned anything else would turn `flash_fwd_roofline` and
+    `flash_bwd_roofline` into null on the chip.  Fail here instead."""
+    import json
+    import pathlib
+
+    from benchmarks.planes.train import kernel_predicates
+
+    bench = pathlib.Path(__file__).resolve().parent.parent / "benchmarks"
+    cfg = json.loads((bench / "configs" / "gpt2-medium.json").read_text())
+    mix = json.loads((bench / "traffic" / "train_stream.json").read_text())
+    m = cfg["model"]
+    hlo = _flash_grad_hlo(chip, int(mix["batch"]), int(mix["seq"]),
+                          m["n_head"], m["n_embd"] // m["n_head"], 1024)
+    found = {label: [ln.split(" = ")[0].strip() for ln in hlo.splitlines()
+                     if pred(ln.strip())]
+             for label, pred in kernel_predicates(cfg, mix).items()}
+    assert len(found["flash_fwd"]) == 1 and "flash_fwd" in found["flash_fwd"][0]
+    assert len(found["flash_bwd"]) == 1 and "flash_bwd" in found["flash_bwd"][0]
 
 
 # ----------------------------------------------------------------------

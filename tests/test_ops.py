@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops import flash_attention
+from ray_tpu.ops import attention, flash_attention
 from ray_tpu.parallel.moe import MoEConfig, init_moe, moe_forward
 from ray_tpu.parallel.ring_attention import plain_attention
 
@@ -44,10 +44,11 @@ def test_flash_attention_grad_matches_plain():
         )
 
 
-@pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16)])
+@pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (16, 64), (64, 32)])
 def test_flash_attention_grad_rect_blocks(bq, bk):
     """Rectangular blocks exercise the causal block-skip predicates and
-    cross-block online-softmax carries in both backward kernels."""
+    cross-block online-softmax carries in both backward kernels; with
+    one block as long as the sequence the forward keeps no state."""
     q, k, v = _qkv(T=64)
 
     def f_flash(q, k, v):
@@ -181,3 +182,82 @@ def test_flash_attention_grad_fused_single_tile(causal):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3
         )
+
+
+# ----------------------------------------------------------------------
+# the in-tile walk: a grid step's tile is walked in sub-tiles, those
+# above the diagonal skipped, only those it crosses masked
+# ----------------------------------------------------------------------
+def test_causal_walk_counts_at_the_train_cell_shape():
+    """`gpt2m_train_stream`: T 1024 as ONE tile.  The counts are the
+    loop bounds the kernels run (`_row_walk` forward, `_col_walk`
+    backward; `causal_walk` asserts the two views agree)."""
+    walk = attention.causal_walk
+    assert walk(1024, 1024, 128, 128) == dict(visited=36, masked=8, total=64)
+    assert walk(1024, 1024, 256, 256) == dict(visited=10, masked=4, total=16)
+    assert walk(1024, 1024, 512, 512) == dict(visited=3, masked=2, total=4)
+    sub = attention._sub_tile(1024)  # what the kernels pick there
+    assert walk(1024, 1024, sub, sub) == dict(visited=10, masked=4, total=16)
+    # a grid tile wholly below the diagonal masks nothing, one wholly
+    # above it visits nothing, and rectangular sub-tiles count too
+    assert walk(1024, 1024, 256, 256, 1024, 0) == dict(
+        visited=16, masked=0, total=16)
+    assert walk(1024, 1024, 256, 256, 0, 1024)["visited"] == 0
+    assert walk(512, 512, 256, 128) == dict(visited=6, masked=4, total=8)
+    # the fused backward walks the same sub-tiles kv sub-block by kv
+    # sub-block (`_col_walk`): both views count alike
+    for bq, bk, sq, sk, q0 in [(1024, 1024, 256, 256, 0), (512, 512, 256, 128, 0),
+                               (512, 512, 128, 256, 0), (512, 512, 128, 128, 512)]:
+        cols = [attention._col_walk(j * sk - q0, sk, sq, bq // sq)
+                for j in range(bk // sk)]
+        got = walk(bq, bk, sq, sk, q0, 0)
+        assert got["visited"] == sum(bq // sq - vis for vis, _ in cols)
+        assert got["masked"] == sum(full - vis for vis, full in cols)
+    # a block the walk cannot cut is walked whole
+    assert (attention._sub_tile(96), attention._sub_tile(32)) == (96, 32)
+
+
+_WALK_CASES = {
+    # T, H, D, dtype, causal, block: one tile holding skipped, unmasked
+    # and diagonal sub-tiles at once, at both head sizes and dtypes
+    "t512-d64-f32": (512, 2, 64, jnp.float32, True, 512),
+    "t512-d128-bf16": (512, 1, 128, jnp.bfloat16, True, 512),
+    "t512-d64-bf16": (512, 2, 64, jnp.bfloat16, True, 512),
+    "t256-d128-f32": (256, 1, 128, jnp.float32, True, 256),
+    "t512-d64-f32-noncausal": (512, 1, 64, jnp.float32, False, 512),
+    # a multi-block grid (forward walk with a moving origin, split
+    # backward): tiles below, on and above the diagonal
+    "t1024-grid512-d64-f32": (1024, 1, 64, jnp.float32, True, 512),
+    "t768-grid256-d64-bf16": (768, 1, 64, jnp.bfloat16, True, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_flash_attention_walk_matches_plain(case):
+    """Output AND gradients against `plain_attention` where a tile is
+    walked in several sub-tiles."""
+    T, H, D, dtype, causal, block = _WALK_CASES[case]
+    assert block // attention._sub_tile(block) >= 2
+    q, k, v = _qkv(B=1, T=T, H=H, D=D, seed=3, dtype=dtype)
+    w = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) * w)
+
+    flash = loss(lambda q, k, v: flash_attention(
+        q, k, v, causal, block, block, True))
+    plain = loss(lambda q, k, v: plain_attention(q, k, v, causal=causal))
+    out = flash_attention(q, k, v, causal, block, block, True)
+    ref = plain_attention(q, k, v, causal=causal)
+    g1 = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(plain, argnums=(0, 1, 2))(q, k, v)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+    np.testing.assert_allclose(
+        np.asarray(out, dtype=np.float32), np.asarray(ref, dtype=np.float32),
+        rtol=tol, atol=tol)
+    gtol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(
+            np.asarray(a, dtype=np.float32), np.asarray(b, dtype=np.float32),
+            rtol=gtol, atol=gtol)
