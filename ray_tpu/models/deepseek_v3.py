@@ -125,6 +125,8 @@ def layer_shapes(cfg: DeepseekV3Config) -> Dict[str, Dict[str, tuple]]:
 # leaves kept in float32 whatever the compute dtype: the router scores
 # decide WHICH experts run, and a bfloat16 score flips near-ties
 F32_LEAVES = ("router", "router_bias")
+# the published router's normaliser: `topk_weights / (sum + 1e-20)`
+ROUTE_EPS = 1e-20
 
 
 def init_params(cfg: DeepseekV3Config, key: jax.Array, std: float = 0.02):
@@ -238,7 +240,8 @@ def _ffn(cfg, layer, x, *, kernel=False, interpret=False, stack_index=None,
                                layer["w_down"], cfg.dtype), None
     flat = h.reshape(-1, h.shape[-1])
     y, stats = dropless_moe(flat, layer, top_k=cfg.top_k,
-                            scale=cfg.routed_scale, dtype=cfg.dtype,
+                            scale=cfg.routed_scale, route_eps=ROUTE_EPS,
+                            dtype=cfg.dtype,
                             kernel=kernel, interpret=interpret,
                             stack_index=stack_index, row_mask=row_mask)
     with jax.named_scope("moe_shared"):

@@ -87,6 +87,24 @@ again (170 us at `W` 16, 250 at `W` 64, against 105 for bf16 on the
 same rows; PERF.md section 5): int8 KV buys pool capacity here, not
 time, until the scales lie in the pool in a form the kernel can copy.
 
+HEADS NARROWER THAN A LANE TILE (`head_dim` 64: `models/lfm2.py`) lie
+ALL to one pool row, side by side: the pool is `[L, num_blocks,
+block_size, KV * hd]` (`kv_pool_tail`), the same bytes in the same
+order as `[..., KV, hd]`, but with whole lanes as the minor dimension
+and a block's tokens as its rows, the layout the latent pool has.  A
+`[.., KV, 64]` pool the TPU would pad to 128 lanes in HBM (twice the
+bytes, and Mosaic refuses to cut a 64-wide page out of it), so the
+kernels never see one.  They run UNCHANGED on the folded pool as ONE kv
+head of width `KV * hd`: the wrapper lays each query into the lanes of
+its own kv head (zeros in the others', so the score is the narrow
+head's), passes the narrow head's scale, and takes each query head's
+own lanes of the result; a score tile then has a column a token, no
+head to mask, and a quarter of the exponentials of the per-head tile.
+The append takes `[B, KV, hd]` rows as the `[B, KV * hd]` they are,
+through the one-row form the latent pool uses, for both pools in one
+call.  The MXU multiplies `KV` times the columns, which decode does not
+notice.
+
 The kernels are COMPILED for the TPU unless the caller passes
 `interpret=True` (the CPU tests do); nothing here looks at the backend.
 `tests/test_aot_tpu_compile.py` lowers every variant for a described
@@ -130,12 +148,25 @@ def dequantize_int8(q: jax.Array, scale: jax.Array, dtype,
             * jnp.expand_dims(scale, axis)).astype(dtype)
 
 
+LANES = 128  # a TPU tile's minor dimension
+
+
+def kv_pool_tail(kv_heads: int, head_dim: int) -> Tuple[int, ...]:
+    """What one token caches in a K or V pool: `(KV, hd)`, or with
+    heads narrower than a lane tile all of them side by side in ONE
+    row of whole lanes, `(KV * hd,)` (see the module docstring).  Rows
+    that would not fill whole lanes stay as they are."""
+    if head_dim % LANES and (kv_heads * head_dim) % LANES == 0:
+        return (kv_heads * head_dim,)
+    return kv_heads, head_dim
+
+
 # ----------------------------------------------------------------------
 # append kernel: one KV row into each sequence's tail block, in place
 # ----------------------------------------------------------------------
 @functools.lru_cache(maxsize=32)
 def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
-                  quantized, interpret, pools=2):
+                  quantized, interpret, pools=2, flat=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -147,8 +178,8 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         # table's LAST block, copied through unchanged (scratch padding
         # for a slot without a table), instead of reading out of bounds
         w = jnp.minimum(pos_ref[b] // BS, W - 1)
-        # a page is [BS, KV, HD], or [BS, HD] in the one-pool latent form
-        return (layer_ref[0], tables_ref[b, w]) + (0,) * (1 + pools)
+        # a page is [BS, KV, HD], or [BS, HD] in the flat form
+        return (layer_ref[0], tables_ref[b, w]) + (0,) * (2 if flat else 3)
 
     def scale_map(b, layer_ref, tables_ref, pos_ref):
         w = jnp.minimum(pos_ref[b] // BS, W - 1)
@@ -214,24 +245,27 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         # prefetch args (megablox gmm convention)
         aliases = {3: 0, 4: 1, 5: 2, 6: 3}
     else:
-        # `pools`: 2 = the K and V pools `[L, NB, BS, KV, HD]`; 1 = one
-        # latent pool `[L, NB, BS, HD]` (MLA: nothing per head, so a
-        # page is a plain `[BS, HD]` tile and a new row `[1, HD]`)
-        page = (BS, KV, HD) if pools == 2 else (BS, HD)
-        row = (KV, HD) if pools == 2 else (1, HD)
+        # `pools`: 2 = a K and a V pool, 1 = one latent pool.  `flat`:
+        # the pools are `[L, NB, BS, HD]`, nothing per head (MLA's
+        # latent; K and V with every head folded into the row), so a
+        # page is a plain `[BS, HD]` tile and a new row `[1, HD]`;
+        # else `[L, NB, BS, KV, HD]`
+        page = (BS, HD) if flat else (BS, KV, HD)
+        row = (1, HD) if flat else (KV, HD)
         def kernel(layer_ref, tables_ref, pos_ref, *refs):
             ins, news, outs = (refs[:pools], refs[pools:2 * pools],
                                refs[2 * pools:])
             b = pl.program_id(0)
             p_b = pos_ref[b]
             off = p_b % BS
-            if pools == 1:
+            if flat:
                 # a `[BS, HD]` page packs two bf16 rows a sublane, and
                 # Mosaic stores a single row only at an offset it can
                 # prove aligned: select the row into the whole page
                 hit = (jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0)
                        == off) & (p_b < view)
-                outs[0][...] = jnp.where(hit, news[0][...], ins[0][...])
+                for src, new, out in zip(ins, news, outs):
+                    out[...] = jnp.where(hit, new[...], src[...])
                 return
             for src, out in zip(ins, outs):
                 out[...] = src[...]
@@ -274,14 +308,26 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
     """Write each row's new KV into its tail pool block, in place.
 
     k_pool/v_pool [L, NB, BS, KV, hd]; k_new/v_new [B, KV, hd] (pool
-    dtype); tables [B, W] int32; pos [B] int32 (the position being
+    dtype; for a folded pool `[L, NB, BS, KV * hd]`, `kv_pool_tail`,
+    the rows are taken as the `[B, KV * hd]` they are);
+    tables [B, W] int32; pos [B] int32 (the position being
     written); layer: scalar int32 (traced OK).  With the int8 sidecar
     (`k_scale`/`v_scale` [L, NB, BS, KV] f32 + per-row `k_new_scale`/
     `v_new_scale` [B, KV]) returns (k_pool, v_pool, k_scale, v_scale),
     else (k_pool, v_pool)."""
-    L, NB, BS, KV, HD = k_pool.shape
     B, W = tables.shape
     quantized = k_scale is not None
+    if k_pool.ndim == 4:  # folded rows: the flat form, both pools
+        L, NB, BS, HD = k_pool.shape
+        assert not quantized, "a folded pool has no int8 scales wired"
+        fn = _build_append(L, NB, BS, 1, HD, B, W,
+                           jnp.dtype(k_pool.dtype).name,
+                           jnp.dtype(k_new.dtype).name, False,
+                           bool(interpret), flat=True)
+        return tuple(fn(jnp.asarray(layer, jnp.int32).reshape(1), tables,
+                        pos, k_pool, v_pool, k_new.reshape(B, 1, HD),
+                        v_new.reshape(B, 1, HD)))
+    L, NB, BS, KV, HD = k_pool.shape
     fn = _build_append(L, NB, BS, KV, HD, B, W,
                        jnp.dtype(k_pool.dtype).name,
                        jnp.dtype(k_new.dtype).name, quantized,
@@ -317,7 +363,7 @@ def dead_row_positions(pos, live, tables, block_size: int):
 # 576-wide bf16 array out 640 wide in HBM whatever its logical shape, so
 # the padding costs the device no byte a 576-wide pool would not cost;
 # the roofline counts the 576 that carry values.
-MLA_LANES = 128
+MLA_LANES = LANES
 
 
 def mla_pool_width(d: int) -> int:
@@ -336,7 +382,7 @@ def mla_paged_kv_append(pool, new, tables, pos, layer, *,
     new = jnp.pad(new, ((0, 0), (0, D - new.shape[-1])))
     fn = _build_append(L, NB, BS, 1, D, B, W, jnp.dtype(pool.dtype).name,
                        jnp.dtype(new.dtype).name, False, bool(interpret),
-                       pools=1)
+                       pools=1, flat=True)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     return fn(layer, tables, pos, pool, new.reshape(B, 1, D))[0]
 
@@ -565,11 +611,33 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
     with nothing to read, whose result is zeros (a dead row:
     `dead_row_positions`).  `layer` scalar int32 selects
     the pool layer.  GQA: query head h attends through kv head
-    h // (H // KV).  Returns o [B, H, hd] in q's dtype."""
-    L, NB, BS, KV, HD = k_pool.shape
+    h // (H // KV).  Returns o [B, H, hd] in q's dtype.
+
+    A FOLDED pool (`kv_pool_tail`: `[L, NB, BS, KV * hd]`, q still
+    `[B, H, hd]`): each query goes into the lanes of its own kv head,
+    the kernel runs on one head of width `KV * hd` at the narrow
+    head's scale, and each query head's own lanes come back."""
     B, W = tables.shape
-    H = q.shape[1]
+    H, hd = q.shape[1:]
     quantized = k_scale is not None
+    if k_pool.ndim == 4:
+        L, NB, BS, HD = k_pool.shape
+        assert not quantized, "a folded pool has no int8 scales wired"
+        KV = HD // hd
+        # query head h reads kv head h // group: lanes [g * hd, (g + 1) * hd)
+        head = jnp.arange(H) // (H // KV)
+        own = head[:, None] == jnp.arange(HD)[None, :] // hd     # [H, HD]
+        q = jnp.where(own[None], jnp.tile(q, (1, 1, KV)),
+                      jnp.zeros((), q.dtype))
+        fn = _build_attention(L, NB, BS, 1, HD, B, W, H,
+                              jnp.dtype(k_pool.dtype).name,
+                              jnp.dtype(q.dtype).name, False,
+                              bool(interpret), scale=hd ** -0.5)
+        o = fn(jnp.asarray(layer, jnp.int32).reshape(1), tables, pos, q,
+               k_pool, v_pool).reshape(B, H, KV, hd)
+        return jnp.take_along_axis(
+            o, head[None, :, None, None], axis=2)[:, :, 0]
+    L, NB, BS, KV, HD = k_pool.shape
     fn = _build_attention(L, NB, BS, KV, HD, B, W, H,
                           jnp.dtype(k_pool.dtype).name,
                           jnp.dtype(q.dtype).name, quantized,

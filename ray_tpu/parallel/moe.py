@@ -192,19 +192,21 @@ def _moe_forward_ep(cfg: MoEConfig, params: Dict, x: jax.Array, mesh: Mesh):
 # ----------------------------------------------------------------------
 # the expert layer that serves: dropless, one chip
 # ----------------------------------------------------------------------
-def sigmoid_topk_route(h, w_router, bias, top_k: int, scale: float):
+def sigmoid_topk_route(h, w_router, bias, top_k: int, scale: float,
+                       eps: float):
     """DeepSeek-V3's `noaux_tc` router with one group: scores
     `sigmoid(h W_g)` in float32 (matmul precision `highest`: a TPU's
     default would round the operands to bfloat16), the top `top_k` of
     `scores + bias` chosen, the weights taken from the scores WITHOUT
-    the bias, normalised and scaled.  h [N, D] -> (weights [N, k] f32,
-    experts [N, k] int32)."""
+    the bias, normalised (`sum + eps`: the caller's model states it,
+    1e-20 for `deepseek_v3`, 1e-6 for `lfm2_moe`) and scaled.  h [N, D]
+    -> (weights [N, k] f32, experts [N, k] int32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), w_router.astype(jnp.float32),
         precision="highest"))
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scale
     return w, idx.astype(jnp.int32)
 
 
@@ -240,7 +242,11 @@ def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
     M, K = xs.shape
     N = w.shape[-1]
     # whole K and N in one tile where they fit: 0.57 ms a product at
-    # (128, 2048, 768) against 0.65-0.67 at tk 1024 / 512 (PERF.md, PR 27)
+    # (128, 2048, 768) against 0.65-0.67 at tk 1024 / 512 (PERF.md, PR 27).
+    # 128 rows a tile also where a prefill brings thousands of rows: the
+    # kernel reads a group's matrix once for every row tile its rows
+    # reach into, but computes a WHOLE tile a visit, so 256- and 512-row
+    # tiles are slower at every shape tried (PERF.md section 6, PR 34)
     tm = 128
     pad = -M % tm
     if pad:  # the kernel walks whole row tiles; the tail belongs to no group
@@ -251,7 +257,8 @@ def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
     return out[:M] if pad else out
 
 
-def dropless_moe(h, layer: Dict, *, top_k: int, scale: float, dtype,
+def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
+                 route_eps: float, dtype,
                  kernel: bool = False, interpret: bool = False,
                  stack_index=None, row_mask=None):
     """Routed experts for inference, nothing dropped: h [N, D] ->
@@ -279,7 +286,7 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float, dtype,
     E = layer["router"].shape[-1]
     with jax.named_scope("moe_router"):
         w, idx = sigmoid_topk_route(h, layer["router"], layer["router_bias"],
-                                    top_k, scale)
+                                    top_k, scale, route_eps)
         if row_mask is not None:  # expert E: behind every group, in none
             idx = jnp.where(row_mask[:, None], idx, E)
         flat = idx.reshape(-1)                      # [N * k], pair -> expert

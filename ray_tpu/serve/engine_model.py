@@ -5,12 +5,16 @@ admission, shedding, block tables, the radix prefix cache, the tick and
 its rings.  What it computes WITH is behind this seam.  An engine model
 tells the engine
 
-- its CACHE SPEC (`cache_leaves`), of one of two kinds.  PAGED: the pool
-  leaves one cached token needs, each as the shape after `[layers,
-  num_blocks, block_size]` and a dtype, and the bytes one token costs
-  (`cache_bytes_per_token`).  PER SLOT (`per_slot`): the leaves one
-  SEQUENCE holds, each as the shape after `[layers, slots]`, and the
-  bytes one slot costs (`cache_bytes_per_slot`; a token costs 0);
+- its CACHE SPEC (`cache_leaves`): leaves of two kinds, and a model may
+  hold EITHER OR BOTH.  PAGED (`kv`, a `PagedKV`): the pool leaves one
+  cached token needs, each as the shape after `[layers, num_blocks,
+  block_size]` and a dtype, and the bytes one token costs
+  (`cache_bytes_per_token`).  PER SLOT (`state`, a `SlotState`): the
+  leaves one SEQUENCE holds, each as the shape after `[layers, slots]`,
+  and the bytes one slot costs (`cache_bytes_per_slot`).  The paged
+  leaves come first.  A leaf says how many layers hold it where that
+  is not all of them (`CacheLeaf.layers`): a model whose layers are of
+  two kinds has paged K and V in some and a state in the others;
 - five program bodies, each keyed by the static shape the engine buckets
   to, all with FLAT signatures so that the engine can jit, name, donate
   and cache them without knowing what the leaves mean:
@@ -28,8 +32,9 @@ tells the engine
     nothing it can avoid: it attends nothing, it WRITES NOTHING into
     the cache (it may still hold a real table whose blocks the prefix
     cache shares: `ops/paged_attention.dead_row_positions`, the dense
-    routes' masked select), the latent model routes it to no expert,
-    and the token it yields is nobody's;
+    routes' masked select; a per-slot state stays as it was), the
+    expert models route it to no expert, and the token it yields is
+    nobody's.  A cache with no paged leaf has no `tables`;
   - `prefill_packed(N)`: `(params, *cache, tokens, seg, posn,
     blk_ids, last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos,
     tok, stop)`: ADMISSION, a tick's cache-miss prompts in one program
@@ -38,13 +43,16 @@ tells the engine
     [N]; `seg` [N] which prompt a token belongs to, -1 for padding;
     `posn` [N] its position inside its own prompt; `blk_ids` [N /
     block_size] the pool block each block of the row is cached in
-    (padding: the scratch block).  A token attends inside its own
+    (padding: the scratch block; a cache with no paged leaf has no
+    `blk_ids`).  A token attends inside its own
     prompt only (`same segment AND causal`: one prompt alone is the
     causal mask, so there is no second form), the head runs on the `K`
     rows `last` names (each prompt's last token), the greedy pick is
     taken INSIDE the program, and per prompt `slots`, `pos0` (its
     length) and `stop0` set the admitted rows of the device state; an
-    unused entry's slot is out of range and dropped.  Nothing comes
+    unused entry's slot is out of range and dropped.  The model's
+    forward is handed the per-slot leaves and `slots`, and leaves each
+    prompt's end state in its slot itself.  Nothing comes
     back to the host.  `K` is 1 where `cfg.attention` is not dense (the
     mask needs the dense form): `seg` and `posn` are then ignored;
   - `prefill(bucket)`: `(params, prompt [1, bucket]) -> (logits
@@ -61,28 +69,28 @@ tells the engine
     so the host never has to tell the device that a row ended).
     Without `stop0, stop` it returns `(*cache, pos, tok)`.
 
-`PagedKV` states the paged cache's FORMAT once: its leaves, blocks ->
+`PagedKV` states the paged kind's FORMAT once: its leaves, blocks ->
 rows of the compute dtype, rows -> blocks (int8 pools with their scale
 sidecar are a value of it, not a family of bodies).  `SlotState` states
-the second cache KIND beside it: leaves `[layers, slots, *tail]`, one
+the second KIND beside it: leaves `[layers, slots, *tail]`, one
 state a sequence whatever its length, with no tables, no `blk_ids` and
-no gather width.  The flat signatures keep their order with `tables` /
-`blk_ids` dropped: `decode_chunk` is `(params, *cache, tok, pos, stop)`
-and `prefill_packed(N)` `(params, *cache, tokens, seg, posn, last,
-slots, pos0, stop0, pos, tok, stop)`, whose forward leaves each
-prompt's end state in its slot itself; `suffix_prefill` and `kv_write`
-do not exist for it (no cached prefix to prefill behind).
-`chunk_program` is the decode chunk of BOTH kinds: liveness, the greedy
-pick, the positions, row 0 and the aux rows, around ONE decode step the
-model hands it.  `kv_write_program` is `kv_write`'s flat signature and
-the admitted slot's state; `packed_prefill_program` and
-`slot_prefill_program` are `prefill_packed`'s, for either kind.
+no gather width.  A model with per-slot leaves has no cached prefix to
+prefill behind: `suffix_prefill` and `kv_write` do not exist for it and
+the radix prefix cache is refused.
+`chunk_program` is THE decode chunk, of every cache: liveness, the
+greedy pick, the positions, row 0 and the aux rows, around ONE decode
+step the model hands it.  `packed_prefill_program` is THE packed
+prefill likewise: the flat signature, the greedy pick, the paged rows
+into their blocks, the admitted rows' state.  `kv_write_program` is
+`kv_write`'s flat signature and the admitted slot's state.
 
-Three implementers: `LlamaEngineModel` (per-head K and V pools),
+Four implementers: `LlamaEngineModel` (per-head K and V pools),
 `LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
-absorbed decode attention, dropless experts) and `RetentionEngineModel`
+absorbed decode attention, dropless experts), `RetentionEngineModel`
 (`models/brumby.py`: every layer a power-retention layer, a per-slot
-state).  `engine_model_for` picks by the config's type, builds the
+state) and `HybridEngineModel` (`models/lfm2.py`: paged K and V in the
+attention layers, a per-slot convolution state in the others, both in
+one spec).  `engine_model_for` picks by the config's type, builds the
 format from the user's `kv_dtype` and hands the implementer the
 resolved route: a user passes a model's config and the model picks its
 route.
@@ -96,7 +104,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.exceptions import PrefixCacheUnsupportedError
-from ray_tpu.models import brumby, deepseek_v3, llama
+from ray_tpu.models import brumby, deepseek_v3, lfm2, llama
 from ray_tpu.ops import paged_attention as _pa
 from ray_tpu.ops import retention as _ret
 from ray_tpu.serve.kv_cache import CacheLeaf
@@ -114,12 +122,12 @@ class PagedKV:
     bytes of bf16) and, after the payloads, a float32 scale sidecar per
     leaf with one scale per (layer, row, kv head): the tail without its
     last axis (`ops/paged_attention.quantize_int8`; the paged kernels
-    take the leaves as they lie and fuse the dequant).  `used`: see
-    `CacheLeaf`."""
+    take the leaves as they lie and fuse the dequant).  `used`,
+    `layers`: see `CacheLeaf`."""
 
     def __init__(self, rows: Dict[str, Tuple[int, ...]], dtype,
                  block_size: int, kv_dtype: str = "model",
-                 used: Optional[int] = None):
+                 used: Optional[int] = None, layers: Optional[int] = None):
         if kv_dtype not in KV_DTYPES:
             raise ValueError(f"kv_dtype={kv_dtype!r} not in {KV_DTYPES}")
         self.kv_dtype, self.dtype = kv_dtype, dtype
@@ -127,11 +135,13 @@ class PagedKV:
         self._int8 = kv_dtype == "int8"
         self.leaves: List[CacheLeaf] = [
             CacheLeaf(name, tail, jnp.int8 if self._int8 else dtype,
-                      used=used) for name, tail in rows.items()]
+                      used=used, layers=layers)
+            for name, tail in rows.items()]
         if self._int8:
             self.leaves += [
                 CacheLeaf(f"{name}_scale", tail[:-1], jnp.float32,
-                          sidecar=True) for name, tail in rows.items()]
+                          sidecar=True, layers=layers)
+                for name, tail in rows.items()]
 
     def rows(self, cache: Sequence, blk) -> tuple:
         """Blocks -> rows: the blocks `blk` names (`[n]`, one sequence's,
@@ -191,19 +201,21 @@ class SlotState:
     size in its slot, not rows that grow.  A cache is a tuple of leaves
     `[layers, slots, *tail]`, one per entry of `leaves` (name -> (the
     tail one sequence holds, dtype)): slot b's state is row b.  There
-    are no blocks, no tables and no gather width; admission is bounded
-    by slots alone (`cache_bytes_per_token` 0, `cache_bytes_per_slot`
-    the leaves' bytes); a prefill's end state enters a slot inside the
-    packed prefill program, which is handed the slots; the decode chunk
-    takes no tables (`chunk_program(paged=False)`).  A trie of blocks
-    cannot share a state, so the radix prefix cache is refused
-    (`PrefixCacheUnsupportedError`)."""
+    are no blocks, no tables and no gather width for these leaves; a
+    cache of them alone bounds admission by slots alone
+    (`cache_bytes_per_token` 0, `cache_bytes_per_slot` the leaves'
+    bytes) and its decode chunk takes no tables
+    (`chunk_program(paged=False)`); beside paged leaves
+    (`HybridEngineModel`) a request needs a slot AND its blocks.  A
+    prefill's end state enters a slot inside the packed prefill
+    program, which is handed the slots.  A trie of blocks cannot share
+    a state, so the radix prefix cache is refused
+    (`PrefixCacheUnsupportedError`).  `layers`: see `CacheLeaf`."""
 
-    kv_dtype = "model"
-
-    def __init__(self, leaves: Dict[str, Tuple[Tuple[int, ...], object]]):
+    def __init__(self, leaves: Dict[str, Tuple[Tuple[int, ...], object]],
+                 layers: Optional[int] = None):
         self.leaves: List[CacheLeaf] = [
-            CacheLeaf(name, tail, dtype, per_slot=True)
+            CacheLeaf(name, tail, dtype, per_slot=True, layers=layers)
             for name, (tail, dtype) in leaves.items()]
 
 
@@ -211,17 +223,19 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
                   aux=None, paged: bool = True):
     """THE decode chunk, `(params, *cache, tables, tok, pos, stop) ->
     (*cache, tok, pos, toks)`: `chunk` greedy steps in one `lax.scan`.
-    `paged` False (a per-slot cache, `SlotState`): the same without
+    `paged` False (a cache with no paged leaf): the same without
     `tables`, in the signature and handed to `step` as None.
 
     `step(params, tok, cache, tables, pos, live) -> (logits, cache,
     stats)` is the model's decode step at per-row positions; `cache` is
     a tuple of arrays in and out, `stats` a tuple of per-step scalars.
     `gather` None: the step runs on the pool in place through the
-    tables (the paged kernels).  `gather` the cache's format: the step
-    runs on the dense view `[L, slots, W * block_size, ...]` of every
-    slot's blocks, and the view goes back into the pool after the scan
-    (`pos` before and after it say which rows the chunk wrote).
+    tables (the paged kernels).  `gather` the paged leaves' format: the
+    step runs on the dense view `[L, slots, W * block_size, ...]` of
+    every slot's blocks, and the view goes back into the pool after the
+    scan (`pos` before and after it say which rows the chunk wrote);
+    per-slot leaves, which lie behind the paged ones, go to the step as
+    they are either way.
     `aux(stats)`: the model's `aux_rows` counters, `[aux_rows]`, from
     the steps' stacked stats."""
     def _fn(params, *flat):
@@ -229,7 +243,10 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
             *pool, tables, tok, pos, stop = flat
         else:
             (*pool, tok, pos, stop), tables = flat, None
-        cache = tuple(pool) if gather is None else gather.rows(pool, tables)
+        cache = tuple(pool)
+        if gather is not None:
+            n = len(gather.leaves)
+            cache = (*gather.rows(pool[:n], tables), *pool[n:])
 
         def body(carry, _):
             tok, cache, pos = carry
@@ -247,7 +264,9 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
         (tok, cache, pos), (toks, stats) = jax.lax.scan(
             body, (tok, cache, pos), None, length=chunk)
         if gather is not None:
-            cache = gather.write(pool, tables, cache, span=(pos_in, pos))
+            cache = (*gather.write(pool[:n], tables, cache[:gather.n_rows],
+                                   span=(pos_in, pos)),
+                     *cache[gather.n_rows:])
         counters = None if aux is None else aux(stats)
         rows = [tok_in[None], toks]
         if counters is not None:
@@ -287,69 +306,66 @@ def _admitted(pos, tok, stop, slots, pos0, tok0, stop0) -> tuple:
             stop.at[slots].set(stop0, mode="drop"))
 
 
-def packed_prefill_program(kv: PagedKV, forward, fit, segmented: bool):
-    """`prefill_packed`, `(params, *cache, tokens, seg, posn, blk_ids,
+def packed_prefill_program(kv: Optional[PagedKV], n_state: int, forward,
+                           fit=lambda *rows: rows, segmented: bool = True):
+    """THE packed prefill, `(params, *cache, tokens, seg, posn, blk_ids,
     last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos, tok,
-    stop)`.  `forward(params, tokens [1, N], packed) -> (logits [1, K,
-    vocab], *rows)` is the model's prefill forward under a
-    `llama.Packed`; `fit(*rows)` the rows `[L, 1, N, ...]` as the pool
-    caches them, which then reshape straight into `blk_ids`' blocks."""
-    n = len(kv.leaves)
+    stop)`, of a cache of `kv`'s paged leaves (None: none, and no
+    `blk_ids` in the signature) and `n_state` per-slot leaves behind
+    them.  `forward(params, state, tokens [1, N], packed, slots) ->
+    (logits [1, K, vocab], rows, state)` is the model's prefill forward
+    under a `llama.Packed`: `rows` what the paged leaves cache, which
+    `fit(*rows)` turns into `[L, 1, N, ...]` as the pool holds them
+    (where they are not that already) and which then reshape straight
+    into `blk_ids`' blocks; `state` the per-slot leaves, each prompt's
+    end state left in its slot by the forward itself."""
+    n_paged = len(kv.leaves) if kv is not None else 0
 
     def _fn(params, *flat):
-        cache = flat[:n]
-        (tokens, seg, posn, blk_ids, last, slots, pos0, stop0,
-         pos, tok, stop) = flat[n:]
+        paged = flat[:n_paged]
+        state = flat[n_paged:n_paged + n_state]
+        (tokens, seg, posn, *blk_ids, last, slots, pos0, stop0,
+         pos, tok, stop) = flat[n_paged + n_state:]
         packed = (llama.Packed(last, seg, posn) if segmented
                   else llama.Packed(last))
-        logits, *rows = forward(params, tokens[None], packed)
+        logits, rows, state = forward(params, tuple(state), tokens[None],
+                                      packed, slots)
         tok0 = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-        cache = kv.write(cache, blk_ids, fit(*rows))
-        return (*cache, *_admitted(pos, tok, stop, slots, pos0, tok0, stop0))
-
-    return _fn
-
-
-def slot_prefill_program(n_leaves: int, forward):
-    """`prefill_packed` over a per-slot cache (`SlotState`): the paged
-    signature without `blk_ids`, `(params, *cache, tokens, seg, posn,
-    last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos, tok,
-    stop)`.  `forward(params, cache, tokens [1, N], packed, slots) ->
-    (logits [1, K, vocab], cache)` is the model's prefill forward under
-    a `llama.Packed`, which leaves each prompt's end state in its slot
-    of the cache itself."""
-    def _fn(params, *flat):
-        cache = flat[:n_leaves]
-        (tokens, seg, posn, last, slots, pos0, stop0,
-         pos, tok, stop) = flat[n_leaves:]
-        logits, cache = forward(params, cache, tokens[None],
-                                llama.Packed(last, seg, posn), slots)
-        tok0 = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-        return (*cache, *_admitted(pos, tok, stop, slots, pos0, tok0, stop0))
+        if kv is not None:
+            paged = kv.write(paged, blk_ids[0], fit(*rows))
+        return (*paged, *state,
+                *_admitted(pos, tok, stop, slots, pos0, tok0, stop0))
 
     return _fn
 
 
 class _EngineModel:
-    """What the engine reads off either implementer besides its five
-    bodies: `cache_leaves`, `n_layers`, `kv.kv_dtype`, `segmented`
-    (a packed prefill may hold several prompts), `aux_rows` and
-    `tick_fields` (the model's own per-tick counters; none here)."""
+    """What the engine reads off any implementer besides its five
+    bodies: `cache_leaves` (the paged leaves of `kv`, then the per-slot
+    leaves of `state`; either may be None), `n_layers`, `kv_dtype`,
+    `segmented` (a packed prefill may hold several prompts), `aux_rows`
+    and `tick_fields` (the model's own per-tick counters; none here)."""
 
     aux_rows = 0
-    per_slot = False  # the cache kind: `PagedKV` (False) or `SlotState`
 
-    def __init__(self, cfg, kv, *, chunk: int, paged: bool,
-                 interpret: bool):
-        self.cfg, self.kv, self.chunk = cfg, kv, chunk
+    def __init__(self, cfg, kv: Optional[PagedKV], *, chunk: int,
+                 paged: bool, interpret: bool,
+                 state: Optional[SlotState] = None):
+        self.cfg, self.kv, self.state, self.chunk = cfg, kv, state, chunk
         self._paged, self._interpret = paged, interpret
         self.n_layers = cfg.n_layers
-        self.cache_leaves = kv.leaves
+        self.cache_leaves = ((kv.leaves if kv is not None else [])
+                             + (state.leaves if state is not None else []))
+        self.kv_dtype = kv.kv_dtype if kv is not None else "model"
         # the segment mask needs the dense attention form
         self.segmented = getattr(cfg, "attention", "dense") == "dense"
 
     def tick_fields(self, aux) -> Dict[str, object]:
         return {}
+
+    def _no_prefix(self, *_):
+        raise PrefixCacheUnsupportedError(
+            "a per-slot state has no cached prefix to prefill behind")
 
 
 class LlamaEngineModel(_EngineModel):
@@ -373,16 +389,16 @@ class LlamaEngineModel(_EngineModel):
                              gather=None if paged else self.kv)
 
     def prefill_packed(self, N: int):
-        def forward(params, tokens, packed):
+        def forward(params, _state, tokens, packed, _slots):
             # garbage KV rows written for pad positions stay masked
             # (a row's pos starts at its prompt's length) and are
             # overwritten as decoding advances through them
-            logits, (ks, vs) = llama.forward(
+            logits, kv = llama.forward(
                 self.cfg, params, tokens, return_kv=True, packed=packed)
-            return logits, ks, vs  # ks/vs [L, 1, N, KV, hd]
+            return logits, kv, ()  # ks/vs [L, 1, N, KV, hd]
 
-        return packed_prefill_program(
-            self.kv, forward, lambda k, v: (k, v), self.segmented)
+        return packed_prefill_program(self.kv, 0, forward,
+                                      segmented=self.segmented)
 
     def prefill(self, bucket: int):
         def _pf(params, prompt):  # prompt [1, bucket], right-padded
@@ -419,7 +435,35 @@ class LlamaEngineModel(_EngineModel):
         return kv_write_program(self.kv, fit)
 
 
-class LatentMoeEngineModel(_EngineModel):
+class _ExpertCounters:
+    """What the two expert models share: the route's keywords, and the
+    two counters of the expert layers the decode program hands back
+    with its tokens (`aux_rows`; `self._pairs`: expert layers x routed
+    experts)."""
+
+    aux_rows = 2  # [experts_touched summed over the chunk | load_max]
+
+    @staticmethod
+    def _aux(stats):
+        return jnp.stack([jnp.sum(stats[0]), jnp.max(stats[1])])
+
+    def tick_fields(self, aux) -> Dict[str, object]:
+        """`aux` [2, slots] from a harvested chunk: the decode steps'
+        distinct (layer, expert) pairs, a step's mean over the chunk
+        (of `self._pairs`), and the most rows any one expert got in any
+        step and layer."""
+        return {"experts_touched": float(aux[0, 0]) / self.chunk,
+                "experts_total": self._pairs,
+                "expert_load_max": int(aux[1, 0])}
+
+    def _kw(self):
+        # interpret mode walks the grouped product tile by tile in
+        # Python: the CPU kernel tests take `lax.ragged_dot` instead
+        return dict(kernel=self._paged and not self._interpret,
+                    interpret=self._interpret)
+
+
+class LatentMoeEngineModel(_ExpertCounters, _EngineModel):
     """`models/deepseek_v3.py` behind the seam: ONE latent pool
     `[L, num_blocks, block_size, Dp]` — a token and layer cache the
     normalised compressed KV beside the rotated shared key, 576 values
@@ -432,27 +476,10 @@ class LatentMoeEngineModel(_EngineModel):
     megablox grouped products (TPU); else the dense view +
     `lax.ragged_dot` (anywhere)."""
 
-    aux_rows = 2  # [experts_touched summed over the chunk | load_max]
-
     def __init__(self, cfg, kv: PagedKV, **route):
         super().__init__(cfg, kv, **route)
         self.width = kv.leaves[0].tail[0]
         self._pairs = cfg.n_moe_layers * cfg.n_routed_experts
-
-    def tick_fields(self, aux) -> Dict[str, object]:
-        """`aux` [2, slots] from a harvested chunk: the decode steps'
-        distinct (layer, expert) pairs, a step's mean over the chunk
-        (of `n_moe_layers * n_routed_experts`), and the most rows any
-        one expert got in any step and layer."""
-        return {"experts_touched": float(aux[0, 0]) / self.chunk,
-                "experts_total": self._pairs,
-                "expert_load_max": int(aux[1, 0])}
-
-    def _kw(self):
-        # interpret mode walks the grouped product tile by tile in
-        # Python: the CPU kernel tests take `lax.ragged_dot` instead
-        return dict(kernel=self._paged and not self._interpret,
-                    interpret=self._interpret)
 
     def decode_chunk(self, W: int):
         cfg, paged, kw = self.cfg, self._paged, self._kw()
@@ -463,21 +490,22 @@ class LatentMoeEngineModel(_EngineModel):
                 tables=tables if paged else None, live=live, **kw)
             return logits, (pool,), (st["experts_touched"], st["load_max"])
 
-        return chunk_program(
-            step, self.chunk, gather=None if paged else self.kv,
-            aux=lambda st: jnp.stack([jnp.sum(st[0]), jnp.max(st[1])]))
+        return chunk_program(step, self.chunk,
+                             gather=None if paged else self.kv, aux=self._aux)
 
     def prefill_packed(self, N: int):
-        def forward(params, tokens, packed):
-            return deepseek_v3.forward(
+        def forward(params, _state, tokens, packed, _slots):
+            logits, lat = deepseek_v3.forward(
                 self.cfg, params, tokens, return_kv=True, packed=packed,
-                **self._kw())  # (logits, lat [L, 1, N, 576])
+                **self._kw())  # lat [L, 1, N, 576]
+            return logits, (lat,), ()
 
         def fit(lat):  # -> the pool's Dp columns
             return (jnp.pad(lat, ((0, 0),) * 3
                             + ((0, self.width - lat.shape[-1]),)),)
 
-        return packed_prefill_program(self.kv, forward, fit, self.segmented)
+        return packed_prefill_program(self.kv, 0, forward, fit,
+                                      segmented=self.segmented)
 
     def prefill(self, bucket: int):
         def _pf(params, prompt):  # prompt [1, bucket], right-padded
@@ -520,10 +548,8 @@ class RetentionEngineModel(_EngineModel):
     exist: there is no cached prefix to prefill behind.  `paged`: the
     Pallas kernels (TPU); else the same algorithms in plain XLA."""
 
-    per_slot = True
-
-    def __init__(self, cfg, kv: SlotState, *, pack_align: int, **route):
-        super().__init__(cfg, kv, **route)
+    def __init__(self, cfg, state: SlotState, *, pack_align: int, **route):
+        super().__init__(cfg, None, state=state, **route)
         self.pack_align = pack_align
 
     def _kw(self):
@@ -541,12 +567,13 @@ class RetentionEngineModel(_EngineModel):
         return chunk_program(step, self.chunk, paged=False)
 
     def prefill_packed(self, N: int):
-        def forward(params, cache, tokens, packed, slots):
-            return brumby.forward(self.cfg, params, tokens, cache,
-                                  packed=packed, slots=slots,
-                                  chunk=self.pack_align, **self._kw())
+        def forward(params, state, tokens, packed, slots):
+            logits, state = brumby.forward(
+                self.cfg, params, tokens, state, packed=packed, slots=slots,
+                chunk=self.pack_align, **self._kw())
+            return logits, (), state
 
-        return slot_prefill_program(len(self.kv.leaves), forward)
+        return packed_prefill_program(None, len(self.state.leaves), forward)
 
     def prefill(self, bucket: int):
         def _pf(params, prompt):  # prompt [1, bucket], right-padded
@@ -556,11 +583,63 @@ class RetentionEngineModel(_EngineModel):
 
         return _pf
 
-    def _no_prefix(self, *_):
-        raise PrefixCacheUnsupportedError(
-            "a per-slot state has no cached prefix to prefill behind")
+    suffix_prefill = kv_write = _EngineModel._no_prefix
 
-    suffix_prefill = kv_write = _no_prefix
+
+class HybridEngineModel(_ExpertCounters, _EngineModel):
+    """`models/lfm2.py` behind the seam: a cache of BOTH kinds.  Paged
+    `k` and `v` `[attn_layers, num_blocks, block_size, KV, hd]` for the
+    few attention layers (`kv`, a `PagedKV` whose leaves count those
+    layers; a token's heads of 64 lie side by side in one row of whole
+    lanes, `[.., KV * hd]`: `ops/paged_attention.kv_pool_tail`), and
+    behind them a per-slot `conv` `[conv_layers, slots,
+    conv_L * D]` for the convolution layers (`state`), in the compute
+    dtype.  A request needs a slot AND its blocks; the packed prefill
+    is handed `blk_ids` for the one kind and `slots` for the other (the
+    forward leaves each prompt's convolution state in its slot); the
+    decode chunk appends to and reads the pool through the tables and
+    rolls each live row's state.  The decode program hands back the
+    expert layers' two counters, as the latent model's (`aux_rows`).
+    `suffix_prefill` and `kv_write` do not exist: sharing a prefix would
+    need the convolution state at the block boundary.  `paged`: the
+    paged kernels + megablox grouped products (TPU); else the dense
+    view + `lax.ragged_dot` (anywhere)."""
+
+    def __init__(self, cfg, kv: PagedKV, state: SlotState, **route):
+        super().__init__(cfg, kv, state=state, **route)
+        self._pairs = cfg.n_moe_layers * cfg.n_experts
+
+    def decode_chunk(self, W: int):
+        cfg, paged, kw = self.cfg, self._paged, self._kw()
+
+        def step(params, tok, cache, tables, pos, live):
+            logits, cache, st = lfm2.decode_step(
+                cfg, params, tok, cache, pos,
+                tables=tables if paged else None, live=live, **kw)
+            return logits, cache, (st["experts_touched"], st["load_max"])
+
+        return chunk_program(step, self.chunk,
+                             gather=None if paged else self.kv, aux=self._aux)
+
+    def prefill_packed(self, N: int):
+        def forward(params, state, tokens, packed, slots):
+            logits, kv, conv = lfm2.forward(
+                self.cfg, params, tokens, state[0], packed=packed,
+                slots=slots, **self._kw())
+            return logits, kv, (conv,)  # ks/vs [attn_layers, 1, N, KV, hd]
+
+        return packed_prefill_program(self.kv, 1, forward,
+                                      segmented=self.segmented)
+
+    def prefill(self, bucket: int):
+        def _pf(params, prompt):  # prompt [1, bucket], right-padded
+            logits, (ks, vs), _ = lfm2.forward(self.cfg, params, prompt,
+                                               **self._kw())
+            return logits[0], ks, vs
+
+        return _pf
+
+    suffix_prefill = kv_write = _EngineModel._no_prefix
 
 
 def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
@@ -576,6 +655,17 @@ def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
             {"state": (state[2:], jnp.float32),
              "keysum": (keysum[2:], jnp.float32)}),
             pack_align=block_size, **route)
+    if isinstance(cfg, lfm2.Lfm2MoeConfig):
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' is not wired for the hybrid cache: its "
+                "K and V are 4 of 16 layers' and 8 KB a token already")
+        tail = _pa.kv_pool_tail(cfg.n_kv_heads, cfg.head_dim)
+        return HybridEngineModel(
+            cfg, PagedKV({"k": tail, "v": tail}, cfg.dtype, block_size,
+                         kv_dtype, layers=cfg.n_attn_layers),
+            SlotState({"conv": ((cfg.conv_L * cfg.dim,), cfg.dtype)},
+                      layers=cfg.n_conv_layers), **route)
     if isinstance(cfg, deepseek_v3.DeepseekV3Config):
         if kv_dtype == "int8":
             raise ValueError(

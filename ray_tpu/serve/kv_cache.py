@@ -44,7 +44,10 @@ class CacheLeaf:
     state), and no block table points at it.  `used`: how many of the
     last dim's values a token really caches where the leaf is padded to
     the device's tiling (None = all of them); `sidecar`: scales beside
-    a quantized payload."""
+    a quantized payload.  `layers`: how many layers hold this leaf, where
+    that is not every layer of the model (None): a model whose layers
+    are of two kinds (`models/lfm2.py`) has paged K and V in some and a
+    per-slot state in the others, and each leaf counts its own."""
 
     name: str
     tail: Tuple[int, ...]
@@ -52,6 +55,7 @@ class CacheLeaf:
     sidecar: bool = False
     used: Optional[int] = None
     per_slot: bool = False
+    layers: Optional[int] = None
 
 
 class BlockPool:
@@ -68,7 +72,8 @@ class BlockPool:
         the spec says (`leaf_shapes`): two per-head pools for a Llama
         (and their scale sidecars, where the format is int8), a single
         latent pool for an MLA model, one state a slot (`slots` of
-        them) for a recurrent model; block ids, the scratch block and
+        them) for a recurrent model, or leaves of both kinds for a
+        model that mixes the layers; block ids, the scratch block and
         the radix cache do not care which, and a spec of per-slot
         leaves alone never asks for a block."""
         if num_blocks < 2:
@@ -81,9 +86,11 @@ class BlockPool:
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
 
     def leaf_shapes(self, layers: int, block_size: int) -> List[tuple]:
-        """(shape, dtype) of every device array the spec asks for."""
-        return [(((layers, self.slots) if leaf.per_slot
-                  else (layers, self.num_blocks, block_size))
+        """(shape, dtype) of every device array the spec asks for;
+        `layers`: the model's, for a leaf that does not count its own."""
+        return [((leaf.layers or layers,)
+                 + ((self.slots,) if leaf.per_slot
+                    else (self.num_blocks, block_size))
                  + tuple(leaf.tail), leaf.dtype) for leaf in self.spec]
 
     def _bytes(self, layers: int, per_slot: bool) -> int:
@@ -92,9 +99,9 @@ class BlockPool:
             if leaf.per_slot != per_slot:
                 continue
             width = leaf.tail[-1] if leaf.used is None else leaf.used
-            total += (math.prod(leaf.tail[:-1]) * width
-                      * np.dtype(leaf.dtype).itemsize)
-        return layers * total
+            total += ((leaf.layers or layers) * math.prod(leaf.tail[:-1])
+                      * width * np.dtype(leaf.dtype).itemsize)
+        return total
 
     def bytes_per_token(self, layers: int) -> int:
         """Bytes one cached token costs over all layers, counting the

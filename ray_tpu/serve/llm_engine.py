@@ -149,12 +149,14 @@ class LlamaEngine:
     bodies of packed prefill, prefill, suffix prefill, KV write and the
     paged decode chunk, all with flat signatures `(params, *cache, ...)`.  The
     cache's FORMAT (`kv_dtype`) is the model's too: the engine hands
-    the string over and reads it back for `stats()`.  Two implementers,
+    the string over and reads it back for `stats()`.  Four implementers,
     picked by the config's type (`engine_model_for`): `LlamaEngineModel`
-    — per-head K and V pools — and `LatentMoeEngineModel` — one latent
+    — per-head K and V pools — `LatentMoeEngineModel` — one latent
     pool, absorbed decode attention, dropless experts
-    (`models/deepseek_v3.py`).  The class keeps its name; nothing a
-    caller passes changed.
+    (`models/deepseek_v3.py`) — `RetentionEngineModel` — a per-slot
+    state (`models/brumby.py`) — and `HybridEngineModel` — paged K and V
+    beside a per-slot state in one spec (`models/lfm2.py`).  The class
+    keeps its name; nothing a caller passes changed.
 
     submit() is thread-safe and returns a `concurrent.futures.Future`
     resolving to the generated token ids (greedy — identical to what a
@@ -173,7 +175,14 @@ class LlamaEngine:
     blocks, so admission is bounded by slots alone, the decode chunk
     takes no tables (one program, `decode_chunk_state`), `block_size`
     is only what a prompt is aligned to in a packed prefill, and
-    `prefix_cache=True` raises `PrefixCacheUnsupportedError`."""
+    `prefix_cache=True` raises `PrefixCacheUnsupportedError`.
+
+    A model whose cache holds BOTH kinds (paged K and V in its attention
+    layers, a per-slot state in the others) is admitted when a slot AND
+    its blocks are free; its packed prefill is handed `blk_ids` and
+    `slots`; `stats()` reports `cache_bytes_per_token` and
+    `cache_bytes_per_slot` both; the prefix cache is refused as for any
+    per-slot state (sharing would need the state at block boundaries)."""
 
     def __init__(self, cfg, params, *, slots: int = 32,
                  max_len: Optional[int] = None, chunk: int = 8,
@@ -235,11 +244,14 @@ class LlamaEngine:
         self._model = engine_model_for(
             cfg, kv_dtype=kv_dtype, block_size=self.block_size, chunk=chunk,
             paged=mode == "pallas", interpret=self._kernel_interpret)
-        # the cache KIND: blocks through tables, or one state a slot.
-        # A per-slot cache never asks the pool for a block: admission
-        # is bounded by slots alone
-        self._per_slot = self._model.per_slot
-        if self._per_slot:
+        # the cache's KINDS: blocks through tables, one state a slot,
+        # or both.  A cache with no paged leaf never asks the pool for a
+        # block: admission is bounded by slots alone
+        self._has_blocks = any(not leaf.per_slot
+                               for leaf in self._model.cache_leaves)
+        self._has_state = any(leaf.per_slot
+                              for leaf in self._model.cache_leaves)
+        if self._has_state:
             if prefix_cache:
                 from ray_tpu.exceptions import PrefixCacheUnsupportedError
 
@@ -248,7 +260,9 @@ class LlamaEngine:
                     "sequence's context as a per-slot state, which a "
                     "radix trie cannot share block by block; pass "
                     "prefix_cache=False (or leave it unset)")
-            prefix_cache, budget = False, 1
+            prefix_cache = False
+            if not self._has_blocks:
+                budget = 1
         elif prefix_cache is None:
             prefix_cache = True
         # +1: reserved scratch block
@@ -422,9 +436,10 @@ class LlamaEngine:
         instead of serving through another route.  Then every packed
         prefill of the closed set, empty (padding only, into scratch):
         no admission compiles after this, whatever the traffic."""
-        tables = () if self._per_slot else (self._jnp.full(
-            (self.slots, 1), SCRATCH_BLOCK, self._jnp.int32),)
-        cfn = self._chunk_step_for(0 if self._per_slot else 1)
+        tables = (self._jnp.full(
+            (self.slots, 1), SCRATCH_BLOCK, self._jnp.int32),
+        ) if self._has_blocks else ()
+        cfn = self._chunk_step_for(int(self._has_blocks))
         self._cache = tuple(cfn(
             self.params, *self._cache, *tables, self._tok, self._pos,
             self._stop)[:len(self._cache)])
@@ -601,11 +616,12 @@ class LlamaEngine:
                 "decode_kernel": self._decode_kernel,
                 "kernel_interpret": self._kernel_interpret,
                 "device": dict(self._device),
-                "kv_dtype": self._model.kv.kv_dtype,
+                "kv_dtype": self._model.kv_dtype,
                 "kv_pool_bytes": self._cache_bytes[0],
                 "kv_scale_bytes": self._cache_bytes[1],
                 "cache_bytes_per_token": self._cache_bytes_per_token,
-                # a per-slot state's bytes a sequence (0: a paged cache)
+                # the per-slot leaves' bytes a sequence (0: none; a
+                # cache of both kinds reports both above zero)
                 "cache_bytes_per_slot": self._cache_bytes_per_slot,
                 "decode_kernel_dispatch_total":
                     self._decode_kernel_dispatches,
@@ -848,9 +864,10 @@ class LlamaEngine:
         path: List = []
         if self._radix is not None:
             shared, path = self._radix.match(prompt)
-        # a per-slot state holds no blocks: the free slot is all it needs
-        own = [] if self._per_slot else self._alloc_or_evict(
-            total_blocks - len(shared))
+        # a request needs a slot AND its blocks; a cache with no paged
+        # leaf holds no blocks, and the free slot is all it needs
+        own = self._alloc_or_evict(
+            total_blocks - len(shared)) if self._has_blocks else []
         if own is None:
             if self._radix is not None:
                 self._radix.release(path)
@@ -934,7 +951,7 @@ class LlamaEngine:
         """A packed prefill's host-made arguments, `(tokens, seg, posn,
         blk_ids, last, slots, pos0, stop0)`: the prompts of `pack` end
         to end in a row of `N` tokens, each from a block boundary.  A
-        per-slot cache has no `blk_ids`."""
+        cache with no paged leaf has no `blk_ids`."""
         bs, K = self.block_size, self._pack_rows
         i32 = np.int32
         tokens, posn = np.zeros(N, i32), np.zeros(N, i32)
@@ -951,12 +968,12 @@ class LlamaEngine:
             posn[at:at + T] = np.arange(T)
             # only the blocks holding real tokens; garbage within the
             # last of them is masked by pos until decode overwrites it
-            if not self._per_slot:
+            if self._has_blocks:
                 blk_ids[at // bs:at // bs + nb] = plan.own[:nb]
             last[i], slots[i] = at + T - 1, plan.slot
             pos0[i], stop0[i] = T, plan.req["stop"]
             at += nb * bs
-        if self._per_slot:
+        if not self._has_blocks:
             return tokens, seg, posn, last, slots, pos0, stop0
         return tokens, seg, posn, blk_ids, last, slots, pos0, stop0
 
@@ -1138,9 +1155,9 @@ class LlamaEngine:
         t1 = _time.perf_counter()
         with self._lock:
             # 0 = nothing live (a live batch needs at least one block);
-            # a per-slot cache has no width, so any live row is 1
+            # a cache with no paged leaf has no width: any live row is 1
             live_now = bool(self._active)
-            W = (int(live_now) if self._per_slot
+            W = (int(live_now) if not self._has_blocks
                  else self._gather_width() if live_now else 0)
         toks = None
         # of the chunk's slots x chunk row-steps, those a request was
@@ -1149,15 +1166,15 @@ class LlamaEngine:
         if W:
             with self._span("engine.dispatch", W=W):
                 tables = ()
-                if not self._per_slot:
+                if self._has_blocks:
                     with self._lock:
                         table = np.zeros((self.slots, W), np.int32)
                         for slot in self._active:
                             blocks = self._slot_blocks[slot][:W]
                             table[slot, :len(blocks)] = blocks
                     tables = (jnp.asarray(table),)
-                self._last_gather_blocks = 0 if self._per_slot else W
-                cfn = self._chunk_step_for(0 if self._per_slot else W)
+                self._last_gather_blocks = W if self._has_blocks else 0
+                cfn = self._chunk_step_for(W if self._has_blocks else 0)
                 out = cfn(self.params, *self._cache, *tables,
                           self._tok, self._pos, self._stop)
                 self._cache = tuple(out[:-3])
@@ -1216,10 +1233,10 @@ class LlamaEngine:
                 "live_tokens": sum(
                     r["pos_host"] for r in self._active.values()
                 ),
-                "gather_blocks": 0 if self._per_slot else W,
+                "gather_blocks": W if self._has_blocks else 0,
                 # rows that owed a token at the chunk's first step: for
-                # a per-slot cache, the states its first step moves
-                "state_rows_live": rows_live if self._per_slot else 0,
+                # per-slot leaves, the states its first step moves
+                "state_rows_live": rows_live if self._has_state else 0,
                 "row_steps_live": row_steps_live,
                 "row_steps": row_steps,
                 "kernel": self._decode_kernel,

@@ -322,7 +322,8 @@ def test_dropless_expert_layer_with_grouped_kernel(chip, rows):
 
     def fn(h, layer, index):
         return moe.dropless_moe(h, layer, top_k=6, scale=2.448,
-                                dtype=BF16, kernel=True, stack_index=index)
+                                route_eps=1e-20, dtype=BF16, kernel=True,
+                                stack_index=index)
 
     hlo = _compile(chip, fn, _s(rows, D), layer, _s(dtype=jnp.int32))
     assert hlo.count("tpu_custom_call") >= 3
@@ -463,8 +464,8 @@ def test_state_model_programs_at_the_cells_shapes(chip):
         jax.eval_shape(lambda: brumby.init_params(cfg, jax.random.PRNGKey(0))))
     model = engine_model_for(cfg, kv_dtype="model", block_size=256, chunk=8,
                              paged=True, interpret=False)
-    assert model.per_slot and [l.per_slot for l in model.cache_leaves] == [
-        True, True]
+    assert model.kv is None and [
+        l.per_slot for l in model.cache_leaves] == [True, True]
     cache = _state_leaves()
     i32 = jnp.int32
     rows = [_s(32, dtype=i32)] * 3
@@ -480,3 +481,82 @@ def test_state_model_programs_at_the_cells_shapes(chip):
                    *[_s(16, dtype=i32)] * 4, *rows, **donate)
     assert "jit_prefill_packed_n2048" in hlo and "(bf16[8,5,2048,128]" in hlo
     assert "input_output_alias" in hlo and "(f32[32,8,128,128]" not in hlo
+
+
+# ----------------------------------------------------------------------
+# the hybrid (LFM2-8B-A1B cut to 16 layers): head width 64, both caches
+# ----------------------------------------------------------------------
+# the benchmark's `lfm2-8b-a1b-l16` engine: 4 attention layers' pools,
+# 10,368 blocks + scratch, 128 slots, the widest table (81 = 1296 / 16)
+_LFM2_POOL = dict(L=4, NB=10369, BS=16, KV=8, HD=64, B=128, H=32)
+
+
+def _lfm2_l16():
+    from ray_tpu.models import lfm2
+
+    return lfm2.Lfm2MoeConfig(layer_types=lfm2.LAYER_TYPES[:16],
+                              max_seq_len=1296)
+
+
+@pytest.mark.parametrize("W", [16, 81])
+def test_paged_kernels_at_head_width_64(chip, W):
+    """A page `[16 x 8, 64]` would be half a lane tile wide: the pool
+    holds a token's heads side by side in one row of whole lanes
+    (`kv_pool_tail`: a page `[16, 512]`, the same bytes), and the decode
+    kernel and the in-place append run on it as the hybrid's attention
+    layers call them, 128 rows, queries and new rows still 64 wide."""
+    d = dict(W=W, **_LFM2_POOL)
+    assert pa.kv_pool_tail(d["KV"], d["HD"]) == (512,)
+    assert pa.kv_pool_tail(8, 128) == (8, 128)
+    pool = _s(d["L"], d["NB"], d["BS"], 512)
+    tables, pos = _s(d["B"], W, dtype=jnp.int32), _s(d["B"], dtype=jnp.int32)
+    q, row = _s(d["B"], d["H"], d["HD"]), _s(d["B"], d["KV"], d["HD"])
+
+    def fn(q, kp, vp, kn, vn, tables, pos, layer):
+        kp, vp = pa.paged_kv_append(kp, vp, kn, vn, tables, pos, layer)
+        return pa.paged_decode_attention(q, kp, vp, tables, pos, layer), kp, vp
+
+    hlo = _compile(chip, fn, q, pool, pool, row, row, tables, pos,
+                   _s(dtype=jnp.int32), donate_argnums=(1, 2))
+    assert hlo.count("tpu_custom_call") >= 2 and "bf16[128,32,512]" in hlo
+    assert "input_output_alias" in hlo
+
+
+def test_hybrid_model_programs_at_the_cells_shapes(chip):
+    """`decode_chunk_w81` and `prefill_packed_n1296` as the engine jits
+    them for the hybrid at the published widths (16 layers: 12
+    convolution + 4 attention, 32 experts, 128 slots, chunk 8): tables
+    AND a per-slot leaf in one signature, all three leaves donated and
+    written in place, and no copy of a layer's experts beside the
+    grouped kernels."""
+    from ray_tpu.models import lfm2
+    from ray_tpu.serve.engine_model import engine_model_for
+
+    cfg = _lfm2_l16()
+    params = jax.tree.map(
+        lambda p: _s(*p.shape, dtype=p.dtype),
+        jax.eval_shape(lambda: lfm2.init_params(cfg, jax.random.PRNGKey(0))))
+    model = engine_model_for(cfg, kv_dtype="model", block_size=16, chunk=8,
+                             paged=True, interpret=False)
+    assert [(l.per_slot, l.layers) for l in model.cache_leaves] == [
+        (False, 4), (False, 4), (True, 12)]
+    d = _LFM2_POOL
+    pool = _s(d["L"], d["NB"], d["BS"], 512)
+    cache = [pool, pool, _s(12, d["B"], 3 * 2048)]
+    i32 = jnp.int32
+    rows = [_s(d["B"], dtype=i32)] * 3
+    donate = dict(donate_argnums=(1, 2, 3))
+    fn = model.decode_chunk(81)
+    fn.__name__ = "decode_chunk_w81"
+    hlo = _compile(chip, fn, params, *cache, _s(d["B"], 81, dtype=i32),
+                   *rows, **donate)
+    assert "jit_decode_chunk_w81" in hlo and "bf16[128,32,512]" in hlo
+    assert "input_output_alias" in hlo
+    assert "bf16[32,2048,1792]" not in hlo and "bf16[32,1792,2048]" not in hlo
+    fn = model.prefill_packed(1296)
+    fn.__name__ = "prefill_packed_n1296"
+    hlo = _compile(chip, fn, params, *cache, *[_s(1296, dtype=i32)] * 3,
+                   _s(81, dtype=i32), *[_s(16, dtype=i32)] * 4, *rows,
+                   **donate)
+    assert "jit_prefill_packed_n1296" in hlo and "input_output_alias" in hlo
+    assert "bf16[32,2048,1792]" not in hlo and "bf16[32,1792,2048]" not in hlo

@@ -98,11 +98,11 @@ def test_dropless_layer_under_one_sided_routing():
     }
     h = jax.random.normal(ks[4], (N, D))
     y, stats = moe.dropless_moe(h, layer, top_k=K, scale=2.448,
-                                dtype=jnp.float32)
+                                route_eps=1e-20, dtype=jnp.float32)
     assert int(stats["experts_touched"]) == K
     assert int(stats["load_max"]) == N       # every token, no capacity
     w, idx = moe.sigmoid_topk_route(h, layer["router"], layer["router_bias"],
-                                    K, 2.448)
+                                    K, 2.448, 1e-20)
     assert set(np.asarray(idx).ravel()) == set(range(K))
     # weights come from the scores WITHOUT the bias, normalised, scaled
     np.testing.assert_allclose(np.asarray(w).sum(-1), 2.448, rtol=1e-5)
@@ -139,7 +139,7 @@ def test_masked_rows_are_routed_nowhere_and_counted_nowhere(dead):
     mask = np.ones(N, bool)
     mask[list(dead)] = False
     h = jnp.where(jnp.asarray(mask)[:, None], h, h[0])
-    kw = dict(top_k=K, scale=2.448, dtype=jnp.float32)
+    kw = dict(top_k=K, scale=2.448, route_eps=1e-20, dtype=jnp.float32)
     y_all, _ = moe.dropless_moe(h, layer, **kw)
     y, stats = moe.dropless_moe(h, layer, row_mask=jnp.asarray(mask), **kw)
     np.testing.assert_array_equal(np.asarray(y)[mask], np.asarray(y_all)[mask])
