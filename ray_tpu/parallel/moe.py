@@ -210,13 +210,55 @@ def sigmoid_topk_route(h, w_router, bias, top_k: int, scale: float,
     return w, idx.astype(jnp.int32)
 
 
+# megablox's row tile
+ROW_TILE = 128
+# mean rows a group from which a grouped product fetches a group ahead
+GROUP_AHEAD_FROM = 32
+
+
+def row_tiling(rows: int, groups: int) -> Tuple[int, bool]:
+    """(row tile, whether the kernel fetches a group's matrix a GROUP
+    ahead) for a grouped product of `rows` sorted rows over `groups`
+    matrices, from the MEAN ROWS A GROUP alone: static shapes, so a
+    program's choice is made when it is traced.
+
+    Under a tile's worth of rows a group a product is a step a group:
+    megablox's pipeline, which fetches a STEP ahead, has every read
+    behind a product.  That is a decode step (16 rows an expert for
+    lfm2, 3 for kanana), whose programs stay as they were, and kanana's
+    smallest prefill (24).  From 32 rows a group on, groups take two
+    steps and more, megablox pays each group's read on top of its
+    products, and `ops/grouped_matmul` starts the next group's read at
+    the current group's FIRST step; with the reads hidden a product is
+    its tiles' MXU time, so the tile is 64 rows: fewer rows computed
+    for nothing where a group ends inside a tile (PERF.md section 6,
+    PR 35: the sweep this rule was read from)."""
+    if rows // groups < GROUP_AHEAD_FROM:
+        return ROW_TILE, False
+    return 64, True
+
+
+def tile_visits(group_sizes, row_tile: int):
+    """Grid steps of a grouped product over rows packed group after
+    group from row 0: one a (row tile, group) pair that share a row,
+    each a whole tile's product.  Over the groups with rows it is the
+    tiles a group's rows reach into: 1.1 in a decode step, 2.2 at 162
+    rows a group and 128 a tile."""
+    ends = jnp.cumsum(group_sizes)
+    first = (ends - group_sizes) // row_tile
+    last = -(-ends // row_tile)
+    return jnp.sum(jnp.where(group_sizes > 0, last - first, 0)
+                   ).astype(jnp.int32)
+
+
 def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
                    interpret: bool = False, stack_index=None):
     """Rows of `xs` [M, K], sorted by group, each times its group's
-    matrix of `w` [G, K, N]; `group_sizes` [G] sums to M.  A group
-    with no row is never read.  `kernel`: megablox's grouped product
-    (Pallas, TPU); otherwise `lax.ragged_dot`, which every backend
-    lowers.
+    matrix of `w` [G, K, N]; `group_sizes` [G] sums to M or less (rows
+    past the last group belong to none and are never read).  A group
+    with no row is never read.  `kernel`: a Pallas grouped product
+    (TPU), megablox's or `ops/grouped_matmul`'s by `row_tiling`;
+    otherwise `lax.ragged_dot`, which every backend lowers.
 
     `w` may be a whole STACK of layers `[L, G, K, N]` with
     `stack_index` (traced) naming the layer.  That is how a layer scan
@@ -231,29 +273,31 @@ def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
         w = lax.dynamic_index_in_dim(w, stack_index, 0, keepdims=False)
     if not kernel:
         return lax.ragged_dot(xs, w, group_sizes)
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-
+    M, K = xs.shape
+    N = w.shape[-1]
+    tm, group_ahead = row_tiling(M, group_sizes.shape[0])
     if w.ndim == 4:
         L, G = w.shape[:2]
         w = w.reshape((L * G,) + w.shape[2:])
         group_sizes = lax.dynamic_update_slice(
             jnp.zeros((L * G,), group_sizes.dtype), group_sizes,
             (stack_index * G,))
-    M, K = xs.shape
-    N = w.shape[-1]
-    # whole K and N in one tile where they fit: 0.57 ms a product at
-    # (128, 2048, 768) against 0.65-0.67 at tk 1024 / 512 (PERF.md, PR 27).
-    # 128 rows a tile also where a prefill brings thousands of rows: the
-    # kernel reads a group's matrix once for every row tile its rows
-    # reach into, but computes a WHOLE tile a visit, so 256- and 512-row
-    # tiles are slower at every shape tried (PERF.md section 6, PR 34)
-    tm = 128
     pad = -M % tm
-    if pad:  # the kernel walks whole row tiles; the tail belongs to no group
+    if pad:  # the kernels walk whole row tiles; the tail belongs to no group
         xs = jnp.pad(xs, ((0, pad), (0, 0)))
-    out = gmm(xs, w, group_sizes, preferred_element_type=xs.dtype,
-              tiling=(tm, min(K, 2048), min(N, 2048)),
-              interpret=interpret)
+    if group_ahead:
+        from ray_tpu.ops.grouped_matmul import gmm
+
+        out = gmm(xs, w, group_sizes, row_tile=tm, interpret=interpret)
+    else:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        # whole K and N in one tile where they fit: 0.57 ms a product at
+        # (128, 2048, 768) against 0.65-0.67 at tk 1024 / 512 (PERF.md,
+        # PR 27)
+        out = gmm(xs, w, group_sizes, preferred_element_type=xs.dtype,
+                  tiling=(tm, min(K, 2048), min(N, 2048)),
+                  interpret=interpret)
     return out[:M] if pad else out
 
 
@@ -274,8 +318,11 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
     summed with the router's weights in float32.  There is no capacity:
     if one expert gets every token its group is the whole array.  A
     padding row routes like any other row and changes no other row's
-    result.  `stats`: `experts_touched` (groups with at least one row)
-    and `load_max` (rows of the largest group), int32 scalars.
+    result.  `stats`, int32 scalars: `experts_touched` (groups with at
+    least one row), `load_max` (rows of the largest group) and
+    `tile_visits` (`tile_visits` of ONE of the three products at the
+    row tile `row_tiling` gives; `ragged_dot` has no tiles and counts
+    megablox's).
 
     `row_mask` [N] bool (the serve engine's live rows of a decode step;
     prefill passes none): a row it leaves out is routed to NO expert.
@@ -306,6 +353,8 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
             # rows of `ys` past the last group are whatever the product
             # left there, a NaN's bits too
             y = jnp.where(row_mask[:, None], y, jnp.zeros_like(y))
+    tile = row_tiling(N * top_k, E)[0] if kernel else ROW_TILE
     stats = {"experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
-             "load_max": jnp.max(sizes)}
+             "load_max": jnp.max(sizes),
+             "tile_visits": tile_visits(sizes, tile)}
     return y, stats

@@ -473,8 +473,8 @@ class LatentMoeEngineModel(_ExpertCounters, _EngineModel):
     through `W_kvb`; decode is the absorbed form on the pool as it
     lies.  The decode program hands back two counters of the expert
     layers with its tokens (`aux_rows`).  `paged`: latent kernels +
-    megablox grouped products (TPU); else the dense view +
-    `lax.ragged_dot` (anywhere)."""
+    Pallas grouped products (TPU; `moe.row_tiling` says whose); else
+    the dense view + `lax.ragged_dot` (anywhere)."""
 
     def __init__(self, cfg, kv: PagedKV, **route):
         super().__init__(cfg, kv, **route)
@@ -602,7 +602,7 @@ class HybridEngineModel(_ExpertCounters, _EngineModel):
     expert layers' two counters, as the latent model's (`aux_rows`).
     `suffix_prefill` and `kv_write` do not exist: sharing a prefix would
     need the convolution state at the block boundary.  `paged`: the
-    paged kernels + megablox grouped products (TPU); else the dense
+    paged kernels + Pallas grouped products (TPU); else the dense
     view + `lax.ragged_dot` (anywhere)."""
 
     def __init__(self, cfg, kv: PagedKV, state: SlotState, **route):

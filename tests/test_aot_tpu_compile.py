@@ -305,30 +305,52 @@ def test_mla_paged_kernels(chip, W):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("rows", [64, 2048], ids=["decode", "prefill-2048"])
-def test_dropless_expert_layer_with_grouped_kernel(chip, rows):
-    """The serving expert layer at kanana's widths with megablox's
-    grouped product: 384 (token, expert) rows of a decode step, 12,288
-    of a 2,048-token prefill, over 128 groups."""
+# D, I, E, L, top_k of each model that serves through `dropless_moe`
+_EXPERTS = {"kanana": (2048, 768, 128, 6, 6), "lfm2": (2048, 1792, 32, 14, 4)}
+
+
+@pytest.mark.parametrize("model,tokens,decode", [
+    ("kanana", 64, True), ("kanana", 2048, False),
+    ("lfm2", 128, True), ("lfm2", 1296, False),
+], ids=["kanana-decode", "kanana-n2048", "lfm2-decode", "lfm2-n1296"])
+def test_dropless_expert_layer_with_grouped_kernel(chip, model, tokens,
+                                                   decode):
+    """The serving expert layer at kanana's and lfm2's widths.  A
+    decode step (`row_mask`, 384 / 512 (token, expert) rows) keeps
+    megablox's grouped product, which returns `bf16[slots * top_k,
+    ...]`: what the benchmark's `moe_grouped` finds it by.  A prefill's
+    three products are `ops/grouped_matmul`'s: both of a group's
+    `[K, N]` slots in VMEM (14.7 MB at lfm2's widths, past Mosaic's
+    default limit), the stack itself left in HBM."""
     from ray_tpu.parallel import moe
 
-    D, I, E, L = 2048, 768, 128, 6
+    D, I, E, L, top_k = _EXPERTS[model]
     layer = {"router": _s(D, E, dtype=jnp.float32),
              "router_bias": _s(E, dtype=jnp.float32),
              # whole stacks, as the layer scan hands them over: the kernel
-             # picks its layer by index, no 403 MB slice is copied
+             # picks its layer by index, no layer's experts are copied
              "e_gate": _s(L, E, D, I), "e_up": _s(L, E, D, I),
              "e_down": _s(L, E, I, D)}
 
-    def fn(h, layer, index):
-        return moe.dropless_moe(h, layer, top_k=6, scale=2.448,
+    def fn(h, layer, index, mask):
+        return moe.dropless_moe(h, layer, top_k=top_k, scale=2.448,
                                 route_eps=1e-20, dtype=BF16, kernel=True,
-                                stack_index=index)
+                                stack_index=index,
+                                row_mask=mask if decode else None)
 
-    hlo = _compile(chip, fn, _s(rows, D), layer, _s(dtype=jnp.int32))
-    assert hlo.count("tpu_custom_call") >= 3
+    hlo = _compile(chip, fn, _s(tokens, D), layer, _s(dtype=jnp.int32),
+                   _s(tokens, dtype=jnp.bool_))
+    tm, ahead = moe.row_tiling(tokens * top_k, E)
+    assert ahead != decode
+    rows = -(-tokens * top_k // tm) * tm
+    calls = [ln.split("=", 1)[1].lstrip() for ln in hlo.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert sum(c.startswith(f"bf16[{rows},{I}]") for c in calls) == 2
+    assert sum(c.startswith(f"bf16[{rows},{D}]") for c in calls) == 1
+    assert all(("grouped_matmul_prefetch" in c) == ahead for c in calls)
     # no copy of a layer's experts beside the kernels
-    assert "bf16[128,2048,768]" not in hlo and "bf16[128,768,2048]" not in hlo
+    assert f"bf16[{E},{D},{I}]" not in hlo and f"bf16[{E},{I},{D}]" not in hlo
 
 
 # ----------------------------------------------------------------------

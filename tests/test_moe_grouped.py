@@ -1,0 +1,196 @@
+"""The serving expert layer's grouped products (`parallel/moe.py`): the
+kernel that fetches a group's matrix a GROUP ahead
+(`ops/grouped_matmul.py`) and megablox's, both in the interpreter,
+against `lax.ragged_dot`; and the rule that picks between them from
+static shapes."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import grouped_matmul
+from ray_tpu.parallel import moe
+
+D, I = 32, 16
+
+
+def _layer(E, stacked, seed=0):
+    """Weights and a router that sends a row to the expert whose column
+    of `h` is largest: `h` decides the routing, exactly."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    lead = (3, E) if stacked else (E,)
+    return {"router": jnp.eye(D, E) * 4.0, "router_bias": jnp.zeros((E,)),
+            "e_gate": jax.random.normal(ks[0], lead + (D, I)) * 0.3,
+            "e_up": jax.random.normal(ks[1], lead + (D, I)) * 0.3,
+            "e_down": jax.random.normal(ks[2], lead + (I, D)) * 0.3}
+
+
+def _rows(case, E, top_k, N, tm):
+    """`h` [N, D] for a case: noise in the columns past E, and in the
+    first E columns what the case wants of the routing."""
+    rng = np.random.default_rng(5)
+    h = np.zeros((N, D), np.float32)
+    h[:, E:] = rng.normal(size=(N, D - E))
+    if case == "random":
+        h[:, :E] = rng.normal(size=(N, E))
+    elif case == "no-row-for-one":
+        h[:, :E] = rng.normal(size=(N, E))
+        h[:, 2] = -9.0
+    elif case == "every-row-to-one":
+        h[:, :E] = rng.normal(size=(N, E))
+        h[:, 1] = 9.0
+    else:  # "a-group-of-one-tile": sizes tm, 2 * tm, 0 and the rest
+        assert case == "a-group-of-one-tile" and top_k == 1
+        to = np.r_[np.zeros(tm), np.ones(2 * tm), np.full(N - 3 * tm, 3)]
+        h[np.arange(N), rng.permutation(to).astype(int)] = 9.0
+    return jnp.asarray(h)
+
+
+def _visits(sizes, tm):
+    """(row tile, group) pairs that share a row, counted the slow way."""
+    ends = np.cumsum(sizes)
+    return int(sum(-(-e // tm) - (e - s) // tm
+                   for s, e in zip(sizes, ends) if s))
+
+
+CASES = [
+    # case, E, top_k, rows, tile: mean rows an expert against the tile
+    ("random", 8, 2, 96, 16),              # 24: most groups straddle
+    ("random", 4, 4, 64, 32),              # 64: groups of 2-3 tiles
+    ("random", 16, 2, 8, 16),              # 1: the decode regime
+    ("no-row-for-one", 8, 2, 64, 16),
+    ("every-row-to-one", 4, 2, 72, 16),    # a group of 4.5 tiles
+    ("a-group-of-one-tile", 4, 1, 80, 16),
+]
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["layer", "stack"])
+@pytest.mark.parametrize("masked", [False, True], ids=["all-rows", "row-mask"])
+@pytest.mark.parametrize("case,E,top_k,N,tm", CASES,
+                         ids=[f"{c[0]}-E{c[1]}-k{c[2]}-n{c[3]}-t{c[4]}"
+                              for c in CASES])
+def test_a_group_ahead_gives_megabloxs_result(
+        monkeypatch, case, E, top_k, N, tm, masked, stacked):
+    """The two kernels walk the same tiles and differ only in when a
+    group's matrix arrives: the SAME bits in float32, both agreeing
+    with `lax.ragged_dot`, and `stats` that count the real rows:
+    `experts_touched`, `load_max`, and `tile_visits`, one a (tile,
+    group) pair that share a row."""
+    layer, h = _layer(E, stacked), _rows(case, E, top_k, N, tm)
+    mask = np.ones(N, bool)
+    if masked:
+        mask[[0, 3, N // 2, N - 1]] = False
+    kw = dict(top_k=top_k, scale=1.5, route_eps=1e-6, dtype=jnp.float32,
+              stack_index=jnp.int32(1) if stacked else None,
+              row_mask=jnp.asarray(mask) if masked else None)
+    want, oracle = moe.dropless_moe(h, layer, **kw)
+    _, idx = moe.sigmoid_topk_route(h, layer["router"], layer["router_bias"],
+                                    top_k, 1.5, 1e-6)
+    sizes = np.bincount(np.asarray(idx)[mask].ravel(), minlength=E)
+    if case == "no-row-for-one":
+        assert sizes[2] == 0 and (np.delete(sizes, 2) > 0).all()
+    elif case == "every-row-to-one":
+        assert sizes[1] == mask.sum() > 4 * tm
+    elif case == "a-group-of-one-tile" and not masked:
+        assert list(sizes) == [tm, 2 * tm, 0, N - 3 * tm]
+    got = {}
+    for ahead in (False, True):
+        monkeypatch.setattr(moe, "row_tiling", lambda r, g: (tm, ahead))
+        y, stats = moe.dropless_moe(h, layer, kernel=True, interpret=True,
+                                    **kw)
+        got[ahead] = np.asarray(y)
+        assert int(stats["experts_touched"]) == (sizes > 0).sum()
+        assert int(stats["load_max"]) == sizes.max()
+        assert int(stats["tile_visits"]) == _visits(sizes, tm)
+    np.testing.assert_array_equal(got[True], got[False])
+    np.testing.assert_allclose(got[True], want, atol=2e-5, rtol=2e-5)
+    assert not got[True][~mask].any()
+    assert int(oracle["experts_touched"]) == (sizes > 0).sum()
+    # `ragged_dot` has no tiles: it counts megablox's
+    assert int(oracle["tile_visits"]) == _visits(sizes, moe.ROW_TILE)
+
+
+@pytest.mark.parametrize("sizes,tm,rows", [
+    ((5, 0, 20, 7), 8, 32), ((0, 0, 16, 0), 8, 24), ((3, 3, 3, 3), 8, 16),
+    ((0, 0, 0, 0), 8, 16), ((40, 0, 0, 0, 0, 1), 16, 48),
+], ids=["straddles", "one-group", "four-in-a-tile", "no-row", "ends-apart"])
+def test_the_walk_names_each_groups_first_step_slot_and_successor(
+        sizes, tm, rows):
+    """What the kernel's copies hang on: a group's matrix is waited for
+    at its FIRST step, in the slot the previous group did not use, and
+    the next group WITH rows is asked for there; after the last, none.
+    Rows past the last group are never computed."""
+    walk, steps = grouped_matmul._walk(jnp.asarray(sizes, jnp.int32), rows, tm)
+    _, gids, _, first, slot, nxt = (np.asarray(a) for a in walk)
+    steps = int(steps)
+    assert steps == _visits(np.asarray(sizes), tm)
+    live = [g for g, s in enumerate(sizes) if s]
+    starts = np.flatnonzero(first)
+    assert (starts < steps).all() and list(gids[starts]) == live
+    assert list(slot[starts]) == [i % 2 for i in range(len(live))]
+    assert list(nxt[starts]) == live[1:] + [-1] * bool(live)
+    for a, b in zip(starts, list(starts[1:]) + [steps]):
+        assert (gids[a:b] == gids[a]).all() and (slot[a:b] == slot[a]).all()
+    rng = np.random.default_rng(1)
+    xs = jnp.asarray(rng.normal(size=(rows, D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(len(sizes), D, I)), jnp.float32)
+    out = grouped_matmul.gmm(xs, w, jnp.asarray(sizes, jnp.int32),
+                             row_tile=tm, interpret=True)
+    real = sum(sizes)
+    np.testing.assert_allclose(
+        out[:real], jax.lax.ragged_dot(xs[:real], w, jnp.asarray(sizes)),
+        atol=2e-5, rtol=2e-5)
+
+
+def _grouped_products(fn, *args):
+    """(kernel name, result shape) of the Pallas calls in `fn`'s trace."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"],
+                              tuple(eqn.outvars[0].aval.shape)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("E,top_k,tokens,ahead", [
+    (32, 4, 128, False),      # lfm2, a decode step: 16 rows an expert
+    (128, 6, 64, False),      # kanana, a decode step: 3
+    (128, 6, 512, False),     # kanana's smallest prefill: 24
+    (32, 4, 256, True),       # lfm2's smallest prefill: 32
+    (32, 4, 1296, True),      # lfm2 `n1296`: 162
+    (128, 6, 1024, True),     # kanana `n1024`: 48
+    (128, 6, 2320, True),     # kanana `n2320`: 108
+], ids=["lfm2-decode", "kanana-decode", "kanana-n512", "lfm2-n256",
+        "lfm2-n1296", "kanana-n1024", "kanana-n2320"])
+def test_the_rule_reads_static_shapes_and_leaves_decode_alone(
+        E, top_k, tokens, ahead):
+    """A decode step's products are the parent's: megablox over
+    `[slots * top_k, ...]` rows at 128 a tile (what the benchmark's
+    `moe_grouped` finds them by).  A prefill's, from 32 rows an expert
+    on, walk the same packed rows in tiles of 64 and fetch a group
+    ahead."""
+    pairs = tokens * top_k
+    tile = 64 if ahead else moe.ROW_TILE
+    assert moe.row_tiling(pairs, E) == (tile, ahead)
+    layer = jax.eval_shape(lambda: _layer(E, True))
+    layer["router"] = jax.ShapeDtypeStruct((D, E), jnp.float32)
+    decode = pairs // E <= 16
+
+    def fn(h, layer):
+        return moe.dropless_moe(
+            h, layer, top_k=top_k, scale=1.0, route_eps=1e-6,
+            dtype=jnp.float32, kernel=True, stack_index=jnp.int32(2),
+            row_mask=jnp.ones((tokens,), bool) if decode else None)
+
+    found = _grouped_products(
+        fn, jax.ShapeDtypeStruct((tokens, D), jnp.float32), layer)
+    rows = -(-pairs // tile) * tile
+    name = "grouped_matmul_prefetch" if ahead else None  # megablox: none
+    assert found == [(name, (rows, I)), (name, (rows, I)), (name, (rows, D))]
