@@ -102,6 +102,82 @@ ADMIT_BUDGET = 16
 # costs the read of the weights, so a smaller program would be no faster
 PACK_MIN_TOKENS = 128
 
+# a tick that waited less than this for the previous chunk's tokens
+# found the chunk FINISHED: the device may have run dry (`starved`)
+STARVED_WAIT_S = 1e-3
+
+# a tick is a STALL, kept whole with its neighbours in stats()["stalls"]
+# (the last STALLS_KEPT), when it compiled nothing and its wall is over
+# both of these: a floor, and a multiple of the tick EMA before it
+STALL_MIN_S = 1.0
+STALL_FACTOR = 4.0
+STALLS_KEPT = 8
+
+# the phases of a tick, where the work happens: each is an
+# `engine.<name>` span of a profiler session AND `<name>_s` in the
+# tick's record, from one stamp (`_Phase`)
+PHASES = ("wait", "plan", "prefill", "dispatch", "device_wait",
+          "harvest_host")
+# a phase's key in the tick record (one string object each: a pickle
+# writes a key it has met as a reference)
+_PHASE_KEYS = tuple((p, p + "_s") for p in PHASES)
+# a starved tick's fields: the host's gap before its first program,
+# and the part of it that lies before the tick (of the rest, `plan_s`
+# is the tick's plan and what is left its packing up to the launch)
+GAPS = ("host_gap_s", "gap_harvest_host_s")
+# what a tick that prefilled adds to stats()'s cumulative counters
+PREFILLED = ("prefill_calls", "prefill_rows", "prefill_tokens",
+             "prefill_padded_tokens")
+
+# stats()["tick_account"]: the tick records summed by the wall second a
+# tick began in, the last ACCOUNT_SECONDS seconds that had a tick.  A
+# column is a tick field summed (`ticks` counts them; `starved`,
+# `stalled` count the ticks that were); a `<x>_us` column is the
+# field `<x>_s` in whole microseconds, which pickle smaller than floats
+ACCOUNT_SECONDS = 96
+_ACCOUNT_TIMES = ("tick_s",) + tuple(k for _, k in _PHASE_KEYS) + (
+    "cpu_s", "proc_cpu_s") + GAPS + ("gap_plan_s",)
+_ACCOUNT_COUNTS = ("starved", "stalled", "row_steps",
+                   "row_steps_live") + PREFILLED
+ACCOUNT_FIELDS = ("sec", "ticks") + tuple(
+    k[:-2] + "_us" for k in _ACCOUNT_TIMES) + _ACCOUNT_COUNTS
+# the one column no tick carries under its name: a starved tick's plan
+# is its `plan_s`
+_GAP_PLAN = ACCOUNT_FIELDS.index("gap_plan_us")
+
+
+def _cpu_now() -> tuple:
+    """(this thread's, the whole process's) CPU seconds so far."""
+    return _time.thread_time(), _time.process_time()
+
+
+def _account_row(sums: List[float]) -> tuple:
+    """A second's sums as its row: the times in whole microseconds."""
+    n = 2 + len(_ACCOUNT_TIMES)
+    return (*sums[:2], *(round(v * 1e6) for v in sums[2:n]), *sums[n:])
+
+
+class _Phase:
+    """One stamp, two surfaces: entering opens the `engine.<name>` span
+    (a no-op outside a profiler session) and leaving adds the elapsed
+    time to the current tick's `<name>_s`, so a span and a field cannot
+    disagree.  `t_end` is the stamp it left at."""
+
+    __slots__ = ("_sums", "_name", "_span", "_t0", "t_end")
+
+    def __init__(self, sums: Dict[str, float], name: str, span):
+        self._sums, self._name, self._span = sums, name, span
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = _time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t_end = _time.perf_counter()
+        self._sums[self._name] += self.t_end - self._t0
+        return self._span.__exit__(*exc)
+
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
@@ -382,14 +458,35 @@ class LlamaEngine:
         )
         self._ttft_samples: deque = deque(maxlen=256)
         # tick introspection ring: the last N per-tick records (batch
-        # composition, live tokens, gather width, kernel route, shed
-        # counters, phase wall times) exposed via stats() for the
-        # dashboard and postmortems.  Bounded; one dict per tick, no
-        # per-request cost.
+        # composition, live tokens, gather width, the phases' times)
+        # exposed via stats() for the dashboard and postmortems.
+        # Bounded; one dict per tick, no per-request cost.
         self._tick_ring: deque = deque(maxlen=max(1, int(
             os.environ.get("RT_ENGINE_TICK_RING", "32") or 32
         )))
         self._tick_ema_s = 0.0
+        # the tick being accounted: its phases' sums (`_Phase` adds;
+        # `wait` is added by the loop BEFORE the tick it is carried
+        # into), the stamp of its first program, the programs it
+        # launched and how many of them compiled.  Closed by `_close`
+        self._phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0)
+        self._t_first: Optional[float] = None
+        self._launched: List[str] = []
+        self._launched_before: List[str] = []
+        self._compiles = 0
+        # where the host's gap before the next tick starts: the return
+        # of this tick's wait for the device (its end, if it had none)
+        self._t_gap_from: Optional[float] = None
+        # (thread, process) CPU seconds at the last close
+        self._cpu_mark = (0.0, 0.0)
+        # the per-second account (ACCOUNT_FIELDS): closed seconds as
+        # rows of ints, and the second still open as a list of sums
+        self._account: deque = deque(maxlen=ACCOUNT_SECONDS - 1)
+        self._account_open: Optional[List[float]] = None
+        # stalled ticks kept whole; the newest may wait for its `after`
+        self._stalls: deque = deque(maxlen=STALLS_KEPT)
+        # stats()'s cumulative prefill counters at the last close
+        self._prefilled_mark = (0, 0, 0, 0)
         self._last_gather_blocks = 0  # W of the latest chunk dispatch
         # request lifecycle ring: one record per FINISHED request (ok,
         # shed, refused or failed), the last REQUEST_RING of them, in
@@ -641,6 +738,11 @@ class LlamaEngine:
                 # the dashboard / postmortems (list of small dicts;
                 # numeric-bridge consumers skip non-float values)
                 "tick_ring": list(self._tick_ring),
+                # the stalled ticks kept whole with their neighbours.
+                # (Order matters to the pickle's size: a key string
+                # first met after the 512 request records is referred
+                # to by 5 bytes, not 2, every time after)
+                "stalls": list(self._stalls),
                 # request lifecycle ring: one record per finished
                 # request (see _record); `finished_total` is the last
                 # record's `seq`, so a reader knows what the ring lost
@@ -655,6 +757,10 @@ class LlamaEngine:
                 "shed_predicted": self._shed_predicted,
                 "shed_total": self._shed_expired + self._shed_predicted,
                 "draining": 1.0 if self._draining else 0.0,
+                # the tick records summed by wall second (columnar: the
+                # last ACCOUNT_SECONDS seconds that had a tick)
+                "tick_account": {"fields": ACCOUNT_FIELDS,
+                                 "rows": self._account_rows()},
             }
 
     def shutdown(self):
@@ -983,16 +1089,16 @@ class LlamaEngine:
         own blocks, their first tokens picked on the device, their
         slots' `pos` / `tok` / `stop` set.  An empty pack is the
         warm-up: padding only, into the scratch block."""
-        self._admitting(pack)
-        arrays = self._pack_arrays(N, pack)
         real = sum(len(plan.prompt) for plan in pack)
-        with self._span("engine.prefill", N=N, rows=len(pack), tokens=real):
-            out = self._prefill_packed_for(N)(
-                self.params, *self._cache, *arrays,
-                self._pos, self._tok, self._stop)
+        with self._phase("prefill", N=N, rows=len(pack), tokens=real):
+            self._admitting(pack)
+            arrays = self._pack_arrays(N, pack)
+            out = self._launch(
+                self._prefill_packed_for(N), self.params, *self._cache,
+                *arrays, self._pos, self._tok, self._stop)
             self._cache = tuple(out[:-3])
             self._pos, self._tok, self._stop = out[-3:]
-        self._prefilled(pack)
+            self._prefilled(pack)
         if pack:
             self._prefill_calls += 1
             self._prefill_rows += len(pack)
@@ -1003,7 +1109,6 @@ class LlamaEngine:
         """PREFIX HIT: prefill only the suffix, attending over the
         gathered prefix blocks (pow-2 buckets on both axes), then write
         its KV and the slot's state."""
-        self._admitting([plan])
         jnp = self._jnp
         bs = self.block_size
         req, slot, prompt, shared, own = plan
@@ -1013,8 +1118,9 @@ class LlamaEngine:
         # real positions correct, the pad tail's garbage KV is masked
         # by the starting pos and overwritten as decoding advances)
         bucket = min(_next_pow2(S), self.max_len - 1)
-        with self._span("engine.prefill", bucket=bucket, slot=slot,
-                        hit_blocks=len(shared)):
+        with self._phase("prefill", bucket=bucket, slot=slot,
+                         hit_blocks=len(shared)):
+            self._admitting([plan])
             p_bucket = _next_pow2(len(shared))
             blk_ids = jnp.asarray(
                 shared + [SCRATCH_BLOCK] * (p_bucket - len(shared)),
@@ -1023,7 +1129,8 @@ class LlamaEngine:
             suffix = jnp.asarray(
                 [prompt[P:] + [0] * (bucket - S)], jnp.int32
             )
-            logits, *kv = self._suffix_prefill_for(bucket, p_bucket)(
+            logits, *kv = self._launch(
+                self._suffix_prefill_for(bucket, p_bucket),
                 self.params, *self._cache, suffix, blk_ids,
                 jnp.asarray(P, jnp.int32),
             )
@@ -1034,7 +1141,8 @@ class LlamaEngine:
             # the suffix starts at a block boundary; write only the
             # blocks holding real tokens
             nb_real = _cdiv(S, bs)
-            out = self._write_blocks_for(bucket, nb_real)(
+            out = self._launch(
+                self._write_blocks_for(bucket, nb_real),
                 *self._cache, *kv,
                 jnp.asarray(own[:nb_real], jnp.int32),
                 jnp.asarray(slot, jnp.int32),
@@ -1044,7 +1152,7 @@ class LlamaEngine:
             )
             self._cache = tuple(out[:-3])
             self._pos, self._tok, self._stop = out[-3:]
-        self._prefilled([plan])
+            self._prefilled([plan])
         self._hit_tokens += P
         self._prefix_hits += 1
         self._prefill_calls += 1
@@ -1113,6 +1221,26 @@ class LlamaEngine:
             if not req["fut"].done():
                 req["fut"].set_result(out)
 
+    def _phase(self, name: str, **stats) -> _Phase:
+        """`with self._phase(name, **stats)`: the span `engine.<name>`
+        with `stats`, and its time in the tick's `<name>_s`."""
+        return _Phase(self._phase_s, name,
+                      self._span("engine." + name, **stats))
+
+    def _launch(self, fn, *args):
+        """Hands a program to the device (async) and returns what it
+        returns.  The tick's first launch ends the host's gap before it
+        (`host_gap_s`), the names are what a stall lists as in flight,
+        and a program that had to be traced and compiled here is
+        counted in the tick's `compiles`."""
+        if self._t_first is None:
+            self._t_first = _time.perf_counter()
+        self._launched.append(fn.__name__)
+        known = fn._cache_size()
+        out = fn(*args)
+        self._compiles += fn._cache_size() - known
+        return out
+
     def _tick(self, admissions: List[tuple], t_wall: float) -> None:
         """One engine tick: admit (shed, prefill) what was popped,
         dispatch the next chunk, harvest the previous one.  Requeued
@@ -1125,34 +1253,34 @@ class LlamaEngine:
             # only; then dispatch what was planned
             plans: List[_Plan] = []
             requeued = 0
-            for i, (prompt, n_new, fut, ts, dl, tk) in \
-                    enumerate(admissions):
-                # shed BEFORE the prefill dispatch: an expired (or,
-                # under load, predictably-expiring) request consumes
-                # neither a slot nor a KV block nor a compile
-                if self._maybe_shed(fut, dl, ts, len(prompt), tk):
+            with self._phase("plan"):
+                for i, (prompt, n_new, fut, ts, dl, tk) in \
+                        enumerate(admissions):
+                    # shed BEFORE the prefill dispatch: an expired (or,
+                    # under load, predictably-expiring) request consumes
+                    # neither a slot nor a KV block nor a compile
+                    if self._maybe_shed(fut, dl, ts, len(prompt), tk):
+                        self._pending_admissions -= 1
+                        continue
+                    with self._lock:
+                        plan = self._plan(prompt, n_new, fut, ts, tk)
+                    if plan is None:
+                        # pool exhausted by LIVE sequences: wait for
+                        # completions, preserving arrival order
+                        requeued = len(admissions) - i
+                        break
                     self._pending_admissions -= 1
-                    continue
-                with self._lock:
-                    plan = self._plan(prompt, n_new, fut, ts, tk)
-                if plan is None:
-                    # pool exhausted by LIVE sequences: wait for
-                    # completions, preserving arrival order
-                    requeued = len(admissions) - i
-                    break
-                self._pending_admissions -= 1
-                plans.append(plan)
-            if requeued:
-                with self._wake:
-                    self._queue.extendleft(
-                        reversed(admissions[-requeued:])
-                    )
+                    plans.append(plan)
+                if requeued:
+                    with self._wake:
+                        self._queue.extendleft(
+                            reversed(admissions[-requeued:])
+                        )
+                        self._pending_admissions = 0
+                    del admissions[-requeued:]
+                else:
                     self._pending_admissions = 0
-                del admissions[-requeued:]
-            else:
-                self._pending_admissions = 0
             self._prefill(plans)
-        t1 = _time.perf_counter()
         with self._lock:
             # 0 = nothing live (a live batch needs at least one block);
             # a cache with no paged leaf has no width: any live row is 1
@@ -1164,7 +1292,7 @@ class LlamaEngine:
         # waiting for (its steps before its stop); the rest are dead
         row_steps = row_steps_live = rows_live = 0
         if W:
-            with self._span("engine.dispatch", W=W):
+            with self._phase("dispatch", W=W):
                 tables = ()
                 if self._has_blocks:
                     with self._lock:
@@ -1174,9 +1302,10 @@ class LlamaEngine:
                             table[slot, :len(blocks)] = blocks
                     tables = (jnp.asarray(table),)
                 self._last_gather_blocks = W if self._has_blocks else 0
-                cfn = self._chunk_step_for(W if self._has_blocks else 0)
-                out = cfn(self.params, *self._cache, *tables,
-                          self._tok, self._pos, self._stop)
+                out = self._launch(
+                    self._chunk_step_for(W if self._has_blocks else 0),
+                    self.params, *self._cache, *tables,
+                    self._tok, self._pos, self._stop)
                 self._cache = tuple(out[:-3])
                 self._tok, self._pos, toks = out[-3:]
                 if self._decode_kernel == "pallas":
@@ -1199,66 +1328,164 @@ class LlamaEngine:
         # earlier remote device), and the dispatch above is async, so
         # the read rides under the compute.  Cost: finish detection
         # lags one chunk.
-        t2 = _time.perf_counter()
         model_fields: Dict[str, object] = {}
+        t_read = None  # when the wait for the device returned
         if self._pending_toks is not None:
             p_toks, p_seq = self._pending_toks
             with self._span("engine.harvest"):
-                toks_host = np.asarray(p_toks)
-                if self._model.aux_rows:
-                    rows = 1 + self.chunk
-                    model_fields = self._model.tick_fields(toks_host[rows:])
-                    toks_host = toks_host[:rows]
-                with self._lock:
-                    self._harvest(toks_host, p_seq)
+                # the BLOCKING read and nothing else: what the tick
+                # waits for the device, apart from the host's own work
+                with self._phase("device_wait") as waited:
+                    toks_host = np.asarray(p_toks)
+                t_read = waited.t_end
+                with self._phase("harvest_host"):
+                    if self._model.aux_rows:
+                        rows = 1 + self.chunk
+                        model_fields = self._model.tick_fields(
+                            toks_host[rows:])
+                        toks_host = toks_host[:rows]
+                    with self._lock:
+                        self._harvest(toks_host, p_seq)
         self._pending_toks = (
             (toks, self._chunk_seq) if toks is not None else None
         )
-        t3 = _time.perf_counter()
-        self._tick_ema_s = (
-            (t3 - t0) if self._tick_ema_s == 0.0
-            else 0.8 * self._tick_ema_s + 0.2 * (t3 - t0)
+        self._close({
+            "seq": self._chunk_seq,
+            "t_wall": t_wall,  # wall clock at the tick's start
+            "admitted": len(admissions),
+            "gather_blocks": W if self._has_blocks else 0,
+            # rows that owed a token at the chunk's first step: for
+            # per-slot leaves, the states its first step moves
+            "state_rows_live": rows_live if self._has_state else 0,
+            "row_steps_live": row_steps_live,
+            "row_steps": row_steps,
+            # the model's own counters of the chunk harvested in this
+            # tick (`engine_model.tick_fields`; none for Llama)
+            **model_fields,
+        }, t0, t_read)
+
+    def _close(self, rec: Dict[str, object], t0: float,
+               t_read: Optional[float]) -> None:
+        """Closes the tick's account: the one record that goes to the
+        ring, into the per-second account and, where the tick stalled,
+        into `stalls`.  Every time in it comes from the phases' stamps.
+
+        `cpu_s` / `proc_cpu_s` are this thread's and the whole
+        process's CPU time since the tick before closed (the tick, the
+        wait before it and the bookkeeping between): a long tick with no
+        CPU anywhere waited on the device or the runtime, one with a
+        CPU-second a second on a contended host or the GIL.
+
+        A tick is `starved` when its wait for the previous chunk was
+        under STARVED_WAIT_S, i.e. the chunk had ENDED before the host
+        asked: the device may have run dry for as long as the host took
+        from the previous tick's read to this tick's first program.
+        `host_gap_s` is that time, an UPPER bound on what the device
+        idled for the host; `gap_harvest_host_s` is its part before the
+        tick (the harvest and bookkeeping of the tick before), `plan_s`
+        the tick's plan, the rest its packing up to the launch.
+        `wait_s` is no part of it: the loop blocks only while no
+        sequence is live, which is idling for want of traffic."""
+        t_end = _time.perf_counter()
+        tick_s = t_end - t0
+        ph = self._phase_s
+        cpu = _cpu_now()
+        rec.update(
+            tick_s=tick_s,
+            admit_s=ph["plan"] + ph["prefill"],
+            dispatch_s=ph["dispatch"],
+            harvest_s=ph["device_wait"] + ph["harvest_host"],
+            cpu_s=cpu[0] - self._cpu_mark[0],
+            proc_cpu_s=cpu[1] - self._cpu_mark[1],
+            starved=(t_read is not None and self._t_first is not None
+                     and ph["device_wait"] < STARVED_WAIT_S),
         )
-        with self._lock:  # keep the lock-free stats() snapshot
-            # one introspection record per tick (bounded ring;
-            # shipped through stats() -> health piggyback ->
-            # /api/serve for batch-composition postmortems)
-            self._tick_ring.append({
-                "seq": self._chunk_seq,
-                "t_wall": t_wall,  # wall clock at the tick's start
-                "admitted": len(admissions),
-                "active": len(self._active),
-                "queued": len(self._queue),
-                "free_slots": len(self._free),
-                "live_tokens": sum(
-                    r["pos_host"] for r in self._active.values()
-                ),
-                "gather_blocks": W if self._has_blocks else 0,
-                # rows that owed a token at the chunk's first step: for
-                # per-slot leaves, the states its first step moves
-                "state_rows_live": rows_live if self._has_state else 0,
-                "row_steps_live": row_steps_live,
-                "row_steps": row_steps,
-                "kernel": self._decode_kernel,
-                "admit_s": t1 - t0,
-                "dispatch_s": t2 - t1,
-                "harvest_s": t3 - t2,
-                "shed_expired": self._shed_expired,
-                "shed_predicted": self._shed_predicted,
-                "rejected_total": self._rejected_total,
-                # the model's own counters of the chunk harvested in
-                # this tick (`engine_model.tick_fields`; none for Llama)
-                **model_fields,
-            })
+        for name, key in _PHASE_KEYS:
+            rec[key] = ph[name]
+        if rec["starved"] and self._t_gap_from is not None:
+            before = t0 - ph["wait"] - self._t_gap_from
+            rec.update(zip(GAPS, (before + self._t_first - t0, before)))
+        if self._compiles:
+            rec["compiles"] = self._compiles
+        stalled = (not self._compiles and tick_s > STALL_MIN_S
+                   and tick_s > STALL_FACTOR * self._tick_ema_s > 0.0)
+        if stalled:
+            rec["stalled"] = True
+        self._tick_ema_s = (
+            tick_s if self._tick_ema_s == 0.0
+            else 0.8 * self._tick_ema_s + 0.2 * tick_s
+        )
+        with self._lock:  # keeps stats() and its snapshot whole
+            rec.update(
+                active=len(self._active), queued=len(self._queue),
+                live_tokens=sum(
+                    r["pos_host"] for r in self._active.values()))
+            done = (self._prefill_calls, self._prefill_rows,
+                    self._prefill_tokens, self._prefill_padded_tokens)
+            if done[0] != self._prefilled_mark[0]:
+                rec.update(zip(PREFILLED, (
+                    n - m for n, m in zip(done, self._prefilled_mark))))
+                self._prefilled_mark = done
+            before = self._tick_ring[-1] if self._tick_ring else None
+            self._tick_ring.append(rec)
+            self._account_add(rec)
+            if self._stalls and self._stalls[-1]["after"] is None:
+                # the tick AFTER a stall: did it wait as usual (the
+                # device was late) or find its chunk done (it was not)
+                self._stalls[-1] = {**self._stalls[-1], "after": rec}
+            if stalled:
+                self._stalls.append({
+                    "before": before, "tick": rec, "after": None,
+                    "in_flight": self._launched_before + self._launched})
             self._stats_snapshot = self._stats_locked()  # fresh
+        self._new_tick(cpu, t_read if t_read is not None else t_end)
+
+    def _new_tick(self, cpu: tuple, t_gap_from: Optional[float]) -> None:
+        """What the next tick's account starts from."""
+        # (0, not 0.0: a phase the tick never entered is an int in its
+        # record, which is 2 bytes of a pickle and not 9)
+        self._phase_s = dict.fromkeys(PHASES, 0)
+        self._t_first = None
+        self._launched_before, self._launched = self._launched, []
+        self._compiles = 0
+        self._cpu_mark = cpu
+        self._t_gap_from = t_gap_from
+
+    def _account_add(self, rec: Dict[str, object]) -> None:
+        """Adds a closed tick to the account of the second it began
+        in.  A second that ends is kept as a row of ints."""
+        sec = int(rec["t_wall"])
+        row = self._account_open
+        if row is None or row[0] != sec:
+            if row is not None:
+                self._account.append(_account_row(row))
+            row = self._account_open = [sec] + [0] * (
+                len(ACCOUNT_FIELDS) - 1)
+        row[1] += 1
+        for i, k in enumerate(_ACCOUNT_TIMES + _ACCOUNT_COUNTS, 2):
+            row[i] += rec.get(k, 0)
+        if "host_gap_s" in rec:
+            row[_GAP_PLAN] += rec["plan_s"]
+
+    def _account_rows(self) -> List[tuple]:
+        rows = list(self._account)
+        if self._account_open is not None:
+            rows.append(_account_row(self._account_open))
+        return rows
 
     def _loop(self):
         jnp = self._jnp
+        # the account starts here, on the engine's own thread (the
+        # warm-up ran its programs on the caller's)
+        self._launched = []
+        self._new_tick(_cpu_now(), None)
         while True:
             with self._wake:
                 while (self._running and not self._active
                        and not (self._queue and self._free)):
-                    with self._span("engine.wait"):
+                    # blocked before a tick: carried into the tick
+                    # that follows (summed over the wake-ups)
+                    with self._phase("wait"):
                         self._wake.wait()
                 if not self._running:
                     # the engine thread sweeps its own state on exit:
@@ -1300,6 +1527,8 @@ class LlamaEngine:
                 logger.exception("llm engine tick failed; failing %d "
                                  "active request(s)", len(self._active))
                 self._pending_toks = None
+                # the failed tick leaves no record: its account is void
+                self._new_tick(_cpu_now(), None)
                 wall = _time.time()
                 with self._lock:
                     for slot, req in list(self._active.items()):

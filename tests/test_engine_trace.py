@@ -4,8 +4,10 @@ the engine-loop spans a `jax.profiler` session records on the host
 plane, and the names the engine's programs and kernels lower under."""
 
 import glob
+import json
 import pickle
 import re
+import threading
 import time
 
 import numpy as np
@@ -120,7 +122,7 @@ def test_shed_and_refused_requests_are_recorded_with_their_status(engine):
 
 def test_the_ring_is_bounded_and_a_pickled_stats_stays_small(engine):
     assert llm_engine.REQUEST_RING == 512
-    _serve(engine, 2)
+    _serve(engine, 16, new=8)  # more ticks than their ring holds
     ok = dict(engine.stats()["request_ring"][-1])
     # the widest records there are: every phase a float
     with engine._ring_lock:
@@ -129,9 +131,32 @@ def test_the_ring_is_bounded_and_a_pickled_stats_stays_small(engine):
     engine.begin_drain()
     for i in range(40):
         engine.submit(_prompt(i), 4)  # refused: one record each
+    # ... the tick ring at its default, every tick starved (its widest
+    # record); the account's 96 seconds, each as busy as a closed cell
+    # on the chip shows one (a second of ticks, most of it waiting for
+    # the device); eight stalls, each three whole ticks
+    with engine._lock:
+        ticks = [dict(t) for t in engine._tick_ring if "host_gap_s" in t]
+        assert len(engine._tick_ring) == 32 and len(ticks) >= 3
+        engine._tick_ring.extend(dict(ticks[0]) for _ in range(32))
+        for i in range(8):
+            engine._stalls.append({
+                "before": dict(ticks[0]), "tick": dict(ticks[1]),
+                "after": dict(ticks[2]),
+                "in_flight": ["prefill_packed_n2048", "decode_chunk_w128",
+                              "prefill_packed_n512", "decode_chunk_w128"]})
+        busy = (9, 1000123, 0, 21034, 33012, 4123, 850321, 91234, 151234,
+                1212345, 31234, 2123, 9123, 3, 0, 9216, 8000, 4, 40, 30000,
+                36864)
+        for i in range(200):
+            engine._account.append((1790000000 + i,) + busy)
     s = engine.stats()
     assert len(s["request_ring"]) == 512
-    assert s["request_ring"][-1]["seq"] == s["finished_total"] == 42
+    assert s["request_ring"][-1]["seq"] == s["finished_total"] == 56
+    acct = s["tick_account"]
+    assert len(acct["rows"]) == llm_engine.ACCOUNT_SECONDS == 96
+    assert all(len(r) == len(acct["fields"]) for r in acct["rows"])
+    assert len(s["stalls"]) == llm_engine.STALLS_KEPT == 8
     assert len(pickle.dumps(s)) < 64 * 1024
 
 
@@ -215,6 +240,236 @@ def test_tick_records_count_the_row_steps_that_were_owed(engine, script):
 
 
 # ----------------------------------------------------------------------
+# A2. the tick's own account: phases, CPU, the host's gap, the account
+#     by the second, the stalls
+# ----------------------------------------------------------------------
+IN_TICK = ("plan_s", "prefill_s", "dispatch_s", "device_wait_s",
+           "harvest_host_s")
+
+
+class _Reads:
+    """What the engine thread's read of a chunk's tokens is made to
+    cost: `delay` every time, `once` the next time only."""
+    delay = once = 0.0
+
+
+@pytest.fixture
+def reads(monkeypatch):
+    real, knob = np.asarray, _Reads()
+
+    def slow(a, *args, **kw):
+        if (threading.current_thread().name == "llm-engine"
+                and isinstance(a, jax.Array)):
+            nap, knob.once = knob.delay + knob.once, 0.0
+            time.sleep(nap)
+        return real(a, *args, **kw)
+
+    monkeypatch.setattr(np, "asarray", slow)
+    return knob
+
+
+def test_a_ticks_phases_are_its_sums_and_its_wall(engine, reads):
+    reads.delay = 0.02  # ticks of 20 ms: what no phase holds is small
+    _serve(engine, 3, new=6)
+    ring = engine.stats()["tick_ring"]
+    assert len(ring) >= 4
+    for t in ring:
+        assert t["admit_s"] == t["plan_s"] + t["prefill_s"]
+        assert t["harvest_s"] == t["device_wait_s"] + t["harvest_host_s"]
+        assert t["dispatch_s"] >= 0 and t["wait_s"] >= 0
+        assert sum(t[k] for k in IN_TICK) <= t["tick_s"]
+    waited = [t for t in ring if t["device_wait_s"]]
+    assert waited and all(t["device_wait_s"] >= 0.02 for t in waited)
+    # the read's delay is in the wait for the device, not in the
+    # host's half of the harvest
+    assert all(t["harvest_host_s"] < 0.01 for t in waited)
+    whole = sum(t["tick_s"] for t in waited)
+    assert sum(t[k] for t in waited for k in IN_TICK) >= 0.99 * whole
+
+
+def test_the_wait_before_a_tick_is_carried_into_it_and_costs_no_cpu(engine):
+    _serve(engine, 1)
+    time.sleep(0.3)  # idle: the loop is blocked on its wake-up
+    _serve(engine, 1, first=1)
+    ring = engine.stats()["tick_ring"]
+    woke = max(ring, key=lambda t: t["wait_s"])
+    assert 0.25 <= woke["wait_s"] <= 5.0 and woke["admitted"] == 1
+    # the wait is outside the tick's wall, and slept
+    assert woke["tick_s"] < woke["wait_s"]
+    assert 0.0 <= woke["cpu_s"] < 0.2
+    for t in ring:
+        assert 0.0 <= t["cpu_s"] <= t["tick_s"] + t["wait_s"] + 0.05
+        assert t["proc_cpu_s"] >= 0.0
+
+
+def test_a_tick_that_finds_its_chunk_finished_is_starved(engine):
+    width = engine._gather_width
+
+    def finish_the_chunk_in_flight():
+        if engine._pending_toks is not None:
+            jax.block_until_ready(engine._pending_toks[0])
+        return width()
+
+    engine._gather_width = finish_the_chunk_in_flight
+    _serve(engine, 2, new=8)
+    ring = engine.stats()["tick_ring"]
+    starved = [(a, b) for a, b in zip(ring, ring[1:]) if b["starved"]]
+    assert len(starved) >= 3
+    for before, t in starved:
+        assert t["device_wait_s"] < llm_engine.STARVED_WAIT_S
+        # from the read before to this tick's first program: the
+        # harvest (and bookkeeping) of the tick before, this tick's
+        # plan, its packing; the wait is no part of it
+        assert t["gap_harvest_host_s"] >= before["harvest_host_s"]
+        assert t["host_gap_s"] >= t["gap_harvest_host_s"] + t["plan_s"]
+        assert t["host_gap_s"] <= (before["tick_s"] + t["tick_s"]
+                                   - t["device_wait_s"])
+    # a tick that dispatched nothing left the device nothing to idle for
+    assert all(not t["starved"] for t in ring if not t["row_steps"]
+               and not t.get("prefill_calls"))
+
+
+def test_a_tick_that_waits_for_its_chunk_is_not_starved(engine, reads):
+    reads.delay = 0.01
+    _serve(engine, 2, new=8)
+    ring = engine.stats()["tick_ring"]
+    waited = [t for t in ring if t["device_wait_s"]]
+    assert len(waited) >= 3
+    assert all(not t["starved"] and "host_gap_s" not in t for t in waited)
+    row = _account_total(engine.stats())
+    assert row["starved"] == row["host_gap_us"] == 0
+
+
+def _account_total(stats):
+    """The account's columns summed over its seconds."""
+    acct = stats["tick_account"]
+    return dict(zip(acct["fields"], (sum(c) for c in zip(*acct["rows"]))))
+
+
+def _summed(records):
+    """What the account holds of `records`: a column a tick field."""
+    out = dict.fromkeys(llm_engine.ACCOUNT_FIELDS, 0)
+    for t in records:
+        out["ticks"] += 1
+        for col in llm_engine.ACCOUNT_FIELDS[2:]:
+            key = col[:-3] + "_s" if col.endswith("_us") else col
+            scale = 1e6 if col.endswith("_us") else 1
+            out[col] += t.get(key, 0) * scale
+        if "host_gap_s" in t:
+            out["gap_plan_us"] += t["plan_s"] * 1e6
+    return out
+
+
+def test_the_account_is_the_ring_summed_by_the_second(model, monkeypatch):
+    monkeypatch.setenv("RT_ENGINE_TICK_RING", "4096")
+    eng = LlamaEngine(*model, slots=2, max_len=48, chunk=2, block_size=8)
+    try:
+        t_end = time.time() + 30  # far more than it takes
+        n = 0
+        # until ticks began in three wall seconds (a loaded host may
+        # spend a whole second inside one compiling tick)
+        while (len(eng.stats()["tick_account"]["rows"]) < 3
+               and time.time() < t_end):
+            _serve(eng, 2, first=n)
+            n += 2
+        s = eng.stats()
+    finally:
+        eng.shutdown()
+    fields, rows = s["tick_account"]["fields"], s["tick_account"]["rows"]
+    assert tuple(fields) == llm_engine.ACCOUNT_FIELDS
+    secs = [r[0] for r in rows]
+    assert secs == sorted(set(secs)) and 3 <= len(secs) <= 4
+    assert sum(r[1] for r in rows) == len(s["tick_ring"]) == s["ticks"]
+    for r in rows:
+        got = dict(zip(fields, r))
+        want = _summed([t for t in s["tick_ring"]
+                        if int(t["t_wall"]) == got["sec"]])
+        for col in fields[1:]:
+            assert got[col] == pytest.approx(want[col], abs=1.0), col
+        assert all(isinstance(v, int) for v in r)
+    total = _account_total(s)
+    assert total["prefill_rows"] == s["prefill_rows"] == n
+    assert total["prefill_tokens"] == s["prefill_tokens"]
+    assert total["prefill_padded_tokens"] == s["prefill_padded_tokens"]
+    assert 0 < total["row_steps_live"] < total["row_steps"]
+    # the pickled account is what the planes ship as JSON
+    assert json.loads(json.dumps(s["tick_account"]))["rows"][0] == list(rows[0])
+
+
+def test_the_account_keeps_the_last_96_seconds_that_had_a_tick(engine):
+    tick = {"tick_s": 0.25, "plan_s": 0.01, "device_wait_s": 0.2,
+            "row_steps": 4, "starved": True, "host_gap_s": 0.03,
+            "gap_harvest_host_s": 0.01}
+    with engine._lock:
+        for sec in range(1000, 1400, 2):  # 200 seconds, two ticks each
+            for _ in range(2):
+                engine._account_add(dict(tick, t_wall=sec + 0.5))
+    acct = engine.stats()["tick_account"]
+    rows = [dict(zip(acct["fields"], r)) for r in acct["rows"]]
+    # whatever the tick rate: seconds, not ticks, bound it
+    assert [r["sec"] for r in rows] == list(range(1208, 1400, 2))
+    assert all((r["ticks"], r["tick_us"], r["device_wait_us"], r["starved"],
+                r["host_gap_us"], r["gap_harvest_host_us"],
+                r["gap_plan_us"], r["row_steps"], r["wait_us"])
+               == (2, 500000, 400000, 2, 60000, 20000, 20000, 8, 0)
+               for r in rows)
+
+
+def test_a_stalled_tick_is_kept_whole_with_its_neighbours(
+        engine, reads, monkeypatch):
+    monkeypatch.setattr(llm_engine, "STALL_MIN_S", 0.05)
+    # compiled, and ticks enough for the EMA to forget the compiling
+    _serve(engine, 12, new=9)
+    assert engine.stats()["stalls"] == []
+    assert engine.stats()["tick_ema_s"] < 0.01
+    reads.once = 0.4  # the next read: of the chunk left in flight
+    engine.submit(_prompt(99), 9).result(timeout=60)  # no prefix hit
+    s = engine.stats()
+    (stall,) = s["stalls"]
+    tick = stall["tick"]
+    assert tick["stalled"] and tick["device_wait_s"] >= 0.4
+    assert tick["tick_s"] >= 0.4 and not tick["starved"]
+    # slept, not computed: the CPU time says which
+    assert tick["cpu_s"] < 0.2
+    assert stall["before"]["seq"] == tick["seq"] - 1
+    assert stall["after"]["seq"] == tick["seq"] + 1
+    assert "stalled" not in stall["before"] and "stalled" not in stall["after"]
+    # the chunk it waited for, and what it had just launched itself
+    assert [n.rstrip("0123456789") for n in stall["in_flight"]] == [
+        "decode_chunk_w", "prefill_packed_n", "decode_chunk_w"]
+    by_seq = {t["seq"]: t for t in s["tick_ring"]}
+    assert by_seq[tick["seq"]] == tick  # the ring holds the same record
+    assert _account_total(s)["stalled"] == 1
+
+
+def test_a_tick_that_compiles_is_no_stall(engine, monkeypatch):
+    monkeypatch.setattr(llm_engine, "STALL_MIN_S", 0.0)
+    monkeypatch.setattr(llm_engine, "STALL_FACTOR", 1e-9)
+    # every tick is "long" now; the first ticks of an engine compile the
+    # programs they launch, and a later prompt of another width its own
+    _serve(engine, 1)
+    engine.submit(_prompt(1, 30), 10).result(timeout=60)
+    s = engine.stats()
+    compiled = [t for t in s["tick_ring"] if t.get("compiles")]
+    assert len(compiled) >= 2
+    assert all("stalled" not in t for t in compiled)
+    kept = [st["tick"]["seq"] for st in s["stalls"]]
+    assert kept and not set(kept) & {t["seq"] for t in compiled}
+    assert len(s["stalls"]) <= llm_engine.STALLS_KEPT
+
+
+def test_a_tick_that_prefilled_says_what_it_added(engine):
+    _serve(engine, 5)
+    s = engine.stats()
+    for key in ("prefill_calls", "prefill_rows", "prefill_tokens",
+                "prefill_padded_tokens"):
+        assert sum(t.get(key, 0) for t in s["tick_ring"]) == s[key] > 0
+    for t in s["tick_ring"]:
+        assert ("prefill_calls" in t) == bool(t["prefill_s"])
+        assert t.get("prefill_rows", 0) == t["admitted"]
+
+
+# ----------------------------------------------------------------------
 # B. the engine-loop spans, under a profiler session
 # ----------------------------------------------------------------------
 def _host_spans(trace_dir):
@@ -243,8 +498,9 @@ def test_a_profiler_session_records_the_loops_spans_nested(engine, tmp_path):
     by = {}
     for s in spans:
         by.setdefault(s[0], []).append(s)
-    assert {"engine.tick", "engine.admit", "engine.prefill",
-            "engine.dispatch", "engine.harvest"} <= set(by)
+    assert {"engine.tick", "engine.admit", "engine.plan", "engine.prefill",
+            "engine.dispatch", "engine.harvest", "engine.device_wait",
+            "engine.harvest_host"} <= set(by)
     ticks = by["engine.tick"]
     for _, _, _, st in ticks:
         assert {"seq", "active", "admitted", "wall_ns"} <= set(st)
@@ -271,8 +527,35 @@ def test_a_profiler_session_records_the_loops_spans_nested(engine, tmp_path):
         whole = [s for s in by[name] if lo <= s[1] and s[2] <= hi]
         assert whole and all(inside(s, ticks) for s in whole), name
     assert all(inside(s, by["engine.admit"]) for s in by["engine.prefill"])
+    # the two halves of admission and of the harvest, each in its whole
+    for inner, outer in (("engine.plan", "engine.admit"),
+                         ("engine.device_wait", "engine.harvest"),
+                         ("engine.harvest_host", "engine.harvest")):
+        whole = [s for s in by[inner] if lo <= s[1] and s[2] <= hi]
+        assert whole and all(inside(s, by[outer]) for s in whole), inner
+    for admit in by["engine.admit"]:  # the plan first, then the programs
+        plans = [s for s in by["engine.plan"] if inside(s, [admit])]
+        packs = [s for s in by["engine.prefill"] if inside(s, [admit])]
+        assert all(s[2] <= p[1] for s in plans for p in packs)
     # blocked on the wake-up: outside every tick
     assert not any(inside(s, ticks) for s in by.get("engine.wait", []))
+    # one stamp, two surfaces: a recorded tick is its ring record (the
+    # anchor `wall_ns` is the record's `t_wall`), and what its spans
+    # measure is what its fields hold
+    ring = {t["t_wall"]: t for t in engine.stats()["tick_ring"]}
+    matched = 0
+    for _, a, b, st in ticks:
+        rec = ring.get(st["wall_ns"] * 1e-9)
+        if rec is None:
+            continue
+        matched += 1
+        for phase in ("plan", "prefill", "dispatch", "device_wait",
+                      "harvest_host"):
+            spanned = sum(s[2] - s[1] for s in by.get("engine." + phase, [])
+                          if a <= s[1] and s[2] <= b) * 1e-9
+            assert rec[phase + "_s"] <= spanned + 1e-6, phase
+            assert spanned - rec[phase + "_s"] < 5e-4, phase
+    assert matched >= 3
 
 
 # ----------------------------------------------------------------------
