@@ -30,13 +30,16 @@ What the serve engine needs of a model, and nothing else:
   end to end, each from a multiple of `chunk`), a chunked scan that
   leaves each prompt's state in its slot of the cache;
 - `decode_step`: one token for every live row off its slot's state, the
-  state updated in place; a dead row's state is untouched.
+  state updated in place; a dead row's state is untouched;
+- `chunk_step`: the same as one step of a CHUNK whose state is written
+  once: the steps before the last READ the state and hold their keys
+  and values beside it, the last folds them in (`ops/retention.py`).
 
 The cache is two leaves a model, per SLOT and not per token
 (`retention.state_shapes`): nothing grows with the context.
 `jax.named_scope`s `retention_attn` and `dense_mlp` mark the two halves
-in a device trace; inside the first the kernels are `retention_prefill`
-and `retention_decode`.
+in a device trace; inside the first the kernels are `retention_prefill`,
+`retention_read` and `retention_decode`.
 """
 
 from __future__ import annotations
@@ -220,6 +223,17 @@ def forward(cfg: BrumbyConfig, params: Dict, tokens: jax.Array,
 # ----------------------------------------------------------------------
 # decode: one step off the state
 # ----------------------------------------------------------------------
+def init_pending(cfg: BrumbyConfig, slots: int, held: int) -> _ret.Pending:
+    """An empty `retention.Pending` for every layer (each leaf `[L,
+    ...]`, `n` too): room for `held` tokens a row, a decode chunk's
+    steps but its last."""
+    k, v, G = _ret.pending_shapes(cfg.n_layers, slots, cfg.n_kv_heads,
+                                  cfg.head_dim, held)
+    return _ret.Pending(jnp.zeros(k, cfg.dtype), jnp.zeros(v, cfg.dtype),
+                        jnp.zeros(G, F32),
+                        jnp.zeros((cfg.n_layers,), jnp.int32))
+
+
 def decode_step(cfg: BrumbyConfig, params: Dict, token: jax.Array, cache,
                 pos, *, live=None, kernel: bool = False,
                 interpret: bool = False):
@@ -228,25 +242,50 @@ def decode_step(cfg: BrumbyConfig, params: Dict, token: jax.Array, cache,
     (`state`, `keysum`) with row b's state in slot b.  `live` [B] bool
     (the engine's `pos < stop`; None: every row): a row that is not
     live leaves its state as it was and yields zeros for attention.
-    Returns (logits [B, vocab] float32, cache)."""
+    Returns (logits [B, vocab] float32, cache).  It is `chunk_step` as
+    a chunk of one: nothing held, the state written at once."""
+    logits, cache, _ = chunk_step(
+        cfg, params, token, cache, init_pending(cfg, token.shape[0], 0), pos,
+        last=True, live=live, kernel=kernel, interpret=interpret)
+    return logits, cache
+
+
+def chunk_step(cfg: BrumbyConfig, params: Dict, token: jax.Array, cache,
+               pending: _ret.Pending, pos, *, last: bool, live=None,
+               kernel: bool = False, interpret: bool = False):
+    """One step of a decode CHUNK whose state is written once, at its
+    last step; the arguments are `decode_step`'s, and `pending`
+    (`init_pending`) holds the chunk's tokens so far.  `last` False:
+    the state is only READ, and the token's keys, values and gates are
+    held in `pending`.  `last` True: the state takes everything held
+    and this token, once (the flush), and `pending` comes back empty.
+    Returns (logits, cache, pending)."""
     B = token.shape[0]
     if live is None:
         live = jnp.ones((B,), bool)
     x = _embed(params, token, cfg.dtype)[:, None, :].astype(cfg.dtype)
+    kw = dict(eps=cfg.retention_eps, kernel=kernel, interpret=interpret)
 
     def body(carry, inputs):
         x, state, keysum = carry
-        li, layer = inputs
+        li, layer, held = inputs        # `held`: this layer's `Pending`
         with jax.named_scope("retention_attn"):
             q, k, v, g = _qkvg(
                 cfg, layer, x, lambda t: _rope_at(t, cfg.rope_theta, pos))
-            o, state, keysum = _ret.retention_decode(
-                q[:, 0], k[:, 0], v[:, 0], g[:, 0], state, keysum, live, li,
-                eps=cfg.retention_eps, kernel=kernel, interpret=interpret)
-        return (_out(cfg, layer, x, o[:, None]), state, keysum), None
+            if last:
+                o, state, keysum = _ret.retention_decode(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], state, keysum, live,
+                    li, pending=held, **kw)
+                held = held._replace(n=jnp.zeros_like(held.n))
+            else:
+                o, held = _ret.retention_read(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], state, keysum, held,
+                    live, li, **kw)
+        return (_out(cfg, layer, x, o[:, None]), state, keysum), held
 
-    (x, *cache), _ = lax.scan(
+    (x, *cache), pending = lax.scan(
         body, (x, *cache),
-        (jnp.arange(cfg.n_layers, dtype=jnp.int32), dict(params["blocks"])))
+        (jnp.arange(cfg.n_layers, dtype=jnp.int32), dict(params["blocks"]),
+         pending))
     x = _rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
-    return _lm_head(x[:, 0, :], params, cfg.dtype), tuple(cache)
+    return _lm_head(x[:, 0, :], params, cfg.dtype), tuple(cache), pending

@@ -220,7 +220,7 @@ class SlotState:
 
 
 def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
-                  aux=None, paged: bool = True):
+                  aux=None, paged: bool = True, last_step=None):
     """THE decode chunk, `(params, *cache, tables, tok, pos, stop) ->
     (*cache, tok, pos, toks)`: `chunk` greedy steps in one `lax.scan`.
     `paged` False (a cache with no paged leaf): the same without
@@ -237,7 +237,12 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
     per-slot leaves, which lie behind the paged ones, go to the step as
     they are either way.
     `aux(stats)`: the model's `aux_rows` counters, `[aux_rows]`, from
-    the steps' stacked stats."""
+    the steps' stacked stats.
+    `last_step` (the signature of `step`): a model whose chunk ENDS in
+    a step of another kind (one that writes what the others only held:
+    `RetentionEngineModel`) names it, and the chunk is `chunk - 1`
+    scanned `step`s and then `last_step` once, as `gather` brackets the
+    scan with its view and its write.  None: ONE scan of `chunk`."""
     def _fn(params, *flat):
         if paged:
             *pool, tables, tok, pos, stop = flat
@@ -248,7 +253,7 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
             n = len(gather.leaves)
             cache = (*gather.rows(pool[:n], tables), *pool[n:])
 
-        def body(carry, _):
+        def body(carry, _, step=step):
             tok, cache, pos = carry
             # a row owes a token while it is short of its stop; a dead
             # row stays where it is
@@ -262,7 +267,13 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
         # needs its own device->host read (one round trip PER REQUEST)
         tok_in, pos_in = tok, pos
         (tok, cache, pos), (toks, stats) = jax.lax.scan(
-            body, (tok, cache, pos), None, length=chunk)
+            body, (tok, cache, pos), None,
+            length=chunk if last_step is None else chunk - 1)
+        if last_step is not None:
+            (tok, cache, pos), ended = body((tok, cache, pos), None, last_step)
+            toks, stats = jax.tree.map(
+                lambda xs, x: jnp.concatenate([xs, x[None]]),
+                (toks, stats), ended)
         if gather is not None:
             cache = (*gather.write(pool[:n], tables, cache[:gather.n_rows],
                                    span=(pos_in, pos)),
@@ -344,9 +355,12 @@ class _EngineModel:
     bodies: `cache_leaves` (the paged leaves of `kv`, then the per-slot
     leaves of `state`; either may be None), `n_layers`, `kv_dtype`,
     `segmented` (a packed prefill may hold several prompts), `aux_rows`
-    and `tick_fields` (the model's own per-tick counters; none here)."""
+    and `tick_fields` (the model's own per-tick counters; none here),
+    `state_write_deferred` (a per-slot state is written at a chunk's
+    last step only, not at every step)."""
 
     aux_rows = 0
+    state_write_deferred = False
 
     def __init__(self, cfg, kv: Optional[PagedKV], *, chunk: int,
                  paged: bool, interpret: bool,
@@ -544,27 +558,53 @@ class RetentionEngineModel(_EngineModel):
     chunked scan over the packed row that leaves each prompt's state in
     its slot (`pack_align`: every prompt starts on a multiple of the
     scan's chunk, which is the engine's `block_size`); decode is one
-    state step a live row.  `suffix_prefill` and `kv_write` do not
+    state READ a live row and step, and one state write a live row and
+    chunk (`decode_chunk`).  `suffix_prefill` and `kv_write` do not
     exist: there is no cached prefix to prefill behind.  `paged`: the
     Pallas kernels (TPU); else the same algorithms in plain XLA."""
 
     def __init__(self, cfg, state: SlotState, *, pack_align: int, **route):
         super().__init__(cfg, None, state=state, **route)
         self.pack_align = pack_align
+        self.state_write_deferred = self.chunk > 1
 
     def _kw(self):
         return dict(kernel=self._paged and not self._interpret,
                     interpret=self._interpret)
 
     def decode_chunk(self, W: int):
-        cfg, kw = self.cfg, self._kw()
+        """A chunk's steps READ each live row's state and hold the
+        token's keys and values beside it (`retention.Pending`, made
+        empty inside the program: it is no leaf of the cache); its LAST
+        step folds them in and writes the state, once a chunk where a
+        step a token would write it `chunk` times.  So between programs
+        the two leaves are whole: prefill, harvest and any snapshot see
+        states with nothing owed.  A row that dies inside the chunk is
+        not flushed: its state is never read again (a prefill's first
+        chunk zeroes the slot).  `chunk` 1 has nothing to defer."""
+        cfg, kw, held = self.cfg, self._kw(), self.chunk - 1
 
-        def step(params, tok, cache, tables, pos, live):
-            logits, cache = brumby.decode_step(cfg, params, tok, cache, pos,
-                                               live=live, **kw)
-            return logits, cache, ()
+        def step_of(last):
+            def step(params, tok, cache, tables, pos, live):
+                state, keysum, pend = cache
+                logits, leaves, pend = brumby.chunk_step(
+                    cfg, params, tok, (state, keysum), pend, pos, last=last,
+                    live=live, **kw)
+                return logits, (*leaves, pend), ()
+            return step
 
-        return chunk_program(step, self.chunk, paged=False)
+        flush = step_of(True)
+        program = (chunk_program(step_of(False), self.chunk, paged=False,
+                                 last_step=flush)
+                   if held else chunk_program(flush, 1, paged=False))
+
+        def _fn(params, state, keysum, tok, pos, stop):
+            pend = brumby.init_pending(cfg, tok.shape[0], held)
+            state, keysum, _, *rows = program(params, state, keysum, pend,
+                                              tok, pos, stop)
+            return (state, keysum, *rows)
+
+        return _fn
 
     def prefill_packed(self, N: int):
         def forward(params, state, tokens, packed, slots):

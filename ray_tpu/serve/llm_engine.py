@@ -1290,7 +1290,7 @@ class LlamaEngine:
         toks = None
         # of the chunk's slots x chunk row-steps, those a request was
         # waiting for (its steps before its stop); the rest are dead
-        row_steps = row_steps_live = rows_live = 0
+        row_steps = row_steps_live = rows_live = rows_flushed = 0
         if W:
             with self._phase("dispatch", W=W):
                 tables = ()
@@ -1321,6 +1321,7 @@ class LlamaEngine:
                         end = min(req["pos_host"] + self.chunk, req["stop"])
                         row_steps_live += end - req["pos_host"]
                         rows_live += end > req["pos_host"]
+                        rows_flushed += end - req["pos_host"] == self.chunk
                         req["pos_host"] = end
         # OVERLAP: harvest the PREVIOUS chunk's tokens while the
         # current chunk computes — the device->host read is round-trip
@@ -1357,6 +1358,12 @@ class LlamaEngine:
             # rows that owed a token at the chunk's first step: for
             # per-slot leaves, the states its first step moves
             "state_rows_live": rows_live if self._has_state else 0,
+            # the rows whose per-slot state the chunk WROTE: a row at
+            # every step it was live, or, where the model defers the
+            # write to the chunk's last step, the rows live at that one
+            "state_rows_flushed": 0 if not self._has_state else (
+                rows_flushed if self._model.state_write_deferred
+                else row_steps_live),
             "row_steps_live": row_steps_live,
             "row_steps": row_steps,
             # the model's own counters of the chunk harvested in this

@@ -44,6 +44,29 @@ def _sanitize(request):
         sanitizer.set_enabled(False)
 
 
+# a process may hold 65,530 memory mappings (`vm.max_map_count`), and
+# every program XLA compiles for the CPU keeps some: an xdist worker that
+# runs two long JAX files in a row (`test_paged_attention.py`, then
+# `test_lfm2.py`, which reaches 61,000 alone) ran out, and XLA's next
+# compile died of a segmentation fault.  Past this many, a test's
+# teardown drops JAX's compiled programs; the next test compiles its own.
+_MAPS_HIGH = 30_000
+
+
+@pytest.fixture(autouse=True)
+def _compiled_programs_stay_under_the_mapping_limit():
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            maps = sum(1 for _ in f)
+    except OSError:
+        return
+    if maps > _MAPS_HIGH:
+        import jax
+
+        jax.clear_caches()
+
+
 @pytest.fixture
 def rt_start():
     """Start a fresh single-node runtime for a test, shut down after."""

@@ -425,26 +425,62 @@ def _state_leaves(d=_BRUMBY):
             retention.state_shapes(d["L"], d["B"], d["KV"], d["d"])]
 
 
-def test_retention_decode_kernel(chip):
+def _held(d=_BRUMBY, held=7):
+    """A layer's `retention.Pending` at the cell's chunk of 8."""
+    from ray_tpu.ops import retention
+
+    kv = _s(d["B"], d["KV"], held, d["d"])
+    return retention.Pending(kv, kv, _s(d["B"], d["KV"], held,
+                                        dtype=jnp.float32),
+                             _s(dtype=jnp.int32))
+
+
+def _step_shapes(d=_BRUMBY):
+    return (_s(d["B"], d["H"], d["d"]), _s(d["B"], d["KV"], d["d"]),
+            _s(d["B"], d["KV"], d["d"]), _s(d["B"], d["KV"], dtype=jnp.float32))
+
+
+@pytest.mark.parametrize("flush", [False, True], ids=["step", "flush"])
+def test_retention_decode_kernel(chip, flush):
     """One `[65, 128, 128]` float32 state block a grid step (4.26 MB,
     two copies each way under a 64 MB VMEM limit), the state and the
     key sum aliased in place; the benchmark finds the kernel by its
-    first result, the numerators `f32[32,8,128,128]`."""
+    first result, the numerators `f32[32,8,128,128]`.  `flush`: the
+    chunk's last step, 8 updates a block (7 held tokens and its own),
+    the same results."""
     from ray_tpu.ops import retention
 
-    d = _BRUMBY
-
-    def step(q, k, v, g, state, keysum, live, layer):
+    def step(q, k, v, g, state, keysum, live, layer, pending):
         return retention.retention_decode(q, k, v, g, state, keysum, live,
-                                          layer, eps=1e-6, kernel=True)
+                                          layer, eps=1e-6, kernel=True,
+                                          pending=pending)
 
     hlo = _compile(
-        chip, step, _s(d["B"], d["H"], d["d"]), _s(d["B"], d["KV"], d["d"]),
-        _s(d["B"], d["KV"], d["d"]), _s(d["B"], d["KV"], dtype=jnp.float32),
-        *_state_leaves(), _s(d["B"], dtype=jnp.bool_), _s(dtype=jnp.int32),
-        donate_argnums=(4, 5))
+        chip, step, *_step_shapes(), *_state_leaves(),
+        _s(_BRUMBY["B"], dtype=jnp.bool_), _s(dtype=jnp.int32),
+        _held() if flush else None, donate_argnums=(4, 5))
     assert "tpu_custom_call" in hlo and "(f32[32,8,128,128]" in hlo
     assert "input_output_alias" in hlo
+
+
+def test_retention_read_kernel(chip):
+    """The step that writes no state: the state block and the key sum
+    are inputs only (one copy in, none out), and its FIRST result is
+    the denominators `f32[32,8,8,128]`, so a trace tells it from the
+    flush, whose first result the benchmark's roofline reader looks
+    for."""
+    from ray_tpu.ops import retention
+
+    def step(q, k, v, g, state, keysum, pending, live, layer):
+        return retention.retention_read(q, k, v, g, state, keysum, pending,
+                                        live, layer, eps=1e-6, kernel=True)
+
+    hlo = _compile(
+        chip, step, *_step_shapes(), *_state_leaves(), _held(),
+        _s(_BRUMBY["B"], dtype=jnp.bool_), _s(dtype=jnp.int32))
+    assert "tpu_custom_call" in hlo and "(f32[32,8,8,128]" in hlo
+    assert "(f32[32,8,128,128]" not in hlo
+    assert "f32[5,32,8,65,128,128]" in hlo and "input_output_alias" not in hlo
 
 
 @pytest.mark.parametrize("N", [256, 2560])
@@ -497,12 +533,57 @@ def test_state_model_programs_at_the_cells_shapes(chip):
     hlo = _compile(chip, fn, params, *cache, *rows, **donate)
     assert "jit_decode_chunk_state" in hlo and "(f32[32,8,128,128]" in hlo
     assert "input_output_alias" in hlo
+    # seven reads and one flush a chunk: both calls, and no step COPIES
+    # a state leaf (5.5 GB) on its way through the two loops
+    assert "(f32[32,8,8,128]" in hlo
+    assert not [line for line in hlo.splitlines()
+                if " copy(" in line
+                and line.split("=", 1)[1].lstrip().startswith(
+                    "f32[5,32,8,65,128,128]")]
     fn = model.prefill_packed(2048)
     fn.__name__ = "prefill_packed_n2048"
     hlo = _compile(chip, fn, params, *cache, *[_s(2048, dtype=i32)] * 3,
                    *[_s(16, dtype=i32)] * 4, *rows, **donate)
     assert "jit_prefill_packed_n2048" in hlo and "(bf16[8,5,2048,128]" in hlo
     assert "input_output_alias" in hlo and "(f32[32,8,128,128]" not in hlo
+
+
+@pytest.mark.parametrize("name, config", [
+    ("llama", "LlamaConfig"), ("deepseek_v3", "DeepseekV3Config"),
+    ("lfm2", "Lfm2MoeConfig"), ("brumby", "BrumbyConfig")])
+def test_a_model_that_names_no_last_step_traces_one_scan(name, config):
+    """`chunk_program(last_step=None)` is ONE scan of `chunk` steps and
+    nothing after it, the program every model but the retention model
+    had before that argument (the dense model, the latent-MoE model,
+    the hybrid whose per-slot leaves ride the same carry); the retention
+    model's is a scan of `chunk - 1` reads, then the flush's layers
+    once.  (Traced, not compiled: no chip is described.)"""
+    import importlib
+
+    from ray_tpu.serve.engine_model import engine_model_for
+
+    mod = importlib.import_module(f"ray_tpu.models.{name}")
+    cfg = getattr(mod, config).tiny()
+    params = jax.eval_shape(
+        lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
+    model = engine_model_for(cfg, kv_dtype="model", block_size=8, chunk=4,
+                             paged=False, interpret=False)
+    slots = 2
+    cache = [_s(l.layers or cfg.n_layers,
+                *((slots,) if l.per_slot else (3, 8)), *l.tail, dtype=l.dtype)
+             for l in model.cache_leaves]
+    tables = [_s(slots, 1, dtype=jnp.int32)] if model.kv is not None else []
+    rows = [_s(slots, dtype=jnp.int32)] * 3
+    jaxpr = jax.make_jaxpr(model.decode_chunk(1))(
+        params, *cache, *tables, *rows)
+    loops = [e.params["length"] for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "scan"]
+    if name == "brumby":
+        # three reads scanned, then the flush's scan over the layers
+        assert loops == [3, cfg.n_layers]
+    else:
+        # the chunk's scan alone: the layers' scans lie inside its body
+        assert loops == [4]
 
 
 # ----------------------------------------------------------------------

@@ -181,6 +181,114 @@ def test_dead_rows_leave_their_state_untouched(route):
 
 
 # ----------------------------------------------------------------------
+# a chunk whose state is written once: reads, then the flush
+# ----------------------------------------------------------------------
+def _warm_leaves(steps=3):
+    """Leaves a few single steps of every row have filled (layer 1),
+    so that a read-out has a denominator; layer 0 keeps its 7s."""
+    state, keysum = _leaves(7.0)
+    state, keysum = state.at[1].set(0.0), keysum.at[1].set(0.0)
+    for t in range(steps):
+        _, state, keysum = R.retention_decode(
+            *_step_args(100 + 10 * t), state, keysum, jnp.ones((SLOTS,), bool),
+            1, eps=EPS)
+    return state, keysum
+
+
+def _step_args(seed):
+    # q and k share an offset, as in the recurrence's test
+    return (_rand(seed, SLOTS, H, D) + 1.0, _rand(seed + 1, SLOTS, KV, D) + 1.0,
+            _rand(seed + 2, SLOTS, KV, D), _gates(seed + 3, SLOTS, KV))
+
+
+def _empty(held):
+    k, v, G = R.pending_shapes(1, SLOTS, KV, D, held)
+    return R.Pending(jnp.zeros(k[1:]), jnp.zeros(v[1:]), jnp.zeros(G[1:]),
+                     jnp.zeros((), jnp.int32))
+
+
+@pytest.mark.parametrize("chunk", [2, 8])
+@pytest.mark.parametrize("route", ROUTES, ids=IDS)
+def test_a_deferred_chunk_equals_single_steps(route, chunk):
+    """`chunk - 1` reads and the flush against `chunk` single steps:
+    every live `o_j`, and the state and key sum after the flush, at the
+    float32 tolerance of `test_kernels_equal_their_xla_bodies` (1e-5,
+    of outputs and states of order 1: the same function, the decays
+    multiplied in another order).  Row 1 DIES inside the chunk and row
+    2 is dead throughout: neither is flushed, both keep their BYTES
+    (the state of a row that died is never read again); a read step
+    has no state among its results, so every read sees the leaves the
+    chunk found."""
+    state0, keysum0 = _warm_leaves()
+    dies = (chunk - 1) // 2                 # row 1's last live step
+    lives = [jnp.asarray([True, j <= dies, False, True])
+             for j in range(chunk)]
+    args = [_step_args(200 + 10 * j) for j in range(chunk)]
+    want, (st, zs) = [], (state0, keysum0)
+    for a, live in zip(args, lives):
+        o, st, zs = R.retention_decode(*a, st, zs, live, 1, eps=EPS)
+        want.append(np.asarray(o))
+    pend = _empty(chunk - 1)
+    for j in range(chunk - 1):
+        o, pend = R.retention_read(*args[j], state0, keysum0, pend, lives[j],
+                                   1, eps=EPS, **route)
+        np.testing.assert_allclose(np.asarray(o), want[j], atol=1e-5)
+        assert int(pend.n) == j + 1
+    o, state, keysum = R.retention_decode(
+        *args[-1], state0, keysum0, lives[-1], 1, eps=EPS, pending=pend,
+        **route)
+    np.testing.assert_allclose(np.asarray(o), want[-1], atol=1e-5)
+    assert float(np.abs(want[-1][0]).max()) > 0.01
+    for row in (0, 3):
+        np.testing.assert_allclose(np.asarray(state[1, row]),
+                                   np.asarray(st[1, row]), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(keysum[1, row]),
+                                   np.asarray(zs[1, row]), atol=1e-5)
+    for mine, before in ((state, state0), (keysum, keysum0)):
+        for dead in (1, 2):
+            np.testing.assert_array_equal(np.asarray(mine[:, dead]),
+                                          np.asarray(before[:, dead]))
+        np.testing.assert_array_equal(np.asarray(mine[0]),
+                                      np.asarray(before[0]))
+    assert float(jnp.abs(o[1]).max()) == 0.0 == float(jnp.abs(o[2]).max())
+
+
+@pytest.mark.parametrize("route", ROUTES, ids=IDS)
+def test_a_flush_with_nothing_held_is_the_single_step(route):
+    """The flush whose `pending` holds nothing (whatever lies in its
+    places) is the single step: a place that is not held adds exact
+    zeros, BIT FOR BIT in plain XLA.  With no `pending` the kernel IS
+    the single step's, operand for operand.  And a read's first result
+    is not the shape the benchmark tells the flush by (the
+    numerators)."""
+    state0, keysum0 = _warm_leaves()
+    live = jnp.asarray([True, False, True, True])
+    args = _step_args(300)
+    junk = R.Pending(_rand(1, SLOTS, KV, 7, D), _rand(2, SLOTS, KV, 7, D),
+                     -jnp.abs(_rand(3, SLOTS, KV, 7)), jnp.zeros((), jnp.int32))
+    one = R.retention_decode(*args, state0, keysum0, live, 1, eps=EPS, **route)
+    got = R.retention_decode(*args, state0, keysum0, live, 1, eps=EPS,
+                             pending=junk, **route)
+    # (the interpreter's CPU program fuses a product into a sum where
+    # it can, and not the same ones in both kernels: an ulp)
+    for a, b in zip(one, got):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=0,
+                                   atol=2e-6 if route else 0)
+    # with no `pending` at all the kernel is the single step's: one key
+    # row a kv head, the value spread over the lanes
+    step = jax.make_jaxpr(lambda *a: R.retention_decode(
+        *a, state0, keysum0, live, 1, eps=EPS, interpret=True))(*args)
+    (call,) = [e for e in step.jaxpr.eqns if "pallas" in e.primitive.name]
+    assert [v.aval.shape for v in call.invars[3:7]] == [
+        (SLOTS, KV, 8, D), (SLOTS, KV, D), (SLOTS, KV, D), (SLOTS, KV, D, D)]
+    read = jax.make_jaxpr(lambda *a: R.retention_read(
+        *a, state0, keysum0, junk, live, 1, eps=EPS, interpret=True))(*args)
+    (call,) = [e for e in read.jaxpr.eqns if "pallas" in e.primitive.name]
+    assert [v.aval.shape for v in call.outvars] == [
+        (SLOTS, KV, 8, D), (SLOTS, KV, D, D)]
+
+
+# ----------------------------------------------------------------------
 # the model and the engine
 # ----------------------------------------------------------------------
 def _model(seed=0):
@@ -252,31 +360,40 @@ def test_decode_continues_the_prefill():
     assert float(jnp.std(want)) > 0.3
 
 
+@pytest.mark.parametrize("chunk,n_new", [(2, 6), (8, 12)])
 @pytest.mark.parametrize("kw", [{}, {"decode_kernel": "pallas",
                                      "kernel_interpret": True}], ids=IDS)
-def test_engine_end_to_end_through_submit(kw):
+def test_engine_end_to_end_through_submit(kw, chunk, n_new):
     """Five requests on three slots through `submit`: the greedy tokens
-    are a loop over `forward`'s (greedy = the same argmax; float32), the
-    cache is per slot (no bytes a token, no blocks taken), admission
-    packed several prompts a program, and the programs have no tables."""
+    are `forward`'s (every answer fed back through it: each token is
+    the argmax at the position before it; float32), the cache is per
+    slot (no bytes a token, no blocks taken), admission packed several
+    prompts a program, and the programs have no tables.  A chunk's
+    state is written at its LAST step only: every answer runs through
+    whole chunks (flushed) and ends inside one (its row dies there,
+    unflushed, and the slot's next prompt starts from zero), and after
+    any program the cache is the two whole leaves, nothing pending;
+    `state_rows_flushed` counts a request's whole chunks."""
     cfg, params = _model()
-    eng = LlamaEngine(cfg, params, slots=3, chunk=2, block_size=C,
+    eng = LlamaEngine(cfg, params, slots=3, chunk=chunk, block_size=C,
                       max_len=48, **kw)
     try:
         prompts = _prompts(5, 13, 8, 20, 3)
         outs = [f.result(timeout=300)
-                for f in [eng.submit(p, 6) for p in prompts]]
+                for f in [eng.submit(p, n_new) for p in prompts]]
         st = eng.stats()
+        leaves = [c.shape for c in eng._cache]
     finally:
         eng.shutdown()
     for p, out in zip(prompts, outs):
-        toks = list(p)
-        for _ in range(6):
-            lg, _ = brumby.forward(cfg, params, jnp.asarray([toks]), chunk=C)
-            toks.append(int(jnp.argmax(lg[0, -1])))
-        assert out == toks[len(p):]
+        assert len(out) == n_new
+        lg, _ = brumby.forward(cfg, params, jnp.asarray([p + out[:-1]]),
+                               chunk=C)
+        assert out == np.argmax(lg[0, len(p) - 1:], axis=-1).tolist()
     state, keysum = R.state_shapes(cfg.n_layers, 1, cfg.n_kv_heads,
                                    cfg.head_dim)
+    assert leaves == list(R.state_shapes(cfg.n_layers, 3, cfg.n_kv_heads,
+                                         cfg.head_dim))
     assert st["cache_bytes_per_token"] == 0
     assert st["cache_bytes_per_slot"] == 4 * (np.prod(state) + np.prod(keysum))
     assert st["prefix_hit_tokens"] == 0 and st["blocks_free"] == st["blocks_total"]
@@ -285,6 +402,32 @@ def test_engine_end_to_end_through_submit(kw):
     ticks = [t for t in st["tick_ring"] if t["row_steps"]]
     assert ticks and all(t["gather_blocks"] == 0 for t in ticks)
     assert max(t["state_rows_live"] for t in ticks) == 3
+    # a request decodes `n_new - 1` steps from a chunk's first
+    assert sum(t["row_steps_live"] for t in ticks) == 5 * (n_new - 1)
+    assert sum(t["state_rows_flushed"] for t in ticks) == 5 * (
+        (n_new - 1) // chunk)
+
+
+def test_a_chunk_of_one_step_defers_nothing():
+    """`chunk` 1: the program is the single step's (no read call, no
+    pending), and the tick says a state write a live row-step."""
+    cfg, params = _model()
+    eng = LlamaEngine(cfg, params, slots=2, chunk=1, block_size=C,
+                      max_len=32, decode_kernel="pallas",
+                      kernel_interpret=True)
+    try:
+        (p,) = _prompts(9)
+        out = eng.submit(p, 4).result(timeout=300)
+        ticks = [t for t in eng.stats()["tick_ring"] if t["row_steps"]]
+        text = str(jax.make_jaxpr(eng._model.decode_chunk(0))(
+            params, *eng._cache, eng._tok, eng._pos, eng._stop))
+    finally:
+        eng.shutdown()
+    lg, _ = brumby.forward(cfg, params, jnp.asarray([p + out[:-1]]), chunk=C)
+    assert out == np.argmax(lg[0, len(p) - 1:], axis=-1).tolist()
+    assert "retention_decode" in text and "retention_read" not in text
+    assert sum(t["state_rows_flushed"] for t in ticks) == 3 == sum(
+        t["row_steps_live"] for t in ticks)
 
 
 def test_prefix_cache_is_refused_with_a_typed_error():
