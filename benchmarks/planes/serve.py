@@ -25,6 +25,12 @@ from benchmarks import loadgen
 from benchmarks.planes import _common
 
 APP, ROUTE = "bench", "/bench"
+# the engine loop's spans in a trace (`LlamaEngine._span`): the blocked
+# wait between ticks, a tick, and its phases, some inside others; an
+# idle gap of the device is named after the innermost
+ENGINE_SPANS = tuple("engine." + n for n in (
+    "wait", "tick", "admit", "plan", "prefill", "dispatch", "harvest",
+    "device_wait", "harvest_host"))
 
 
 def _next_pow2(n: int) -> int:
@@ -274,7 +280,8 @@ class BenchLlamaService:
             from benchmarks import trace_reduce
 
             out["trace"] = trace_reduce.reduce_dir(
-                self._trace["dir"], default_gap="engine loop, unattributed",
+                self._trace["dir"], annotations=ENGINE_SPANS,
+                default_gap="engine loop, unattributed",
                 kernels=kernel_predicates(self.cfg))
             keep = cmd.get("keep_trace_to")
             if keep:

@@ -234,7 +234,8 @@ class BenchHybridService(base.BenchLlamaService):
             from benchmarks import trace_reduce, trace_scopes
 
             out["trace"] = trace_reduce.reduce_dir(
-                self._trace["dir"], default_gap="engine loop, unattributed",
+                self._trace["dir"], annotations=base.ENGINE_SPANS,
+                default_gap="engine loop, unattributed",
                 kernels=kernel_predicates(self.cfg))
             out["trace"]["scopes"] = trace_scopes.scope_seconds(
                 self._trace["dir"], SCOPES, ("jit_decode_chunk_",))

@@ -29,21 +29,28 @@ from benchmarks.planes.serve import verdict  # noqa: F401  (the plane's)
 SCOPES = ("retention_attn", "dense_mlp")
 TICK_KEYS = ("seq", "admitted", "active", "queued", "live_tokens",
              "gather_blocks", "admit_s", "dispatch_s", "harvest_s")
-STATE_KEYS = ("t_wall", "state_rows_live", "row_steps_live", "row_steps")
+STATE_KEYS = ("t_wall", "state_rows_live", "state_rows_flushed",
+              "row_steps_live", "row_steps")
 CONTROLS = ("fp8", "state_bf16")
 
 
 def kernel_predicates(cfg: dict) -> dict:
     """How the trace prints this model's Pallas kernels (told apart by
-    what they return, as in `planes/serve.py`): the decode step's first
-    result is the numerators `f32[slots, kv_heads, head_dim, head_dim]`
-    (also under the label `paged_decode`, so that `decode_step_ms`
-    finds the decode programs of this cell as it stands); the chunked
-    prefill's is `bf16[kv_heads, group, N, head_dim]`."""
+    what they return, as in `planes/serve.py`): the step that WRITES
+    the state (a chunk's flush) gives the numerators first, `f32[slots,
+    kv_heads, head_dim, head_dim]` (also under the label
+    `paged_decode`: one call a layer and chunk program, so that
+    `decode_step_ms` finds the decode programs of this cell as it
+    stands); the step that only READS it gives the denominators first,
+    `f32[slots, kv_heads, 8, head_dim]` (the group's query heads padded
+    to a register's 8 rows); the chunked prefill's first result is
+    `bf16[kv_heads, group, N, head_dim]`."""
     m, e = cfg["model"], cfg["engine"]
     KV, d = m["num_key_value_heads"], m["head_dim"]
+    group = m["num_attention_heads"] // KV
     decode = f"(f32[{e['slots']},{KV},{d},{d}]"
-    prefill = f"(bf16[{KV},{m['num_attention_heads'] // KV},"
+    read = f"(f32[{e['slots']},{KV},{-(-group // 8) * 8},{d}]"
+    prefill = f"(bf16[{KV},{group},"
 
     def is_kernel(n):
         return "custom-call(" in n and "tpu_custom_call" in n
@@ -54,6 +61,7 @@ def kernel_predicates(cfg: dict) -> dict:
     return {
         "paged_decode": lambda n: gives(n, decode),
         "retention_decode": lambda n: gives(n, decode),
+        "retention_read": lambda n: gives(n, read),
         "retention_prefill": lambda n: gives(n, prefill),
     }
 
@@ -114,8 +122,12 @@ def round_bf16(x):
 def hold_state_in_bf16() -> None:
     """The second control: the state and the key sum rounded to
     bfloat16 wherever a kernel leaves them (a layer's slots after every
-    decode step and every prefill).  Patches the program's two entry
-    points IN THIS PROCESS; only `--control state_bf16` calls it."""
+    prefill and every flush, which is once a decode chunk where the
+    program writes once a chunk), and what the reading steps take in
+    beside them, the chunk's held log-gates, rounded as well: the state
+    they read is rounded already, nothing else writes it.  Patches the
+    program's three entry points IN THIS PROCESS; only `--control
+    state_bf16` calls it."""
     import jax
 
     from ray_tpu.ops import retention as ret
@@ -132,10 +144,17 @@ def hold_state_in_bf16() -> None:
             return o, one(state), one(keysum)
         return wrapped
 
+    def takes_rounded(fn):
+        def wrapped(q, k, v, g, state, keysum, pending, *rest, **kw):
+            return fn(q, k, v, g, state, keysum,
+                      pending._replace(G=round_bf16(pending.G)), *rest, **kw)
+        return wrapped
+
     # `layer` is the 8th positional argument of the one, the 10th of
     # the other (`models/brumby.py` passes it so)
     ret.retention_decode = rounded(ret.retention_decode, 7)
     ret.retention_prefill = rounded(ret.retention_prefill, 9)
+    ret.retention_read = takes_rounded(ret.retention_read)
 
 
 class BenchRetentionService(base.BenchLlamaService):
@@ -221,7 +240,8 @@ class BenchRetentionService(base.BenchLlamaService):
             from benchmarks import trace_reduce, trace_scopes
 
             out["trace"] = trace_reduce.reduce_dir(
-                self._trace["dir"], default_gap="engine loop, unattributed",
+                self._trace["dir"], annotations=base.ENGINE_SPANS,
+                default_gap="engine loop, unattributed",
                 kernels=kernel_predicates(self.cfg))
             out["trace"]["scopes"] = trace_scopes.scope_seconds(
                 self._trace["dir"], SCOPES, ("jit_decode_chunk_",))

@@ -3,6 +3,13 @@ shapes (as `roofline.py` counts the others: what the mathematics needs,
 so a share can only be overstated by a kernel that does less).  The
 state is counted at its 8,256 DISTINCT monomials a KV head (`d (d + 1)
 / 2` at `d` 128), whatever layout the program holds them in.
+
+ONE READ A TOKEN AND ONE WRITE A CHUNK.  A token's answer needs a live
+row's state READ once (`retention_read`); the state has to be WRITTEN
+only where a chunk of decode steps ends (`retention_decode`: the rows
+live at that step read and written once, every held token folded in).
+A program that writes the state at every token calls the second alone,
+at every step, and its count is the same a call.
 """
 
 from __future__ import annotations
@@ -24,17 +31,32 @@ def state_bytes(kv_heads: int, head_dim: int) -> int:
 
 def retention_decode(live_rows: float, heads: int, kv_heads: int,
                      head_dim: int, act_bytes: int = 2) -> dict:
-    """One decode step, one layer: every LIVE row's state and key sum
-    read and written once; per row and KV head the decay and the
-    `phi(k) v^T` update (3 operations a state value) and one read-out a
-    query head (2 a value), the key sum likewise.  Bytes: the states
-    twice, and q, k, v read and o written a live row."""
+    """One WRITING decode step (a chunk's last, or every step of a
+    program that writes a token), one layer: the state and key sum of
+    every row LIVE AT THAT STEP read and written once; per row and KV
+    head the decay and the `phi(k) v^T` update (3 operations a state
+    value) and one read-out a query head (2 a value), the key sum
+    likewise.  Bytes: the states twice, and q, k, v read and o written
+    a live row."""
     D = monomials(head_dim)
     group = heads // kv_heads
     per_row = kv_heads * (D * head_dim + D) * (3 + 2 * group)
     io = (2 * heads + 2 * kv_heads) * head_dim * act_bytes
     return {"flops": live_rows * per_row,
             "bytes": live_rows * (2 * state_bytes(kv_heads, head_dim) + io)}
+
+
+def retention_read(live_rows: float, heads: int, kv_heads: int,
+                   head_dim: int, act_bytes: int = 2) -> dict:
+    """One decode step that writes no state, one layer: every LIVE
+    row's state and key sum read once, one read-out a query head (2
+    operations a state value).  Bytes: the states once, and q, k, v
+    read and o written a live row."""
+    D = monomials(head_dim)
+    per_row = kv_heads * (D * head_dim + D) * 2 * (heads // kv_heads)
+    io = (2 * heads + 2 * kv_heads) * head_dim * act_bytes
+    return {"flops": live_rows * per_row,
+            "bytes": live_rows * (state_bytes(kv_heads, head_dim) + io)}
 
 
 def retention_prefill(tokens: float, prompts: float, chunk: int, heads: int,

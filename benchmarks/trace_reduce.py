@@ -19,12 +19,15 @@ their own names.  All planes share one clock (nanoseconds).
   first and last events bracket time in which the device's work was
   simply not recorded).
 - per-op sums: seconds and calls per op name, and per program.
-- gaps: the longest idle intervals, each named after the benchmark's
-  own host annotation that covers most of it (else `default_gap`).
+- gaps: every idle interval, each part of it named after the INNERMOST
+  of the host annotations asked for that covers that part (a tick's
+  phase before the tick), what none covers after `default_gap`; summed
+  by name, the largest first.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import shutil
@@ -96,6 +99,34 @@ def self_times(events):
     return out
 
 
+def innermost(spans):
+    """Disjoint, sorted (start, end, name) pieces of the time some span
+    covers, each named after the span that began LAST among those that
+    cover it: of nested spans, the innermost."""
+    # stack of (name, end); the pieces so far end at `cur`
+    out, stack, cur = [], [], float("-inf")
+
+    def own(until):  # the top of the stack owns [cur, until)
+        nonlocal cur
+        if until > cur:
+            out.append((cur, until, stack[-1][0]))
+            cur = until
+
+    for name, s, e in sorted(spans, key=lambda t: (t[1], -t[2])):
+        while stack and stack[-1][1] <= s:
+            own(stack[-1][1])
+            stack.pop()
+        if stack:
+            own(s)
+        else:
+            cur = max(cur, s)
+        stack.append((name, e))
+    while stack:
+        own(stack[-1][1])
+        stack.pop()
+    return out
+
+
 def short(name: str, n: int = 160) -> str:
     """An op's event name is its whole HLO line; keep what identifies
     it: the result name, the result shape and the op kind."""
@@ -149,14 +180,18 @@ def reduce(profile, *, annotations=(), default_gap="unattributed",
             if s1 > e0:
                 gaps.append((e0, s1))
     gap_by = defaultdict(float)
+    pieces = innermost(host_spans)
+    ends = [e for _, e, _ in pieces]
     for g0, g1 in gaps:
-        best, cover = default_gap, 0.0
-        for n, s, e in host_spans:
-            c = min(g1, e) - max(g0, s)
-            if c > cover:
-                best, cover = n, c
-        gap_by[best if cover > 0.5 * (g1 - g0) else default_gap] += \
-            (g1 - g0) * 1e-9
+        named = 0.0
+        for i in range(bisect.bisect_right(ends, g0), len(pieces)):
+            s, e, n = pieces[i]
+            if s >= g1:
+                break
+            gap_by[n] += (min(g1, e) - max(g0, s)) * 1e-9
+            named += min(g1, e) - max(g0, s)
+        if g1 - g0 > named:
+            gap_by[default_gap] += (g1 - g0 - named) * 1e-9
     n_dev = len(devices)
     busy_s = sum(per_dev_busy) / n_dev
     rank = lambda d: [[short(k), v] for k, v in sorted(  # noqa: E731

@@ -67,9 +67,10 @@ def test_end_to_end_bounds_and_setup():
         assert e["source"] in ("host_clock", "device_trace")
 
 
-def test_every_cell_reports_enough_and_uses_a_known_config():
+def check_every_cell_reports_enough_and_uses_a_known_config(M):
+    cells = [c["name"] for c in M["workloads"]]
     configs = {c["name"]: c for c in M["configs"]}
-    assert len({(c["config"], c["traffic"]) for c in M["workloads"]}) == len(CELLS)
+    assert len({(c["config"], c["traffic"]) for c in M["workloads"]}) == len(cells)
     for c in M["workloads"]:
         assert c["config"] in configs and c["chips"] in (1, 4)
         assert NAME.match(c["config"]) and NAME.match(c["traffic"])
@@ -78,7 +79,60 @@ def test_every_cell_reports_enough_and_uses_a_known_config():
         assert manifest.metrics_for(c["name"], "per_layer")
     assert {c["config"] for c in M["workloads"]} == set(configs)
     four = sum(c["chips"] == 4 for c in M["workloads"])
-    assert four <= max(1, len(CELLS) // 4)
+    assert four <= max(1, len(cells) // 4)
+
+
+def test_every_cell_reports_enough_and_uses_a_known_config():
+    check_every_cell_reports_enough_and_uses_a_known_config(M)
+
+
+def _copy_of_bench(tmp_path, *subs):
+    bench = tmp_path / "benchmarks"
+    for sub in subs:
+        shutil.copytree(os.path.join(manifest.BENCH, sub), bench / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    return bench
+
+
+def test_a_list_can_grow(tmp_path, monkeypatch):
+    """What the next PR that brings a cell does to the manifest: one
+    more configuration, cell and per-layer entry at the END of their
+    lists, the cell's name appended to the `workloads` of the shared
+    metrics it reports.  No test may hold an entry by its place: the
+    checks that hold what earlier PRs added pass on the grown copy."""
+    import test_bench_hybrid as hybrid
+
+    bench = _copy_of_bench(tmp_path, "traffic", "configs", "layer_metrics",
+                           "reference")
+    grown = manifest.manifest()
+    last = grown["configs"][-1]
+    cfg = manifest.load_json(os.path.join(manifest.REPO, last["file"]))
+    (bench / "configs" / "one-more.json").write_text(
+        json.dumps({**cfg, "name": "one-more"}))
+    grown["configs"].append({**last, "name": "one-more",
+                             "file": "benchmarks/configs/one-more.json"})
+    grown["workloads"].append({
+        "name": "one_more_cell", "config": "one-more",
+        "traffic": "batch_closed", "chips": 1, "why": "a list can grow"})
+    for kind, name in (("end_to_end", "serve_tokens_per_s"),
+                       ("per_layer", "decode_step_ms")):
+        shared = next(e for e in grown[kind] if e["name"] == name)
+        shared["workloads"].append("one_more_cell")
+    (bench / "layer_metrics" / "one_more_metric.py").write_text(
+        'LAYER, UNIT, SOURCE, MOVES = "engine", "ms", "program_counter", '
+        '"serve_tokens_per_s"\n\ndef read(ctx):\n    return None\n')
+    grown["per_layer"].append({
+        "name": "one_more_metric", "unit": "ms", "better": "lower",
+        "source": "program_counter", "layer": "engine",
+        "moves": "serve_tokens_per_s", "workloads": ["one_more_cell"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(grown))
+    monkeypatch.setattr(manifest, "REPO", str(tmp_path))
+    monkeypatch.setattr(manifest, "BENCH", str(bench))
+    assert manifest.manifest()["workloads"][-1]["name"] == "one_more_cell"
+    assert [p["name"] for p in manifest.metrics_for(
+        "one_more_cell", "per_layer")] == ["decode_step_ms", "one_more_metric"]
+    hybrid.check_the_manifest_finds_every_new_file()
+    check_every_cell_reports_enough_and_uses_a_known_config(grown)
 
 
 def test_each_layer_metric_moves_a_metric_its_cells_report():
@@ -118,9 +172,7 @@ def test_no_width_is_reduced():
 
 
 def test_a_dropped_in_file_is_found_with_no_code_change(tmp_path, monkeypatch):
-    bench = tmp_path / "benchmarks"
-    for sub in ("traffic", "configs", "layer_metrics"):
-        shutil.copytree(os.path.join(manifest.BENCH, sub), bench / sub)
+    bench = _copy_of_bench(tmp_path, "traffic", "configs", "layer_metrics")
     shutil.copy(os.path.join(manifest.BENCH, "peaks.json"), bench)
     (bench / "traffic" / "bursty_new.json").write_text(json.dumps({
         "kind": "open_loop", "rate_per_s": 3.0, "arrival_cv": 3.0,

@@ -206,6 +206,43 @@ def test_the_controls():
         np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32)))
 
 
+def test_the_second_control_rounds_what_the_kernels_leave_and_take_in(
+        monkeypatch):
+    """`--control state_bf16` on the program's three entry points: the
+    layer's state and key sum come back from the flush as bfloat16
+    values (and no other layer's), and a reading step sees its held
+    log-gates rounded; the state it reads was rounded by its writer."""
+    from ray_tpu.ops import retention as ret
+
+    for name in ("retention_decode", "retention_prefill", "retention_read"):
+        monkeypatch.setattr(ret, name, getattr(ret, name))  # put back after
+    plain_read = ret.retention_read
+    plane.hold_state_in_bf16()
+    B, H, KV, d, L = 2, 4, 2, 8, 2
+    key = jax.random.split(jax.random.PRNGKey(5), 8)
+    q, k, v = (jax.random.normal(key[i], (B, n, d)) + 0.5
+               for i, n in enumerate((H, KV, KV)))
+    g = -jnp.abs(jax.random.normal(key[3], (B, KV))) * 0.3
+    shapes = ret.state_shapes(L, B, KV, d)
+    state, keysum = (jax.random.normal(key[4 + i], sh) for i, sh in
+                     enumerate(shapes))
+    live, is_bf16 = jnp.ones((B,), bool), lambda x: bool(jnp.array_equal(  # noqa: E731
+        x, x.astype(jnp.bfloat16).astype(jnp.float32)))
+    _, st, zs = ret.retention_decode(q, k, v, g, state, keysum, live, 1,
+                                     eps=1e-6)
+    assert is_bf16(st[1]) and is_bf16(zs[1]) and not is_bf16(state[1])
+    np.testing.assert_array_equal(np.asarray(st[0]), np.asarray(state[0]))
+    held = ret.Pending(k[:, :, None], v[:, :, None], g[:, :, None] * 1.37,
+                       jnp.asarray(1, jnp.int32))
+    got, _ = ret.retention_read(q, k, v, g, st, zs, held, live, 1, eps=1e-6)
+    raw, _ = plain_read(q, k, v, g, st, zs, held, live, 1, eps=1e-6)
+    want, _ = plain_read(q, k, v, g, st, zs,
+                         held._replace(G=plane.round_bf16(held.G)), live, 1,
+                         eps=1e-6)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(got), np.asarray(raw))
+
+
 def test_roofline_counts_at_the_cells_shapes():
     m = CFG["model"]
     H, KV, d = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
@@ -220,6 +257,13 @@ def test_roofline_counts_at_the_cells_shapes():
     peaks = manifest.peaks("TPU v5 lite")
     least = roofline_retention.least_seconds(w, peaks)
     assert least["bound"] == "memory" and 2.6e-3 < least["seconds"] < 2.7e-3
+    # a step that writes no state reads each live row's once: half the
+    # bytes but for q, k, v, o, and the read-out's 2 operations alone
+    r = roofline_retention.retention_read(28, H, KV, d)
+    assert r["bytes"] == 28 * (34_080_768 + io)
+    assert r["flops"] == 28 * 8 * 8256 * 129 * 2 * 5
+    least = roofline_retention.least_seconds(r, peaks)
+    assert least["bound"] == "memory" and 1.16e-3 < least["seconds"] < 1.17e-3
     # a 2,048-token prompt alone, 256-token chunks: 8 chunks, 7 carried
     p = roofline_retention.retention_prefill(2048, 1, 256, H, KV, d)
     pairs = 8 * 256 * 257 / 2
@@ -296,31 +340,75 @@ def test_the_new_readers_and_what_they_return_on_the_parent():
     assert read("engine_state_rows_live") == pytest.approx(30.0)
     # 28 live rows x 68.2 MB at 819 GB/s = 2.33 ms; traced 4 ms a call
     assert 57 < read("retention_decode_roofline") < 60
+    # a program that writes at every step has no kernel that only reads
+    assert read("retention_read_roofline") is None
     # 2,048 tokens in 2 prompts a call: 171 GFLOP = 0.87 ms; traced 2 ms
     assert 42 < read("retention_prefill_roofline") < 45
     assert read("decode_step_ms") == pytest.approx(1e3 * 2.0 / (8 * 10))
     parent = _ctx({}, [{"active": 32}])
     for name in ("retention_device_share", "engine_state_rows_live",
-                 "retention_decode_roofline", "retention_prefill_roofline"):
+                 "retention_decode_roofline", "retention_read_roofline",
+                 "retention_prefill_roofline"):
         assert manifest.layer_metric(name).read(parent) is None, name
 
 
-def test_kernel_predicates_tell_the_two_kernels_apart():
+def test_the_flush_is_counted_at_its_own_rows_and_the_reads_at_theirs():
+    """A chunk of 8 steps that 30 rows began and 26 ended (four stopped
+    after their 4th step): 224 live row-steps, of which the last step's
+    26 are WRITTEN and the seven steps before it read 198, 28.3 a call.
+    One flush and seven reads a layer and chunk program."""
+    tick = {"active": 32, "state_rows_live": 30, "state_rows_flushed": 26,
+            "row_steps_live": 224, "row_steps": 256}
+    flush = {"seconds": 2.0, "calls": 10, "op_seconds": 0.15,
+             "op_calls": 10 * 5}
+    reads = {"seconds": 2.0, "calls": 10, "op_seconds": 0.49,
+             "op_calls": 10 * 7 * 5}
+    kernels = {"paged_decode": flush, "retention_decode": flush,
+               "retention_read": reads}
+    ctx = _ctx({}, [tick, {"active": 0}], kernels)
+    read = lambda n: manifest.layer_metric(n).read(ctx)  # noqa: E731
+    io = (2 * 40 + 2 * 8) * 128 * 2
+    # 26 rows x 68.2 MB at 819 GB/s = 2.165 ms; traced 3 ms a call
+    assert read("retention_decode_roofline") == pytest.approx(
+        100 * 26 * (2 * 34_080_768 + io) / 819e9 / 3e-3)
+    # 198 / 7 rows x 34.1 MB = 1.178 ms; traced 1.4 ms a call
+    assert read("retention_read_roofline") == pytest.approx(
+        100 * (198 / 7) * (34_080_768 + io) / 819e9 / 1.4e-3)
+    assert 84 < read("retention_read_roofline") < 85
+    # one flush a chunk program: the program's time over its 8 steps
+    assert read("decode_step_ms") == pytest.approx(1e3 * 2.0 / (8 * 10))
+    # a plane that does not ship what was flushed (this benchmark's
+    # parent): both at the chunk's mean, 28 rows, as before
+    old = _ctx({}, [{k: v for k, v in tick.items()
+                     if k != "state_rows_flushed"}], kernels)
+    assert manifest.layer_metric("retention_decode_roofline").read(old) == \
+        pytest.approx(100 * 28 * (2 * 34_080_768 + io) / 819e9 / 3e-3)
+    assert manifest.layer_metric("retention_read_roofline").read(old) == \
+        pytest.approx(100 * 28 * (34_080_768 + io) / 819e9 / 1.4e-3)
+    assert "state_rows_flushed" in plane.STATE_KEYS
+
+
+def test_kernel_predicates_tell_the_three_kernels_apart():
     pred = plane.kernel_predicates(CFG)
     decode = ("%closed_call.9 = (f32[32,8,128,128]{3,2,1,0}, "
               "f32[32,8,8,128]{3,2,1,0}, f32[5,32,8,65,128,128]{5,4,3,2,1,0}, "
               "f32[5,32,8,65,128]{4,3,2,1,0}) custom-call(s32[1] %a), "
               "custom_call_target=\"tpu_custom_call\"")
+    # the denominators first, as the program's `retention_read` gives them
+    reading = ("%retention_read.6 = (f32[32,8,8,128]{3,2,1,0:T(8,128)S(1)}, "
+               "f32[32,8,128,128]{3,2,1,0:T(8,128)S(1)}) custom-call(s32[1] "
+               "%a), custom_call_target=\"tpu_custom_call\"")
     prefill = ("%closed_call.3 = (bf16[8,5,2048,128]{3,2,1,0}, "
                "f32[5,32,8,65,128,128]{5,4,3,2,1,0}, f32[5,32,8,65,128]"
                "{4,3,2,1,0}) custom-call(s32[1] %a), "
                "custom_call_target=\"tpu_custom_call\"")
     fusion = "%fusion.3 = f32[32,8,128,128]{3,2,1,0} fusion(f32[32] %x)"
-    assert pred["retention_decode"](decode) and pred["paged_decode"](decode)
-    assert pred["retention_prefill"](prefill)
-    assert not pred["retention_decode"](prefill)
-    assert not pred["retention_prefill"](decode)
-    assert not any(p(fusion) for p in pred.values())
+    assert set(pred) == {"paged_decode", "retention_decode",
+                         "retention_read", "retention_prefill"}
+    for line, names in ((decode, {"paged_decode", "retention_decode"}),
+                        (reading, {"retention_read"}),
+                        (prefill, {"retention_prefill"}), (fusion, set())):
+        assert {n for n, p in pred.items() if p(line)} == names, line
 
 
 def test_the_cells_rehearsal_leaves_nothing_running():

@@ -45,11 +45,60 @@ def test_busy_is_the_union_and_idle_is_the_rest():
     assert r["op_seconds"]["%kernel"] == pytest.approx(0.020)
     assert r["op_calls"]["%fusion.a"] == 1
     assert r["device_ops"][0][1] == pytest.approx(0.020)
-    # gaps: [40, 60) is mostly `data`; [80, 99) has no annotation
+    # gaps: [40, 60) is `step` to 45 and `data` from there; [80, 99)
+    # has no annotation that was asked for
     gaps = dict(r["idle_gaps"])
-    assert gaps["data"] == pytest.approx(0.020)
+    assert gaps["step"] == pytest.approx(0.005)
+    assert gaps["data"] == pytest.approx(0.015)
     assert gaps["rest"] == pytest.approx(0.019)
     assert r["longest_gap_s"] == pytest.approx(0.020)
+
+
+def test_a_gap_is_named_after_the_innermost_span_that_covers_it():
+    """Two ticks of an engine's loop as its spans nest: the tick, inside
+    it `admit` (inside that `plan` and `prefill`), `dispatch`, `harvest`
+    (inside that `device_wait` and `harvest_host`), and the blocked
+    `wait` between ticks.  The device idles [30, 52) and [60, 100)."""
+    from benchmarks.planes.serve import ENGINE_SPANS
+
+    host = [ev("engine.tick", 0, 50),
+            ev("engine.admit", 30, 12), ev("engine.plan", 30, 4),
+            ev("engine.prefill", 34, 8), ev("engine.dispatch", 43, 2),
+            ev("engine.harvest", 45, 5), ev("engine.device_wait", 45, 3),
+            ev("engine.harvest_host", 48, 2),
+            ev("engine.wait", 50, 1),
+            ev("engine.tick", 51, 60), ev("engine.admit", 51, 1),
+            ev("engine.harvest", 60, 38), ev("engine.device_wait", 62, 30),
+            ev("engine.harvest_host", 92, 6), ev("not.asked.for", 0, 200)]
+    r = trace_reduce.reduce(profile(
+        ops=[ev("%a", 0, 30), ev("%b", 52, 8), ev("%c", 100, 1)], host=host),
+        annotations=ENGINE_SPANS, default_gap="rest")
+    gaps = dict(r["idle_gaps"])
+    want = {"engine.plan": 4, "engine.prefill": 8, "engine.admit": 1,
+            # what a tick's phases leave of it: [42, 43) and [98, 100)
+            "engine.tick": 1 + 2, "engine.dispatch": 2,
+            "engine.device_wait": 3 + 30, "engine.harvest_host": 2 + 6,
+            "engine.harvest": 2, "engine.wait": 1}
+    assert gaps == pytest.approx({k: v * 1e-3 for k, v in want.items()})
+    assert sum(gaps.values()) == pytest.approx(0.062)
+    assert r["idle_gaps"][0][0] == "engine.device_wait"
+    # the pieces are disjoint whatever the spans do: one that outlives
+    # its parent owns the time past the parent's end, once
+    assert trace_reduce.innermost([("p", 0, 10), ("c", 5, 15), ("q", 12, 20)]) \
+        == [(0, 5, "p"), (5, 12, "c"), (12, 20, "q")]
+
+
+def test_the_spans_asked_for_are_the_engines():
+    import inspect
+
+    from benchmarks.planes.serve import ENGINE_SPANS
+    from ray_tpu.serve import llm_engine
+
+    src = inspect.getsource(llm_engine)
+    assert len(ENGINE_SPANS) == len(set(ENGINE_SPANS)) == 9
+    for name in ENGINE_SPANS:
+        tail = name.removeprefix("engine.")
+        assert f'_span("{name}"' in src or f'_phase("{tail}"' in src, name
 
 
 def test_union_merges_overlaps():
