@@ -304,7 +304,7 @@ def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
 def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
                  route_eps: float, dtype,
                  kernel: bool = False, interpret: bool = False,
-                 stack_index=None, row_mask=None):
+                 stack_index=None, row_mask=None, held=None):
     """Routed experts for inference, nothing dropped: h [N, D] ->
     (y [N, D], stats).  `layer`: `router` [D, E] float32, `router_bias`
     [E], `e_gate` / `e_up` [E, D, I], `e_down` [E, I, D] — or the three
@@ -328,12 +328,25 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
     prefill passes none): a row it leaves out is routed to NO expert.
     Its pairs sort behind the last group and count in no group size, so
     the grouped products never read them, `stats` count the other rows
-    only, and its row of `y` is zeros."""
+    only, and its row of `y` is zeros.
+
+    `held` = `(offset, count)`: this chip's SHARE of an expert layer
+    that several chips hold between them.  The router keeps its
+    published width and the top-k is taken over all of its experts; the
+    expert leaves hold experts `offset .. offset + count` only (`[count,
+    ...]`).  A pair whose expert is not held goes to NO expert exactly
+    as a masked row's pairs do (behind the last group, in no group
+    size) and adds nothing to `y`, which is then this chip's PARTIAL sum
+    (the other chips' parts are theirs to add: nothing here stands in
+    for them); `stats` count the held experts."""
     N, D = h.shape
     E = layer["router"].shape[-1]
     with jax.named_scope("moe_router"):
         w, idx = sigmoid_topk_route(h, layer["router"], layer["router_bias"],
                                     top_k, scale, route_eps)
+        if held is not None:  # the held experts renumbered from 0
+            lo, E = held
+            idx = jnp.where((idx >= lo) & (idx < lo + E), idx - lo, E)
         if row_mask is not None:  # expert E: behind every group, in none
             idx = jnp.where(row_mask[:, None], idx, E)
         flat = idx.reshape(-1)                      # [N * k], pair -> expert
@@ -348,6 +361,10 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
         act = jax.nn.silu(mm(xs, layer["e_gate"])) * mm(xs, layer["e_up"])
         ys = mm(act, layer["e_down"])               # [N * k, D]
         y = ys[inverse].reshape(N, top_k, D).astype(jnp.float32)
+        if held is not None:
+            # a pair that went to no expert reads a row past the last
+            # group: whatever the product left there
+            y = jnp.where((idx < E)[..., None], y, 0.0)
         y = jnp.sum(y * w[..., None], axis=1).astype(dtype)
         if row_mask is not None:
             # rows of `ys` past the last group are whatever the product

@@ -84,13 +84,16 @@ prefill likewise: the flat signature, the greedy pick, the paged rows
 into their blocks, the admitted rows' state.  `kv_write_program` is
 `kv_write`'s flat signature and the admitted slot's state.
 
-Four implementers: `LlamaEngineModel` (per-head K and V pools),
+Five implementers: `LlamaEngineModel` (per-head K and V pools),
 `LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
 absorbed decode attention, dropless experts), `RetentionEngineModel`
 (`models/brumby.py`: every layer a power-retention layer, a per-slot
 state) and `HybridEngineModel` (`models/lfm2.py`: paged K and V in the
 attention layers, a per-slot convolution state in the others, both in
-one spec).  `engine_model_for` picks by the config's type, builds the
+one spec) and `SparseLatentEngineModel` (`models/dots3.py`: latent
+attention of two forms with a learned selection; three paged leaves of
+different widths and layer counts on one table; no packed prefill).
+`engine_model_for` picks by the config's type, builds the
 format from the user's `kv_dtype` and hands the implementer the
 resolved route: a user passes a model's config and the model picks its
 route.
@@ -104,7 +107,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.exceptions import PrefixCacheUnsupportedError
-from ray_tpu.models import brumby, deepseek_v3, lfm2, llama
+from ray_tpu.models import brumby, deepseek_v3, dots3, lfm2, llama
 from ray_tpu.ops import paged_attention as _pa
 from ray_tpu.ops import retention as _ret
 from ray_tpu.serve.kv_cache import CacheLeaf
@@ -123,24 +126,30 @@ class PagedKV:
     leaf with one scale per (layer, row, kv head): the tail without its
     last axis (`ops/paged_attention.quantize_int8`; the paged kernels
     take the leaves as they lie and fuse the dequant).  `used`,
-    `layers`: see `CacheLeaf`."""
+    `layers`: see `CacheLeaf`; one value for every leaf, or a dict by
+    the leaf's name where the leaves differ (a model whose layers are
+    of two attention forms caches rows of another width in each)."""
 
     def __init__(self, rows: Dict[str, Tuple[int, ...]], dtype,
                  block_size: int, kv_dtype: str = "model",
-                 used: Optional[int] = None, layers: Optional[int] = None):
+                 used=None, layers=None):
         if kv_dtype not in KV_DTYPES:
             raise ValueError(f"kv_dtype={kv_dtype!r} not in {KV_DTYPES}")
         self.kv_dtype, self.dtype = kv_dtype, dtype
         self.block_size, self.n_rows = block_size, len(rows)
         self._int8 = kv_dtype == "int8"
+
+        def of(value, name):
+            return value.get(name) if isinstance(value, dict) else value
+
         self.leaves: List[CacheLeaf] = [
             CacheLeaf(name, tail, jnp.int8 if self._int8 else dtype,
-                      used=used, layers=layers)
+                      used=of(used, name), layers=of(layers, name))
             for name, tail in rows.items()]
         if self._int8:
             self.leaves += [
                 CacheLeaf(f"{name}_scale", tail[:-1], jnp.float32,
-                          sidecar=True, layers=layers)
+                          sidecar=True, layers=of(layers, name))
                 for name, tail in rows.items()]
 
     def rows(self, cache: Sequence, blk) -> tuple:
@@ -357,10 +366,13 @@ class _EngineModel:
     `segmented` (a packed prefill may hold several prompts), `aux_rows`
     and `tick_fields` (the model's own per-tick counters; none here),
     `state_write_deferred` (a per-slot state is written at a chunk's
-    last step only, not at every step)."""
+    last step only, not at every step), `packs` (it has a packed
+    prefill; False: every admission is a suffix prefill, behind a
+    cached prefix or behind nothing)."""
 
     aux_rows = 0
     state_write_deferred = False
+    packs = True
 
     def __init__(self, cfg, kv: Optional[PagedKV], *, chunk: int,
                  paged: bool, interpret: bool,
@@ -375,6 +387,12 @@ class _EngineModel:
         self.segmented = getattr(cfg, "attention", "dense") == "dense"
 
     def tick_fields(self, aux) -> Dict[str, object]:
+        return {}
+
+    def context_fields(self, contexts: Sequence[int]) -> Dict[str, object]:
+        """The model's own tick fields of the contexts (tokens a row
+        attends over, its own included) of the rows live at a chunk's
+        first step; none here."""
         return {}
 
     def _no_prefix(self, *_):
@@ -682,9 +700,130 @@ class HybridEngineModel(_ExpertCounters, _EngineModel):
     suffix_prefill = kv_write = _EngineModel._no_prefix
 
 
+class SparseLatentEngineModel(_ExpertCounters, _EngineModel):
+    """`models/dots3.py` behind the seam: latent attention of TWO forms
+    and a learned sparse selection, so THREE paged leaves of different
+    widths and layer counts on one block table: `latent` `[full layers,
+    .., 576 -> 640]`, `index_k` `[full layers, .., 128]` (the indexer's
+    keys) and `swa_latent` `[window layers, .., 1088 -> 1152]`.  A
+    window layer's rows lie on the sequence's table like the others and
+    none is freed while the sequence lives; its programs READ only the
+    blocks the window can touch (a start position), so its time is
+    O(window).  Every leaf is paged, so the radix prefix cache shares
+    all three.
+
+    Both programs reach the pools through the table in plain XLA on
+    any backend (no dense view, no route of its own for the CPU);
+    `paged` only picks the experts' grouped products (Pallas on a TPU,
+    `lax.ragged_dot` elsewhere).  `packs` False: a selection makes
+    every query's key set its own, so there is no packed prefill of
+    several prompts under one mask; EVERY admission is a suffix
+    prefill, behind its cached prefix or behind nothing, and a long
+    prompt chunk by chunk (the engine's `prefill_chunk`).  The decode
+    program hands back the HELD experts' two counters (`aux_rows`)."""
+
+    packs = False
+
+    def __init__(self, cfg, kv: PagedKV, **route):
+        super().__init__(cfg, kv, **route)
+        self._pairs = cfg.n_moe_layers * cfg.experts_held
+        self._widths = [leaf.tail[0] for leaf in kv.leaves]
+
+    def tick_fields(self, aux) -> Dict[str, object]:
+        return {**super().tick_fields(aux),
+                "experts_held": self.cfg.experts_held}
+
+    def context_fields(self, contexts: Sequence[int]) -> Dict[str, object]:
+        """`dsa_selected_share`: the mean over the live rows of the
+        share of its context a full layer attends, `min(T, index_topk)
+        / T`; `window_rows_live`: the rows a window layer reads for
+        them, `min(T, window)` each."""
+        if not contexts:
+            return {"dsa_selected_share": 0.0, "window_rows_live": 0}
+        k, w = self.cfg.index_topk, self.cfg.window
+        return {"dsa_selected_share": sum(min(t, k) / t for t in contexts)
+                / len(contexts),
+                "window_rows_live": sum(min(t, w) for t in contexts)}
+
+    def decode_chunk(self, W: int):
+        cfg, kw = self.cfg, self._kw()
+
+        def step(params, tok, cache, tables, pos, live):
+            logits, cache, st = dots3.decode_step(
+                cfg, params, tok, cache, pos, tables, live=live, **kw)
+            return logits, cache, (st["experts_touched"], st["load_max"])
+
+        return chunk_program(step, self.chunk, aux=self._aux)
+
+    def _prefix(self, cache, blk_ids, prefix_len):
+        """The cached rows a suffix prefill reads: the full layers' of
+        every prefix block, the window layers' of the last blocks that
+        cover `window - 1` tokens (`prefix_len` is whole blocks)."""
+        bs = self.kv.block_size
+        lat, kI, swa = cache
+        p_lat, p_kI = (r[:, 0] for r in self.kv.rows((lat, kI), blk_ids))
+        wb = -(-(self.cfg.window - 1) // bs)
+        j = prefix_len // bs - wb + jnp.arange(wb)
+        ids = jnp.where(j >= 0, blk_ids[jnp.clip(j, 0, blk_ids.shape[0] - 1)],
+                        0)
+        p_swa = jnp.take(swa, ids, axis=1)
+        p_swa = p_swa.reshape(swa.shape[0], wb * bs, swa.shape[-1])
+        return p_lat, p_kI, p_swa, (prefix_len // bs - wb) * bs
+
+    def prefill(self, bucket: int):
+        def _pf(params, prompt):  # prompt [1, bucket], right-padded
+            logits, rows = dots3.forward_with_prefix(
+                self.cfg, params, prompt[0], **self._kw())
+            return (logits, *(r[:, None] for r in rows))
+
+        return _pf
+
+    def suffix_prefill(self, s_bucket: int, p_blocks: int):
+        def _pf(params, *flat):
+            *cache, suffix, blk_ids, prefix_len = flat
+            logits, rows = dots3.forward_with_prefix(
+                self.cfg, params, suffix[0],
+                self._prefix(cache, blk_ids, prefix_len), prefix_len,
+                **self._kw())
+            return (logits, *(r[:, None] for r in rows))
+
+        return _pf
+
+    def kv_write(self, t_in: int, nb: int):
+        target = nb * self.kv.block_size
+
+        def fit(*rows):  # each [L, 1, t_in, d] -> nb blocks, pool width
+            return tuple(
+                jnp.pad(r[:, :, :target],
+                        ((0, 0), (0, 0), (0, max(0, target - t_in)),
+                         (0, width - r.shape[-1])))
+                for r, width in zip(rows, self._widths))
+
+        return kv_write_program(self.kv, fit)
+
+    def prefill_packed(self, N: int):
+        raise NotImplementedError(
+            "a selection has no packed prefill: `packs` is False")
+
+
 def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
     """The implementer for a model's config, its cache in the format
     the user's `kv_dtype` names: the model picks its route."""
+    if isinstance(cfg, dots3.Dots3Config):
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' quantizes per-head K and V rows; the "
+                "latent pools have no heads to scale by")
+        return SparseLatentEngineModel(cfg, PagedKV(
+            {"latent": (_pa.mla_pool_width(cfg.latent_dim),),
+             "index_k": (cfg.index_head_dim,),
+             "swa_latent": (_pa.mla_pool_width(cfg.swa_latent_dim),)},
+            cfg.dtype, block_size, kv_dtype,
+            used={"latent": cfg.latent_dim,
+                  "swa_latent": cfg.swa_latent_dim},
+            layers={"latent": cfg.n_full_layers,
+                    "index_k": cfg.n_full_layers,
+                    "swa_latent": cfg.n_swa_layers}), **route)
     if isinstance(cfg, brumby.BrumbyConfig):
         if kv_dtype == "int8":
             raise ValueError(

@@ -225,14 +225,16 @@ class LlamaEngine:
     bodies of packed prefill, prefill, suffix prefill, KV write and the
     paged decode chunk, all with flat signatures `(params, *cache, ...)`.  The
     cache's FORMAT (`kv_dtype`) is the model's too: the engine hands
-    the string over and reads it back for `stats()`.  Four implementers,
+    the string over and reads it back for `stats()`.  Five implementers,
     picked by the config's type (`engine_model_for`): `LlamaEngineModel`
     — per-head K and V pools — `LatentMoeEngineModel` — one latent
     pool, absorbed decode attention, dropless experts
     (`models/deepseek_v3.py`) — `RetentionEngineModel` — a per-slot
     state (`models/brumby.py`) — and `HybridEngineModel` — paged K and V
-    beside a per-slot state in one spec (`models/lfm2.py`).  The class
-    keeps its name; nothing a caller passes changed.
+    beside a per-slot state in one spec (`models/lfm2.py`) — and
+    `SparseLatentEngineModel` — three paged leaves of different widths,
+    a learned selection, no packed prefill (`models/dots3.py`).  The
+    class keeps its name; nothing a caller passes changed.
 
     submit() is thread-safe and returns a `concurrent.futures.Future`
     resolving to the generated token ids (greedy — identical to what a
@@ -253,6 +255,16 @@ class LlamaEngine:
     is only what a prompt is aligned to in a packed prefill, and
     `prefix_cache=True` raises `PrefixCacheUnsupportedError`.
 
+    `prefill_chunk` (tokens, whole blocks; None: off): the most one
+    prefill program holds of ONE prompt.  The packed sizes stop there,
+    and a prompt whose uncached part is longer is admitted CHUNK BY
+    CHUNK, each chunk a suffix prefill behind the request's own blocks
+    (the cached prefix's, then those the chunks before it wrote), so a
+    prompt may be as long as `max_len` allows without a program that
+    attends `[max_len, max_len]`.  A model with no packed prefill
+    (`engine_model.packs` False) is admitted that way always, the whole
+    uncached part one chunk where none is set.
+
     A model whose cache holds BOTH kinds (paged K and V in its attention
     layers, a per-slot state in the others) is admitted when a slot AND
     its blocks are free; its packed prefill is handed `blk_ids` and
@@ -267,7 +279,8 @@ class LlamaEngine:
                  max_queued: Optional[int] = None,
                  decode_kernel: str = "auto", kv_dtype: str = "model",
                  chunk_cache_cap: int = 8,
-                 kernel_interpret: bool = False):
+                 kernel_interpret: bool = False,
+                 prefill_chunk: Optional[int] = None):
         import jax
         import jax.numpy as jnp
 
@@ -431,8 +444,21 @@ class LlamaEngine:
         self._prefill_padded_tokens = 0  # their sizes (N, bucket) summed
         # the packed prefill's closed set of sizes and the prompts one
         # program holds: the segment mask needs the dense attention form
-        self._pack_sizes = _pack_sizes(
-            self._max_seq_blocks * self.block_size, self.block_size)
+        top = self._max_seq_blocks * self.block_size
+        self._prefill_chunk = None
+        if prefill_chunk is not None:
+            if not self._has_blocks or self._has_state:
+                raise ValueError(
+                    "prefill_chunk: chunks are suffix prefills behind the "
+                    "request's own blocks, which a per-slot state has not")
+            if prefill_chunk < 1 or prefill_chunk % self.block_size:
+                raise ValueError(
+                    f"prefill_chunk={prefill_chunk} must be whole blocks "
+                    f"of {self.block_size}")
+            self._prefill_chunk = int(prefill_chunk)
+            top = min(top, self._prefill_chunk)
+        self._pack_sizes = (_pack_sizes(top, self.block_size)
+                            if self._model.packs else [])
         self._pack_rows = ADMIT_BUDGET if self._model.segmented else 1
         # overload plane: bound the admission queue and shed queued
         # requests whose caller has (or must have) given up BEFORE
@@ -487,6 +513,7 @@ class LlamaEngine:
         self._stalls: deque = deque(maxlen=STALLS_KEPT)
         # stats()'s cumulative prefill counters at the last close
         self._prefilled_mark = (0, 0, 0, 0)
+        self._hit_mark = 0  # ... and its prefix-hit tokens
         self._last_gather_blocks = 0  # W of the latest chunk dispatch
         # request lifecycle ring: one record per FINISHED request (ok,
         # shed, refused or failed), the last REQUEST_RING of them, in
@@ -695,6 +722,7 @@ class LlamaEngine:
                 "block_occupancy": (
                     1.0 - self._pool.free_blocks / self._pool.capacity
                 ),
+                "prefix_hits": self._prefix_hits,
                 "prefix_hit_tokens": self._hit_tokens,
                 "prefill_tokens": self._prefill_tokens,
                 "prefix_hit_rate": (
@@ -880,7 +908,7 @@ class LlamaEngine:
             "queue_s": t_done - t_submit, "prefill_dispatch_s": None,
             "first_token_s": None, "decode_s": None, "harvests": 0,
             "tokens_in": tokens_in, "tokens_hit": 0, "tokens_out": 0,
-            "prefill_rows": None,
+            "prefill_rows": None, "prefill_chunks": None,
         }
         if req is not None:
             rec["queue_s"] = req["t_admit"] - t_submit
@@ -891,6 +919,7 @@ class LlamaEngine:
             rec["harvests"] = req["harvests"]
             rec["tokens_hit"] = req["tokens_hit"]
             rec["prefill_rows"] = req["prefill_rows"]
+            rec["prefill_chunks"] = req["prefill_chunks"]
             rec["tokens_out"] = min(len(req["out"]), req["want"])
         with self._ring_lock:
             self._finished_total += 1
@@ -1003,6 +1032,7 @@ class LlamaEngine:
             "tk": tk, "tokens_in": T, "tokens_hit": len(shared) * bs,
             "harvests": 0, "t_submit": t_submit, "t_admit": t_admit,
             "t_prefill": t_admit, "t_first": None, "prefill_rows": 0,
+            "prefill_chunks": 0,
         }
         return _Plan(req, slot, prompt, shared, own)
 
@@ -1015,10 +1045,14 @@ class LlamaEngine:
         prefix hit prefills its suffix, one program a request, behind
         whatever wrote the blocks it shares."""
         bs = self.block_size
-        cap = self._pack_sizes[-1]
+        cap = self._pack_sizes[-1] if self._pack_sizes else 0
+        # a prompt no packed program holds goes chunk by chunk
+        chunked = {id(p) for p in plans
+                   if not p.shared and len(p.prompt) > cap}
         pack: List[_Plan] = []
         used = 0
-        for plan in [p for p in plans if not p.shared] + [None]:
+        for plan in [p for p in plans
+                     if not p.shared and id(p) not in chunked] + [None]:
             need = 0 if plan is None else _cdiv(len(plan.prompt), bs) * bs
             if pack and (plan is None or used + need > cap
                          or len(pack) == self._pack_rows):
@@ -1029,7 +1063,7 @@ class LlamaEngine:
                 pack.append(plan)
                 used += need
         for plan in plans:
-            if plan.shared:
+            if plan.shared or id(plan) in chunked:
                 self._run_suffix(plan)
 
     def _admitting(self, plans: List[_Plan]) -> None:
@@ -1106,57 +1140,78 @@ class LlamaEngine:
             self._prefill_padded_tokens += N
 
     def _run_suffix(self, plan: _Plan) -> None:
-        """PREFIX HIT: prefill only the suffix, attending over the
-        gathered prefix blocks (pow-2 buckets on both axes), then write
-        its KV and the slot's state."""
+        """The part of a prompt no cached prefix covers (all of it, for
+        a prompt too long for a packed program), prefilled behind the
+        request's own blocks in chunks of at most `prefill_chunk`
+        tokens, one program pair a chunk; the last sets the slot's
+        state."""
+        bs = self.block_size
+        T, P = len(plan.prompt), len(plan.shared) * bs
+        step = self._prefill_chunk or T - P
+        starts = range(P, T, step)
+        for i, lo in enumerate(starts):
+            self._run_suffix_chunk(plan, lo, min(T, lo + step), i,
+                                   len(starts))
+        plan.req["prefill_chunks"] = len(starts)
+        self._hit_tokens += P
+        self._prefix_hits += bool(P)
+        self._prefill_rows += 1
+
+    def _run_suffix_chunk(self, plan: _Plan, lo: int, hi: int, i: int,
+                          n: int) -> None:
+        """Tokens `lo .. hi` of a prompt (from a block boundary),
+        attending over the gathered blocks of the tokens before them
+        (pow-2 buckets on both axes); then their KV into the request's
+        blocks and, after the prompt's last chunk, the slot's state."""
         jnp = self._jnp
         bs = self.block_size
         req, slot, prompt, shared, own = plan
-        T, P = len(prompt), len(shared) * bs
-        S = T - P
+        blocks = shared + own
+        before = blocks[:lo // bs]
+        S, last = hi - lo, i == n - 1
         # RIGHT-pad (the scheme depends on it: causal prefill keeps the
         # real positions correct, the pad tail's garbage KV is masked
         # by the starting pos and overwritten as decoding advances)
         bucket = min(_next_pow2(S), self.max_len - 1)
         with self._phase("prefill", bucket=bucket, slot=slot,
-                         hit_blocks=len(shared)):
+                         hit_blocks=len(before), chunk=f"{i + 1}/{n}"):
             self._admitting([plan])
-            p_bucket = _next_pow2(len(shared))
+            p_bucket = _next_pow2(len(before))
             blk_ids = jnp.asarray(
-                shared + [SCRATCH_BLOCK] * (p_bucket - len(shared)),
+                before + [SCRATCH_BLOCK] * (p_bucket - len(before)),
                 jnp.int32,
             )
             suffix = jnp.asarray(
-                [prompt[P:] + [0] * (bucket - S)], jnp.int32
+                [prompt[lo:hi] + [0] * (bucket - S)], jnp.int32
             )
             logits, *kv = self._launch(
                 self._suffix_prefill_for(bucket, p_bucket),
                 self.params, *self._cache, suffix, blk_ids,
-                jnp.asarray(P, jnp.int32),
+                jnp.asarray(lo, jnp.int32),
             )
             # first generated token comes from the LAST REAL prompt
             # position; it STAYS on device — the next chunk emits it in
-            # its pre-chunk token row
-            tok0 = jnp.argmax(logits[S - 1], axis=-1).astype(jnp.int32)
-            # the suffix starts at a block boundary; write only the
+            # its pre-chunk token row.  A chunk that is not the prompt's
+            # last picks nothing and sets no slot: an index past the
+            # last is dropped
+            tok0 = (jnp.argmax(logits[S - 1], axis=-1).astype(jnp.int32)
+                    if last else jnp.zeros((), jnp.int32))
+            # the chunk starts at a block boundary; write only the
             # blocks holding real tokens
             nb_real = _cdiv(S, bs)
             out = self._launch(
                 self._write_blocks_for(bucket, nb_real),
                 *self._cache, *kv,
-                jnp.asarray(own[:nb_real], jnp.int32),
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(T, jnp.int32),
+                jnp.asarray(blocks[lo // bs:lo // bs + nb_real], jnp.int32),
+                jnp.asarray(slot if last else self.slots, jnp.int32),
+                jnp.asarray(hi, jnp.int32),
                 tok0, self._pos, self._tok,
                 jnp.asarray(req["stop"], jnp.int32), self._stop,
             )
             self._cache = tuple(out[:-3])
             self._pos, self._tok, self._stop = out[-3:]
             self._prefilled([plan])
-        self._hit_tokens += P
-        self._prefix_hits += 1
         self._prefill_calls += 1
-        self._prefill_rows += 1
         self._prefill_tokens += S
         self._prefill_padded_tokens += bucket
 
@@ -1291,6 +1346,7 @@ class LlamaEngine:
         # of the chunk's slots x chunk row-steps, those a request was
         # waiting for (its steps before its stop); the rest are dead
         row_steps = row_steps_live = rows_live = rows_flushed = 0
+        contexts: List[int] = []  # of the rows live at its first step
         if W:
             with self._phase("dispatch", W=W):
                 tables = ()
@@ -1321,6 +1377,8 @@ class LlamaEngine:
                         end = min(req["pos_host"] + self.chunk, req["stop"])
                         row_steps_live += end - req["pos_host"]
                         rows_live += end > req["pos_host"]
+                        if end > req["pos_host"]:
+                            contexts.append(req["pos_host"] + 1)
                         rows_flushed += end - req["pos_host"] == self.chunk
                         req["pos_host"] = end
         # OVERLAP: harvest the PREVIOUS chunk's tokens while the
@@ -1367,8 +1425,10 @@ class LlamaEngine:
             "row_steps_live": row_steps_live,
             "row_steps": row_steps,
             # the model's own counters of the chunk harvested in this
-            # tick (`engine_model.tick_fields`; none for Llama)
+            # tick (`engine_model.tick_fields`; none for Llama), and its
+            # own fields of the dispatched chunk's live rows' contexts
             **model_fields,
+            **(self._model.context_fields(contexts) if W else {}),
         }, t0, t_read)
 
     def _close(self, rec: Dict[str, object], t0: float,
@@ -1433,6 +1493,10 @@ class LlamaEngine:
                 rec.update(zip(PREFILLED, (
                     n - m for n, m in zip(done, self._prefilled_mark))))
                 self._prefilled_mark = done
+            if self._hit_tokens != self._hit_mark:
+                # the prompt tokens its admissions found cached
+                rec["prefix_hit_tokens"] = self._hit_tokens - self._hit_mark
+                self._hit_mark = self._hit_tokens
             before = self._tick_ring[-1] if self._tick_ring else None
             self._tick_ring.append(rec)
             self._account_add(rec)
