@@ -39,15 +39,22 @@ expanded per-head keys and values.
 THE CACHE is three paged leaves on ONE block table a sequence
 (`serve/engine_model.SparseLatentEngineModel`): `latent` `[full
 layers, NB, BS, 576 -> 640]`, `index_k` `[full layers, NB, BS, 128]`,
-`swa_latent` `[window layers, NB, BS, 1088 -> 1152]`.  Both programs
-reach it through the table in plain XLA, on any backend: a decode step
-scatters its rows in, scores a row's whole context with the indexer,
-takes the top-k, gathers the selected rows by their flat pool index,
-and for a window layer gathers only the `ceil((window - 1) / BS) + 1`
-blocks the window can touch (a START position: time O(window) whatever
-the context; the blocks before it are not freed).  The full layers walk
-the slots in groups of `ROW_GROUP` rows so that a group's index scores
-`[rows, index heads, context]` stay small.
+`swa_latent` `[window layers, NB, BS, 1088 -> 1152]`.  BOTH programs
+reach it through the table in plain XLA, on any backend, and have one
+form: a layer writes its new rows in (a decode step one row a
+sequence, a prefill whole blocks), a full layer scores a row's whole
+context with the indexer, takes the top-k and gathers the selected
+rows, and a window layer gathers only the blocks the window can touch
+(a START position: time O(window) whatever the context; the blocks
+before it are not freed).  A decode step walks the slots in groups of
+`ROW_GROUP` rows so that a group's index scores `[rows, index heads,
+context]` stay small, and gathers a row's selection from the pool by
+flat index; a prefill (`forward_with_prefix`) holds the next tokens of
+SEVERAL sequences, each behind its own cached rows, walks them in query
+blocks of `QUERY_BLOCK` rows, copies a block's sequence's own blocks
+side by side once and gathers its 64 queries' selections from that
+copy, and walks only the blocks that hold a token: the weights are
+read once for all of them.
 
 Layers are a LIST of per-layer dicts and the programs unroll them: the
 two kinds differ in every leaf's shape, and a cut of the published
@@ -75,8 +82,9 @@ FULL, SWA = "full_attention", "sliding_attention"
 # the published order: two full layers, then (window x 3, full) periods
 LAYER_TYPES = (FULL, FULL) + (SWA, SWA, SWA, FULL) * 11
 ROUTE_EPS = 1e-20
-# rows of the slots (decode) or queries (prefill) a full layer scores,
-# selects and gathers for at a time
+# rows a layer scores, selects and gathers for at a time: of the slots
+# (decode), of one sequence's queries (prefill: every sequence of a
+# packed prefill starts on a multiple of it)
 ROW_GROUP = 8
 QUERY_BLOCK = 64
 NEG = -1e30
@@ -343,19 +351,30 @@ def _attend_rows(a: _Attn, q, rows, valid, dtype):
                       rows[..., :a.rank], preferred_element_type=F32)
 
 
-def _attn_out(cfg, a: _Attn, layer, h, o_lat):
-    """o_lat [N, H, rank] -> the half's output [N, D]: out of the
-    latent space through `W_uv`, each head times its gate, `W_o`."""
+def _out_of_latent(cfg, a: _Attn, layer, o_lat):
+    """o_lat [N, H, rank] -> [N, H, v] float32: out of the latent space
+    through `W_uv`."""
     dt = cfg.dtype
     w_uv = layer["wkv_b"].astype(dt).reshape(
         a.rank, a.heads, a.nope + a.v)[..., a.nope:]
-    o = jnp.einsum("nhc,chv->nhv", o_lat.astype(dt), w_uv,
-                   preferred_element_type=F32)
+    return jnp.einsum("nhc,chv->nhv", o_lat.astype(dt), w_uv,
+                      preferred_element_type=F32)
+
+
+def _gated_out(cfg, a: _Attn, layer, h, o):
+    """o [N, H, v] float32 -> the half's output [N, D]: each head times
+    its gate, `W_o`."""
+    dt = cfg.dtype
     with jax.named_scope("attn_gate"):
         g = jax.nn.sigmoid(jnp.dot(h, layer["w_gate_attn"].astype(dt),
                                    preferred_element_type=F32))
         o = (o * g[..., None]).astype(dt)
     return _apply(o.reshape(o.shape[0], -1), layer["wo"], dt)
+
+
+def _attn_out(cfg, a: _Attn, layer, h, o_lat):
+    """o_lat [N, H, rank] -> the half's output [N, D]."""
+    return _gated_out(cfg, a, layer, h, _out_of_latent(cfg, a, layer, o_lat))
 
 
 def _select(scores, k: int, payload=None):
@@ -409,51 +428,89 @@ def _blocked(fn, xs, block: int):
 
 
 # ----------------------------------------------------------------------
-# prefill: one sequence's next S tokens behind its cached rows
+# prefill: several sequences' next tokens, each behind its cached rows
 # ----------------------------------------------------------------------
-def forward_with_prefix(cfg: Dots3Config, params: Dict, tokens: jax.Array,
-                        prefix=None, prefix_len=0, *, kernel: bool = False,
-                        interpret: bool = False):
-    """tokens [S] at positions `prefix_len ..` of ONE sequence -> (logits
-    [S, vocab] float32, (latent [full layers, S, 576], index_k [full
-    layers, S, 128], swa_latent [window layers, S, 1088]): the rows to
-    cache).  `prefix` None: the sequence's first tokens.  Else
-    `(latent [full layers, P, >= 576], index_k [full layers, P, 128],
-    swa [window layers, Wp, >= 1088], swa_pos0)`: the cached rows of
-    positions 0..P of the full layers (those at or past `prefix_len`
-    masked) and, for the window layers, the `Wp >= window - 1` rows
-    from position `swa_pos0` (< 0 where the prefix is shorter) up to
-    `prefix_len`: a window layer never sees more of a prefix, however
-    long.  Right-padding `tokens` changes no real token's result.
+def _live_blocks(fn, n_live, xs, block: int, out_tail: tuple):
+    """`fn(i, *group)` over the first `n_live` groups of `block` rows of
+    every array of `xs`, one after the other in a loop whose TRIP COUNT
+    is `n_live` (a traced scalar): a group past them costs nothing and
+    its rows of the result stay zeros.  `fn` returns `[block,
+    *out_tail]` float32.  Returns (the result, the trips made)."""
+    n = xs[0].shape[0]
 
-    A prompt longer than one program wants to hold is prefilled CHUNK
-    BY CHUNK with this function, each chunk behind the rows of those
-    before it (`serve/llm_engine.py`)."""
-    S = tokens.shape[0]
+    def body(i, carry):
+        out, trips = carry
+        group = [lax.dynamic_slice_in_dim(v, i * block, block, 0) for v in xs]
+        return (lax.dynamic_update_slice_in_dim(out, fn(i, *group),
+                                                i * block, 0), trips + 1)
+
+    return lax.fori_loop(0, n_live, body, (
+        jnp.zeros((n,) + out_tail, F32), jnp.zeros((), jnp.int32)))
+
+
+def forward_with_prefix(cfg: Dots3Config, params: Dict, tokens: jax.Array,
+                        pos: jax.Array, cache, tables: jax.Array, *,
+                        last=None, kernel: bool = False,
+                        interpret: bool = False):
+    """The next tokens of SEVERAL sequences in one pass over the
+    weights, each behind its own cached rows: the decode step's form
+    with many rows a sequence.  `tokens` [N]: the sequences' tokens end
+    to end, each sequence's from a QUERY-BLOCK boundary (`N //
+    tables.shape[0]` rows, whole cache blocks); `pos` [N] a token's
+    position in its own sequence, -1 for padding; `cache` the three
+    paged pools as `decode_step` takes them; `tables` [query blocks, W]
+    each query block's sequence's row of the block table, which has to
+    name the blocks of every position up to the block's last.  A
+    sequence's first position here starts a cache block.
+
+    A layer WRITES its new rows into the sequences' own blocks first,
+    whole blocks at a time (a block's rows past the sequence's last
+    token are written too: whatever padding computed, which the
+    sequence's `pos` masks until decoding overwrites them; a block of
+    padding alone is written nowhere), then attends THROUGH THE TABLE,
+    the new rows with the cached ones: a full layer scores a query
+    block's index keys over its whole table, selects, and gathers the
+    selected rows from a copy of the table's blocks laid side by side;
+    a window layer reads the blocks the block's window can touch.  Only the LIVE query blocks
+    (those that hold a token; they come first) are walked: a padded
+    block costs no score, no sort and no gather.  Projections, gates,
+    experts and norms run once on the `[N, D]` rows, padding routed to
+    no expert.
+
+    Returns (logits float32 of the rows `last` [K] names, or of every
+    row; the cache; `{"query_blocks": the blocks the layers walked,
+    summed}`).  A sequence alone, from position 0 or behind a cached
+    prefix or a chunk of a long prompt behind those before it, is a
+    pack of one."""
     dt = cfg.dtype
-    pos = prefix_len + jnp.arange(S, dtype=jnp.int32)
-    if prefix is None:
-        Wp = cfg.window - 1
-        prefix = (jnp.zeros((cfg.n_full_layers, 0, cfg.latent_dim), dt),
-                  jnp.zeros((cfg.n_full_layers, 0, cfg.index_head_dim), dt),
-                  jnp.zeros((cfg.n_swa_layers, Wp, cfg.swa_latent_dim), dt),
-                  jnp.asarray(-Wp, jnp.int32))
-    p_lat, p_kI, p_swa, swa_pos0 = prefix
-    P, Wp = p_lat.shape[1], p_swa.shape[1]
-    if Wp < cfg.window - 1:
-        raise ValueError(f"a window layer's prefix rows ({Wp}) must cover "
-                         f"window - 1 = {cfg.window - 1} tokens")
-    # full layers: keys are the prefix's rows, then the chunk's own
-    kpos = jnp.concatenate([jnp.arange(P, dtype=jnp.int32), pos])
-    kreal = jnp.concatenate([jnp.arange(P) < prefix_len,
-                             jnp.ones((S,), bool)])
-    # window layers: the prefix's last rows, then the chunk's own
-    wpos = jnp.concatenate([swa_pos0 + jnp.arange(Wp, dtype=jnp.int32), pos])
-    wreal = jnp.concatenate([(wpos[:Wp] >= 0) & (wpos[:Wp] < prefix_len),
-                             jnp.ones((S,), bool)])
-    QB = QUERY_BLOCK if S % QUERY_BLOCK == 0 else S
+    lat_pool, kI_pool, swa_pool = cache
+    NB, BS = lat_pool.shape[1:3]
+    N, (NQ, W) = tokens.shape[0], tables.shape
+    QB = N // NQ
+    if N % NQ or QB % BS:
+        raise ValueError(f"{N} rows in {NQ} query blocks of whole cache "
+                         f"blocks of {BS}")
+    real = pos >= 0
+    n_live = jnp.sum(real[::QB]).astype(jnp.int32)
+    # the cache block each block of BS rows is written to; padding: none
+    first = pos[::BS]
+    wblk = jnp.take_along_axis(
+        tables[jnp.arange(N // BS) * BS // QB],
+        jnp.clip(first // BS, 0, W - 1)[:, None], axis=1)[:, 0]
+    wblk = jnp.where(first >= 0, wblk, NB)
+    pos = jnp.maximum(pos, 0)
+    kpos = jnp.arange(W * BS, dtype=jnp.int32)
+    # a window layer's blocks: from the one a query block's oldest
+    # position lies in to the one its newest does
+    WB = min(-(-(cfg.window - 2 + QB) // BS) + 1, W)
+
+    def write(pool, li, new):
+        new = jnp.pad(new, ((0, 0), (0, pool.shape[-1] - new.shape[-1])))
+        return pool.at[li, wblk].set(
+            new.reshape(N // BS, BS, -1).astype(pool.dtype), mode="drop")
+
     x = _embed(params, tokens, dt).astype(dt)
-    lat_out, kI_out, swa_out = [], [], []
+    walked = jnp.zeros((), jnp.int32)
 
     for i, layer in enumerate(params["layers"]):
         kind = cfg.layer_types[i]
@@ -463,53 +520,76 @@ def forward_with_prefix(cfg: Dots3Config, params: Dict, tokens: jax.Array,
         if kind == FULL:
             with jax.named_scope("dsa_index"):
                 qI, w, kI = _index_inputs(cfg, layer, h, c_q, pos)
-            rows = jnp.concatenate(
-                [p_lat[li][:, :a.latent].astype(dt), new], axis=0)
-            keys = jnp.concatenate([p_kI[li].astype(dt), kI], axis=0)
+                kI_pool = write(kI_pool, li, kI)
+            lat_pool = write(lat_pool, li, new)
+            keys_flat = kI_pool.reshape((-1,) + kI_pool.shape[2:])
+            rows_flat = lat_pool.reshape((-1,) + lat_pool.shape[2:])
 
-            def full(q, qI, w, qpos, rows=rows, keys=keys, a=a):
+            def full(b, q, qI, w, p, li=li, a=a, layer=layer,
+                     keys_flat=keys_flat, rows_flat=rows_flat):
+                # the sequence's own blocks, side by side: 22 MB of rows
+                # at 17k positions, which the selected rows are then
+                # gathered FROM (a quarter of the time a gather from
+                # the whole pool takes: the copy fits the fast memory)
+                tab = li * NB + tables[b]
                 with jax.named_scope("dsa_index"):
-                    sc = _index_scores(qI, w, keys)
-                    ok = kreal[None, :] & (kpos[None, :] <= qpos[:, None])
-                    sc = jnp.where(ok, sc, NEG)
+                    keys = keys_flat[tab].reshape(W * BS, -1)
+                    sc = _index_scores(qI, w, keys.astype(dt))
+                    sc = jnp.where(kpos[None, :] <= p[:, None], sc, NEG)
                 with jax.named_scope("dsa_select"):
-                    idx, real = _select(sc, cfg.index_topk)
+                    idx, picked = _select(sc, cfg.index_topk)
                 with jax.named_scope("dsa_attn"):
-                    return _attend_rows(a, q, rows[idx], real, dt)
+                    rows = rows_flat[tab][..., :a.latent].reshape(
+                        W * BS, -1)
+                    return _out_of_latent(cfg, a, layer, _attend_rows(
+                        a, q, rows[idx], picked, dt))
 
-            o_lat = _blocked(full, (q, qI, w, pos), QB)
-            lat_out.append(new)
-            kI_out.append(kI)
+            o, trips = _live_blocks(full, n_live, (q, qI, w, pos), QB,
+                                    (a.heads, a.v))
         else:
-            rows = jnp.concatenate(
-                [p_swa[li][:, :a.latent].astype(dt), new], axis=0)
+            swa_pool = write(swa_pool, li, new)
+            pages = swa_pool.reshape((-1,) + swa_pool.shape[2:])
 
-            def swa(q, qpos, at, rows=rows, a=a):
-                # a block of queries sees the Wp rows before it and itself
-                n = q.shape[0]
-                keys = lax.dynamic_slice_in_dim(rows, at[0], Wp + n, 0)
-                kp = lax.dynamic_slice_in_dim(wpos, at[0], Wp + n, 0)
-                kr = lax.dynamic_slice_in_dim(wreal, at[0], Wp + n, 0)
-                d = qpos[:, None] - kp[None, :]
-                ok = kr[None, :] & (d >= 0) & (d < cfg.window)
+            def swa(b, q, p, li=li, a=a, layer=layer, pages=pages):
+                b0 = jnp.maximum(p[0] - (cfg.window - 1), 0) // BS
+                wtab = tables[b][jnp.clip(b0 + jnp.arange(WB), 0, W - 1)]
+                d = p[:, None] - (b0 * BS + jnp.arange(WB * BS))[None, :]
                 with jax.named_scope("swa_attn"):
-                    return _attend_rows(a, q, keys, ok, dt)
+                    rows = pages[li * NB + wtab].reshape(WB * BS, -1)
+                    return _out_of_latent(cfg, a, layer, _attend_rows(
+                        a, q, rows, (d >= 0) & (d < cfg.window), dt))
 
-            o_lat = _blocked(swa, (q, pos, jnp.arange(S, dtype=jnp.int32)),
-                             QB)
-            swa_out.append(new)
-        x = x + _attn_out(cfg, a, layer, h, o_lat)
+            o, trips = _live_blocks(swa, n_live, (q, pos), QB,
+                                    (a.heads, a.v))
+        walked = walked + trips
+        x = x + _gated_out(cfg, a, layer, h, o)
         x, _ = _ffn(cfg, layer, x, kernel=kernel, interpret=interpret,
-                    row_mask=None)
+                    row_mask=real)
+    if last is not None:
+        x = x[last]
     x = _rms_norm(x, params["final_norm"].astype(dt), cfg.norm_eps)
-    return _lm_head(x, params, dt), (
-        jnp.stack(lat_out), jnp.stack(kI_out), jnp.stack(swa_out))
+    return (_lm_head(x, params, dt), (lat_pool, kI_pool, swa_pool),
+            {"query_blocks": walked})
 
 
 def forward(cfg: Dots3Config, params: Dict, tokens: jax.Array, **kw):
     """tokens [T] -> logits [T, vocab] float32: the whole sequence in
-    one program (tests; a serving prompt goes chunk by chunk)."""
-    return forward_with_prefix(cfg, params, tokens, **kw)[0]
+    one program, through a cache of its own (tests; a serving prompt
+    goes through the engine's pool, chunk by chunk)."""
+    T = tokens.shape[0]
+    QB = QUERY_BLOCK if T % QUERY_BLOCK == 0 else T
+    BS = math.gcd(T, 8)
+    nb = T // BS
+    cache = tuple(
+        jnp.zeros((n, nb + 1, BS, d), cfg.dtype) for n, d in (
+            (cfg.n_full_layers, cfg.latent_dim),
+            (cfg.n_full_layers, cfg.index_head_dim),
+            (cfg.n_swa_layers, cfg.swa_latent_dim)))
+    tables = jnp.broadcast_to(jnp.arange(1, nb + 1, dtype=jnp.int32),
+                              (T // QB, nb))
+    return forward_with_prefix(cfg, params, tokens,
+                               jnp.arange(T, dtype=jnp.int32), cache,
+                               tables, **kw)[0]
 
 
 # ----------------------------------------------------------------------

@@ -69,6 +69,13 @@ tells the engine
     so the host never has to tell the device that a row ended).
     Without `stop0, stop` it returns `(*cache, pos, tok)`.
 
+  A model may have ONE admission family in place of `prefill_packed`,
+  `suffix_prefill` and `kv_write` (`packs_suffixes`):
+  `suffix_prefill_packed(N)`, a tick's admissions of every kind (a
+  prefix hit, a miss, a chunk of a long prompt) in one program, each
+  behind its own cached rows through the block table
+  (`SparseLatentEngineModel` has the signature).
+
 `PagedKV` states the paged kind's FORMAT once: its leaves, blocks ->
 rows of the compute dtype, rows -> blocks (int8 pools with their scale
 sidecar are a value of it, not a family of bodies).  `SlotState` states
@@ -92,7 +99,8 @@ state) and `HybridEngineModel` (`models/lfm2.py`: paged K and V in the
 attention layers, a per-slot convolution state in the others, both in
 one spec) and `SparseLatentEngineModel` (`models/dots3.py`: latent
 attention of two forms with a learned selection; three paged leaves of
-different widths and layer counts on one table; no packed prefill).
+different widths and layer counts on one table; one admission family,
+its suffixes packed).
 `engine_model_for` picks by the config's type, builds the
 format from the user's `kv_dtype` and hands the implementer the
 resolved route: a user passes a model's config and the model picks its
@@ -366,13 +374,16 @@ class _EngineModel:
     `segmented` (a packed prefill may hold several prompts), `aux_rows`
     and `tick_fields` (the model's own per-tick counters; none here),
     `state_write_deferred` (a per-slot state is written at a chunk's
-    last step only, not at every step), `packs` (it has a packed
-    prefill; False: every admission is a suffix prefill, behind a
-    cached prefix or behind nothing)."""
+    last step only, not at every step), `packs_suffixes` (ONE admission
+    family, `suffix_prefill_packed`: a tick's admissions, hits, misses
+    and chunks of long prompts alike, share a program, each behind its
+    own rows through the block table; it then has no `prefill_packed`,
+    `suffix_prefill` or `kv_write`) and `pack_align` (the rows each of
+    them is aligned to in that program)."""
 
     aux_rows = 0
     state_write_deferred = False
-    packs = True
+    packs_suffixes = False
 
     def __init__(self, cfg, kv: Optional[PagedKV], *, chunk: int,
                  paged: bool, interpret: bool,
@@ -715,19 +726,41 @@ class SparseLatentEngineModel(_ExpertCounters, _EngineModel):
     Both programs reach the pools through the table in plain XLA on
     any backend (no dense view, no route of its own for the CPU);
     `paged` only picks the experts' grouped products (Pallas on a TPU,
-    `lax.ragged_dot` elsewhere).  `packs` False: a selection makes
-    every query's key set its own, so there is no packed prefill of
-    several prompts under one mask; EVERY admission is a suffix
-    prefill, behind its cached prefix or behind nothing, and a long
-    prompt chunk by chunk (the engine's `prefill_chunk`).  The decode
-    program hands back the HELD experts' two counters (`aux_rows`)."""
+    `lax.ragged_dot` elsewhere).
 
-    packs = False
+    ONE ADMISSION FAMILY (`packs_suffixes`), `suffix_prefill_packed(N)`,
+    printed `jit_suffix_prefill_packed_n<N>`: a selection makes every
+    query's key set its own, so nothing is gained by one mask over
+    several prompts, but everything by ONE READ OF THE WEIGHTS for a
+    tick's admissions.  `(params, *cache, tokens [N], posn [N], tables
+    [N / pack_align, W], last [K], slots [K], pos0 [K], stop0 [K], pos,
+    tok, stop) -> (*cache, pos, tok, stop)`: up to `K` requests' next
+    tokens end to end in a row of `N`, each from a multiple of
+    `pack_align` (`dots3.QUERY_BLOCK` rows, or the largest power of two
+    below it that the engine's sizes allow), at positions `posn` of
+    their own sequences (-1: padding); a prefix hit starts behind its
+    cached blocks, a miss at 0, a chunk of a long prompt behind the
+    chunks before it.  Each query block of `pack_align` rows carries
+    its sequence's whole row of the block table (`W` the engine's
+    `_max_seq_blocks`: the program's shape knows nothing of a prefix's
+    length), a layer writes the new rows into the requests' own blocks
+    and then attends through the table as the decode step does, and
+    only the query blocks that hold a token are walked
+    (`dots3.forward_with_prefix`).  The head runs on the `K` rows
+    `last` names, the first tokens are picked inside and the admitted
+    slots' state set (a chunk that is not its prompt's last names a
+    slot past the last: dropped), as `packed_prefill_program` does.
+    `N` comes from the engine's closed set (`_pack_sizes`: 128, 256,
+    512, 1,024 and `prefill_chunk`), all of it warmed at start.  The
+    decode program hands back the HELD experts' two counters
+    (`aux_rows`)."""
+
+    packs_suffixes = True
+    pack_align = dots3.QUERY_BLOCK
 
     def __init__(self, cfg, kv: PagedKV, **route):
         super().__init__(cfg, kv, **route)
         self._pairs = cfg.n_moe_layers * cfg.experts_held
-        self._widths = [leaf.tail[0] for leaf in kv.leaves]
 
     def tick_fields(self, aux) -> Dict[str, object]:
         return {**super().tick_fields(aux),
@@ -755,55 +788,27 @@ class SparseLatentEngineModel(_ExpertCounters, _EngineModel):
 
         return chunk_program(step, self.chunk, aux=self._aux)
 
-    def _prefix(self, cache, blk_ids, prefix_len):
-        """The cached rows a suffix prefill reads: the full layers' of
-        every prefix block, the window layers' of the last blocks that
-        cover `window - 1` tokens (`prefix_len` is whole blocks)."""
-        bs = self.kv.block_size
-        lat, kI, swa = cache
-        p_lat, p_kI = (r[:, 0] for r in self.kv.rows((lat, kI), blk_ids))
-        wb = -(-(self.cfg.window - 1) // bs)
-        j = prefix_len // bs - wb + jnp.arange(wb)
-        ids = jnp.where(j >= 0, blk_ids[jnp.clip(j, 0, blk_ids.shape[0] - 1)],
-                        0)
-        p_swa = jnp.take(swa, ids, axis=1)
-        p_swa = p_swa.reshape(swa.shape[0], wb * bs, swa.shape[-1])
-        return p_lat, p_kI, p_swa, (prefix_len // bs - wb) * bs
-
     def prefill(self, bucket: int):
         def _pf(params, prompt):  # prompt [1, bucket], right-padded
-            logits, rows = dots3.forward_with_prefix(
-                self.cfg, params, prompt[0], **self._kw())
-            return (logits, *(r[:, None] for r in rows))
+            # the rows stay in the call's own cache
+            return (dots3.forward(self.cfg, params, prompt[0], **self._kw()),)
 
         return _pf
 
-    def suffix_prefill(self, s_bucket: int, p_blocks: int):
-        def _pf(params, *flat):
-            *cache, suffix, blk_ids, prefix_len = flat
-            logits, rows = dots3.forward_with_prefix(
-                self.cfg, params, suffix[0],
-                self._prefix(cache, blk_ids, prefix_len), prefix_len,
-                **self._kw())
-            return (logits, *(r[:, None] for r in rows))
+    def suffix_prefill_packed(self, N: int):
+        cfg, kw, n = self.cfg, self._kw(), len(self.kv.leaves)
 
-        return _pf
+        def _fn(params, *flat):
+            cache = flat[:n]
+            (tokens, posn, tables, last, slots, pos0, stop0,
+             pos, tok, stop) = flat[n:]
+            logits, cache, _ = dots3.forward_with_prefix(
+                cfg, params, tokens, posn, cache, tables, last=last, **kw)
+            tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (*cache,
+                    *_admitted(pos, tok, stop, slots, pos0, tok0, stop0))
 
-    def kv_write(self, t_in: int, nb: int):
-        target = nb * self.kv.block_size
-
-        def fit(*rows):  # each [L, 1, t_in, d] -> nb blocks, pool width
-            return tuple(
-                jnp.pad(r[:, :, :target],
-                        ((0, 0), (0, 0), (0, max(0, target - t_in)),
-                         (0, width - r.shape[-1])))
-                for r, width in zip(rows, self._widths))
-
-        return kv_write_program(self.kv, fit)
-
-    def prefill_packed(self, N: int):
-        raise NotImplementedError(
-            "a selection has no packed prefill: `packs` is False")
+        return _fn
 
 
 def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):

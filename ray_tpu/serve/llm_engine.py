@@ -54,7 +54,9 @@ TPU-native design points:
   last row only, the greedy pick and the block writes inside it), so
   they share one read of the weights and nothing comes back to the
   host.  `N` comes from a small closed set (`_pack_sizes`), all of it
-  compiled and run once at start on the kernel route.
+  compiled and run once at start on the kernel route.  A model whose
+  prefill reads the cache through the block table packs its prefix
+  HITS the same way (`suffix_prefill_packed_n<N>`).
 - A ROW THAT OWES NO TOKEN COSTS NOTHING IT CAN AVOID: the device holds
   `stop[slots]` beside `pos` and `tok`, set at admission to `T + n_new
   - 1`; in every decode step a row is live iff `pos < stop`.  A dead
@@ -72,6 +74,7 @@ the same prompt, with the prefix cache on or off
 from __future__ import annotations
 
 import logging
+import math
 import os
 import threading
 import time as _time
@@ -212,6 +215,15 @@ class _Plan(NamedTuple):
     own: List[int]     # the blocks allocated to it, in position order
 
 
+class _Part(NamedTuple):
+    """Tokens `lo .. hi` of a planned request's prompt, from a block
+    boundary: what ONE program prefills of it where a model packs its
+    suffixes (the uncached part whole, or a chunk of it)."""
+    plan: _Plan
+    lo: int
+    hi: int
+
+
 class LlamaEngine:
     """Resident continuous-batching decode engine over a paged KV pool.
 
@@ -233,8 +245,9 @@ class LlamaEngine:
     state (`models/brumby.py`) — and `HybridEngineModel` — paged K and V
     beside a per-slot state in one spec (`models/lfm2.py`) — and
     `SparseLatentEngineModel` — three paged leaves of different widths,
-    a learned selection, no packed prefill (`models/dots3.py`).  The
-    class keeps its name; nothing a caller passes changed.
+    a learned selection, one admission family that packs a tick's
+    suffixes (`models/dots3.py`).  The class keeps its name; nothing a
+    caller passes changed.
 
     submit() is thread-safe and returns a `concurrent.futures.Future`
     resolving to the generated token ids (greedy — identical to what a
@@ -261,9 +274,17 @@ class LlamaEngine:
     CHUNK, each chunk a suffix prefill behind the request's own blocks
     (the cached prefix's, then those the chunks before it wrote), so a
     prompt may be as long as `max_len` allows without a program that
-    attends `[max_len, max_len]`.  A model with no packed prefill
-    (`engine_model.packs` False) is admitted that way always, the whole
-    uncached part one chunk where none is set.
+    attends `[max_len, max_len]`.
+
+    A model that PACKS ITS SUFFIXES (`engine_model.packs_suffixes`) has
+    one admission path and one program family,
+    `suffix_prefill_packed_n<N>`: all of a tick's admissions, prefix
+    hits, misses and the chunks of long prompts alike, lie end to end in
+    as few programs as hold them, each behind its own request's blocks
+    through the block table (`_prefill_behind`), so a tick's hits share
+    one read of the weights.  `N` comes from the same closed set, warmed
+    at start; `prefill_chunk` caps it.  The other paged models keep one
+    program a hit (`_run_suffix`).
 
     A model whose cache holds BOTH kinds (paged K and V in its attention
     layers, a per-slot state in the others) is admitted when a slot AND
@@ -457,9 +478,14 @@ class LlamaEngine:
                     f"of {self.block_size}")
             self._prefill_chunk = int(prefill_chunk)
             top = min(top, self._prefill_chunk)
-        self._pack_sizes = (_pack_sizes(top, self.block_size)
-                            if self._model.packs else [])
+        self._pack_sizes = _pack_sizes(top, self.block_size)
         self._pack_rows = ADMIT_BUDGET if self._model.segmented else 1
+        # where a model packs its suffixes: the rows each request is
+        # aligned to (whole blocks that divide every size)
+        self._pack_align = self.block_size
+        if self._model.packs_suffixes:
+            self._pack_align = math.lcm(self.block_size, math.gcd(
+                *self._pack_sizes, self._model.pack_align))
         # overload plane: bound the admission queue and shed queued
         # requests whose caller has (or must have) given up BEFORE
         # they burn prefill compute.  All counters are plain ints
@@ -558,8 +584,10 @@ class LlamaEngine:
         proves the Mosaic lowering and the in-place pool update; where
         the kernels cannot compile the engine fails HERE, at start,
         instead of serving through another route.  Then every packed
-        prefill of the closed set, empty (padding only, into scratch):
-        no admission compiles after this, whatever the traffic."""
+        prefill of the closed set (of a model that packs its suffixes:
+        every packed suffix prefill), empty (padding only, into scratch
+        or nowhere): no admission compiles after this, whatever the
+        traffic."""
         tables = (self._jnp.full(
             (self.slots, 1), SCRATCH_BLOCK, self._jnp.int32),
         ) if self._has_blocks else ()
@@ -567,8 +595,10 @@ class LlamaEngine:
         self._cache = tuple(cfn(
             self.params, *self._cache, *tables, self._tok, self._pos,
             self._stop)[:len(self._cache)])
+        run = (self._run_suffixes if self._model.packs_suffixes
+               else self._run_packed)
         for n in self._pack_sizes:
-            self._run_packed(n, [])
+            run(n, [])
         self._jax.block_until_ready(self._cache)
 
     # -- public surface ------------------------------------------------
@@ -848,11 +878,17 @@ class LlamaEngine:
 
     def _prefill_packed_for(self, N: int):
         """Admission's program: up to `_pack_rows` prompts in one row
-        of `N` tokens (`engine_model`'s `prefill_packed`)."""
+        of `N` tokens (`engine_model`'s `prefill_packed`; of a model
+        that packs its suffixes, its `suffix_prefill_packed`)."""
         fn = self._packed_cache.get(N)
         if fn is None:
-            _pf = self._model.prefill_packed(N)
-            _pf.__name__ = f"prefill_packed_n{N}"
+            if self._model.packs_suffixes:
+                _pf = self._model.suffix_prefill_packed(N)
+                # found with the suffix programs by their prefix
+                _pf.__name__ = f"suffix_prefill_packed_n{N}"
+            else:
+                _pf = self._model.prefill_packed(N)
+                _pf.__name__ = f"prefill_packed_n{N}"
             fn = self._packed_cache[N] = self._jax.jit(
                 _pf, donate_argnums=tuple(range(1, 1 + len(self._cache))))
         return fn
@@ -1036,6 +1072,10 @@ class LlamaEngine:
         }
         return _Plan(req, slot, prompt, shared, own)
 
+    def _pack_size(self, used: int) -> int:
+        """The smallest packed program that holds `used` tokens."""
+        return next(n for n in self._pack_sizes if n >= used)
+
     def _prefill(self, plans: List[_Plan]) -> None:
         """Admission's second phase: the planned requests' prefills,
         dispatched (async; nothing is read back).  Those with no cached
@@ -1043,9 +1083,12 @@ class LlamaEngine:
         hold them (a program holds `_pack_rows` prompts and the largest
         pack size of tokens, each prompt in whole blocks); then each
         prefix hit prefills its suffix, one program a request, behind
-        whatever wrote the blocks it shares."""
+        whatever wrote the blocks it shares.  A model that packs its
+        suffixes has ONE path for all of them (`_prefill_behind`)."""
+        if self._model.packs_suffixes:
+            return self._prefill_behind(plans)
         bs = self.block_size
-        cap = self._pack_sizes[-1] if self._pack_sizes else 0
+        cap = self._pack_sizes[-1]
         # a prompt no packed program holds goes chunk by chunk
         chunked = {id(p) for p in plans
                    if not p.shared and len(p.prompt) > cap}
@@ -1056,8 +1099,7 @@ class LlamaEngine:
             need = 0 if plan is None else _cdiv(len(plan.prompt), bs) * bs
             if pack and (plan is None or used + need > cap
                          or len(pack) == self._pack_rows):
-                self._run_packed(next(n for n in self._pack_sizes
-                                      if n >= used), pack)
+                self._run_packed(self._pack_size(used), pack)
                 pack, used = [], 0
             if plan is not None:
                 pack.append(plan)
@@ -1065,6 +1107,38 @@ class LlamaEngine:
         for plan in plans:
             if plan.shared or id(plan) in chunked:
                 self._run_suffix(plan)
+
+    def _prefill_behind(self, plans: List[_Plan]) -> None:
+        """The planned requests' prefills where the model packs its
+        suffixes: what no cached prefix covers of each prompt (behind a
+        hit, or all of it), cut into parts of at most the largest pack
+        size, the parts in arrival order end to end in as few programs
+        as hold them (`_pack_rows` parts and the largest size of
+        tokens, each part from a multiple of `_pack_align`).  A tick's
+        hits share one read of the weights; a long prompt's chunks are
+        programs of their own, each behind the one before."""
+        bs, cap, align = self.block_size, self._pack_sizes[-1], \
+            self._pack_align
+        pack: List[_Part] = []
+        used = 0
+        for plan in plans:
+            T, P = len(plan.prompt), len(plan.shared) * bs
+            starts = range(P, T, cap)
+            plan.req["prefill_chunks"] = len(starts)
+            for lo in starts:
+                part = _Part(plan, lo, min(T, lo + cap))
+                need = _cdiv(part.hi - lo, align) * align
+                if pack and (used + need > cap
+                             or len(pack) == self._pack_rows):
+                    self._run_suffixes(self._pack_size(used), pack)
+                    pack, used = [], 0
+                pack.append(part)
+                used += need
+            self._hit_tokens += P
+            self._prefix_hits += bool(P)
+            self._prefill_rows += 1
+        if pack:
+            self._run_suffixes(self._pack_size(used), pack)
 
     def _admitting(self, plans: List[_Plan]) -> None:
         """Queue wait ends HERE, where the host starts on the program
@@ -1117,6 +1191,43 @@ class LlamaEngine:
             return tokens, seg, posn, last, slots, pos0, stop0
         return tokens, seg, posn, blk_ids, last, slots, pos0, stop0
 
+    def _suffix_arrays(self, N: int, pack: List[_Part]) -> tuple:
+        """A packed suffix prefill's host-made arguments, `(tokens,
+        posn, tables, last, slots, pos0, stop0)`: the parts of `pack`
+        end to end in a row of `N` tokens, each from a multiple of
+        `_pack_align`, at the positions of its own sequence (padding:
+        -1); every query block of `_pack_align` rows with its request's
+        row of the block table.  Only a part that ends its prompt picks
+        a token and sets its slot."""
+        align, K = self._pack_align, self._pack_rows
+        i32 = np.int32
+        tokens, posn = np.zeros(N, i32), np.full(N, -1, i32)
+        tables = np.full((N // align, self._max_seq_blocks), SCRATCH_BLOCK,
+                         i32)
+        last, pos0, stop0 = (np.zeros(K, i32) for _ in range(3))
+        slots = np.full(K, self.slots, i32)  # out of range: dropped
+        at = 0
+        for i, (plan, lo, hi) in enumerate(pack):
+            blocks = plan.shared + plan.own
+            nq = _cdiv(hi - lo, align)
+            tokens[at:at + hi - lo] = plan.prompt[lo:hi]
+            posn[at:at + hi - lo] = np.arange(lo, hi)
+            tables[at // align:at // align + nq, :len(blocks)] = blocks
+            if hi == len(plan.prompt):
+                last[i], slots[i] = at + hi - lo - 1, plan.slot
+                pos0[i], stop0[i] = hi, plan.req["stop"]
+            at += nq * align
+        return tokens, posn, tables, last, slots, pos0, stop0
+
+    def _launch_packed(self, N: int, arrays: tuple) -> None:
+        """The packed program of size `N` on `arrays`, the cache and
+        the slots' device state rebound to what it returns."""
+        out = self._launch(
+            self._prefill_packed_for(N), self.params, *self._cache,
+            *arrays, self._pos, self._tok, self._stop)
+        self._cache = tuple(out[:-3])
+        self._pos, self._tok, self._stop = out[-3:]
+
     def _run_packed(self, N: int, pack: List[_Plan]) -> None:
         """ONE program for the prompts of `pack`, end to end in a row
         of `N` tokens, each from a block boundary: their KV into their
@@ -1126,16 +1237,29 @@ class LlamaEngine:
         real = sum(len(plan.prompt) for plan in pack)
         with self._phase("prefill", N=N, rows=len(pack), tokens=real):
             self._admitting(pack)
-            arrays = self._pack_arrays(N, pack)
-            out = self._launch(
-                self._prefill_packed_for(N), self.params, *self._cache,
-                *arrays, self._pos, self._tok, self._stop)
-            self._cache = tuple(out[:-3])
-            self._pos, self._tok, self._stop = out[-3:]
+            self._launch_packed(N, self._pack_arrays(N, pack))
             self._prefilled(pack)
         if pack:
             self._prefill_calls += 1
             self._prefill_rows += len(pack)
+            self._prefill_tokens += real
+            self._prefill_padded_tokens += N
+
+    def _run_suffixes(self, N: int, pack: List[_Part]) -> None:
+        """ONE program for the parts of `pack`, each behind its own
+        request's blocks through the block table: their rows into the
+        requests' blocks, and for a part that ends its prompt the first
+        token picked on the device and the slot's `pos` / `tok` /
+        `stop` set.  An empty pack is the warm-up: padding only, which
+        is written nowhere."""
+        plans = [part.plan for part in pack]
+        real = sum(part.hi - part.lo for part in pack)
+        with self._phase("prefill", N=N, rows=len(pack), tokens=real):
+            self._admitting(plans)
+            self._launch_packed(N, self._suffix_arrays(N, pack))
+            self._prefilled(plans)
+        if pack:
+            self._prefill_calls += 1
             self._prefill_tokens += real
             self._prefill_padded_tokens += N
 

@@ -60,54 +60,38 @@ def ref_logits(cfg, params, toks, **kw):
                                cfg.norm_eps)), overlaps
 
 
-_JITS = {}
-
-
-def _jit(em, family, *key):
-    """One compiled program a shape, shared by the tests' caches."""
-    if (family, key) not in _JITS:
-        _JITS[family, key] = jax.jit(getattr(em, family)(*key))
-    return _JITS[family, key]
-
-
 _DECODE = jax.jit(
     lambda p, c, t, q, tb: dots3.decode_step(CFG, p, t, c, q, tb))
+_PREFILL = jax.jit(
+    lambda p, t, q, c, tb: dots3.forward_with_prefix(CFG, p, t, q, c, tb))
 
 
 class Cache:
-    """One sequence's paged cache, driven through the engine model's own
-    programs as the engine drives them."""
+    """One sequence's paged cache, in the leaves the engine model names,
+    driven through the model's two functions as the engine's programs
+    drive them."""
 
     def __init__(self, cfg, params, blocks=24):
         self.cfg, self.params = cfg, params
-        self.em = engine_model_for(cfg, kv_dtype="model", block_size=BS,
-                                   chunk=1, paged=False, interpret=False)
+        em = engine_model_for(cfg, kv_dtype="model", block_size=BS,
+                              chunk=1, paged=False, interpret=False)
         self.cache = tuple(
             jnp.zeros((leaf.layers, blocks + 1, BS) + leaf.tail, leaf.dtype)
-            for leaf in self.em.cache_leaves)
+            for leaf in em.cache_leaves)
         self.table = list(range(1, blocks + 1))
-        self.state = [jnp.zeros((1,), jnp.int32)] * 3  # pos, tok, stop
 
     def prefill(self, toks, lo, hi, bucket=None):
-        """Tokens lo..hi behind the cached 0..lo; returns their logits."""
+        """Tokens lo..hi behind the cached 0..lo, a pack of one in a
+        program of `bucket` rows; returns their logits."""
         S = hi - lo
-        bucket = bucket or S
-        before = self.table[:lo // BS]
-        p_bucket = max(1, 1 << max(0, len(before) - 1).bit_length())
-        blk = jnp.asarray(before + [0] * (p_bucket - len(before)), jnp.int32)
-        suffix = jnp.asarray([list(toks[lo:hi]) + [0] * (bucket - S)],
-                             jnp.int32)
-        logits, *kv = _jit(self.em, "suffix_prefill", bucket, p_bucket)(
-            self.params, *self.cache, suffix, blk, jnp.asarray(lo, jnp.int32))
-        nb = -(-S // BS)
-        pos, tok, stop = self.state
-        out = _jit(self.em, "kv_write", bucket, nb)(
-            *self.cache, *kv,
-            jnp.asarray(self.table[lo // BS:lo // BS + nb], jnp.int32),
-            jnp.asarray(0, jnp.int32), jnp.asarray(hi, jnp.int32),
-            jnp.asarray(0, jnp.int32), pos, tok, jnp.asarray(0, jnp.int32),
-            stop)
-        self.cache = tuple(out[:-3])
+        bucket = bucket or -(-S // BS) * BS
+        posn = np.full(bucket, -1, np.int32)
+        posn[:S] = np.arange(lo, hi)
+        suffix = np.zeros(bucket, np.int32)
+        suffix[:S] = toks[lo:hi]
+        logits, self.cache, _ = _PREFILL(
+            self.params, jnp.asarray(suffix), jnp.asarray(posn), self.cache,
+            jnp.asarray([self.table], jnp.int32))
         return np.asarray(logits[:S])
 
     def decode(self, tok, pos, width=None):
