@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops.attention import checkpoint_block
 from ray_tpu.parallel.ring_attention import plain_attention, select_attention
 
 
@@ -50,13 +51,15 @@ class GPT2Config:
     # length is n_layer, or n_layer/2 under remat_policy="half")
     scan_unroll: int = 1
     # remat policy: "full" recomputes the whole block backward (min
-    # memory); "dots" saves matmul outputs (checkpoint_policies
-    # dots_with_no_batch_dims_saveable); "names" saves exactly the
-    # tagged matmul inputs (see `_SAVED_NAMES`) so the backward
-    # recomputes ONLY the attention score/prob internals — the
-    # quadratic part — instead of the whole block (~15% of fwd FLOPs
-    # recomputed vs 100% for "full", at ~750 MB/layer saved residuals
-    # for the 124M bench shapes)
+    # memory) but the flash kernel's two results, which the block keeps
+    # (`ops.attention.checkpoint_block`: 33 MB a layer at gpt2-medium,
+    # 16 x 1024; nothing with another attention); "dots" saves matmul
+    # outputs (checkpoint_policies dots_with_no_batch_dims_saveable);
+    # "names" saves exactly the tagged matmul inputs (see
+    # `_SAVED_NAMES`) so the backward recomputes ONLY the attention
+    # score/prob internals — the quadratic part — instead of the whole
+    # block (~15% of fwd FLOPs recomputed vs 100% for "full", at
+    # ~750 MB/layer saved residuals for the 124M bench shapes)
     remat_policy: str = "full"
     # layers exempted from remat (the LAST `remat_skip` of the stack
     # keep their activations resident and skip the backward's forward
@@ -254,7 +257,7 @@ def backbone(cfg: GPT2Config, params: Dict, tokens: jax.Array,
                     ),
                 )
             else:
-                fn = jax.checkpoint(one)
+                fn = checkpoint_block(one)
         else:
             fn = one
         return fn(x), None

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops.attention import checkpoint_block
 from ray_tpu.parallel.ring_attention import plain_attention, select_attention
 
 
@@ -364,7 +365,7 @@ def forward(cfg: LlamaConfig, params: Dict, tokens: jax.Array,
                                   layer.get("wo_scale"))
             return _mlp(cfg, x1, layer, layer_lora), k_kv, v_kv
 
-        fn = jax.checkpoint(one) if cfg.remat else one
+        fn = checkpoint_block(one) if cfg.remat else one
         out, k_kv, v_kv = fn(x)
         return out, ((k_kv, v_kv) if return_kv else None)
 

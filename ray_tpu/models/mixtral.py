@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.llama import _apply, _rms_norm, _rope
+from ray_tpu.ops.attention import checkpoint_block
 from ray_tpu.parallel.moe import MoEConfig, init_moe, moe_forward
 from ray_tpu.parallel.ring_attention import select_attention
 
@@ -170,7 +171,7 @@ def forward(cfg: MixtralConfig, params: Dict, tokens: jax.Array,
             moe_out, aux = moe_forward(moe_cfg, moe_params, h2, mesh)
             return x1 + moe_out, aux["load_balance_loss"]
 
-        fn = jax.checkpoint(one) if cfg.remat else one
+        fn = checkpoint_block(one) if cfg.remat else one
         out, aux_loss = fn(x)
         return out, aux_loss
 

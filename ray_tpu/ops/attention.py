@@ -74,6 +74,19 @@ tests); `tests/test_aot_tpu_compile.py` lowers the train shapes for a
 described v5e chip.  The benchmark finds the calls in a trace by their
 RESULT shapes (`(bf16[B*H,T,hd], f32[B*H,T,1])` and three
 `bf16[B*H,T,hd]`): operands and scratch are free, results are not.
+
+Under remat the forward kernel is NOT replayed.  `_fwd` names its two
+results (`FLASH_RESIDUALS`: "flash_out", and "flash_lse" squeezed to
+`[B*H, T]`) with `checkpoint_name`, and `checkpoint_block` is the
+`jax.checkpoint` that keeps exactly those: a block's replay recomputes
+q, k, v and everything else, and reads the kernel's results back (1 MB
++ 32 MB of numbers a layer at the train shapes).  That buys a fifth of
+the call it spares (0.15 of 0.65 ms), because the kernel's lse is
+`f32[B*H, T, 1]`, lane-padded 128 x in HBM: squeezing it after the
+forward call and handing it back to the backward kernel are two XLA
+passes over 134 MB (0.18 + 0.30 ms) that the kernels hide under their
+products (PERF.md section 6, PR 46).  Outside such a checkpoint the
+names lower to nothing.
 """
 
 from __future__ import annotations
@@ -83,8 +96,12 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
+
+# what a remat block keeps of the forward kernel (`checkpoint_block`)
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _dot_f32(a, b, trans_b=False):
@@ -565,12 +582,20 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
     fwd = _build_fwd(causal, scale, block_q, block_k, n_k, interpret,
                      q.dtype, (_sub_tile(block_q), _sub_tile(block_k)))
     out, lse = fwd(_fold(q), _fold(k), _fold(v))
-    # lse and out stay folded [B*H, T, ...] for the backward kernels
-    return _unfold(out, B, H), (q, k, v, lse, out)
+    # the two residuals worth more than their bytes (FLASH_RESIDUALS):
+    # out as the caller's out-projection reads it, [B, T, H*D] (folded
+    # [B*H, T, 64] it is lane-padded to twice the bytes in HBM), and
+    # lse WITHOUT its singleton lane dim (f32[B*H, T, 1]: 128 x)
+    out = checkpoint_name(_unfold(out, B, H).reshape(B, T, H * D),
+                          "flash_out")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
+    return out.reshape(B, T, H, D), (q, k, v, lse, out)
 
 
 def _bwd(causal, block_q, block_k, interpret, res, g):
-    q, k, v, lse, out_folded = res
+    q, k, v, lse, out = res
+    out_folded = _fold(out.reshape(q.shape))
+    lse = lse[..., None]  # [BH, T, 1], as the kernels take it
     B, T, H, D = q.shape
     block_q, block_k = _blocks(q, block_q, block_k)
     scale = 1.0 / (D ** 0.5)
@@ -596,3 +621,21 @@ def _bwd(causal, block_q, block_k, interpret, res, g):
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+def checkpoint_block(fn):
+    """`jax.checkpoint(fn)` for a transformer block that a `lax.scan`
+    walks: everything is recomputed in the backward pass but what the
+    flash kernel names (`FLASH_RESIDUALS`).  A block that holds no such
+    name (dense, ring or ulysses attention; the kernel inside a
+    `shard_map`) keeps nothing, as under a bare `jax.checkpoint`.
+
+    `prevent_cse=False`: the forward and the replay of a scanned block
+    live in two different loops, where nothing can merge them, and the
+    barriers that would prevent it keep XLA from fusing a layer's
+    slices of the stacked weights and activations into their readers
+    (4 ms of a 407 ms step at gpt2-medium, PERF.md section 6, PR 46)."""
+    return jax.checkpoint(
+        fn, prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS))

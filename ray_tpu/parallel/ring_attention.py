@@ -32,7 +32,13 @@ _NEG_INF = -1e30
 
 def select_attention(kind: str, q, k, v, mesh=None, causal: bool = True):
     """One dispatch point for the attention backends (dense | flash |
-    ring | ulysses) shared by all model families."""
+    ring | ulysses) shared by all model families.
+
+    Without a mesh a remat block (`ops.attention.checkpoint_block`)
+    keeps the flash kernel's `out` and `lse` and its replay does not
+    run the kernel again.  Under a mesh the kernel sits inside the
+    `shard_map` below, its names with it, where the block's policy does
+    not see them: that route recomputes the forward kernel."""
     if kind == "flash":
         from ray_tpu.ops import flash_attention
 

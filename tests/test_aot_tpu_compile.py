@@ -124,28 +124,72 @@ def test_flash_attention_fwd_bwd(chip, B, T, H, D, block):
     assert hlo.count("tpu_custom_call") >= (2 if block == T else 3)
 
 
-def test_flash_calls_are_what_the_benchmark_looks_for(chip):
-    """`gpt2m_train_stream` finds the two kernels in a device trace by
-    the custom calls' RESULT shapes (`benchmarks/planes/train.py::
-    kernel_predicates`, read here and not edited): a kernel change that
-    returned anything else would turn `flash_fwd_roofline` and
-    `flash_bwd_roofline` into null on the chip.  Fail here instead."""
+def _train_cell():
+    """`gpt2m_train_stream`'s configuration and traffic files."""
     import json
     import pathlib
 
+    bench = pathlib.Path(__file__).resolve().parent.parent / "benchmarks"
+    return (json.loads((bench / "configs" / "gpt2-medium.json").read_text()),
+            json.loads((bench / "traffic" / "train_stream.json").read_text()))
+
+
+def _flash_calls(hlo, cfg, mix):
+    """The custom calls of `hlo` by the label the benchmark's train
+    plane gives them (`kernel_predicates`, read here and not edited)."""
     from benchmarks.planes.train import kernel_predicates
 
-    bench = pathlib.Path(__file__).resolve().parent.parent / "benchmarks"
-    cfg = json.loads((bench / "configs" / "gpt2-medium.json").read_text())
-    mix = json.loads((bench / "traffic" / "train_stream.json").read_text())
+    return {label: [ln.split(" = ")[0].strip() for ln in hlo.splitlines()
+                    if pred(ln.strip())]
+            for label, pred in kernel_predicates(cfg, mix).items()}
+
+
+def test_flash_calls_are_what_the_benchmark_looks_for(chip):
+    """`gpt2m_train_stream` finds the two kernels in a device trace by
+    the custom calls' RESULT shapes (`benchmarks/planes/train.py::
+    kernel_predicates`): a kernel change that returned anything else
+    would turn `flash_fwd_roofline` and `flash_bwd_roofline` into null
+    on the chip.  Fail here instead."""
+    cfg, mix = _train_cell()
     m = cfg["model"]
     hlo = _flash_grad_hlo(chip, int(mix["batch"]), int(mix["seq"]),
                           m["n_head"], m["n_embd"] // m["n_head"], 1024)
-    found = {label: [ln.split(" = ")[0].strip() for ln in hlo.splitlines()
-                     if pred(ln.strip())]
-             for label, pred in kernel_predicates(cfg, mix).items()}
+    found = _flash_calls(hlo, cfg, mix)
     assert len(found["flash_fwd"]) == 1 and "flash_fwd" in found["flash_fwd"][0]
     assert len(found["flash_bwd"]) == 1 and "flash_bwd" in found["flash_bwd"][0]
+
+
+def test_the_cells_train_step_holds_the_flash_forward_once(chip):
+    """`gpt2m_train_stream`'s step (`remat=True`, `attention="flash"`,
+    gpt2-medium's widths at the cell's batch; 2 layers, the scan's body
+    is the same at 24) compiled for the chip: the backward scan's replay
+    reads the forward kernel's kept results (`FLASH_RESIDUALS`), so the
+    program holds ONE call the benchmark counts as `flash_fwd` and one
+    it counts as `flash_bwd`.  A bare `jax.checkpoint` holds two and
+    one, and `flash_fwd_calls_per_bwd` reads 2.0 on the chip."""
+    import optax
+
+    from ray_tpu.models import gpt2
+
+    cfg, mix = _train_cell()
+    m, tr = cfg["model"], cfg["trainer"]
+    gcfg = gpt2.GPT2Config(
+        vocab_size=m["vocab_size"], n_positions=m["n_positions"],
+        n_embd=m["n_embd"], n_layer=2, n_head=m["n_head"],
+        attention=tr["attention"], remat=tr["remat"],
+        logits_dtype=jnp.bfloat16)
+    opt = optax.chain(optax.clip_by_global_norm(tr["clip_norm"]),
+                      optax.adamw(tr["lr"], b1=tr["b1"], b2=tr["b2"],
+                                  weight_decay=tr["weight_decay"]))
+    params = jax.eval_shape(
+        lambda: gpt2.init_params(gcfg, jax.random.PRNGKey(0)))
+    tokens = _s(int(mix["batch"]), int(mix["seq"]) + 1, dtype=jnp.int32)
+    hlo = _compile(chip, gpt2.make_train_step(gcfg, opt), params,
+                   jax.eval_shape(opt.init, params), tokens,
+                   donate_argnums=(0, 1))
+    found = _flash_calls(hlo, cfg, mix)
+    assert len(found["flash_fwd"]) == 1, found
+    assert len(found["flash_bwd"]) == 1, found
 
 
 # ----------------------------------------------------------------------
