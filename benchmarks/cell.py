@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import importlib
 import json
+import math
 import os
 import sys
 import time
@@ -129,9 +130,6 @@ def main() -> int:
         ctx["peaks"] = manifest.peaks(device["kind"])
 
     v = plane.verdict(ctx, cfg)
-    for name, value, limit in v["rows"]:
-        say({"check": name, "value": value, "limit": limit,
-             "ok": bool(value <= limit)})
     e2e = end_to_end(ctx, mix)
     if ctx["plane"] == "serve":
         c = ctx["client"]
@@ -139,6 +137,10 @@ def main() -> int:
              "failed": c["failed"], "cut_at_end": c["cut_at_end"],
              "completed_in_window": c["completed_in_window"],
              "unanswered_at_window_end": c["unanswered_at_window_end"],
+             # the reading a closed cell had until PR 49, beside the
+             # metric in every run: a note, no metric
+             "tokens_ended_in_window_per_s":
+                 c["tokens_ended_in_window_per_s"],
              "latency_samples": len(c["latency_ms"]),
              "latency_p50_ms": loadgen.percentile(c["latency_ms"], 50),
              "latency_p95_ms": loadgen.percentile(c["latency_ms"], 95),
@@ -176,6 +178,8 @@ def main() -> int:
     if args.trace and trace:
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
+    result.update(checks_of(v))
+    say_checks(v)
     if args.detail:
         os.makedirs(os.path.dirname(args.detail) or ".", exist_ok=True)
         with open(args.detail, "w") as f:
@@ -195,6 +199,28 @@ def main() -> int:
         return 4  # a control run never ends in a result line
     say(result)
     return 0
+
+
+def checks_of(v: dict) -> dict:
+    """What `correct` compared, for the result's line: the rows that
+    FAILED under `failed_checks` (empty when correct), so that a
+    refusal's record says which row and by how much, and every row
+    beside its limit under `checks`, the line's last key."""
+    def num(x):  # the line is strict JSON: no Infinity, no NaN
+        return x if math.isfinite(x) else None
+
+    rows = [[name, num(value), num(limit)] for name, value, limit in v["rows"]]
+    return {"failed_checks": [r for r, (_, value, limit) in zip(rows, v["rows"])
+                              if not value <= limit],
+            "checks": rows}
+
+
+def say_checks(v: dict) -> None:
+    """Each number compared beside its limit, as the run's last lines
+    on standard error (the result's line has them under `checks`)."""
+    for name, value, limit in v["rows"]:
+        log(json.dumps({"check": name, "value": value, "limit": limit,
+                        "ok": bool(value <= limit)}))
 
 
 def _dump_logs(tail: int = 40, files: int = 8) -> None:

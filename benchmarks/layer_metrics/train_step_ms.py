@@ -1,5 +1,7 @@
-"""The jitted step through its device->host read of the loss (median):
-the steady statistic beside `train_tokens_per_s`."""
+"""One step as the host reads it (median): from a step's dispatch, or
+from the end of the step before it where that is later (the loop keeps
+`ahead_steps` steps in flight, so it is), to the device->host read of
+its loss.  The steady statistic beside `train_tokens_per_s`."""
 LAYER, UNIT, SOURCE, MOVES = "models", "ms", "host_clock", "train_tokens_per_s"
 
 

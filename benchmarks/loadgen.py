@@ -18,7 +18,10 @@ Kinds:
   coefficient of variation `arrival_cv` (1 = Poisson).  A request is
   timed from the instant it was DUE, and how late it was sent is kept.
 - `closed_loop`: `clients` callers, each sending its next request when
-  the previous one returns.
+  the previous one returns, until the window's end; the requests out
+  then are waited for (`drain_s` is the ceiling of that wait).  Its
+  rate is read as tokens are produced (`produced_per_s`), not from the
+  answers that happened to end inside the window.
 - `train_stream`: no requests; the train plane reads the batch shape
   and token distribution from the same file.
 
@@ -262,8 +265,12 @@ async def _closed_loop(url: str, plans: List[List[Request]], seconds: float,
                 recs.append(rec)
                 await _post(s, url, req, rec, t0)
 
-        # a closed loop has no schedule to honour after the window: what
-        # is still in flight `drain_s` after its end is cut, not failed
+        # no caller sends after the window's end, and the loop returns as
+        # soon as each has its last answer: the wait is what the engine
+        # needs to empty, `drain_s` only its ceiling.  The requests out at
+        # the window's end are waited for because the closed reading
+        # credits their tokens (`produced_per_s`); one still unanswered
+        # at the ceiling is cut, and a closed cell's `correct` counts it
         tasks = [asyncio.ensure_future(client(p)) for p in plans]
         _, pending = await asyncio.wait(tasks, timeout=seconds + drain_s)
         for t in pending:
@@ -291,24 +298,82 @@ def percentile(values, q: float) -> Optional[float]:
     return v[min(len(v) - 1, max(0, math.ceil(q / 100.0 * len(v)) - 1))]
 
 
+# A closed loop's rate is read from this share of the window on.  By
+# then the slots are full in every closed cell (the slowest to fill,
+# 128 slots at 16 admissions a tick, takes 8 ticks, under 3.3 s of
+# 30), and the first requests' arrival race and their one-off delay
+# (all callers arrive at once, the first tick admits a part) lie before
+# it.  The rate is NOT yet level there where a request lives long: the
+# callers' short first answers make the early ticks heavy with
+# admissions, and `dots3_docqa_closed_16k` credits 2,860 tokens/s over
+# 6-10 s, 3,210 over 10-14 s and 3,310-3,370 from there on, one
+# request's life in (chip, PR 49).  That climb is the traffic's, the
+# same in every run: read from S/2 on, six runs spread 0.56% for 0.61%,
+# no steadier for fewer seconds.  One constant for every cell: no field
+# of a traffic file.
+CLOSED_READ_FROM = 0.2
+
+
+def _produced_by(r: Record, t: float) -> float:
+    """Tokens of an answered request credited by the instant `t`: its
+    `got` tokens spread evenly over `[sent_s, done_s]`."""
+    life = r.done_s - r.sent_s
+    if life <= 0.0:
+        return float(r.got) if t >= r.done_s else 0.0
+    return r.got * min(1.0, max(0.0, (t - r.sent_s) / life))
+
+
+def produced_per_s(recs: List[Record], seconds: float) -> float:
+    """A closed loop's tokens per second AS THEY ARE PRODUCED, from the
+    client's clock alone: every answered request (status 200, as many
+    tokens as asked) is credited its tokens evenly over the time the
+    caller waited for it, and the reading is the credit that falls
+    between `CLOSED_READ_FROM` of the window and its end, over that
+    time.  A closed loop's caller always has exactly one request out,
+    so this is each caller's tokens per second of waiting, summed over
+    the callers (Little's law): continuous in where the window's end
+    falls, blind to a shift of the whole run.  The count of answers
+    that ENDED inside the window moves in steps of one engine tick's
+    completions instead: 0.36% of itself in the quickest closed cell
+    and 1.98% in the slowest (PERF.md section 2).  A failed, short or
+    never answered request is credited nothing.  Against the engine's
+    own count of the same seconds of the same run (`row_steps_live` and
+    a first token an admission, one traced run a cell) it reads within
+    0.4% where the tick ring is stamped (`dots3` twice, Brumby), and
+    between -2.3% and +1.6% over all five cells by the coarser
+    per-second account: the start's climb and the drain's thinning
+    engine are no steady state (chip, PR 49; PERF.md section 6)."""
+    a = CLOSED_READ_FROM * seconds
+    done = [r for r in recs if r.ok and not math.isnan(r.done_s)]
+    return sum(_produced_by(r, seconds) - _produced_by(r, a)
+               for r in done) / (seconds - a)
+
+
 def summarize(recs: List[Record], seconds: float, miss_ms: float,
               closed: bool = False) -> dict:
-    """`tokens_per_s`: output tokens of requests that completed inside
-    the window, over the window.  `latency_ms`: one value per request
-    SENT, from its due instant; a failed, short or unanswered request
-    counts `miss_ms` (worse than any answer)."""
+    """`tokens_per_s`: open loop, output tokens of requests that
+    completed inside the window, over the window; closed loop, tokens
+    as they are produced (`produced_per_s`).  The first reading is kept
+    for every run as `tokens_ended_in_window_per_s` (a note, no metric).
+    `latency_ms`: one value per request SENT, from its due instant; a
+    failed, short or unanswered request counts `miss_ms` (worse than
+    any answer).  `cut_at_end`: closed loop, requests still unanswered
+    when the drain's ceiling was reached; they have no `done_s`, so no
+    credit, and a closed cell's `correct` holds their number to 0."""
     cut = [r for r in recs if r.cut and closed]
     recs = [r for r in recs if not (r.cut and closed)]
     done_in = [r for r in recs if r.ok and r.done_s <= seconds]
     lat = [(r.done_s - r.due_s) * 1e3 if r.ok else miss_ms for r in recs]
     late = [(r.sent_s - r.due_s) * 1e3 for r in recs
             if not math.isnan(r.sent_s)]
+    ended = sum(r.got for r in done_in) / seconds
     return {
         "cut_at_end": len(cut),
         "attempted": len(recs),
         "failed": sum(not r.ok for r in recs),
         "completed_in_window": len(done_in),
-        "tokens_per_s": sum(r.got for r in done_in) / seconds,
+        "tokens_per_s": produced_per_s(recs, seconds) if closed else ended,
+        "tokens_ended_in_window_per_s": ended,
         "latency_ms": lat,
         "late_ms": late,
         "plane_overhead_ms": [

@@ -265,9 +265,10 @@ class BenchLlamaService:
         stats = self.engine.stats()
         out["engine"] = {k: v for k, v in stats.items() if k != "tick_ring"}
         out["tick_ring"] = [
-            {k: r[k] for k in ("seq", "admitted", "active", "queued",
-                               "live_tokens", "gather_blocks",
-                               "admit_s", "dispatch_s", "harvest_s")}
+            {k: r[k] for k in ("seq", "t_wall", "admitted", "active",
+                               "queued", "live_tokens", "gather_blocks",
+                               "admit_s", "dispatch_s", "harvest_s")
+             if k in r}
             for r in stats.get("tick_ring", [])]
         w0, _ = self._window or (0.0, 0.0)
         out["compiles_in_window"] = [
@@ -473,13 +474,27 @@ def run(cell: dict, cfg: dict, mix: dict, args, t_process_start: float) -> dict:
              sample=int(cfg["reference"]["sample"]),
              keep_trace_to=os.environ.get("RT_BENCH_KEEP_TRACE"))
     results = _wait_files("result_*.json", replicas, 300.0, bench_dir)
+    ring_to(results, wall_start + seconds)
     summary = loadgen.summarize(recs, seconds, (seconds + drain_s) * 1e3,
                                 closed=mix["kind"] == "closed_loop")
     return {
         "plane": "serve", "setup_s": setup_s, "seconds": seconds,
         "client": summary, "replicas": results, "ready": ready,
         "check_s": time.time() - window_end,
+        # the client's own records (`--detail` keeps them): what both
+        # readings of a closed loop's rate are reduced from
+        "records": [[r.sent_s, r.done_s, r.got, r.ok] for r in recs],
     }
+
+
+def ring_to(results: list, wall_end: float) -> None:
+    """The ring the readers average ends where the window does: the
+    ticks after it are of an engine that admits nothing and empties
+    (3-12 s of a closed loop, whose requests out at the window's end
+    are waited for).  A tick without a stamp stays."""
+    for r in results:
+        r["tick_ring"] = [t for t in r["tick_ring"]
+                          if t.get("t_wall", 0.0) < wall_end]
 
 
 def verdict(ctx: dict, cfg: dict) -> dict:
@@ -495,4 +510,8 @@ def verdict(ctx: dict, cfg: dict) -> dict:
         ("max_margin_below_reference_argmax", worst, lim["max_margin_limit"]),
         ("sampled_tokens_at_least", -tokens, -lim["min_tokens"]),
     ]
+    if (ctx.get("traffic") or {}).get("kind") == "closed_loop":
+        # a closed cell's reading credits every request sent in the
+        # window, so each has to come back before the drain's ceiling
+        rows.append(("cut_at_end", ctx["client"]["cut_at_end"], 0))
     return {"rows": rows, "correct": all(v <= l for _, v, l in rows)}

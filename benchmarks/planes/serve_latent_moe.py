@@ -25,7 +25,7 @@ from benchmarks.planes.serve import verdict  # noqa: F401  (the plane's)
 
 # the parts of a step the program marks with `jax.named_scope`
 SCOPES = ("mla_attn", "moe_router", "moe_routed", "moe_shared", "dense_mlp")
-TICK_KEYS = ("seq", "admitted", "active", "queued", "live_tokens",
+TICK_KEYS = ("seq", "t_wall", "admitted", "active", "queued", "live_tokens",
              "gather_blocks", "admit_s", "dispatch_s", "harvest_s")
 EXPERT_KEYS = ("experts_touched", "experts_total", "expert_load_max")
 
@@ -187,7 +187,7 @@ class BenchLatentMoeService(base.BenchLlamaService):
         stats = self.engine.stats()
         out["engine"] = {k: v for k, v in stats.items() if k != "tick_ring"}
         out["tick_ring"] = [
-            {**{k: r[k] for k in TICK_KEYS},
+            {**{k: r[k] for k in TICK_KEYS if k in r},
              **{k: r[k] for k in EXPERT_KEYS if k in r}}
             for r in stats.get("tick_ring", [])]
         w0, _ = self._window or (0.0, 0.0)

@@ -4,12 +4,21 @@ step.  The loop is the benchmark's own: it makes the weights from the
 seed, holds the system's loss and gradients to the plain reference on
 a seeded sample before anything is timed, annotates its three phases
 (`data`, `step`, `report`) for the trace, and takes the trace itself.
+
+The loop keeps the chip fed while the host stands still: it dispatches
+`ahead_steps` steps (the mix's; some five seconds of work) beyond the
+one whose loss it waits for, so a step's loss is read, and reported,
+that many steps late.  When the window's time is up it sends nothing
+more, waits for every step it sent, and reads the clock after that
+wait: every step counts, over all of that time.
 """
 
 from __future__ import annotations
 
+import collections
 import math
 import os
+import statistics
 import time
 
 ANNOTATIONS = ("data", "step", "report")
@@ -147,8 +156,35 @@ def train_loop(config):
     trace_dir = os.path.join(opts["bench_dir"], "trace_train")
     trace_at = float(mix.get("trace_at_s", 0.4 * seconds))
     trace_s = float(mix.get("trace_s", 3.0))
-    tracing, traced = False, None
+    tracing, traced, traced_from, step_s = False, None, 0.0, 0.0
     spans = {k: [] for k in ANNOTATIONS}
+    ahead = int(mix.get("ahead_steps", 0))
+    sent = collections.deque()  # (metrics, dispatched at) of steps in flight
+    ends = []                   # when each step's loss reached the host
+
+    def settle():
+        """Wait for the oldest step in flight.  Its `step` span is the
+        time from the step's dispatch, or from the end of the step
+        before it where that is later, to the read of its loss: what
+        the step took on the chip while the chip is kept fed."""
+        met, at = sent.popleft()
+        loss = float(met["loss"])  # device -> host: the step has ended
+        t = time.perf_counter()
+        spans["step"].append(t - max(at, ends[-1] if ends else at))
+        ends.append(t)
+        return loss
+
+    def report(loss):
+        tc = time.perf_counter()
+        with jax.profiler.TraceAnnotation("report"):
+            train.report({"step": len(losses), "loss": loss})
+        losses.append(loss)
+        spans["report"].append(time.perf_counter() - tc)
+
+    def drain():
+        while sent:
+            report(settle())
+
     wall_start, w0 = time.time(), time.perf_counter()
     train.report({"window_start_wall": wall_start, "setup_timing": timing})
     n_compiles0 = len(compiles)
@@ -157,27 +193,35 @@ def train_loop(config):
         if now >= seconds:
             break
         if opts.get("trace") and traced is None:
+            # a traced window holds whole steps: the steps in flight
+            # are waited for before the trace starts and before it
+            # stops (a traced run's own cost, as the profiler's is),
+            # and it stops sending once the steps in flight carry the
+            # trace to `trace_s`
             if not tracing and now >= trace_at:
+                drain()
+                step_s = statistics.median(spans["step"] or [0.0])
                 jax.profiler.start_trace(trace_dir)
-                tracing = True
-            elif tracing and now >= trace_at + trace_s:
+                tracing, traced_from = True, time.perf_counter() - w0
+            elif tracing and (now - traced_from + len(sent) * step_s
+                              >= trace_s):
+                drain()
                 jax.profiler.stop_trace()
                 tracing, traced = False, trace_dir
         ta = time.perf_counter()
         with jax.profiler.TraceAnnotation("data"):
             tokens = jnp.asarray(host_batch())
         tb = time.perf_counter()
+        spans["data"].append(tb - ta)
         with jax.profiler.TraceAnnotation("step"):
             params, opt_state, met = step(params, opt_state, tokens)
-            loss = float(met["loss"])  # device -> host: the step has ended
-        tc = time.perf_counter()
-        with jax.profiler.TraceAnnotation("report"):
-            train.report({"step": len(losses), "loss": loss})
-        td = time.perf_counter()
-        losses.append(loss)
-        spans["data"].append(tb - ta)
-        spans["step"].append(tc - tb)
-        spans["report"].append(td - tc)
+            sent.append((met, tb))
+            loss = settle() if len(sent) > ahead else None
+        if loss is not None:
+            report(loss)
+    # the window's time is up: nothing more is sent, every step that
+    # was sent is waited for, and the clock is read after that wait
+    drain()
     elapsed = time.perf_counter() - w0
     if tracing:
         jax.profiler.stop_trace()
@@ -187,7 +231,8 @@ def train_loop(config):
     out = {"final": True, "device": device, "timing": timing,
            "check": check, "losses": losses, "steps": steps,
            "elapsed_s": elapsed, "tokens_per_step": batch * seq,
-           "spans": spans, "window_start_wall": wall_start,
+           "spans": spans, "ends_s": [t - w0 for t in ends], "ahead": ahead,
+           "window_start_wall": wall_start,
            "compiles_in_window": compiles[n_compiles0:]}
     ms = _common.memory_stats()
     out["memory_runtime_peak_bytes"] = int(ms.get("peak_bytes_in_use", 0))

@@ -94,6 +94,37 @@ def test_rehearsal_leaves_nothing_and_prints_no_result(workload):
     assert_clean(mark)
 
 
+def test_the_train_window_counts_every_step_it_sent_over_all_of_its_time(
+        tmp_path):
+    """The train loop keeps `ahead_steps` steps in flight: when the
+    window's time is up it sends nothing more, waits for every step it
+    sent and reads the clock after that wait, so no step is counted
+    that has not ended and none that was sent is left out."""
+    import json
+
+    detail = tmp_path / "detail.json"
+    proc, mark = start("gpt2m_train_stream", "--detail", str(detail))
+    out, err = proc.communicate(timeout=300)
+    assert proc.returncode == 3, err[-3000:]
+    assert_clean(mark)
+    with open(detail) as f:
+        d = json.load(f)
+    ctx, t = d["ctx"], d["ctx"]["train"]
+    ahead, ends = t["ahead"], t["ends_s"]
+    assert ahead == ctx["traffic"]["ahead_steps"] >= 1
+    assert t["steps"] == len(ends) == len(t["spans"]["step"])
+    assert t["steps"] == ctx["reported_steps"] > ahead
+    assert ends == sorted(ends)
+    # the steps still in flight when the time was up ended after it,
+    # and the clock was read after the last of them
+    assert ends[-ahead - 1] <= ctx["seconds"] + 1.0
+    assert ctx["seconds"] <= ends[-1] <= t["elapsed_s"]
+    assert d["e2e"]["train_tokens_per_s"] == pytest.approx(
+        t["steps"] * t["tokens_per_step"] / t["elapsed_s"])
+    # a step's span is never counted twice: the spans fit in the time
+    assert sum(t["spans"]["step"]) <= t["elapsed_s"]
+
+
 def test_cell_killed_mid_run_leaves_nothing():
     proc, mark = start("mistral7b_chat_open", "--seconds", "60")
     cell = wait_for(mark, "benchmarks.cell")

@@ -79,7 +79,7 @@ def test_the_configuration_is_the_catalog_row_cut_in_depth_only():
                                  "weights": [2, 2, 1]}
     assert (mix["mix_seed"], mix["first_output_step"],
             mix["requests_per_client"], mix["drain_s"], mix["trace_s"]) == (
-        2407, 8, 48, 2.0, 3.0)
+        2407, 8, 48, 30.0, 3.0)   # `drain_s`: a ceiling since PR 49
     assert max(mix["prompt_len"]["choices"]) + 64 < e["max_len"]
     assert all(p % e["block_size"] == 0 for p in mix["prompt_len"]["choices"])
     held = (e["kv_blocks"] + 1) * 16 * 8192 + e["slots"] * 147456

@@ -267,6 +267,200 @@ def test_latency_counts_from_the_due_instant_and_misses_count():
     assert loadgen.percentile([], 95) is None
 
 
+# ---------------------------------------- a closed loop's rate (PR 49)
+# the five closed mixes' sizes, each with its cell's tick in the window
+# (PERF.md section 2): what one harvest is of a 30 s count
+CLOSED_SIZES = {
+    "batch_closed": dict(tick=0.13, slots=64, callers=128, answer=128,
+                         first_step=8),
+    "batch_closed_1k": dict(tick=0.17, slots=64, callers=96, answer=256,
+                            first_step=16),
+    "batch_closed_1k_a128": dict(tick=0.17, slots=32, callers=48, answer=128,
+                                 first_step=8),
+    "batch_closed_512_a128": dict(tick=0.35, slots=128, callers=192,
+                                  answer=64, first_step=8),
+    "docqa_closed_16k_a128": dict(tick=0.41, slots=128, callers=192,
+                                  answer=256, first_step=8),
+}
+
+
+def _ticking_engine(tick, slots, callers, answer, first_step, chunk=8,
+                    admit=16, seconds=30.0, shift=0.0):
+    """A closed loop's records against an engine that ticks: at every
+    tick the rows that have their tokens are handed back TOGETHER (a
+    harvest; their callers send again at once), up to `admit` waiting
+    requests take free slots, every live row gets `chunk` tokens.  The
+    completions come in a wave of harvests, as the cells' do."""
+    recs, queue, live = [], [], []
+
+    def send(c, t, first):
+        want = (first_step * (1 + c % (answer // first_step)) if first
+                else answer)
+        r = loadgen.Record(len(recs), t, sent_s=t, want=want)
+        r.client = c
+        recs.append(r)
+        queue.append(r)
+
+    for c in range(callers):
+        send(c, 0.0, True)
+    t = shift
+    while queue or live:
+        for row in [x for x in live if x[1] == 0]:
+            live.remove(row)
+            r = row[0]
+            r.done_s, r.got, r.ok, r.status = t, r.want, True, 200
+            if t < seconds:
+                send(r.client, t, False)
+        for _ in range(min(admit, slots - len(live), len(queue))):
+            r = queue.pop(0)
+            live.append([r, -(-r.want // chunk)])
+        for row in live:
+            row[1] -= 1
+        t += tick
+    return recs
+
+
+def _both(recs, seconds):
+    s = loadgen.summarize(recs, seconds, 1e9, closed=True)
+    return s["tokens_per_s"], s["tokens_ended_in_window_per_s"]
+
+
+def _apart(values):
+    return (max(values) - min(values)) / (sum(values) / len(values))
+
+
+def test_the_mixes_in_the_table_are_the_closed_mixes():
+    man = manifest.manifest()
+    closed = {c["traffic"] for c in man["workloads"]
+              if manifest.traffic(c["traffic"])["kind"] == "closed_loop"}
+    assert closed == set(CLOSED_SIZES)
+    for name, size in CLOSED_SIZES.items():
+        mix = manifest.traffic(name)
+        assert mix["clients"] == size["callers"]
+        assert mix["output_len"] == {"fixed": size["answer"]}
+        assert mix["first_output_step"] == size["first_step"]
+
+
+@pytest.mark.parametrize("mix", sorted(CLOSED_SIZES))
+def test_closed_rate_does_not_step_with_the_windows_end(mix):
+    """ONE run's records read at window ends swept across one tick: the
+    answers that ended inside the window step by a harvest, the tokens
+    as they are produced do not."""
+    size = CLOSED_SIZES[mix]
+    recs = _ticking_engine(seconds=31.0, **size)
+    reads = [_both(recs, 30.0 - size["tick"] * j / 10) for j in range(11)]
+    assert _apart([new for new, _ in reads]) < 0.002
+    harvest = size["tick"] / 30.0
+    assert _apart([old for _, old in reads]) > 0.8 * harvest
+    if harvest > 0.01:
+        assert _apart([old for _, old in reads]) > 0.01
+
+
+@pytest.mark.parametrize("mix", sorted(CLOSED_SIZES))
+def test_closed_rate_does_not_step_with_a_shift_of_the_run(mix):
+    """The whole run later by 0-1 tick (the engine's phase against the
+    client's clock): the same."""
+    size = CLOSED_SIZES[mix]
+    reads = [_both(_ticking_engine(shift=size["tick"] * j / 10, **size), 30.0)
+             for j in range(10)]
+    new, old = _apart([n for n, _ in reads]), _apart([o for _, o in reads])
+    # the callers' first requests, whose lives hold the shift, still run
+    # at S/5 where a request lives 4-14 s: 0.33-0.35% there, the old
+    # reading's 0.46-1.4%; nothing where a life is short
+    assert new < (0.004 if size["tick"] > 0.3 else 0.0005) and new < old
+
+
+@pytest.mark.parametrize("mix", sorted(CLOSED_SIZES))
+def test_closed_rate_reads_a_two_percent_faster_engine_as_two_percent(mix):
+    size = CLOSED_SIZES[mix]
+    base, _ = _both(_ticking_engine(**size), 30.0)
+    fast, _ = _both(_ticking_engine(
+        **{**size, "tick": size["tick"] / 1.02}), 30.0)
+    # +1.80 to +2.17: the start's climb ends 2% sooner too, and a part of
+    # it lies after S/5 (the old reading: +0.46 to +2.75)
+    assert 100.0 * (fast / base - 1.0) == pytest.approx(2.0, abs=0.25)
+
+
+def test_closed_rate_credits_answers_only_and_counts_the_cut():
+    R = loadgen.Record
+    recs = [R(0, 0.0, sent_s=0.0, done_s=20.0, ok=True, got=100, want=100),
+            R(1, 0.0, sent_s=5.0, done_s=15.0, ok=True, got=50, want=50),
+            # a short answer, a refused one, one that never came back
+            R(2, 0.0, sent_s=0.0, done_s=10.0, ok=False, got=7, want=50),
+            R(3, 0.0, sent_s=0.0, done_s=10.0, ok=False, status=500),
+            R(4, 0.0, sent_s=8.0, cut=True)]
+    s = loadgen.summarize(recs, seconds=10.0, miss_ms=1e6, closed=True)
+    # over [2, 10]: 100 x 8/20 of request 0, 50 x 5/10 of request 1
+    assert s["tokens_per_s"] == pytest.approx((40.0 + 25.0) / 8.0)
+    assert loadgen.produced_per_s(recs, 10.0) == s["tokens_per_s"]
+    # by the window's end: 100 x 10/20 of request 0, 50 x 5/10 of request 1
+    assert [loadgen._produced_by(r, 10.0) for r in recs[:2]] == [50.0, 25.0]
+    assert s["cut_at_end"] == 1 and s["attempted"] == 4 and s["failed"] == 2
+    assert s["completed_in_window"] == 0
+
+
+def test_the_old_reading_stays_under_a_name_of_its_own():
+    R = loadgen.Record
+    recs = [R(0, 0.0, sent_s=0.0, done_s=4.0, ok=True, got=40, want=40),
+            R(1, 0.0, sent_s=4.0, done_s=12.0, ok=True, got=40, want=40)]
+    closed = loadgen.summarize(recs, 10.0, 1e6, closed=True)
+    opened = loadgen.summarize(recs, 10.0, 1e6)
+    assert closed["tokens_ended_in_window_per_s"] == 4.0
+    assert opened["tokens_ended_in_window_per_s"] == 4.0
+    assert opened["tokens_per_s"] == 4.0        # an open loop's, as it was
+    # [2, 10]: 40 x 2/4 of the first, 40 x 6/8 of the second
+    assert closed["tokens_per_s"] == pytest.approx((20.0 + 30.0) / 8.0)
+
+
+def test_a_closed_cells_verdict_holds_the_cut_to_nought():
+    from benchmarks.planes import serve
+
+    cfg = {"reference": {"mean_margin_limit": 0.1, "max_margin_limit": 1.0,
+                         "min_tokens": 8}}
+    check = {"sampled": 2, "tokens": 16, "mean_margin": 0.01,
+             "max_margin": 0.2}
+    ctx = {"replicas": [{"check": check}], "client": {"cut_at_end": 0},
+           "traffic": {"kind": "closed_loop"}}
+    v = serve.verdict(ctx, cfg)
+    assert v["correct"] and ("cut_at_end", 0, 0) in v["rows"]
+    ctx["client"]["cut_at_end"] = 1
+    assert not serve.verdict(ctx, cfg)["correct"]
+    # an open loop's late answers are late, not wrong: no such row
+    ctx["traffic"] = {"kind": "open_loop"}
+    v = serve.verdict(ctx, cfg)
+    assert v["correct"] and len(v["rows"]) == 3
+
+
+def test_the_ring_the_readers_average_ends_with_the_window():
+    from benchmarks.planes import serve
+
+    results = [{"tick_ring": [{"seq": 1, "t_wall": 99.0}, {"seq": 2},
+                              {"seq": 3, "t_wall": 129.9},
+                              {"seq": 4, "t_wall": 130.0},
+                              {"seq": 5, "t_wall": 141.0}]},
+               {"tick_ring": []}]
+    serve.ring_to(results, 130.0)
+    # set-up's ticks and one without a stamp stay, the drain's go
+    assert [t["seq"] for t in results[0]["tick_ring"]] == [1, 2, 3]
+    assert results[1]["tick_ring"] == []
+
+
+def test_the_last_line_names_the_rows_that_failed():
+    from benchmarks import cell
+
+    rows = [("mean_margin_below_reference_argmax", 0.31, 0.1),
+            ("sampled_tokens_at_least", -2048, -1024),
+            ("max_margin_below_reference_argmax", math.inf, 5.0)]
+    out = cell.checks_of({"rows": rows, "correct": False})
+    assert out["failed_checks"] == [
+        ["mean_margin_below_reference_argmax", 0.31, 0.1],
+        ["max_margin_below_reference_argmax", None, 5.0]]
+    assert [r[0] for r in out["checks"]] == [r[0] for r in rows]
+    assert list(out) == ["failed_checks", "checks"]   # `checks` comes last
+    json.loads(json.dumps(out, allow_nan=False))      # strict JSON
+    assert cell.checks_of({"rows": rows[1:2]})["failed_checks"] == []
+
+
 def test_open_loop_client_sends_on_schedule_against_a_slow_server():
     from aiohttp import web
 

@@ -319,6 +319,51 @@ def test_the_verdict_holds_the_hits_and_the_documents():
         assert not plane.verdict(ctx, CFG)["correct"]
 
 
+def _served(n_docs=8, full_in=None, doc=32, span=256, shorts=24, fulls=18):
+    """A window's answers as the replica keeps them: behind each of
+    `n_docs` documents `shorts` callers' first answers of 8..248 tokens
+    and `fulls` of full length (none behind a document outside
+    `full_in`), and the warm-up's short prompt, which is no document's."""
+    rng = np.random.default_rng(5)
+    out = [([1] * 16, [2, 2])]
+    for d in range(n_docs):
+        head = [100 + d] * doc
+        for j in range(shorts):
+            out.append((head + [7] * 8, [3] * (8 * (1 + j % 31))))
+        if full_in is None or d in full_in:
+            for _ in range(fulls):
+                out.append((head + [7] * 8, [4] * span))
+    return [out[i] for i in rng.permutation(len(out))]
+
+
+@pytest.mark.parametrize("seed", [0, 7, 4900000101, 2**31 + 11])
+def test_the_sample_is_full_answers_over_every_document(seed):
+    """8 picks of 256 tokens whatever the seed: `sampled_tokens_at_least`
+    then fails only where the program served too little."""
+    served = [s for s in _served() if len(s[0]) > 32]
+    pick = plane.sample_answers(served, 32, 8, 256, seed)
+    assert len(pick) == len(set(pick)) == 8
+    assert [len(served[i][1]) for i in pick] == [256] * 8
+    assert len({served[i][0][0] for i in pick}) == 8   # every document
+    assert pick == plane.sample_answers(served, 32, 8, 256, seed)
+    other = plane.sample_answers(served, 32, 8, 256, seed + 1)
+    assert sorted(other) != sorted(pick)               # the seed draws
+
+
+def test_the_sample_falls_back_only_where_a_document_has_no_full_answer():
+    served = [s for s in _served(full_in={0, 1, 2}) if len(s[0]) > 32]
+    pick = plane.sample_answers(served, 32, 8, 256, 3)
+    by_doc = {}
+    for i in pick:
+        by_doc.setdefault(served[i][0][0] - 100, []).append(len(served[i][1]))
+    assert sorted(by_doc) == list(range(8))            # still round-robin
+    assert all(by_doc[d] == [256] for d in (0, 1, 2))
+    assert all(n < 256 for d in range(3, 8) for n in by_doc[d])
+    # fewer answers than asked for: all of them, once
+    few = served[:5]
+    assert sorted(plane.sample_answers(few, 32, 8, 256, 3)) == list(range(5))
+
+
 # -- the manifest ---------------------------------------------------------
 def test_the_manifest_finds_every_new_file():
     man = manifest.manifest()
@@ -367,5 +412,6 @@ def test_the_cells_rehearsal_leaves_nothing_running():
     assert "rehearsal passed" in err
     assert '"correct"' not in out.strip().splitlines()[-1]
     assert '"metrics"' not in out
-    assert '"window_requests_not_a_whole_document_hit", "value": 0' in out
+    # the rows of `correct` are the run's last lines on standard error
+    assert '"window_requests_not_a_whole_document_hit", "value": 0' in err
     guard.assert_clean(mark)
