@@ -1,6 +1,7 @@
 """Model families (the flagship workloads of the framework).
 
-- gpt2: pretraining flagship (BASELINE #2; bench.py measures it)
+- gpt2: pretraining flagship (BASELINE #2; the benchmark's
+  `gpt2m_train_stream` cell measures it)
 - llama: fine-tune/serving flagship with first-class LoRA and
   KV-cached decoding (BASELINE #4/#5)
 - mixtral: sparse-MoE family exercising expert parallelism over the

@@ -46,47 +46,6 @@ class GPT2Config:
     # single largest tensor in the step) at ~1e-3 loss precision;
     # `forward()` always returns f32 logits for inference callers
     logits_dtype: Any = jnp.float32
-    # layer-scan unroll factor: >1 lets XLA fuse/pipeline across block
-    # boundaries at the cost of code size (any positive value; the scan
-    # length is n_layer, or n_layer/2 under remat_policy="half")
-    scan_unroll: int = 1
-    # remat policy: "full" recomputes the whole block backward (min
-    # memory) but the flash kernel's two results, which the block keeps
-    # (`ops.attention.checkpoint_block`: 33 MB a layer at gpt2-medium,
-    # 16 x 1024; nothing with another attention); "dots" saves matmul
-    # outputs (checkpoint_policies dots_with_no_batch_dims_saveable);
-    # "names" saves exactly the tagged matmul inputs (see
-    # `_SAVED_NAMES`) so the backward recomputes ONLY the attention
-    # score/prob internals — the quadratic part — instead of the whole
-    # block (~15% of fwd FLOPs recomputed vs 100% for "full", at
-    # ~750 MB/layer saved residuals for the 124M bench shapes)
-    remat_policy: str = "full"
-    # layers exempted from remat (the LAST `remat_skip` of the stack
-    # keep their activations resident and skip the backward's forward
-    # replay).  Sized to HBM headroom: each exempt layer trades ~1.1 GB
-    # of saved activations (124M bench shapes, batch 32) for 1/n_layer
-    # of the remat recompute — the knob between "full" (min memory) and
-    # remat off (min FLOPs)
-    remat_skip: int = 0
-
-    def __post_init__(self):
-        if self.remat_policy not in ("full", "dots", "names", "half"):
-            raise ValueError(
-                f"unknown remat_policy {self.remat_policy!r}; "
-                "expected 'full', 'dots', 'names', or 'half'"
-            )
-        if self.remat_policy == "half" and self.n_layer % 2:
-            raise ValueError("remat_policy='half' needs an even n_layer")
-        if self.scan_unroll < 1:
-            raise ValueError("scan_unroll must be >= 1")
-        if not 0 <= self.remat_skip <= self.n_layer:
-            raise ValueError(
-                f"remat_skip must be in [0, n_layer], got {self.remat_skip}"
-            )
-        if self.remat_skip and self.remat_policy != "full":
-            raise ValueError(
-                "remat_skip composes with remat_policy='full' only"
-            )
 
     @property
     def head_dim(self) -> int:
@@ -167,14 +126,6 @@ def logical_axes(cfg: GPT2Config) -> Dict:
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
-# Activations saved (not recomputed) under remat_policy="names": every
-# matmul/gelu input except the attention score+prob tensors.
-_SAVED_NAMES = (
-    "ln1_out", "qkv", "attn_out_in", "resid_attn", "ln2_out",
-    "pre_gelu", "gelu_out",
-)
-
-
 # `jax.named_scope`s (`embed`, `attn`, `mlp`, `norm`, `lm_head`) put the
 # block part an op came from into its metadata, which is what a device
 # trace prints; they change nothing that is computed.
@@ -194,107 +145,48 @@ def backbone(cfg: GPT2Config, params: Dict, tokens: jax.Array,
         x = (params["wte"].astype(cfg.dtype)[tokens]
              + params["wpe"].astype(cfg.dtype)[:T])
 
-    blocks = params["blocks"]
-
-    def _make_one(layer_params):
-        # layer_params: one layer's slice of every block param
-        from jax.ad_checkpoint import checkpoint_name
-
-        def one(cfg_x):
+    def block(x, layer_params):
+        # layer_params: one layer's slice of every block param, closed
+        # over by `one` (as arguments of the checkpointed function they
+        # reorder the backward loop's operands: another program text)
+        def one(x):
             with jax.named_scope("attn"):
                 h = _layer_norm(
-                    cfg_x,
+                    x,
                     layer_params["ln1_g"].astype(cfg.dtype),
                     layer_params["ln1_b"].astype(cfg.dtype),
                 )
-                h = checkpoint_name(h, "ln1_out")
-                B_, T_, E = cfg_x.shape
+                B_, T_, E = x.shape
                 qkv = (h @ layer_params["attn_qkv_w"].astype(cfg.dtype)
                        + layer_params["attn_qkv_b"].astype(cfg.dtype))
-                qkv = checkpoint_name(qkv, "qkv")
                 q, k, v = jnp.split(qkv, 3, axis=-1)
                 q = q.reshape(B_, T_, cfg.n_head, cfg.head_dim)
                 k = k.reshape(B_, T_, cfg.n_head, cfg.head_dim)
                 v = v.reshape(B_, T_, cfg.n_head, cfg.head_dim)
                 o = select_attention(cfg.attention, q, k, v, mesh, causal=True)
-                o = checkpoint_name(o.reshape(B_, T_, E), "attn_out_in")
-                x1 = cfg_x + (
-                    o @ layer_params["attn_out_w"].astype(cfg.dtype)
+                x1 = x + (
+                    o.reshape(B_, T_, E)
+                    @ layer_params["attn_out_w"].astype(cfg.dtype)
                     + layer_params["attn_out_b"].astype(cfg.dtype)
                 )
-                x1 = checkpoint_name(x1, "resid_attn")
             with jax.named_scope("mlp"):
                 h2 = _layer_norm(
                     x1,
                     layer_params["ln2_g"].astype(cfg.dtype),
                     layer_params["ln2_b"].astype(cfg.dtype),
                 )
-                h2 = checkpoint_name(h2, "ln2_out")
                 h2 = (h2 @ layer_params["mlp_fc_w"].astype(cfg.dtype)
                       + layer_params["mlp_fc_b"].astype(cfg.dtype))
-                h2 = checkpoint_name(h2, "pre_gelu")
                 h2 = jax.nn.gelu(h2)
-                h2 = checkpoint_name(h2, "gelu_out")
                 h2 = (h2 @ layer_params["mlp_out_w"].astype(cfg.dtype)
                       + layer_params["mlp_out_b"].astype(cfg.dtype))
             return x1 + h2
 
-        return one
+        # remat recomputes the whole block in the backward pass but the
+        # flash kernel's two results, which `checkpoint_block` keeps
+        return (checkpoint_block(one) if cfg.remat else one)(x), None
 
-    def body(x, layer_params):
-        one = _make_one(layer_params)
-        if cfg.remat:
-            if cfg.remat_policy == "dots":
-                fn = jax.checkpoint(
-                    one,
-                    policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                )
-            elif cfg.remat_policy == "names":
-                fn = jax.checkpoint(
-                    one,
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        *_SAVED_NAMES
-                    ),
-                )
-            else:
-                fn = checkpoint_block(one)
-        else:
-            fn = one
-        return fn(x), None
-
-    def body_pair(x, pair_params):
-        # remat_policy="half": checkpoint only the FIRST of each layer
-        # pair — halves the backward's recompute FLOPs for half the
-        # activation memory of no-remat (the sweet spot when full
-        # activations OOM but full recompute wastes ~2N FLOPs/token)
-        p0 = jax.tree.map(lambda a: a[0], pair_params)
-        p1 = jax.tree.map(lambda a: a[1], pair_params)
-        x = jax.checkpoint(_make_one(p0))(x)
-        return _make_one(p1)(x), None
-
-    def body_plain(x, layer_params):
-        return _make_one(layer_params)(x), None
-
-    x = x.astype(cfg.dtype)
-    if cfg.remat and cfg.remat_policy == "half":
-        if cfg.n_layer % 2:
-            raise ValueError("remat_policy='half' needs an even n_layer")
-        pairs = jax.tree.map(
-            lambda a: a.reshape(cfg.n_layer // 2, 2, *a.shape[1:]), blocks
-        )
-        x, _ = lax.scan(body_pair, x, pairs, unroll=cfg.scan_unroll)
-    elif cfg.remat and cfg.remat_skip:
-        # two scans: the first (n_layer - remat_skip) layers remat, the
-        # last remat_skip keep their activations and skip the backward
-        # forward-replay entirely
-        split = cfg.n_layer - cfg.remat_skip
-        first = jax.tree.map(lambda a: a[:split], blocks)
-        last = jax.tree.map(lambda a: a[split:], blocks)
-        if split:
-            x, _ = lax.scan(body, x, first, unroll=cfg.scan_unroll)
-        x, _ = lax.scan(body_plain, x, last, unroll=cfg.scan_unroll)
-    else:
-        x, _ = lax.scan(body, x, blocks, unroll=cfg.scan_unroll)
+    x, _ = lax.scan(block, x.astype(cfg.dtype), params["blocks"])
     return _layer_norm(
         x, params["lnf_g"].astype(cfg.dtype), params["lnf_b"].astype(cfg.dtype)
     )

@@ -1,13 +1,10 @@
 """RLlib PPO fleet benchmark harness (BASELINE config #3).
 
-One measured shape, two consumers:
-
-- ``bench.py --config rllib_ppo`` — the baseline-closing bench row
-  (env-steps/s + learner updates/s; ``vs_baseline`` = async-overlap
-  throughput over the reference's synchronous sample→update loop at
-  the identical fleet shape);
-- ``python -m ray_tpu.scripts.perf --config rllib_ppo`` — the tier-1
-  structural row (both metrics present, exactly-once accounting).
+One measured shape (env-steps/s + learner updates/s, async overlap
+or the reference's synchronous sample→update loop at the identical
+fleet shape), one consumer: ``python -m ray_tpu.scripts.perf --config
+rllib_ppo`` — the tier-1 structural row (both metrics present,
+exactly-once accounting).
 
 The workload is the production shape the ROADMAP names: an
 `EnvRunnerGroup` fleet of CPU sampling actors streaming rollouts as

@@ -38,7 +38,7 @@ def make_data_mesh(num_devices: int):
     devices with axis name "data" — the learner gang's substrate.  On
     CPU boxes, virtual devices come from
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set BEFORE
-    jax initializes; bench.py and tests/conftest.py both do)."""
+    jax initializes; `rllib/bench.py` and tests/conftest.py both do)."""
     import jax
 
     devices = jax.devices()

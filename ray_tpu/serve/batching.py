@@ -19,12 +19,10 @@ from ray_tpu.exceptions import BackPressureError
 class _BatchQueue:
     def __init__(self, fn: Callable, max_batch_size: int,
                  batch_wait_timeout_s: float,
-                 bucket_fill_timeout_s: Optional[float] = None,
                  max_queued_requests: int = -1):
         self._fn = fn
         self._max = max_batch_size
         self._wait = batch_wait_timeout_s
-        self._bucket_wait = bucket_fill_timeout_s
         self._max_queued = max_queued_requests
         self._queue: asyncio.Queue = asyncio.Queue()
         self._task: Optional[asyncio.Task] = None
@@ -61,31 +59,10 @@ class _BatchQueue:
         batch = [await self._queue.get()]
         loop = asyncio.get_running_loop()
         deadline = loop.time() + self._wait
-        capped = False
         while len(batch) < self._max:
             remaining = deadline - loop.time()
             if remaining <= 0:
                 break
-            # BUCKET-FILL FLUSH (PERF.md serve sweep: at max_batch=32 /
-            # c=64 the batcher formed ragged 32+16 group pairs that
-            # serialized per gather cycle).  Pow-2-bucketed consumers
-            # pad a batch UP to the next power of two, so a batch
-            # sitting exactly at a boundary gains nothing from one
-            # more straggler — it would re-pad to double the size.
-            # Once the batch REACHES an upper boundary (>= max/4:
-            # where doubling the pad is expensive; tiny batches still
-            # gather normally — padding 1->2 is cheap and halves
-            # dispatches), the per-item wait STAYS capped at
-            # `bucket_fill_timeout_s` — a lone straggler pushing the
-            # count to boundary+1 must not reopen the full window it
-            # cannot fill.
-            n = len(batch)
-            if (self._bucket_wait is not None
-                    and n & (n - 1) == 0
-                    and n >= max(2, self._max // 4)):
-                capped = True
-            if capped:
-                remaining = min(remaining, self._bucket_wait)
             try:
                 batch.append(
                     await asyncio.wait_for(self._queue.get(), timeout=remaining)
@@ -132,22 +109,11 @@ def batch(
     *,
     max_batch_size: int = 10,
     batch_wait_timeout_s: float = 0.01,
-    bucket_fill_timeout_s: Optional[float] = None,
     max_queued_requests: int = -1,
 ):
     """Decorator: turn `async def f(self, item)`-shaped handlers into
     batched `f(self, items: List)` execution (reference:
     `serve/batching.py` `@serve.batch`).
-
-    `bucket_fill_timeout_s` (optional, for pow-2-bucketed consumers):
-    once the gathering batch sits exactly at an upper power-of-two
-    boundary (>= max_batch_size/4), wait at most this long for further
-    items before flushing — a trickle of stragglers otherwise re-pads
-    the batch to the NEXT bucket and serializes a ragged group pair
-    per gather cycle (the measured max_batch=32 stall in PERF.md's
-    serve sweep).  Small batches keep gathering under the normal
-    batch_wait_timeout_s, where padding up is cheap and batching pays
-    the most.
 
     `max_queued_requests` (default -1 = unbounded) bounds the pending
     list the same way the deployment-level admission cap does: the
@@ -189,8 +155,6 @@ def batch(
                     call,
                     over.get("max_batch_size", max_batch_size),
                     over.get("batch_wait_timeout_s", batch_wait_timeout_s),
-                    over.get("bucket_fill_timeout_s",
-                             bucket_fill_timeout_s),
                     over.get("max_queued_requests", max_queued_requests),
                 )
                 setattr(owner, attr, q)

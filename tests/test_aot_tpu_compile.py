@@ -296,7 +296,7 @@ def test_llama1b4_decode_step_rows_paged(chip, int8):
 
 
 # ----------------------------------------------------------------------
-# the @serve.batch path (LlamaService -> llama.generate): no kernels,
+# `llama.generate`, the engine's oracle (`reference_check`): no kernels,
 # but the two programs it is made of must fit and compile at size
 # ----------------------------------------------------------------------
 def test_llama1b4_prefill_and_decode_step(chip):

@@ -75,7 +75,7 @@ def test_no_code_sets_the_cache_path():
     for root in (os.path.join(REPO, "ray_tpu"), REPO):
         for dirpath, _, files in os.walk(root):
             if root == REPO and dirpath != REPO:
-                continue  # top level only (bench.py, chip_smoke.py, …)
+                continue  # top level only (chip_smoke.py, …)
             for name in files:
                 if not name.endswith(".py"):
                     continue

@@ -160,43 +160,6 @@ def test_batching(serve_instance):
     assert max(sizes) > 1  # requests were actually batched
 
 
-def test_batching_bucket_fill_flush(serve_instance):
-    """`bucket_fill_timeout_s`: a batch sitting exactly at a pow-2
-    boundary flushes after the short bucket wait instead of holding the
-    whole batch_wait_timeout_s for stragglers that would re-pad it into
-    the next bucket (the PERF.md ragged-group stall)."""
-    @serve.deployment(max_ongoing_requests=32)
-    class Bucketed:
-        def __init__(self):
-            self.batch_sizes = []
-
-        @serve.batch(max_batch_size=16, batch_wait_timeout_s=5.0,
-                     bucket_fill_timeout_s=0.05)
-        async def handle(self, items):
-            self.batch_sizes.append(len(items))
-            return [x + 1 for x in items]
-
-        async def __call__(self, x):
-            return await self.handle(x)
-
-        def sizes(self):
-            return self.batch_sizes
-
-    h = serve.run(Bucketed.bind(), name="bucketed",
-                  route_prefix="/bucketed")
-    t0 = time.monotonic()
-    responses = [h.remote(i) for i in range(4)]
-    values = sorted(r.result(timeout_s=15) for r in responses)
-    elapsed = time.monotonic() - t0
-    assert values == [1, 2, 3, 4]
-    # 4 requests land well inside one bucket wait of each other and 4
-    # is a pow-2 boundary: one batch, flushed WAY before the 5 s
-    # batch_wait deadline
-    assert elapsed < 3.0
-    sizes = h.sizes.remote().result(timeout_s=10)
-    assert max(sizes) <= 4
-
-
 def test_http_post_json_and_response_type(serve_instance):
     @serve.deployment
     class Api:
