@@ -166,7 +166,7 @@ def kv_pool_tail(kv_heads: int, head_dim: int) -> Tuple[int, ...]:
 # ----------------------------------------------------------------------
 @functools.lru_cache(maxsize=32)
 def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
-                  quantized, interpret, pools=2, flat=False):
+                  quantized, interpret, pools=2, flat=False, vd=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -249,9 +249,14 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         # the pools are `[L, NB, BS, HD]`, nothing per head (MLA's
         # latent; K and V with every head folded into the row), so a
         # page is a plain `[BS, HD]` tile and a new row `[1, HD]`;
-        # else `[L, NB, BS, KV, HD]`
+        # else `[L, NB, BS, KV, HD]`.  `vd` (flat, two pools): the V
+        # pool's rows are that wide where K's are `HD`
         page = (BS, HD) if flat else (BS, KV, HD)
         row = (1, HD) if flat else (KV, HD)
+        pages = [page] * pools
+        rows = [row] * pools
+        if vd:
+            pages[1], rows[1] = (BS, vd), (1, vd)
         def kernel(layer_ref, tables_ref, pos_ref, *refs):
             ins, news, outs = (refs[:pools], refs[pools:2 * pools],
                                refs[2 * pools:])
@@ -279,12 +284,13 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
             num_scalar_prefetch=3,
             grid=(B,),
             in_specs=(
-                [pl.BlockSpec((None, None) + page, pool_map)] * pools
-                + [pl.BlockSpec((None,) + row, row_map)] * pools),
-            out_specs=[pl.BlockSpec((None, None) + page, pool_map)] * pools,
+                [pl.BlockSpec((None, None) + pg, pool_map) for pg in pages]
+                + [pl.BlockSpec((None,) + r, row_map) for r in rows]),
+            out_specs=[pl.BlockSpec((None, None) + pg, pool_map)
+                       for pg in pages],
         )
-        out_shape = [
-            jax.ShapeDtypeStruct((L, NB) + page, pool_dtype)] * pools
+        out_shape = [jax.ShapeDtypeStruct((L, NB) + pg, pool_dtype)
+                     for pg in pages]
         aliases = {3 + i: i for i in range(pools)}
 
     return pl.pallas_call(
@@ -309,7 +315,8 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
 
     k_pool/v_pool [L, NB, BS, KV, hd]; k_new/v_new [B, KV, hd] (pool
     dtype; for a folded pool `[L, NB, BS, KV * hd]`, `kv_pool_tail`,
-    the rows are taken as the `[B, KV * hd]` they are);
+    the rows are taken as the `[B, KV * hd]` they are, and a folded V
+    pool may be of another width than K's, `[L, NB, BS, KV * hd_v]`);
     tables [B, W] int32; pos [B] int32 (the position being
     written); layer: scalar int32 (traced OK).  With the int8 sidecar
     (`k_scale`/`v_scale` [L, NB, BS, KV] f32 + per-row `k_new_scale`/
@@ -319,14 +326,16 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
     quantized = k_scale is not None
     if k_pool.ndim == 4:  # folded rows: the flat form, both pools
         L, NB, BS, HD = k_pool.shape
+        VD = v_pool.shape[-1]
         assert not quantized, "a folded pool has no int8 scales wired"
         fn = _build_append(L, NB, BS, 1, HD, B, W,
                            jnp.dtype(k_pool.dtype).name,
                            jnp.dtype(k_new.dtype).name, False,
-                           bool(interpret), flat=True)
+                           bool(interpret), flat=True,
+                           vd=VD if VD != HD else 0)
         return tuple(fn(jnp.asarray(layer, jnp.int32).reshape(1), tables,
                         pos, k_pool, v_pool, k_new.reshape(B, 1, HD),
-                        v_new.reshape(B, 1, HD)))
+                        v_new.reshape(B, 1, VD)))
     L, NB, BS, KV, HD = k_pool.shape
     fn = _build_append(L, NB, BS, KV, HD, B, W,
                        jnp.dtype(k_pool.dtype).name,
@@ -415,21 +424,24 @@ def _pages_per_block(BS, KV, H, W, block_tokens=_BLOCK_TOKENS):
 
 @functools.lru_cache(maxsize=32)
 def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
-                     quantized, interpret, latent=0, scale=None):
+                     quantized, interpret, latent=0, scale=None, vd=0):
     """`latent` > 0 is the MLA form: ONE pool whose row is the latent
     (`KV` 1, `HD` the score width, 576), all `H` query heads score
     against it, and the VALUE is the first `latent` columns (512) of
     the very tile the scores were taken on: the pool is read once, no
     V pool exists, and with one kv head the head mask falls away.
     `scale` is then the caller's (1 / sqrt(192): the width of the
-    un-absorbed query, not of the latent)."""
+    un-absorbed query, not of the latent).  `vd` > 0: the V pool's rows
+    are `vd` wide where K's are `HD` (a folded pool of heads whose keys
+    and values differ in width), and so is the result."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     assert not (latent and (quantized or KV != 1))
+    assert not (vd and (latent or quantized or KV != 1))
     group = H // KV
     scale = HD ** -0.5 if scale is None else scale
-    VD = latent or HD  # width of a value row, and of the result
+    VD = latent or vd or HD  # width of a value row, and of the result
     q_dt = jnp.dtype(q_dtype)
     pool_dt = jnp.dtype(pool_dtype)
     P = _pages_per_block(BS, KV, H, W,
@@ -569,7 +581,9 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
 
     whole = pl.BlockSpec((B, H, HD), lambda *_: (0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    scratch = [pltpu.VMEM((2, C, HD), pool_dt)] * (1 if latent else 2)
+    scratch = [pltpu.VMEM((2, C, HD), pool_dt)]
+    if not latent:
+        scratch.append(pltpu.VMEM((2, C, VD), pool_dt))
     if quantized:
         scratch += [pltpu.VMEM((2, 1, C), jnp.float32)] * 2
     scratch.append(pltpu.SemaphoreType.DMA((2, 2)))  # [K | V, slot]
@@ -593,9 +607,25 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
         out_shape=jax.ShapeDtypeStruct((B, H, VD), q_dt),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            **_vmem_limit(B * H * (HD + VD) * q_dt.itemsize),
         ),
         interpret=interpret,
     )
+
+
+# the queries and the result lie whole in VMEM, each in the two buffers
+# a pipelined operand gets; where that is well past what Mosaic grants
+# by default (16 MiB on a v5e, of 128 physical: 64 query heads against
+# a folded row of 768 lanes at 128 slots are 42 MB) the call asks for
+# its own; the shapes that ran before run as they did
+_VMEM_DEFAULT_BYTES = 24 << 20
+
+
+def _vmem_limit(q_and_o_bytes: int) -> dict:
+    need = 2 * q_and_o_bytes + (4 << 20)
+    if need <= _VMEM_DEFAULT_BYTES:
+        return {}
+    return {"vmem_limit_bytes": need}
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
@@ -616,14 +646,16 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
     A FOLDED pool (`kv_pool_tail`: `[L, NB, BS, KV * hd]`, q still
     `[B, H, hd]`): each query goes into the lanes of its own kv head,
     the kernel runs on one head of width `KV * hd` at the narrow
-    head's scale, and each query head's own lanes come back."""
+    head's scale, and each query head's own lanes come back.  The V
+    pool's heads may be of another width, `[L, NB, BS, KV * hd_v]`: the
+    result is then `[B, H, hd_v]`."""
     B, W = tables.shape
     H, hd = q.shape[1:]
     quantized = k_scale is not None
     if k_pool.ndim == 4:
         L, NB, BS, HD = k_pool.shape
         assert not quantized, "a folded pool has no int8 scales wired"
-        KV = HD // hd
+        KV, VD = HD // hd, v_pool.shape[-1]
         # query head h reads kv head h // group: lanes [g * hd, (g + 1) * hd)
         head = jnp.arange(H) // (H // KV)
         own = head[:, None] == jnp.arange(HD)[None, :] // hd     # [H, HD]
@@ -632,9 +664,10 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer, *,
         fn = _build_attention(L, NB, BS, 1, HD, B, W, H,
                               jnp.dtype(k_pool.dtype).name,
                               jnp.dtype(q.dtype).name, False,
-                              bool(interpret), scale=hd ** -0.5)
+                              bool(interpret), scale=hd ** -0.5,
+                              vd=VD if VD != HD else 0)
         o = fn(jnp.asarray(layer, jnp.int32).reshape(1), tables, pos, q,
-               k_pool, v_pool).reshape(B, H, KV, hd)
+               k_pool, v_pool).reshape(B, H, KV, VD // KV)
         return jnp.take_along_axis(
             o, head[None, :, None, None], axis=2)[:, :, 0]
     L, NB, BS, KV, HD = k_pool.shape

@@ -91,7 +91,7 @@ prefill likewise: the flat signature, the greedy pick, the paged rows
 into their blocks, the admitted rows' state.  `kv_write_program` is
 `kv_write`'s flat signature and the admitted slot's state.
 
-Five implementers: `LlamaEngineModel` (per-head K and V pools),
+Six implementers: `LlamaEngineModel` (per-head K and V pools),
 `LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
 absorbed decode attention, dropless experts), `RetentionEngineModel`
 (`models/brumby.py`: every layer a power-retention layer, a per-slot
@@ -100,7 +100,11 @@ attention layers, a per-slot convolution state in the others, both in
 one spec) and `SparseLatentEngineModel` (`models/dots3.py`: latent
 attention of two forms with a learned selection; three paged leaves of
 different widths and layer counts on one table; one admission family,
-its suffixes packed).
+its suffixes packed) and `WindowFullEngineModel` (`models/mimo_v2.py`:
+BOTH kinds for ATTENTION: paged K and V of different widths for the
+full layers, a per-slot RING of window rows for the window layers; a
+third admission program, `chunk_prefill`, carries the ring from chunk
+to chunk of a long prompt).
 `engine_model_for` picks by the config's type, builds the
 format from the user's `kv_dtype` and hands the implementer the
 resolved route: a user passes a model's config and the model picks its
@@ -115,10 +119,10 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.exceptions import PrefixCacheUnsupportedError
-from ray_tpu.models import brumby, deepseek_v3, dots3, lfm2, llama
+from ray_tpu.models import brumby, deepseek_v3, dots3, lfm2, llama, mimo_v2
 from ray_tpu.ops import paged_attention as _pa
 from ray_tpu.ops import retention as _ret
-from ray_tpu.serve.kv_cache import CacheLeaf
+from ray_tpu.serve.kv_cache import BlockPool, CacheLeaf
 
 KV_DTYPES = ("model", "int8")
 
@@ -378,12 +382,18 @@ class _EngineModel:
     family, `suffix_prefill_packed`: a tick's admissions, hits, misses
     and chunks of long prompts alike, share a program, each behind its
     own rows through the block table; it then has no `prefill_packed`,
-    `suffix_prefill` or `kv_write`) and `pack_align` (the rows each of
-    them is aligned to in that program)."""
+    `suffix_prefill` or `kv_write`), `pack_align` (the rows each of
+    them is aligned to in that program) and `state_carries_chunks` (a
+    per-slot leaf that a prompt admitted CHUNK BY CHUNK can carry from
+    one chunk's program to the next: a ring of window rows does, and
+    the model then has `chunk_prefill`; a retention or convolution
+    state as wired today is what a prefill leaves ONCE, and does
+    not)."""
 
     aux_rows = 0
     state_write_deferred = False
     packs_suffixes = False
+    state_carries_chunks = False
 
     def __init__(self, cfg, kv: Optional[PagedKV], *, chunk: int,
                  paged: bool, interpret: bool,
@@ -811,9 +821,132 @@ class SparseLatentEngineModel(_ExpertCounters, _EngineModel):
         return _fn
 
 
+class WindowFullEngineModel(_ExpertCounters, _EngineModel):
+    """`models/mimo_v2.py` behind the seam: a cache of BOTH kinds, both
+    for ATTENTION.  Paged `k` `[full layers, num_blocks, block_size, KV
+    * 192]` and `v` `[.., KV * 128]` for the full layers (`kv`: the two
+    tails DIFFER, a token's heads folded side by side into whole lanes),
+    and behind them a per-slot RING of window rows for the window
+    layers, `swa_k` `[window layers, slots, ring, KV_w * 192]` and
+    `swa_v` `[.., KV_w * 128]` (`state`): the row of position `p` at `p
+    mod ring`, `ring` the window.  A decode step appends to and reads
+    the pools through the tables, writes its row into each live slot's
+    ring and reads the ring masked by position.  No row of a window
+    layer is paged: `cache_bytes_per_token` counts the full layers alone
+    and `cache_bytes_per_slot`, the rings, does not grow with a
+    sequence; admission needs a slot and the FULL layers' blocks.
+
+    THREE admission programs.  `prefill_packed(N)`: whole prompts end to
+    end under the segment, causal and window masks, each prompt's last
+    `ring` window rows left in its slot's ring.  `chunk_prefill(N)`,
+    printed `jit_prefill_chunk_n<N>`: tokens `lo .. lo + n` of ONE
+    prompt too long for a packed program, `(params, *cache, tokens [N],
+    table [W], slot, lo, n, admit, pos0, stop0, pos, tok, stop) ->
+    (*cache, pos, tok, stop)`: its full layers write the chunk's rows
+    into the request's blocks and attend the earlier chunks' through
+    `table` key block by key block; its window layers attend the slot's
+    ring beside the chunk and leave the ring at the chunk's end
+    (`state_carries_chunks`); `admit` is the slot whose `pos` / `tok` /
+    `stop` the chunk sets (the prompt's last chunk; any other names a
+    slot past the last: dropped).  `suffix_prefill` and `kv_write` do
+    not exist: sharing a prefix would need the ring at the block
+    boundary.  `paged`: the paged kernels + Pallas grouped products
+    (TPU); else the same through the table in plain XLA +
+    `lax.ragged_dot` (anywhere)."""
+
+    state_carries_chunks = True
+
+    def __init__(self, cfg, kv: PagedKV, state: SlotState, **route):
+        super().__init__(cfg, kv, state=state, **route)
+        self._pairs = cfg.n_moe_layers * cfg.experts_held
+        # what `stats()["cache_bytes_per_slot"]` reads: a slot's rings
+        self._ring_bytes = BlockPool(2, spec=state.leaves).bytes_per_slot(0)
+
+    def tick_fields(self, aux) -> Dict[str, object]:
+        return {**super().tick_fields(aux),
+                "experts_held": self.cfg.experts_held}
+
+    def context_fields(self, contexts: Sequence[int]) -> Dict[str, object]:
+        """Of the rows live at a chunk's first step: `window_rows_live`,
+        the ring rows a window layer reads for them (`min(T, window)`
+        each); `ring_bytes_live`, their rings' bytes over the window
+        layers; `full_cache_tokens_live`, the tokens a full layer reads
+        for them."""
+        w = self.cfg.window
+        return {"window_rows_live": sum(min(t, w) for t in contexts),
+                "ring_bytes_live": len(contexts) * self._ring_bytes,
+                "full_cache_tokens_live": sum(contexts)}
+
+    def decode_chunk(self, W: int):
+        cfg, kw, paged = self.cfg, self._kw(), self._paged
+
+        def step(params, tok, cache, tables, pos, live):
+            logits, cache, st = mimo_v2.decode_step(
+                cfg, params, tok, cache, pos, tables, live=live,
+                paged_kernel=paged, **kw)
+            return logits, cache, (st["experts_touched"], st["load_max"])
+
+        return chunk_program(step, self.chunk, aux=self._aux)
+
+    def prefill_packed(self, N: int):
+        def forward(params, ring, tokens, packed, slots):
+            logits, kv, ring = mimo_v2.forward(
+                self.cfg, params, tokens, ring, packed=packed, slots=slots,
+                **self._kw())
+            return logits, kv, ring  # ks / vs [full layers, 1, N, KV * d]
+
+        return packed_prefill_program(self.kv, 2, forward,
+                                      segmented=self.segmented)
+
+    def prefill(self, bucket: int):
+        def _pf(params, prompt):  # prompt [1, bucket], right-padded
+            logits, (ks, vs), _ = mimo_v2.forward(self.cfg, params, prompt,
+                                                  **self._kw())
+            return logits[0], ks, vs
+
+        return _pf
+
+    def chunk_prefill(self, N: int):
+        cfg, kw, n = self.cfg, self._kw(), len(self.cache_leaves)
+
+        def _fn(params, *flat):
+            cache = flat[:n]
+            (tokens, table, slot, lo, real, admit, pos0, stop0,
+             pos, tok, stop) = flat[n:]
+            logits, cache = mimo_v2.forward_chunk(
+                cfg, params, tokens, lo, real, cache, table, slot, **kw)
+            tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (*cache,
+                    *_admitted(pos, tok, stop, admit, pos0, tok0, stop0))
+
+        return _fn
+
+    suffix_prefill = kv_write = _EngineModel._no_prefix
+
+
 def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
     """The implementer for a model's config, its cache in the format
     the user's `kv_dtype` names: the model picks its route."""
+    if isinstance(cfg, mimo_v2.MimoV2Config):
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' is not wired for the window-and-full "
+                "cache: its paged K and V are folded pools of two widths "
+                "with no scales, and the rings are 3 MB a slot already")
+        if cfg.full_sink and route.get("paged"):
+            raise ValueError("the paged decode kernel has no sink column; "
+                             "a full layer with a sink needs "
+                             "decode_kernel='gather'")
+        dk, dv = cfg.head_dim, cfg.v_head_dim
+        return WindowFullEngineModel(
+            cfg, PagedKV({"k": (cfg.n_kv_heads * dk,),
+                          "v": (cfg.n_kv_heads * dv,)}, cfg.dtype,
+                         block_size, kv_dtype, layers=cfg.n_full_layers),
+            SlotState({"swa_k": ((cfg.ring_rows, cfg.swa_n_kv_heads * dk),
+                                 cfg.dtype),
+                       "swa_v": ((cfg.ring_rows, cfg.swa_n_kv_heads * dv),
+                                 cfg.dtype)},
+                      layers=cfg.n_swa_layers), **route)
     if isinstance(cfg, dots3.Dots3Config):
         if kv_dtype == "int8":
             raise ValueError(

@@ -237,7 +237,7 @@ class LlamaEngine:
     bodies of packed prefill, prefill, suffix prefill, KV write and the
     paged decode chunk, all with flat signatures `(params, *cache, ...)`.  The
     cache's FORMAT (`kv_dtype`) is the model's too: the engine hands
-    the string over and reads it back for `stats()`.  Five implementers,
+    the string over and reads it back for `stats()`.  Six implementers,
     picked by the config's type (`engine_model_for`): `LlamaEngineModel`
     — per-head K and V pools — `LatentMoeEngineModel` — one latent
     pool, absorbed decode attention, dropless experts
@@ -246,8 +246,10 @@ class LlamaEngine:
     beside a per-slot state in one spec (`models/lfm2.py`) — and
     `SparseLatentEngineModel` — three paged leaves of different widths,
     a learned selection, one admission family that packs a tick's
-    suffixes (`models/dots3.py`).  The class keeps its name; nothing a
-    caller passes changed.
+    suffixes (`models/dots3.py`) — and `WindowFullEngineModel` — paged K
+    and V of two widths for the full layers beside a per-slot ring of
+    window rows (`models/mimo_v2.py`).  The class keeps its name;
+    nothing a caller passes changed.
 
     submit() is thread-safe and returns a `concurrent.futures.Future`
     resolving to the generated token ids (greedy — identical to what a
@@ -274,7 +276,12 @@ class LlamaEngine:
     CHUNK, each chunk a suffix prefill behind the request's own blocks
     (the cached prefix's, then those the chunks before it wrote), so a
     prompt may be as long as `max_len` allows without a program that
-    attends `[max_len, max_len]`.
+    attends `[max_len, max_len]`.  Beside a per-slot leaf it is allowed
+    where the MODEL can carry that leaf from one chunk's program to the
+    next (`engine_model.state_carries_chunks`: a ring of window rows;
+    the chunk is then ONE program, `prefill_chunk_n<N>`, that takes and
+    hands back the slot's leaves beside the blocks); a state that a
+    prefill leaves once is refused.
 
     A model that PACKS ITS SUFFIXES (`engine_model.packs_suffixes`) has
     one admission path and one program family,
@@ -428,6 +435,7 @@ class LlamaEngine:
         self._packed_cache: Dict[int, object] = {}         # pack size N
         self._prefill_cache: Dict[int, object] = {}        # prompt bucket
         self._suffix_cache: Dict[tuple, object] = {}       # (S_bucket, P_blocks)
+        self._chunk_prefill_cache: Dict[int, object] = {}  # chunk size N
         self._write_cache: Dict[tuple, object] = {}        # (T_in, nb)
 
         self._lock = threading.Lock()
@@ -468,7 +476,13 @@ class LlamaEngine:
         top = self._max_seq_blocks * self.block_size
         self._prefill_chunk = None
         if prefill_chunk is not None:
-            if not self._has_blocks or self._has_state:
+            # a chunk prefills behind the request's own blocks; a
+            # per-slot leaf has to be one the MODEL can carry from one
+            # chunk's program to the next (a ring of window rows: yes;
+            # a state that a prefill leaves once: no)
+            if not self._has_blocks or (
+                    self._has_state
+                    and not self._model.state_carries_chunks):
                 raise ValueError(
                     "prefill_chunk: chunks are suffix prefills behind the "
                     "request's own blocks, which a per-slot state has not")
@@ -599,6 +613,9 @@ class LlamaEngine:
                else self._run_packed)
         for n in self._pack_sizes:
             run(n, [])
+        if self._prefill_chunk and self._model.state_carries_chunks:
+            for n in self._pack_sizes:
+                self._run_state_chunk(None, 0, 0, 0, 1, N=n)
         self._jax.block_until_ready(self._cache)
 
     # -- public surface ------------------------------------------------
@@ -913,6 +930,19 @@ class LlamaEngine:
             _pf = self._model.suffix_prefill(s_bucket, p_blocks)
             _pf.__name__ = f"suffix_prefill_s{s_bucket}_p{p_blocks}"
             fn = self._suffix_cache[key] = self._jax.jit(_pf)
+        return fn
+
+    def _chunk_prefill_for(self, N: int):
+        """A chunk of ONE long prompt where the model carries a
+        per-slot leaf from chunk to chunk (`engine_model`'s
+        `chunk_prefill`): `N` tokens behind the request's own blocks,
+        the slot's leaves taken and handed back beside them."""
+        fn = self._chunk_prefill_cache.get(N)
+        if fn is None:
+            _pf = self._model.chunk_prefill(N)
+            _pf.__name__ = f"prefill_chunk_n{N}"
+            fn = self._chunk_prefill_cache[N] = self._jax.jit(
+                _pf, donate_argnums=tuple(range(1, 1 + len(self._cache))))
         return fn
 
     def _write_blocks_for(self, t_in: int, nb: int):
@@ -1273,9 +1303,11 @@ class LlamaEngine:
         T, P = len(plan.prompt), len(plan.shared) * bs
         step = self._prefill_chunk or T - P
         starts = range(P, T, step)
+        # a per-slot leaf the model carries rides in the chunk's program
+        run = (self._run_state_chunk if self._model.state_carries_chunks
+               else self._run_suffix_chunk)
         for i, lo in enumerate(starts):
-            self._run_suffix_chunk(plan, lo, min(T, lo + step), i,
-                                   len(starts))
+            run(plan, lo, min(T, lo + step), i, len(starts))
         plan.req["prefill_chunks"] = len(starts)
         self._hit_tokens += P
         self._prefix_hits += bool(P)
@@ -1338,6 +1370,44 @@ class LlamaEngine:
         self._prefill_calls += 1
         self._prefill_tokens += S
         self._prefill_padded_tokens += bucket
+
+    def _run_state_chunk(self, plan: Optional[_Plan], lo: int, hi: int,
+                         i: int, n: int, N: Optional[int] = None) -> None:
+        """Tokens `lo .. hi` of a prompt in ONE program that takes the
+        whole cache, the slot's per-slot leaves with the request's
+        blocks, and hands it back (`engine_model`'s `chunk_prefill`):
+        the rows into the request's blocks through its row of the table,
+        the slot's leaves as they stand at `hi`, and after the prompt's
+        last chunk the slot's state.  `plan` None is the warm-up: no
+        token, written nowhere."""
+        i32 = np.int32
+        S, last = hi - lo, plan is not None and i == n - 1
+        N = N or self._pack_size(S)
+        tokens = np.zeros(N, i32)
+        table = np.full(self._max_seq_blocks, SCRATCH_BLOCK, i32)
+        slot = self.slots  # past the last: dropped
+        if plan is not None:
+            tokens[:S] = plan.prompt[lo:hi]
+            blocks = plan.shared + plan.own
+            table[:len(blocks)] = blocks
+            slot = plan.slot
+        plans = [] if plan is None else [plan]
+        with self._phase("prefill", N=N, slot=slot, tokens=S,
+                         chunk=f"{i + 1}/{n}"):
+            self._admitting(plans)
+            out = self._launch(
+                self._chunk_prefill_for(N), self.params, *self._cache,
+                tokens, table, i32(slot), i32(lo), i32(S),
+                i32(slot if last else self.slots), i32(hi),
+                i32(plan.req["stop"] if last else 0),
+                self._pos, self._tok, self._stop)
+            self._cache = tuple(out[:-3])
+            self._pos, self._tok, self._stop = out[-3:]
+            self._prefilled(plans)
+        if plan is not None:
+            self._prefill_calls += 1
+            self._prefill_tokens += S
+            self._prefill_padded_tokens += N
 
     def _release(self, slot: int, req: Dict):
         self._slot_blocks[slot] = []

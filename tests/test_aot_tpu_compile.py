@@ -15,6 +15,7 @@ before, and a test the clock never reaches guards nothing.  Skipped
 only where the topology cannot be described (no libtpu).
 """
 
+import dataclasses
 import os
 
 import pytest
@@ -707,3 +708,81 @@ def test_hybrid_model_programs_at_the_cells_shapes(chip):
                    **donate)
     assert "jit_prefill_packed_n1296" in hlo and "input_output_alias" in hlo
     assert "bf16[32,2048,1792]" not in hlo and "bf16[32,1792,2048]" not in hlo
+
+
+# ----------------------------------------------------------------------
+# the window-and-full model: keys 192 and values 128 wide, folded pools
+# of two widths beside a per-slot ring of window rows
+# ----------------------------------------------------------------------
+def _mimo_l7():
+    from ray_tpu.models import mimo_v2
+
+    full = mimo_v2.MimoV2Config()
+    return dataclasses.replace(
+        full, layer_pattern=full.layer_pattern[:7],
+        moe_layers=full.moe_layers[:7], experts_held=16, vocab_size=19072)
+
+
+@pytest.mark.parametrize("W", [128, 545])
+def test_paged_kernels_at_keys_192_and_values_128(chip, W):
+    """The full layers' folded pools: a K page `[16, 4 x 192 = 768]` and
+    a V page `[16, 4 x 128 = 512]`, 64 query heads against them at 128
+    rows; the queries and the result lie whole in VMEM (42 MB in their
+    two buffers each), so the call asks for its own limit."""
+    B, H, NB = 128, 64, 4096
+    kp, vp = _s(2, NB, 16, 768), _s(2, NB, 16, 512)
+    tables, pos = _s(B, W, dtype=jnp.int32), _s(B, dtype=jnp.int32)
+
+    def fn(q, kp, vp, kn, vn, tables, pos, layer):
+        kp, vp = pa.paged_kv_append(kp, vp, kn, vn, tables, pos, layer)
+        return pa.paged_decode_attention(q, kp, vp, tables, pos, layer), kp, vp
+
+    hlo = _compile(chip, fn, _s(B, H, 192), kp, vp, _s(B, 768), _s(B, 512),
+                   tables, pos, _s(dtype=jnp.int32), donate_argnums=(1, 2))
+    assert hlo.count("tpu_custom_call") >= 2 and "bf16[128,64,512]" in hlo
+    assert "input_output_alias" in hlo
+
+
+def test_window_full_model_programs_at_the_cells_shapes(chip):
+    """`decode_chunk_w545`, `prefill_packed_n2048` and
+    `prefill_chunk_n2048` as the engine jits them for MiMo-V2.5's cut at
+    the published widths (7 layers: 2 full + 5 window, 16 of 256 experts
+    held, 128 slots, chunk 8): tables and per-slot rings in one
+    signature, all four leaves donated and written in place, the paged
+    kernel at 768 / 512 lanes inside the decode program."""
+    from ray_tpu.models import mimo_v2
+    from ray_tpu.serve.engine_model import engine_model_for
+
+    cfg = _mimo_l7()
+    params = jax.tree.map(
+        lambda p: _s(*p.shape, dtype=p.dtype),
+        jax.eval_shape(lambda: mimo_v2.init_params(cfg, jax.random.PRNGKey(0))))
+    model = engine_model_for(cfg, kv_dtype="model", block_size=16, chunk=8,
+                             paged=True, interpret=False)
+    assert [(l.per_slot, l.layers) for l in model.cache_leaves] == [
+        (False, 2), (False, 2), (True, 5), (True, 5)]
+    B, NB, W, N = 128, 40961, 545, 2048
+    cache = [_s(2, NB, 16, 768), _s(2, NB, 16, 512),
+             _s(5, B, 128, 1536), _s(5, B, 128, 1024)]
+    i32 = jnp.int32
+    rows, one = [_s(B, dtype=i32)] * 3, _s(dtype=i32)
+    donate = dict(donate_argnums=(1, 2, 3, 4))
+    fn = model.decode_chunk(W)
+    fn.__name__ = "decode_chunk_w545"
+    hlo = _compile(chip, fn, params, *cache, _s(B, W, dtype=i32), *rows,
+                   **donate)
+    assert "jit_decode_chunk_w545" in hlo and "bf16[128,64,512]" in hlo
+    assert "input_output_alias" in hlo
+    fn = model.prefill_packed(N)
+    fn.__name__ = "prefill_packed_n2048"
+    hlo = _compile(chip, fn, params, *cache, *[_s(N, dtype=i32)] * 3,
+                   _s(N // 16, dtype=i32), *[_s(16, dtype=i32)] * 4, *rows,
+                   **donate)
+    assert "jit_prefill_packed_n2048" in hlo and "input_output_alias" in hlo
+    fn = model.chunk_prefill(N)
+    fn.__name__ = "prefill_chunk_n2048"
+    hlo = _compile(chip, fn, params, *cache, _s(N, dtype=i32),
+                   _s(W, dtype=i32), *[one] * 6, *rows, **donate)
+    assert "jit_prefill_chunk_n2048" in hlo and "input_output_alias" in hlo
+    # never a [chunk, context] score array whole
+    assert "f32[4,16,2048,8720]" not in hlo

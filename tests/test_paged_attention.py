@@ -44,7 +44,7 @@ def _dense_reference(q, k, v, pos):
     B, H, hd = q.shape
     KV = k.shape[2]
     group = H // KV
-    out = np.zeros((B, H, hd), np.float32)
+    out = np.zeros((B, H, v.shape[-1]), np.float32)
     qf = np.asarray(q, np.float32)
     kf = np.asarray(k, np.float32)
     vf = np.asarray(v, np.float32)
@@ -75,7 +75,7 @@ _RAGGED = {
 }
 
 
-def _ragged_case(BS, W, KV, hd, seed, pad_page=0):
+def _ragged_case(BS, W, KV, hd, seed, pad_page=0, hd_v=None):
     """A random pool and five rows over it: a row at position 0, one
     whose last live page is partial, one that fills all W pages, an
     IDLE row (position 0, its table all scratch padding) and one that
@@ -95,8 +95,19 @@ def _ragged_case(BS, W, KV, hd, seed, pad_page=0):
         n = pos[b] // BS + 1
         tables[b, :n], pages = pages[:n], pages[n:]
     k = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
-    v = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
+    v = rng.standard_normal((1, NB, BS, KV, hd_v or hd)).astype(np.float32)
     return k, v, tables, pos
+
+
+# keys wider than values (`models/mimo_v2.py`: 192 / 128), on pools that
+# fold a token's heads into one row each, `[.., KV * hd_k]` and `[.., KV *
+# hd_v]`: (hd_k, hd_v) of the cases called "wide-k"
+WIDE_K = (24, 16)
+
+
+def _folded(pool):
+    """`[L, NB, BS, KV, hd]` as the folded pool `[L, NB, BS, KV * hd]`."""
+    return pool.reshape(pool.shape[:3] + (-1,))
 
 
 def _rows(pool, tables):
@@ -118,18 +129,21 @@ def _pools(k, v, dtype, int8):
         np.asarray(pa.dequantize_int8(vq, vs, jnp.float32)))
 
 
-@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("kv", ["model", "int8", "wide-k"])
 @pytest.mark.parametrize("shape", sorted(_RAGGED))
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 2e-2)])
 def test_kernel_matches_dense_reference_ragged(dtype, tol, shape, kv):
     """Ragged positions (different live lengths, partial last pages,
     shuffled non-contiguous block tables, an idle row) against a dense
-    softmax, at table widths around and between compute blocks."""
+    softmax, at table widths around and between compute blocks.
+    "wide-k": keys wider than values, on folded pools."""
     BS, W, KV, H = _RAGGED[shape]
-    hd = 16
-    k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed=7)
+    hd, hd_v = WIDE_K if kv == "wide-k" else (16, 16)
+    k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed=7, hd_v=hd_v)
     (kp, vp), scales, (kf, vf) = _pools(k, v, dtype, kv == "int8")
+    if kv == "wide-k":
+        kp, vp = _folded(kp), _folded(vp)
     q = np.random.default_rng(8).standard_normal(
         (len(pos), H, hd)).astype(np.float32)
     qd = jnp.asarray(q).astype(dtype)
@@ -137,7 +151,7 @@ def test_kernel_matches_dense_reference_ragged(dtype, tol, shape, kv):
         qd, kp, vp, jnp.asarray(tables), jnp.asarray(pos), 0,
         interpret=True, **scales,
     )
-    assert out.dtype == dtype and out.shape == q.shape
+    assert out.dtype == dtype and out.shape == q.shape[:2] + (hd_v,)
     ref = _dense_reference(np.asarray(qd, np.float32), _rows(kf, tables),
                            _rows(vf, tables), pos)
     np.testing.assert_allclose(np.asarray(out, np.float32), ref,
@@ -213,7 +227,11 @@ def _dead_case(kind, BS, W, KV, H, hd, seed):
     """(call, tables, pos): `call(tables, pos)` runs the decode
     attention of `kind` (per-head bf16, per-head int8, latent) over one
     random pool."""
-    k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed)
+    if kind == "wide-k":
+        hd, hd_v = WIDE_K
+        k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed, hd_v=hd_v)
+    else:
+        k, v, tables, pos = _ragged_case(BS, W, KV, hd, seed)
     q = jnp.asarray(np.random.default_rng(seed + 1).standard_normal(
         (len(pos), H, hd)), jnp.bfloat16)
     if kind == "latent":
@@ -226,6 +244,8 @@ def _dead_case(kind, BS, W, KV, H, hd, seed):
                 value_dim=hd - 4, scale=0.25, interpret=True)
     else:
         (kp, vp), scales, _ = _pools(k, v, jnp.bfloat16, kind == "int8")
+        if kind == "wide-k":
+            kp, vp = _folded(kp), _folded(vp)
 
         def call(tables, pos):
             return pa.paged_decode_attention(
@@ -236,7 +256,7 @@ def _dead_case(kind, BS, W, KV, H, hd, seed):
 
 @pytest.mark.parametrize("dead", [(0, 2, 3), (1, 4)], ids=["first", "last"])
 @pytest.mark.parametrize("dead_pos", [-1, 0], ids=["no-block", "one-block"])
-@pytest.mark.parametrize("kind", ["model", "int8", "latent"])
+@pytest.mark.parametrize("kind", ["model", "int8", "latent", "wide-k"])
 def test_dead_rows_leave_live_rows_bit_identical(kind, dead_pos, dead):
     """Rows that owe no token, before, between and behind live rows
     (the walk prefetches across rows): handed -1 over a scratch table
@@ -260,7 +280,7 @@ def test_dead_rows_leave_live_rows_bit_identical(kind, dead_pos, dead):
         assert not got[list(dead)].any()
 
 
-@pytest.mark.parametrize("kind", ["model", "int8", "latent"])
+@pytest.mark.parametrize("kind", ["model", "int8", "latent", "wide-k"])
 def test_append_writes_nothing_for_a_dead_row(kind):
     """`dead_row_positions` hands a dead row the first position past its
     table's reach: the append kernels reject it, so the pool (and the
@@ -284,11 +304,16 @@ def test_append_writes_nothing_for_a_dead_row(kind):
         want = [pa.mla_paged_kv_append(pool0, new[::2], tables[::2],
                                        pos[::2], 0, interpret=True)]
     else:
+        hd_v = hd
+        if kind == "wide-k":
+            hd, hd_v = WIDE_K
         k0 = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
-        v0 = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
+        v0 = rng.standard_normal((1, NB, BS, KV, hd_v)).astype(np.float32)
         (kp, vp), scales, _ = _pools(k0, v0, jnp.float32, kind == "int8")
         kn = jnp.asarray(rng.standard_normal((B, KV, hd)), jnp.float32)
-        vn = jnp.asarray(rng.standard_normal((B, KV, hd)), jnp.float32)
+        vn = jnp.asarray(rng.standard_normal((B, KV, hd_v)), jnp.float32)
+        if kind == "wide-k":  # folded pools take the rows as they are
+            kp, vp = _folded(kp), _folded(vp)
         new_scales = [{}, {}]
         if scales:
             (kn, ksn), (vn, vsn) = pa.quantize_int8(kn), pa.quantize_int8(vn)
