@@ -214,6 +214,8 @@ def sigmoid_topk_route(h, w_router, bias, top_k: int, scale: float,
 ROW_TILE = 128
 # mean rows a group from which a grouped product fetches a group ahead
 GROUP_AHEAD_FROM = 32
+# (token, expert) pairs from which a held share's pairs are compacted
+COMPACT_FROM = 16384
 
 
 def row_tiling(rows: int, groups: int) -> Tuple[int, bool]:
@@ -249,6 +251,31 @@ def tile_visits(group_sizes, row_tile: int):
     last = -(-ends // row_tile)
     return jnp.sum(jnp.where(group_sizes > 0, last - first, 0)
                    ).astype(jnp.int32)
+
+
+def slab_rows(pairs: int, held: int, experts: int) -> int:
+    """Sorted rows a slab of a held share's compact form takes (0: the
+    pair-wide form), from static shapes alone: of `pairs` (token,
+    expert) pairs the `held` of the router's `experts` get `pairs *
+    held / experts` on average, and a slab is TWICE that mean in whole
+    row tiles: at 16,384 pairs and 16 of 256 held 2,048 rows for a mean
+    of 1,024 whose standard deviation is 31, so one slab holds them all
+    but for a router that has collapsed, and then a second follows.
+
+    Under `COMPACT_FROM` pairs the pair-wide form stays.  One expert
+    layer at MiMo-V2.5's widths (16 of 256 experts of 4096 x 2048 held)
+    and at dots3's (32 of 256 of 5120 x 1536), device time by op: at
+    8,192 pairs the pair-wide form's two gathers are 0.19-0.22 ms
+    beside 1.1 / 2.0 ms of grouped products and the forms tie (1.84
+    against 1.61 ms a layer, 2.80 against 2.79); at 16,384 pairs XLA's
+    gather of `[16384, D]` rows takes 2.0-2.5 ms and a layer is 4.15
+    ms pair-wide, 2.26 compact.  A decode chunk's 1,024 pairs and every
+    program under 2,048 tokens at top-8 stay what they were (PERF.md
+    section 6, PR 52: the sweep)."""
+    if pairs < COMPACT_FROM:
+        return 0
+    tiles = -(-min(2 * pairs * held // experts, pairs) // ROW_TILE)
+    return max(tiles, 1) * ROW_TILE
 
 
 def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
@@ -322,7 +349,8 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
     least one row), `load_max` (rows of the largest group) and
     `tile_visits` (`tile_visits` of ONE of the three products at the
     row tile `row_tiling` gives; `ragged_dot` has no tiles and counts
-    megablox's).
+    megablox's), and `passes` (the slabs a held share's compact form
+    walked, below; 0 on this pair-wide form).
 
     `row_mask` [N] bool (the serve engine's live rows of a decode step;
     prefill passes none): a row it leaves out is routed to NO expert.
@@ -338,9 +366,16 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
     as a masked row's pairs do (behind the last group, in no group
     size) and adds nothing to `y`, which is then this chip's PARTIAL sum
     (the other chips' parts are theirs to add: nothing here stands in
-    for them); `stats` count the held experts."""
+    for them); `stats` count the held experts.  From `COMPACT_FROM`
+    pairs on (`slab_rows`, static shapes alone) nothing pair-wide is
+    built for a share: the held pairs, the first `sum(sizes)` sorted
+    rows, are gathered, multiplied and added back a slab at a time
+    (`_held_slabs`), the same pairs and the same float32 sum in another
+    order; `row_tiling` is asked with the slab's rows, and
+    `tile_visits` is summed over the slabs."""
     N, D = h.shape
     E = layer["router"].shape[-1]
+    rows = 0 if held is None else slab_rows(N * top_k, held[1], E)
     with jax.named_scope("moe_router"):
         w, idx = sigmoid_topk_route(h, layer["router"], layer["router_bias"],
                                     top_k, scale, route_eps)
@@ -351,27 +386,80 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
             idx = jnp.where(row_mask[:, None], idx, E)
         flat = idx.reshape(-1)                      # [N * k], pair -> expert
         order = jnp.argsort(flat, stable=True)      # sorted row -> pair
-        inverse = jnp.argsort(order)                # pair -> sorted row
+        if not rows:
+            inverse = jnp.argsort(order)            # pair -> sorted row
         sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
-    with jax.named_scope("moe_routed"):
-        xs = h.astype(dtype)[order // top_k]        # [N * k, D]
+
+    def swiglu(xs, sizes):
         mm = lambda a, b: grouped_matmul(  # noqa: E731
             a, b.astype(dtype), sizes, kernel=kernel, interpret=interpret,
             stack_index=stack_index)
         act = jax.nn.silu(mm(xs, layer["e_gate"])) * mm(xs, layer["e_up"])
-        ys = mm(act, layer["e_down"])               # [N * k, D]
-        y = ys[inverse].reshape(N, top_k, D).astype(jnp.float32)
-        if held is not None:
-            # a pair that went to no expert reads a row past the last
-            # group: whatever the product left there
-            y = jnp.where((idx < E)[..., None], y, 0.0)
-        y = jnp.sum(y * w[..., None], axis=1).astype(dtype)
-        if row_mask is not None:
-            # rows of `ys` past the last group are whatever the product
-            # left there, a NaN's bits too
-            y = jnp.where(row_mask[:, None], y, jnp.zeros_like(y))
-    tile = row_tiling(N * top_k, E)[0] if kernel else ROW_TILE
+        return mm(act, layer["e_down"])
+
+    tile = row_tiling(rows or N * top_k, E)[0] if kernel else ROW_TILE
+    visits, passes = None, jnp.int32(0)
+    with jax.named_scope("moe_routed"):
+        if rows:
+            y, visits, passes = _held_slabs(
+                h.astype(dtype), w, order, sizes, swiglu, top_k=top_k,
+                rows=rows, tile=tile)
+            y = y.astype(dtype)
+        else:
+            xs = h.astype(dtype)[order // top_k]    # [N * k, D]
+            ys = swiglu(xs, sizes)                  # [N * k, D]
+            y = ys[inverse].reshape(N, top_k, D).astype(jnp.float32)
+            if held is not None:
+                # a pair that went to no expert reads a row past the
+                # last group: whatever the product left there
+                y = jnp.where((idx < E)[..., None], y, 0.0)
+            y = jnp.sum(y * w[..., None], axis=1).astype(dtype)
+            if row_mask is not None:
+                # rows of `ys` past the last group are whatever the
+                # product left there, a NaN's bits too
+                y = jnp.where(row_mask[:, None], y, jnp.zeros_like(y))
     stats = {"experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
              "load_max": jnp.max(sizes),
-             "tile_visits": tile_visits(sizes, tile)}
+             "tile_visits": (tile_visits(sizes, tile) if visits is None
+                             else visits),
+             "passes": passes}
     return y, stats
+
+
+def _held_slabs(h, w, order, sizes, swiglu, *, top_k: int, rows: int,
+                tile: int):
+    """`dropless_moe`'s compact form for a held share: the pairs with a
+    group are the first `sum(sizes)` sorted rows, and only they are
+    gathered, multiplied and added back, a SLAB of `rows` sorted rows at
+    a time (`slab_rows`: static).  A slab gathers its rows of `h`, runs
+    `swiglu` (the three grouped products) on `[rows, D]` with each
+    group's size clipped to the slab, and scatter-adds its rows, times
+    the router's weights in float32, into `y [N, D]` float32 by token.
+    The slabs are walked while one begins under `sum(sizes)`: one in
+    the normal case, more where the router sends this chip more than
+    twice its mean, so nothing is dropped whatever the routing.  A token
+    none of whose pairs is held (a masked row among them) keeps its
+    zeros.  -> (y float32, the slabs' `tile_visits` summed, slabs
+    walked)."""
+    N, D = h.shape
+    total, ends = jnp.sum(sizes), jnp.cumsum(sizes)
+    starts = ends - sizes
+    # a slab is sliced whole: the last may reach past the pairs
+    order = jnp.pad(order, (0, -order.shape[0] % rows))
+    w = w.reshape(-1)
+
+    def slab(carry):
+        lo, y, visits = carry
+        pairs = lax.dynamic_slice(order, (lo,), (rows,))
+        tok = pairs // top_k
+        here = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+        ys = swiglu(h[tok], here).astype(jnp.float32) * w[pairs][:, None]
+        # rows past the last group are whatever the product left there
+        paired = lo + jnp.arange(rows, dtype=jnp.int32) < total
+        y = y.at[tok].add(jnp.where(paired[:, None], ys, 0.0))
+        return lo + rows, y, visits + tile_visits(here, tile)
+
+    lo, y, visits = lax.while_loop(
+        lambda carry: carry[0] < total, slab,
+        (jnp.int32(0), jnp.zeros((N, D), jnp.float32), jnp.int32(0)))
+    return y, visits, lo // rows

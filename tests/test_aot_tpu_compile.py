@@ -773,12 +773,15 @@ def test_window_full_model_programs_at_the_cells_shapes(chip):
                    **donate)
     assert "jit_decode_chunk_w545" in hlo and "bf16[128,64,512]" in hlo
     assert "input_output_alias" in hlo
+    # a step's 1,024 pairs stay pair-wide: every pair's row in and out
+    assert "bf16[1024,4096]" in hlo
     fn = model.prefill_packed(N)
     fn.__name__ = "prefill_packed_n2048"
     hlo = _compile(chip, fn, params, *cache, *[_s(N, dtype=i32)] * 3,
                    _s(N // 16, dtype=i32), *[_s(16, dtype=i32)] * 4, *rows,
                    **donate)
     assert "jit_prefill_packed_n2048" in hlo and "input_output_alias" in hlo
+    _holds_slab_rows_only(hlo)
     fn = model.chunk_prefill(N)
     fn.__name__ = "prefill_chunk_n2048"
     hlo = _compile(chip, fn, params, *cache, _s(N, dtype=i32),
@@ -786,3 +789,29 @@ def test_window_full_model_programs_at_the_cells_shapes(chip):
     assert "jit_prefill_chunk_n2048" in hlo and "input_output_alias" in hlo
     # never a [chunk, context] score array whole
     assert "f32[4,16,2048,8720]" not in hlo
+    _holds_slab_rows_only(hlo)
+
+
+def _holds_slab_rows_only(hlo):
+    """A 2,048-token admission program's expert layers move the held
+    pairs alone (`parallel/moe.dropless_moe(held=)` from `COMPACT_FROM`
+    pairs on): no array over all 16,384 (token, expert) pairs at the
+    model's width, none `[tokens, top_k, D]` in float32, and the slab's
+    2,048 rows (twice the mean 1,024 that fall to 16 of 256 experts) in
+    and out of the grouped products, whose tile is 64 rows."""
+    from ray_tpu.parallel import moe
+
+    assert moe.slab_rows(2048 * 8, 16, 256) == 2048
+    # what the expert layers compute (the dense layer's `w_down` is a
+    # `bf16[16384,4096]` too): the ops traced under their scope
+    made = [ln.split("=", 1)[1].lstrip() for ln in hlo.splitlines()
+            if " = " in ln and "/moe_routed/" in ln]
+    for pair_wide in ("bf16[16384,4096]", "bf16[16384,2048]",
+                      "f32[16384,4096]", "f32[2048,8,4096]"):
+        assert not [c for c in made if c.startswith(pair_wide)], pair_wide
+    assert [c for c in made if c.startswith("bf16[2048,4096]")]
+    calls = [c for c in made if "grouped_matmul_prefetch" in c
+             and "custom-call(" in c]
+    assert len(calls) == 3 * 6
+    assert sum(c.startswith("bf16[2048,2048]") for c in calls) == 2 * 6
+    assert sum(c.startswith("bf16[2048,4096]") for c in calls) == 6

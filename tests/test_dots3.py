@@ -118,6 +118,24 @@ def test_full_forward_equals_the_reference(params):
     assert np.abs(want).mean() > 0.3   # logits of order one
 
 
+def test_full_forward_with_the_held_pairs_compacted_equals_the_reference(
+        params, monkeypatch):
+    """`parallel/moe.COMPACT_FROM` lowered to this model's size: the
+    expert layers move the held pairs alone, the logits stay the
+    reference's."""
+    monkeypatch.setattr(moe, "COMPACT_FROM", 64)
+    slabs, traced = moe._held_slabs, []
+    monkeypatch.setattr(moe, "_held_slabs", lambda *a, **kw: (
+        traced.append(kw["rows"]), slabs(*a, **kw))[1])
+    toks = tokens(40)
+    assert moe.slab_rows(40 * CFG.top_k, CFG.experts_held,
+                         CFG.n_routed_experts)
+    want, _ = ref_logits(CFG, params, toks)
+    got = np.asarray(dots3.forward(CFG, params, jnp.asarray(toks)))
+    assert np.abs(got - want).max() < TOL
+    assert traced and set(traced) == {128}
+
+
 def test_bf16_breaks_it(params):
     """The tolerance is one a bfloat16-for-float32 swap breaks."""
     toks = tokens(40)

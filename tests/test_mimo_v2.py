@@ -209,6 +209,25 @@ def test_forward_equals_the_reference(params, n):
     assert np.abs(want).mean() > 0.3   # logits of order one
 
 
+def test_forward_with_the_held_pairs_compacted_equals_the_reference(
+        params, monkeypatch):
+    """A program long enough for `parallel/moe.COMPACT_FROM` moves the
+    held pairs alone through its expert layers: the reference's logits
+    all the same (the constant lowered to this model's size; a fresh
+    trace, the module's jitted forward was traced above it)."""
+    monkeypatch.setattr(moe, "COMPACT_FROM", 64)
+    slabs, traced = moe._held_slabs, []
+    monkeypatch.setattr(moe, "_held_slabs", lambda *a, **kw: (
+        traced.append(kw["rows"]), slabs(*a, **kw))[1])
+    toks = tokens(LONG)
+    assert moe.slab_rows(LONG * CFG.top_k, CFG.experts_held,
+                         CFG.n_routed_experts)
+    got = jax.jit(lambda p, t: mimo_v2.forward(CFG, p, t)[0][0])(
+        params, jnp.asarray([toks], jnp.int32))
+    assert np.abs(np.asarray(got) - ref_logits(CFG, params, toks)).max() < TOL
+    assert len(traced) == sum(CFG.moe_layers)
+
+
 def test_bf16_breaks_it(params):
     """The tolerance is one a bfloat16-for-float32 swap breaks."""
     toks = tokens(LONG)

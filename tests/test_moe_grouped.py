@@ -194,3 +194,106 @@ def test_the_rule_reads_static_shapes_and_leaves_decode_alone(
     rows = -(-pairs // tile) * tile
     name = "grouped_matmul_prefetch" if ahead else None  # megablox: none
     assert found == [(name, (rows, I)), (name, (rows, I)), (name, (rows, D))]
+
+
+# -- a held share: the compact form -------------------------------------
+def _held_rows(case, N, lo, n, E=16):
+    """`h` [N, D] for `_layer`'s router (a row goes to the experts whose
+    columns of `h` are largest): random, or a share of the rows sent to
+    the held experts `lo .. lo + n` with all of their pairs, the others
+    to experts held elsewhere."""
+    rng = np.random.default_rng(11)
+    h = np.zeros((N, D), np.float32)
+    h[:, E:] = rng.normal(size=(N, D - E))
+    h[:, :E] = rng.normal(size=(N, E))
+    if case != "random":
+        here = rng.permutation(N) < int(N * case)
+        away = [e for e in range(E) if not lo <= e < lo + n]
+        h[np.ix_(here, range(lo, lo + n))] += 9.0
+        h[np.ix_(~here, away)] += 9.0
+    return jnp.asarray(h)
+
+
+HELD = [
+    # case (or the share of rows sent here), top_k, tokens, (lo, n), mask,
+    # kernel's tile (None: `ragged_dot`), slabs walked.  `ragged_dot` at
+    # the constant's 16,384 pairs; the interpreter at an eighth of it
+    ("random", 4, 4096, (0, 8), False, None, 1),    # the shares of 2,
+    ("random", 4, 4096, (8, 8), True, None, 1),
+    ("random", 4, 4096, (4, 4), False, None, 1),    # of 4
+    ("random", 4, 512, (12, 4), True, 16, 1),
+    ("random", 4, 512, (6, 2), False, 16, 1),       # and of 8
+    ("random", 4, 4100, (14, 2), True, None, 1),    # a last slab cut short
+    (0.5, 2, 8192, (4, 2), False, None, 2),         # a skewed router:
+    (0.75, 2, 8192, (4, 2), True, None, 3),         # more than a slab
+    (0.75, 2, 1024, (2, 2), False, 32, 3),
+    (1.0, 2, 8192, (0, 2), False, None, 4),         # every pair is held
+    (0.0, 2, 8192, (8, 2), False, None, 0),         # and none is
+    (0.0, 2, 1024, (8, 2), True, 32, 0),
+]
+
+
+@pytest.mark.parametrize(
+    "case,top_k,N,held,masked,tm,passes", HELD,
+    ids=[f"{c[0]}-k{c[1]}-n{c[2]}-held{c[3][0]}+{c[3][1]}"
+         f"{'-row-mask' if c[4] else ''}{'-t%d' % c[5] if c[5] else ''}"
+         for c in HELD])
+def test_a_held_share_moves_only_its_pairs(monkeypatch, case, top_k, N, held,
+                                           masked, tm, passes):
+    """From `COMPACT_FROM` pairs on a held share gathers, multiplies and
+    adds back its own pairs alone, a slab at a time: the pair-wide
+    form's result to float32 rounding (the at most `top_k` terms of a
+    token are added in another order) and `reference.routed`'s, for ANY
+    routing: a router that sends this share several slabs' worth walks
+    them all (`passes`), one that sends it nothing walks none and gives
+    zeros.  `tile_visits` is summed over the slabs, each packed from its
+    own row 0."""
+    from benchmarks.reference import dots3 as ref
+
+    E, (lo, n) = 16, held
+    if tm:
+        monkeypatch.setattr(moe, "COMPACT_FROM", moe.COMPACT_FROM // 8)
+    assert N * top_k >= moe.COMPACT_FROM
+    assert not moe.slab_rows(moe.COMPACT_FROM - 1, n, E)
+    rows = moe.slab_rows(N * top_k, n, E)
+    assert rows % moe.ROW_TILE == 0 and rows < N * top_k + moe.ROW_TILE
+    whole = _layer(E, False)
+    layer = {**whole, **{k: whole[k][lo:lo + n]
+                         for k in ("e_gate", "e_up", "e_down")}}
+    h = _held_rows(case, N, lo, n)
+    mask = np.ones(N, bool)
+    if masked:
+        mask[[0, 3, N // 2, N - 1]] = False
+    kw = dict(top_k=top_k, scale=1.5, route_eps=1e-6, dtype=jnp.float32,
+              held=held, row_mask=jnp.asarray(mask) if masked else None)
+    if tm:
+        monkeypatch.setattr(moe, "row_tiling", lambda r, g: (tm, True))
+        kw.update(kernel=True, interpret=True)
+    y, stats = moe.dropless_moe(h, layer, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(moe, "COMPACT_FROM", N * top_k + 1)
+        wide, wide_stats = moe.dropless_moe(h, layer, **kw)
+    assert int(wide_stats["passes"]) == 0
+    _, idx = moe.sigmoid_topk_route(h, layer["router"], layer["router_bias"],
+                                    top_k, 1.5, 1e-6)
+    idx = np.asarray(idx)[mask].ravel()
+    sizes = np.bincount(idx[(idx >= lo) & (idx < lo + n)] - lo, minlength=n)
+    assert -(-sizes.sum() // rows) == passes == int(stats["passes"])
+    for k in ("experts_touched", "load_max"):
+        assert int(stats[k]) == int(wide_stats[k])
+    assert int(stats["load_max"]) == sizes.max()
+    # a slab's groups: each group's rows that fall inside it
+    ends = np.cumsum(sizes)
+    cut = [np.clip(ends, a, a + rows) - np.clip(ends - sizes, a, a + rows)
+           for a in range(0, int(sizes.sum()), rows)]
+    assert int(stats["tile_visits"]) == sum(
+        _visits(c, tm or moe.ROW_TILE) for c in cut)
+    y, wide = np.asarray(y), np.asarray(wide)
+    assert np.isfinite(y).all() and not y[~mask].any()
+    np.testing.assert_allclose(y, wide, atol=2e-5, rtol=2e-5)
+    want = ref.routed(h, layer, top_k=top_k, scale=1.5, offset=lo,
+                      quant=ref._identity)
+    np.testing.assert_allclose(y[mask], np.asarray(want)[mask],
+                               atol=5e-5, rtol=5e-5)
+    if not passes:
+        assert not y.any()
