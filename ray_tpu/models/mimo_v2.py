@@ -52,6 +52,17 @@ layer attends the slot's ring and the chunk and leaves the ring as it
 stands at the chunk's end).  All window attention is banded: a query
 block of `ring` rows meets the `2 x ring` keys it can see.
 
+WHERE A FULL LAYER'S PREFILL FOLDS.  `paged_kernel` (the engine's
+`paged` route: the chip, and the interpreter in the CPU tests): in ONE
+fused kernel a layer, `ops/prefill_attention.py`, the scores in VMEM;
+`forward` hands it the row's own keys, `forward_chunk` the table's rows
+gathered once into contiguous K and V (`_fused_full`), and it skips the
+key blocks the masks would zero whole.  Anywhere else: `_attend_blocks`,
+the same fold as a `fori_loop` in plain XLA, a key block's scores `[KV,
+G, N, 512]` float32 through HBM.  The window layers' `_banded` and every
+decode attention are the same on both routes but for the paged decode
+kernels.
+
 Layers are a LIST of per-layer dicts and the programs unroll them: the
 two kinds differ in their leaves' shapes.  `jax.named_scope`s
 `full_attn`, `swa_attn`, `swa_ring_write`, `dense_mlp`, `moe_router`,
@@ -70,6 +81,7 @@ from jax import lax
 from ray_tpu.models.deepseek_v3 import _swiglu
 from ray_tpu.models.llama import Packed, _apply, _embed, _lm_head, _rms_norm
 from ray_tpu.ops import paged_attention as _pa
+from ray_tpu.ops.prefill_attention import prefill_attention
 from ray_tpu.parallel.moe import dropless_moe
 
 F32 = jnp.float32
@@ -81,7 +93,15 @@ MOE_LAYERS = (0,) + (1,) * 47
 ROUTE_EPS = 1e-20
 NEG = -1e30
 # keys a full layer's prefill folds into its running softmax at a time
+# in plain XLA ...
 KEY_BLOCK = 512
+# ... and in the fused kernel, whose scores stay in VMEM: (query rows,
+# each all 16 heads of a group; keys).  Measured on the v5e at 2,048
+# rows behind 0 / 2,048 / 4,096 / 6,144: 128 x 512 2.09 / 4.30 / 6.51 /
+# 8.71 ms, 128 x 1024 1.76 / 3.04 / 4.28 / 5.60, 256 x 1024 the same
+# within 2%, 128 x 2048 2.19 / 3.55 / 4.92 / 6.30 (the XLA fold: 4.81 /
+# 9.29 / 13.8 / 18.3; PERF.md section 6, PR 53)
+FUSED_BLOCKS = (128, 1024)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -328,6 +348,19 @@ def _attend_blocks(cfg, q, keys_of, n_blocks, sink):
     return jnp.moveaxis(o, 2, 0)
 
 
+def _fused_full(cfg, q, k, v, qseg, kseg, lo, sink, interpret):
+    """`_attend_blocks`' fold as ONE kernel whose scores stay in VMEM
+    (`ops/prefill_attention.py`): q [N, KV, G, dk] whose row `i` is key
+    row `lo + i` of the contiguous k [S, KV, dk] / v [S, KV, dv], seen
+    where the segments agree, the key is not past the query and the
+    query is real -> [N, KV, G, dv] in the model's dtype."""
+    if sink is not None:
+        raise ValueError("the fused prefill attention has no sink column")
+    return prefill_attention(
+        q, k, v, qseg, kseg, lo, scale=cfg.head_dim ** -0.5,
+        block_q=FUSED_BLOCKS[0], block_k=FUSED_BLOCKS[1], interpret=interpret)
+
+
 def _banded(cfg, q, k, v, qpos, qseg, prev, sink):
     """Window attention of a row of `N` tokens whose sequences lie end
     to end: query block `i` of `ring` rows meets key rows `(i - 1) *
@@ -409,7 +442,8 @@ def _head(cfg, params, x):
 # ----------------------------------------------------------------------
 def forward(cfg: MimoV2Config, params: Dict, tokens: jax.Array, ring=None,
             *, packed: Optional[Packed] = None, slots=None,
-            kernel: bool = False, interpret: bool = False):
+            kernel: bool = False, interpret: bool = False,
+            paged_kernel: bool = False):
     """tokens [1, T] -> (logits float32, (ks, vs), ring).
 
     `packed` None: one prompt from position 0, right-padded: logits `[1,
@@ -420,7 +454,9 @@ def forward(cfg: MimoV2Config, params: Dict, tokens: jax.Array, ring=None,
     the rows the full layers cache, as the pools hold them.  `ring` =
     `(swa_k, swa_v)` `[window layers, slots, ring, ..]` with `slots` [K]
     (past the last: dropped): each prompt's last `ring` window rows go
-    into its slot's ring at `position mod ring`; None: none is kept."""
+    into its slot's ring at `position mod ring`; None: none is kept.
+    `paged_kernel`: a full layer's attention is ONE fused kernel
+    (`_fused_full`); else the same fold in plain XLA."""
     B, T = tokens.shape
     if B != 1:
         raise ValueError("a prefill takes one row")
@@ -451,16 +487,19 @@ def forward(cfg: MimoV2Config, params: Dict, tokens: jax.Array, ring=None,
         if kind == FULL:
             with jax.named_scope("full_attn"):
                 q, k, v = _qkv(cfg, a, layer, h, pos)
+                if paged_kernel:
+                    o = _fused_full(cfg, q, k, v, seg, seg, 0, sink,
+                                    interpret)
+                else:
+                    def keys_of(j, k=k, v=v):
+                        cut = lambda t: lax.dynamic_slice_in_dim(  # noqa: E731
+                            t, j * KB, KB, 0)
+                        mask = ((seg[:, None] == cut(seg)[None, :])
+                                & (row[:, None] >= cut(row)[None, :])
+                                & real[:, None])
+                        return cut(k), cut(v), mask
 
-                def keys_of(j, k=k, v=v):
-                    cut = lambda t: lax.dynamic_slice_in_dim(  # noqa: E731
-                        t, j * KB, KB, 0)
-                    mask = ((seg[:, None] == cut(seg)[None, :])
-                            & (row[:, None] >= cut(row)[None, :])
-                            & real[:, None])
-                    return cut(k), cut(v), mask
-
-                o = _attend_blocks(cfg, q, keys_of, T // KB, sink)
+                    o = _attend_blocks(cfg, q, keys_of, T // KB, sink)
                 ks.append(k.reshape(T, -1))
                 vs.append(v.reshape(T, -1))
         else:
@@ -488,7 +527,8 @@ def forward(cfg: MimoV2Config, params: Dict, tokens: jax.Array, ring=None,
 # ----------------------------------------------------------------------
 def forward_chunk(cfg: MimoV2Config, params: Dict, tokens: jax.Array,
                   lo, n, cache, table: jax.Array, slot, *,
-                  kernel: bool = False, interpret: bool = False):
+                  kernel: bool = False, interpret: bool = False,
+                  paged_kernel: bool = False):
     """Tokens `lo .. lo + n` of one sequence, `tokens` [N] (the first
     `n` real), behind the `lo` tokens that earlier chunks cached:
     `cache` = `(k, v, swa_k, swa_v)`, `table` [W] the sequence's blocks
@@ -498,9 +538,11 @@ def forward_chunk(cfg: MimoV2Config, params: Dict, tokens: jax.Array,
     sequence's `pos`, masked until decoding overwrites it; a block of
     padding alone is written nowhere), then attends positions `0 .. lo +
     n` through the table in key blocks of `KEY_BLOCK` under a running
-    softmax; a window layer attends the slot's ring (the `ring` rows
-    before `lo`) beside the chunk and leaves the ring as it stands at
-    `lo + n`.  Returns (logits [vocab] float32 of the chunk's last real
+    softmax (`paged_kernel`: the table's rows gathered ONCE a layer
+    into contiguous K and V and folded by one fused kernel,
+    `_fused_full`); a window layer attends the slot's ring (the `ring`
+    rows before `lo`) beside the chunk and leaves the ring as it stands
+    at `lo + n`.  Returns (logits [vocab] float32 of the chunk's last real
     token, the cache)."""
     dt = cfg.dtype
     k_pool, v_pool, ring_k, ring_v = cache
@@ -541,15 +583,25 @@ def forward_chunk(cfg: MimoV2Config, params: Dict, tokens: jax.Array,
                         mode="drop")
                     for pool, t in ((k_pool, k), (v_pool, v)))
 
-                def keys_of(j, li=li, a=a, k_pool=k_pool, v_pool=v_pool):
-                    blk = table[jnp.clip(j * PB + jnp.arange(PB), 0, W - 1)]
-                    kpos = j * KB + jnp.arange(KB)
-                    mask = (kpos[None, :] <= pos[:, None]) & real[:, None]
-                    return (k_pool[li, blk].reshape(KB, a.kv, -1).astype(dt),
+                if paged_kernel:
+                    o = _fused_full(
+                        cfg, q,
+                        k_pool[li, table].reshape(W * BS, a.kv, -1).astype(dt),
+                        v_pool[li, table].reshape(W * BS, a.kv, -1).astype(dt),
+                        seg, jnp.zeros((W * BS,), jnp.int32), lo, sink,
+                        interpret)
+                else:
+                    def keys_of(j, li=li, a=a, k_pool=k_pool, v_pool=v_pool):
+                        blk = table[jnp.clip(j * PB + jnp.arange(PB), 0,
+                                             W - 1)]
+                        kpos = j * KB + jnp.arange(KB)
+                        mask = (kpos[None, :] <= pos[:, None]) & real[:, None]
+                        return (
+                            k_pool[li, blk].reshape(KB, a.kv, -1).astype(dt),
                             v_pool[li, blk].reshape(KB, a.kv, -1).astype(dt),
                             mask)
 
-                o = _attend_blocks(cfg, q, keys_of, n_blocks, sink)
+                    o = _attend_blocks(cfg, q, keys_of, n_blocks, sink)
         else:
             with jax.named_scope("swa_attn"):
                 pk = ring_k[li, slot][prow].reshape(R, a.kv, -1).astype(dt)

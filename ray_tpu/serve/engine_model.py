@@ -850,8 +850,12 @@ class WindowFullEngineModel(_ExpertCounters, _EngineModel):
     `stop` the chunk sets (the prompt's last chunk; any other names a
     slot past the last: dropped).  `suffix_prefill` and `kv_write` do
     not exist: sharing a prefix would need the ring at the block
-    boundary.  `paged`: the paged kernels + Pallas grouped products
-    (TPU); else the same through the table in plain XLA +
+    boundary.  `paged`: the paged decode kernels, the full layers'
+    prefill attention as one fused kernel a layer in both admission
+    programs (`ops/prefill_attention.py`: the scores stay in VMEM) +
+    Pallas grouped products (TPU; `interpret`: the attention kernels in
+    the interpreter); else the same through the table in plain XLA,
+    the prefill's running softmax a `fori_loop` over key blocks, +
     `lax.ragged_dot` (anywhere)."""
 
     state_carries_chunks = True
@@ -877,13 +881,17 @@ class WindowFullEngineModel(_ExpertCounters, _EngineModel):
                 "ring_bytes_live": len(contexts) * self._ring_bytes,
                 "full_cache_tokens_live": sum(contexts)}
 
+    def _kw(self):
+        # the full layers' attention follows the route in all three
+        # programs: the paged decode kernels, the fused prefill fold
+        return dict(super()._kw(), paged_kernel=self._paged)
+
     def decode_chunk(self, W: int):
-        cfg, kw, paged = self.cfg, self._kw(), self._paged
+        cfg, kw = self.cfg, self._kw()
 
         def step(params, tok, cache, tables, pos, live):
             logits, cache, st = mimo_v2.decode_step(
-                cfg, params, tok, cache, pos, tables, live=live,
-                paged_kernel=paged, **kw)
+                cfg, params, tok, cache, pos, tables, live=live, **kw)
             return logits, cache, (st["experts_touched"], st["load_max"])
 
         return chunk_program(step, self.chunk, aux=self._aux)

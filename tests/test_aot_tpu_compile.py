@@ -782,6 +782,7 @@ def test_window_full_model_programs_at_the_cells_shapes(chip):
                    **donate)
     assert "jit_prefill_packed_n2048" in hlo and "input_output_alias" in hlo
     _holds_slab_rows_only(hlo)
+    _folds_the_full_layers_in_one_kernel_each(hlo)
     fn = model.chunk_prefill(N)
     fn.__name__ = "prefill_chunk_n2048"
     hlo = _compile(chip, fn, params, *cache, _s(N, dtype=i32),
@@ -790,6 +791,27 @@ def test_window_full_model_programs_at_the_cells_shapes(chip):
     # never a [chunk, context] score array whole
     assert "f32[4,16,2048,8720]" not in hlo
     _holds_slab_rows_only(hlo)
+    _folds_the_full_layers_in_one_kernel_each(hlo)
+
+
+def _folds_the_full_layers_in_one_kernel_each(hlo):
+    """A 2,048-token admission program under the `paged` route: each of
+    the two full layers' attention is ONE `prefill_attention` call under
+    the `full_attn` scope (where `window_full_prefill_attn_ms` finds it)
+    and no key block's scores `[KV, G, N, 512]` exist as an array.  The
+    benchmark tells this cell's kernels apart by what they return: the
+    call gives back `bf16[4,16,2048,128]`, not the paged decode kernel's
+    `bf16[128,64,512]`, and aliases no operand, as the append does."""
+    assert "f32[4,16,2048,512]" not in hlo and "f32[4,16,2048,1024]" not in hlo
+    calls = [ln.split("=", 1)[1].lstrip() for ln in hlo.splitlines()
+             if " = " in ln and "tpu_custom_call" in ln
+             and "/full_attn/" in ln]
+    assert len(calls) == 2
+    for c in calls:
+        assert c.startswith("bf16[4,16,2048,128]") and "prefill_attention" in c
+        assert "output_to_operand_aliasing" not in c
+    assert not [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+                and ln.split("=", 1)[1].lstrip().startswith("bf16[128,64,512]")]
 
 
 def _holds_slab_rows_only(hlo):

@@ -85,16 +85,34 @@ def forward_logits(cfg, params, toks):
 
 
 @functools.lru_cache(maxsize=None)
-def _packed(cfg):
+def _packed(cfg, paged=False):
     return jax.jit(lambda p, t, ring, packed, slots: mimo_v2.forward(
-        cfg, p, t, ring, packed=packed, slots=slots))
+        cfg, p, t, ring, packed=packed, slots=slots, paged_kernel=paged,
+        interpret=paged))
 
 
 @functools.lru_cache(maxsize=None)
-def _chunk(cfg):
+def _chunk(cfg, paged=False):
     return jax.jit(lambda p, t, lo, n, cache, table, slot:
                    mimo_v2.forward_chunk(cfg, p, t, lo, n, cache, table,
-                                         slot))
+                                         slot, paged_kernel=paged,
+                                         interpret=paged))
+
+
+ROUTES = pytest.mark.parametrize("paged", [False, True],
+                                 ids=["xla", "paged-interpret"])
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """The fused prefill kernel's blocks cut to this model's size, so a
+    prompt of 40 tokens walks several key blocks a query block, some
+    plain, some masked, some skipped; the programs traced under them
+    are dropped with them."""
+    monkeypatch.setattr(mimo_v2, "FUSED_BLOCKS", (8, 16))
+    _packed.cache_clear(), _chunk.cache_clear()
+    yield
+    _packed.cache_clear(), _chunk.cache_clear()
 
 
 class Cache:
@@ -133,7 +151,7 @@ class Cache:
             last[i], sl[i] = at + T - 1, s
             at += nb * BS
         packed = Packed(jnp.asarray(last), jnp.asarray(seg), jnp.asarray(posn))
-        logits, (ks, vs), ring = _packed(self.cfg)(
+        logits, (ks, vs), ring = _packed(self.cfg, self.paged)(
             self.params, jnp.asarray(tok)[None], self.cache[2:], packed,
             jnp.asarray(sl, jnp.int32))
         k_pool, v_pool = (
@@ -147,7 +165,7 @@ class Cache:
         N = N or -(-(hi - lo) // BS) * BS
         buf = np.zeros(N, np.int32)
         buf[:hi - lo] = toks[lo:hi]
-        logits, self.cache = _chunk(self.cfg)(
+        logits, self.cache = _chunk(self.cfg, self.paged)(
             self.params, jnp.asarray(buf), jnp.int32(lo), jnp.int32(hi - lo),
             self.cache, jnp.asarray(self.tables[slot], jnp.int32),
             jnp.int32(slot))
@@ -238,9 +256,9 @@ def test_bf16_breaks_it(params):
     assert np.abs(forward_logits(cfg, low, toks) - want).max() > 20 * TOL
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["xla", "paged-interpret"])
+@ROUTES
 def test_prefill_then_decode_through_both_caches_equals_the_reference(
-        params, paged):
+        params, paged, small_blocks):
     """A packed prefill as admission runs it (two prompts into slots 2
     and 0), then `decode_step` on the engine's own leaves, teacher-
     forced PAST THE RING'S WRAP: every logit against the reference's
@@ -286,18 +304,22 @@ def test_the_ring_reads_what_every_row_kept_reads(params):
     assert all(h % CFG.ring_rows == r for r, h in enumerate(held))
 
 
+@ROUTES
 @pytest.mark.parametrize("chunks", [
     [(0, 16), (16, 32), (32, LONG)], [(0, 24), (24, LONG)],
     [(0, 8), (8, 16), (16, 24), (24, 32), (32, LONG)]])
-def test_a_prompt_admitted_in_chunks_equals_one_program(params, chunks):
+def test_a_prompt_admitted_in_chunks_equals_one_program(params, chunks, paged,
+                                                        small_blocks):
     """A prompt chunk by chunk, each behind the request's own blocks and
     the slot's ring, against the same prompt in one program: the last
     token's logits, the full layers' blocks, the ring at the prompt's
-    end, and the decoding that follows."""
+    end, and the decoding that follows.  `paged`: the full layers fold
+    in the fused kernel (interpreted), the table's rows gathered once a
+    layer, each chunk from its own `lo`."""
     seq = tokens(LONG + 6, 4)
-    whole = Cache(CFG, params)
+    whole = Cache(CFG, params, paged=paged)
     first = whole.pack([seq[:LONG]], [1], -(-LONG // BS) * BS)[0]
-    c = Cache(CFG, params)
+    c = Cache(CFG, params, paged=paged)
     for lo, hi in chunks:
         logits = c.chunk(seq, lo, hi, slot=1, N=-(-LONG // BS) * BS)
     assert np.abs(logits - first).max() < 1e-4
@@ -315,16 +337,20 @@ def test_a_prompt_admitted_in_chunks_equals_one_program(params, chunks):
         assert np.abs(out[1] - want[p]).max() < TOL, p
 
 
-def test_packed_prompts_under_the_segment_and_window_masks(params):
+@ROUTES
+def test_packed_prompts_under_the_segment_and_window_masks(params, paged,
+                                                           small_blocks):
     """Three prompts end to end in one row, two of them longer than the
     window: each one's logits are those of the prompt alone, and each
-    slot's ring is the one the prompt alone leaves."""
+    slot's ring is the one the prompt alone leaves.  `paged`: the
+    segments reach the fused kernel, whose walk skips the key blocks of
+    the prompts before a query block's own."""
     prompts = [tokens(19, 5), tokens(3, 6), tokens(LONG - 9, 7)]
-    c = Cache(CFG, params)
+    c = Cache(CFG, params, paged=paged)
     got = c.pack(prompts, [1, 2, 0], 72)
     for i, (p, slot) in enumerate(zip(prompts, [1, 2, 0])):
         assert np.abs(got[i] - ref_logits(CFG, params, p)[-1]).max() < TOL
-        alone = Cache(CFG, params)
+        alone = Cache(CFG, params, paged=paged)
         alone.pack([p], [slot], -(-len(p) // BS) * BS)
         held = np.asarray(mimo_v2._ring_index(CFG, jnp.asarray(len(p)))) >= 0
         for a, b in zip(c.cache[2:], alone.cache[2:]):
@@ -440,6 +466,25 @@ def test_engine_packed_and_chunked_admission_and_decode(engine, params):
     by_len = {r["tokens_in"]: r for r in s["request_ring"]}
     assert [by_len[n]["prefill_chunks"] for n in (5, 16, 40, 33, 12, 64)] \
         == [0, 0, 3, 3, 0, 4]
+
+
+def test_engine_admission_and_decode_on_the_kernel_route(params,
+                                                         small_blocks):
+    """The same through the route the chip takes, its kernels
+    interpreted: the paged decode kernels, and the fused prefill fold
+    in the packed and the chunked admission programs alike."""
+    eng = LlamaEngine(CFG, params, slots=3, chunk=2, block_size=BS,
+                      max_len=96, kv_blocks=30, prefill_chunk=16,
+                      decode_kernel="pallas", kernel_interpret=True)
+    try:
+        prompts = [tokens(n, 20 + n).tolist() for n in (5, 40, 12)]
+        futs = [eng.submit(p, 6) for p in prompts]
+        for p, f in zip(prompts, futs):
+            assert f.result(timeout=600) == greedy(params, p, 6)
+        by_len = {r["tokens_in"]: r for r in eng.stats()["request_ring"]}
+        assert [by_len[n]["prefill_chunks"] for n in (5, 40, 12)] == [0, 3, 0]
+    finally:
+        eng.shutdown()
 
 
 def test_engine_tick_fields(engine):
