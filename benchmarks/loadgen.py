@@ -29,6 +29,17 @@ Lengths: `{"fixed": n}`, `{"choices": [...], "weights": [...]}`, or
 `{"dist": "lognormal", "median": m, "sigma": s}`; with a `grid`
 (`min`, `max`, `step`) a drawn length is rounded UP to the grid and
 clipped to it, so the engine sees a closed set of shapes.
+
+Fields that differ by request: `"request_fields": {name: spec}`, each
+spec `{"fixed": v}` or `{"choices": [...], "weights": [...]}` with the
+values as the file writes them (a number, a string).  A request's
+fields go into its body beside `tokens` and `max_new_tokens`, which no
+field may be called.  Each field is drawn once a request from a
+generator of its own, seeded from `mix_seed` and the field's name: the
+lengths, gaps and prompts of a mix are what they are without the key,
+and a field added later moves no other.  Under `seed_reorders` a
+request's fields move with its lengths.  A mix without the key sends
+the bytes it always sent.
 """
 
 from __future__ import annotations
@@ -50,6 +61,7 @@ class Request:
     prompt: List[int]
     n_out: int
     client: int = -1        # closed loop: which caller sends it
+    fields: dict = dataclasses.field(default_factory=dict)  # `request_fields`
 
 
 @dataclasses.dataclass
@@ -67,6 +79,7 @@ class Record:
     replica: str = ""
     error: str = ""
     cut: bool = False            # closed loop: still in flight at the end
+    fields: dict = dataclasses.field(default_factory=dict)
 
 
 # ----------------------------------------------------------------------
@@ -77,13 +90,17 @@ def grid_values(grid: dict) -> List[int]:
                       int(grid["step"])))
 
 
+def _choice_p(spec: dict) -> np.ndarray:
+    w = np.asarray(spec.get("weights", [1] * len(spec["choices"])), float)
+    return w / w.sum()
+
+
 def draw_lengths(spec: dict, n: int, rng: np.random.Generator) -> np.ndarray:
     if "fixed" in spec:
         out = np.full(n, int(spec["fixed"]), np.int64)
     elif "choices" in spec:
-        w = np.asarray(spec.get("weights", [1] * len(spec["choices"])), float)
         out = rng.choice(np.asarray(spec["choices"], np.int64), size=n,
-                         p=w / w.sum())
+                         p=_choice_p(spec))
     elif spec.get("dist") == "lognormal":
         out = np.rint(np.exp(rng.normal(math.log(spec["median"]),
                                         spec["sigma"], size=n))).astype(np.int64)
@@ -108,6 +125,38 @@ def possible_lengths(spec: dict) -> List[int]:
         return grid_values(spec["grid"])
     raise ValueError("a drawn length needs a `grid`: continuous lengths "
                      "would compile inside the measured window")
+
+
+RESERVED_FIELDS = ("tokens", "max_new_tokens")
+
+
+def request_field_specs(mix: dict) -> dict:
+    """The mix's `request_fields`, refused where a field takes a name
+    the body already has or a spec of no known form."""
+    specs = mix.get("request_fields") or {}
+    for name, spec in specs.items():
+        if name in RESERVED_FIELDS:
+            raise ValueError(f"`request_fields` may not name {name!r}: the "
+                             "body carries it already")
+        if not isinstance(spec, dict) or not ("fixed" in spec
+                                              or spec.get("choices")):
+            raise ValueError(f"unknown field spec for {name!r}: {spec!r}")
+    return specs
+
+
+def draw_fields(mix: dict, n: int) -> List[dict]:
+    """One dict of fields a request, from the mix's own seed alone."""
+    specs = request_field_specs(mix)
+    cols = {}
+    for name, spec in specs.items():
+        if "fixed" in spec:
+            cols[name] = [spec["fixed"]] * n
+            continue
+        rng = np.random.default_rng(
+            [int(mix.get("mix_seed", 0)), 0xF1E1D, *name.encode()])
+        picks = rng.choice(len(spec["choices"]), size=n, p=_choice_p(spec))
+        cols[name] = [spec["choices"][i] for i in picks]
+    return [{name: col[i] for name, col in cols.items()} for i in range(n)]
 
 
 def _tokens(rng, n: int, vocab: int) -> List[int]:
@@ -148,16 +197,18 @@ def open_loop_schedule(mix: dict, seconds: float, seed: int,
     n = len(gaps)
     plens = draw_lengths(mix["prompt_len"], n, fixed)
     olens = draw_lengths(mix["output_len"], n, fixed)
+    fields = draw_fields(mix, n)
     rng = np.random.default_rng([int(seed), 0x5EED])
     gaps = np.asarray(gaps)
     if mix.get("seed_reorders"):
         gaps = rng.permutation(gaps)
         order = rng.permutation(n)
         plens, olens = plens[order], olens[order]
+        fields = [fields[i] for i in order]
     due = np.cumsum(gaps)
     prompts = _prompts(mix, plens, rng, vocab)
-    return [Request(i, float(due[i]), prompts[i], int(olens[i]))
-            for i in range(n)]
+    return [Request(i, float(due[i]), prompts[i], int(olens[i]),
+                    fields=fields[i]) for i in range(n)]
 
 
 def closed_loop_schedule(mix: dict, seed: int, vocab: int) -> List[List[Request]]:
@@ -171,10 +222,12 @@ def closed_loop_schedule(mix: dict, seed: int, vocab: int) -> List[List[Request]
     fixed = np.random.default_rng(int(mix.get("mix_seed", 0)))
     plens = draw_lengths(mix["prompt_len"], clients * per, fixed)
     olens = draw_lengths(mix["output_len"], clients * per, fixed)
+    fields = draw_fields(mix, clients * per)
     rng = np.random.default_rng([int(seed), 0x5EED])
     if mix.get("seed_reorders"):
         order = rng.permutation(clients * per)
         plens, olens = plens[order], olens[order]
+        fields = [fields[i] for i in order]
     step = int(mix.get("first_output_step", 0))
     prompts = _prompts(mix, plens, rng, vocab)
     out, k = [], 0
@@ -184,7 +237,8 @@ def closed_loop_schedule(mix: dict, seed: int, vocab: int) -> List[List[Request]
             n_out = int(olens[k])
             if step and j == 0:
                 n_out = step * (1 + c % max(1, n_out // step))
-            mine.append(Request(k, 0.0, prompts[k], n_out, client=c))
+            mine.append(Request(k, 0.0, prompts[k], n_out, client=c,
+                                fields=fields[k]))
             k += 1
         out.append(mine)
     return out
@@ -193,13 +247,19 @@ def closed_loop_schedule(mix: dict, seed: int, vocab: int) -> List[List[Request]
 # ----------------------------------------------------------------------
 # the client (one thread, asyncio + aiohttp)
 # ----------------------------------------------------------------------
+def body_of(req: Request) -> str:
+    """What is sent: with no fields, byte for byte what it always was."""
+    return json.dumps({"tokens": [req.prompt], "max_new_tokens": req.n_out,
+                       **req.fields})
+
+
 async def _post(session, url: str, req: Request, rec: Record,
                 t0: float) -> None:
     rec.prompt_len, rec.want = len(req.prompt), req.n_out
+    rec.fields = req.fields
     rec.sent_s = time.perf_counter() - t0
     try:
-        async with session.post(url, data=json.dumps(
-                {"tokens": [req.prompt], "max_new_tokens": req.n_out})) as r:
+        async with session.post(url, data=body_of(req)) as r:
             rec.status = r.status
             body = await r.read()
         rec.done_s = time.perf_counter() - t0
@@ -359,7 +419,10 @@ def summarize(recs: List[Record], seconds: float, miss_ms: float,
     failed, short or unanswered request counts `miss_ms` (worse than
     any answer).  `cut_at_end`: closed loop, requests still unanswered
     when the drain's ceiling was reached; they have no `done_s`, so no
-    credit, and a closed cell's `correct` holds their number to 0."""
+    credit, and a closed cell's `correct` holds their number to 0.
+    `by_field`: only where requests carried fields (`request_fields`),
+    per field and value the answered requests and their tokens: a
+    count, no metric."""
     cut = [r for r in recs if r.cut and closed]
     recs = [r for r in recs if not (r.cut and closed)]
     done_in = [r for r in recs if r.ok and r.done_s <= seconds]
@@ -367,7 +430,14 @@ def summarize(recs: List[Record], seconds: float, miss_ms: float,
     late = [(r.sent_s - r.due_s) * 1e3 for r in recs
             if not math.isnan(r.sent_s)]
     ended = sum(r.got for r in done_in) / seconds
-    return {
+    by_field: dict = {}
+    for r in recs:
+        for name, value in r.fields.items():
+            seen = by_field.setdefault(name, {}).setdefault(
+                str(value), {"completed": 0, "tokens": 0})
+            seen["completed"] += int(r.ok)
+            seen["tokens"] += r.got if r.ok else 0
+    out = {
         "cut_at_end": len(cut),
         "attempted": len(recs),
         "failed": sum(not r.ok for r in recs),
@@ -385,3 +455,6 @@ def summarize(recs: List[Record], seconds: float, miss_ms: float,
         "unanswered_at_window_end": sum(
             1 for r in recs if math.isnan(r.done_s) or r.done_s > seconds),
     }
+    if by_field:
+        out["by_field"] = by_field
+    return out

@@ -9,6 +9,8 @@ import importlib.util
 import json
 import os
 
+from benchmarks import loadgen
+
 BENCH = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(BENCH)
 
@@ -39,7 +41,11 @@ def config(name: str) -> dict:
 
 
 def traffic(name: str) -> dict:
-    return load_json(os.path.join(BENCH, "traffic", name + ".json"))
+    """The mix as its file has it; `request_fields` that name what the
+    body carries already are refused here, where the file is read."""
+    mix = load_json(os.path.join(BENCH, "traffic", name + ".json"))
+    loadgen.request_field_specs(mix)
+    return mix
 
 
 def peaks(device_kind: str) -> dict:

@@ -16,13 +16,9 @@ def _ctx(*prefill_scopes, plane="serve"):
                          else {} for s in prefill_scopes]}
 
 
-def test_it_declares_what_a_manifest_entry_would_say():
-    """The reader is NOT listed yet: `test_bench_window_full.py` holds
-    the cell's per-layer entries as the manifest's LAST six and their
-    count at 22, and a PR that claims a gain may edit no file the
-    benchmark has.  The `benchmark` PR that lists it appends exactly
-    this entry; until then the reader is read by hand off a `--detail`
-    file's context."""
+def check_the_manifest_finds_every_new_file():
+    """The entry as PR 52 wrote it down and PR 54 appended it, held by
+    name: listed once, for the one cell whose plane keeps the scope."""
     assert (READER.LAYER, READER.UNIT, READER.SOURCE, READER.MOVES) == (
         "models", "ms", "device_trace", "serve_tokens_per_s")
     want = {"name": NAME, "unit": READER.UNIT, "better": "lower",
@@ -30,11 +26,17 @@ def test_it_declares_what_a_manifest_entry_would_say():
             "moves": READER.MOVES, "workloads": ["mimo25_mixed_closed_8k"]}
     listed = [p for p in manifest.manifest()["per_layer"]
               if p["name"] == NAME]
-    assert listed in ([], [want])
+    assert listed == [want]
+    assert NAME in [p["name"] for p in manifest.metrics_for(
+        "mimo25_mixed_closed_8k", "per_layer")]
     # the scope it reads is one the cell's plane keeps
     from benchmarks.planes import serve_window_full
 
     assert "moe_routed" in serve_window_full.SCOPES
+
+
+def test_it_declares_what_a_manifest_entry_would_say():
+    check_the_manifest_finds_every_new_file()
 
 
 @pytest.mark.parametrize("ctx,want", [

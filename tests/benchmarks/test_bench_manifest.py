@@ -7,6 +7,7 @@ import math
 import os
 import re
 import shutil
+import sys
 import threading
 
 import numpy as np
@@ -19,6 +20,17 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 M = manifest.manifest()
 CELLS = [c["name"] for c in M["workloads"]]
+# the sixteen per-layer readers every closed cell reports: a module that
+# holds a closed cell's entries holds at least these, as a set
+CLOSED_SHARED = {
+    "serve_plane_overhead_p50_ms", "engine_compiles_in_window",
+    "engine_batch_occupancy", "engine_tick_host_ms",
+    "request_p95_ms.saturated", "device_idle_share.serve", "decode_step_ms",
+    "prefill_device_share", "engine_inter_token_p50_ms",
+    "engine_prefill_rows_per_program", "engine_tick_host_busy_ms",
+    "engine_device_wait_share", "engine_starved_gap_share",
+    "engine_live_row_share", "engine_prefill_padding_share",
+    "engine_stall_ticks"}
 
 
 def test_top_level_keys_and_sizes():
@@ -94,45 +106,114 @@ def _copy_of_bench(tmp_path, *subs):
     return bench
 
 
-def test_a_list_can_grow(tmp_path, monkeypatch):
-    """What the next PR that brings a cell does to the manifest: one
-    more configuration, cell and per-layer entry at the END of their
-    lists, the cell's name appended to the `workloads` of the shared
-    metrics it reports.  No test may hold an entry by its place: the
-    checks that hold what earlier PRs added pass on the grown copy."""
-    import test_bench_hybrid as hybrid
+def manifest_checks():
+    """Every test module of this directory that holds manifest entries
+    gives its check the callable form
+    `check_the_manifest_finds_every_new_file()`; they are found by
+    walking the directory, so a PR that adds a module adds no name
+    here."""
+    import importlib
 
+    here = os.path.dirname(os.path.abspath(__file__))
+    found = {}
+    for file in sorted(os.listdir(here)):
+        if file.startswith("test_bench_") and file.endswith(".py"):
+            mod = importlib.import_module(file[:-3])
+            check = getattr(mod, "check_the_manifest_finds_every_new_file",
+                            None)
+            if check is not None:
+                found[file[:-3]] = check
+    return found
+
+
+def grow(tmp_path, monkeypatch):
+    """A copy of the manifest and of the files it names, grown as the
+    next PR that brings a closed cell grows them, and `manifest` turned
+    to the copy.  Nothing that is there is edited: one configuration,
+    one cell with a traffic file and a `closed_sizes` file of its own and
+    one per-layer entry of its own at the END of their lists, one more
+    per-layer entry of a cell that is there (PR 52's case), and the
+    cell's name appended to EVERY list that all the closed cells share."""
     bench = _copy_of_bench(tmp_path, "traffic", "configs", "layer_metrics",
                            "reference")
+    sizes = tmp_path / "closed_sizes"
+    shutil.copytree(CLOSED_DIR, sizes)
     grown = manifest.manifest()
+    closed = {c["name"] for c in grown["workloads"]
+              if manifest.traffic(c["traffic"])["kind"] == "closed_loop"}
     last = grown["configs"][-1]
     cfg = manifest.load_json(os.path.join(manifest.REPO, last["file"]))
     (bench / "configs" / "one-more.json").write_text(
         json.dumps({**cfg, "name": "one-more"}))
     grown["configs"].append({**last, "name": "one-more",
                              "file": "benchmarks/configs/one-more.json"})
+    mix = {**manifest.traffic("batch_closed"),
+           "clients": 2 * cfg["engine"]["slots"],
+           "request_fields": {"steps": {"choices": [2, 4]}}}
+    (bench / "traffic" / "one_more_closed.json").write_text(json.dumps(mix))
+    (sizes / "one_more_closed.json").write_text(json.dumps({
+        "tick": 0.2, "slots": cfg["engine"]["slots"],
+        "callers": mix["clients"], "answer": mix["output_len"]["fixed"],
+        "first_step": mix["first_output_step"], "cell": "one_more_cell",
+        "measured_at": "nowhere: test_a_list_can_grow"}))
     grown["workloads"].append({
         "name": "one_more_cell", "config": "one-more",
-        "traffic": "batch_closed", "chips": 1, "why": "a list can grow"})
-    for kind, name in (("end_to_end", "serve_tokens_per_s"),
-                       ("per_layer", "decode_step_ms")):
-        shared = next(e for e in grown[kind] if e["name"] == name)
-        shared["workloads"].append("one_more_cell")
-    (bench / "layer_metrics" / "one_more_metric.py").write_text(
-        'LAYER, UNIT, SOURCE, MOVES = "engine", "ms", "program_counter", '
-        '"serve_tokens_per_s"\n\ndef read(ctx):\n    return None\n')
-    grown["per_layer"].append({
-        "name": "one_more_metric", "unit": "ms", "better": "lower",
-        "source": "program_counter", "layer": "engine",
-        "moves": "serve_tokens_per_s", "workloads": ["one_more_cell"]})
+        "traffic": "one_more_closed", "chips": 1, "why": "a list can grow"})
+    shared = [e for e in grown["end_to_end"] + grown["per_layer"]
+              if closed <= set(e.get("workloads", ()))]
+    for e in shared:
+        e["workloads"].append("one_more_cell")
+    reader = ('LAYER, UNIT, SOURCE, MOVES = "engine", "ms", "program_counter", '
+              '"serve_tokens_per_s"\n\ndef read(ctx):\n    return None\n')
+    earlier = grown["workloads"][-2]["name"]
+    for name, cell in (("one_more_metric", "one_more_cell"),
+                       ("one_more_of_an_earlier_cell", earlier)):
+        (bench / "layer_metrics" / (name + ".py")).write_text(reader)
+        grown["per_layer"].append({
+            "name": name, "unit": "ms", "better": "lower",
+            "source": "program_counter", "layer": "engine",
+            "moves": "serve_tokens_per_s", "workloads": [cell]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(grown))
     monkeypatch.setattr(manifest, "REPO", str(tmp_path))
     monkeypatch.setattr(manifest, "BENCH", str(bench))
+    monkeypatch.setattr(sys.modules[__name__], "CLOSED_DIR", str(sizes))
+    return grown, [e["name"] for e in shared], earlier
+
+
+def test_a_list_can_grow(tmp_path, monkeypatch):
+    """What the next PR that brings a cell does to the manifest (`grow`).
+    No test may hold an entry by its place: EVERY module's check of what
+    an earlier PR added passes on the grown copy.  (The check of
+    `test_bench_window_full.py` as PR 51 wrote it, `workloads[-1]` and
+    `per_layer[-6:]`, fails there: CHANGES.md, PR 54, has the run.)"""
+    before = manifest.manifest()
+    checks = manifest_checks()   # imported while `manifest` is the tree's
+    grown, shared, earlier = grow(tmp_path, monkeypatch)
     assert manifest.manifest()["workloads"][-1]["name"] == "one_more_cell"
-    assert [p["name"] for p in manifest.metrics_for(
-        "one_more_cell", "per_layer")] == ["decode_step_ms", "one_more_metric"]
-    hybrid.check_the_manifest_finds_every_new_file()
+    # the sixteen per-layer lists of a closed cell and its end-to-end one
+    assert {"serve_tokens_per_s", *CLOSED_SHARED} <= set(shared)
+    mine = [p["name"] for p in manifest.metrics_for("one_more_cell",
+                                                    "per_layer")]
+    assert mine == shared[1:] + ["one_more_metric"]
+    was = [p["name"] for p in before["per_layer"]
+           if earlier in p.get("workloads", ())]
+    assert [p["name"] for p in manifest.metrics_for(earlier, "per_layer")] \
+        == was + ["one_more_of_an_earlier_cell"]
+    assert {"test_bench_hybrid", "test_bench_sparse_latent",
+            "test_bench_window_full"} <= set(checks)
+    for module, check in checks.items():
+        try:
+            check()
+        except AssertionError as e:
+            raise AssertionError(f"{module} holds an entry by its place: "
+                                 f"{e}") from e
     check_every_cell_reports_enough_and_uses_a_known_config(grown)
+    check_the_mixes_in_the_table_are_the_closed_mixes()
+    assert "one_more_closed" in closed_sizes()
+    # the grown cell's requests carry the field its traffic file names
+    plan = loadgen.closed_loop_schedule(manifest.traffic("one_more_closed"),
+                                        3, 1000)
+    assert {r.fields["steps"] for p in plan for r in p} == {2, 4}
 
 
 def test_each_layer_metric_moves_a_metric_its_cells_report():
@@ -268,23 +349,34 @@ def test_latency_counts_from_the_due_instant_and_misses_count():
 
 
 # ---------------------------------------- a closed loop's rate (PR 49)
-# the five closed mixes' sizes, each with its cell's tick in the window
-# (PERF.md section 2): what one harvest is of a 30 s count
-CLOSED_SIZES = {
-    "batch_closed": dict(tick=0.13, slots=64, callers=128, answer=128,
-                         first_step=8),
-    "batch_closed_1k": dict(tick=0.17, slots=64, callers=96, answer=256,
-                            first_step=16),
-    "batch_closed_1k_a128": dict(tick=0.17, slots=32, callers=48, answer=128,
-                                 first_step=8),
-    "batch_closed_512_a128": dict(tick=0.35, slots=128, callers=192,
-                                  answer=64, first_step=8),
-    "docqa_closed_16k_a128": dict(tick=0.41, slots=128, callers=192,
-                                  answer=256, first_step=8),
-}
+# the closed mixes' sizes, a file a mix: `closed_sizes/<traffic>.json`,
+# each with its cell and its cell's tick in the window (PERF.md section
+# 2): what one harvest is of a 30 s count.  A PR that adds a closed mix
+# adds its file; the set check and the three checks below take it as a
+# case, and nothing here is edited
+CLOSED_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "closed_sizes")
+SIZE_KEYS = ("tick", "slots", "callers", "answer", "first_step")
+CHUNK = 8    # tokens a live row gets a tick, in every closed cell
 
 
-def _ticking_engine(tick, slots, callers, answer, first_step, chunk=8,
+def closed_sizes() -> dict:
+    """traffic file's name -> its row, `cell` and `measured_at` with it."""
+    rows = {}
+    for file in sorted(os.listdir(CLOSED_DIR)):
+        if file.endswith(".json"):
+            rows[file[:-5]] = manifest.load_json(os.path.join(CLOSED_DIR,
+                                                              file))
+    return rows
+
+
+def _size(mix: str) -> dict:
+    """The row as `_ticking_engine` takes it."""
+    row = manifest.load_json(os.path.join(CLOSED_DIR, mix + ".json"))
+    return {k: row[k] for k in SIZE_KEYS}
+
+
+def _ticking_engine(tick, slots, callers, answer, first_step, chunk=CHUNK,
                     admit=16, seconds=30.0, shift=0.0):
     """A closed loop's records against an engine that ticks: at every
     tick the rows that have their tokens are handed back TOGETHER (a
@@ -329,24 +421,35 @@ def _apart(values):
     return (max(values) - min(values)) / (sum(values) / len(values))
 
 
-def test_the_mixes_in_the_table_are_the_closed_mixes():
+def check_the_mixes_in_the_table_are_the_closed_mixes():
     man = manifest.manifest()
-    closed = {c["traffic"] for c in man["workloads"]
+    closed = {c["traffic"]: c for c in man["workloads"]
               if manifest.traffic(c["traffic"])["kind"] == "closed_loop"}
-    assert closed == set(CLOSED_SIZES)
-    for name, size in CLOSED_SIZES.items():
+    rows = closed_sizes()
+    assert set(closed) == set(rows)
+    for name, size in rows.items():
+        assert set(size) == {*SIZE_KEYS, "cell", "measured_at"}, name
         mix = manifest.traffic(name)
         assert mix["clients"] == size["callers"]
         assert mix["output_len"] == {"fixed": size["answer"]}
         assert mix["first_output_step"] == size["first_step"]
+        # the row's slots are its cell's engine's
+        assert closed[name]["name"] == size["cell"]
+        cfg = manifest.config(closed[name]["config"])
+        assert cfg["engine"]["slots"] == size["slots"], name
+        assert cfg["engine"]["chunk"] == CHUNK, name
 
 
-@pytest.mark.parametrize("mix", sorted(CLOSED_SIZES))
+def test_the_mixes_in_the_table_are_the_closed_mixes():
+    check_the_mixes_in_the_table_are_the_closed_mixes()
+
+
+@pytest.mark.parametrize("mix", sorted(closed_sizes()))
 def test_closed_rate_does_not_step_with_the_windows_end(mix):
     """ONE run's records read at window ends swept across one tick: the
     answers that ended inside the window step by a harvest, the tokens
     as they are produced do not."""
-    size = CLOSED_SIZES[mix]
+    size = _size(mix)
     recs = _ticking_engine(seconds=31.0, **size)
     reads = [_both(recs, 30.0 - size["tick"] * j / 10) for j in range(11)]
     assert _apart([new for new, _ in reads]) < 0.002
@@ -356,23 +459,29 @@ def test_closed_rate_does_not_step_with_the_windows_end(mix):
         assert _apart([old for _, old in reads]) > 0.01
 
 
-@pytest.mark.parametrize("mix", sorted(CLOSED_SIZES))
+@pytest.mark.parametrize("mix", sorted(closed_sizes()))
 def test_closed_rate_does_not_step_with_a_shift_of_the_run(mix):
     """The whole run later by 0-1 tick (the engine's phase against the
     client's clock): the same."""
-    size = CLOSED_SIZES[mix]
+    size = _size(mix)
     reads = [_both(_ticking_engine(shift=size["tick"] * j / 10, **size), 30.0)
              for j in range(10)]
     new, old = _apart([n for n, _ in reads]), _apart([o for _, o in reads])
     # the callers' first requests, whose lives hold the shift, still run
-    # at S/5 where a request lives 4-14 s: 0.33-0.35% there, the old
-    # reading's 0.46-1.4%; nothing where a life is short
-    assert new < (0.004 if size["tick"] > 0.3 else 0.0005) and new < old
+    # at the instant the reading starts (`CLOSED_READ_FROM` of the
+    # window, 6 s of 30) where a tick is long or a request's life in the
+    # engine (`answer / CHUNK` ticks) passes that instant: 0.24-0.35%
+    # there (the old reading 0.46-1.4%); under 0.03% where both are short.
+    # `tick > 0.3` alone stood in for this until a row came with a tick
+    # of 0.25 and a life of 16 s (`mixed_closed_8k_a512`: 0.236%)
+    life = size["answer"] / CHUNK * size["tick"]
+    still_run = size["tick"] > 0.3 or life > loadgen.CLOSED_READ_FROM * 30.0
+    assert new < (0.004 if still_run else 0.0005) and new < old
 
 
-@pytest.mark.parametrize("mix", sorted(CLOSED_SIZES))
+@pytest.mark.parametrize("mix", sorted(closed_sizes()))
 def test_closed_rate_reads_a_two_percent_faster_engine_as_two_percent(mix):
-    size = CLOSED_SIZES[mix]
+    size = _size(mix)
     base, _ = _both(_ticking_engine(**size), 30.0)
     fast, _ = _both(_ticking_engine(
         **{**size, "tick": size["tick"] / 1.02}), 30.0)
