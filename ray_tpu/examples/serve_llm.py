@@ -6,7 +6,10 @@ continuous-batching engine (`serve.llm_engine.LlamaEngine`: a resident
 decode batch over a paged KV cache that requests join mid-flight).
 
 Token-id interface (no tokenizer dependency in-image): POST
-`{"tokens": [[1,2,3,...]], "max_new_tokens": 16}` -> generated ids.
+`{"tokens": [[1,2,3,...]], "max_new_tokens": 16}` -> generated ids.  A
+model that generates by diffusion over blocks (`model_size="sdar_tiny"`)
+also takes `"denoising_steps"` and `"confidence_threshold"` in the body,
+a request's own, and answers `"forwards"` beside the tokens.
 
     from ray_tpu.examples.serve_llm import run
     handle = run(model_size="tiny")          # or "llama2_7b"/"llama3_8b"
@@ -26,7 +29,7 @@ from typing import List, Optional
 
 from ray_tpu import serve
 
-MODEL_SIZES = ("tiny", "llama1b4", "llama2_7b", "llama3_8b")
+MODEL_SIZES = ("tiny", "llama1b4", "llama2_7b", "llama3_8b", "sdar_tiny")
 
 
 def _model_config(model_size: str):
@@ -36,6 +39,10 @@ def _model_config(model_size: str):
 
     if model_size not in MODEL_SIZES:
         raise ValueError(f"model_size must be one of {MODEL_SIZES}")
+    if model_size == "sdar_tiny":
+        from ray_tpu.models import sdar
+
+        return sdar.SdarMoeConfig.tiny()
     return {
         "tiny": llama.LlamaConfig.tiny,
         # the per-chip serving unit for a 16 GB v5e-1 (same 1.4B
@@ -58,6 +65,10 @@ def _build_model(model_size: str, seed: int):
     from ray_tpu.models import llama
 
     cfg = _model_config(model_size)
+    if model_size == "sdar_tiny":
+        from ray_tpu.models import sdar
+
+        return cfg, sdar.init_params(cfg, jax.random.PRNGKey(seed), std=0.2)
     params = llama.init_params(cfg, jax.random.PRNGKey(seed))
     if model_size != "tiny":
         # serving decode is weight-read bound: bf16 weights halve
@@ -154,7 +165,11 @@ class ContinuousLlamaService:
         self.max_new_tokens = max_new_tokens
         self.max_new_tokens_limit = max_new_tokens
 
-    async def generate(self, token_lists, max_new_tokens=None):
+    async def generate(self, token_lists, max_new_tokens=None,
+                       denoising_steps=None, confidence_threshold=None):
+        """`denoising_steps`, `confidence_threshold`: a request's own
+        fields of a block-diffusion model, handed to `submit` (which
+        refuses them for a model that yields a token a step)."""
         import asyncio
 
         from ray_tpu.core.runtime import remaining_deadline_s
@@ -169,7 +184,10 @@ class ContinuousLlamaService:
         budget = remaining_deadline_s()
         futs = [
             asyncio.wrap_future(
-                self.engine.submit(list(t), n_new, timeout_s=budget)
+                self.engine.submit(
+                    list(t), n_new, timeout_s=budget,
+                    denoising_steps=denoising_steps,
+                    confidence_threshold=confidence_threshold)
             )
             for t in token_lists
         ]
@@ -178,7 +196,13 @@ class ContinuousLlamaService:
     async def __call__(self, request):
         body = request.json() if request.body() else {}
         n_new = int(body.get("max_new_tokens", self.max_new_tokens))
-        return {"tokens": await self.generate(body["tokens"], n_new)}
+        out = await self.generate(
+            body["tokens"], n_new, body.get("denoising_steps"),
+            body.get("confidence_threshold"))
+        # what the device counted for a block-diffusion answer
+        counted = ({"forwards": [o.forwards for o in out]}
+                   if out and hasattr(out[0], "forwards") else {})
+        return {"tokens": out, **counted}
 
     def stats(self):
         """Queue-depth/TTFT/occupancy signals, piggybacked by the serve

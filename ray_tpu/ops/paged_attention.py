@@ -166,7 +166,7 @@ def kv_pool_tail(kv_heads: int, head_dim: int) -> Tuple[int, ...]:
 # ----------------------------------------------------------------------
 @functools.lru_cache(maxsize=32)
 def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
-                  quantized, interpret, pools=2, flat=False, vd=0):
+                  quantized, interpret, pools=2, flat=False, vd=0, new_rows=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -251,12 +251,16 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
         # page is a plain `[BS, HD]` tile and a new row `[1, HD]`;
         # else `[L, NB, BS, KV, HD]`.  `vd` (flat, two pools): the V
         # pool's rows are that wide where K's are `HD`
+        # `new_rows` > 1 (flat): a slot writes that many CONSECUTIVE
+        # rows from `pos` on, all inside one page (`pos` and BS are
+        # multiples of it: a block of a block-diffusion step)
         page = (BS, HD) if flat else (BS, KV, HD)
-        row = (1, HD) if flat else (KV, HD)
+        row = (new_rows, HD) if flat else (KV, HD)
         pages = [page] * pools
         rows = [row] * pools
         if vd:
-            pages[1], rows[1] = (BS, vd), (1, vd)
+            pages[1], rows[1] = (BS, vd), (new_rows, vd)
+
         def kernel(layer_ref, tables_ref, pos_ref, *refs):
             ins, news, outs = (refs[:pools], refs[pools:2 * pools],
                                refs[2 * pools:])
@@ -267,10 +271,26 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
                 # a `[BS, HD]` page packs two bf16 rows a sublane, and
                 # Mosaic stores a single row only at an offset it can
                 # prove aligned: select the row into the whole page
-                hit = (jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0)
-                       == off) & (p_b < view)
+                if new_rows == 1:
+                    hit = (jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0)
+                           == off) & (p_b < view)
+                    for src, new, out in zip(ins, news, outs):
+                        out[...] = jnp.where(hit, new[...], src[...])
+                    return
+                at = jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0) - off
+                # page row `off + r` takes new row r: a one-hot product
+                # places the rows (exact: one term a sum), so no packed
+                # sublane is sliced
+                hit = (at >= 0) & (at < new_rows) & (p_b < view)
+                place = (at == jax.lax.broadcasted_iota(
+                    jnp.int32, (BS, new_rows), 1))
                 for src, new, out in zip(ins, news, outs):
-                    out[...] = jnp.where(hit, new[...], src[...])
+                    moved = jax.lax.dot_general(
+                        place.astype(new.dtype), new[...],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    out[...] = jnp.where(hit, moved.astype(out.dtype),
+                                         src[...])
                 return
             for src, out in zip(ins, outs):
                 out[...] = src[...]
@@ -310,7 +330,8 @@ def _build_append(L, NB, BS, KV, HD, B, W, pool_dtype, new_dtype,
 
 def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
                     k_scale=None, v_scale=None, k_new_scale=None,
-                    v_new_scale=None, interpret: bool = False):
+                    v_new_scale=None, interpret: bool = False,
+                    rows: int = 1):
     """Write each row's new KV into its tail pool block, in place.
 
     k_pool/v_pool [L, NB, BS, KV, hd]; k_new/v_new [B, KV, hd] (pool
@@ -321,21 +342,29 @@ def paged_kv_append(k_pool, v_pool, k_new, v_new, tables, pos, layer, *,
     written); layer: scalar int32 (traced OK).  With the int8 sidecar
     (`k_scale`/`v_scale` [L, NB, BS, KV] f32 + per-row `k_new_scale`/
     `v_new_scale` [B, KV]) returns (k_pool, v_pool, k_scale, v_scale),
-    else (k_pool, v_pool)."""
+    else (k_pool, v_pool).
+
+    `rows` > 1 (a folded pool only): each sequence writes that many
+    CONSECUTIVE rows, `k_new` / `v_new` `[B, rows, KV * hd]`, at `pos ..
+    pos + rows - 1`; `pos` and the block size are multiples of `rows`,
+    so the rows lie in one page (a block-diffusion step's block, which
+    every forward of the block writes again)."""
     B, W = tables.shape
     quantized = k_scale is not None
     if k_pool.ndim == 4:  # folded rows: the flat form, both pools
         L, NB, BS, HD = k_pool.shape
         VD = v_pool.shape[-1]
         assert not quantized, "a folded pool has no int8 scales wired"
+        assert BS % rows == 0, (BS, rows)
         fn = _build_append(L, NB, BS, 1, HD, B, W,
                            jnp.dtype(k_pool.dtype).name,
                            jnp.dtype(k_new.dtype).name, False,
                            bool(interpret), flat=True,
-                           vd=VD if VD != HD else 0)
+                           vd=VD if VD != HD else 0, new_rows=rows)
         return tuple(fn(jnp.asarray(layer, jnp.int32).reshape(1), tables,
-                        pos, k_pool, v_pool, k_new.reshape(B, 1, HD),
-                        v_new.reshape(B, 1, VD)))
+                        pos, k_pool, v_pool, k_new.reshape(B, rows, HD),
+                        v_new.reshape(B, rows, VD)))
+    assert rows == 1, "several rows a sequence: a folded pool's form"
     L, NB, BS, KV, HD = k_pool.shape
     fn = _build_append(L, NB, BS, KV, HD, B, W,
                        jnp.dtype(k_pool.dtype).name,

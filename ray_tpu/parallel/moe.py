@@ -210,6 +210,32 @@ def sigmoid_topk_route(h, w_router, bias, top_k: int, scale: float,
     return w, idx.astype(jnp.int32)
 
 
+def softmax_topk_route(h, w_router, top_k: int, renorm: bool = True):
+    """Qwen3-MoE's router (`sdar_moe` has it): probabilities `softmax(h
+    W_g)` over ALL experts in float32 (matmul precision `highest`, as
+    `sigmoid_topk_route`), the `top_k` largest chosen, and with `renorm`
+    (`norm_topk_prob`) the chosen weights divided by their sum; no
+    bias, no scale.  h [N, D] -> (weights [N, k] f32, experts [N, k]
+    int32)."""
+    probs = jax.nn.softmax(jnp.dot(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision="highest"), axis=-1)
+    w, idx = lax.top_k(probs, top_k)
+    if renorm:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, idx.astype(jnp.int32)
+
+
+# the routes `dropless_moe` knows by name: `(h, layer, top_k, scale,
+# route_eps) -> (weights [N, k] f32, experts [N, k] int32)`
+ROUTES = {
+    "sigmoid": lambda h, layer, top_k, scale, eps: sigmoid_topk_route(
+        h, layer["router"], layer["router_bias"], top_k, scale, eps),
+    # `route_eps` unused: a softmax's top-k sums to more than 1 / E
+    "softmax": lambda h, layer, top_k, scale, eps: softmax_topk_route(
+        h, layer["router"], top_k),
+}
+
 # megablox's row tile
 ROW_TILE = 128
 # mean rows a group from which a grouped product fetches a group ahead
@@ -331,12 +357,16 @@ def grouped_matmul(xs, w, group_sizes, *, kernel: bool = False,
 def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
                  route_eps: float, dtype,
                  kernel: bool = False, interpret: bool = False,
-                 stack_index=None, row_mask=None, held=None):
+                 stack_index=None, row_mask=None, held=None,
+                 route="sigmoid"):
     """Routed experts for inference, nothing dropped: h [N, D] ->
     (y [N, D], stats).  `layer`: `router` [D, E] float32, `router_bias`
     [E], `e_gate` / `e_up` [E, D, I], `e_down` [E, I, D] — or the three
     expert leaves as whole stacks `[L, E, ...]` with `stack_index`
-    naming the layer (see `grouped_matmul`).
+    naming the layer (see `grouped_matmul`).  `route`: a name of
+    `ROUTES` ("sigmoid": `sigmoid_topk_route`, what every caller had;
+    "softmax": `softmax_topk_route`, which reads no `router_bias`) or a
+    callable of their signature.
 
     The N * k (token, expert) pairs are sorted by expert, so each
     expert's rows are contiguous and the three SwiGLU products are
@@ -377,8 +407,8 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
     E = layer["router"].shape[-1]
     rows = 0 if held is None else slab_rows(N * top_k, held[1], E)
     with jax.named_scope("moe_router"):
-        w, idx = sigmoid_topk_route(h, layer["router"], layer["router_bias"],
-                                    top_k, scale, route_eps)
+        w, idx = (route if callable(route) else ROUTES[route])(
+            h, layer, top_k, scale, route_eps)
         if held is not None:  # the held experts renumbered from 0
             lo, E = held
             idx = jnp.where((idx >= lo) & (idx < lo + E), idx - lo, E)

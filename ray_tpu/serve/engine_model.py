@@ -84,14 +84,34 @@ state a sequence whatever its length, with no tables, no `blk_ids` and
 no gather width.  A model with per-slot leaves has no cached prefix to
 prefill behind: `suffix_prefill` and `kv_write` do not exist for it and
 the radix prefix cache is refused.
-`chunk_program` is THE decode chunk, of every cache: liveness, the
+TWO CHUNK PROGRAMS.  `chunk_program` is THE decode chunk of a model
+that yields ONE token a live row a step, of every cache: liveness, the
 greedy pick, the positions, row 0 and the aux rows, around ONE decode
-step the model hands it.  `packed_prefill_program` is THE packed
-prefill likewise: the flat signature, the greedy pick, the paged rows
-into their blocks, the admitted rows' state.  `kv_write_program` is
-`kv_write`'s flat signature and the admitted slot's state.
+step the model hands it.  `block_chunk_program` is the chunk of a model
+that generates by DIFFUSION OVER BLOCKS: `chunk` FORWARDS of a block of
+`B` positions a row, each live row its own state machine (a forward
+decides some of the block's positions, or, with none left undecided,
+COMMITS the block: `B` tokens out, `pos += B`), so a step yields 0 or
+`B` tokens a row and `toks` says per slot how many tokens the chunk
+committed, which, and the step each was decided at.  What the two
+share is shared code: the flat signature and the dense view
+(`_chunk_args`, `_chunk_cache`), liveness (`pos < stop` at every step;
+a dead row writes nothing and is routed to no expert) and the aux rows
+behind the tokens (`_chunk_rows`).  What a REQUEST is to the model sits
+on `_EngineModel` and is the one-token model's by default: the row's
+state beside `pos` and `stop` (`init_tok`: a last token; a
+`BlockRows` row), `rows_needed` (the row's `stop`), `first_pos`,
+`request_fields` (a request's own generation fields: none, refused),
+`pack_extras`, `harvested` (what a chunk's `toks` hold for a slot) and
+`device_counts` (the host's mirror of `pos` is a bound, and the live
+row-steps come back with the chunk).
+`packed_prefill_program` is THE packed prefill: the flat signature,
+what the admitted rows start from (`first`: the greedy pick and the
+prompt's length, or a block-diffusion row's first block), the paged
+rows into their blocks, the admitted rows' state.  `kv_write_program`
+is `kv_write`'s flat signature and the admitted slot's state.
 
-Six implementers: `LlamaEngineModel` (per-head K and V pools),
+Seven implementers: `LlamaEngineModel` (per-head K and V pools),
 `LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
 absorbed decode attention, dropless experts), `RetentionEngineModel`
 (`models/brumby.py`: every layer a power-retention layer, a per-slot
@@ -104,7 +124,10 @@ its suffixes packed) and `WindowFullEngineModel` (`models/mimo_v2.py`:
 BOTH kinds for ATTENTION: paged K and V of different widths for the
 full layers, a per-slot RING of window rows for the window layers; a
 third admission program, `chunk_prefill`, carries the ring from chunk
-to chunk of a long prompt).
+to chunk of a long prompt) and `BlockDiffusionEngineModel`
+(`models/sdar.py`: folded K and V pools, every layer a softmax-routed
+expert layer, `block_chunk_program`; a prefill yields no token and
+runs no head).
 `engine_model_for` picks by the config's type, builds the
 format from the user's `kv_dtype` and hands the implementer the
 resolved route: a user passes a model's config and the model picks its
@@ -119,7 +142,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.exceptions import PrefixCacheUnsupportedError
-from ray_tpu.models import brumby, deepseek_v3, dots3, lfm2, llama, mimo_v2
+from ray_tpu.models import (brumby, deepseek_v3, dots3, lfm2, llama,
+                            mimo_v2, sdar)
 from ray_tpu.ops import paged_attention as _pa
 from ray_tpu.ops import retention as _ret
 from ray_tpu.serve.kv_cache import BlockPool, CacheLeaf
@@ -240,9 +264,47 @@ class SlotState:
             for name, (tail, dtype) in leaves.items()]
 
 
+def _chunk_args(flat, paged: bool, gather: Optional[PagedKV]):
+    """A chunk program's flat arguments `(*cache, [tables,] tok, pos,
+    stop)` as `(pool, cache, tables, tok, pos, stop)`: `pool` the leaves
+    as they came, `cache` what the steps run on (`gather`: the dense
+    view of the paged leaves, the per-slot leaves behind it)."""
+    if paged:
+        *pool, tables, tok, pos, stop = flat
+    else:
+        (*pool, tok, pos, stop), tables = flat, None
+    cache = tuple(pool)
+    if gather is not None:
+        n = len(gather.leaves)
+        cache = (*gather.rows(pool[:n], tables), *pool[n:])
+    return pool, cache, tables, tok, pos, stop
+
+
+def _chunk_cache(gather: Optional[PagedKV], pool, tables, cache, span):
+    """The cache a chunk program hands back: `gather`'s view written
+    into the pool again (`span`: the rows the chunk wrote)."""
+    if gather is None:
+        return cache
+    n = len(gather.leaves)
+    return (*gather.write(pool[:n], tables, cache[:gather.n_rows],
+                          span=span),
+            *cache[gather.n_rows:])
+
+
+def _chunk_rows(rows: list, counters, slots) -> jax.Array:
+    """What a chunk's one device->host read holds: `rows` (each `[n,
+    slots]` int32) and behind them the model's aux `counters` (`[n]`,
+    or None), each as a row."""
+    if counters is not None:
+        rows.append(jnp.broadcast_to(
+            counters[:, None], counters.shape + slots).astype(jnp.int32))
+    return jnp.concatenate(rows, axis=0)
+
+
 def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
                   aux=None, paged: bool = True, last_step=None):
-    """THE decode chunk, `(params, *cache, tables, tok, pos, stop) ->
+    """THE decode chunk of a model that yields ONE token a live row a
+    step, `(params, *cache, tables, tok, pos, stop) ->
     (*cache, tok, pos, toks)`: `chunk` greedy steps in one `lax.scan`.
     `paged` False (a cache with no paged leaf): the same without
     `tables`, in the signature and handed to `step` as None.
@@ -263,16 +325,13 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
     a step of another kind (one that writes what the others only held:
     `RetentionEngineModel`) names it, and the chunk is `chunk - 1`
     scanned `step`s and then `last_step` once, as `gather` brackets the
-    scan with its view and its write.  None: ONE scan of `chunk`."""
+    scan with its view and its write.  None: ONE scan of `chunk`.
+
+    What it shares with `block_chunk_program`: the flat signature and
+    the view (`_chunk_args`, `_chunk_cache`), liveness (`pos < stop`
+    at every step) and the aux rows (`_chunk_rows`)."""
     def _fn(params, *flat):
-        if paged:
-            *pool, tables, tok, pos, stop = flat
-        else:
-            (*pool, tok, pos, stop), tables = flat, None
-        cache = tuple(pool)
-        if gather is not None:
-            n = len(gather.leaves)
-            cache = (*gather.rows(pool[:n], tables), *pool[n:])
+        pool, cache, tables, tok, pos, stop = _chunk_args(flat, paged, gather)
 
         def body(carry, _, step=step):
             tok, cache, pos = carry
@@ -295,17 +354,128 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
             toks, stats = jax.tree.map(
                 lambda xs, x: jnp.concatenate([xs, x[None]]),
                 (toks, stats), ended)
-        if gather is not None:
-            cache = (*gather.write(pool[:n], tables, cache[:gather.n_rows],
-                                   span=(pos_in, pos)),
-                     *cache[gather.n_rows:])
+        cache = _chunk_cache(gather, pool, tables, cache, (pos_in, pos))
         counters = None if aux is None else aux(stats)
-        rows = [tok_in[None], toks]
-        if counters is not None:
-            rows.append(jnp.broadcast_to(
-                counters[:, None], counters.shape + tok.shape
-            ).astype(jnp.int32))
-        return (*cache, tok, pos, jnp.concatenate(rows, axis=0))
+        return (*cache, tok, pos,
+                _chunk_rows([tok_in[None], toks], counters, tok.shape))
+
+    return _fn
+
+
+class BlockRows:
+    """A block-diffusion row's state beside `pos` and `stop`: ONE int32
+    row `[3 B + 3]` a slot, which the engine carries where a one-token
+    model's `tok` stands: the block's tokens `blk [B]`, which of them
+    are undecided `und [B]` (a flag of the state, never `blk ==
+    mask_id`: a prompt that holds the id is only a prompt), the step
+    each was decided at `dec [B]` (-1: given by the prompt, or not yet
+    decided), the block's step `s`, the request's steps `S` and its
+    confidence threshold (a float32's bits)."""
+
+    def __init__(self, block: int):
+        self.block, self.width = block, 3 * block + 3
+
+    def unpack(self, state):
+        B = self.block
+        return (state[:, :B], state[:, B:2 * B] != 0, state[:, 2 * B:3 * B],
+                state[:, 3 * B], state[:, 3 * B + 1],
+                jax.lax.bitcast_convert_type(state[:, 3 * B + 2],
+                                             jnp.float32))
+
+    def pack(self, blk, und, dec, s, steps, thr):
+        return jnp.concatenate([
+            blk, und.astype(jnp.int32), dec, s[:, None], steps[:, None],
+            jax.lax.bitcast_convert_type(thr.astype(jnp.float32),
+                                         jnp.int32)[:, None]], axis=1)
+
+
+def block_chunk_program(step, unmask, chunk: int, rows: BlockRows,
+                        mask_id: int, cap: int, *,
+                        gather: Optional[PagedKV] = None, aux=None,
+                        commit: bool = True):
+    """THE chunk of a model that generates by DIFFUSION OVER BLOCKS,
+    `(params, *cache, tables, state, pos, stop) -> (*cache, state, pos,
+    toks)`: `chunk` FORWARDS in one `lax.scan`, each live row (`pos <
+    stop`, as in `chunk_program`) its own state machine over `state`
+    (`BlockRows`), so rows that commit and rows that denoise share a
+    forward.
+
+    `step(params, tokens [slots, B], cache, tables, pos, live) ->
+    (logits [slots, B, vocab], cache, stats)` is the model's forward of
+    one block a row at positions `pos .. pos + B - 1`, which writes the
+    block's rows into the cache; its input is `where(und, mask_id,
+    blk)`.  A live row with NOTHING undecided was at its COMMIT forward:
+    the rows it wrote are the block's, its tokens are the row's output,
+    `pos += B`, the next block starts undecided at step 0.  Any other
+    live row takes `unmask(logits, blk, und, dec, s, steps, thr) ->
+    (blk, und, dec)`, the denoising choice, and `s += 1`.  A dead row
+    keeps its state.  `commit` False is the benchmark's control: a block
+    is output at the forward that decides its last position, so the rows
+    a forward with masks in its input wrote stay in the cache.
+
+    `toks` `[2 + 2 cap (+ aux_rows), slots]` int32, `cap` the tokens a
+    chunk commits for a row at most (the model's `advance`: a block
+    takes two forwards at least): row 0 HOW MANY tokens
+    the chunk committed for the slot, row 1 how many forwards it was
+    live in, rows `2 .. 2 + cap` the committed tokens in order, the next
+    `cap` rows the step of its block each was decided at (-1: a prompt's
+    token), then `aux(stats)`: `stats` = (rows that committed, rows that
+    denoised, the columns the live rows attended (`pos + B` each), *the
+    step's own) a forward.  The host learns what a row
+    produced from this read alone: it cannot count it, the steps a block
+    takes depend on the request and on the logits."""
+    B = rows.block
+
+    def _fn(params, *flat):
+        pool, cache, tables, state, pos, stop = _chunk_args(flat, True,
+                                                            gather)
+        slots = pos.shape[0]
+        at = jnp.arange(cap, dtype=jnp.int32)[None, :] // B     # [1, cap]
+
+        def body(carry, _):
+            state, cache, pos, out, n_tok, n_fwd = carry
+            live = pos < stop
+            blk, und, dec, s, steps, thr = rows.unpack(state)
+            logits, cache, stats = step(
+                params, jnp.where(und, mask_id, blk), cache, tables, pos,
+                live)
+            open_ = jnp.any(und, axis=-1)
+            denoise = live & open_
+            nblk, nund, ndec = unmask(logits, blk, und, dec, s, steps, thr)
+            if commit:
+                done = live & ~open_
+            else:  # the control: out with its last decision
+                done = denoise & ~jnp.any(nund, axis=-1)
+                blk, dec = (jnp.where(done[:, None], a, b)
+                            for a, b in ((nblk, blk), (ndec, dec)))
+            # the committed block behind what the chunk committed before
+            here = done[:, None] & (at == (n_tok // B)[:, None])
+            out = tuple(jnp.where(here, jnp.tile(x, (1, cap // B)), o)
+                        for x, o in zip((blk, dec), out))
+            d, c = denoise[:, None], done[:, None]
+            state = rows.pack(
+                jnp.where(c, 0, jnp.where(d, nblk, blk)),
+                jnp.where(c, True, jnp.where(d, nund, und)),
+                jnp.where(c, -1, jnp.where(d, ndec, dec)),
+                jnp.where(done, 0, jnp.where(denoise, s + 1, s)),
+                steps, thr)
+            return ((state, cache, jnp.where(done, pos + B, pos), out,
+                     n_tok + B * done, n_fwd + live),
+                    (jnp.sum(done), jnp.sum(denoise),
+                     jnp.sum(jnp.where(live, pos + B, 0)), *stats))
+
+        zero = jnp.zeros((slots,), jnp.int32)
+        pos_in = pos
+        (state, cache, pos, out, n_tok, n_fwd), stats = jax.lax.scan(
+            body, (state, cache, pos,
+                   (jnp.zeros((slots, cap), jnp.int32),) * 2, zero, zero),
+            None, length=chunk)
+        # the open block's rows were written too
+        cache = _chunk_cache(gather, pool, tables, cache, (pos_in, pos + B))
+        counters = None if aux is None else aux(stats)
+        return (*cache, state, pos, _chunk_rows(
+            [n_tok[None], n_fwd[None], out[0].T, out[1].T], counters,
+            pos.shape))
 
     return _fn
 
@@ -338,8 +508,15 @@ def _admitted(pos, tok, stop, slots, pos0, tok0, stop0) -> tuple:
             stop.at[slots].set(stop0, mode="drop"))
 
 
+def _greedy_first(logits, _tokens, _last, pos0):
+    """What a one-token model's admission sets: the prompt's length and
+    the token picked from its last row's logits."""
+    return pos0, jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+
+
 def packed_prefill_program(kv: Optional[PagedKV], n_state: int, forward,
-                           fit=lambda *rows: rows, segmented: bool = True):
+                           fit=lambda *rows: rows, segmented: bool = True,
+                           first=_greedy_first, extras: int = 0):
     """THE packed prefill, `(params, *cache, tokens, seg, posn, blk_ids,
     last, slots, pos0, stop0, pos, tok, stop) -> (*cache, pos, tok,
     stop)`, of a cache of `kv`'s paged leaves (None: none, and no
@@ -350,19 +527,27 @@ def packed_prefill_program(kv: Optional[PagedKV], n_state: int, forward,
     `fit(*rows)` turns into `[L, 1, N, ...]` as the pool holds them
     (where they are not that already) and which then reshape straight
     into `blk_ids`' blocks; `state` the per-slot leaves, each prompt's
-    end state left in its slot by the forward itself."""
+    end state left in its slot by the forward itself.
+
+    `first(logits, tokens, last, pos0, *extra) -> (pos0, tok0)`: what
+    the admitted rows' `pos` and `tok` are set to (the greedy pick and
+    the prompt's length; a model whose prefill yields no token makes
+    its rows' first state here), `extra` the `extras` per-prompt arrays
+    `[K]` the host puts behind `stop0` (`_EngineModel.pack_extras`)."""
     n_paged = len(kv.leaves) if kv is not None else 0
 
     def _fn(params, *flat):
         paged = flat[:n_paged]
         state = flat[n_paged:n_paged + n_state]
-        (tokens, seg, posn, *blk_ids, last, slots, pos0, stop0,
-         pos, tok, stop) = flat[n_paged + n_state:]
+        *host, pos, tok, stop = flat[n_paged + n_state:]
+        extra = host[len(host) - extras:]
+        (tokens, seg, posn, *blk_ids, last, slots, pos0,
+         stop0) = host[:len(host) - extras]
         packed = (llama.Packed(last, seg, posn) if segmented
                   else llama.Packed(last))
         logits, rows, state = forward(params, tuple(state), tokens[None],
                                       packed, slots)
-        tok0 = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+        pos0, tok0 = first(logits, tokens, last, pos0, *extra)
         if kv is not None:
             paged = kv.write(paged, blk_ids[0], fit(*rows))
         return (*paged, *state,
@@ -394,12 +579,24 @@ class _EngineModel:
     state_write_deferred = False
     packs_suffixes = False
     state_carries_chunks = False
+    # what a row yields is COUNTED ON THE DEVICE (a block-diffusion
+    # model): the host's mirror of `pos` is then an upper bound, and the
+    # tick's live row-steps come back with the chunk (`tick_fields`)
+    device_counts = False
+    # a prompt's cached prefix can be prefilled behind (`suffix_prefill`
+    # and `kv_write`, or `packs_suffixes`); per-slot leaves never can
+    shares_prefix = True
 
     def __init__(self, cfg, kv: Optional[PagedKV], *, chunk: int,
                  paged: bool, interpret: bool,
                  state: Optional[SlotState] = None):
         self.cfg, self.kv, self.state, self.chunk = cfg, kv, state, chunk
         self._paged, self._interpret = paged, interpret
+        # a chunk's rows of `toks` before the aux rows; the positions a
+        # live row moves in a chunk at most (`advance`) and may touch
+        # past its `pos` (`reach`)
+        self.token_rows = 1 + chunk
+        self.advance = self.reach = chunk
         self.n_layers = cfg.n_layers
         self.cache_leaves = ((kv.leaves if kv is not None else [])
                              + (state.leaves if state is not None else []))
@@ -409,6 +606,45 @@ class _EngineModel:
 
     def tick_fields(self, aux) -> Dict[str, object]:
         return {}
+
+    # -- what a REQUEST is to the model (one token a step, here) --------
+    def init_tok(self, slots: int) -> jax.Array:
+        """The slots' device state beside `pos` and `stop`: a row's last
+        token."""
+        return jnp.zeros((slots,), jnp.int32)
+
+    def rows_needed(self, T: int, n_new: int) -> int:
+        """The cache rows a prompt of `T` tokens and `n_new` new ones
+        need, which is the row's `stop`: the highest index a WANTED
+        token's step touches is `T + n_new - 2`."""
+        return T + n_new - 1
+
+    def first_pos(self, T: int) -> int:
+        """A row's `pos` at admission."""
+        return T
+
+    def request_fields(self, denoising_steps=None,
+                       confidence_threshold=None) -> Dict[str, object]:
+        """A request's own generation fields, validated (`submit`).  A
+        model that yields one greedy token a step has none."""
+        if denoising_steps is not None or confidence_threshold is not None:
+            raise ValueError(
+                f"{type(self.cfg).__name__} generates one token a step: it "
+                "takes no denoising_steps / confidence_threshold")
+        return {}
+
+    def pack_extras(self, K: int, reqs: Sequence[Dict]) -> tuple:
+        """A packed prefill's per-prompt arrays `[K]` behind `stop0`,
+        from the packed requests' `fields`; none here."""
+        return ()
+
+    def harvested(self, toks_host, slot: int, first: bool) -> tuple:
+        """`(tokens, more)` a harvested chunk holds for `slot`: here a
+        token a step, from row 0 (the prefill's token) in the request's
+        FIRST chunk and from row 1 after; the engine cuts them to what
+        the request still wants.  `more`: None, or what else the device
+        counted for the row."""
+        return toks_host[0 if first else 1:self.token_rows, slot], None
 
     def context_fields(self, contexts: Sequence[int]) -> Dict[str, object]:
         """The model's own tick fields of the contexts (tokens a row
@@ -932,9 +1168,185 @@ class WindowFullEngineModel(_ExpertCounters, _EngineModel):
     suffix_prefill = kv_write = _EngineModel._no_prefix
 
 
+class BlockDiffusionEngineModel(_ExpertCounters, _EngineModel):
+    """`models/sdar.py` behind the seam: a model that generates by
+    DIFFUSION OVER BLOCKS of `B = cfg.block_length` positions, every
+    layer an expert layer (softmax top-k).  Folded K and V pools `[L,
+    num_blocks, block_size, KV * hd]` (`kv`), `block_size % B == 0`, so
+    a block never straddles two pool blocks.
+
+    A step yields 0 or `B` tokens a row, and how many steps a block
+    takes depends on the request (`denoising_steps`, `confidence_
+    threshold`: `request_fields`) and on the logits, so everything the
+    engine counted on the host for a one-token model comes FROM THE
+    DEVICE here (`device_counts`): `decode_chunk` is `block_chunk_
+    program`, whose `toks` say per slot how many tokens the chunk
+    committed, which, the step each was decided at and the forwards the
+    row was live in (`harvested`); `tick_fields` hands the tick ring its
+    `row_steps_live` (ROW-FORWARDS) beside `tokens_committed`,
+    `commit_row_steps`, `denoise_row_steps` and the experts' counters.
+    A row's device state beside `pos` and `stop` is a `BlockRows` row
+    (`init_tok`).  Admission (`prefill_packed`) prefills a prompt's
+    whole blocks under the block-causal mask with NO head and no token:
+    its `first` makes the row's first block from the prompt's `T mod B`
+    tail tokens, `pos` the tail's start.  `stop` is `rows_needed`:
+    `ceil((T + n) / B) B`.
+
+    `suffix_prefill` and `kv_write` do not exist: a prefix would be
+    valid at multiples of `B`, which no test holds yet, so the prefix
+    cache is refused.  `paged`: the paged kernels (`B` rows appended a
+    slot, `B x H` query heads of one row) + Pallas grouped products
+    (TPU); else the dense view + `lax.ragged_dot` (anywhere).
+    `commit` False is the benchmark's control (`block_chunk_program`)."""
+
+    aux_rows = 5
+    device_counts = True
+    shares_prefix = False
+    commit = True
+
+    def __init__(self, cfg, kv: PagedKV, **route):
+        super().__init__(cfg, kv, **route)
+        B = cfg.block_length
+        self.rows = BlockRows(B)
+        self._pairs = cfg.n_layers * cfg.n_experts
+        # tokens a chunk commits for a row at most (two forwards a block
+        # at least; the control commits with the deciding forward)
+        self.advance = (-(-self.chunk // 2) if self.commit
+                        else self.chunk) * B
+        self.reach = self.advance + B       # the open block's rows
+        self.token_rows = 2 + 2 * self.advance
+
+    # -- what a request is to this model --------------------------------
+    def init_tok(self, slots: int) -> jax.Array:
+        return jnp.zeros((slots, self.rows.width), jnp.int32)
+
+    def rows_needed(self, T: int, n_new: int) -> int:
+        B = self.rows.block
+        return -(-(T + n_new) // B) * B
+
+    def first_pos(self, T: int) -> int:
+        return T - T % self.rows.block
+
+    def request_fields(self, denoising_steps=None,
+                       confidence_threshold=None) -> Dict[str, object]:
+        B = self.rows.block
+        steps = (self.cfg.denoising_steps if denoising_steps is None
+                 else int(denoising_steps))
+        thr = (self.cfg.confidence_threshold if confidence_threshold is None
+               else float(confidence_threshold))
+        if not 1 <= steps <= B:
+            raise ValueError(f"denoising_steps={steps} not in [1, {B}]")
+        if not 0.0 <= thr:
+            raise ValueError(f"confidence_threshold={thr} is negative")
+        return {"denoising_steps": steps, "confidence_threshold": thr}
+
+    def pack_extras(self, K: int, reqs: Sequence[Dict]) -> tuple:
+        import numpy as np
+
+        steps, thr = np.ones(K, np.int32), np.zeros(K, np.float32)
+        for i, req in enumerate(reqs):
+            steps[i] = req["fields"]["denoising_steps"]
+            thr[i] = req["fields"]["confidence_threshold"]
+        return steps, thr
+
+    def harvested(self, toks_host, slot: int, first: bool) -> tuple:
+        n, cap = int(toks_host[0, slot]), self.advance
+        return toks_host[2:2 + n, slot], {
+            "decided_at": toks_host[2 + cap:2 + cap + n, slot],
+            "forwards": int(toks_host[1, slot])}
+
+    @staticmethod
+    def _aux(stats):
+        done, denoise, attended, touched, load = stats
+        return jnp.stack([jnp.sum(done), jnp.sum(denoise), jnp.sum(attended),
+                          jnp.sum(touched), jnp.max(load)])
+
+    def tick_fields(self, aux) -> Dict[str, object]:
+        """`aux` [5, slots] from a harvested chunk: its ROW-FORWARDS by
+        kind (a row's forward is what this model's `row_steps_live`
+        counts), the tokens they committed, the cached columns a
+        forward's live rows attended (each `pos + B`: a FORWARD's mean),
+        and the experts' counters as `_ExpertCounters` gives them (a
+        forward's mean too)."""
+        done, denoise = int(aux[0, 0]), int(aux[1, 0])
+        return {"row_steps_live": done + denoise,
+                "commit_row_steps": done, "denoise_row_steps": denoise,
+                "tokens_committed": done * self.rows.block,
+                "attended_tokens": float(aux[2, 0]) / self.chunk,
+                **super().tick_fields(aux[3:])}
+
+    # -- compiled-program bodies ---------------------------------------
+    def decode_chunk(self, W: int):
+        cfg, paged, kw = self.cfg, self._paged, self._kw()
+
+        def step(params, tokens, cache, tables, pos, live):
+            logits, cache, st = sdar.block_step(
+                cfg, params, tokens, cache, pos,
+                tables=tables if paged else None, live=live, **kw)
+            return logits, cache, (st["experts_touched"], st["load_max"])
+
+        return block_chunk_program(
+            step, sdar.unmask, self.chunk, self.rows, cfg.mask_id,
+            self.advance, gather=None if paged else self.kv, aux=self._aux,
+            commit=self.commit)
+
+    def prefill_packed(self, N: int):
+        B, rows = self.rows.block, self.rows
+
+        def forward(params, _state, tokens, packed, _slots):
+            # a tail's rows (`T mod B` tokens of a block that is not
+            # whole) are written too: no whole block sees them, and the
+            # block's first forward writes them again
+            _, kv = sdar.forward(self.cfg, params, tokens, packed=packed,
+                                 logits=False, **self._kw())
+            return None, kv, ()  # ks / vs [L, 1, N, KV * hd]
+
+        def first(_logits, tokens, last, T, steps, thr):
+            """Each prompt's first block: its `T mod B` tail tokens
+            decided (by the prompt: -1), the rest open, from the tail's
+            start."""
+            tail = T % B
+            j = jnp.arange(B, dtype=jnp.int32)[None, :]
+            und = j >= tail[:, None]
+            at = jnp.clip(last[:, None] - tail[:, None] + 1 + j, 0,
+                          tokens.shape[0] - 1)
+            blk = jnp.where(und, 0, tokens[at])
+            return T - tail, rows.pack(
+                blk, und, jnp.full_like(blk, -1), jnp.zeros_like(T), steps,
+                thr)
+
+        return packed_prefill_program(self.kv, 0, forward,
+                                      segmented=self.segmented, first=first,
+                                      extras=2)
+
+    def prefill(self, bucket: int):
+        def _pf(params, prompt):  # prompt [1, bucket], right-padded
+            logits, (ks, vs) = sdar.forward(self.cfg, params, prompt,
+                                            **self._kw())
+            return logits[0], ks, vs
+
+        return _pf
+
+    suffix_prefill = kv_write = _EngineModel._no_prefix
+
+
 def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
     """The implementer for a model's config, its cache in the format
     the user's `kv_dtype` names: the model picks its route."""
+    if isinstance(cfg, sdar.SdarMoeConfig):
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' is not wired for a block-diffusion cache: "
+                "its K and V are folded pools with no scales, and a "
+                "block's rows are written again at every forward")
+        if block_size % cfg.block_length:
+            raise ValueError(
+                f"block_size={block_size} must be whole blocks of "
+                f"block_length={cfg.block_length}: a block's rows lie in "
+                "one pool block")
+        tail = (cfg.n_kv_heads * cfg.head_dim,)
+        return BlockDiffusionEngineModel(cfg, PagedKV(
+            {"k": tail, "v": tail}, cfg.dtype, block_size, kv_dtype), **route)
     if isinstance(cfg, mimo_v2.MimoV2Config):
         if kv_dtype == "int8":
             raise ValueError(

@@ -205,6 +205,17 @@ def _pack_sizes(top: int, block_size: int) -> List[int]:
     return sorted(n for n in set(sizes) if n < top) + [top]
 
 
+class Generated(list):
+    """The answer of a request to a model that generates by diffusion
+    over blocks: the tokens, as any model's answer, with what the DEVICE
+    counted for them: `decided_at` (per token, the step of its block at
+    which it was decided) and `forwards` (the forwards the request was
+    live in: its blocks' denoising forwards and commits)."""
+
+    decided_at: List[int]
+    forwards: int
+
+
 class _Plan(NamedTuple):
     """An admission between its two phases: the request's `_active`
     entry, and what its prefill dispatch needs."""
@@ -237,7 +248,7 @@ class LlamaEngine:
     bodies of packed prefill, prefill, suffix prefill, KV write and the
     paged decode chunk, all with flat signatures `(params, *cache, ...)`.  The
     cache's FORMAT (`kv_dtype`) is the model's too: the engine hands
-    the string over and reads it back for `stats()`.  Six implementers,
+    the string over and reads it back for `stats()`.  Seven implementers,
     picked by the config's type (`engine_model_for`): `LlamaEngineModel`
     — per-head K and V pools — `LatentMoeEngineModel` — one latent
     pool, absorbed decode attention, dropless experts
@@ -248,8 +259,13 @@ class LlamaEngine:
     a learned selection, one admission family that packs a tick's
     suffixes (`models/dots3.py`) — and `WindowFullEngineModel` — paged K
     and V of two widths for the full layers beside a per-slot ring of
-    window rows (`models/mimo_v2.py`).  The class keeps its name;
-    nothing a caller passes changed.
+    window rows (`models/mimo_v2.py`) — and `BlockDiffusionEngineModel`
+    — generation by diffusion over blocks (`models/sdar.py`): a step
+    yields 0 or `B` tokens a row, so what a chunk produced is COUNTED ON
+    THE DEVICE and the host's mirror of `pos` only bounds it
+    (`_harvest`), a request carries `denoising_steps` /
+    `confidence_threshold` (`submit`) and its answer is a `Generated`.
+    The class keeps its name; nothing a caller passes changed.
 
     submit() is thread-safe and returns a `concurrent.futures.Future`
     resolving to the generated token ids (greedy — identical to what a
@@ -361,6 +377,11 @@ class LlamaEngine:
         self._model = engine_model_for(
             cfg, kv_dtype=kv_dtype, block_size=self.block_size, chunk=chunk,
             paged=mode == "pallas", interpret=self._kernel_interpret)
+        if self._model.rows_needed(1, self.max_len - 2) > self.max_len:
+            raise ValueError(
+                f"max_len={self.max_len} cannot hold the rows "
+                f"{type(cfg).__name__} needs for its longest request "
+                "(whole blocks of its block_length)")
         # the cache's KINDS: blocks through tables, one state a slot,
         # or both.  A cache with no paged leaf never asks the pool for a
         # block: admission is bounded by slots alone
@@ -368,15 +389,17 @@ class LlamaEngine:
                                for leaf in self._model.cache_leaves)
         self._has_state = any(leaf.per_slot
                               for leaf in self._model.cache_leaves)
-        if self._has_state:
+        if self._has_state or not self._model.shares_prefix:
             if prefix_cache:
                 from ray_tpu.exceptions import PrefixCacheUnsupportedError
 
                 raise PrefixCacheUnsupportedError(
-                    f"prefix_cache=True: {type(cfg).__name__} keeps a "
-                    "sequence's context as a per-slot state, which a "
-                    "radix trie cannot share block by block; pass "
-                    "prefix_cache=False (or leave it unset)")
+                    f"prefix_cache=True: {type(cfg).__name__} " + (
+                        "keeps a sequence's context as a per-slot state, "
+                        "which a radix trie cannot share block by block"
+                        if self._has_state else
+                        "has no prefill behind a cached prefix")
+                    + "; pass prefix_cache=False (or leave it unset)")
             prefix_cache = False
             if not self._has_blocks:
                 budget = 1
@@ -416,11 +439,7 @@ class LlamaEngine:
             self._model.n_layers)
         self._cache_bytes_per_slot = self._pool.bytes_per_slot(
             self._model.n_layers)
-        self._pos = jnp.zeros((slots,), jnp.int32)
-        self._tok = jnp.zeros((slots,), jnp.int32)
-        # a row is live in a decode step iff pos < stop; 0: a slot
-        # nothing was admitted to owes nobody a token
-        self._stop = jnp.zeros((slots,), jnp.int32)
+        self._reset_rows()
 
         # compiled-program families (each keyed by a static shape).
         # The chunk family is LRU-BOUNDED: each entry retains a
@@ -585,6 +604,17 @@ class LlamaEngine:
         )
         self._thread.start()
 
+    def _reset_rows(self) -> None:
+        """The slots' device state, nothing admitted: `pos`, the model's
+        own row beside it (`tok`: a one-token model's last token, a
+        block-diffusion model's block) and `stop`.  A row is live in a
+        step iff pos < stop; 0: a slot nothing was admitted to owes
+        nobody a token."""
+        jnp = self._jnp
+        self._pos = jnp.zeros((self.slots,), jnp.int32)
+        self._tok = self._model.init_tok(self.slots)
+        self._stop = jnp.zeros((self.slots,), jnp.int32)
+
     def _alloc_cache(self) -> tuple:
         """The device arrays of the model's cache spec, zeroed."""
         return tuple(
@@ -640,17 +670,29 @@ class LlamaEngine:
         self._draining = True
 
     def submit(self, prompt_ids: List[int], max_new_tokens: int,
-               timeout_s: Optional[float] = None) -> Future:
+               timeout_s: Optional[float] = None, *,
+               denoising_steps: Optional[int] = None,
+               confidence_threshold: Optional[float] = None) -> Future:
         """`timeout_s` is the caller's remaining end-to-end budget: the
         request carries its admission deadline through the queue, and
         the admission loop sheds it BEFORE prefill once the deadline
-        has passed (or predictably must pass) — see _maybe_shed."""
+        has passed (or predictably must pass) — see _maybe_shed.
+
+        `denoising_steps`, `confidence_threshold`: a request's own
+        fields of a model that generates by diffusion over blocks (None:
+        the model's config's); a model that yields one token a step
+        refuses them (`ValueError`, as a prompt it cannot hold).  Such a
+        model's answer is a `Generated`: the tokens, with the step each
+        was decided at and the forwards the request took."""
         limit = self.max_len - 1
-        if not prompt_ids or len(prompt_ids) >= limit:
+        try:
+            if not prompt_ids or len(prompt_ids) >= limit:
+                raise ValueError(f"prompt length must be in [1, {limit - 1}]")
+            fields = self._model.request_fields(denoising_steps,
+                                                confidence_threshold)
+        except ValueError as e:
             f: Future = Future()
-            f.set_exception(ValueError(
-                f"prompt length must be in [1, {limit - 1}]"
-            ))
+            f.set_exception(e)
             return f
         n_new = max(1, min(int(max_new_tokens), limit - len(prompt_ids)))
         # the lifecycle's first stamp (wall clock, like the ledger's)
@@ -709,7 +751,7 @@ class LlamaEngine:
                 ))
                 return fut
             self._queue.append(
-                (list(prompt_ids), n_new, fut, t_submit, deadline, tk)
+                (list(prompt_ids), n_new, fut, t_submit, deadline, tk, fields)
             )
             self._wake.notify()
         return fut
@@ -987,6 +1029,9 @@ class LlamaEngine:
             rec["prefill_rows"] = req["prefill_rows"]
             rec["prefill_chunks"] = req["prefill_chunks"]
             rec["tokens_out"] = min(len(req["out"]), req["want"])
+            if req["fields"]:  # a block-diffusion request's own
+                rec["denoising_steps"] = req["fields"]["denoising_steps"]
+                rec["forwards"] = req["forwards"]
         with self._ring_lock:
             self._finished_total += 1
             rec["seq"] = self._finished_total
@@ -1048,17 +1093,20 @@ class LlamaEngine:
         return own
 
     def _plan(self, prompt: List[int], n_new: int, fut: Future,
-              t_submit: float, tk=None) -> Optional[_Plan]:
+              t_submit: float, tk=None,
+              fields: Optional[Dict] = None) -> Optional[_Plan]:
         """Admission's first phase, on the host only: the radix match,
         the request's blocks, its slot and its `_active` entry.
         Returns None, without consuming anything, when the pool cannot
         cover the request right now: the caller requeues it."""
         bs = self.block_size
         T = len(prompt)
-        # highest KV index a WANTED token's step touches is T+n_new-2:
         # the row is live while its position is short of `stop`, on the
-        # device (`decode_chunk`) as in the host's mirror `pos_host`
-        stop = T + n_new - 1
+        # device (`decode_chunk`) as in the host's mirror `pos_host`;
+        # the model says what a request needs (a token a step: the
+        # highest KV index a WANTED token's step touches, T+n_new-2)
+        stop = self._model.rows_needed(T, n_new)
+        pos0 = self._model.first_pos(T)
         total_blocks = _cdiv(stop, bs)
 
         shared: List[int] = []
@@ -1093,8 +1141,14 @@ class LlamaEngine:
         self._active[slot] = req = {
             "fut": fut, "out": [], "want": n_new,
             "since": self._chunk_seq + 1,  # first chunk with its steps
-            "pos_host": T, "stop": stop,
+            "pos_host": pos0, "stop": stop,
             "own_blocks": own_set, "tree_path": path,
+            # the model's own fields of the request; where the device
+            # counts (`device_counts`): where the row started, the
+            # positions it has moved, the prompt's tokens its first
+            # block hands back, what the device said of its answer
+            "fields": fields or {}, "pos0": pos0, "moved": 0,
+            "skip": T - pos0, "decided_at": [], "forwards": 0,
             "tk": tk, "tokens_in": T, "tokens_hit": len(shared) * bs,
             "harvests": 0, "t_submit": t_submit, "t_admit": t_admit,
             "t_prefill": t_admit, "t_first": None, "prefill_rows": 0,
@@ -1217,9 +1271,10 @@ class LlamaEngine:
             last[i], slots[i] = at + T - 1, plan.slot
             pos0[i], stop0[i] = T, plan.req["stop"]
             at += nb * bs
+        extra = self._model.pack_extras(K, [plan.req for plan in pack])
         if not self._has_blocks:
-            return tokens, seg, posn, last, slots, pos0, stop0
-        return tokens, seg, posn, blk_ids, last, slots, pos0, stop0
+            return (tokens, seg, posn, last, slots, pos0, stop0, *extra)
+        return (tokens, seg, posn, blk_ids, last, slots, pos0, stop0, *extra)
 
     def _suffix_arrays(self, N: int, pack: List[_Part]) -> tuple:
         """A packed suffix prefill's host-made arguments, `(tokens,
@@ -1423,29 +1478,46 @@ class LlamaEngine:
         `stop` (its allocation ends there too)."""
         need = 1
         for req in self._active.values():
-            hi = min(req["pos_host"] + self.chunk, req["stop"]) - 1
+            hi = min(req["pos_host"] + self._model.reach, req["stop"]) - 1
             need = max(need, hi // self.block_size + 1)
         return min(_next_pow2(need), self._max_seq_blocks)
 
     def _harvest(self, toks_host: np.ndarray, seq: int):
-        """toks_host [1 + chunk, slots] from dispatch `seq` (row 0 =
-        pre-chunk tokens): append per active slot, finish those that
-        reached their budget.  Slots admitted after `seq` was
-        dispatched are skipped — their tokens start in a later chunk.
-        A request's FIRST chunk contributes from row 0 (its prefill
-        token rode along); later chunks from row 1."""
+        """toks_host [token rows, slots] from dispatch `seq`: append per
+        active slot what the MODEL says the chunk holds for it
+        (`engine_model.harvested`), finish those that reached their
+        budget.  Slots admitted after `seq` was dispatched are skipped —
+        their tokens start in a later chunk.  A one-token model: row 0 =
+        pre-chunk tokens; a request's FIRST chunk contributes from row
+        0 (its prefill token rode along), later chunks from row 1.  A
+        model whose device counts (`device_counts`): as many tokens as
+        the device says it committed, the first of them the prompt's
+        own tail (`skip`), with the step each was decided at and the
+        row's forwards; the host's mirror of `pos` is set from the
+        count (a bound again for the chunks in flight)."""
         now = _time.monotonic()  # ages the shed predictor's samples
         wall = _time.time()      # the lifecycle stamp of this harvest
         done = []
         for slot, req in self._active.items():
             if req["since"] > seq:
                 continue
-            start = 0 if req["since"] == seq else 1
+            new, more = self._model.harvested(toks_host, slot,
+                                              req["since"] == seq)
+            lo = 0
+            if more is not None:
+                req["forwards"] += more["forwards"]
+                req["moved"] += len(new)
+                req["pos_host"] = min(req["stop"], (
+                    req["pos0"] + req["moved"]
+                    + self._model.advance * (self._chunk_seq - seq)))
+                lo = min(req["skip"], len(new))
+                req["skip"] -= lo
             need = req["want"] - len(req["out"])
-            if need > 0:
-                req["out"].extend(
-                    int(t) for t in toks_host[start:start + need, slot]
-                )
+            if need > 0 and len(new) > lo:
+                req["out"].extend(int(t) for t in new[lo:lo + need])
+                if more is not None:
+                    req["decided_at"].extend(
+                        int(t) for t in more["decided_at"][lo:lo + need])
                 req["harvests"] += 1
             if req["out"] and req["t_first"] is None:
                 req["t_first"] = wall
@@ -1463,6 +1535,10 @@ class LlamaEngine:
             req = self._active.pop(slot)
             self._release(slot, req)
             out = req["out"][:req["want"]]
+            if self._model.device_counts:
+                out = Generated(out)
+                out.decided_at = req["decided_at"][:req["want"]]
+                out.forwards = req["forwards"]
             self._record("ok", req["t_submit"], wall, req["tokens_in"],
                          req)
             if req["tk"] is not None:
@@ -1503,7 +1579,7 @@ class LlamaEngine:
             plans: List[_Plan] = []
             requeued = 0
             with self._phase("plan"):
-                for i, (prompt, n_new, fut, ts, dl, tk) in \
+                for i, (prompt, n_new, fut, ts, dl, tk, *fields) in \
                         enumerate(admissions):
                     # shed BEFORE the prefill dispatch: an expired (or,
                     # under load, predictably-expiring) request consumes
@@ -1512,7 +1588,8 @@ class LlamaEngine:
                         self._pending_admissions -= 1
                         continue
                     with self._lock:
-                        plan = self._plan(prompt, n_new, fut, ts, tk)
+                        plan = self._plan(prompt, n_new, fut, ts, tk,
+                                          *fields)
                     if plan is None:
                         # pool exhausted by LIVE sequences: wait for
                         # completions, preserving arrival order
@@ -1566,9 +1643,12 @@ class LlamaEngine:
                 row_steps = self.slots * self.chunk
                 with self._lock:
                     # the host's mirror of the device's `pos`: a row
-                    # advances while it is short of its stop
+                    # advances while it is short of its stop (where the
+                    # device counts: by no more than this, a bound that
+                    # the harvest corrects)
                     for req in self._active.values():
-                        end = min(req["pos_host"] + self.chunk, req["stop"])
+                        end = min(req["pos_host"] + self._model.advance,
+                                  req["stop"])
                         row_steps_live += end - req["pos_host"]
                         rows_live += end > req["pos_host"]
                         if end > req["pos_host"]:
@@ -1593,7 +1673,7 @@ class LlamaEngine:
                 t_read = waited.t_end
                 with self._phase("harvest_host"):
                     if self._model.aux_rows:
-                        rows = 1 + self.chunk
+                        rows = self._model.token_rows
                         model_fields = self._model.tick_fields(
                             toks_host[rows:])
                         toks_host = toks_host[:rows]
@@ -1616,7 +1696,10 @@ class LlamaEngine:
             "state_rows_flushed": 0 if not self._has_state else (
                 rows_flushed if self._model.state_write_deferred
                 else row_steps_live),
-            "row_steps_live": row_steps_live,
+            # (where the device counts, the harvested chunk's own count
+            # comes with `model_fields`: the mirror's is of positions)
+            "row_steps_live": (0 if self._model.device_counts
+                               else row_steps_live),
             "row_steps": row_steps,
             # the model's own counters of the chunk harvested in this
             # tick (`engine_model.tick_fields`; none for Llama), and its
@@ -1739,7 +1822,6 @@ class LlamaEngine:
         return rows
 
     def _loop(self):
-        jnp = self._jnp
         # the account starts here, on the engine's own thread (the
         # warm-up ran its programs on the caller's)
         self._launched = []
@@ -1804,7 +1886,7 @@ class LlamaEngine:
                     # admissions popped from the queue but not (yet)
                     # registered in _active would otherwise hang their
                     # callers forever
-                    for _p, _n, fut, _ts, _dl, _tk in admissions:
+                    for _p, _n, fut, *_ in admissions:
                         if not fut.done():
                             fut.set_exception(e)
                     self._active.clear()
@@ -1826,6 +1908,4 @@ class LlamaEngine:
                 # cache leaf: sidecars are donated too) or every later
                 # dispatch dies on invalid donated buffers
                 self._cache = self._alloc_cache()
-                self._pos = jnp.zeros((self.slots,), jnp.int32)
-                self._tok = jnp.zeros((self.slots,), jnp.int32)
-                self._stop = jnp.zeros((self.slots,), jnp.int32)
+                self._reset_rows()

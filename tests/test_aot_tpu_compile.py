@@ -837,3 +837,80 @@ def _holds_slab_rows_only(hlo):
     assert len(calls) == 3 * 6
     assert sum(c.startswith("bf16[2048,2048]") for c in calls) == 2 * 6
     assert sum(c.startswith("bf16[2048,4096]") for c in calls) == 6
+
+
+# ----------------------------------------------------------------------
+# the block-diffusion model: a block of B rows a slot a forward
+# ----------------------------------------------------------------------
+# the benchmark's `sdar-30b-a3b-chat-l6` engine: 6 layers' folded pools
+# (4 heads of 128 side by side), 10,368 blocks + scratch, 128 slots
+_SDAR_POOL = dict(L=6, NB=10369, BS=16, HD=512, B=128)
+
+
+def _sdar_l6():
+    from ray_tpu.models import sdar
+
+    return sdar.SdarMoeConfig(n_layers=6, max_seq_len=1296)
+
+
+@pytest.mark.parametrize("W", [32, 81])
+def test_paged_kernels_take_a_block_of_rows_a_slot(chip, W):
+    """A block step's two calls at the cell's shapes: `paged_kv_append(
+    rows=4)` writes four consecutive rows a slot into one page of the
+    folded pool, and the decode kernel reads them back for `KV x (B x
+    G)` = 128 query heads of one row."""
+    d = _SDAR_POOL
+    pool = _s(d["L"], d["NB"], d["BS"], d["HD"])
+    tables, pos = _s(d["B"], W, dtype=jnp.int32), _s(d["B"], dtype=jnp.int32)
+    q, new = _s(d["B"], 128, 128), _s(d["B"], 4, d["HD"])
+
+    def fn(q, kp, vp, kn, vn, tables, pos, layer):
+        kp, vp = pa.paged_kv_append(kp, vp, kn, vn, tables, pos, layer,
+                                    rows=4)
+        return (pa.paged_decode_attention(q, kp, vp, tables, pos + 3, layer),
+                kp, vp)
+
+    hlo = _compile(chip, fn, q, pool, pool, new, new, tables, pos,
+                   _s(dtype=jnp.int32), donate_argnums=(1, 2))
+    assert hlo.count("tpu_custom_call") >= 2 and "bf16[128,128,512]" in hlo
+    assert "input_output_alias" in hlo
+
+
+def test_block_diffusion_programs_at_the_cells_shapes(chip):
+    """`decode_chunk_w81` (the block chunk program: 8 forwards of 128
+    slots x 4 positions) and `prefill_packed_n1296` as the engine jits
+    them for SDAR-30B-A3B's cut at the published widths (6 layers, 128
+    experts, the whole vocabulary): both pools donated and written in
+    place, no copy of a layer's experts beside the grouped kernels, and
+    the row state `[128, 15]` where a one-token model's `tok` stands."""
+    from ray_tpu.models import sdar
+    from ray_tpu.serve.engine_model import engine_model_for
+
+    cfg = _sdar_l6()
+    params = jax.tree.map(
+        lambda p: _s(*p.shape, dtype=p.dtype),
+        jax.eval_shape(lambda: sdar.init_params(cfg, jax.random.PRNGKey(0))))
+    model = engine_model_for(cfg, kv_dtype="model", block_size=16, chunk=8,
+                             paged=True, interpret=False)
+    assert (model.advance, model.reach, model.token_rows) == (16, 20, 34)
+    d = _SDAR_POOL
+    pool = _s(d["L"], d["NB"], d["BS"], d["HD"])
+    i32 = jnp.int32
+    rows = [_s(d["B"], dtype=i32)] * 2
+    state = _s(d["B"], 15, dtype=i32)
+    donate = dict(donate_argnums=(1, 2))
+    fn = model.decode_chunk(81)
+    fn.__name__ = "decode_chunk_w81"
+    hlo = _compile(chip, fn, params, pool, pool, _s(d["B"], 81, dtype=i32),
+                   state, *rows, **donate)
+    assert "jit_decode_chunk_w81" in hlo and "bf16[128,128,512]" in hlo
+    assert "input_output_alias" in hlo and "s32[39,128]" in hlo
+    assert "bf16[128,2048,768]" not in hlo and "bf16[128,768,2048]" not in hlo
+    fn = model.prefill_packed(1296)
+    fn.__name__ = "prefill_packed_n1296"
+    hlo = _compile(chip, fn, params, pool, pool, *[_s(1296, dtype=i32)] * 3,
+                   _s(81, dtype=i32), *[_s(16, dtype=i32)] * 4,
+                   _s(16, dtype=i32), _s(16, dtype=jnp.float32),
+                   rows[0], state, rows[1], **donate)
+    assert "jit_prefill_packed_n1296" in hlo and "input_output_alias" in hlo
+    assert "bf16[128,2048,768]" not in hlo and "bf16[128,768,2048]" not in hlo
