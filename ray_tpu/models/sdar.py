@@ -30,23 +30,33 @@ cached; its `T mod B` tail opens the first generated block as decided
 positions.  A block at `pos`: tokens `blk [B]`, `und [B]` which are
 undecided, step `s`.  One FORWARD runs `where(und, mask_id, blk)` at
 positions `pos .. pos + B - 1`, writes its K and V rows there (again at
-every forward of the block) and attends columns `0 .. pos + B - 1`.
-With `und` empty that was the COMMIT forward: its rows are the block's,
-the block's tokens are output, the next block starts undecided.
-Otherwise `unmask` decides positions: `n_s = B // S + (s < B mod S)`
-of them, or every undecided position whose confidence `max softmax` is
-over the threshold where those are at least `n_s`.  A block so takes at
-most `S` denoising forwards and one commit.
+every forward of the block) and attends columns `0 .. pos + B - 1`;
+`unmask` then decides positions: `n_s = B // S + (s < B mod S)` of
+them, or every undecided position whose confidence `max softmax` is
+over the threshold where those are at least `n_s`.  The forward that
+decides the block's last position OUTPUTS it, and the next block starts
+undecided.  What the cache owes the next block are the rows a forward
+of the block's DECIDED tokens writes (the COMMIT; every forward before
+had masks in its input): they are written by the next block's first
+forward itself, which carries the decided block as a commit half at
+`pos - B .. pos - 1` beside its own (`block_step(commit=)`: the mask is
+block-causal, so in each layer both halves' rows are written and then
+both attend).  A block so takes at most `S` forwards, none of them a
+commit's own; a request's last block is output and never committed
+(nobody reads its rows: a cache that SHARED prefixes would have to
+commit it first).
 
 What the serve engine needs, and nothing else:
 
 - `forward`: a PACKED row's prompts (`llama.Packed`) under `same prompt
   AND block-causal`, the K and V rows to cache; logits only where asked
   (admission needs none: the first block's forward makes them).
-- `block_step`: one forward of every live row's block through the paged
-  pool (`ops/paged_attention`: `B` rows appended a slot, then the decode
-  kernel on `B x H` query heads of one row at position `pos + B - 1`:
-  there is no mask inside a block) or its dense view.
+- `block_step`: one forward of every live row's block, and of the
+  block before it where that is still owed its clean rows, through the
+  paged pool (`ops/paged_attention`: `B` rows appended a slot, then the
+  decode kernel on `B x H` query heads of one row at position `pos + B
+  - 1`: there is no mask inside a block; one call of each a half) or
+  its dense view.
 - `unmask`: the denoising choice, from the logits.
 
 K and V pools are FOLDED: `[L, num_blocks, block_size, KV * hd]`, a
@@ -265,8 +275,8 @@ def forward(cfg: SdarMoeConfig, params: Dict, tokens: jax.Array, *,
 # generation: one forward of every live row's block
 # ----------------------------------------------------------------------
 def block_step(cfg: SdarMoeConfig, params: Dict, tokens: jax.Array, cache,
-               pos, *, tables=None, live=None, kernel: bool = False,
-               interpret: bool = False):
+               pos, *, tables=None, live=None, commit=None,
+               kernel: bool = False, interpret: bool = False):
     """One forward of a block a row: tokens [S, B] (masks where a
     position is undecided) at positions `pos[s] .. pos[s] + B - 1`, `pos`
     [S] multiples of `B`; `cache` = `(k, v)`.  `tables` [S, W] given: the
@@ -277,72 +287,105 @@ def block_step(cfg: SdarMoeConfig, params: Dict, tokens: jax.Array, cache,
     attend columns `0 .. pos + B - 1` through the decode kernel as
     `KV x (B x G)` heads of one query.  `tables` None: the dense view
     `[L, S, M, KV * hd]`, written by a slice update, attended under a
-    mask.  Every forward of a block writes the block's rows AGAIN, so
-    what stays once the block is committed is what its commit forward
-    wrote.  Returns (logits [S, B, vocab] float32, cache, stats) with
-    `stats` = `experts_touched`, `load_max` over the layers.
+    mask.  Every forward of a block writes the block's rows AGAIN, from
+    an input with masks until the block is decided: the rows that STAY
+    are those a clean input wrote, which is what `commit` is for.
+    Returns (logits [S, B, vocab] float32, cache, stats) with `stats` =
+    `experts_touched`, `load_max` over the layers.
 
     `live` [S] bool (the engine's `pos < stop`; None: every row): a row
     that is not live writes nothing, attends nothing on the paged route
-    and is routed to no expert."""
+    and is routed to no expert.
+
+    `commit` = `(clean [S, B], riding [S] bool)`: the forward carries a
+    COMMIT HALF beside every row's block, the block BEFORE it with its
+    decided tokens `clean`, at positions `pos - B .. pos - 1`, through
+    the same layers: `2 S` rows of `B` positions.  The mask is
+    block-causal, so in each layer both halves' rows are written first
+    and then both attend, the commit half columns `0 .. pos - 1`: every
+    row's result is what a forward of the clean block followed by a
+    forward of the open one gives.  A half that does not ride (`riding`
+    False) is a dead row.  On the paged route the halves are TWO calls
+    of each kernel a layer, each over `S` rows (the shape a trace finds
+    the attention by; a dead half's pages are not copied); in the view
+    both blocks go into the slot's one row.  Only the open halves reach
+    the head: the logits stay `[S, B, vocab]`.  `stats` count both
+    halves' experts."""
     S, B = tokens.shape
     H, KV, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     G = H // KV
     k_cache = cache[0]
-    at = pos[:, None] + jnp.arange(B, dtype=pos.dtype)[None, :]   # [S, B]
+    # the halves a slot: the open block at `pos`, the pending one behind
+    halves = [(pos, live)]
+    if commit is not None:
+        tokens = jnp.concatenate([tokens, commit[0]])
+        halves = [(pos, jnp.ones((S,), bool) if live is None else live),
+                  (pos - B, commit[1])]
+    parts = [slice(i * S, (i + 1) * S) for i in range(len(halves))]
+    at = jnp.concatenate([p[:, None] + jnp.arange(B, dtype=pos.dtype)[None, :]
+                          for p, _ in halves])                 # [n S, B]
     if tables is None:
-        M = k_cache.shape[2]
-        valid = (jnp.arange(M)[None, :] < (pos + B)[:, None])
-        valid = valid[:, None, None, None, :]
-    else:  # where a row's block goes, how far its queries attend
-        w_pos, a_pos = _pa.dead_row_positions(pos, live, tables,
-                                              k_cache.shape[2])
-        a_pos = jnp.where(a_pos < 0, a_pos, a_pos + B - 1)
-    rows = None if live is None else jnp.repeat(live, B)
-    x = _embed(params, tokens, cfg.dtype).astype(cfg.dtype)     # [S, B, D]
+        cols = jnp.arange(k_cache.shape[2])[None, :]
+        valid = [(cols < (p + B)[:, None])[:, None, None, None, :]
+                 for p, _ in halves]
+    else:  # where a half's block goes, how far its queries attend
+        spots = [_pa.dead_row_positions(p, on, tables, k_cache.shape[2])
+                 for p, on in halves]
+        spots = [(w, jnp.where(a < 0, a, a + B - 1)) for w, a in spots]
+    rows = None if halves[0][1] is None else jnp.repeat(
+        jnp.concatenate([on for _, on in halves]), B)
+    x = _embed(params, tokens, cfg.dtype).astype(cfg.dtype)   # [n S, B, D]
 
-    def write_view(c, new, li):
-        """The dense view's layer `li` with each live row's block at its
-        position: a slice update a row."""
-        old = lax.dynamic_index_in_dim(c, li, 0, keepdims=False)
-
+    def write_view(c_rows, new, p, on):
+        """The dense view's rows of a layer with each live half's block
+        at its position: a slice update a row."""
         def one(c_row, n_row, p, on):
+            p = jnp.maximum(p, 0)   # a first block has none behind it
             was = lax.dynamic_slice_in_dim(c_row, p, B, 0)
             return lax.dynamic_update_slice_in_dim(
                 c_row, jnp.where(on, n_row.astype(c_row.dtype), was), p, 0)
 
-        on = jnp.ones((S,), bool) if live is None else live
-        return jax.vmap(one)(old, new, pos, on)
+        on = jnp.ones((S,), bool) if on is None else on
+        return jax.vmap(one)(c_rows, new, p, on)
 
     def layer(carry, li):
         x, kc, vc = carry
         w = _at(params["layers"], li)
         h = _rms_norm(x, w["attn_norm"].astype(cfg.dtype), cfg.norm_eps)
         q, k, v = _qkv(cfg, w, h, lambda t: _rope_pos(t, cfg.rope_theta, at))
-        k, v = k.reshape(S, B, KV * d), v.reshape(S, B, KV * d)
+        k, v = k.reshape(-1, B, KV * d), v.reshape(-1, B, KV * d)
         if tables is not None:
             with jax.named_scope("block_kv_write"):
-                kc, vc = _pa.paged_kv_append(
-                    kc, vc, k.astype(kc.dtype), v.astype(vc.dtype), tables,
-                    w_pos, li, interpret=interpret, rows=B)
+                for part, (w_pos, _) in zip(parts, spots):
+                    kc, vc = _pa.paged_kv_append(
+                        kc, vc, k[part].astype(kc.dtype),
+                        v[part].astype(vc.dtype), tables, w_pos, li,
+                        interpret=interpret, rows=B)
             with jax.named_scope("block_attn"):
                 # KV x (B x G) query heads of ONE row: head `kv * B * G +
                 # b * G + g` reads kv head `kv`, and every column up to
                 # the block's last is its to see
-                qh = q.reshape(S, B, KV, G, d).transpose(0, 2, 1, 3, 4)
-                o = _pa.paged_decode_attention(
-                    qh.reshape(S, KV * B * G, d), kc, vc, tables, a_pos, li,
-                    interpret=interpret)
-                o = o.reshape(S, KV, B, G, d).transpose(0, 2, 1, 3, 4)
-                o = o.reshape(S, B, H * d)
+                qh = q.reshape(-1, B, KV, G, d).transpose(0, 2, 1, 3, 4)
+                qh = qh.reshape(-1, KV * B * G, d)
+                o = jnp.concatenate([_pa.paged_decode_attention(
+                    qh[part], kc, vc, tables, a_pos, li, interpret=interpret)
+                    for part, (_, a_pos) in zip(parts, spots)])
+                o = o.reshape(-1, KV, B, G, d).transpose(0, 2, 1, 3, 4)
+                o = o.reshape(-1, B, H * d)
         else:
             with jax.named_scope("block_kv_write"):
-                kr, vr = write_view(kc, k, li), write_view(vc, v, li)
+                kr = lax.dynamic_index_in_dim(kc, li, 0, keepdims=False)
+                vr = lax.dynamic_index_in_dim(vc, li, 0, keepdims=False)
+                for part, (p, on) in zip(parts, halves):
+                    kr = write_view(kr, k[part], p, on)
+                    vr = write_view(vr, v[part], p, on)
                 kc = lax.dynamic_update_index_in_dim(kc, kr, li, 0)
                 vc = lax.dynamic_update_index_in_dim(vc, vr, li, 0)
             with jax.named_scope("block_attn"):
-                o = _attend(cfg, q, kr.reshape(S, -1, KV, d),
-                            vr.reshape(S, -1, KV, d), valid)
+                o = jnp.concatenate([_attend(
+                    cfg, q[part], kr.reshape(S, -1, KV, d),
+                    vr.reshape(S, -1, KV, d), ok)
+                    for part, ok in zip(parts, valid)])
         x = x + _apply(o.astype(cfg.dtype), w["wo"], cfg.dtype)
         x, stats = _moe(cfg, params, li, x, kernel=kernel,
                         interpret=interpret, row_mask=rows)
@@ -350,7 +393,7 @@ def block_step(cfg: SdarMoeConfig, params: Dict, tokens: jax.Array, cache,
 
     (x, kc, vc), (touched, load) = lax.scan(
         layer, (x, *cache), jnp.arange(cfg.n_layers, dtype=jnp.int32))
-    return _head(cfg, params, x), (kc, vc), {
+    return _head(cfg, params, x[:S]), (kc, vc), {
         "experts_touched": jnp.sum(touched), "load_max": jnp.max(load)}
 
 

@@ -90,10 +90,12 @@ greedy pick, the positions, row 0 and the aux rows, around ONE decode
 step the model hands it.  `block_chunk_program` is the chunk of a model
 that generates by DIFFUSION OVER BLOCKS: `chunk` FORWARDS of a block of
 `B` positions a row, each live row its own state machine (a forward
-decides some of the block's positions, or, with none left undecided,
-COMMITS the block: `B` tokens out, `pos += B`), so a step yields 0 or
-`B` tokens a row and `toks` says per slot how many tokens the chunk
-committed, which, and the step each was decided at.  What the two
+decides some of the block's positions; the one that decides the last
+OUTPUTS the block, `B` tokens out, `pos += B`, and the block's clean
+rows are written by the row's next forward, which carries them beside
+the next block's own), so a step yields 0 or `B` tokens a row and
+`toks` says per slot how many tokens the chunk output, which, and the
+step each was decided at.  What the two
 share is shared code: the flat signature and the dense view
 (`_chunk_args`, `_chunk_cache`), liveness (`pos < stop` at every step;
 a dead row writes nothing and is routed to no expert) and the aux rows
@@ -364,29 +366,37 @@ def chunk_program(step, chunk: int, *, gather: Optional[PagedKV] = None,
 
 class BlockRows:
     """A block-diffusion row's state beside `pos` and `stop`: ONE int32
-    row `[3 B + 3]` a slot, which the engine carries where a one-token
+    row `[4 B + 4]` a slot, which the engine carries where a one-token
     model's `tok` stands: the block's tokens `blk [B]`, which of them
     are undecided `und [B]` (a flag of the state, never `blk ==
     mask_id`: a prompt that holds the id is only a prompt), the step
     each was decided at `dec [B]` (-1: given by the prompt, or not yet
     decided), the block's step `s`, the request's steps `S` and its
-    confidence threshold (a float32's bits)."""
+    confidence threshold (a float32's bits), and the PENDING COMMIT: the
+    decided tokens `held [B]` of the block before this one, already
+    output, and `pend`, whether their clean K and V rows are still owed
+    to the cache (they ride in the row's next forward)."""
 
     def __init__(self, block: int):
-        self.block, self.width = block, 3 * block + 3
+        self.block, self.width = block, 4 * block + 4
 
     def unpack(self, state):
         B = self.block
         return (state[:, :B], state[:, B:2 * B] != 0, state[:, 2 * B:3 * B],
                 state[:, 3 * B], state[:, 3 * B + 1],
                 jax.lax.bitcast_convert_type(state[:, 3 * B + 2],
-                                             jnp.float32))
+                                             jnp.float32),
+                state[:, 3 * B + 3:4 * B + 3], state[:, 4 * B + 3] != 0)
 
-    def pack(self, blk, und, dec, s, steps, thr):
+    def pack(self, blk, und, dec, s, steps, thr, held=None, pend=None):
+        """`held`, `pend` None: nothing pending (a row just admitted)."""
+        if held is None:
+            held, pend = jnp.zeros_like(blk), jnp.zeros_like(s, bool)
         return jnp.concatenate([
             blk, und.astype(jnp.int32), dec, s[:, None], steps[:, None],
             jax.lax.bitcast_convert_type(thr.astype(jnp.float32),
-                                         jnp.int32)[:, None]], axis=1)
+                                         jnp.int32)[:, None],
+            held, pend.astype(jnp.int32)[:, None]], axis=1)
 
 
 def block_chunk_program(step, unmask, chunk: int, rows: BlockRows,
@@ -397,33 +407,46 @@ def block_chunk_program(step, unmask, chunk: int, rows: BlockRows,
     `(params, *cache, tables, state, pos, stop) -> (*cache, state, pos,
     toks)`: `chunk` FORWARDS in one `lax.scan`, each live row (`pos <
     stop`, as in `chunk_program`) its own state machine over `state`
-    (`BlockRows`), so rows that commit and rows that denoise share a
-    forward.
+    (`BlockRows`), so rows at any step of any block share a forward.
 
-    `step(params, tokens [slots, B], cache, tables, pos, live) ->
-    (logits [slots, B, vocab], cache, stats)` is the model's forward of
-    one block a row at positions `pos .. pos + B - 1`, which writes the
-    block's rows into the cache; its input is `where(und, mask_id,
-    blk)`.  A live row with NOTHING undecided was at its COMMIT forward:
-    the rows it wrote are the block's, its tokens are the row's output,
-    `pos += B`, the next block starts undecided at step 0.  Any other
-    live row takes `unmask(logits, blk, und, dec, s, steps, thr) ->
-    (blk, und, dec)`, the denoising choice, and `s += 1`.  A dead row
-    keeps its state.  `commit` False is the benchmark's control: a block
-    is output at the forward that decides its last position, so the rows
-    a forward with masks in its input wrote stay in the cache.
+    `step(params, tokens [slots, B], cache, tables, pos, live, commit)
+    -> (logits [slots, B, vocab], cache, stats)` is the model's forward
+    of one block a row at positions `pos .. pos + B - 1`, which writes
+    the block's rows into the cache; its input is `where(und, mask_id,
+    blk)`.  A live row takes `unmask(logits, blk, und, dec, s, steps,
+    thr) -> (blk, und, dec)`, the denoising choice, and `s += 1`.  The
+    forward that decides a block's LAST position OUTPUTS the block: its
+    tokens are the row's output, `pos += B`, the next block starts
+    undecided at step 0.  The rows that forward wrote had masks in their
+    input, and the cache owes the next block the rows a CLEAN input
+    writes: A COMMIT IS NEVER A FORWARD OF ITS OWN.  The block's tokens
+    stay in the row's state as a pending commit (`held`, `pend`) and
+    ride in the row's NEXT forward as its commit half (`commit` =
+    `(held, live & pend)`: `sdar.block_step`), beside the next block's
+    first denoising forward, which reads them; then `pend` is cleared.
+    A pending commit survives a chunk's end in the state.  A request's
+    LAST block is never committed: `pos` reaches `stop`, the row is
+    dead, and nobody reads those rows (a prefix cache over this model
+    would have to commit the last block before adopting it).  A block so
+    takes as many forwards as it has denoising steps.  A dead row keeps
+    its state.
+
+    `commit` False is the benchmark's control, and no user's option:
+    nothing is ever pending and `step` is handed no commit half, so the
+    rows a forward with masks in its input wrote stay in the cache.
 
     `toks` `[2 + 2 cap (+ aux_rows), slots]` int32, `cap` the tokens a
-    chunk commits for a row at most (the model's `advance`: a block
-    takes two forwards at least): row 0 HOW MANY tokens
-    the chunk committed for the slot, row 1 how many forwards it was
-    live in, rows `2 .. 2 + cap` the committed tokens in order, the next
-    `cap` rows the step of its block each was decided at (-1: a prompt's
-    token), then `aux(stats)`: `stats` = (rows that committed, rows that
-    denoised, the columns the live rows attended (`pos + B` each), *the
-    step's own) a forward.  The host learns what a row
-    produced from this read alone: it cannot count it, the steps a block
-    takes depend on the request and on the logits."""
+    chunk outputs for a row at most (the model's `advance`: a block a
+    forward): row 0 HOW MANY tokens the chunk output for the slot, row 1
+    how many forwards it was live in, rows `2 .. 2 + cap` the tokens in
+    order, the next `cap` rows the step of its block each was decided at
+    (-1: a prompt's token), then `aux(stats)`: `stats` = (live rows that
+    decided nothing, rows that denoised, the cached columns the halves
+    attended (`pos + B` a live row, `pos` more where a commit rode),
+    rows a commit rode in, blocks output, *the step's own) a forward.
+    The host learns what a row produced from this read alone: it cannot
+    count it, the steps a block takes depend on the request and on the
+    logits."""
     B = rows.block
 
     def _fn(params, *flat):
@@ -435,43 +458,44 @@ def block_chunk_program(step, unmask, chunk: int, rows: BlockRows,
         def body(carry, _):
             state, cache, pos, out, n_tok, n_fwd = carry
             live = pos < stop
-            blk, und, dec, s, steps, thr = rows.unpack(state)
+            blk, und, dec, s, steps, thr, held, pend = rows.unpack(state)
+            riding = live & pend
             logits, cache, stats = step(
                 params, jnp.where(und, mask_id, blk), cache, tables, pos,
-                live)
-            open_ = jnp.any(und, axis=-1)
-            denoise = live & open_
+                live, (held, riding) if commit else None)
+            denoise = live & jnp.any(und, axis=-1)
             nblk, nund, ndec = unmask(logits, blk, und, dec, s, steps, thr)
-            if commit:
-                done = live & ~open_
-            else:  # the control: out with its last decision
-                done = denoise & ~jnp.any(nund, axis=-1)
-                blk, dec = (jnp.where(done[:, None], a, b)
-                            for a, b in ((nblk, blk), (ndec, dec)))
-            # the committed block behind what the chunk committed before
+            d = denoise[:, None]
+            blk, und, dec = (jnp.where(d, a, b) for a, b in (
+                (nblk, blk), (nund, und), (ndec, dec)))
+            # out with its last decision, behind what the chunk output
+            done = live & ~jnp.any(und, axis=-1)
             here = done[:, None] & (at == (n_tok // B)[:, None])
             out = tuple(jnp.where(here, jnp.tile(x, (1, cap // B)), o)
                         for x, o in zip((blk, dec), out))
-            d, c = denoise[:, None], done[:, None]
+            c = done[:, None]
             state = rows.pack(
-                jnp.where(c, 0, jnp.where(d, nblk, blk)),
-                jnp.where(c, True, jnp.where(d, nund, und)),
-                jnp.where(c, -1, jnp.where(d, ndec, dec)),
+                jnp.where(c, 0, blk), c | und, jnp.where(c, -1, dec),
                 jnp.where(done, 0, jnp.where(denoise, s + 1, s)),
-                steps, thr)
+                steps, thr, jnp.where(c, blk, held),
+                jnp.where(live, done & commit, pend))
             return ((state, cache, jnp.where(done, pos + B, pos), out,
                      n_tok + B * done, n_fwd + live),
-                    (jnp.sum(done), jnp.sum(denoise),
-                     jnp.sum(jnp.where(live, pos + B, 0)), *stats))
+                    (jnp.sum(live & ~denoise), jnp.sum(denoise),
+                     jnp.sum(jnp.where(live, pos + B, 0)
+                             + jnp.where(riding, pos, 0)),
+                     jnp.sum(riding), jnp.sum(done), *stats))
 
         zero = jnp.zeros((slots,), jnp.int32)
-        pos_in = pos
+        pos_in, owed = pos, rows.unpack(state)[-1]
         (state, cache, pos, out, n_tok, n_fwd), stats = jax.lax.scan(
             body, (state, cache, pos,
                    (jnp.zeros((slots, cap), jnp.int32),) * 2, zero, zero),
             None, length=chunk)
-        # the open block's rows were written too
-        cache = _chunk_cache(gather, pool, tables, cache, (pos_in, pos + B))
+        # from a pending commit's rows to the open block's
+        cache = _chunk_cache(
+            gather, pool, tables, cache,
+            (jnp.where(owed, jnp.maximum(pos_in - B, 0), pos_in), pos + B))
         counters = None if aux is None else aux(stats)
         return (*cache, state, pos, _chunk_rows(
             [n_tok[None], n_fwd[None], out[0].T, out[1].T], counters,
@@ -583,6 +607,8 @@ class _EngineModel:
     # model): the host's mirror of `pos` is then an upper bound, and the
     # tick's live row-steps come back with the chunk (`tick_fields`)
     device_counts = False
+    # the `tick_fields` the engine also sums from its start (`stats()`)
+    summed: Tuple[str, ...] = ()
     # a prompt's cached prefix can be prefilled behind (`suffix_prefill`
     # and `kv_write`, or `packs_suffixes`); per-slot leaves never can
     shares_prefix = True
@@ -1175,45 +1201,54 @@ class BlockDiffusionEngineModel(_ExpertCounters, _EngineModel):
     num_blocks, block_size, KV * hd]` (`kv`), `block_size % B == 0`, so
     a block never straddles two pool blocks.
 
-    A step yields 0 or `B` tokens a row, and how many steps a block
-    takes depends on the request (`denoising_steps`, `confidence_
+    A forward yields 0 or `B` tokens a row, and how many forwards a
+    block takes depends on the request (`denoising_steps`, `confidence_
     threshold`: `request_fields`) and on the logits, so everything the
     engine counted on the host for a one-token model comes FROM THE
     DEVICE here (`device_counts`): `decode_chunk` is `block_chunk_
     program`, whose `toks` say per slot how many tokens the chunk
-    committed, which, the step each was decided at and the forwards the
+    output, which, the step each was decided at and the forwards the
     row was live in (`harvested`); `tick_fields` hands the tick ring its
-    `row_steps_live` (ROW-FORWARDS) beside `tokens_committed`,
-    `commit_row_steps`, `denoise_row_steps` and the experts' counters.
-    A row's device state beside `pos` and `stop` is a `BlockRows` row
-    (`init_tok`).  Admission (`prefill_packed`) prefills a prompt's
-    whole blocks under the block-causal mask with NO head and no token:
-    its `first` makes the row's first block from the prompt's `T mod B`
-    tail tokens, `pos` the tail's start.  `stop` is `rows_needed`:
-    `ceil((T + n) / B) B`.
+    counters.  A row's device state beside `pos` and `stop` is a
+    `BlockRows` row (`init_tok`).  Admission (`prefill_packed`) prefills
+    a prompt's whole blocks under the block-causal mask with NO head and
+    no token: its `first` makes the row's first block from the prompt's
+    `T mod B` tail tokens, `pos` the tail's start, nothing pending.
+    `stop` is `rows_needed`: `ceil((T + n) / B) B`.
+
+    A block is output by the forward that decides its last position,
+    and its clean K and V rows are written by the row's NEXT forward,
+    which carries them as a commit half beside the next block's first
+    denoising forward (`block_chunk_program`): a block takes `S`
+    forwards, not `S + 1`, and a chunk of `chunk` forwards may output
+    `chunk` blocks a row (`advance`).  A request's last block is output
+    and never committed: nothing reads its rows.
 
     `suffix_prefill` and `kv_write` do not exist: a prefix would be
     valid at multiples of `B`, which no test holds yet, so the prefix
-    cache is refused.  `paged`: the paged kernels (`B` rows appended a
-    slot, `B x H` query heads of one row) + Pallas grouped products
-    (TPU); else the dense view + `lax.ragged_dot` (anywhere).
-    `commit` False is the benchmark's control (`block_chunk_program`)."""
+    cache is refused; one over this model would also have to commit a
+    request's last block before adopting it.  `paged`: the paged kernels
+    (`B` rows appended a slot, `B x H` query heads of one row, each
+    called once a half) + Pallas grouped products (TPU); else the dense
+    view + `lax.ragged_dot` (anywhere).
+    `commit` False is the benchmark's control, which that sets on the
+    class before it makes its engine and nothing else does: no commit is
+    ever pending, no forward carries a commit half, and a block's rows
+    stay as its last denoising forward wrote them, masks in its input."""
 
-    aux_rows = 5
+    aux_rows = 7
     device_counts = True
     shares_prefix = False
     commit = True
+    summed = ("fused_commit_row_steps",)
 
     def __init__(self, cfg, kv: PagedKV, **route):
         super().__init__(cfg, kv, **route)
         B = cfg.block_length
         self.rows = BlockRows(B)
         self._pairs = cfg.n_layers * cfg.n_experts
-        # tokens a chunk commits for a row at most (two forwards a block
-        # at least; the control commits with the deciding forward)
-        self.advance = (-(-self.chunk // 2) if self.commit
-                        else self.chunk) * B
-        self.reach = self.advance + B       # the open block's rows
+        # a forward may output a block, and writes no row past it
+        self.advance = self.reach = self.chunk * B
         self.token_rows = 2 + 2 * self.advance
 
     # -- what a request is to this model --------------------------------
@@ -1257,32 +1292,44 @@ class BlockDiffusionEngineModel(_ExpertCounters, _EngineModel):
 
     @staticmethod
     def _aux(stats):
-        done, denoise, attended, touched, load = stats
-        return jnp.stack([jnp.sum(done), jnp.sum(denoise), jnp.sum(attended),
-                          jnp.sum(touched), jnp.max(load)])
+        *counted, touched, load = stats
+        return jnp.stack([*(jnp.sum(c) for c in counted), jnp.sum(touched),
+                          jnp.max(load)])
 
     def tick_fields(self, aux) -> Dict[str, object]:
-        """`aux` [5, slots] from a harvested chunk: its ROW-FORWARDS by
-        kind (a row's forward is what this model's `row_steps_live`
-        counts), the tokens they committed, the cached columns a
-        forward's live rows attended (each `pos + B`: a FORWARD's mean),
-        and the experts' counters as `_ExpertCounters` gives them (a
-        forward's mean too)."""
-        done, denoise = int(aux[0, 0]), int(aux[1, 0])
-        return {"row_steps_live": done + denoise,
-                "commit_row_steps": done, "denoise_row_steps": denoise,
-                "tokens_committed": done * self.rows.block,
-                "attended_tokens": float(aux[2, 0]) / self.chunk,
-                **super().tick_fields(aux[3:])}
+        """`aux` [7, slots] from a harvested chunk: its ROW-FORWARDS, a
+        live (slot, forward) pair each (what this model's
+        `row_steps_live` counts), by kind: `denoise_row_steps` decided
+        positions, `commit_row_steps` decided NOTHING (0: a commit is
+        no forward of its own; the key stays, its reader asks for it);
+        `fused_commit_row_steps`, those that carried a pending commit
+        beside their own block; `tokens_committed`, the tokens OUTPUT;
+        `attended_tokens`, the cached columns ONE CALL of the attention
+        kernel read, a forward's mean: a forward is one call a half,
+        the open halves' (`pos + B` a live row) and the commit halves'
+        (`pos` where one rode), so both halves' columns, halved (the
+        control's forward has one half: not halved); then the experts'
+        counters as `_ExpertCounters` gives them (a forward's mean too,
+        both halves' experts)."""
+        idle, denoise, attended, fused, blocks = (
+            int(aux[i, 0]) for i in range(5))
+        return {"row_steps_live": idle + denoise,
+                "commit_row_steps": idle, "denoise_row_steps": denoise,
+                "fused_commit_row_steps": fused,
+                "tokens_committed": blocks * self.rows.block,
+                "attended_tokens": attended / self.chunk / (
+                    2 if self.commit else 1),
+                **super().tick_fields(aux[5:])}
 
     # -- compiled-program bodies ---------------------------------------
     def decode_chunk(self, W: int):
         cfg, paged, kw = self.cfg, self._paged, self._kw()
 
-        def step(params, tokens, cache, tables, pos, live):
+        def step(params, tokens, cache, tables, pos, live, commit):
             logits, cache, st = sdar.block_step(
                 cfg, params, tokens, cache, pos,
-                tables=tables if paged else None, live=live, **kw)
+                tables=tables if paged else None, live=live, commit=commit,
+                **kw)
             return logits, cache, (st["experts_touched"], st["load_max"])
 
         return block_chunk_program(

@@ -210,7 +210,7 @@ class Generated(list):
     over blocks: the tokens, as any model's answer, with what the DEVICE
     counted for them: `decided_at` (per token, the step of its block at
     which it was decided) and `forwards` (the forwards the request was
-    live in: its blocks' denoising forwards and commits)."""
+    live in: its blocks' denoising forwards)."""
 
     decided_at: List[int]
     forwards: int
@@ -490,6 +490,8 @@ class LlamaEngine:
         self._prefill_calls = 0       # prefill programs (packed+suffix)
         self._prefill_rows = 0        # requests those programs prefilled
         self._prefill_padded_tokens = 0  # their sizes (N, bucket) summed
+        # the model's own tick counters it wants summed from the start
+        self._model_sums = {k: 0 for k in self._model.summed}
         # the packed prefill's closed set of sizes and the prompts one
         # program holds: the segment mask needs the dense attention form
         top = self._max_seq_blocks * self.block_size
@@ -822,6 +824,7 @@ class LlamaEngine:
                 "prefill_calls": self._prefill_calls,
                 "prefill_rows": self._prefill_rows,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
+                **self._model_sums,
                 "gather_blocks": self._last_gather_blocks,
                 # decode-kernel / quantization plane: which route the
                 # chunk dispatches take and what the pool costs in HBM
@@ -1491,7 +1494,7 @@ class LlamaEngine:
         pre-chunk tokens; a request's FIRST chunk contributes from row
         0 (its prefill token rode along), later chunks from row 1.  A
         model whose device counts (`device_counts`): as many tokens as
-        the device says it committed, the first of them the prompt's
+        the device says it output, the first of them the prompt's
         own tail (`skip`), with the step each was decided at and the
         row's forwards; the host's mirror of `pos` is set from the
         count (a bound again for the chunks in flight)."""
@@ -1677,6 +1680,8 @@ class LlamaEngine:
                         model_fields = self._model.tick_fields(
                             toks_host[rows:])
                         toks_host = toks_host[:rows]
+                        for k in self._model_sums:
+                            self._model_sums[k] += model_fields[k]
                     with self._lock:
                         self._harvest(toks_host, p_seq)
         self._pending_toks = (

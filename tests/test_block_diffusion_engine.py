@@ -30,22 +30,34 @@ class Recorder:
 
     def __init__(self):
         self.forwards, self._real = [], sdar.block_step
+        # beside each forward: which rows carried a pending commit
+        self.riding = []
 
     def __call__(self, cfg, params, tokens, cache, pos, **kw):
         logits, cache, stats = self._real(cfg, params, tokens, cache, pos,
                                           **kw)
-        jax.debug.callback(
-            lambda p, l, lg: self.forwards.append(
-                (np.asarray(p), np.asarray(l), np.asarray(lg))),
-            pos, kw["live"], logits, ordered=True)
+        commit = kw.get("commit")
+        riding = np.zeros(pos.shape, bool) if commit is None else commit[1]
+
+        def keep(p, l, lg, r):
+            self.forwards.append((np.asarray(p), np.asarray(l),
+                                  np.asarray(lg)))
+            self.riding.append(np.asarray(r))
+
+        jax.debug.callback(keep, pos, kw["live"], logits, riding,
+                           ordered=True)
         return logits, cache, stats
+
+    def clear(self):
+        self.forwards.clear()
+        self.riding.clear()
 
     def of_the_one_live_row(self):
         """[(pos, logits [B, vocab])] of the forwards in which exactly
         one row was live (a request served alone), then forgotten."""
         out = [(int(p[l][0]), lg[l][0]) for p, l, lg in self.forwards
                if l.sum() == 1]
-        self.forwards.clear()
+        self.clear()
         return out
 
 
@@ -70,10 +82,21 @@ def _want(cfg, logits_of, prompt, n, S, thr=0.9):
                         logits_of)
 
 
+def _forwards(want):
+    """The forwards the ENGINE takes for the reference's answer: the
+    reference counts one commit a block (`benchmarks/reference/sdar.py`,
+    which a serving PR does not edit) and the engine's commit rides in
+    the next block's first forward, so its count is the reference's
+    less its blocks: the denoising forwards alone."""
+    blocks = len({pos for pos, _, _ in want[3]})
+    assert want[2] - blocks == len(want[3])
+    return want[2] - blocks
+
+
 def _same(out, want):
     assert isinstance(out, Generated)
     assert list(out) == want[0] and out.decided_at == want[1]
-    assert out.forwards == want[2]
+    assert out.forwards == _forwards(want)
 
 
 @pytest.mark.parametrize("T,n,S", [(8, 8, 1), (6, 7, 2), (13, 9, 0),
@@ -87,25 +110,19 @@ def test_a_request_alone_equals_the_reference_forward_by_forward(served, T,
     S = S or cfg.block_length
     prompt = [int(t) for t in _toks(T, seed=T * 31 + n)]
     want = _want(cfg, logits_of, prompt, n, S)
-    rec.forwards.clear()
+    rec.clear()
     out = eng.submit(prompt, n, denoising_steps=S).result(timeout=300)
     _same(out, want)
     time.sleep(0.2)   # the chunk that ran behind the last harvest
     mine = rec.of_the_one_live_row()
-    assert len(mine) == want[2]
-    trace = iter(want[3])
-    at, left = None, 0
-    for pos, logits in mine:
-        if pos != at:   # a new block: its denoising forwards, then one more
-            at, left = pos, sum(1 for p, _, _ in want[3] if p == pos)
-        if left:
-            p, _, wanted = next(trace)
-            assert p == pos and np.abs(logits - wanted).max() < TOL
-            left -= 1
-    assert next(trace, None) is None
+    # every forward is a denoising forward: a block's commit rides in
+    # the next block's first, whose logits read the clean rows it wrote
+    assert len(mine) == _forwards(want) == len(want[3])
+    for (pos, logits), (p, _, wanted) in zip(mine, want[3]):
+        assert p == pos and np.abs(logits - wanted).max() < TOL
     record = eng.stats()["request_ring"][-1]
     assert (record["denoising_steps"], record["forwards"],
-            record["tokens_out"]) == (S, want[2], n)
+            record["tokens_out"]) == (S, _forwards(want), n)
 
 
 def test_rows_that_commit_and_rows_that_denoise_share_a_forward(served):
@@ -129,15 +146,127 @@ def test_rows_that_commit_and_rows_that_denoise_share_a_forward(served):
     assert max(live for live, _ in mixed) == 3
     ever = np.any([l for _, l, _ in rec.forwards], axis=0)
     assert ever.sum() == 3                   # the fourth slot: a dead row
-    rec.forwards.clear()
+    rec.clear()
     ticks = [t for t in eng.stats()["tick_ring"] if t.get("row_steps_live")]
     assert ticks
     for t in ticks:
         assert t["row_steps_live"] == (t["commit_row_steps"]
                                        + t["denoise_row_steps"])
-        assert t["tokens_committed"] == B * t["commit_row_steps"]
+        assert t["commit_row_steps"] == 0 and t["tokens_committed"] % B == 0
+        assert 0 <= t["fused_commit_row_steps"] <= t["denoise_row_steps"]
         assert t["row_steps"] == ENGINE["slots"] * ENGINE["chunk"]
         assert 0 < t["experts_touched"] <= t["experts_total"]
+
+
+def _ticks_since(eng, seq):
+    time.sleep(0.3)   # the tick that harvested the last chunk closes
+    return [t for t in eng.stats()["tick_ring"]
+            if t["seq"] > seq and t.get("row_steps_live")]
+
+
+def test_the_tick_ring_counts_what_both_halves_did(served):
+    """Three requests at once: no live row-forward decides nothing
+    (`commit_row_steps` 0), `tokens_committed` is the tokens OUTPUT,
+    `fused_commit_row_steps` one a block but a request's first, and
+    `attended_tokens` (the columns ONE CALL of the attention kernel
+    read, a forward's mean: both halves', halved) sums to a hand count
+    from the reference's own trace."""
+    cfg, eng, rec, logits_of = served
+    B, chunk = cfg.block_length, ENGINE["chunk"]
+    cases = [(9, 39, 1), (12, 36, 2), (7, 41, B)]
+    prompts = [[int(t) for t in _toks(T, seed=T + 90)] for T, _, _ in cases]
+    wants = [_want(cfg, logits_of, p, n, S)
+             for p, (_, n, S) in zip(prompts, cases)]
+    before = eng.stats()
+    futs = [eng.submit(p, n, denoising_steps=S)
+            for p, (_, n, S) in zip(prompts, cases)]
+    for f, want in zip(futs, wants):
+        _same(f.result(timeout=300), want)
+    ticks = _ticks_since(eng, before["ticks"])
+    for t in ticks:
+        assert t["commit_row_steps"] == 0
+        assert t["row_steps_live"] == t["denoise_row_steps"]
+        assert 0 <= t["fused_commit_row_steps"] <= t["denoise_row_steps"]
+    traces = [want[3] for want in wants]
+    firsts = [[pos for pos, s, _ in tr if s == 0] for tr in traces]
+    total = lambda k: sum(t[k] for t in ticks)               # noqa: E731
+    assert total("denoise_row_steps") == sum(len(tr) for tr in traces)
+    assert total("tokens_committed") == B * sum(len(f) for f in firsts)
+    # a block's clean rows ride in the NEXT block's first forward
+    fused = sum(len(f) - 1 for f in firsts)
+    assert 0 < fused == total("fused_commit_row_steps")
+    assert (eng.stats()["fused_commit_row_steps"]
+            - before["fused_commit_row_steps"]) == fused
+    # an open half attends `pos + B` columns, a commit half `pos`
+    columns = (sum(pos + B for tr in traces for pos, _, _ in tr)
+               + sum(sum(f[1:]) for f in firsts))
+    assert round(total("attended_tokens") * chunk * 2) == columns
+    rec.clear()
+
+
+def test_a_pending_commit_waits_over_a_chunks_end_and_an_admission(served):
+    """`S` = `chunk` forwards a block: every block is decided by a
+    chunk's LAST forward, so its commit waits in the row's state for
+    the next program.  Another request is admitted in between: the
+    first's commit rides in the first forward of the chunk that holds
+    the second's first forward, and both equal their references."""
+    cfg, eng, rec, logits_of = served
+    chunk = ENGINE["chunk"]
+    cases = [(8, 48, chunk), (6, 10, 1)]
+    prompts = [[int(t) for t in _toks(T, seed=T + 70)] for T, _, _ in cases]
+    wants = [_want(cfg, logits_of, p, n, S)
+             for p, (_, n, S) in zip(prompts, cases)]
+    rec.clear()
+    long_ = eng.submit(prompts[0], cases[0][1], denoising_steps=chunk)
+    while not eng.stats()["ticks"] or not rec.forwards:
+        time.sleep(0.001)   # the long one is being served
+    short = eng.submit(prompts[1], cases[1][1], denoising_steps=1)
+    _same(long_.result(timeout=300), wants[0])
+    _same(short.result(timeout=300), wants[1])
+    time.sleep(0.2)
+    live = np.stack([l for _, l, _ in rec.forwards])
+    riding = np.stack(rec.riding)
+    assert len(live) % chunk == 0
+    a = int(np.argmax(live[0]))                  # the long one's slot
+    b = int(np.argmax(live.any(axis=0) & (np.arange(live.shape[1]) != a)))
+    # over every chunk's end: the commit rides in the next one's first
+    starts = np.arange(chunk, len(live), chunk)
+    starts = starts[live[starts, a]]
+    assert len(starts) >= 4 and riding[starts, a].all()
+    assert not riding[starts + 1, a].any()       # ... and only there
+    # the chunk in which the second request's row first ran
+    born = int(np.argmax(live[:, b])) // chunk * chunk
+    assert born in starts and riding[born, a] and not riding[born, b]
+    rec.clear()
+
+
+def test_the_last_block_is_output_and_never_committed(served):
+    """A request alone: a commit rides once a block but the last, whose
+    rows nobody reads.  The flag it leaves in the dead row's state is
+    not the next tenant's: that row's first forward carries nothing,
+    and its answer is its reference's."""
+    cfg, eng, rec, logits_of = served
+    B, tenants = cfg.block_length, set()
+    for T, n, S in ((8, 16, 2), (5, 9, 1)):
+        prompt = [int(t) for t in _toks(T, seed=T + 60)]
+        want = _want(cfg, logits_of, prompt, n, S)
+        blocks = len({pos for pos, _, _ in want[3]})
+        rec.clear()
+        _same(eng.submit(prompt, n, denoising_steps=S).result(timeout=300),
+              want)
+        time.sleep(0.2)
+        mine = [(int(np.argmax(l)), int(p[l][0]), bool(r[l][0]))
+                for (p, l, _), r in zip(rec.forwards, rec.riding)
+                if l.sum() == 1]
+        tenants.add(mine[0][0])
+        assert len({slot for slot, _, _ in mine}) == 1 == len(tenants)
+        rode = [pos for _, pos, r in mine if r]
+        first = (T - T % B)
+        # at each block's first forward but the request's first: the
+        # last block (at `first + (blocks - 1) B`) is where the last rode
+        assert rode == [first + B * i for i in range(1, blocks)]
+        assert not mine[0][2] and sum(r.sum() for r in rec.riding) == len(rode)
+    rec.clear()
 
 
 def test_three_prompts_packed_into_one_admission(served):
@@ -156,7 +285,7 @@ def test_three_prompts_packed_into_one_admission(served):
     assert eng.stats()["prefill_calls"] == calls + 1
     assert {r["prefill_rows"] for r in
             eng.stats()["request_ring"][-3:]} == {3}
-    rec.forwards.clear()
+    rec.clear()
 
 
 def test_the_threshold_ends_blocks_early_and_the_device_says_so():
@@ -181,7 +310,7 @@ def test_the_threshold_ends_blocks_early_and_the_device_says_so():
                      for pos in range(8, 32, B)]
             early += sum(k < S for k in steps)
             late += sum(k > 1 for k in steps)
-            assert out.forwards == sum(steps) + len(steps) < 6 * (S + 1)
+            assert out.forwards == sum(steps) < 6 * S
         assert early and late          # it bites, and not everywhere
     finally:
         eng.shutdown()
@@ -304,10 +433,10 @@ def test_the_deployment_passes_the_bodys_fields():
                 return json.loads(r.read())
 
         one, four = post(denoising_steps=1), post(denoising_steps=4)
-        assert (one["forwards"], four["forwards"]) == ([4], [10])
+        assert (one["forwards"], four["forwards"]) == ([2], [8])
         for body, S in ((one, 1), (four, 4)):
             assert body["tokens"][0] == _want(cfg, logits_of, prompt, 8, S)[0]
-        assert post()["forwards"] == [10]       # the config's default
+        assert post()["forwards"] == [8]        # the config's default
     finally:
         serve.delete("blocks")
         rt.shutdown()

@@ -200,49 +200,93 @@ def test_unmask_is_the_references_choice(B, S):
 
 
 # ------------------------------------------------- generate, step by step
-def _loop(cfg, params, prompt, n, S, thr=0.9, block_size=8):
+def _cache(cfg, M, block_size, paged):
+    """An empty cache of `M` rows for ONE sequence: the dense view `[L,
+    1, M, KV * hd]`, or the folded pools with a scratch block behind and
+    the table that names the others in order."""
+    width = cfg.n_kv_heads * cfg.head_dim
+    if not paged:
+        k = jnp.zeros((cfg.n_layers, 1, M, width), cfg.dtype)
+        return (k, k), None
+    nb = M // block_size
+    k = jnp.zeros((cfg.n_layers, nb + 1, block_size, width), cfg.dtype)
+    return (k, k), jnp.arange(nb, dtype=jnp.int32)[None]
+
+
+def _rows(cache):
+    """A cache of `_cache` as rows `[L, M, KV * hd]` (k, v)."""
+    if cache[0].shape[1] == 1:
+        return tuple(np.asarray(c[:, 0]) for c in cache)
+    return tuple(np.asarray(c[:, :-1]).reshape(c.shape[0], -1, c.shape[-1])
+                 for c in cache)
+
+
+def _loop(cfg, params, prompt, n, S, thr=0.9, block_size=8, fused=False,
+          paged=False):
     """The engine's state machine by hand over `sdar.forward` (the
-    prompt's whole blocks into a dense cache) and `sdar.block_step` /
+    prompt's whole blocks into the cache) and `sdar.block_step` /
     `sdar.unmask`: -> (answer, decided_at, forwards, [(pos, step,
-    logits)] of the denoising forwards)."""
+    logits)] of the denoising forwards, the cache's rows).  `fused`
+    False: a block decided is COMMITTED by a forward of its own, whose
+    input has no mask.  True: it is output at once and its tokens ride
+    as the commit half of the next forward (`block_step(commit=)`); the
+    last block's never do.  `paged`: through the pools and the paged
+    kernels in the interpreter."""
     B, T = cfg.block_length, len(prompt)
     end = -(-(T + n) // B) * B
     M = -(-end // block_size) * block_size
-    width = cfg.n_kv_heads * cfg.head_dim
-    k = jnp.zeros((cfg.n_layers, 1, M, width), cfg.dtype)
-    v = jnp.zeros_like(k)
+    (k, v), tables = _cache(cfg, M, block_size, paged)
     pos = T - T % B
     if pos:
         _, (ks, vs) = sdar.forward(cfg, params,
                                    jnp.asarray(prompt[:pos])[None],
                                    logits=False)
-        k, v = k.at[:, :, :pos].set(ks), v.at[:, :, :pos].set(vs)
+        if paged:
+            k, v = (c.at[:, :-1].set(jnp.pad(
+                new[:, 0], ((0, 0), (0, M - pos), (0, 0))).reshape(
+                    c[:, :-1].shape)) for c, new in ((k, ks), (v, vs)))
+        else:
+            k, v = k.at[:, :, :pos].set(ks), v.at[:, :, :pos].set(vs)
     blk = np.array(list(prompt[pos:]) + [0] * (B - T % B))
     und, dec, s = np.arange(B) >= T % B, np.full(B, -1), 0
-    step = jax.jit(lambda x, cache, p: sdar.block_step(
-        cfg, params, x, cache, p))
+    held, pend = np.zeros(B, int), False
+    kw = dict(tables=tables, interpret=paged)
+    plain = jax.jit(lambda x, cache, p: sdar.block_step(
+        cfg, params, x, cache, p, **kw))
+    both = jax.jit(lambda x, cache, p, held, on: sdar.block_step(
+        cfg, params, x, cache, p, commit=(held, on), **kw))
     out, decided, trace, forwards = [], [], [], 0
     while pos < end:
-        x = np.where(und, cfg.mask_id, blk)
-        logits, (k, v), _ = step(jnp.asarray(x, jnp.int32)[None], (k, v),
-                                 jnp.asarray([pos], jnp.int32))
+        x = jnp.asarray(np.where(und, cfg.mask_id, blk), jnp.int32)[None]
+        at = jnp.asarray([pos], jnp.int32)
+        if fused:
+            logits, (k, v), _ = both(x, (k, v), at,
+                                     jnp.asarray(held, jnp.int32)[None],
+                                     jnp.asarray([pend]))
+            pend = False
+        else:
+            logits, (k, v), _ = plain(x, (k, v), at)
         forwards += 1
-        if not und.any():
-            out += [int(t) for t in blk]
-            decided += [int(d) for d in dec]
-            pos += B
-            blk, und, dec, s = (np.zeros(B, int), np.ones(B, bool),
-                                np.full(B, -1), 0)
-            continue
-        trace.append((pos, s, np.asarray(logits[0])))
-        nb, nu, nd = sdar.unmask(
-            logits, jnp.asarray(blk, jnp.int32)[None], jnp.asarray(und)[None],
-            jnp.asarray(dec, jnp.int32)[None], jnp.asarray([s], jnp.int32),
-            jnp.asarray([S], jnp.int32), jnp.asarray([thr], jnp.float32))
-        blk, und, dec, s = (np.asarray(nb[0]), np.asarray(nu[0]),
-                            np.asarray(nd[0]), s + 1)
+        if und.any():
+            trace.append((pos, s, np.asarray(logits[0])))
+            nb, nu, nd = sdar.unmask(
+                logits, jnp.asarray(blk, jnp.int32)[None],
+                jnp.asarray(und)[None], jnp.asarray(dec, jnp.int32)[None],
+                jnp.asarray([s], jnp.int32), jnp.asarray([S], jnp.int32),
+                jnp.asarray([thr], jnp.float32))
+            blk, und, dec, s = (np.asarray(nb[0]), np.asarray(nu[0]),
+                                np.asarray(nd[0]), s + 1)
+            if und.any() or not fused:
+                continue
+        out += [int(t) for t in blk]
+        decided += [int(d) for d in dec]
+        pos += B
+        held, pend = blk, True
+        blk, und, dec, s = (np.zeros(B, int), np.ones(B, bool),
+                            np.full(B, -1), 0)
     lo = T % B
-    return out[lo:lo + n], decided[lo:lo + n], forwards, trace
+    return (out[lo:lo + n], decided[lo:lo + n], forwards, trace,
+            _rows((k, v)))
 
 
 @pytest.mark.parametrize("B,S,T,n", [(4, 1, 8, 8), (4, 2, 6, 7),
@@ -260,6 +304,84 @@ def test_block_steps_equal_the_references_generate(B, S, T, n):
     assert [(p, s) for p, s, _ in got[3]] == [(p, s) for p, s, _ in want[3]]
     for (_, _, a), (_, _, b) in zip(got[3], want[3]):
         assert np.abs(a - b).max() < TOL
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["view", "paged"])
+@pytest.mark.parametrize("B,S,T,n", [(4, 1, 8, 12), (4, 2, 6, 11),
+                                     (4, 4, 5, 9), (8, 2, 16, 16)])
+def test_a_commit_that_rides_is_a_commit_forward_of_its_own(B, S, T, n,
+                                                           paged):
+    """A block's clean rows written by the NEXT block's first forward
+    (`block_step(commit=)`) against a commit forward followed by a
+    separate first forward: every denoising forward's logits (each next
+    block's first reads the rows the commit half wrote in the same
+    layers) and the rows left in the cache within the tolerance, the
+    same tokens at the same steps, one forward a block fewer; `S` of 1,
+    2 and `B`, `T mod B` of 0 and not, the view and the paged kernels in
+    the interpreter.  The LAST block is never committed: its rows stay
+    as a forward with masks in its input wrote them."""
+    cfg, params = model(B)
+    prompt = [int(t) for t in _toks(T, seed=T + n + S)]
+    two = _loop(cfg, params, prompt, n, S, paged=paged)
+    one = _loop(cfg, params, prompt, n, S, fused=True, paged=paged)
+    assert one[:2] == two[:2]
+    blocks = len({pos for pos, _, _ in two[3]})
+    assert one[2] == two[2] - blocks == len(one[3]) and blocks >= 2
+    assert [(p, s) for p, s, _ in one[3]] == [(p, s) for p, s, _ in two[3]]
+    for (_, _, a), (_, _, b) in zip(one[3], two[3]):
+        assert np.abs(a - b).max() < TOL
+    last = max(pos for pos, _, _ in two[3])
+    for a, b in zip(one[4], two[4]):
+        assert np.abs(a[:, :last] - b[:, :last]).max() < TOL
+        assert np.abs(a[:, last:last + B] - b[:, last:last + B]).max() > TOL
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["view", "paged"])
+def test_a_commit_half_that_does_not_ride_writes_nothing(paged):
+    """Three slots' forward with a commit half a slot of which NONE
+    rides (one slot at position 0, with no block behind it; one dead):
+    every row of the cache but the live rows' open blocks is BIT-equal
+    before and after, and the logits are the narrow forward's."""
+    B, bs, M = 4, 8, 24
+    cfg, params = model(B)
+    width = cfg.n_kv_heads * cfg.head_dim
+    rng = np.random.default_rng(7)
+    if paged:
+        shape = (cfg.n_layers, 3 * M // bs + 1, bs, width)
+        tables = jnp.arange(3 * M // bs, dtype=jnp.int32).reshape(3, -1)
+    else:
+        shape, tables = (cfg.n_layers, 3, M, width), None
+    cache = tuple(jnp.asarray(rng.normal(size=shape), cfg.dtype)
+                  for _ in range(2))
+    pos = jnp.asarray([8, 0, 12], jnp.int32)
+    live = jnp.asarray([True, True, False])
+    x = jnp.asarray(rng.integers(1, 200, size=(3, B)), jnp.int32)
+    held = jnp.asarray(rng.integers(1, 200, size=(3, B)), jnp.int32)
+    kw = dict(tables=tables, live=live, interpret=paged)
+    narrow, _, _ = sdar.block_step(cfg, params, x, cache, pos, **kw)
+    logits, after, _ = sdar.block_step(
+        cfg, params, x, cache, pos, commit=(held, jnp.zeros((3,), bool)),
+        **kw)
+    assert np.abs(np.asarray(logits) - np.asarray(narrow))[:2].max() < TOL
+    for was, now in zip(cache, after):
+        was, now = (np.asarray(c).reshape(cfg.n_layers, -1, width)[:, :3 * M]
+                    .reshape(cfg.n_layers, 3, M, width) for c in (was, now))
+        wrote = np.zeros((3, M), bool)
+        wrote[0, 8:12] = wrote[1, 0:4] = True
+        assert np.array_equal(was[:, ~wrote], now[:, ~wrote])
+        assert not np.array_equal(was[:, wrote], now[:, wrote])
+    # ... and one that rides writes its block's rows, and only those
+    _, rode, _ = sdar.block_step(
+        cfg, params, x, cache, pos,
+        commit=(held, jnp.asarray([True, False, False])), **kw)
+    for now, then in zip(after, rode):
+        now, then = (np.asarray(c).reshape(cfg.n_layers, -1, width)[:, :3 * M]
+                     .reshape(cfg.n_layers, 3, M, width) for c in (now, then))
+        behind = np.zeros((3, M), bool)
+        behind[0, 4:8] = True
+        rest = ~behind & ~wrote
+        assert np.array_equal(now[:, rest], then[:, rest])
+        assert not np.array_equal(now[:, behind], then[:, behind])
 
 
 def test_replay_gives_generates_logits_at_every_block_and_step():
