@@ -92,8 +92,10 @@ logger = logging.getLogger(__name__)
 
 
 # finished requests kept in stats()["request_ring"]: bounded so that a
-# pickled stats() stays small on the 2 s health-check path
-REQUEST_RING = 512
+# pickled stats() stays small on the 2 s health-check path (64 KiB with
+# every ring and account at its widest: 90 bytes a record here.  512
+# until the launches' stamp took 7 KB of that room)
+REQUEST_RING = 448
 
 
 # requests a tick admits at most: the cap keeps one tick's admission
@@ -109,12 +111,23 @@ PACK_MIN_TOKENS = 128
 # found the chunk FINISHED: the device may have run dry (`starved`)
 STARVED_WAIT_S = 1e-3
 
+# a launch (the host's call that hands a program to the device) that
+# took longer than this did not return when the program was queued: the
+# device's queue was full and the host BLOCKED inside the call until a
+# program ahead of it ended (`launch_blocked_s`).  That is the device's
+# time, not the host's.  Set from the chip: over 3 x the slowest call of
+# a STARVED tick, whose queue is short by construction (2.19 ms: a
+# prefill's call, which carries its host-made arrays to the device, takes
+# 1.2-4.9 ms unblocked and a chunk's 0.3-1.5, whatever the program;
+# docs/observability.md has the distribution by program family)
+LAUNCH_BLOCKED_S = 8e-3
+
 # a tick is a STALL, kept whole with its neighbours in stats()["stalls"]
 # (the last STALLS_KEPT), when it compiled nothing and its wall is over
 # both of these: a floor, and a multiple of the tick EMA before it
 STALL_MIN_S = 1.0
 STALL_FACTOR = 4.0
-STALLS_KEPT = 8
+STALLS_KEPT = 4
 
 # the phases of a tick, where the work happens: each is an
 # `engine.<name>` span of a profiler session AND `<name>_s` in the
@@ -124,6 +137,8 @@ PHASES = ("wait", "plan", "prefill", "dispatch", "device_wait",
 # a phase's key in the tick record (one string object each: a pickle
 # writes a key it has met as a reference)
 _PHASE_KEYS = tuple((p, p + "_s") for p in PHASES)
+# what `_Phase` sums over a tick: the phases, and the launches' stamp
+_TICK_SUMS = PHASES + ("launch",)
 # a starved tick's fields: the host's gap before its first program,
 # and the part of it that lies before the tick (of the rest, `plan_s`
 # is the tick's plan and what is left its packing up to the launch)
@@ -131,6 +146,10 @@ GAPS = ("host_gap_s", "gap_harvest_host_s")
 # what a tick that prefilled adds to stats()'s cumulative counters
 PREFILLED = ("prefill_calls", "prefill_rows", "prefill_tokens",
              "prefill_padded_tokens")
+# a tick's launches, from the stamp in `_launch`: how many, all the time
+# inside the calls, and the whole time of those that blocked.  PARTS of
+# `prefill_s` / `dispatch_s`, not phases beside them
+LAUNCHED = ("launches", "launch_s", "launch_blocked_s")
 
 # stats()["tick_account"]: the tick records summed by the wall second a
 # tick began in, the last ACCOUNT_SECONDS seconds that had a tick.  A
@@ -144,9 +163,29 @@ _ACCOUNT_COUNTS = ("starved", "stalled", "row_steps",
                    "row_steps_live") + PREFILLED
 ACCOUNT_FIELDS = ("sec", "ticks") + tuple(
     k[:-2] + "_us" for k in _ACCOUNT_TIMES) + _ACCOUNT_COUNTS
+# ... and the launches' three columns AFTER them: a reader zips a row's
+# columns by name, and a row of before is a prefix of a row of now.
+# (A tuple of their own because the benchmark's test of the account's
+# readers holds ACCOUNT_FIELDS to what it was)
+ACCOUNT_LAUNCH_FIELDS = ("launches", "launch_us", "launch_blocked_us")
+_ACCOUNT_KEYS = _ACCOUNT_TIMES + _ACCOUNT_COUNTS + LAUNCHED
+_ACCOUNT_COLUMNS = ACCOUNT_FIELDS + ACCOUNT_LAUNCH_FIELDS
 # the one column no tick carries under its name: a starved tick's plan
 # is its `plan_s`
 _GAP_PLAN = ACCOUNT_FIELDS.index("gap_plan_us")
+
+# stats()["launch_account"]: the launches since the loop began, summed
+# by the program's name and by whether a profiler session was recording
+# (`traced` 1: the launches of the LATEST session alone).  `rows` ..
+# `live_tokens` are what the programs held, as the callers of `_launch`
+# say it (`padded_tokens` their `N`); `blocked` counts the launches
+# over LAUNCH_BLOCKED_S.  A device trace prints a program as
+# `jit_<program>(<hash>)`: its seconds there, against these counts
+LAUNCH_HELD = ("rows", "tokens", "N", "attended_pairs", "rows_live",
+               "live_tokens")
+LAUNCH_ACCOUNT_FIELDS = (
+    "program", "traced", "launches", "rows", "tokens", "padded_tokens",
+    "attended_pairs", "rows_live", "live_tokens", "launch_us", "blocked")
 
 
 def _cpu_now() -> tuple:
@@ -156,8 +195,8 @@ def _cpu_now() -> tuple:
 
 def _account_row(sums: List[float]) -> tuple:
     """A second's sums as its row: the times in whole microseconds."""
-    n = 2 + len(_ACCOUNT_TIMES)
-    return (*sums[:2], *(round(v * 1e6) for v in sums[2:n]), *sums[n:])
+    return tuple(round(v * 1e6) if f.endswith("_us") else v
+                 for f, v in zip(_ACCOUNT_COLUMNS, sums))
 
 
 class _Phase:
@@ -166,20 +205,39 @@ class _Phase:
     time to the current tick's `<name>_s`, so a span and a field cannot
     disagree.  `t_end` is the stamp it left at."""
 
-    __slots__ = ("_sums", "_name", "_span", "_t0", "t_end")
+    __slots__ = ("_sums", "_name", "_span", "t0", "t_end")
 
     def __init__(self, sums: Dict[str, float], name: str, span):
         self._sums, self._name, self._span = sums, name, span
 
     def __enter__(self):
         self._span.__enter__()
-        self._t0 = _time.perf_counter()
+        self.t0 = _time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.t_end = _time.perf_counter()
-        self._sums[self._name] += self.t_end - self._t0
+        self._sums[self._name] += self.t_end - self.t0
         return self._span.__exit__(*exc)
+
+
+class _Launched(NamedTuple):
+    """One program handed to the device, as `_launch` stamped it."""
+    program: str    # the jitted function's name
+    traced: bool    # a profiler session was recording
+    t0: float       # the call's start (perf_counter)
+    took_s: float   # ... and how long the call took to return
+    blocked: bool   # over LAUNCH_BLOCKED_S, and compiled nothing
+    held: Dict[str, int]  # what the program held (LAUNCH_HELD)
+
+
+def attended_pairs(parts) -> int:
+    """The (query, key) pairs a causal mask needs for the `(lo, hi)`
+    parts of sequences one program holds: a part's `n = hi - lo`
+    queries each see the `lo` keys before the part, and the part's own
+    keys up to itself.  Model-free: a reader applies heads and widths."""
+    return sum((hi - lo) * lo + (hi - lo) * (hi - lo + 1) // 2
+               for lo, hi in parts)
 
 
 def _next_pow2(n: int) -> int:
@@ -554,13 +612,17 @@ class LlamaEngine:
         self._tick_ema_s = 0.0
         # the tick being accounted: its phases' sums (`_Phase` adds;
         # `wait` is added by the loop BEFORE the tick it is carried
-        # into), the stamp of its first program, the programs it
-        # launched and how many of them compiled.  Closed by `_close`
-        self._phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0)
-        self._t_first: Optional[float] = None
-        self._launched: List[str] = []
-        self._launched_before: List[str] = []
+        # into; `launch`: all the time inside `_launch`'s calls), the
+        # programs it launched, each as stamped, and how many of them
+        # compiled.  Closed by `_close`
+        self._phase_s: Dict[str, float] = dict.fromkeys(_TICK_SUMS, 0)
+        self._launches: List[_Launched] = []
+        self._launched_before: List[str] = []  # the tick before's names
         self._compiles = 0
+        # (program, traced) -> its launches summed (`launch_account`),
+        # and whether the last launch folded in was a traced one
+        self._launch_account: Dict[tuple, List[float]] = {}
+        self._traced_last = False
         # where the host's gap before the next tick starts: the return
         # of this tick's wait for the device (its end, if it had none)
         self._t_gap_from: Optional[float] = None
@@ -879,8 +941,12 @@ class LlamaEngine:
                 "draining": 1.0 if self._draining else 0.0,
                 # the tick records summed by wall second (columnar: the
                 # last ACCOUNT_SECONDS seconds that had a tick)
-                "tick_account": {"fields": ACCOUNT_FIELDS,
+                "tick_account": {"fields": _ACCOUNT_COLUMNS,
                                  "rows": self._account_rows()},
+                # the launches summed by program and by whether a
+                # profiler session recorded them (columnar, ints)
+                "launch_account": {"fields": LAUNCH_ACCOUNT_FIELDS,
+                                   "rows": self._launch_account_rows()},
             }
 
     def shutdown(self):
@@ -1307,12 +1373,12 @@ class LlamaEngine:
             at += nq * align
         return tokens, posn, tables, last, slots, pos0, stop0
 
-    def _launch_packed(self, N: int, arrays: tuple) -> None:
-        """The packed program of size `N` on `arrays`, the cache and
-        the slots' device state rebound to what it returns."""
+    def _launch_packed(self, arrays: tuple, **held) -> None:
+        """The packed program of size `held["N"]` on `arrays`, the cache
+        and the slots' device state rebound to what it returns."""
         out = self._launch(
-            self._prefill_packed_for(N), self.params, *self._cache,
-            *arrays, self._pos, self._tok, self._stop)
+            self._prefill_packed_for(held["N"]), self.params, *self._cache,
+            *arrays, self._pos, self._tok, self._stop, **held)
         self._cache = tuple(out[:-3])
         self._pos, self._tok, self._stop = out[-3:]
 
@@ -1323,9 +1389,12 @@ class LlamaEngine:
         slots' `pos` / `tok` / `stop` set.  An empty pack is the
         warm-up: padding only, into the scratch block."""
         real = sum(len(plan.prompt) for plan in pack)
-        with self._phase("prefill", N=N, rows=len(pack), tokens=real):
+        held = dict(N=N, rows=len(pack), tokens=real,
+                    attended_pairs=attended_pairs(
+                        (0, len(plan.prompt)) for plan in pack))
+        with self._phase("prefill", **held):
             self._admitting(pack)
-            self._launch_packed(N, self._pack_arrays(N, pack))
+            self._launch_packed(self._pack_arrays(N, pack), **held)
             self._prefilled(pack)
         if pack:
             self._prefill_calls += 1
@@ -1342,9 +1411,12 @@ class LlamaEngine:
         is written nowhere."""
         plans = [part.plan for part in pack]
         real = sum(part.hi - part.lo for part in pack)
-        with self._phase("prefill", N=N, rows=len(pack), tokens=real):
+        held = dict(N=N, rows=len(pack), tokens=real,
+                    attended_pairs=attended_pairs(
+                        (part.lo, part.hi) for part in pack))
+        with self._phase("prefill", **held):
             self._admitting(plans)
-            self._launch_packed(N, self._suffix_arrays(N, pack))
+            self._launch_packed(self._suffix_arrays(N, pack), **held)
             self._prefilled(plans)
         if pack:
             self._prefill_calls += 1
@@ -1387,8 +1459,11 @@ class LlamaEngine:
         # real positions correct, the pad tail's garbage KV is masked
         # by the starting pos and overwritten as decoding advances)
         bucket = min(_next_pow2(S), self.max_len - 1)
+        held = dict(N=bucket, rows=1, tokens=S,
+                    attended_pairs=attended_pairs([(lo, hi)]))
         with self._phase("prefill", bucket=bucket, slot=slot,
-                         hit_blocks=len(before), chunk=f"{i + 1}/{n}"):
+                         hit_blocks=len(before), chunk=f"{i + 1}/{n}",
+                         **held):
             self._admitting([plan])
             p_bucket = _next_pow2(len(before))
             blk_ids = jnp.asarray(
@@ -1401,7 +1476,7 @@ class LlamaEngine:
             logits, *kv = self._launch(
                 self._suffix_prefill_for(bucket, p_bucket),
                 self.params, *self._cache, suffix, blk_ids,
-                jnp.asarray(lo, jnp.int32),
+                jnp.asarray(lo, jnp.int32), **held,
             )
             # first generated token comes from the LAST REAL prompt
             # position; it STAYS on device — the next chunk emits it in
@@ -1421,6 +1496,8 @@ class LlamaEngine:
                 jnp.asarray(hi, jnp.int32),
                 tok0, self._pos, self._tok,
                 jnp.asarray(req["stop"], jnp.int32), self._stop,
+                # (the write attends nothing)
+                N=bucket, rows=1, tokens=S,
             )
             self._cache = tuple(out[:-3])
             self._pos, self._tok, self._stop = out[-3:]
@@ -1450,15 +1527,17 @@ class LlamaEngine:
             table[:len(blocks)] = blocks
             slot = plan.slot
         plans = [] if plan is None else [plan]
-        with self._phase("prefill", N=N, slot=slot, tokens=S,
-                         chunk=f"{i + 1}/{n}"):
+        held = dict(N=N, rows=len(plans), tokens=S,
+                    attended_pairs=attended_pairs([(lo, hi)]))
+        with self._phase("prefill", slot=slot, chunk=f"{i + 1}/{n}",
+                         **held):
             self._admitting(plans)
             out = self._launch(
                 self._chunk_prefill_for(N), self.params, *self._cache,
                 tokens, table, i32(slot), i32(lo), i32(S),
                 i32(slot if last else self.slots), i32(hi),
                 i32(plan.req["stop"] if last else 0),
-                self._pos, self._tok, self._stop)
+                self._pos, self._tok, self._stop, **held)
             self._cache = tuple(out[:-3])
             self._pos, self._tok, self._stop = out[-3:]
             self._prefilled(plans)
@@ -1555,18 +1634,27 @@ class LlamaEngine:
         return _Phase(self._phase_s, name,
                       self._span("engine." + name, **stats))
 
-    def _launch(self, fn, *args):
+    def _launch(self, fn, *args, **held):
         """Hands a program to the device (async) and returns what it
-        returns.  The tick's first launch ends the host's gap before it
+        returns, under ONE stamp: the span `engine.launch` (inside the
+        open `engine.prefill` / `engine.dispatch`) with the program's
+        name and `held`, what the caller says the program holds
+        (LAUNCH_HELD), and a `_Launched` in the tick's list.  The
+        tick's first launch ends the host's gap before it
         (`host_gap_s`), the names are what a stall lists as in flight,
         and a program that had to be traced and compiled here is
-        counted in the tick's `compiles`."""
-        if self._t_first is None:
-            self._t_first = _time.perf_counter()
-        self._launched.append(fn.__name__)
+        counted in the tick's `compiles` (its call is never `blocked`:
+        compiling is the host's own time)."""
         known = fn._cache_size()
-        out = fn(*args)
-        self._compiles += fn._cache_size() - known
+        with _Phase(self._phase_s, "launch", self._span(
+                "engine.launch", program=fn.__name__, **held)) as call:
+            out = fn(*args)
+        took = call.t_end - call.t0
+        compiled = fn._cache_size() - known
+        self._compiles += compiled
+        self._launches.append(_Launched(
+            fn.__name__, self._span.is_enabled(), call.t0, took,
+            took > LAUNCH_BLOCKED_S and not compiled, held))
         return out
 
     def _tick(self, admissions: List[tuple], t_wall: float) -> None:
@@ -1632,17 +1720,6 @@ class LlamaEngine:
                             table[slot, :len(blocks)] = blocks
                     tables = (jnp.asarray(table),)
                 self._last_gather_blocks = W if self._has_blocks else 0
-                out = self._launch(
-                    self._chunk_step_for(W if self._has_blocks else 0),
-                    self.params, *self._cache, *tables,
-                    self._tok, self._pos, self._stop)
-                self._cache = tuple(out[:-3])
-                self._tok, self._pos, toks = out[-3:]
-                if self._decode_kernel == "pallas":
-                    self._decode_kernel_dispatches += 1
-                else:
-                    self._decode_gather_dispatches += 1
-                self._chunk_seq += 1
                 row_steps = self.slots * self.chunk
                 with self._lock:
                     # the host's mirror of the device's `pos`: a row
@@ -1658,6 +1735,20 @@ class LlamaEngine:
                             contexts.append(req["pos_host"] + 1)
                         rows_flushed += end - req["pos_host"] == self.chunk
                         req["pos_host"] = end
+                # (a chunk is no prefill: it pads nothing, `N` 0; its
+                # live rows and the tokens they attend at its first step)
+                out = self._launch(
+                    self._chunk_step_for(W if self._has_blocks else 0),
+                    self.params, *self._cache, *tables,
+                    self._tok, self._pos, self._stop,
+                    N=0, rows_live=rows_live, live_tokens=sum(contexts))
+                self._cache = tuple(out[:-3])
+                self._tok, self._pos, toks = out[-3:]
+                if self._decode_kernel == "pallas":
+                    self._decode_kernel_dispatches += 1
+                else:
+                    self._decode_gather_dispatches += 1
+                self._chunk_seq += 1
         # OVERLAP: harvest the PREVIOUS chunk's tokens while the
         # current chunk computes — the device->host read is round-trip
         # latency (measured at ~half the synced chunk wall time on an
@@ -1737,7 +1828,7 @@ class LlamaEngine:
         sequence is live, which is idling for want of traffic."""
         t_end = _time.perf_counter()
         tick_s = t_end - t0
-        ph = self._phase_s
+        ph, launches = self._phase_s, self._launches
         cpu = _cpu_now()
         rec.update(
             tick_s=tick_s,
@@ -1746,14 +1837,17 @@ class LlamaEngine:
             harvest_s=ph["device_wait"] + ph["harvest_host"],
             cpu_s=cpu[0] - self._cpu_mark[0],
             proc_cpu_s=cpu[1] - self._cpu_mark[1],
-            starved=(t_read is not None and self._t_first is not None
+            starved=(t_read is not None and bool(launches)
                      and ph["device_wait"] < STARVED_WAIT_S),
         )
         for name, key in _PHASE_KEYS:
             rec[key] = ph[name]
+        rec.update(zip(LAUNCHED, (
+            len(launches), ph["launch"],
+            sum(call.took_s for call in launches if call.blocked))))
         if rec["starved"] and self._t_gap_from is not None:
             before = t0 - ph["wait"] - self._t_gap_from
-            rec.update(zip(GAPS, (before + self._t_first - t0, before)))
+            rec.update(zip(GAPS, (before + launches[0].t0 - t0, before)))
         if self._compiles:
             rec["compiles"] = self._compiles
         stalled = (not self._compiles and tick_s > STALL_MIN_S
@@ -1782,6 +1876,7 @@ class LlamaEngine:
             before = self._tick_ring[-1] if self._tick_ring else None
             self._tick_ring.append(rec)
             self._account_add(rec)
+            self._launch_account_add(launches)
             if self._stalls and self._stalls[-1]["after"] is None:
                 # the tick AFTER a stall: did it wait as usual (the
                 # device was late) or find its chunk done (it was not)
@@ -1789,7 +1884,8 @@ class LlamaEngine:
             if stalled:
                 self._stalls.append({
                     "before": before, "tick": rec, "after": None,
-                    "in_flight": self._launched_before + self._launched})
+                    "in_flight": self._launched_before + [
+                        call.program for call in launches]})
             self._stats_snapshot = self._stats_locked()  # fresh
         self._new_tick(cpu, t_read if t_read is not None else t_end)
 
@@ -1797,9 +1893,9 @@ class LlamaEngine:
         """What the next tick's account starts from."""
         # (0, not 0.0: a phase the tick never entered is an int in its
         # record, which is 2 bytes of a pickle and not 9)
-        self._phase_s = dict.fromkeys(PHASES, 0)
-        self._t_first = None
-        self._launched_before, self._launched = self._launched, []
+        self._phase_s = dict.fromkeys(_TICK_SUMS, 0)
+        self._launched_before = [call.program for call in self._launches]
+        self._launches = []
         self._compiles = 0
         self._cpu_mark = cpu
         self._t_gap_from = t_gap_from
@@ -1813,12 +1909,35 @@ class LlamaEngine:
             if row is not None:
                 self._account.append(_account_row(row))
             row = self._account_open = [sec] + [0] * (
-                len(ACCOUNT_FIELDS) - 1)
+                len(_ACCOUNT_COLUMNS) - 1)
         row[1] += 1
-        for i, k in enumerate(_ACCOUNT_TIMES + _ACCOUNT_COUNTS, 2):
+        for i, k in enumerate(_ACCOUNT_KEYS, 2):
             row[i] += rec.get(k, 0)
         if "host_gap_s" in rec:
             row[_GAP_PLAN] += rec["plan_s"]
+
+    def _launch_account_add(self, launches: List[_Launched]) -> None:
+        """Adds a closed tick's launches to the account by program.  The
+        first launch a NEW profiler session records drops the rows of
+        the session before: the traced rows are one session's."""
+        acct = self._launch_account
+        for call in launches:
+            if call.traced and not self._traced_last:
+                for key in [k for k in acct if k[1]]:
+                    del acct[key]
+            self._traced_last = call.traced
+            row = acct.setdefault((call.program, call.traced),
+                                  [0] * (len(LAUNCH_HELD) + 3))
+            row[0] += 1
+            for i, k in enumerate(LAUNCH_HELD, 1):
+                row[i] += call.held.get(k, 0)
+            row[-2] += call.took_s
+            row[-1] += call.blocked
+
+    def _launch_account_rows(self) -> List[tuple]:
+        return [(program, int(traced), *row[:-2], round(row[-2] * 1e6),
+                 row[-1])
+                for (program, traced), row in self._launch_account.items()]
 
     def _account_rows(self) -> List[tuple]:
         rows = list(self._account)
@@ -1829,7 +1948,7 @@ class LlamaEngine:
     def _loop(self):
         # the account starts here, on the engine's own thread (the
         # warm-up ran its programs on the caller's)
-        self._launched = []
+        self._launches = []
         self._new_tick(_cpu_now(), None)
         while True:
             with self._wake:

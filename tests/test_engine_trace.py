@@ -9,6 +9,7 @@ import pickle
 import re
 import threading
 import time
+from concurrent.futures import Future
 
 import numpy as np
 import pytest
@@ -121,7 +122,7 @@ def test_shed_and_refused_requests_are_recorded_with_their_status(engine):
 
 
 def test_the_ring_is_bounded_and_a_pickled_stats_stays_small(engine):
-    assert llm_engine.REQUEST_RING == 512
+    assert llm_engine.REQUEST_RING == 448
     _serve(engine, 16, new=8)  # more ticks than their ring holds
     ok = dict(engine.stats()["request_ring"][-1])
     # the widest records there are: every phase a float
@@ -134,7 +135,7 @@ def test_the_ring_is_bounded_and_a_pickled_stats_stays_small(engine):
     # ... the tick ring at its default, every tick starved (its widest
     # record); the account's 96 seconds, each as busy as a closed cell
     # on the chip shows one (a second of ticks, most of it waiting for
-    # the device); eight stalls, each three whole ticks
+    # the device); the stalls kept, each three whole ticks
     with engine._lock:
         ticks = [dict(t) for t in engine._tick_ring if "host_gap_s" in t]
         assert len(engine._tick_ring) == 32 and len(ticks) >= 3
@@ -147,16 +148,35 @@ def test_the_ring_is_bounded_and_a_pickled_stats_stays_small(engine):
                               "prefill_packed_n512", "decode_chunk_w128"]})
         busy = (9, 1000123, 0, 21034, 33012, 4123, 850321, 91234, 151234,
                 1212345, 31234, 2123, 9123, 3, 0, 9216, 8000, 4, 40, 30000,
-                36864)
+                36864, 13, 35123, 21045)
         for i in range(200):
             engine._account.append((1790000000 + i,) + busy)
+        # the launch account at its widest: 25 program names, each with
+        # and without a profiler session, every count as large as a day
+        # of a closed cell on the chip makes it
+        for i in range(25):
+            for traced in (False, True):
+                engine._launch_account[
+                    f"suffix_prefill_packed_n{2 ** (i % 12) * 8}_{i}",
+                    traced] = [912345, 1212345, 2123456789, 3123456789,
+                               4123456789012, 51234567, 61234567890,
+                               7123.456789, 81234]
     s = engine.stats()
-    assert len(s["request_ring"]) == 512
+    launched = s["launch_account"]
+    assert tuple(launched["fields"]) == llm_engine.LAUNCH_ACCOUNT_FIELDS
+    assert len(launched["rows"]) >= 50
+    assert all(len(r) == len(launched["fields"])
+               and all(isinstance(v, int) for v in r[1:])
+               for r in launched["rows"])
+    assert len(pickle.dumps(launched)) < 6 * 1024
+    assert len(s["request_ring"]) == 448
     assert s["request_ring"][-1]["seq"] == s["finished_total"] == 56
     acct = s["tick_account"]
     assert len(acct["rows"]) == llm_engine.ACCOUNT_SECONDS == 96
     assert all(len(r) == len(acct["fields"]) for r in acct["rows"])
-    assert len(s["stalls"]) == llm_engine.STALLS_KEPT == 8
+    assert len(s["stalls"]) == llm_engine.STALLS_KEPT == 4
+    # (63.6 KB here: the request ring 40.0, the account 8.3, the tick
+    # ring 6.9, the launch account 4.8, the stalls 3.1)
     assert len(pickle.dumps(s)) < 64 * 1024
 
 
@@ -340,6 +360,10 @@ def test_a_tick_that_waits_for_its_chunk_is_not_starved(engine, reads):
     assert row["starved"] == row["host_gap_us"] == 0
 
 
+# the account's columns: PR 37's, then the launches' three
+ACCOUNT_COLUMNS = llm_engine.ACCOUNT_FIELDS + llm_engine.ACCOUNT_LAUNCH_FIELDS
+
+
 def _account_total(stats):
     """The account's columns summed over its seconds."""
     acct = stats["tick_account"]
@@ -348,10 +372,10 @@ def _account_total(stats):
 
 def _summed(records):
     """What the account holds of `records`: a column a tick field."""
-    out = dict.fromkeys(llm_engine.ACCOUNT_FIELDS, 0)
+    out = dict.fromkeys(ACCOUNT_COLUMNS, 0)
     for t in records:
         out["ticks"] += 1
-        for col in llm_engine.ACCOUNT_FIELDS[2:]:
+        for col in ACCOUNT_COLUMNS[2:]:
             key = col[:-3] + "_s" if col.endswith("_us") else col
             scale = 1e6 if col.endswith("_us") else 1
             out[col] += t.get(key, 0) * scale
@@ -376,7 +400,7 @@ def test_the_account_is_the_ring_summed_by_the_second(model, monkeypatch):
     finally:
         eng.shutdown()
     fields, rows = s["tick_account"]["fields"], s["tick_account"]["rows"]
-    assert tuple(fields) == llm_engine.ACCOUNT_FIELDS
+    assert tuple(fields) == ACCOUNT_COLUMNS
     secs = [r[0] for r in rows]
     assert secs == sorted(set(secs)) and 3 <= len(secs) <= 4
     assert sum(r[1] for r in rows) == len(s["tick_ring"]) == s["ticks"]
@@ -470,6 +494,232 @@ def test_a_tick_that_prefilled_says_what_it_added(engine):
 
 
 # ----------------------------------------------------------------------
+# A3. the launches' stamp: every program handed to the device
+# ----------------------------------------------------------------------
+class _StandIn:
+    """A program whose CALL takes `nap` seconds to return (the host
+    blocked: the device's queue was full), then runs the real one."""
+
+    def __init__(self, fn, nap):
+        self._fn, self.nap, self.__name__ = fn, nap, fn.__name__
+
+    def _cache_size(self):
+        return self._fn._cache_size()
+
+    def __call__(self, *args):
+        time.sleep(self.nap)
+        return self._fn(*args)
+
+
+def _burst(eng, reqs):
+    """Every `(prompt, new)` into the queue at once, as ONE tick's
+    admissions (submit() would wake the loop on the first of them)."""
+    entries = [(p, n, Future(), time.time(), None, None) for p, n in reqs]
+    with eng._wake:
+        eng._queue.extend(entries)
+        eng._wake.notify()
+    return [e[2] for e in entries]
+
+
+def _launched(stats):
+    """`launch_account` as {(program, traced): {field: value}}."""
+    acct = stats["launch_account"]
+    return {(r[0], r[1]): dict(zip(acct["fields"][2:], r[2:]))
+            for r in acct["rows"]}
+
+
+@pytest.mark.parametrize("nap,blocks", [
+    (4 * llm_engine.LAUNCH_BLOCKED_S, True),
+    (llm_engine.LAUNCH_BLOCKED_S / 5, False),
+], ids=["a-call-that-blocks", "a-call-that-returns"])
+def test_a_launch_that_blocks_is_told_from_host_work(engine, reads, nap,
+                                                     blocks):
+    _serve(engine, 2, new=8)  # compile outside what is looked at
+    chunk_for = engine._chunk_step_for
+    engine._chunk_step_for = lambda W: _StandIn(chunk_for(W), nap)
+    reads.delay = 0.02  # ticks of 20 ms: what no phase holds is small
+    seq = engine.stats()["ticks"]
+    _serve(engine, 2, new=8, first=2)
+    s = engine.stats()
+    ring = [t for t in s["tick_ring"] if t["seq"] > seq and t["row_steps"]]
+    assert len(ring) >= 4 and not any(t.get("compiles") for t in ring)
+    for t in ring:
+        # the chunk's call, and a packed prefill's where one was admitted
+        assert t["launches"] == 1 + t.get("prefill_calls", 0)
+        assert nap <= t["launch_s"] <= t["prefill_s"] + t["dispatch_s"]
+        if blocks:
+            assert nap <= t["launch_blocked_s"] <= t["launch_s"]
+            # the host's own work of the tick is what is left
+            assert t["dispatch_s"] - t["launch_blocked_s"] < nap
+        else:
+            assert t["launch_blocked_s"] == 0
+    # parts of `prefill_s` / `dispatch_s`, not phases beside them: the
+    # five phases still are the tick's wall
+    whole = sum(t["tick_s"] for t in ring)
+    assert sum(t[k] for t in ring for k in IN_TICK) >= 0.99 * whole
+    assert sum(t[k] for t in ring for k in IN_TICK) <= whole
+    total = _account_total(s)
+    for col, key in (("launches", "launches"), ("launch_us", "launch_s"),
+                     ("launch_blocked_us", "launch_blocked_s")):
+        scale = 1e6 if col.endswith("_us") else 1
+        assert total[col] == pytest.approx(
+            sum(t[key] for t in s["tick_ring"]) * scale, abs=len(s["tick_ring"]))
+    by = _launched(s)
+    chunks = [v for (name, _), v in by.items()
+              if name.startswith("decode_chunk")]
+    assert sum(v["blocked"] for v in chunks) == (
+        sum(1 for t in s["tick_ring"] if t["launch_blocked_s"])
+        if blocks else 0)
+    # appended: a row of before the stamp is a prefix of a row of now
+    assert s["tick_account"]["fields"][-3:] == (
+        "launches", "launch_us", "launch_blocked_us")
+
+
+def test_a_launch_that_compiles_is_not_blocked(model):
+    eng = LlamaEngine(*model, slots=2, max_len=48, chunk=2, block_size=8)
+    try:
+        _serve(eng, 1)
+        s = eng.stats()
+    finally:
+        eng.shutdown()
+    compiled = [t for t in s["tick_ring"] if t.get("compiles")]
+    assert compiled and all(t["launch_s"] > llm_engine.LAUNCH_BLOCKED_S
+                            for t in compiled)
+    assert all(t["launch_blocked_s"] == 0 for t in compiled)
+
+
+def _pairs(*parts):
+    """The formula, spelled out: a query at position p attends p + 1
+    keys."""
+    return sum(p + 1 for lo, hi in parts for p in range(lo, hi))
+
+
+def test_a_launch_says_what_it_holds(model):
+    """Real programs of the dense-prefix model: a packed pack of two
+    prompts, then a prefix hit's suffix (two launches: the prefill and
+    the write), then the chunks that decode them."""
+    eng = LlamaEngine(*model, slots=2, max_len=48, chunk=2, block_size=8,
+                      prefix_cache=True)
+    try:
+        shared = _prompt(7, 16)
+        f0, f1 = _burst(eng, [(_prompt(0, 12), 4), (_prompt(1, 9), 4)])
+        f0.result(timeout=60), f1.result(timeout=60)
+        eng.submit(shared + _prompt(8, 5), 3).result(timeout=60)
+        eng.submit(shared + _prompt(9, 7), 3).result(timeout=60)  # a hit
+        s = eng.stats()
+    finally:
+        eng.shutdown()
+    by = _launched(s)
+    assert {t for _, t in by} == {0}  # no profiler session
+    packed = by["prefill_packed_n48", 0]
+    # the pack of two, then the first long prompt alone (a miss)
+    assert packed["launches"] == 2 and packed["rows"] == 3
+    assert packed["tokens"] == 12 + 9 + 21
+    assert packed["padded_tokens"] == 2 * 48
+    assert packed["attended_pairs"] == _pairs((0, 12), (0, 9), (0, 21))
+    (suffix,) = [v for (n, _), v in by.items()
+                 if n.startswith("suffix_prefill_")]
+    (write,) = [v for (n, _), v in by.items() if n.startswith("kv_write_")]
+    # the hit: 16 tokens cached, 7 prefilled behind them
+    assert s["prefix_hit_tokens"] == 16
+    assert (suffix["launches"], suffix["rows"], suffix["tokens"],
+            suffix["padded_tokens"]) == (1, 1, 7, 8)
+    assert suffix["attended_pairs"] == _pairs((16, 23)) == 7 * 16 + 28
+    assert (write["tokens"], write["attended_pairs"]) == (7, 0)
+    chunks = [v for (n, _), v in by.items() if n.startswith("decode_chunk")]
+    assert sum(v["launches"] for v in chunks) == s["ticks"]
+    assert all(v["padded_tokens"] == v["rows"] == v["tokens"] == 0
+               for v in chunks)
+    # a chunk's live rows, and the tokens they attend at its first step
+    assert sum(v["rows_live"] for v in chunks) > 0
+    assert sum(v["live_tokens"] for v in chunks) >= sum(
+        v["rows_live"] for v in chunks) * 10
+    # the two accounts count the same launches
+    assert sum(v["launches"] for v in by.values()) == _account_total(
+        s)["launches"]
+
+
+class _Recorded(Exception):
+    pass
+
+
+@pytest.mark.parametrize("run,parts", [
+    # a packed-suffix pack: a miss, a hit's suffix and a long prompt's
+    # second chunk, each behind its own `lo`
+    ("suffixes", [(0, 11), (16, 29), (32, 40)]),
+    # a chunk that carries the slot's per-slot leaves
+    ("state_chunk", [(24, 37)]),
+    ("suffix_chunk", [(8, 21)]),
+], ids=["packed-suffix-pack", "state-chunk", "suffix-chunk"])
+def test_every_admission_family_counts_its_attended_pairs(engine, run, parts):
+    """The three callers a dense-prefix model's traffic does not reach
+    (`_run_suffixes`: a model that packs its suffixes; `_run_state_chunk`:
+    one that carries per-slot leaves; `_run_suffix_chunk` at a chosen
+    `lo`), driven on hand-made plans with the launch itself recorded."""
+    held = []
+
+    def record(fn, *args, **kw):
+        held.append((fn.__name__, kw))
+        raise _Recorded
+
+    engine._launch = record
+    engine._admitting = engine._prefilled = lambda plans: None
+    engine._chunk_prefill_for = engine._prefill_packed_for = (
+        lambda N: _StandIn(len, 0))  # (no such program of this model)
+    req = {"stop": 44}
+    # `lo` tokens in shared blocks, the rest of the sequence's six its own
+    plans = [llm_engine._Plan(req, i, list(range(1, hi + 1)),
+                              list(range(1, 1 + lo // 8)),
+                              list(range(10, 16 - lo // 8)))
+             for i, (lo, hi) in enumerate(parts)]
+    with pytest.raises(_Recorded):
+        if run == "suffixes":
+            engine._run_suffixes(48, [llm_engine._Part(p, lo, hi) for p,
+                                      (lo, hi) in zip(plans, parts)])
+        elif run == "state_chunk":
+            engine._run_state_chunk(plans[0], *parts[0], 1, 2, N=16)
+        else:
+            engine._run_suffix_chunk(plans[0], *parts[0], 0, 1)
+    (name, kw), = held
+    real = sum(hi - lo for lo, hi in parts)
+    assert (kw["rows"], kw["tokens"]) == (len(parts), real)
+    assert kw["N"] == {"suffixes": 48, "state_chunk": 16,
+                       "suffix_chunk": 16}[run]
+    assert kw["attended_pairs"] == _pairs(*parts)
+    assert kw["attended_pairs"] == sum(
+        (hi - lo) * lo + (hi - lo) * (hi - lo + 1) // 2 for lo, hi in parts)
+
+
+def test_the_stamp_costs_microseconds_a_launch(engine):
+    """What `_launch` adds to a program's own call, outside a profiler
+    session: two clock reads, a span that is a no-op, a record."""
+    class Nothing:
+        __name__ = "nothing"
+
+        @staticmethod
+        def _cache_size():
+            return 1
+
+        def __call__(self, *args):
+            return None
+
+    fn, n = Nothing(), 20_000
+    held = dict(N=2048, rows=3, tokens=1900, attended_pairs=1234567)
+    t0 = time.perf_counter()
+    for _ in range(n):
+        fn(1, 2, 3)
+    bare = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for _ in range(n):
+        engine._launch(fn, 1, 2, 3, **held)
+    stamped = time.perf_counter() - t0
+    engine._launches.clear()
+    per_launch_us = 1e6 * (stamped - bare) / n
+    print(f"the launch stamp: {per_launch_us:.2f} us a launch")
+    assert per_launch_us < 100  # (3-6 us here; a program is 10-230 ms)
+
+
+# ----------------------------------------------------------------------
 # B. the engine-loop spans, under a profiler session
 # ----------------------------------------------------------------------
 def _host_spans(trace_dir):
@@ -556,6 +806,69 @@ def test_a_profiler_session_records_the_loops_spans_nested(engine, tmp_path):
             assert rec[phase + "_s"] <= spanned + 1e-6, phase
             assert spanned - rec[phase + "_s"] < 5e-4, phase
     assert matched >= 3
+
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+def test_a_profiler_session_splits_the_launch_account(engine, tmp_path):
+    """`launch_account`'s `traced` rows are what the programs launched
+    inside the LATEST profiler session held: what a device trace's
+    `jit_<program>` calls are set against."""
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    _serve(engine, 2)  # compile outside the sessions
+    before = _launched(engine.stats())
+    assert before and {traced for _, traced in before} == {0}
+
+    def session(to, n, first):
+        jax.profiler.start_trace(str(to))
+        try:
+            assert jax.profiler.TraceAnnotation.is_enabled()
+            _serve(engine, n, first=first)  # unshared prompts
+            time.sleep(0.05)  # the loop closes its last tick
+        finally:
+            jax.profiler.stop_trace()
+        return _launched(engine.stats())
+
+    first = session(tmp_path / "a", 4, 10)
+    traced = {n: v for (n, t), v in first.items() if t}
+    packed = traced["prefill_packed_n48"]
+    assert (packed["rows"], packed["tokens"]) == (4, 48)
+    assert packed["attended_pairs"] == 4 * (12 * 13 // 2)
+    assert packed["padded_tokens"] == 48 * packed["launches"]
+    # the untraced rows stand as they were: nothing is counted twice
+    assert {k: v for k, v in first.items() if not k[1]} == before
+    # the spans say the same, launch by launch, each inside the phase
+    # that launched it
+    spans = _host_spans(tmp_path / "a")
+    launches = [s for s in spans if s[0] == "engine.launch"]
+    outer = [s for s in spans
+             if s[0] in ("engine.prefill", "engine.dispatch")]
+    assert all(any(o[1] <= s[1] and s[2] <= o[2] for o in outer)
+               for s in launches)
+    by_program = {}
+    for *_, st in launches:
+        by_program.setdefault(st["program"], []).append(st)
+    # (a tick in flight at either edge: its span may be cut, its launch
+    # is counted by where the launch itself fell)
+    for name, row in traced.items():
+        assert abs(len(by_program.get(name, ())) - row["launches"]) <= 1
+    assert sum(st["attended_pairs"] for st in by_program[
+        "prefill_packed_n48"]) == packed["attended_pairs"]
+    chunks = [st for name, sts in by_program.items()
+              if name.startswith("decode_chunk") for st in sts]
+    # (finish detection lags a chunk: a row's last chunk may owe nothing)
+    assert all(st["N"] == 0 and st["live_tokens"] >= 12 * st["rows_live"]
+               for st in chunks) and any(st["rows_live"] for st in chunks)
+    # outside a session again: the traced rows keep the session's
+    _serve(engine, 2, first=20)
+    after = _launched(engine.stats())
+    assert {k: v for k, v in after.items() if k[1]} == {
+        k: v for k, v in first.items() if k[1]}
+    assert after["prefill_packed_n48", 0]["rows"] == 2 + 2
+    # a second session starts its rows anew
+    second = session(tmp_path / "b", 2, 30)
+    packed = second["prefill_packed_n48", 1]
+    assert (packed["rows"], packed["tokens"]) == (2, 24)
+    assert second["prefill_packed_n48", 0]["rows"] == 4
 
 
 # ----------------------------------------------------------------------
