@@ -374,13 +374,19 @@ def percentile(values, q: float) -> Optional[float]:
 CLOSED_READ_FROM = 0.2
 
 
+def _share_by(r: Record, t: float) -> float:
+    """The share of an answered request credited by the instant `t`:
+    what it was asked for, spread evenly over `[sent_s, done_s]`."""
+    life = r.done_s - r.sent_s
+    if life <= 0.0:
+        return 1.0 if t >= r.done_s else 0.0
+    return min(1.0, max(0.0, (t - r.sent_s) / life))
+
+
 def _produced_by(r: Record, t: float) -> float:
     """Tokens of an answered request credited by the instant `t`: its
     `got` tokens spread evenly over `[sent_s, done_s]`."""
-    life = r.done_s - r.sent_s
-    if life <= 0.0:
-        return float(r.got) if t >= r.done_s else 0.0
-    return r.got * min(1.0, max(0.0, (t - r.sent_s) / life))
+    return r.got * _share_by(r, t)
 
 
 def produced_per_s(recs: List[Record], seconds: float) -> float:
@@ -409,6 +415,20 @@ def produced_per_s(recs: List[Record], seconds: float) -> float:
                for r in done) / (seconds - a)
 
 
+def credited(recs: List[Record], seconds: float) -> dict:
+    """A closed loop's answered requests, each with what it was
+    (`prompt_len`, `got`, `fields`) and the SHARE of it that
+    `produced_per_s` credits between `from_s` and `to_s`: whatever else
+    is counted a request (the operations it needed: `serve_mfu`) is
+    credited over the same seconds as its tokens.  A failed, short or
+    never answered request is not listed."""
+    a = CLOSED_READ_FROM * seconds
+    return {"from_s": a, "to_s": seconds, "requests": [
+        {"prompt_len": r.prompt_len, "got": r.got, "fields": r.fields,
+         "share": _share_by(r, seconds) - _share_by(r, a)}
+        for r in recs if r.ok and not math.isnan(r.done_s)]}
+
+
 def summarize(recs: List[Record], seconds: float, miss_ms: float,
               closed: bool = False) -> dict:
     """`tokens_per_s`: open loop, output tokens of requests that
@@ -422,7 +442,9 @@ def summarize(recs: List[Record], seconds: float, miss_ms: float,
     credit, and a closed cell's `correct` holds their number to 0.
     `by_field`: only where requests carried fields (`request_fields`),
     per field and value the answered requests and their tokens: a
-    count, no metric."""
+    count, no metric.  `credited`: closed loop only, the answered
+    requests one by one with their share of credit in the read window
+    (`credited`)."""
     cut = [r for r in recs if r.cut and closed]
     recs = [r for r in recs if not (r.cut and closed)]
     done_in = [r for r in recs if r.ok and r.done_s <= seconds]
@@ -457,4 +479,6 @@ def summarize(recs: List[Record], seconds: float, miss_ms: float,
     }
     if by_field:
         out["by_field"] = by_field
+    if closed:
+        out["credited"] = credited(recs, seconds)
     return out

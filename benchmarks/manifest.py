@@ -59,16 +59,31 @@ def peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
+def _module(folder: str, name: str):
+    """`benchmarks/<folder>/<name>.py`, loaded by its file's name (a
+    metric's name may hold a dot)."""
+    path = os.path.join(BENCH, folder, name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        f"benchmarks.{folder}." + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def layer_metric(name: str):
     """The reader module of one per-layer metric:
     `benchmarks/layer_metrics/<name>.py` with LAYER, UNIT, SOURCE, MOVES
     and `read(ctx) -> float | None`."""
-    path = os.path.join(BENCH, "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks.layer_metrics." + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _module("layer_metrics", name)
+
+
+def needed_flops(plane: str):
+    """The count of the operations a request NEEDS under one plane's
+    family of models: `benchmarks/needed_flops/<plane>.py` with
+    `matmul_weights(config) -> dict` and `request_flops(config, mix,
+    prompt_len, got, fields) -> float`.  A later configuration whose
+    plane is new adds a file; nothing here names one."""
+    return _module("needed_flops", plane)
 
 
 def metrics_for(cell_name: str, kind: str) -> list:

@@ -193,7 +193,9 @@ def test_by_field_adds_up_to_the_totals(closed):
             R(4, 0.0, sent_s=0.0, done_s=5.0, ok=False, status=500,
               fields={"steps": 8, "tag": "x"})]
     s = loadgen.summarize(recs, 10.0, 1e6, closed=closed)
-    assert list(s) == TODAYS_KEYS + ["by_field"]
+    # a closed loop's summary also lists the requests it credits (PR 59)
+    credited = ["credited"] if closed else []
+    assert list(s) == TODAYS_KEYS + ["by_field"] + credited
     assert s["by_field"] == {
         "steps": {"2": {"completed": 2, "tokens": 64},
                   "4": {"completed": 1, "tokens": 40},
@@ -208,7 +210,7 @@ def test_by_field_adds_up_to_the_totals(closed):
     for r in recs:
         r.fields = {}
     assert list(loadgen.summarize(recs, 10.0, 1e6, closed=closed)) == \
-        TODAYS_KEYS
+        TODAYS_KEYS + credited
 
 
 @pytest.fixture

@@ -20,9 +20,12 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 M = manifest.manifest()
 CELLS = [c["name"] for c in M["workloads"]]
-# the sixteen per-layer readers every closed cell reports: a module that
-# holds a closed cell's entries holds at least these, as a set
+# the seventeen per-layer readers every closed cell reports (sixteen that
+# list the closed cells, and `serve_mfu`, which lists none and moves what
+# they all report): a module that holds a closed cell's entries holds at
+# least these, as a set
 CLOSED_SHARED = {
+    "serve_mfu",
     "serve_plane_overhead_p50_ms", "engine_compiles_in_window",
     "engine_batch_occupancy", "engine_tick_host_ms",
     "request_p95_ms.saturated", "device_idle_share.serve", "decode_step_ms",
@@ -135,7 +138,7 @@ def grow(tmp_path, monkeypatch):
     per-layer entry of a cell that is there (PR 52's case), and the
     cell's name appended to EVERY list that all the closed cells share."""
     bench = _copy_of_bench(tmp_path, "traffic", "configs", "layer_metrics",
-                           "reference")
+                           "reference", "needed_flops")
     sizes = tmp_path / "closed_sizes"
     shutil.copytree(CLOSED_DIR, sizes)
     grown = manifest.manifest()
@@ -159,10 +162,18 @@ def grow(tmp_path, monkeypatch):
     grown["workloads"].append({
         "name": "one_more_cell", "config": "one-more",
         "traffic": "one_more_closed", "chips": 1, "why": "a list can grow"})
+    def lists_them(e):
+        return closed <= set(e.get("workloads", ()))
+
+    # an entry that lists no cell is every cell's that reports what it
+    # moves (`serve_mfu`): shared too, and nobody appends to it
+    moved = {e["name"] for e in grown["end_to_end"] if lists_them(e)}
     shared = [e for e in grown["end_to_end"] + grown["per_layer"]
-              if closed <= set(e.get("workloads", ()))]
+              if lists_them(e)
+              or ("workloads" not in e and e.get("moves") in moved)]
     for e in shared:
-        e["workloads"].append("one_more_cell")
+        if "workloads" in e:
+            e["workloads"].append("one_more_cell")
     reader = ('LAYER, UNIT, SOURCE, MOVES = "engine", "ms", "program_counter", '
               '"serve_tokens_per_s"\n\ndef read(ctx):\n    return None\n')
     earlier = grown["workloads"][-2]["name"]
@@ -190,13 +201,14 @@ def test_a_list_can_grow(tmp_path, monkeypatch):
     checks = manifest_checks()   # imported while `manifest` is the tree's
     grown, shared, earlier = grow(tmp_path, monkeypatch)
     assert manifest.manifest()["workloads"][-1]["name"] == "one_more_cell"
-    # the sixteen per-layer lists of a closed cell and its end-to-end one
+    # the per-layer entries every closed cell reports and its end-to-end one
     assert {"serve_tokens_per_s", *CLOSED_SHARED} <= set(shared)
     mine = [p["name"] for p in manifest.metrics_for("one_more_cell",
                                                     "per_layer")]
     assert mine == shared[1:] + ["one_more_metric"]
     was = [p["name"] for p in before["per_layer"]
-           if earlier in p.get("workloads", ())]
+           if earlier in p.get("workloads", ())
+           or ("workloads" not in p and p["name"] in shared)]
     assert [p["name"] for p in manifest.metrics_for(earlier, "per_layer")] \
         == was + ["one_more_of_an_earlier_cell"]
     assert {"test_bench_hybrid", "test_bench_sparse_latent",
