@@ -20,16 +20,19 @@ engine's per-layer scan never slices (= copies) the pool:
   block, in place (`input_output_aliases`) — the grid touches ONE
   block per row, replacing the chunk stepper's whole-view scatter.
 - `paged_decode_attention`: ONE grid step whose own loops walk rows
-  and, per row, COMPUTE BLOCKS of P pages (`_pages_per_block`: 128
-  tokens = 8 pages at the serving shapes, the whole table where it is
-  narrower).  The walk ends at the row's last live page (`pos[b]`): a
-  row of 200 tokens takes two blocks whether its table is 16, 64 or 81
-  wide, and a page a row does not have is neither copied nor stepped
-  over.  A DEAD row, one that owes no token in this step (the engine's
-  `pos >= stop`), is handed position -1 and has no live page: no copy,
-  no block, a row of zeros out, and the next row's first copies start
-  in its place; the append kernel is handed a position past the
-  table's reach and writes nothing for it (`dead_row_positions`).  The
+  and, per row, COMPUTE BLOCKS of P pages (`_pages_per_block`: the
+  longest block the score tile allows, from `H`, `KV`, the block size
+  and the table's width alone: 128 tokens = 8 pages for the per-head
+  forms at the serving shapes, 512 or 256 for a folded or a latent pool,
+  the whole table where it is narrower).  The walk ends at the row's
+  last live page (`pos[b]`): a per-head row of 200 tokens takes two
+  blocks whether its table is 16, 64 or 81 wide, and a page a row does
+  not have is neither copied nor stepped over.  A DEAD row, one that
+  owes no token in this step (the engine's `pos >= stop`), is handed
+  position -1 and has no live page: no copy, no block, a row of zeros
+  out, and the next row's first copies start in its place; the append
+  kernel is handed a position past the table's reach and writes
+  nothing for it (`dead_row_positions`).  The
   pools stay in HBM; a block's live pages are copied through
   the row's table into one of two VMEM tiles (`make_async_copy`, page
   by page: pages are scattered, so no `BlockSpec` describes the tile)
@@ -99,7 +102,8 @@ head of width `KV * hd`: the wrapper lays each query into the lanes of
 its own kv head (zeros in the others', so the score is the narrow
 head's), passes the narrow head's scale, and takes each query head's
 own lanes of the result; a score tile then has a column a token, no
-head to mask, and a quarter of the exponentials of the per-head tile.
+head to mask, and a quarter of the exponentials of the per-head tile:
+which is why its block may be four times as long (`_pages_per_block`).
 The append takes `[B, KV, hd]` rows as the `[B, KV * hd]` they are,
 through the one-row form the latent pool uses, for both pools in one
 call.  The MXU multiplies `KV` times the columns, which decode does not
@@ -115,6 +119,7 @@ tier-1 instead of the first chip run.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -428,27 +433,33 @@ def mla_paged_kv_append(pool, new, tables, pos, layer, *,
 # ----------------------------------------------------------------------
 # decode attention kernel: split-KV walk over the block table
 # ----------------------------------------------------------------------
-# A compute block is this many tokens of a row, fewer where its score
-# tile [H, tokens * KV] f32 would outgrow the 32 vector registers' worth
-# the softmax works in (which also keeps the four K / V tiles, two of
-# each so that the next block's copies run under this block's
-# arithmetic, within a few MB of VMEM)
-_BLOCK_TOKENS = 128
-# the latent form's block: one kv "head", so the score tile stays small
-# ([H, 512] f32 = 64 KB) and a longer block spreads the per-block costs
-# (DMA waits, the accumulator's rescale) over more tokens: 0.61 / 0.47 /
-# 0.40 ms a call at 128 / 256 / 512 with 64 rows of 1,500 live tokens
-# (PERF.md section 6, PR 27); its two [512, 640] tiles are 1.3 MB of VMEM
-_MLA_BLOCK_TOKENS = 512
+# A compute block is the LONGEST its shapes allow: this many tokens of a
+# row, fewer where the score tile [H, tokens * KV] f32 would outgrow the
+# 32 vector registers' worth the softmax works in.  A longer block
+# spreads the per-block costs (DMA waits, the accumulator's rescale, the
+# softmax chain's latency) over more tokens: the latent form 0.61 / 0.47
+# / 0.40 ms a call at 128 / 256 / 512 with 64 rows of 1,500 live tokens
+# (PERF.md section 6, PR 27); the folded forms' timings: PR 60's
+# paragraph there.  The score tile is what tells the forms apart, and no
+# form is asked its name: with `KV` heads a token the tile is `KV` times
+# wider, so the per-head forms (KV 8 or 16 at H 32 or 16) stay at 128
+# tokens, and the forms the kernel sees as ONE kv head (the latent pool,
+# a folded pool) take 512 at up to 64 query heads, 256 at 128.  The four
+# K / V tiles (two of each, so that the next block's copies run under
+# this block's arithmetic) are then up to 2.6 MB of VMEM, which
+# `_vmem_limit` counts
+_BLOCK_TOKENS = 512
 _SCORE_TILE_BYTES = 128 * 1024
 
 
-def _pages_per_block(BS, KV, H, W, block_tokens=_BLOCK_TOKENS):
-    """Pages folded per step of the walk, from the shapes: at the
-    serving shapes (BS 16, KV 8 or 16, H 16 or 32) 8 pages = 128
-    tokens; the whole table where it is narrower; never less than one."""
+def _pages_per_block(BS, KV, H, W):
+    """Pages folded per step of the walk, from the shapes alone: what
+    the score tile allows up to `_BLOCK_TOKENS` (at BS 16: 8 pages for
+    KV 8 x H 32 and KV 16 x H 16; for one kv head 32 pages up to H 64,
+    16 at H 128); the whole table where it is narrower; never less than
+    one."""
     by_score = _SCORE_TILE_BYTES // (4 * H * KV * BS)
-    return max(1, min(W, block_tokens // BS, by_score))
+    return max(1, min(W, _BLOCK_TOKENS // BS, by_score))
 
 
 @functools.lru_cache(maxsize=32)
@@ -473,8 +484,7 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
     VD = latent or vd or HD  # width of a value row, and of the result
     q_dt = jnp.dtype(q_dtype)
     pool_dt = jnp.dtype(pool_dtype)
-    P = _pages_per_block(BS, KV, H, W,
-                         _MLA_BLOCK_TOKENS if latent else _BLOCK_TOKENS)
+    P = _pages_per_block(BS, KV, H, W)
     T = P * BS   # tokens in a compute block
     R = BS * KV  # rows of one page: (token, kv head) pairs, token-major
     C = P * R    # ... and of a compute block: the score tile's columns
@@ -610,11 +620,13 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
 
     whole = pl.BlockSpec((B, H, HD), lambda *_: (0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    scratch = [pltpu.VMEM((2, C, HD), pool_dt)]
+    # the walk's tiles, two slots each: K, V, an int8 pool's scales
+    tiles = [((2, C, HD), pool_dt)]
     if not latent:
-        scratch.append(pltpu.VMEM((2, C, VD), pool_dt))
+        tiles.append(((2, C, VD), pool_dt))
     if quantized:
-        scratch += [pltpu.VMEM((2, 1, C), jnp.float32)] * 2
+        tiles += [((2, 1, C), jnp.dtype(jnp.float32))] * 2
+    scratch = [pltpu.VMEM(shape, dt) for shape, dt in tiles]
     scratch.append(pltpu.SemaphoreType.DMA((2, 2)))  # [K | V, slot]
 
     return pl.pallas_call(
@@ -636,22 +648,26 @@ def _build_attention(L, NB, BS, KV, HD, B, W, H, pool_dtype, q_dtype,
         out_shape=jax.ShapeDtypeStruct((B, H, VD), q_dt),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            **_vmem_limit(B * H * (HD + VD) * q_dt.itemsize),
+            **_vmem_limit(
+                B * H * (HD + VD) * q_dt.itemsize,
+                sum(math.prod(shape) * dt.itemsize for shape, dt in tiles)),
         ),
         interpret=interpret,
     )
 
 
 # the queries and the result lie whole in VMEM, each in the two buffers
-# a pipelined operand gets; where that is well past what Mosaic grants
-# by default (16 MiB on a v5e, of 128 physical: 64 query heads against
-# a folded row of 768 lanes at 128 slots are 42 MB) the call asks for
-# its own; the shapes that ran before run as they did
+# a pipelined operand gets, beside the walk's own tiles (two of K and of
+# V, and of an int8 pool's scales) and a flat 4 MB for what the fold
+# keeps; where that is well past what Mosaic grants by default (16 MiB
+# on a v5e, of 128 physical: 64 query heads against a folded row of 768
+# lanes at 128 slots are 42 MB) the call asks for its own; the shapes
+# that ran under the default run as they did
 _VMEM_DEFAULT_BYTES = 24 << 20
 
 
-def _vmem_limit(q_and_o_bytes: int) -> dict:
-    need = 2 * q_and_o_bytes + (4 << 20)
+def _vmem_limit(q_and_o_bytes: int, tile_bytes: int) -> dict:
+    need = 2 * q_and_o_bytes + tile_bytes + (4 << 20)
     if need <= _VMEM_DEFAULT_BYTES:
         return {}
     return {"vmem_limit_bytes": need}

@@ -876,6 +876,57 @@ def test_paged_kernels_take_a_block_of_rows_a_slot(chip, W):
     assert "input_output_alias" in hlo
 
 
+def _decode_walk(fn, *args):
+    """(the walk's VMEM tiles, the VMEM the call asks for or None) of
+    the one `paged_decode_attention` kernel `fn` holds, from its jaxpr."""
+    calls = [e for e in jax.make_jaxpr(fn)(*args).eqns
+             if e.primitive.name == "pallas_call"
+             and e.params["name"] == "paged_decode_attention"]
+    assert len(calls) == 1
+    p = calls[0].params
+    tiles = [a.inner_aval for a in p["grid_mapping"].scratch_avals
+             if a.inner_aval.ndim == 3]
+    return tiles, p["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+
+
+# the three cells whose pools are FOLDED, as their decode programs call
+# the kernel: slots, query heads, query width, K and V lanes, the widest
+# table, and the tokens a block of the walk then holds
+_FOLDED_WALKS = {
+    "mimo25_mixed_closed_8k": (128, 64, 192, 768, 512, 545, 512),
+    "lfm2_batch_closed_512": (128, 32, 64, 512, 512, 81, 512),
+    "sdar30b_blockgen_closed_512": (128, 128, 128, 512, 512, 81, 256),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_FOLDED_WALKS))
+def test_folded_walks_lower_at_their_longest_block(chip, cell):
+    """A folded pool is one kv head to the kernel, so its block is the
+    longest the score tile allows (`pa._pages_per_block`): 512 tokens at
+    up to 64 query heads, 256 at 128.  Each cell's call holds K and V
+    tiles of that many rows, two of each; the VMEM it asks for, where it
+    asks, covers them beside the queries and the result in their two
+    buffers each; and Mosaic takes the call for the described chip (a
+    call that asks for none lowers under Mosaic's own limit)."""
+    B, H, hd, lanes_k, lanes_v, W, T = _FOLDED_WALKS[cell]
+    args = (_s(B, H, hd), _s(2, 4096, 16, lanes_k), _s(2, 4096, 16, lanes_v),
+            _s(B, W, dtype=jnp.int32), _s(B, dtype=jnp.int32))
+
+    def fn(q, kp, vp, tables, pos):
+        return pa.paged_decode_attention(q, kp, vp, tables, pos, 1)
+
+    tiles, ask = _decode_walk(fn, *args)
+    assert [t.shape for t in tiles] == [(2, T, lanes_k), (2, T, lanes_v)]
+    held = (2 * B * H * (lanes_k + lanes_v) * 2
+            + sum(t.size * t.dtype.itemsize for t in tiles))
+    if ask is None:
+        assert held <= pa._VMEM_DEFAULT_BYTES
+    else:
+        assert ask >= held
+    hlo = _compile(chip, fn, *args)
+    assert "tpu_custom_call" in hlo and f"bf16[{B},{H},{lanes_v}]" in hlo
+
+
 def test_block_diffusion_programs_at_the_cells_shapes(chip):
     """`decode_chunk_w81` (the block chunk program: 8 forwards of 128
     slots x 4 positions) and `prefill_packed_n1296` as the engine jits

@@ -60,19 +60,29 @@ def _dense_reference(q, k, v, pos):
 
 
 # block size, table width, kv heads, query heads.  The kernel folds P
-# pages a compute block, P = min(W, 128 // BS) at these sizes: "w3" is
-# one block (the shape this test began with), "w1" / "w2" tables
-# narrower than any block, "w11" 8 + 3 pages and "w81" 32 + 32 + 17 —
-# widths that are a multiple of no P — each as MHA (H == KV) and GQA
+# pages a compute block, the most the score tile `[H, tokens * KV]`
+# allows (`pa._pages_per_block`): "w3" is one block (the shape this test
+# began with), "w1" / "w2" tables narrower than any block; "w11" and
+# "w81" carry the serving forms' head counts (32 query heads on 8 kv
+# heads, 16 on 16), so that the tile binds as it does there: "w11" walks
+# 8 + 3 pages and "w81" 32 + 32 + 17, widths that are a multiple of no P
 _RAGGED = {
     "w3-gqa2": (4, 3, 2, 4),
     "w1-gqa2": (8, 1, 2, 4),
     "w2-mha": (8, 2, 2, 2),
-    "w11-gqa4": (16, 11, 2, 8),
-    "w11-mha": (16, 11, 4, 4),
-    "w81-gqa4": (4, 81, 2, 8),
-    "w81-mha": (4, 81, 2, 2),
+    "w11-gqa4": (16, 11, 8, 32),
+    "w11-mha": (16, 11, 16, 16),
+    "w81-gqa4": (4, 81, 8, 32),
+    "w81-mha": (4, 81, 16, 16),
 }
+
+
+@pytest.mark.parametrize("shape,pages", [
+    ("w3-gqa2", 3), ("w1-gqa2", 1), ("w2-mha", 2), ("w11-gqa4", 8),
+    ("w11-mha", 8), ("w81-gqa4", 32), ("w81-mha", 32)])
+def test_the_ragged_shapes_walk_the_blocks_they_say(shape, pages):
+    BS, W, KV, H = _RAGGED[shape]
+    assert pa._pages_per_block(BS, KV, H, W) == pages
 
 
 def _ragged_case(BS, W, KV, hd, seed, pad_page=0, hd_v=None):
@@ -196,6 +206,152 @@ def test_kernel_never_reads_dead_pages_into_a_result(shape, kv):
     rows = [0, 1, 2, 4]  # row 3 is idle: it attends the scratch block
     assert np.isfinite(poisoned[rows]).all()
     np.testing.assert_array_equal(poisoned[rows], clean[rows])
+
+
+# The folded forms as the kernel sees them: ONE kv head whose row holds a
+# token's heads side by side, so the score tile is `[H, tokens]` and the
+# block is the longest of all (`_pages_per_block`): 32 pages up to 64
+# query heads, 16 at 128.  (query heads, kv heads, key width, value
+# width, table width): tables a few pages wider than two blocks
+_LONG_BLOCKS = {
+    "h32-kv8": (32, 8, 16, 16, 70),           # the hybrid's: 512 lanes
+    "h64-kv4-wide-k": (64, 4, 24, 16, 70),    # keys wider than values
+    "h128-kv4": (128, 4, 16, 16, 38),         # 4 positions x 32 heads a row
+    "h128-kv4-wide-k": (128, 4, 24, 16, 38),
+}
+
+
+def _long_block_case(H, KV, hd, hd_v, W, seed, BS=16):
+    """Folded pools and six rows placed around the block's edges: a row
+    that ends on a block boundary, a DEAD row between live ones, a row
+    one token past the boundary, one that ends inside a page of the
+    second block, one shorter than a block, one that fills the table."""
+    T = pa._pages_per_block(BS, 1, H, W) * BS
+    pos = np.asarray([T - 1, -1, T, T + BS + 5, T // 2 - 3, W * BS - 1],
+                     np.int32)
+    rng = np.random.default_rng(seed)
+    B = len(pos)
+    NB = 2 + B * W
+    tables = np.zeros((B, W), np.int32)
+    pages = rng.permutation(np.arange(2, NB))
+    for b in range(B):
+        n = (pos[b] + BS) // BS
+        tables[b, :n], pages = pages[:n], pages[n:]
+    k = rng.standard_normal((1, NB, BS, KV, hd)).astype(np.float32)
+    v = rng.standard_normal((1, NB, BS, KV, hd_v)).astype(np.float32)
+    q = rng.standard_normal((B, H, hd)).astype(np.float32)
+    return T, q, k, v, tables, pos
+
+
+@pytest.mark.parametrize("form", sorted(_LONG_BLOCKS))
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_folded_walk_in_its_longest_blocks(dtype, tol, form):
+    """The folded forms at blocks of 32 and 16 pages, rows ending on,
+    one past and well inside a block, against the dense softmax; and
+    the same call walked 128 tokens a block, as it was before the block
+    followed the score tile: a head over the results picks the same
+    token for every row."""
+    H, KV, hd, hd_v, W = _LONG_BLOCKS[form]
+    T, q, k, v, tables, pos = _long_block_case(H, KV, hd, hd_v, W, seed=41)
+    assert T == (256 if H == 128 else 512) and 2 * T < W * 16
+    (kp, vp), _, (kf, vf) = _pools(k, v, dtype, False)
+    qd = jnp.asarray(q).astype(dtype)
+
+    def run():
+        pa._build_attention.cache_clear()
+        return np.asarray(pa.paged_decode_attention(
+            qd, _folded(kp), _folded(vp), jnp.asarray(tables),
+            jnp.asarray(pos), 0, interpret=True), np.float32)
+
+    out = run()
+    live = pos >= 0
+    ref = _dense_reference(np.asarray(qd, np.float32)[live],
+                           _rows(kf, tables)[live], _rows(vf, tables)[live],
+                           pos[live])
+    np.testing.assert_allclose(out[live], ref, rtol=tol, atol=tol)
+    assert not out[~live].any()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "_BLOCK_TOKENS", 128)
+        assert pa._pages_per_block(16, 1, H, W) == 8
+        short = run()
+    pa._build_attention.cache_clear()
+    np.testing.assert_allclose(out, short, rtol=tol, atol=tol)
+    head = np.random.default_rng(43).standard_normal(
+        (H * hd_v, 97)).astype(np.float32)
+    np.testing.assert_array_equal(
+        (out.reshape(len(pos), -1) @ head)[live].argmax(-1),
+        (short.reshape(len(pos), -1) @ head)[live].argmax(-1))
+
+
+def _shape(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _per_head(B, H, KV, int8=False):
+    """(q, pools, int8 scales) of a per-head form, as shapes."""
+    pool = _shape(1, 4, 16, KV, 128, dtype=jnp.int8 if int8 else jnp.bfloat16)
+    scale = _shape(1, 4, 16, KV, dtype=jnp.float32)
+    return (_shape(B, H, 128), (pool, pool),
+            {"k_scale": scale, "v_scale": scale} if int8 else {})
+
+
+def _one_head(B, H, hd, *lanes):
+    """... of a form the kernel sees as one kv head: a folded K and V
+    pool, or the ONE latent pool."""
+    return (_shape(B, H, hd), tuple(_shape(1, 4, 16, n) for n in lanes), {})
+
+
+def _attend(q, pools, tables, pos, **scales):
+    if len(pools) == 1:
+        return pa.mla_paged_decode_attention(
+            q, *pools, tables, pos, 0, value_dim=512, scale=192 ** -0.5)
+    return pa.paged_decode_attention(q, *pools, tables, pos, 0, **scales)
+
+
+# every form the engine serves, at its cell's (or `chip_smoke.py`'s)
+# shapes: the call's shapes, the table's width, and (pages a block, H,
+# KV as the kernel sees it)
+_BLOCK_TABLE = {
+    "mistral7b-w16": (_per_head(64, 32, 8), 16, (8, 32, 8)),
+    "mistral7b-w81": (_per_head(64, 32, 8), 81, (8, 32, 8)),
+    "mistral7b-int8": (_per_head(64, 32, 8, int8=True), 81, (8, 32, 8)),
+    "llama1b4": (_per_head(32, 16, 16), 11, (8, 16, 16)),
+    "llama1b4-int8": (_per_head(32, 16, 16, int8=True), 11, (8, 16, 16)),
+    "kanana2-latent": (_one_head(64, 32, 576, 640), 145, (32, 32, 1)),
+    "mimo25-folded": (_one_head(128, 64, 192, 768, 512), 545, (32, 64, 1)),
+    "lfm2-folded": (_one_head(128, 32, 64, 512, 512), 81, (32, 32, 1)),
+    "sdar-folded": (_one_head(128, 128, 128, 512, 512), 81, (16, 128, 1)),
+    "a-table-narrower-than-a-block": (
+        _one_head(128, 64, 192, 768, 512), 5, (5, 64, 1)),
+}
+
+
+@pytest.mark.parametrize("form", sorted(_BLOCK_TABLE))
+def test_the_block_is_the_longest_the_shapes_allow(form, monkeypatch):
+    """The rule's table, read where the kernels ask it: every form's
+    call is traced at its serving shapes (no kernel runs) and each
+    question put to `_pages_per_block` is kept.  The per-head forms,
+    int8 or not, and the latent one get the pages they always got; the
+    folded ones the longest block their score tile allows; and the int8
+    wrapper, which lays the scales out by blocks, asks with the
+    arguments the kernel's builder asks with."""
+    (q, pools, scales), W, (pages, H, KV) = _BLOCK_TABLE[form]
+    asked = []
+    rule = pa._pages_per_block
+
+    def keep(*a):
+        asked.append((a, rule(*a)))
+        return asked[-1][1]
+
+    monkeypatch.setattr(pa, "_pages_per_block", keep)
+    pa._build_attention.cache_clear()
+    B = q.shape[0]
+    jax.eval_shape(_attend, q, pools, _shape(B, W, dtype=jnp.int32),
+                   _shape(B, dtype=jnp.int32), **scales)
+    pa._build_attention.cache_clear()
+    assert len(asked) == (2 if scales else 1)
+    assert set(asked) == {((16, KV, H, W), pages)}
 
 
 def test_append_writes_one_row_and_preserves_rest():
