@@ -1,0 +1,10 @@
+"""Share of the step programs' device time spent in attention (projections, norms, rotation, the flash kernels, the gate):
+the trace's time under the scopes attn_window, attn_full, forward and
+backward, over the time of the `jit_step` programs."""
+LAYER, UNIT, SOURCE, MOVES = "models", "%", "device_trace", "train_tokens_per_s"
+
+
+def read(ctx):
+    from benchmarks.layer_metrics._train_window_moe_common import scope_share
+
+    return scope_share(ctx, ('attn_window', 'attn_full'))

@@ -53,17 +53,22 @@ def _walk(group_sizes, m: int, tm: int):
             slot.astype(jnp.int32), nxt.astype(jnp.int32)), steps
 
 
-@functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
-def gmm(xs, w, group_sizes, *, row_tile: int = 128, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("row_tile", "interpret",
+                                             "transpose_rhs"))
+def gmm(xs, w, group_sizes, *, row_tile: int = 128, interpret: bool = False,
+        transpose_rhs: bool = False):
     """`xs` [M, K] (M a multiple of `row_tile`), `w` [G, K, N] with K
     and N whole in one tile, `group_sizes` [G] int32 summing to M or
     less: rows past the last group belong to none, are never computed,
-    and come back as whatever was there."""
+    and come back as whatever was there.  `transpose_rhs`: `w` is `[G,
+    N, K]` and a group's rows are multiplied by its matrix transposed
+    (the input's gradient of the product with `w`, no copy of `w`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (m, k), (_, _, n), tm = xs.shape, w.shape, row_tile
-    if m % tm or w.shape[1] != k:
+    (m, k), tm = xs.shape, row_tile
+    n = w.shape[1] if transpose_rhs else w.shape[2]
+    if m % tm or w.shape[2 if transpose_rhs else 1] != k:
         raise ValueError(f"xs {xs.shape} against w {w.shape} at {tm} rows "
                          "a tile")
     size = jnp.dtype(xs.dtype).itemsize
@@ -94,8 +99,13 @@ def gmm(xs, w, group_sizes, *, row_tile: int = 128, interpret: bool = False):
             def _the_next_has_this_groups_steps_to_arrive():
                 matrix(nxt[i], 1 - s).start()
 
-        acc = jnp.dot(x_ref[...], w_buf[s],
-                      preferred_element_type=jnp.float32)
+        if transpose_rhs:
+            acc = lax.dot_general(x_ref[...], w_buf[s],
+                                  (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        else:
+            acc = jnp.dot(x_ref[...], w_buf[s],
+                          preferred_element_type=jnp.float32)
         row = tids[i] * tm + lax.broadcasted_iota(jnp.int32, (tm, n), 0)
         mine = (row >= offsets[g]) & (row < offsets[g + 1])
         # a tile is revisited by the groups that share it, one after
@@ -115,7 +125,7 @@ def gmm(xs, w, group_sizes, *, row_tile: int = 128, interpret: bool = False):
             in_specs=[pl.BlockSpec((tm, k), row_tile_of),
                       pl.BlockSpec(memory_space=pltpu.HBM)],
             out_specs=pl.BlockSpec((tm, n), row_tile_of),
-            scratch_shapes=[pltpu.VMEM((2, k, n), w.dtype),
+            scratch_shapes=[pltpu.VMEM((2,) + w.shape[1:], w.dtype),
                             pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), xs.dtype),
@@ -123,3 +133,48 @@ def gmm(xs, w, group_sizes, *, row_tile: int = 128, interpret: bool = False):
             dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
         interpret=interpret,
     )(*walk, xs, w)
+
+
+# (rows, K, N) tile of the matrices' gradient: the accumulator and the
+# result's tile are `[K tile, N tile]` float32
+TGMM_TILING = (512, 512, 1024)
+
+
+def _rows_with_a_group(x, group_sizes):
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(row < jnp.sum(group_sizes), x, jnp.zeros_like(x))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def grouped_product(xs, w, group_sizes, row_tile: int = 128,
+                    interpret: bool = False):
+    """`gmm(xs, w.astype(xs.dtype), group_sizes)` that can be
+    differentiated: `xs` [M, K] sorted by group, `w` [G, K, N] (the
+    parameters' dtype; its gradient comes back in it), `group_sizes`
+    [G].  Rows past the last group are zeros."""
+    return _product_fwd(xs, w, group_sizes, row_tile, interpret)[0]
+
+
+def _product_fwd(xs, w, group_sizes, row_tile, interpret):
+    out = gmm(xs, w.astype(xs.dtype), group_sizes, row_tile=row_tile,
+              interpret=interpret)
+    return _rows_with_a_group(out, group_sizes), (xs, w, group_sizes)
+
+
+def _product_bwd(row_tile, interpret, res, dy):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    xs, w, group_sizes = res
+    dy = dy.astype(xs.dtype)
+    dxs = gmm(dy, w.astype(xs.dtype), group_sizes, row_tile=row_tile,
+              interpret=interpret, transpose_rhs=True)
+    k, n = w.shape[1:]
+    tm, tk, tn = TGMM_TILING
+    dw = tgmm(xs.swapaxes(0, 1), dy, group_sizes,
+              preferred_element_type=jnp.float32,
+              tiling=(min(tm, xs.shape[0]), min(tk, k), min(tn, n)),
+              interpret=interpret)
+    return _rows_with_a_group(dxs, group_sizes), dw.astype(w.dtype), None
+
+
+grouped_product.defvjp(_product_fwd, _product_bwd)

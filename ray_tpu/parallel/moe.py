@@ -14,12 +14,16 @@ TRAINS (`models/mixtral.py`).  `dropless_moe` at the end of this file
 is the one that SERVES (`models/deepseek_v3.py` through the engine):
 sigmoid scores, every token reaches all of its experts whatever the
 load, SwiGLU experts as grouped products over tokens sorted by expert.
-Do not take one for the other.
+Do not take one for the other.  `dropless_moe_train` below it is the
+serving layer's held share (`held=`) with a backward: the same pick,
+the same weights, no pair dropped, what absent experts would add left
+out (`models/afmoe.py` trains through it).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -493,3 +497,159 @@ def _held_slabs(h, w, order, sizes, swiglu, *, top_k: int, rows: int,
         lambda carry: carry[0] < total, slab,
         (jnp.int32(0), jnp.zeros((N, D), jnp.float32), jnp.int32(0)))
     return y, visits, lo // rows
+
+
+# ----------------------------------------------------------------------
+# the same held share, trained
+# ----------------------------------------------------------------------
+# rows a grouped product of the trained share takes a grid step: its
+# groups hold a thousand rows and more, so a tile is as tall as the
+# accumulator allows and a group's end inside a tile costs little
+TRAIN_ROW_TILE = 256
+
+
+def train_slab_rows(pairs: int, held: int, experts: int,
+                    tile: int = TRAIN_ROW_TILE) -> int:
+    """Sorted rows a slab of the trained share takes: twice the mean
+    the held experts get of `pairs` (token, expert) pairs, in whole row
+    tiles, and never more than all the pairs.  Static shapes alone, as
+    `slab_rows`; there is no pair-wide form under it."""
+    up = lambda x: -(-x // tile) * tile  # noqa: E731
+    return min(up(max(2 * pairs * held // experts, 1)), up(pairs))
+
+
+def dropless_moe_train(h, layer: Dict, bias, *, top_k: int, scale: float,
+                       route_eps: float, dtype, held: Tuple[int, int],
+                       kernel: bool = False, interpret: bool = False):
+    """`dropless_moe(held=)` that can be differentiated: h [N, D] ->
+    (y [N, D] float32, stats).  `layer`: `router` [D, E_all] float32,
+    `e_gate` / `e_up` [count, D, I], `e_down` [count, I, D] in the
+    parameters' dtype (float32: `grouped_product` rounds them to `dtype`
+    inside and returns their gradients unrounded); `bias` [E_all] is the
+    router's balancing bias, an input with NO gradient (it only moves
+    the pick).  `held` = `(offset, count)`: the experts this chip holds.
+
+    The pick is `sigmoid_topk_route`'s: the `top_k` of `sigmoid(h W) +
+    bias` over ALL `E_all` experts, the weights the picked scores
+    WITHOUT the bias over their sum, times `scale`, all in float32.  A
+    pair whose expert is not held adds nothing here: `y` is this chip's
+    partial sum and nothing stands in for the absent chips.
+
+    NO pair of a held expert is dropped whatever the load, with static
+    shapes: the held pairs are the first `sum(sizes)` rows of the pairs
+    sorted by expert, and they are walked in slabs of `rows =
+    train_slab_rows(...)` sorted rows (`_held_slabs_train`): as many
+    slabs as have work, one where the load is up to twice its mean, all
+    `ceil(N * top_k / rows)` where every pair is held.
+
+    `stats`: `counts` [E_all] int32 (the pairs EVERY expert of the
+    router was picked for: the bias's rule and the load counters read
+    it), `held_pairs` (pairs of held experts: the rows the grouped
+    products took), `slabs` (slabs that had work)."""
+    N, D = h.shape
+    E_all = layer["router"].shape[-1]
+    lo, E = held
+    pairs = N * top_k
+    tile = TRAIN_ROW_TILE if kernel else ROW_TILE
+    rows = train_slab_rows(pairs, E, E_all, tile)
+    with jax.named_scope("moe_router"):
+        w, idx = sigmoid_topk_route(
+            h, layer["router"], lax.stop_gradient(bias), top_k, scale,
+            route_eps)
+        # a compare and a column sum: a scatter of N * top_k ones is a
+        # serial walk on the chip
+        counts = jnp.sum(
+            (idx.reshape(-1, 1) == jnp.arange(E_all, dtype=jnp.int32)
+             ).astype(jnp.int32), axis=0)
+        local = jnp.where((idx >= lo) & (idx < lo + E), idx - lo, E)
+        order = jnp.argsort(local.reshape(-1), stable=True)  # row -> pair
+        order = jnp.pad(order, (0, -pairs % rows)).astype(jnp.int32)
+        sizes = lax.dynamic_slice(counts, (lo,), (E,))
+    with jax.named_scope("moe_routed"):
+        y = _held_slabs_train(
+            (top_k, rows, tile, dtype, kernel, interpret),
+            h.astype(dtype), w.reshape(-1), layer["e_gate"], layer["e_up"],
+            layer["e_down"], order, sizes)
+    total = jnp.sum(sizes)
+    stats = {"counts": counts, "held_pairs": total,
+             "slabs": -(-total // rows)}
+    return y, stats
+
+
+def _train_slab(static, hx, wf, e_gate, e_up, e_down, order, sizes, at):
+    """What the sorted rows `[at, at + rows)` add to `y`: `[N, D]`
+    float32, zeros but for their tokens' rows."""
+    from ray_tpu.ops.grouped_matmul import grouped_product
+
+    top_k, rows, tile, dtype, kernel, interpret = static
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    here = jnp.clip(ends, at, at + rows) - jnp.clip(starts, at, at + rows)
+
+    def product(xs, mat):
+        if kernel:
+            return grouped_product(xs, mat, here, tile, interpret)
+        ys = lax.ragged_dot(xs, mat.astype(dtype), here)
+        # `ragged_dot` leaves zeros past the last group on every
+        # backend; the where keeps that a property of THIS function
+        row = lax.broadcasted_iota(jnp.int32, ys.shape, 0)
+        return jnp.where(row < jnp.sum(here), ys, jnp.zeros_like(ys))
+
+    pair = lax.dynamic_slice(order, (at,), (rows,))
+    tok = pair // top_k
+    xs = hx[tok]
+    act = jax.nn.silu(product(xs, e_gate)) * product(xs, e_up)
+    ys = product(act, e_down).astype(jnp.float32)
+    paired = (at + jnp.arange(rows, dtype=jnp.int32) < ends[-1])[:, None]
+    return jnp.zeros((hx.shape[0], hx.shape[1]), jnp.float32).at[tok].add(
+        jnp.where(paired, ys * wf[pair][:, None], 0.0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_slabs_train(static, hx, wf, e_gate, e_up, e_down, order, sizes):
+    """The held pairs' sum into `y [N, D]` float32, a slab of `rows`
+    sorted rows at a time, forward and backward a `lax.while_loop` over
+    the slabs THAT HAVE WORK (`_held_slabs`' walk; a `while_loop` has no
+    reverse of its own, so this function brings it).  The first slab
+    always runs, and its own `jax.vjp` is what the backward pass starts
+    from: in the normal case, one slab, the layer's experts are computed
+    once a pass and nothing is recomputed here.  A later slab keeps
+    nothing: the backward pass walks the same slabs again and takes each
+    one's `jax.vjp` there, its gradients added into the first slab's
+    (one set of buffers, however many slabs).  What the bound costs: a
+    gather and a scatter-add of `rows` rows a slab whatever the load
+    (the padding past the last pair is never multiplied: the grouped
+    products walk tiles with rows only), and one more forward of every
+    slab past the first."""
+    return _slabs_fwd(static, hx, wf, e_gate, e_up, e_down, order, sizes)[0]
+
+
+def _slabs_fwd(static, hx, wf, e_gate, e_up, e_down, order, sizes):
+    rows, total = static[1], jnp.sum(sizes)
+    diff = (hx, wf, e_gate, e_up, e_down)
+    y, first = jax.vjp(lambda *a: _train_slab(
+        static, *a, order, sizes, jnp.int32(0)), *diff)
+    _, y = lax.while_loop(
+        lambda c: c[0] < total,
+        lambda c: (c[0] + rows, c[1] + _train_slab(
+            static, *diff, order, sizes, c[0])),
+        (jnp.int32(rows), y))
+    return y, (first, diff, order, sizes)
+
+
+def _slabs_bwd(static, res, dy):
+    first, diff, order, sizes = res
+    rows, total = static[1], jnp.sum(sizes)
+
+    def later(c):
+        at, grads = c
+        _, back = jax.vjp(lambda *a: _train_slab(
+            static, *a, order, sizes, at), *diff)
+        return at + rows, jax.tree.map(jnp.add, grads, back(dy))
+
+    _, grads = lax.while_loop(lambda c: c[0] < total, later,
+                              (jnp.int32(rows), first(dy)))
+    return (*grads, None, None)
+
+
+_held_slabs_train.defvjp(_slabs_fwd, _slabs_bwd)

@@ -968,3 +968,85 @@ def test_block_diffusion_programs_at_the_cells_shapes(chip):
                    rows[0], state, rows[1], **donate)
     assert "jit_prefill_packed_n1296" in hlo and "input_output_alias" in hlo
     assert "bf16[128,2048,768]" not in hlo and "bf16[128,768,2048]" not in hlo
+
+
+# ----------------------------------------------------------------------
+# the trained sparse cell: grouped, windowed flash kernels and the
+# grouped product with its backward, at `trinity_mini_train_8k`'s shapes
+# ----------------------------------------------------------------------
+def _sparse_train_cell():
+    from benchmarks import manifest
+
+    cell = manifest.cell("trinity_mini_train_8k")
+    return manifest.config(cell["config"]), manifest.traffic(cell["traffic"])
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_grouped_windowed_flash_lowers_at_the_cells_shapes(chip, window):
+    """32 query heads over 4 KV heads of 128 at 8,192 tokens: the
+    forward (its log-sum-exp transposed to a row of lanes in the
+    kernel) and the split backward, under the names the cell's plane
+    looks for; and no `flash_fwd` / `flash_bwd` of GPT-2's readers."""
+    from benchmarks.planes import train_window_moe as plane
+
+    cfg, mix = _sparse_train_cell()
+    m = cfg["model"]
+    B, T = int(mix["batch"]), int(mix["seq"])
+    H, KV, D = (m["num_attention_heads"], m["num_key_value_heads"],
+                m["head_dim"])
+
+    def grads(q, k, v, do):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, True, 1024, 1024, False, window).astype(jnp.float32)
+            * do), (0, 1, 2))(q, k, v)
+
+    hlo = _compile(chip, grads, _s(B, T, H, D), _s(B, T, KV, D),
+                   _s(B, T, KV, D), _s(B, T, H, D, dtype=jnp.float32))
+    calls = [l.strip() for l in hlo.splitlines() if " custom-call(" in l
+             and "tpu_custom_call" in l]
+    preds = plane.kernel_predicates(cfg, mix)
+    found = {k: [c for c in calls if p(c)] for k, p in preds.items()}
+    assert len(found["afmoe_flash_fwd"]) == 1
+    assert len(found["afmoe_flash_bwd"]) == 2    # dq, and dk with dv
+    assert not found["afmoe_gmm"] and not found["afmoe_tgmm"]
+    # the forward's second result is rows of lanes, not `[.., T, 1]`
+    assert f"f32[{B * KV},{T // 256},1,2048]" in found["afmoe_flash_fwd"][0]
+
+
+def test_the_grouped_product_and_its_backward_lower(chip):
+    """One slab of the cell's held experts (16 of 2,048 x 1,024, 16,384
+    sorted rows): `gmm` forward, `gmm` against the matrices transposed
+    for the rows' gradient, megablox's `tgmm` for the matrices' in
+    float32, each under the name the cell's plane looks for."""
+    from benchmarks.planes import train_window_moe as plane
+    from ray_tpu.ops.grouped_matmul import grouped_product
+    from ray_tpu.parallel import moe
+
+    cfg, mix = _sparse_train_cell()
+    m = cfg["model"]
+    E, D, I = m["num_experts"], m["hidden_size"], m["moe_intermediate_size"]
+    rows = moe.train_slab_rows(
+        int(mix["batch"]) * int(mix["seq"]) * m["num_experts_per_tok"], E,
+        cfg["deployment"]["router_experts"])
+    assert rows == 16384
+
+    def grads(xs, gate, down, sizes, dy):
+        def f(xs, gate, down):
+            act = grouped_product(xs, gate, sizes, moe.TRAIN_ROW_TILE)
+            return jnp.sum(grouped_product(
+                jax.nn.silu(act), down, sizes, moe.TRAIN_ROW_TILE
+            ).astype(jnp.float32) * dy)
+        return jax.grad(f, (0, 1, 2))(xs, gate, down)
+
+    hlo = _compile(
+        chip, grads, _s(rows, D), _s(E, D, I, dtype=jnp.float32),
+        _s(E, I, D, dtype=jnp.float32), _s(E, dtype=jnp.int32),
+        _s(rows, D, dtype=jnp.float32))
+    calls = [l.strip() for l in hlo.splitlines() if " custom-call(" in l
+             and "tpu_custom_call" in l]
+    preds = plane.kernel_predicates(cfg, mix)
+    # the first product forward (the last one's VALUE is not needed by
+    # its gradient), and both against the matrices transposed
+    assert len([c for c in calls if preds["afmoe_gmm"](c)]) == 3
+    tg = [c for c in calls if preds["afmoe_tgmm"](c)]
+    assert len(tg) == 2 and all(f"f32[{E}," in c for c in tg)

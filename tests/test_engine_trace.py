@@ -930,7 +930,7 @@ def test_the_paged_kernels_lower_under_their_names():
 
 @pytest.mark.parametrize("T,block,names", [
     (512, 512, ("flash_fwd", "flash_bwd_fused")),
-    (512, 256, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+    (512, 256, ("flash_fwd", "flash_bwd_dq_grouped", "flash_bwd_dkv_grouped")),
 ])
 def test_the_flash_kernels_lower_under_their_names(T, block, names):
     from ray_tpu.ops import flash_attention

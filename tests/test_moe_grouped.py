@@ -297,3 +297,62 @@ def test_a_held_share_moves_only_its_pairs(monkeypatch, case, top_k, N, held,
                                atol=5e-5, rtol=5e-5)
     if not passes:
         assert not y.any()
+
+
+# ---------------------------------------------------------------------
+# the same held share, trained: `dropless_moe_train`
+# ---------------------------------------------------------------------
+TRAINED = [
+    # share of the rows sent here, top_k, tokens, (lo, n), kernels, slabs
+    ("random", 4, 256, (0, 8), False, 1),
+    ("random", 4, 256, (8, 8), True, 1),
+    (0.75, 2, 512, (4, 2), False, 3),      # more than a slab's worth
+    (1.0, 2, 512, (0, 2), False, 4),       # every pair is held: all slabs
+    (1.0, 2, 256, (0, 2), True, 2),
+    (0.0, 2, 256, (8, 2), False, 0),       # and none is
+]
+
+
+@pytest.mark.parametrize(
+    "case,top_k,N,held,kernel,slabs", TRAINED,
+    ids=[f"{c[0]}-k{c[1]}-n{c[2]}-held{c[3][0]}+{c[3][1]}"
+         f"{'-kernels' if c[4] else ''}" for c in TRAINED])
+def test_the_trained_share_is_the_serving_share_with_a_gradient(
+        case, top_k, N, held, kernel, slabs):
+    """`dropless_moe_train` gives `dropless_moe(held=)`'s result for ANY
+    routing (the same pick, the same weights, no pair dropped: a router
+    that sends this share several slabs' worth walks them all), counts
+    the router's experts as the serving layer's `stats` do, and its
+    gradient by the rows AND by the held matrices is `jax.grad`'s of the
+    pair-wide `ragged_dot` form."""
+    E, (lo, n) = 16, held
+    whole = _layer(E, False)
+    layer = {**whole, **{k: whole[k][lo:lo + n]
+                         for k in ("e_gate", "e_up", "e_down")}}
+    h = _held_rows(case, N, lo, n)
+    kw = dict(top_k=top_k, scale=1.5, route_eps=1e-6, dtype=jnp.float32,
+              held=held)
+    mats = {k: layer[k] for k in ("e_gate", "e_up", "e_down")}
+
+    def trained(h, mats):
+        return moe.dropless_moe_train(
+            h, {**layer, **mats}, layer["router_bias"], kernel=kernel,
+            interpret=kernel, **kw)
+
+    def served(h, mats):  # the pair-wide form: `ragged_dot`, no loop
+        return moe.dropless_moe(h, {**layer, **mats}, **kw)
+
+    (y, stats), (want, served_stats) = trained(h, mats), served(h, mats)
+    np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-5)
+    assert int(stats["slabs"]) == slabs
+    _, idx = moe.sigmoid_topk_route(h, layer["router"], layer["router_bias"],
+                                    top_k, 1.5, 1e-6)
+    counts = np.bincount(np.asarray(idx).ravel(), minlength=E)
+    np.testing.assert_array_equal(stats["counts"], counts)
+    assert int(stats["held_pairs"]) == counts[lo:lo + n].sum()
+    assert int(served_stats["load_max"]) == counts[lo:lo + n].max()
+    dy = jax.random.normal(jax.random.PRNGKey(9), y.shape)
+    got = jax.grad(lambda *a: jnp.sum(trained(*a)[0] * dy), (0, 1))(h, mats)
+    ref = jax.grad(lambda *a: jnp.sum(served(*a)[0] * dy), (0, 1))(h, mats)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(a, b, atol=3e-4, rtol=3e-4)

@@ -163,6 +163,28 @@ def test_moe_expert_parallel_matches_local():
     )
 
 
+@pytest.mark.parametrize("bq,bk", [(32, 32), (16, 32), (32, 16), (16, 64)])
+def test_flash_attention_grad_split_noncausal(bq, bk):
+    """Past the fused kernel's limit a call that is NOT causal takes
+    the same split pair (a group of one head in the rows): every q block
+    walks every kv block, and none is masked."""
+    q, k, v = _qkv(T=64)
+    w = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+
+    def f_flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, False, bq, bk, True) * w)
+
+    def f_plain(q, k, v):
+        return jnp.sum(plain_attention(q, k, v, causal=False) * w)
+
+    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(f_plain, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3
+        )
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_grad_fused_single_tile(causal):
     """blocks == T dispatches the FUSED single-tile backward (one
