@@ -65,6 +65,13 @@ TPU-native design points:
   into the cache and stays where it is (`serve/engine_model.py`); the
   host never tells the device that a row ended.  `tick_ring` counts
   both kinds of row-step (`row_steps_live`, `row_steps`).
+- A FINISHED ROW'S SLOT DOES NOT WAIT FOR ITS HARVEST: budgets are
+  deterministic, so the dispatch that moves a row's mirror `pos_host`
+  to its `stop` is the dispatch of its last wanted step, and the slot,
+  the blocks and the trie path go back right there (`_hand_off`) for
+  the next tick's admission; the request drains in `_handed` until
+  that chunk's harvest resolves it.  Only where the DEVICE counts what
+  a chunk produced (`device_counts`) is a row released at its harvest.
 
 Greedy outputs are bit-identical to a dedicated `llama.generate` for
 the same prompt, with the prefix cache on or off
@@ -527,6 +534,14 @@ class LlamaEngine:
         # slot -> dict(fut, out, want, since, pos_host, blocks, ...)
         self._active: Dict[int, Dict] = {}
         self._slot_blocks: List[List[int]] = [[] for _ in range(slots)]
+        # (slot, request) of the requests HANDED OFF: their last wanted
+        # step lies in a chunk already dispatched, so slot, blocks and
+        # trie path went back at that dispatch (`_hand_off`); they wait
+        # here, out of `_active`, for that chunk's harvest.  By request,
+        # not by slot: a slot's old request may drain while its new one
+        # is active, or is handed off in its turn
+        self._handed: List[tuple] = []
+        self._handoffs_total = 0
         self._running = True
         self._pending_toks = None  # deferred-harvest chunk (see _loop)
         # requests popped from the queue but not yet admitted: they
@@ -860,15 +875,17 @@ class LlamaEngine:
         with self._ring_lock:
             ring = list(self._request_ring)
             finished = self._finished_total
+        unresolved = self._unresolved()
         return {
-                "active": len(self._active),
+                # (a request handed off is active until its harvest, as
+                # it was before slots went back at dispatch: it may be
+                # counted beside the slot's next holder)
+                "active": len(unresolved),
                 "queued": len(self._queue),
                 "free_slots": len(self._free),
-                "queue_depth": (len(self._active) + len(self._queue)
+                "queue_depth": (len(unresolved) + len(self._queue)
                                 + self._pending_admissions),
-                "live_tokens": sum(
-                    r["pos_host"] for r in self._active.values()
-                ),
+                "live_tokens": sum(r["pos_host"] for r in unresolved),
                 "blocks_total": self._pool.capacity,
                 "blocks_free": self._pool.free_blocks,
                 "blocks_cached": cached,
@@ -930,6 +947,9 @@ class LlamaEngine:
                 # record's `seq`, so a reader knows what the ring lost
                 "request_ring": ring,
                 "finished_total": finished,
+                # of the finished, those whose slot went back at the
+                # dispatch of their last chunk and not at its harvest
+                "handoffs_total": self._handoffs_total,
                 # overload plane (admission control + shedding):
                 # consumed by the SLO autoscaler and /api/serve
                 "max_queued": (-1 if self.max_queued is None
@@ -955,15 +975,24 @@ class LlamaEngine:
             self._wake.notify()
         self._thread.join(timeout=10)
         with self._lock:
-            for req in list(self._active.values()):
-                if not req["fut"].done():
-                    req["fut"].cancel()
-            self._active.clear()
+            self._cancel_unresolved()
         with self._wake:
             for item in self._queue:
                 if not item[2].done():
                     item[2].cancel()
             self._queue.clear()
+
+    def _unresolved(self) -> List[Dict]:
+        """The admitted requests whose future is open: those that hold a
+        slot, and those handed off that wait for their last harvest."""
+        return [*self._active.values(), *(req for _, req in self._handed)]
+
+    def _cancel_unresolved(self) -> None:
+        for req in self._unresolved():
+            if not req["fut"].done():
+                req["fut"].cancel()
+        self._active.clear()
+        self._handed.clear()
 
     # -- compiled-program families ------------------------------------
     def _chunk_step_for(self, W: int):
@@ -1210,6 +1239,7 @@ class LlamaEngine:
         self._active[slot] = req = {
             "fut": fut, "out": [], "want": n_new,
             "since": self._chunk_seq + 1,  # first chunk with its steps
+            "last": None,  # ... and the last, once handed off
             "pos_host": pos0, "stop": stop,
             "own_blocks": own_set, "tree_path": path,
             # the model's own fields of the request; where the device
@@ -1553,6 +1583,32 @@ class LlamaEngine:
             self._radix.release(req["tree_path"])
         self._pool.free(req["own_blocks"])
 
+    def _hand_off(self, slots: List[int]) -> None:
+        """The rows of `slots` reached their `stop` in the chunk just
+        dispatched, which the host KNOWS because its mirror of `pos` is
+        exact for this model: their requests leave `_active` for
+        `_handed`, where that chunk's harvest resolves them, and slot,
+        blocks and trie path go back NOW, a chunk before that harvest,
+        so that the next tick's admission budget counts the slot.
+
+        THE ONE PLACE THAT RELIES ON LAUNCH ORDER.  Every program is
+        launched from this thread, in program order, and takes the whole
+        cache and the slots' `pos` / `tok` / `stop` from the program
+        before it (donated: a data dependence, not only a stream).  The
+        chunk that holds these rows' last steps is already launched
+        (`last`), so whatever a later admission launches (a prefill into
+        a freed block or an evicted trie block, the slot's per-slot
+        state, its `pos` / `tok` / `stop`) runs strictly after the
+        row's last read and last write.  Until somebody takes the slot
+        it stays dead on the device (`pos == stop`) behind a zero row of
+        the table, and writes nothing."""
+        for slot in slots:
+            req = self._active.pop(slot)
+            req["last"] = self._chunk_seq
+            self._handed.append((slot, req))
+            self._release(slot, req)
+        self._handoffs_total += len(slots)
+
     # -- engine loop ---------------------------------------------------
     def _gather_width(self) -> int:
         """Blocks per slot the next chunk must see: covers every active
@@ -1580,8 +1636,11 @@ class LlamaEngine:
         now = _time.monotonic()  # ages the shed predictor's samples
         wall = _time.time()      # the lifecycle stamp of this harvest
         done = []
-        for slot, req in self._active.items():
-            if req["since"] > seq:
+        for slot, req in [*self._handed, *self._active.items()]:
+            # (a request handed off takes nothing past its last chunk:
+            # the slot's next holder owns the column from there)
+            if req["since"] > seq or (req["last"] is not None
+                                      and seq > req["last"]):
                 continue
             new, more = self._model.harvested(toks_host, slot,
                                               req["since"] == seq)
@@ -1612,10 +1671,14 @@ class LlamaEngine:
                 if req["tk"] is not None:
                     req["tk"].first_token(wall)
             if len(req["out"]) >= req["want"]:
-                done.append(slot)
-        for slot in done:
-            req = self._active.pop(slot)
-            self._release(slot, req)
+                done.append((slot, req))
+        finished = {id(req) for _, req in done}
+        self._handed = [(slot, req) for slot, req in self._handed
+                        if id(req) not in finished]
+        for slot, req in done:
+            if req["last"] is None:  # not handed off: released here
+                del self._active[slot]
+                self._release(slot, req)
             out = req["out"][:req["want"]]
             if self._model.device_counts:
                 out = Generated(out)
@@ -1704,13 +1767,20 @@ class LlamaEngine:
             live_now = bool(self._active)
             W = (int(live_now) if not self._has_blocks
                  else self._gather_width() if live_now else 0)
+            # the rows whose last wanted step lies in the chunk about to
+            # be dispatched: their slots go back at its dispatch.  Not
+            # where the device counts: the mirror is then only a bound
+            # and a row's end is known at its harvest
+            ending = [] if self._model.device_counts else [
+                slot for slot, req in self._active.items()
+                if req["pos_host"] + self._model.advance >= req["stop"]]
         toks = None
         # of the chunk's slots x chunk row-steps, those a request was
         # waiting for (its steps before its stop); the rest are dead
         row_steps = row_steps_live = rows_live = rows_flushed = 0
         contexts: List[int] = []  # of the rows live at its first step
         if W:
-            with self._phase("dispatch", W=W):
+            with self._phase("dispatch", W=W, handed_off=len(ending)):
                 tables = ()
                 if self._has_blocks:
                     with self._lock:
@@ -1749,12 +1819,16 @@ class LlamaEngine:
                 else:
                     self._decode_gather_dispatches += 1
                 self._chunk_seq += 1
+                with self._lock:
+                    self._hand_off(ending)
         # OVERLAP: harvest the PREVIOUS chunk's tokens while the
         # current chunk computes — the device->host read is round-trip
         # latency (measured at ~half the synced chunk wall time on an
         # earlier remote device), and the dispatch above is async, so
-        # the read rides under the compute.  Cost: finish detection
-        # lags one chunk.
+        # the read rides under the compute.  Cost: a request's tokens
+        # reach its caller a chunk late; its SLOT went back at the
+        # dispatch of its last chunk (`_hand_off`), except where the
+        # device counts.
         model_fields: Dict[str, object] = {}
         t_read = None  # when the wait for the device returned
         if self._pending_toks is not None:
@@ -1782,6 +1856,8 @@ class LlamaEngine:
             "seq": self._chunk_seq,
             "t_wall": t_wall,  # wall clock at the tick's start
             "admitted": len(admissions),
+            # requests whose slot went back at this tick's dispatch
+            "handed_off": len(ending),
             "gather_blocks": W if self._has_blocks else 0,
             # rows that owed a token at the chunk's first step: for
             # per-slot leaves, the states its first step moves
@@ -1859,10 +1935,10 @@ class LlamaEngine:
             else 0.8 * self._tick_ema_s + 0.2 * tick_s
         )
         with self._lock:  # keeps stats() and its snapshot whole
+            unresolved = self._unresolved()
             rec.update(
-                active=len(self._active), queued=len(self._queue),
-                live_tokens=sum(
-                    r["pos_host"] for r in self._active.values()))
+                active=len(unresolved), queued=len(self._queue),
+                live_tokens=sum(r["pos_host"] for r in unresolved))
             done = (self._prefill_calls, self._prefill_rows,
                     self._prefill_tokens, self._prefill_padded_tokens)
             if done[0] != self._prefilled_mark[0]:
@@ -1952,7 +2028,10 @@ class LlamaEngine:
         self._new_tick(_cpu_now(), None)
         while True:
             with self._wake:
+                # (a request handed off waits for a chunk in flight:
+                # the loop ticks on, dispatching nothing, to harvest it)
                 while (self._running and not self._active
+                       and not self._handed
                        and not (self._queue and self._free)):
                     # blocked before a tick: carried into the tick
                     # that follows (summed over the wake-ups)
@@ -1969,10 +2048,7 @@ class LlamaEngine:
                             item[2].cancel()
                     self._queue.clear()
                     with self._lock:
-                        for req in self._active.values():
-                            if not req["fut"].done():
-                                req["fut"].cancel()
-                        self._active.clear()
+                        self._cancel_unresolved()
                     return
                 admissions = []
                 # bound by the FREE SLOTS, not just the cap: _admit
@@ -1990,19 +2066,21 @@ class LlamaEngine:
                 # tick_ring and request_ring
                 wall_ns = _time.time_ns()
                 with self._span("engine.tick", seq=self._chunk_seq,
-                                active=len(self._active),
+                                active=(len(self._active)
+                                        + len(self._handed)),
                                 admitted=len(admissions),
                                 wall_ns=wall_ns):
                     self._tick(admissions, wall_ns * 1e-9)
             except Exception as e:  # engine must not die silently
                 logger.exception("llm engine tick failed; failing %d "
-                                 "active request(s)", len(self._active))
+                                 "active request(s)",
+                                 len(self._active) + len(self._handed))
                 self._pending_toks = None
                 # the failed tick leaves no record: its account is void
                 self._new_tick(_cpu_now(), None)
                 wall = _time.time()
                 with self._lock:
-                    for slot, req in list(self._active.items()):
+                    for req in self._unresolved():
                         self._record("error", req["t_submit"], wall,
                                      req["tokens_in"], req)
                         if not req["fut"].done():
@@ -2014,6 +2092,7 @@ class LlamaEngine:
                         if not fut.done():
                             fut.set_exception(e)
                     self._active.clear()
+                    self._handed.clear()
                     self._free = list(range(self.slots))
                     self._slot_blocks = [[] for _ in range(self.slots)]
                     self._pending_admissions = 0

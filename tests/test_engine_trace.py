@@ -240,8 +240,10 @@ def test_tick_records_count_the_row_steps_that_were_owed(engine, script):
     """`row_steps` is what a chunk computes (slots x chunk, 0 in a tick
     that dispatched none), `row_steps_live` what requests were waiting
     for: a request of n tokens owes n - 1 decode steps (its first token
-    is the prefill's), wherever its budget ends in a chunk, and a row
-    that is finished but not harvested yet owes none."""
+    is the prefill's), wherever its budget ends in a chunk.  No chunk
+    runs for a finished row's sake: a row's slot goes back at the
+    dispatch of its last chunk, and the tick that harvests the last of
+    them dispatches nothing."""
     futs = [engine.submit(_prompt(i, n), new)
             for i, (n, new) in enumerate(script)]
     for f in futs:
@@ -255,8 +257,14 @@ def test_tick_records_count_the_row_steps_that_were_owed(engine, script):
         assert t["live_tokens"] <= sum(n + new - 1 for n, new in script)
     assert sum(t["row_steps_live"] for t in ring) == sum(
         new - 1 for _, new in script)
-    # the chunk in flight while the last harvest lagged was all dead
-    assert any(t["row_steps"] and not t["row_steps_live"] for t in ring)
+    # (a request of ONE token owes no step: the chunk that carries its
+    # prefill's token to the host is all dead)
+    dead = [t for t in ring if t["row_steps"] and not t["row_steps_live"]]
+    assert len(dead) == (script == [(3, 1)])
+    assert sum(t["handed_off"] for t in ring) == len(script)
+    last = ring[-1]  # the harvest of the last chunk, and nothing else
+    assert (last["row_steps"], last["active"], last["launches"]) == (0, 0, 0)
+    assert last["seq"] == ring[-2]["seq"] == engine.stats()["ticks"]
 
 
 # ----------------------------------------------------------------------
@@ -403,7 +411,9 @@ def test_the_account_is_the_ring_summed_by_the_second(model, monkeypatch):
     assert tuple(fields) == ACCOUNT_COLUMNS
     secs = [r[0] for r in rows]
     assert secs == sorted(set(secs)) and 3 <= len(secs) <= 4
-    assert sum(r[1] for r in rows) == len(s["tick_ring"]) == s["ticks"]
+    assert sum(r[1] for r in rows) == len(s["tick_ring"])
+    # (`ticks` counts the chunks: a tick that only harvests has none)
+    assert s["ticks"] == sum(bool(t["row_steps"]) for t in s["tick_ring"])
     for r in rows:
         got = dict(zip(fields, r))
         want = _summed([t for t in s["tick_ring"]
@@ -446,7 +456,9 @@ def test_a_stalled_tick_is_kept_whole_with_its_neighbours(
     _serve(engine, 12, new=9)
     assert engine.stats()["stalls"] == []
     assert engine.stats()["tick_ema_s"] < 0.01
-    reads.once = 0.4  # the next read: of the chunk left in flight
+    # the next read: of the request's first chunk, in its second tick
+    # (an idle engine leaves no chunk in flight)
+    reads.once = 0.4
     engine.submit(_prompt(99), 9).result(timeout=60)  # no prefix hit
     s = engine.stats()
     (stall,) = s["stalls"]
@@ -458,9 +470,10 @@ def test_a_stalled_tick_is_kept_whole_with_its_neighbours(
     assert stall["before"]["seq"] == tick["seq"] - 1
     assert stall["after"]["seq"] == tick["seq"] + 1
     assert "stalled" not in stall["before"] and "stalled" not in stall["after"]
-    # the chunk it waited for, and what it had just launched itself
+    # the tick before's programs, the chunk it waited for the last of
+    # them, and what it had just launched itself
     assert [n.rstrip("0123456789") for n in stall["in_flight"]] == [
-        "decode_chunk_w", "prefill_packed_n", "decode_chunk_w"]
+        "prefill_packed_n", "decode_chunk_w", "decode_chunk_w"]
     by_seq = {t["seq"]: t for t in s["tick_ring"]}
     assert by_seq[tick["seq"]] == tick  # the ring holds the same record
     assert _account_total(s)["stalled"] == 1
@@ -806,6 +819,38 @@ def test_a_profiler_session_records_the_loops_spans_nested(engine, tmp_path):
             assert rec[phase + "_s"] <= spanned + 1e-6, phase
             assert spanned - rec[phase + "_s"] < 5e-4, phase
     assert matched >= 3
+
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+def test_a_hand_off_is_on_the_tick_record_and_the_dispatch_span(
+        engine, tmp_path):
+    """A request of 5 tokens at a chunk of 2 ends in its second chunk:
+    the tick that dispatches it says `handed_off` 1, on its record and
+    on its `engine.dispatch` span, and still counts the request
+    `active` (it is, until the next tick's harvest); the slot is free
+    from that dispatch on."""
+    _serve(engine, 1)  # compile outside the session
+    seq0 = engine.stats()["ticks"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve(engine, 1, first=20)
+        s = engine.stats()
+    finally:
+        jax.profiler.stop_trace()
+    first, second, third = [t for t in s["tick_ring"] if t["seq"] > seq0][:3]
+    assert (first["handed_off"], first["active"]) == (0, 1)
+    assert (second["handed_off"], second["active"]) == (1, 1)
+    assert (third["handed_off"], third["active"]) == (0, 0)
+    assert third["seq"] == second["seq"] and not third["row_steps"]
+    assert s["handoffs_total"] == s["finished_total"] == 2
+    assert (s["active"], s["free_slots"]) == (0, 2)
+    spans = [st for name, _, _, st in _host_spans(tmp_path)
+             if name == "engine.dispatch"]
+    assert [st["handed_off"] for st in spans] == [0, 1]
+    # the tick after, which only harvests, says who it still waits for
+    ticks = [st for name, _, _, st in _host_spans(tmp_path)
+             if name == "engine.tick"]
+    assert [st["active"] for st in ticks][-1] == 1
 
 
 @pytest.mark.filterwarnings("ignore::DeprecationWarning")

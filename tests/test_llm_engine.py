@@ -9,6 +9,10 @@ block reuse, radix prefix-cache hits, and LRU eviction under
 block-pool pressure (RT008: all prompt RNGs seeded).
 """
 
+import threading
+import time
+from concurrent.futures import Future
+
 import numpy as np
 import pytest
 
@@ -366,5 +370,340 @@ def test_a_dead_row_stays_put_writes_nothing_and_costs_no_token(
         live = sum(t["row_steps_live"] for t in s["tick_ring"])
         assert live == sum(n - 1 for _, n in reqs)
         assert live < sum(t["row_steps"] for t in s["tick_ring"])
+    finally:
+        eng.shutdown()
+
+
+# ----------------------------------------------------------------------
+# the hand-off: a row whose last wanted step lies in the chunk just
+# dispatched gives its slot back at that dispatch, a chunk before the
+# harvest that resolves it
+# ----------------------------------------------------------------------
+def _burst(eng, reqs, **fields):
+    """Every request into the queue at once, as one tick's admissions."""
+    entries = [(list(p), n, Future(), time.time(), None, None,
+                *([fields] if fields else []))
+               for p, n in reqs]
+    with eng._wake:
+        eng._queue.extend(entries)
+        eng._wake.notify()
+    return [e[2] for e in entries]
+
+
+def _teacher_forced(logits_of, width):
+    """An oracle from ONE whole-sequence forward at one padded length:
+    `got` is the greedy continuation of `prompt` iff every token of it
+    is the argmax given all before it."""
+    def check(prompt, n, got):
+        seq = list(prompt) + list(got)
+        lg = np.asarray(logits_of(seq + [0] * (width - len(seq))))
+        want = np.argmax(lg[len(prompt) - 1:len(seq) - 1], -1).tolist()
+        assert len(got) == n and list(got) == want, (len(prompt), n)
+    return check
+
+
+def _rand_prompts(vocab, lengths, seed):
+    rng = np.random.RandomState(seed)
+    return [[int(x) for x in rng.randint(1, vocab, size=T)] for T in lengths]
+
+
+# answers SHORTER than a chunk of 4 (1, 2, 3), answers whose last step
+# ends a chunk (5, 9: 1 + whole chunks of steps) and answers that end
+# inside one, dealt over more callers than the three slots
+_BUDGETS = (7, 1, 5, 2, 9, 3, 11, 4, 6, 1, 8)
+
+
+_GENERATED = {}
+
+
+def _seam_llama(model, prefix_cache):
+    """(bfloat16: a teacher-forced float pass is no oracle for it, a
+    dedicated `llama.generate` is, bit for bit; once for both cases)"""
+    cfg, params = model
+    prompts = _prompts_with_shared_system_prompt(
+        cfg, len(_BUDGETS), np.random.RandomState(11))
+
+    def check(prompt, n, got):
+        key = (tuple(prompt), n)
+        if key not in _GENERATED:
+            _GENERATED[key] = _expected(cfg, params, prompt, n)
+        assert got == _GENERATED[key], (len(prompt), n)
+
+    return (cfg, params, dict(max_len=64, prefix_cache=prefix_cache),
+            prompts, check, {})
+
+
+def _seam_state(_model):
+    from ray_tpu.models import brumby
+
+    cfg = brumby.BrumbyConfig.tiny()
+    params = brumby.init_params(cfg, jax.random.PRNGKey(0), std=0.2)
+    fwd = jax.jit(lambda t: brumby.forward(cfg, params, t[None], chunk=8)[0][0])
+    prompts = _rand_prompts(cfg.vocab_size,
+                            (5, 13, 8, 20, 3, 17, 9, 12, 6, 15, 10), 12)
+    return (cfg, params, dict(max_len=48), prompts,
+            _teacher_forced(lambda s: fwd(jnp.asarray(s)), 48), {})
+
+
+def _seam_hybrid(_model):
+    """Paged K and V beside a per-slot convolution state: a slot's next
+    holder overwrites BOTH behind the old row's last chunk."""
+    from ray_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2MoeConfig.tiny()
+    params = lfm2.init_params(cfg, jax.random.PRNGKey(0), std=0.2)
+    fwd = jax.jit(lambda t: lfm2.forward(cfg, params, t[None])[0][0])
+    prompts = _rand_prompts(cfg.vocab_size,
+                            (5, 13, 8, 20, 3, 17, 9, 12, 6, 15, 10), 17)
+    return (cfg, params, dict(max_len=48, kv_blocks=12), prompts,
+            _teacher_forced(lambda s: fwd(jnp.asarray(s)), 48), {})
+
+
+def _seam_latent(_model):
+    cfg, params = _latent_model()
+    from ray_tpu.models import deepseek_v3
+
+    fwd = jax.jit(lambda t: deepseek_v3.forward(cfg, params, t[None])[0])
+    prompts = _prompts_with_shared_system_prompt(
+        cfg, len(_BUDGETS), np.random.RandomState(18))
+    return (cfg, params, dict(max_len=64), prompts,
+            _teacher_forced(lambda s: fwd(jnp.asarray(s)), 64), {})
+
+
+def _seam_ring(_model):
+    import test_mimo_v2 as tm
+
+    params = tm.mimo_v2.init_params(tm.CFG, jax.random.PRNGKey(0), std=0.2)
+    # long prompts admitted chunk by chunk, the slot's ring carried
+    prompts = _rand_prompts(tm.CFG.vocab_size,
+                            (5, 40, 12, 33, 16, 7, 64, 9, 21, 4, 18), 13)
+    return (tm.CFG, params,
+            dict(max_len=96, kv_blocks=30, prefill_chunk=16), prompts,
+            _teacher_forced(
+                lambda s: tm.forward_logits(tm.CFG, params, s), 96), {})
+
+
+def _seam_hits(_model, monkeypatch):
+    """The sparse-latent model behind ONE resident document: every
+    request of the wave is a prefix HIT, packed with its tick's."""
+    from ray_tpu.models import dots3
+    from ray_tpu.serve.engine_model import SparseLatentEngineModel
+
+    monkeypatch.setattr(SparseLatentEngineModel, "pack_align", 8)
+    cfg = dots3.Dots3Config.tiny()
+    params = dots3.init_params(cfg, jax.random.PRNGKey(7), std=0.2)
+    fwd = jax.jit(lambda t: dots3.forward(cfg, params, t))
+    doc, = _rand_prompts(cfg.vocab_size, (32,), 14)
+    tails = _rand_prompts(cfg.vocab_size,
+                          (5, 8, 3, 11, 6, 4, 9, 7, 2, 10, 12), 15)
+    return (cfg, params,
+            dict(max_len=96, kv_blocks=40, prefill_chunk=64),
+            [doc + t for t in tails],
+            _teacher_forced(lambda s: fwd(jnp.asarray(s)), 96),
+            {"resident": doc})
+
+
+def _seam_block_diffusion(_model):
+    """Where the DEVICE counts what a chunk produced the host cannot
+    know a row's last step at dispatch: nothing is handed off."""
+    from benchmarks.reference import sdar as ref
+    from test_sdar_model import model as sdar_model, reference_logits
+
+    cfg, params = sdar_model(4)
+    logits_of = reference_logits(cfg, params)
+    prompts = _rand_prompts(cfg.vocab_size,
+                            (8, 6, 13, 16, 5, 9, 11, 4, 7, 12, 10), 16)
+
+    def check(prompt, n, got):
+        want = ref.generate(prompt, n, cfg.block_length, 2, 0.9,
+                            cfg.mask_id, logits_of)
+        assert list(got) == want[0] and got.decided_at == want[1]
+
+    return (cfg, params, dict(max_len=64, block_size=8, kv_blocks=28),
+            prompts, check, {"fields": {"denoising_steps": 2},
+                             "hands_off": False})
+
+
+_SEAMS = {
+    "llama-prefix-cache": lambda m, mp: _seam_llama(m, True),
+    "llama-no-prefix-cache": lambda m, mp: _seam_llama(m, False),
+    "latent-prefix-cache": lambda m, mp: _seam_latent(m),
+    "state": lambda m, mp: _seam_state(m),
+    "hybrid": lambda m, mp: _seam_hybrid(m),
+    "window-ring": lambda m, mp: _seam_ring(m),
+    "sparse-latent-every-request-a-hit": _seam_hits,
+    "block-diffusion": lambda m, mp: _seam_block_diffusion(m),
+}
+
+
+def _pinned(radix):
+    stack, pinned = list(radix._root.children.values()), []
+    while stack:
+        node = stack.pop()
+        stack.extend(node.children.values())
+        if node.refs:
+            pinned.append(node.block)
+    return pinned
+
+
+@pytest.mark.parametrize("seam", list(_SEAMS))
+def test_a_finished_row_hands_its_slot_over_at_dispatch(model, seam,
+                                                        monkeypatch):
+    """Eleven callers on three slots, answers shorter than a chunk, on a
+    chunk's boundary and inside one, behind every seam: the tokens are
+    the per-request oracle's, every finished request was handed off
+    (none where the device counts), and an idle engine holds nothing:
+    every block free or cached, no node of the trie pinned."""
+    cfg, params, kw, prompts, check, more = _SEAMS[seam](model, monkeypatch)
+    eng = LlamaEngine(cfg, params, slots=3, chunk=4,
+                      **{"block_size": 8, **kw})
+    try:
+        before = 0
+        if "resident" in more:  # the document, cached by a first request
+            eng.submit(more["resident"] + [1, 2], 2).result(timeout=600)
+            before = 1
+        reqs = list(zip(prompts, _BUDGETS))
+        fields = more.get("fields", {})
+        fields = fields and eng._model.request_fields(**fields)
+        # two waves: a burst that one tick admits from, then callers one
+        # by one while the first wave's rows drain
+        futs = _burst(eng, reqs[:6], **fields)
+        futs += [eng.submit(p, n, **more.get("fields", {}))
+                 for p, n in reqs[6:]]
+        for (p, n), f in zip(reqs, futs):
+            check(p, n, f.result(timeout=600))
+        s = eng.stats()
+        done = before + len(reqs)
+        assert s["finished_total"] == done
+        assert s["handoffs_total"] == (done if more.get("hands_off", True)
+                                       else 0)
+        if "resident" in more:
+            assert s["prefix_hits"] == len(reqs)
+        assert (s["active"], s["queued"], s["free_slots"]) == (0, 0, 3)
+        assert not eng._handed and not eng._active
+        assert s["blocks_free"] + s["blocks_cached"] == s["blocks_total"]
+        assert eng._radix is None or not _pinned(eng._radix)
+        ticks = s["tick_ring"]
+        if more.get("hands_off", True) and len(ticks) < 32:
+            assert sum(t["handed_off"] for t in ticks) == done
+    finally:
+        eng.shutdown()
+
+
+class _Gate:
+    """The engine thread's reads of a chunk's tokens, held at a gate:
+    while it is closed the tick that would harvest waits INSIDE its
+    read, after its own dispatch, with whatever that dispatch handed
+    off still in `_handed`."""
+
+    def __init__(self, monkeypatch):
+        self.open, self.fail = threading.Event(), None
+        real = np.asarray
+
+        def read(a, *args, **kw):
+            if (threading.current_thread().name == "llm-engine"
+                    and isinstance(a, jax.Array)):
+                assert self.open.wait(timeout=60)
+                if self.fail is not None:
+                    raise self.fail
+            return real(a, *args, **kw)
+
+        monkeypatch.setattr(np, "asarray", read)
+
+    def handed_off(self, eng, n=1):
+        """Waits until `n` requests drain behind the closed gate."""
+        t_end = time.time() + 60
+        while len(eng._handed) < n and time.time() < t_end:
+            time.sleep(0.005)
+        assert len(eng._handed) == n
+
+
+@pytest.fixture
+def gate(monkeypatch):
+    g = _Gate(monkeypatch)
+    yield g
+    g.open.set()
+
+
+@pytest.mark.parametrize("sweep", ["shutdown", "failed-tick", "begin-drain"])
+def test_a_request_that_drains_is_never_left_pending(model, gate, sweep):
+    """A request of 4 tokens at a chunk of 2 is handed off at its second
+    dispatch; the tick's read (of its FIRST chunk) waits at the gate, so
+    the sweep finds it in `_handed`: a shutdown cancels it at the loop's
+    next turn, a tick that raises fails it and rebuilds the engine's
+    state, a drain lets it run to its tokens."""
+    from ray_tpu.exceptions import BackPressureError
+
+    cfg, params = model
+    eng = LlamaEngine(cfg, params, slots=2, max_len=48, chunk=2,
+                      block_size=8)
+    try:
+        prompt, = _rand_prompts(cfg.vocab_size, (12,), 21)
+        fut = eng.submit(prompt, 4)
+        gate.handed_off(eng)
+        assert not fut.done() and not eng._active
+        s = eng.stats()
+        assert (s["active"], s["free_slots"], s["queue_depth"]) == (1, 2, 1)
+        if sweep == "shutdown":
+            threading.Timer(0.2, gate.open.set).start()
+            eng.shutdown()  # the loop ends after the tick at the gate
+            assert fut.cancelled()
+        elif sweep == "failed-tick":
+            gate.fail = RuntimeError("the read failed")
+            gate.open.set()
+            with pytest.raises(RuntimeError, match="the read failed"):
+                fut.result(timeout=60)
+            gate.fail = None
+            assert eng.stats()["request_ring"][-1]["status"] == "error"
+            # host and device state restart from scratch, and serve
+            assert eng.submit(prompt, 4).result(timeout=120) == _expected(
+                cfg, params, prompt, 4)
+            s = eng.stats()
+            assert (s["active"], s["free_slots"]) == (0, 2)
+            assert s["blocks_free"] + s["blocks_cached"] == s["blocks_total"]
+        else:
+            eng.begin_drain()
+            gate.open.set()
+            assert fut.result(timeout=120) == _expected(
+                cfg, params, prompt, 4)
+            with pytest.raises(BackPressureError):
+                eng.submit(prompt, 4).result(timeout=60)
+        assert not eng._handed and not eng._active
+    finally:
+        gate.open.set()
+        eng.shutdown()
+
+
+def test_a_pool_that_runs_out_while_a_request_drains_requeues_in_order(
+        model):
+    """Seven blocks for requests of 3, 4, 3 and 4: two are admitted, the
+    third waits for blocks.  The hand-off of the first (two chunks; the
+    second runs four) frees three: the next tick admits the third and
+    requeues the fourth, while the first still drains, and admission
+    stays in arrival order."""
+    cfg, params = model
+    eng = LlamaEngine(cfg, params, slots=3, max_len=48, chunk=4,
+                      block_size=8, kv_blocks=7, prefix_cache=False)
+    plans, plan = [], eng._plan
+
+    def spy(prompt, *a, **k):
+        out = plan(prompt, *a, **k)
+        plans.append((len(prompt), out is not None, len(eng._handed)))
+        return out
+
+    eng._plan = spy
+    try:
+        prompts = _rand_prompts(cfg.vocab_size, (17, 18, 19, 20), 22)
+        reqs = list(zip(prompts, (6, 14, 6, 6)))
+        futs = _burst(eng, reqs)
+        for (p, n), f in zip(reqs, futs):
+            assert f.result(timeout=120) == _expected(cfg, params, p, n)
+        admitted = [T for T, ok, _ in plans if ok]
+        assert admitted == [17, 18, 19, 20]
+        # refused for want of blocks while a handed-off request drained
+        assert any(not ok and draining for _, ok, draining in plans)
+        s = eng.stats()
+        assert s["handoffs_total"] == s["finished_total"] == 4
+        assert s["blocks_free"] == s["blocks_total"] == 7
     finally:
         eng.shutdown()
