@@ -367,10 +367,15 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
     (y [N, D], stats).  `layer`: `router` [D, E] float32, `router_bias`
     [E], `e_gate` / `e_up` [E, D, I], `e_down` [E, I, D] — or the three
     expert leaves as whole stacks `[L, E, ...]` with `stack_index`
-    naming the layer (see `grouped_matmul`).  `route`: a name of
+    naming the layer (see `grouped_matmul`).  A layer with NO `e_gate`
+    holds two-matrix experts, `W_down relu(W_up x)^2` (`nemotron_h`'s
+    `relu2`): two grouped products a pair where SwiGLU has three.
+    `route`: a name of
     `ROUTES` ("sigmoid": `sigmoid_topk_route`, what every caller had;
     "softmax": `softmax_topk_route`, which reads no `router_bias`) or a
-    callable of their signature.
+    callable of their signature (a model whose experts work in another
+    width than its router reads routes before the call and hands the
+    picks in: `h` is then what the EXPERTS take).
 
     The N * k (token, expert) pairs are sorted by expert, so each
     expert's rows are contiguous and the three SwiGLU products are
@@ -383,8 +388,9 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
     least one row), `load_max` (rows of the largest group) and
     `tile_visits` (`tile_visits` of ONE of the three products at the
     row tile `row_tiling` gives; `ragged_dot` has no tiles and counts
-    megablox's), and `passes` (the slabs a held share's compact form
-    walked, below; 0 on this pair-wide form).
+    megablox's), `passes` (the slabs a held share's compact form
+    walked, below; 0 on this pair-wide form) and `held_pairs` (the pairs
+    that reached an expert here: the rows the grouped products took).
 
     `row_mask` [N] bool (the serve engine's live rows of a decode step;
     prefill passes none): a row it leaves out is routed to NO expert.
@@ -428,6 +434,9 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
         mm = lambda a, b: grouped_matmul(  # noqa: E731
             a, b.astype(dtype), sizes, kernel=kernel, interpret=interpret,
             stack_index=stack_index)
+        if "e_gate" not in layer:
+            return mm(jnp.square(jax.nn.relu(mm(xs, layer["e_up"]))),
+                      layer["e_down"])
         act = jax.nn.silu(mm(xs, layer["e_gate"])) * mm(xs, layer["e_up"])
         return mm(act, layer["e_down"])
 
@@ -456,7 +465,8 @@ def dropless_moe(h, layer: Dict, *, top_k: int, scale: float,
              "load_max": jnp.max(sizes),
              "tile_visits": (tile_visits(sizes, tile) if visits is None
                              else visits),
-             "passes": passes}
+             "passes": passes,
+             "held_pairs": jnp.sum(sizes).astype(jnp.int32)}
     return y, stats
 
 

@@ -113,7 +113,7 @@ prompt's length, or a block-diffusion row's first block), the paged
 rows into their blocks, the admitted rows' state.  `kv_write_program`
 is `kv_write`'s flat signature and the admitted slot's state.
 
-Seven implementers: `LlamaEngineModel` (per-head K and V pools),
+Eight implementers: `LlamaEngineModel` (per-head K and V pools),
 `LatentMoeEngineModel` (`models/deepseek_v3.py`: ONE latent pool,
 absorbed decode attention, dropless experts), `RetentionEngineModel`
 (`models/brumby.py`: every layer a power-retention layer, a per-slot
@@ -129,7 +129,12 @@ third admission program, `chunk_prefill`, carries the ring from chunk
 to chunk of a long prompt) and `BlockDiffusionEngineModel`
 (`models/sdar.py`: folded K and V pools, every layer a softmax-routed
 expert layer, `block_chunk_program`; a prefill yields no token and
-runs no head).
+runs no head) and `RecurrentEngineModel` (`models/nemotron_h.py`: THREE
+leaves over disjoint layers, paged K and V in the few attention layers,
+a per-slot float32 RECURRENT state and a convolution state in the
+Mamba-2 layers, nothing in the expert layers; its `chunk_prefill`
+RESUMES the scan from the slot's state, so a long prompt is admitted
+chunk by chunk).
 `engine_model_for` picks by the config's type, builds the
 format from the user's `kv_dtype` and hands the implementer the
 resolved route: a user passes a model's config and the model picks its
@@ -145,7 +150,7 @@ import jax.numpy as jnp
 
 from ray_tpu.exceptions import PrefixCacheUnsupportedError
 from ray_tpu.models import (brumby, deepseek_v3, dots3, lfm2, llama,
-                            mimo_v2, sdar)
+                            mimo_v2, nemotron_h, sdar)
 from ray_tpu.ops import paged_attention as _pa
 from ray_tpu.ops import retention as _ret
 from ray_tpu.serve.kv_cache import BlockPool, CacheLeaf
@@ -594,10 +599,12 @@ class _EngineModel:
     `suffix_prefill` or `kv_write`), `pack_align` (the rows each of
     them is aligned to in that program) and `state_carries_chunks` (a
     per-slot leaf that a prompt admitted CHUNK BY CHUNK can carry from
-    one chunk's program to the next: a ring of window rows does, and
-    the model then has `chunk_prefill`; a retention or convolution
-    state as wired today is what a prefill leaves ONCE, and does
-    not)."""
+    one chunk's program to the next, and the model then has
+    `chunk_prefill`: a ring of window rows does, and so does a
+    RECURRENT state whose scan resumes from the slot's state
+    (`RecurrentEngineModel`); Brumby's retention state and LFM2's
+    convolution state as wired today are what a prefill leaves ONCE,
+    and do not)."""
 
     aux_rows = 0
     state_write_deferred = False
@@ -1083,7 +1090,68 @@ class SparseLatentEngineModel(_ExpertCounters, _EngineModel):
         return _fn
 
 
-class WindowFullEngineModel(_ExpertCounters, _EngineModel):
+class _CarriedStateAdmission:
+    """The THREE admission programs of a model whose per-slot leaves a
+    long prompt's chunks CARRY (`state_carries_chunks`), over
+    `self._module`'s `forward` and `forward_chunk`:
+    `prefill_packed(N)`, whole prompts end to end, each prompt's end
+    state left in its slot by the forward itself; `prefill(bucket)`;
+    and `chunk_prefill(N)`, printed `jit_prefill_chunk_n<N>`: tokens `lo
+    .. lo + n` of ONE prompt too long for a packed program, `(params,
+    *cache, tokens [N], table [W], slot, lo, n, admit, pos0, stop0, pos,
+    tok, stop) -> (*cache, pos, tok, stop)`, which takes the whole
+    cache, the slot's leaves beside the request's blocks, and hands it
+    back; `admit` is the slot whose `pos` / `tok` / `stop` the chunk
+    sets (the prompt's last chunk; any other names a slot past the last:
+    dropped).  `suffix_prefill` and `kv_write` do not exist: sharing a
+    prefix would need the per-slot leaves at the block boundary."""
+
+    state_carries_chunks = True
+
+    def _kw(self):
+        # the paged layers' attention follows the route in all three
+        # programs: the paged decode kernels, the fused prefill fold
+        return dict(super()._kw(), paged_kernel=self._paged)
+
+    def prefill_packed(self, N: int):
+        def forward(params, state, tokens, packed, slots):
+            # -> (logits, (ks, vs) [paged layers, 1, N, KV * d], state)
+            return self._module.forward(
+                self.cfg, params, tokens, state, packed=packed, slots=slots,
+                **self._kw())
+
+        return packed_prefill_program(self.kv, len(self.state.leaves),
+                                      forward, segmented=self.segmented)
+
+    def prefill(self, bucket: int):
+        def _pf(params, prompt):  # prompt [1, bucket], right-padded
+            logits, (ks, vs), _ = self._module.forward(
+                self.cfg, params, prompt, **self._kw())
+            return logits[0], ks, vs
+
+        return _pf
+
+    def chunk_prefill(self, N: int):
+        cfg, kw, n = self.cfg, self._kw(), len(self.cache_leaves)
+        forward_chunk = self._module.forward_chunk
+
+        def _fn(params, *flat):
+            cache = flat[:n]
+            (tokens, table, slot, lo, real, admit, pos0, stop0,
+             pos, tok, stop) = flat[n:]
+            logits, cache = forward_chunk(
+                cfg, params, tokens, lo, real, cache, table, slot, **kw)
+            tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (*cache,
+                    *_admitted(pos, tok, stop, admit, pos0, tok0, stop0))
+
+        return _fn
+
+    suffix_prefill = kv_write = _EngineModel._no_prefix
+
+
+class WindowFullEngineModel(_CarriedStateAdmission, _ExpertCounters,
+                            _EngineModel):
     """`models/mimo_v2.py` behind the seam: a cache of BOTH kinds, both
     for ATTENTION.  Paged `k` `[full layers, num_blocks, block_size, KV
     * 192]` and `v` `[.., KV * 128]` for the full layers (`kv`: the two
@@ -1120,7 +1188,7 @@ class WindowFullEngineModel(_ExpertCounters, _EngineModel):
     the prefill's running softmax a `fori_loop` over key blocks, +
     `lax.ragged_dot` (anywhere)."""
 
-    state_carries_chunks = True
+    _module = mimo_v2
 
     def __init__(self, cfg, kv: PagedKV, state: SlotState, **route):
         super().__init__(cfg, kv, state=state, **route)
@@ -1143,11 +1211,6 @@ class WindowFullEngineModel(_ExpertCounters, _EngineModel):
                 "ring_bytes_live": len(contexts) * self._ring_bytes,
                 "full_cache_tokens_live": sum(contexts)}
 
-    def _kw(self):
-        # the full layers' attention follows the route in all three
-        # programs: the paged decode kernels, the fused prefill fold
-        return dict(super()._kw(), paged_kernel=self._paged)
-
     def decode_chunk(self, W: int):
         cfg, kw = self.cfg, self._kw()
 
@@ -1157,41 +1220,6 @@ class WindowFullEngineModel(_ExpertCounters, _EngineModel):
             return logits, cache, (st["experts_touched"], st["load_max"])
 
         return chunk_program(step, self.chunk, aux=self._aux)
-
-    def prefill_packed(self, N: int):
-        def forward(params, ring, tokens, packed, slots):
-            logits, kv, ring = mimo_v2.forward(
-                self.cfg, params, tokens, ring, packed=packed, slots=slots,
-                **self._kw())
-            return logits, kv, ring  # ks / vs [full layers, 1, N, KV * d]
-
-        return packed_prefill_program(self.kv, 2, forward,
-                                      segmented=self.segmented)
-
-    def prefill(self, bucket: int):
-        def _pf(params, prompt):  # prompt [1, bucket], right-padded
-            logits, (ks, vs), _ = mimo_v2.forward(self.cfg, params, prompt,
-                                                  **self._kw())
-            return logits[0], ks, vs
-
-        return _pf
-
-    def chunk_prefill(self, N: int):
-        cfg, kw, n = self.cfg, self._kw(), len(self.cache_leaves)
-
-        def _fn(params, *flat):
-            cache = flat[:n]
-            (tokens, table, slot, lo, real, admit, pos0, stop0,
-             pos, tok, stop) = flat[n:]
-            logits, cache = mimo_v2.forward_chunk(
-                cfg, params, tokens, lo, real, cache, table, slot, **kw)
-            tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (*cache,
-                    *_admitted(pos, tok, stop, admit, pos0, tok0, stop0))
-
-        return _fn
-
-    suffix_prefill = kv_write = _EngineModel._no_prefix
 
 
 class BlockDiffusionEngineModel(_ExpertCounters, _EngineModel):
@@ -1377,6 +1405,83 @@ class BlockDiffusionEngineModel(_ExpertCounters, _EngineModel):
     suffix_prefill = kv_write = _EngineModel._no_prefix
 
 
+class RecurrentEngineModel(_CarriedStateAdmission, _ExpertCounters,
+                           _EngineModel):
+    """`models/nemotron_h.py` behind the seam: a cache of THREE leaves
+    over DISJOINT layers.  Paged `k` and `v` `[attention layers,
+    num_blocks, block_size, KV * hd]` for the few attention layers
+    (`kv`; a token's heads folded side by side into whole lanes), and
+    behind them, for the Mamba-2 layers, a per-slot RECURRENT state
+    `ssm` `[Mamba layers, slots, heads, head_dim, N]` float32 and the
+    convolution's last inputs `conv` `[.., slots, (taps - 1) *
+    conv_dim]` in the compute dtype (`state`).  The expert layers hold
+    nothing.  `cache_bytes_per_token` counts the attention layers alone
+    and `cache_bytes_per_slot`, the states, does not grow with a
+    sequence; admission needs a slot and the attention layers' blocks.
+
+    THREE admission programs, as `WindowFullEngineModel`'s.
+    `prefill_packed(N)`: whole prompts end to end, the scan and the
+    convolution RESET at a segment's start, each prompt's end state left
+    in its slot.  `chunk_prefill(N)`, printed `jit_prefill_chunk_n<N>`,
+    in `WindowFullEngineModel`'s signature: tokens `lo .. lo + n` of ONE
+    prompt too long for a packed program; its Mamba layers START FROM
+    THE SLOT'S `ssm` and `conv` (zero where `lo` is 0, whatever the slot
+    held) and leave them as they stand at the chunk's end
+    (`state_carries_chunks`: a recurrent state a prefill RESUMES from),
+    its attention layers write the chunk's rows into the request's
+    blocks and attend the earlier chunks' through `table`.  A decode
+    step steps every live row's state once, read and written in float32
+    (a dead row's state stays as it was).  `suffix_prefill` and
+    `kv_write` do not exist: sharing a prefix would need the states at
+    the block boundary.  `paged`: the paged decode kernels, the fused
+    prefill attention + Pallas grouped products (TPU); else the same in
+    plain XLA + `lax.ragged_dot` (anywhere).  The recurrence and the
+    convolution are plain XLA on both routes (`ops/ssd.py`).
+
+    The decode program hands back THREE counters of the held experts
+    (`aux_rows`): `_ExpertCounters`' two and `held_pairs`, the (row,
+    expert) pairs that reached an expert this chip holds, summed over
+    the chunk's steps and the expert layers."""
+
+    aux_rows = 3
+    _module = nemotron_h
+
+    def __init__(self, cfg, kv: PagedKV, state: SlotState, **route):
+        super().__init__(cfg, kv, state=state, **route)
+        self._pairs = cfg.n_moe_layers * cfg.experts_held
+        # what `stats()["cache_bytes_per_slot"]` reads: a slot's states
+        self._slot_bytes = BlockPool(2, spec=state.leaves).bytes_per_slot(0)
+
+    @staticmethod
+    def _aux(stats):
+        return jnp.stack([jnp.sum(stats[0]), jnp.max(stats[1]),
+                          jnp.sum(stats[2])])
+
+    def tick_fields(self, aux) -> Dict[str, object]:
+        return {**super().tick_fields(aux),
+                "experts_held": self.cfg.experts_held,
+                "held_pairs": int(aux[2, 0])}
+
+    def context_fields(self, contexts: Sequence[int]) -> Dict[str, object]:
+        """Of the rows live at a chunk's first step: `ssm_bytes_live`,
+        their states' bytes over the Mamba layers (whatever their
+        contexts); `full_cache_tokens_live`, the tokens an attention
+        layer reads for them."""
+        return {"ssm_bytes_live": len(contexts) * self._slot_bytes,
+                "full_cache_tokens_live": sum(contexts)}
+
+    def decode_chunk(self, W: int):
+        cfg, kw = self.cfg, self._kw()
+
+        def step(params, tok, cache, tables, pos, live):
+            logits, cache, st = nemotron_h.decode_step(
+                cfg, params, tok, cache, pos, tables, live=live, **kw)
+            return logits, cache, (st["experts_touched"], st["load_max"],
+                                   st["held_pairs"])
+
+        return chunk_program(step, self.chunk, aux=self._aux)
+
+
 def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
     """The implementer for a model's config, its cache in the format
     the user's `kv_dtype` names: the model picks its route."""
@@ -1394,6 +1499,21 @@ def engine_model_for(cfg, *, kv_dtype: str, block_size: int, **route):
         tail = (cfg.n_kv_heads * cfg.head_dim,)
         return BlockDiffusionEngineModel(cfg, PagedKV(
             {"k": tail, "v": tail}, cfg.dtype, block_size, kv_dtype), **route)
+    if isinstance(cfg, nemotron_h.NemotronHConfig):
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' is not wired for the recurrent cache: its "
+                "K and V are one layer in eleven, 1 KB a token already, and "
+                "the state is float32 by the model's own statement")
+        tail = (cfg.n_kv_heads * cfg.head_dim,)
+        return RecurrentEngineModel(
+            cfg, PagedKV({"k": tail, "v": tail}, cfg.dtype, block_size,
+                         kv_dtype, layers=cfg.n_attn_layers),
+            SlotState({"ssm": ((cfg.mamba_heads, cfg.mamba_head_dim,
+                                cfg.state_size), jnp.float32),
+                       "conv": (((cfg.conv_kernel - 1) * cfg.conv_dim,),
+                                cfg.dtype)},
+                      layers=cfg.n_mamba_layers), **route)
     if isinstance(cfg, mimo_v2.MimoV2Config):
         if kv_dtype == "int8":
             raise ValueError(

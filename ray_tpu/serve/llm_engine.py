@@ -359,9 +359,12 @@ class LlamaEngine:
     prompt may be as long as `max_len` allows without a program that
     attends `[max_len, max_len]`.  Beside a per-slot leaf it is allowed
     where the MODEL can carry that leaf from one chunk's program to the
-    next (`engine_model.state_carries_chunks`: a ring of window rows;
-    the chunk is then ONE program, `prefill_chunk_n<N>`, that takes and
-    hands back the slot's leaves beside the blocks); a state that a
+    next (`engine_model.state_carries_chunks`: a ring of window rows, a
+    recurrent state whose scan resumes from the slot's state; the chunk
+    is then ONE program, `prefill_chunk_n<N>`, that takes and hands back
+    the slot's leaves beside the blocks; `stats()["state_chunks_resumed"]`
+    and the tick's field of that name count the chunks that started
+    from what an earlier chunk left in the slot); a state that a
     prefill leaves once is refused.
 
     A model that PACKS ITS SUFFIXES (`engine_model.packs_suffixes`) has
@@ -563,6 +566,9 @@ class LlamaEngine:
         self._prefill_calls = 0       # prefill programs (packed+suffix)
         self._prefill_rows = 0        # requests those programs prefilled
         self._prefill_padded_tokens = 0  # their sizes (N, bucket) summed
+        # chunks of long prompts that started from what an earlier chunk
+        # left in the slot's per-slot leaves (`_run_state_chunk`)
+        self._state_chunks_resumed = self._resumed_mark = 0
         # the model's own tick counters it wants summed from the start
         self._model_sums = {k: 0 for k in self._model.summed}
         # the packed prefill's closed set of sizes and the prompts one
@@ -572,8 +578,9 @@ class LlamaEngine:
         if prefill_chunk is not None:
             # a chunk prefills behind the request's own blocks; a
             # per-slot leaf has to be one the MODEL can carry from one
-            # chunk's program to the next (a ring of window rows: yes;
-            # a state that a prefill leaves once: no)
+            # chunk's program to the next (a ring of window rows, a
+            # recurrent state its scan resumes from: yes; a state that
+            # a prefill leaves once: no)
             if not self._has_blocks or (
                     self._has_state
                     and not self._model.state_carries_chunks):
@@ -903,6 +910,7 @@ class LlamaEngine:
                 "prefill_calls": self._prefill_calls,
                 "prefill_rows": self._prefill_rows,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
+                "state_chunks_resumed": self._state_chunks_resumed,
                 **self._model_sums,
                 "gather_blocks": self._last_gather_blocks,
                 # decode-kernel / quantization plane: which route the
@@ -1575,6 +1583,7 @@ class LlamaEngine:
             self._prefill_calls += 1
             self._prefill_tokens += S
             self._prefill_padded_tokens += N
+            self._state_chunks_resumed += int(lo > 0)
 
     def _release(self, slot: int, req: Dict):
         self._slot_blocks[slot] = []
@@ -1949,6 +1958,10 @@ class LlamaEngine:
                 # the prompt tokens its admissions found cached
                 rec["prefix_hit_tokens"] = self._hit_tokens - self._hit_mark
                 self._hit_mark = self._hit_tokens
+            if self._state_chunks_resumed != self._resumed_mark:
+                rec["state_chunks_resumed"] = (
+                    self._state_chunks_resumed - self._resumed_mark)
+                self._resumed_mark = self._state_chunks_resumed
             before = self._tick_ring[-1] if self._tick_ring else None
             self._tick_ring.append(rec)
             self._account_add(rec)

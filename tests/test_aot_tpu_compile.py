@@ -840,6 +840,87 @@ def _holds_slab_rows_only(hlo):
 
 
 # ----------------------------------------------------------------------
+# the recurrent model: a per-slot float32 state, one attention layer in
+# eleven, a share of a latent expert layer
+# ----------------------------------------------------------------------
+def _nemotron3s_l11():
+    from ray_tpu.models import nemotron_h
+
+    return nemotron_h.NemotronHConfig(vocab_size=32768, experts_held=128)
+
+
+def test_recurrent_model_programs_at_the_cells_shapes(chip):
+    """`decode_chunk_w545`, `prefill_packed_n2048` and
+    `prefill_chunk_n2048` as the engine jits them for Nemotron 3 Super's
+    cut at the published widths (11 layers `MEMEMEM*EME`, 128 of 512
+    experts held, 128 slots, chunk 8): the table and both per-slot
+    states in one signature, all four leaves donated and written in
+    place, the paged kernel on the folded 256-lane pool inside the
+    decode program, the grouped products of the two-matrix experts in a
+    1,024-wide latent (22 pairs a token: 2,816 a decode step, pair-wide;
+    45,056 a chunk, the held pairs alone in slabs of 22,528 rows), and
+    the scan's float32 state carried between its chunks of 128."""
+    from ray_tpu.models import nemotron_h
+    from ray_tpu.parallel import moe
+    from ray_tpu.serve.engine_model import engine_model_for
+
+    cfg = _nemotron3s_l11()
+    params = jax.tree.map(
+        lambda p: _s(*p.shape, dtype=p.dtype),
+        jax.eval_shape(lambda: nemotron_h.init_params(
+            cfg, jax.random.PRNGKey(0))))
+    model = engine_model_for(cfg, kv_dtype="model", block_size=16, chunk=8,
+                             paged=True, interpret=False)
+    assert [(l.per_slot, l.layers) for l in model.cache_leaves] == [
+        (False, 1), (False, 1), (True, 5), (True, 5)]
+    B, NB, W, N = 128, 40961, 545, 2048
+    cache = [_s(1, NB, 16, 256), _s(1, NB, 16, 256),
+             _s(5, B, 128, 64, 128, dtype=jnp.float32), _s(5, B, 30720)]
+    i32 = jnp.int32
+    rows, one = [_s(B, dtype=i32)] * 3, _s(dtype=i32)
+    donate = dict(donate_argnums=(1, 2, 3, 4))
+    fn = model.decode_chunk(W)
+    fn.__name__ = "decode_chunk_w545"
+    hlo = _compile(chip, fn, params, *cache, _s(B, W, dtype=i32), *rows,
+                   **donate)
+    assert "jit_decode_chunk_w545" in hlo and "input_output_alias" in hlo
+    # the paged kernel's result: 32 heads on the folded 2 x 128 lanes
+    assert "bf16[128,32,256]" in hlo
+    # a step's 2,816 pairs stay pair-wide, in the latent width
+    assert "bf16[2816,1024]" in hlo and "bf16[2816,4096]" not in hlo
+    for name in ("ssm_step", "ssm_conv", "ssm_proj", "latent_moe_routed",
+                 "latent_moe_proj", "moe_shared", "full_attn"):
+        assert f"/{name}/" in hlo, name
+    assert moe.slab_rows(N * 22, 128, 512) == 22528
+    for fn, name, host in (
+            (model.prefill_packed(N), "prefill_packed_n2048",
+             [*[_s(N, dtype=i32)] * 3, _s(N // 16, dtype=i32),
+              *[_s(16, dtype=i32)] * 4]),
+            (model.chunk_prefill(N), "prefill_chunk_n2048",
+             [_s(N, dtype=i32), _s(W, dtype=i32), *[one] * 6])):
+        fn.__name__ = name
+        hlo = _compile(chip, fn, params, *cache, *host, *rows, **donate)
+        assert f"jit_{name}" in hlo and "input_output_alias" in hlo
+        # the attention layer folds in ONE kernel; no score array whole
+        calls = [ln.split("=", 1)[1].lstrip() for ln in hlo.splitlines()
+                 if " = " in ln and "tpu_custom_call" in ln
+                 and "/full_attn/" in ln]
+        assert len(calls) == 1 and calls[0].startswith("bf16[2,16,2048,128]")
+        assert "f32[2,16,2048,8720]" not in hlo
+        # the held pairs alone, two grouped products a slab and layer
+        made = [ln.split("=", 1)[1].lstrip() for ln in hlo.splitlines()
+                if " = " in ln and "/latent_moe_routed/" in ln]
+        assert not [c for c in made if c.startswith("bf16[45056,")]
+        gmm = [c for c in made if "grouped_matmul_prefetch" in c
+               and "custom-call(" in c]
+        assert len(gmm) == 2 * 5
+        assert sum(c.startswith("bf16[22528,2688]") for c in gmm) == 5
+        assert sum(c.startswith("bf16[22528,1024]") for c in gmm) == 5
+        # the scan: 16 chunks' start states, float32, a layer
+        assert "f32[16,128,64,128]" in hlo and "/ssm_scan/" in hlo
+
+
+# ----------------------------------------------------------------------
 # the block-diffusion model: a block of B rows a slot a forward
 # ----------------------------------------------------------------------
 # the benchmark's `sdar-30b-a3b-chat-l6` engine: 6 layers' folded pools
