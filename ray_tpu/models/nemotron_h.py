@@ -338,12 +338,13 @@ def _norm(cfg, layer, x):
     return _rms_norm(x, layer["norm"].astype(cfg.dtype), cfg.norm_eps)
 
 
-def _mamba_scan(cfg, layer, h, seg, ends, state):
+def _mamba_scan(cfg, layer, h, seg, ends, state, *, kernel, interpret):
     """A Mamba layer over a row: h `[T, D]` normed, `state` = `(ssm [H,
     P, N], conv [taps - 1, conv_dim])` sequence 0 continues from, or
     None -> (the mixer's output `[T, D]`, the states after the tokens
     `ends` names: ssm `[K, H, P, N]` float32, conv `[K, (taps - 1) *
-    conv_dim]`)."""
+    conv_dim]`).  `kernel`: the scan's chunks as one Pallas call
+    (`ops/ssd.py`)."""
     z, xbc, dt = _mamba_in(cfg, layer, h)
     with jax.named_scope("ssm_conv"):
         xbc, conv = ssd.conv_scan(
@@ -354,7 +355,7 @@ def _mamba_scan(cfg, layer, h, seg, ends, state):
         y, ssm = ssd.ssd_scan(
             x, dt, -jnp.exp(layer["A_log"].astype(F32)), B, C, seg, ends,
             init=None if state is None else state[0], dtype=cfg.dtype,
-            chunk=cfg.scan_chunk)
+            chunk=cfg.scan_chunk, kernel=kernel, interpret=interpret)
     return (_mamba_out(cfg, layer, y, x, z),
             ssm, conv.reshape(conv.shape[0], -1))
 
@@ -394,7 +395,8 @@ def forward(cfg: NemotronHConfig, params: Dict, tokens: jax.Array,
         kind, li = cfg.pattern[i], leaf_index(cfg, i)
         h = _norm(cfg, layer, x)
         if kind == MAMBA:
-            y, ssm, conv = _mamba_scan(cfg, layer, h, seg, ends, None)
+            y, ssm, conv = _mamba_scan(cfg, layer, h, seg, ends, None,
+                                       kernel=kernel, interpret=interpret)
             if state is not None:
                 state = (state[0].at[li, slots].set(ssm, mode="drop"),
                          state[1].at[li, slots].set(
@@ -461,7 +463,8 @@ def forward_chunk(cfg: NemotronHConfig, params: Dict, tokens: jax.Array,
             held = (jnp.where(resumes, was[0], 0.0),
                     jnp.where(resumes, was[1], jnp.zeros((), conv.dtype)
                               ).reshape(cfg.conv_kernel - 1, -1))
-            y, s_end, c_end = _mamba_scan(cfg, layer, h, seg, ends, held)
+            y, s_end, c_end = _mamba_scan(cfg, layer, h, seg, ends, held,
+                                          kernel=kernel, interpret=interpret)
             # ONE slot's rows written in place (a scatter that may drop
             # its row lowers to a pass over the whole 2.7 GB leaf): a
             # warm-up's slot, past the last, writes back what was there

@@ -1435,8 +1435,11 @@ class RecurrentEngineModel(_CarriedStateAdmission, _ExpertCounters,
     `kv_write` do not exist: sharing a prefix would need the states at
     the block boundary.  `paged`: the paged decode kernels, the fused
     prefill attention + Pallas grouped products (TPU); else the same in
-    plain XLA + `lax.ragged_dot` (anywhere).  The recurrence and the
-    convolution are plain XLA on both routes (`ops/ssd.py`).
+    plain XLA + `lax.ragged_dot` (anywhere).  The scan of admission
+    follows the same flags (`ops/ssd.ssd_scan(kernel=)`: one Pallas call
+    a Mamba layer in both admission programs on the TPU, plain XLA
+    elsewhere); the decode step's recurrence and the convolution are
+    plain XLA on both routes.
 
     The decode program hands back THREE counters of the held experts
     (`aux_rows`): `_ExpertCounters`' two and `held_pairs`, the (row,

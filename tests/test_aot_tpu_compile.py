@@ -849,6 +849,34 @@ def _nemotron3s_l11():
     return nemotron_h.NemotronHConfig(vocab_size=32768, experts_held=128)
 
 
+@pytest.mark.parametrize("K,resumes", [(1, True), (16, False), (1, False),
+                                       (16, True)])
+def test_ssd_scan_kernel(chip, K, resumes):
+    """The Mamba-2 scan of one layer at the cell's shapes (a row of
+    2,048 tokens, 128 heads of 64 in 8 groups, a state of 128, chunks of
+    128): a chunk of a long prompt that RESUMES from a state (K 1) and a
+    packed row of 16 prompts from zero, as the two admission programs
+    call it, and the two other pairings.  Sixteen heads a grid step, two
+    side by side on a tile of lanes; `y` and every chunk's start state
+    come back with the heads on the lanes."""
+    from ray_tpu.ops import ssd
+
+    T, H, P, G, N = 2048, 128, 64, 8, 128
+    f32, i32 = jnp.float32, jnp.int32
+
+    def scan(x, dt, A, B, C, seg, ends, *init):
+        return ssd.ssd_scan(x, dt, A, B, C, seg, ends,
+                            init=init[0] if init else None, kernel=True)
+
+    hlo = _compile(
+        chip, scan, _s(T, H, P), _s(T, H, dtype=f32), _s(H, dtype=f32),
+        _s(T, G, N), _s(T, G, N), _s(T, dtype=i32), _s(K, dtype=i32),
+        *([_s(H, P, N, dtype=f32)] if resumes else []))
+    assert hlo.count("tpu_custom_call") == 1
+    assert "(f32[16,128,8192]" in hlo and "f32[16,128,128,128]" not in hlo
+    assert f"f32[{K},128,64,128]" in hlo
+
+
 def test_recurrent_model_programs_at_the_cells_shapes(chip):
     """`decode_chunk_w545`, `prefill_packed_n2048` and
     `prefill_chunk_n2048` as the engine jits them for Nemotron 3 Super's
@@ -859,7 +887,8 @@ def test_recurrent_model_programs_at_the_cells_shapes(chip):
     decode program, the grouped products of the two-matrix experts in a
     1,024-wide latent (22 pairs a token: 2,816 a decode step, pair-wide;
     45,056 a chunk, the held pairs alone in slabs of 22,528 rows), and
-    the scan's float32 state carried between its chunks of 128."""
+    the scan of each Mamba layer as one kernel in both admission
+    programs."""
     from ray_tpu.models import nemotron_h
     from ray_tpu.parallel import moe
     from ray_tpu.serve.engine_model import engine_model_for
@@ -916,8 +945,12 @@ def test_recurrent_model_programs_at_the_cells_shapes(chip):
         assert len(gmm) == 2 * 5
         assert sum(c.startswith("bf16[22528,2688]") for c in gmm) == 5
         assert sum(c.startswith("bf16[22528,1024]") for c in gmm) == 5
-        # the scan: 16 chunks' start states, float32, a layer
-        assert "f32[16,128,64,128]" in hlo and "/ssm_scan/" in hlo
+        # the scan: ONE kernel a Mamba layer, whose float32 state is
+        # carried between its 16 chunks in VMEM; no decay array whole
+        scans = [ln for ln in hlo.splitlines() if " = " in ln
+                 and "tpu_custom_call" in ln and "/ssm_scan/" in ln]
+        assert len(scans) == 5, name
+        assert "f32[16,128,128,128]" not in hlo
 
 
 # ----------------------------------------------------------------------

@@ -81,17 +81,20 @@ def forward_logits(cfg, params, toks):
 
 
 @functools.lru_cache(maxsize=None)
-def _packed(cfg, paged=False):
+def _packed(cfg, paged=False, scan=False):
+    """`paged`: the route the chip takes, its kernels interpreted (the
+    fused prefill fold and the scan); `scan`: the scan's kernel alone."""
     return jax.jit(lambda p, t, state, packed, slots: nh.forward(
         cfg, p, t, state, packed=packed, slots=slots, paged_kernel=paged,
-        interpret=paged))
+        interpret=paged or scan))
 
 
 @functools.lru_cache(maxsize=None)
-def _chunk(cfg, paged=False):
+def _chunk(cfg, paged=False, scan=False):
     return jax.jit(lambda p, t, lo, n, cache, table, slot:
                    nh.forward_chunk(cfg, p, t, lo, n, cache, table, slot,
-                                    paged_kernel=paged, interpret=paged))
+                                    paged_kernel=paged,
+                                    interpret=paged or scan))
 
 
 ROUTES = pytest.mark.parametrize("paged", [False, True],
@@ -113,8 +116,10 @@ class Cache:
     driven through the model's three functions as the engine's programs
     drive them."""
 
-    def __init__(self, cfg, params, slots=3, blocks=30, paged=False):
+    def __init__(self, cfg, params, slots=3, blocks=30, paged=False,
+                 scan=False):
         self.cfg, self.params, self.slots = cfg, params, slots
+        self.scan = scan
         em = engine_model_for(cfg, kv_dtype="model", block_size=BS, chunk=1,
                               paged=paged, interpret=True)
         self.cache = tuple(
@@ -144,7 +149,7 @@ class Cache:
             last[i], sl[i] = at + T - 1, s
             at += nb * BS
         packed = Packed(jnp.asarray(last), jnp.asarray(seg), jnp.asarray(posn))
-        logits, (ks, vs), state = _packed(self.cfg, self.paged)(
+        logits, (ks, vs), state = _packed(self.cfg, self.paged, self.scan)(
             self.params, jnp.asarray(tok)[None], self.cache[2:], packed,
             jnp.asarray(sl, jnp.int32))
         k_pool, v_pool = (
@@ -158,7 +163,7 @@ class Cache:
         N = N or -(-(hi - lo) // BS) * BS
         buf = np.zeros(N, np.int32)
         buf[:hi - lo] = toks[lo:hi]
-        logits, self.cache = _chunk(self.cfg, self.paged)(
+        logits, self.cache = _chunk(self.cfg, self.paged, self.scan)(
             self.params, jnp.asarray(buf), jnp.int32(lo), jnp.int32(hi - lo),
             self.cache, jnp.asarray(self.tables[slot], jnp.int32),
             jnp.int32(slot))
@@ -329,6 +334,38 @@ def test_a_prompt_admitted_in_chunks_equals_one_program(params, chunks, paged,
     for p in range(LONG, LONG + 6):
         out, _ = c.decode([0, seq[p], 0], [0, p, 0], live)
         assert np.abs(out[1] - want[p]).max() < TOL, p
+
+
+@pytest.mark.parametrize("case", ["whole", "two-chunks", "packed"])
+def test_the_scan_kernel_interpreted_equals_the_xla_route(params, case):
+    """`forward` and `forward_chunk` with the scan as its Pallas kernel
+    (interpreted; everything else plain XLA) against the XLA route: a
+    prompt in one piece, in two chunks of which the second RESUMES
+    inside a scan chunk's reach, and packed beside another that starts
+    mid-chunk; the logits and BOTH end states of every Mamba layer."""
+    seq, other = tokens(LONG, 4), tokens(13, 8)
+    routes = [Cache(CFG, params), Cache(CFG, params, scan=True)]
+    out = []
+    for c in routes:
+        if case == "whole":
+            out.append(c.pack([seq], [1], -(-LONG // BS) * BS))
+        elif case == "two-chunks":
+            c.chunk(seq, 0, 24, slot=1, N=24)
+            out.append(c.chunk(seq, 24, LONG, slot=1, N=24))
+        else:
+            out.append(c.pack([other, seq], [2, 1], 72))
+    assert np.abs(out[0] - out[1]).max() < 1e-5
+    assert np.abs(out[0]).max() > 0.1
+    for slot in (1, 2) if case == "packed" else (1,):
+        for a, b in zip(routes[0].cache[2:], routes[1].cache[2:]):
+            a, b = np.asarray(a[:, slot]), np.asarray(b[:, slot])
+            assert np.abs(a - b).max() < 1e-5 and np.abs(a).max() > 0.1
+    # the kernel is in the traced program, once a Mamba layer
+    toks = jnp.zeros((1, 16), jnp.int32)
+    for interpret, calls in ((False, 0), (True, CFG.n_mamba_layers)):
+        text = str(jax.make_jaxpr(lambda p, t: nh.forward(
+            CFG, p, t, interpret=interpret))(params, toks))
+        assert text.count("pallas_call") == calls
 
 
 def test_a_chunk_that_starts_from_zero_is_another_result(params):
